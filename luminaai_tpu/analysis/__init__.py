@@ -3,8 +3,8 @@
 Three bug classes this repo has shipped are mechanically detectable
 before anything runs:
 
-  - version-fragile `jax.experimental.shard_map` imports (the jax-0.4.37
-    class PR 5's `parallel/mesh.shard_map` compat wrapper exists for);
+  - `shard_map` reached around `parallel/mesh.shard_map`, the one entry
+    point repo code calls;
   - silent recompiles that `train_recompiles_total` only counts after
     the fact (ROADMAP item 5's per-variant recompile surface);
   - sharding-annotation gaps and host syncs inside jitted hot paths,

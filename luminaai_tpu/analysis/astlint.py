@@ -8,7 +8,7 @@ classes this repo has actually shipped — they are deliberately
 narrow-scope (precise on THIS codebase) rather than general-purpose:
 
   LX001  direct `jax.experimental.shard_map` / `jax.shard_map` use
-         outside parallel/mesh.py (the version-compat wrapper)
+         outside parallel/mesh.py (the one entry point)
   LX002  host-sync calls (.item(), np.asarray, jax.device_get,
          block_until_ready) inside jit/scan/while bodies
   LX003  Python branching or f-string formatting on tracer-typed
@@ -381,7 +381,7 @@ def _tracer_name_uses(
 
 
 # --------------------------------------------------------------------------
-# LX001 — shard_map outside the compat wrapper
+# LX001 — shard_map outside parallel/mesh.py
 # --------------------------------------------------------------------------
 
 _MESH_WRAPPER_SUFFIX = "parallel/mesh.py"
@@ -392,8 +392,8 @@ def _check_lx001(ctx: FileContext) -> Iterator[Finding]:
         return
     msg = (
         "direct shard_map use: import it from "
-        "luminaai_tpu.parallel.mesh (the version-compat wrapper) — "
-        "jax.experimental.shard_map breaks across jax 0.4.x/0.7 lines"
+        "luminaai_tpu.parallel.mesh — the one entry point, so every "
+        "manual-axes region stays enumerable"
     )
     for node in ast.walk(ctx.tree):
         if isinstance(node, ast.ImportFrom):

@@ -58,9 +58,7 @@ HOST_TRANSFER_PRIMITIVES = frozenset(
         "pure_callback",
         "io_callback",
         "debug_callback",
-        "callback",
-        "outside_call",
-        "host_callback_call",
+        "debug_print",
         "infeed",
         "outfeed",
     }
@@ -106,7 +104,7 @@ def audit_config(**overrides):
 
 
 def _iter_sub_jaxprs(params: Dict[str, Any]):
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     for value in params.values():
         stack = [value]

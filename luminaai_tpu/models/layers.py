@@ -556,14 +556,27 @@ class GQAttention(nn.Module):
             cfg.use_flash_attention
             and (kv_cache is None or rolling_prefill)
             and flash_eligible(S, d, cfg.flash_block_q, cfg.flash_block_kv)
+            # init traces with a batch-1 dummy that can't shard over the
+            # data axes; param shapes don't depend on the attention path.
+            and not self.is_initializing()
         )
         if use_flash:
-            from luminaai_tpu.ops.flash_attention import flash_attention
+            from luminaai_tpu.ops.flash_attention import (
+                flash_attention_on_mesh,
+            )
+            from luminaai_tpu.parallel.mesh import active_mesh
 
-            out = flash_attention(
+            out = flash_attention_on_mesh(
                 q,
                 k,
                 v,
+                active_mesh(),
+                nn.logical_to_mesh_axes(
+                    ("activation_batch", None, "activation_heads", None)
+                ),
+                nn.logical_to_mesh_axes(
+                    ("activation_batch", None, "activation_kv_heads", None)
+                ),
                 causal=True,
                 block_q=cfg.flash_block_q,
                 block_kv=cfg.flash_block_kv,

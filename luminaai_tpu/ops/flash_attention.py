@@ -19,6 +19,7 @@ GQAttention's einsum path.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -195,6 +196,7 @@ def _fwd(q, k, v, *, scale, causal, block_q, block_kv, window=0):
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_fwd",
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3), lse
 
@@ -344,6 +346,7 @@ def _bwd(scale, causal, block_q, block_kv, window, res, g, g_lse=None):
         out_shape=jax.ShapeDtypeStruct((B, Hq, Sq, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=_interpret(),
+        name="flash_bwd_dq",
     )(qt, kt, vt, dot, lse, delta)
 
     # dkv kernels iterate q blocks innermost; index maps swap (i, j) roles,
@@ -383,6 +386,7 @@ def _bwd(scale, causal, block_q, block_kv, window, res, g, g_lse=None):
             pltpu.VMEM((block_kv, D), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_bwd_dkv",
     )(qt, kt, vt, dot, lse, delta)
 
     # Sum GQA head groups back to the kv heads.
@@ -530,3 +534,46 @@ def flash_attention(
         q, k, v, causal=causal, scale=scale,
         block_q=block_q, block_kv=block_kv, window=window,
     )[0]
+
+
+def flash_attention_on_mesh(q, k, v, mesh, q_spec, kv_spec, **kw):
+    """flash_attention under a device mesh.
+
+    GSPMD cannot partition a Mosaic kernel ("Mosaic kernels cannot be
+    automatically partitioned"), so on a mesh of more than one device the
+    call runs under shard_map: the kernel is independent per (batch row,
+    head), batch rows split over the data axes and heads over the tensor
+    axis as `q_spec` / `kv_spec` say (PartitionSpecs over [B, S, H, D]
+    from the active logical axis rules). Sequence stays whole — sequence
+    parallelism is ring attention's job. Heads are split only when q and
+    kv heads split the same way, so every local q head still finds its kv
+    group; a dimension its axes do not divide evenly stays whole. Inside
+    an enclosing manual region (1F1B stages, the hierarchical gradient
+    sync) the caller's axes are already manual and the kernel is called
+    as is.
+    """
+    from jax.sharding import PartitionSpec as P
+
+    from luminaai_tpu.parallel.mesh import shard_map
+
+    if mesh is None or mesh.size == 1 or jax.sharding.get_abstract_mesh(
+    ).manual_axes:
+        return flash_attention(q, k, v, **kw)
+
+    def fits(axes, *dims):
+        # shard_map wants even splits; GSPMD would pad. A dim the axes do
+        # not divide (a microbatch smaller than the data axes) stays whole.
+        names = (axes,) if isinstance(axes, str) else tuple(axes or ())
+        n = math.prod(mesh.shape[a] for a in names)
+        return axes if all(d % n == 0 for d in dims) else None
+
+    heads = q_spec[2] if q_spec[2] == kv_spec[2] else None
+    spec = P(
+        fits(q_spec[0], q.shape[0]), None,
+        fits(heads, q.shape[2], k.shape[2]), None,
+    )
+    return shard_map(
+        lambda q, k, v: flash_attention(q, k, v, **kw),
+        mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False,
+    )(q, k, v)
+

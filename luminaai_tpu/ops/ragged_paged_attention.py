@@ -403,6 +403,7 @@ def ragged_paged_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, 1, D), q.dtype),
         interpret=_interpret(),
+        name="ragged_paged_decode",
     )(lengths, table, qt, kt, vt)
     return out.transpose(0, 2, 1, 3)
 

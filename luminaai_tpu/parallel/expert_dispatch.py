@@ -44,7 +44,7 @@ This module is also the sanctioned home for raw collective calls:
 astlint rule LX010 fails `lumina analyze` on direct `lax.all_to_all` /
 `lax.ppermute` use outside `parallel/` — route through
 `parallel.mesh.all_to_all` / `parallel.mesh.ppermute` (thin wrappers
-kept next to the shard_map compat wrapper) so every collective call
+kept next to the shard_map entry point) so every collective call
 site in model code stays enumerable.
 """
 

@@ -333,10 +333,21 @@ def hierarchical_grad_sync(
                 # scattered chunk already holds the full fp32 in-host
                 # sum before the cast.
                 if dcn_dtype == "bf16":
-                    c = psum(
-                        c.astype(jnp.bfloat16), data_axis,
-                        axis_index_groups=g2,
-                    ).astype(jnp.float32)
+                    c = c.astype(jnp.bfloat16)
+                    if jax.default_backend() == "cpu":
+                        # XLA:CPU (jaxlib 0.9.0) aborts the process in
+                        # AllReducePromotion on a bf16 all-reduce under
+                        # a partial-auto shard_map ("Invalid binary
+                        # instruction opcode copy"); the CPU mesh has no
+                        # DCN to save bytes on, so sum the bf16-rounded
+                        # chunks in fp32 there and round the result.
+                        c = psum(
+                            c.astype(jnp.float32), data_axis,
+                            axis_index_groups=g2,
+                        ).astype(jnp.bfloat16)
+                    else:
+                        c = psum(c, data_axis, axis_index_groups=g2)
+                    c = c.astype(jnp.float32)
                 else:
                     c = psum(c, data_axis, axis_index_groups=g2)
             if ici_d > 1:

@@ -97,38 +97,18 @@ def build_mesh(
 
 
 def shard_map(f, mesh, in_specs, out_specs, axis_names=None, check_vma=None):
-    """`jax.shard_map` across jax versions — the ONE entry point repo code
-    calls (models/moe.py, ops/ring_attention.py, parallel/pipeline.py).
+    """`jax.shard_map` — the ONE entry point repo code calls (models/moe.py,
+    ops/ring_attention.py, parallel/pipeline.py; astlint LX001).
 
-    Newer jax exposes `jax.shard_map` (manual axes named via `axis_names`,
-    replication check via `check_vma`); 0.4.x ships it as
-    `jax.experimental.shard_map.shard_map` (COMPLEMENT semantics: `auto` =
-    the axes left automatic, replication check `check_rep`, and partial-
-    auto requires the check off). Passing neither flag keeps each
-    implementation's default.
+    `axis_names` names the manual axes (any iterable; jax wants a set),
+    `check_vma` the replication check. Passing neither keeps jax's default.
     """
-    if hasattr(jax, "shard_map"):
-        kw = {}
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
     kw = {}
     if check_vma is not None:
-        kw["check_rep"] = check_vma
+        kw["check_vma"] = check_vma
     if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-        if auto:
-            kw["auto"] = auto
-            # Partial-auto shard_map predates the rep checker's support
-            # for it in 0.4.x; the checker must be off there.
-            kw["check_rep"] = False
-    return _shard_map(
+        kw["axis_names"] = frozenset(axis_names)
+    return jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw
     )
 

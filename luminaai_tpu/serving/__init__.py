@@ -8,6 +8,7 @@ from luminaai_tpu.serving.server import (
     ChatServer,
     ContinuousScheduler,
     MicroBatcher,
+    build_server,
     serve,
 )
 
@@ -19,5 +20,6 @@ __all__ = [
     "MicroBatcher",
     "Replica",
     "Router",
+    "build_server",
     "serve",
 ]

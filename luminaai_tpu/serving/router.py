@@ -1336,13 +1336,23 @@ class Router:
 
 
 def wait_ready(urls: List[str], timeout_s: float = 120.0,
-               poll_s: float = 0.25) -> None:
+               poll_s: float = 0.25, procs=None) -> None:
     """Block until every url answers /healthz with 200 (replica warmed).
-    Raises TimeoutError naming the stragglers."""
+    Raises TimeoutError naming the stragglers. With `procs` (the
+    replicas' Popen handles, in url order) a replica that exits before
+    it is ready raises RuntimeError at once instead of waiting out the
+    timeout; its own error is on the stderr it inherited."""
     transport = HttpTransport()
     deadline = time.monotonic() + timeout_s
     pending = list(urls)
     while pending:
+        for url, proc in zip(urls, procs or ()):
+            rc = proc.poll()
+            if rc is not None:
+                raise RuntimeError(
+                    f"replica {url} exited with code {rc} before it was "
+                    "ready (its error is above)"
+                )
         still = []
         for url in pending:
             try:

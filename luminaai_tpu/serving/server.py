@@ -473,6 +473,14 @@ class ContinuousScheduler:
         self._m_decode_steps = r.counter(
             "serve_decode_steps_total", "Scheduler decode steps executed"
         )
+        # Which attention path the decode step compiled (value is always
+        # 1; the label is the payload): a scrape shows what served, not
+        # what a config asked for.
+        r.gauge(
+            "serve_attention_backend",
+            "Attention backend of the decoder behind this scheduler",
+            labelnames=("backend",),
+        ).labels(backend=str(getattr(self.decoder, "backend", "dense"))).set(1)
         self._m_timeouts = r.counter(
             "serving_requests_timed_out_total",
             "Requests evicted (or refused admission) because their "
@@ -2753,7 +2761,7 @@ class ChatServer:
             httpd.server_close()
 
 
-def serve(
+def build_server(
     checkpoint: Optional[str] = None,
     host: str = "127.0.0.1",
     port: int = 5001,
@@ -2792,7 +2800,11 @@ def serve(
     page_pull_timeout_s: float = 2.0,
     page_share_max_inflight: int = 2,
 ):
-    """Build an engine from a checkpoint and serve it (CLI `serve`)."""
+    """The stack `lumina serve` runs, short of binding the socket: engine
+    restored from `checkpoint`, ContinuousScheduler, ChatServer with
+    background warmup. chip_smoke.py binds the result to an ephemeral
+    port in its own process; `host`/`port` only name this replica to
+    its page-share peers."""
     from luminaai_tpu.inference.chat import ChatInterface
 
     chat = ChatInterface(
@@ -2808,7 +2820,7 @@ def serve(
         tracer = SpanTracer(
             jsonl_path=trace_jsonl, use_jax_profiler=trace_jax
         )
-    ChatServer(
+    return ChatServer(
         chat.engine, secure=secure, bootstrap_user=bootstrap_user,
         continuous=continuous, num_slots=num_slots, page_size=page_size,
         admission_window_ms=admission_window_ms,
@@ -2856,4 +2868,11 @@ def serve(
         # background warmup generation so probes hold traffic until the
         # executables exist.
         warmup=True,
-    ).serve_forever(host, port)
+    )
+
+
+def serve(host: str = "127.0.0.1", port: int = 5001, **kw) -> None:
+    """Build an engine from a checkpoint and serve it (CLI `serve`);
+    keywords are build_server's."""
+    build_server(host=host, port=port, **kw).serve_forever(host, port)
+

@@ -49,7 +49,7 @@ def _tiny_model():
     model = LuminaTransformer(cfg)
     ids = jnp.arange(cfg.batch_size * cfg.seq_length, dtype=jnp.int32)
     ids = ids.reshape(cfg.batch_size, cfg.seq_length) % cfg.vocab_size
-    params = model.init(jax.random.key(0), ids)["params"]
+    params = jax.jit(model.init)(jax.random.key(0), ids)["params"]
     return cfg, model, params, ids
 
 
@@ -71,7 +71,9 @@ def test_loss_mask_gates_predicted_token_position():
     loss_fn = make_loss_fn(cfg, model)
     loss, _ = loss_fn(params, batch, jax.random.key(1))
 
-    logits, _ = model.apply({"params": params}, ids, deterministic=True)
+    logits, _ = jax.jit(model.apply, static_argnames="deterministic")(
+        {"params": params}, ids, deterministic=True
+    )
     logp = jax.nn.log_softmax(logits[:, j - 1].astype(jnp.float32), axis=-1)
     expected = -jnp.take_along_axis(
         logp, ids[:, j][:, None], axis=-1
@@ -105,7 +107,9 @@ def test_loss_weights_follow_label_shift():
         rng,
     )
     # Compute the per-position CE at j-1 (predicting ids[j]) directly.
-    logits, _ = model.apply({"params": params}, ids, deterministic=True)
+    logits, _ = jax.jit(model.apply, static_argnames="deterministic")(
+        {"params": params}, ids, deterministic=True
+    )
     logp = jax.nn.log_softmax(logits[:, j - 1].astype(jnp.float32), axis=-1)
     ce_j = -jnp.take_along_axis(logp, ids[:, j][:, None], axis=-1)[:, 0]
     n = cfg.batch_size * (cfg.seq_length - 1)  # valid loss positions
@@ -242,7 +246,7 @@ def _spec_engine(seq_length, attention_window, max_context):
         precision="fp32", gradient_checkpointing=False, max_new_tokens=16,
     )
     model = LuminaTransformer(cfg)
-    params = model.init(jax.random.key(0), jnp.ones((1, 8), jnp.int32))[
+    params = jax.jit(model.init)(jax.random.key(0), jnp.ones((1, 8), jnp.int32))[
         "params"
     ]
     params = jax.tree.map(

@@ -166,8 +166,8 @@ def test_rule_silent_on_repo(repo_findings, rule_id):
 
 def test_environment_no_direct_shard_map_import(repo_findings):
     """Regression for the day-one LX001 violation: connectivity_probe
-    imported jax.experimental.shard_map directly (the jax-0.4.37
-    breaking class PR 5's compat wrapper exists for). Both the lint
+    imported jax.experimental.shard_map directly instead of the one
+    parallel/mesh.shard_map entry point. Both the lint
     view and the raw AST must agree it is gone."""
     env_path = os.path.join(PKG_DIR, "utils", "environment.py")
     with open(env_path) as fh:

@@ -2,11 +2,8 @@
 
 Covers: the op-classifier goldens, attribute_trace on a synthetic
 fixture, compiled-cost gauges present-or-gracefully-absent on CPU, the
-analytic-vs-compiled MFU cross-check, the tamper-evident last-good
-cache contract (_persist_last_good writes a source block;
-_load_last_good rejects unsourced/tampered entries), the bench_gate
-pass/fail rules, and the last_good derivation pin against the committed
-sweep log."""
+analytic-vs-compiled MFU cross-check, the bench_gate pass/fail rules,
+and the donation audit."""
 
 import importlib.util
 import json
@@ -278,133 +275,6 @@ def test_connectivity_probe_reports_degraded_slice(monkeypatch):
     assert snap["diagnose_device_visibility_ok"] == 0.0
     assert snap["diagnose_processes"] == n + 2
     assert "diagnose_allreduce_seconds" not in snap
-
-
-# ---------------------------------------------------------------------------
-# tamper-evident last-good cache
-# ---------------------------------------------------------------------------
-
-@pytest.fixture
-def cache_path(monkeypatch, tmp_path):
-    path = tmp_path / "last_good_bench.json"
-    monkeypatch.setattr(bench, "LAST_GOOD_PATH", str(path))
-    return path
-
-
-RESULT = {
-    "metric": bench.METRIC,
-    "value": 31557.0,
-    "unit": "tokens/sec/chip",
-    "vs_baseline": 0.53,
-    "extras": {"platform": "tpu", "config": "flagship_tuned"},
-}
-
-
-def test_persist_writes_source_block(cache_path):
-    bench._persist_last_good(RESULT)
-    on_disk = json.loads(cache_path.read_text())
-    src = on_disk["source"]
-    assert src["kind"] == "bench_run"
-    assert "flagship_tuned" in src["origin"]
-    assert src["platform"] == "tpu"
-    assert src["payload_sha256"] == bench._payload_sha256(on_disk)
-    assert "captured_at" in on_disk and "captured_at_unix" in on_disk
-
-
-def test_load_accepts_persisted_entry(cache_path):
-    bench._persist_last_good(RESULT)
-    cached, reject = bench._load_last_good()
-    assert reject is None
-    assert cached["value"] == 31557.0
-
-
-def test_load_rejects_unsourced_entry(cache_path):
-    payload = dict(RESULT)
-    payload["captured_at"] = "2026-07-31T22:43:54Z"
-    cache_path.write_text(json.dumps(payload))
-    cached, reject = bench._load_last_good()
-    assert cached is None
-    assert reject == "cached_unsourced"
-
-
-def test_load_rejects_edited_value(cache_path):
-    bench._persist_last_good(RESULT)
-    doctored = json.loads(cache_path.read_text())
-    doctored["value"] = 99999.0
-    cache_path.write_text(json.dumps(doctored))
-    cached, reject = bench._load_last_good()
-    assert cached is None
-    assert "cached_tampered" in reject
-
-
-def test_load_rejects_moved_capture_time(cache_path):
-    """The r5 falsification: captured_at silently moved. It is inside
-    the payload hash now, so moving it breaks the entry."""
-    bench._persist_last_good(RESULT)
-    doctored = json.loads(cache_path.read_text())
-    doctored["captured_at"] = "2026-07-31T22:43:54Z"
-    cache_path.write_text(json.dumps(doctored))
-    cached, reject = bench._load_last_good()
-    assert cached is None
-    assert "cached_tampered" in reject
-
-
-def test_load_rejects_sweep_entry_when_log_line_edited(
-    cache_path, tmp_path, monkeypatch
-):
-    """A sweep_log-sourced entry dies when the cited log line no longer
-    hashes to the recorded sha (log edited after derivation)."""
-    rederive = _load_script("rederive_last_good")
-    log = tmp_path / "sweep.txt"
-    log.write_text(
-        "# session_end: 2026-07-31T04:39:09Z\n"
-        "attn       step   1038.4 ms      31557 tok/s compile"
-        "   40.2s loss 17.090\n"
-    )
-    payload = rederive.derive(str(log), "attn")
-    # Re-anchor the recorded path inside bench's _HERE for validation.
-    payload["source"]["path"] = os.path.relpath(str(log), bench._HERE)
-    payload["source"]["payload_sha256"] = bench._payload_sha256(payload)
-    cache_path.write_text(json.dumps(payload))
-    cached, reject = bench._load_last_good()
-    assert reject is None and cached["value"] == 31557.0
-
-    # Now "improve" the log line: the cache entry must die with it.
-    log.write_text(
-        "# session_end: 2026-07-31T04:39:09Z\n"
-        "attn       step    938.4 ms      34557 tok/s compile"
-        "   40.2s loss 17.090\n"
-    )
-    cached, reject = bench._load_last_good()
-    assert cached is None
-    assert "source_line_sha256_mismatch" in reject
-
-
-# ---------------------------------------------------------------------------
-# derivation pin: the committed cache IS the derivation of the committed log
-# ---------------------------------------------------------------------------
-
-def test_committed_last_good_matches_derivation():
-    """scripts/last_good_bench.json must be exactly what
-    scripts/rederive_last_good.py derives from scripts/sweep_out2.txt
-    (modulo the when-was-this-derived git_commit field) — hand-editing
-    either file breaks this test. Also pins the honest r5-revert values
-    (VERDICT r5 'Next round' #1)."""
-    rederive = _load_script("rederive_last_good")
-    derived = rederive.derive(
-        os.path.join(REPO, "scripts", "sweep_out2.txt"), "attn"
-    )
-    with open(os.path.join(REPO, "scripts", "last_good_bench.json")) as f:
-        committed = json.load(f)
-    for d in (derived, committed):
-        d["source"]["git_commit"] = None
-    assert committed == derived
-    # The honest capture facts, pinned explicitly:
-    assert committed["captured_at"] == "2026-07-31T04:39:09Z"
-    assert committed["value"] == 31557.0
-    assert committed["source"]["path"] == "scripts/sweep_out2.txt"
-    # And the shipped pair passes bench's own load-time validation.
-    assert bench._validate_source(committed) is None
 
 
 # ---------------------------------------------------------------------------

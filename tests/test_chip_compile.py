@@ -1,0 +1,218 @@
+"""Compile the main path's Pallas kernels for a described TPU v5e, at the
+flagship's real shapes, without a chip.
+
+The TPU compiler is installed with jax and compiles for a chip that is
+described and not attached (`topologies.get_topology_desc`), so what
+Mosaic refuses — a misaligned slice, too much VMEM, an unpartitionable
+kernel — is refused here, in ~2 s a kernel, at no chip time. Nothing
+runs: these tests say nothing about results or speed.
+
+Rules this file keeps (guide "on-chip-measurement" §2): the topology is
+described inside a module-scoped fixture, never at import, in a skipif or
+in a parametrize argument; everything built from it is built in the test;
+compiles happen in this process; the persistent compile cache is off
+around them (a described-chip executable is written but cannot be read
+back without a chip). The code under test asks `jax.default_backend()`,
+which is `cpu` here, so the tests steer `_interpret` / pick the megablox
+kernel themselves.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from luminaai_tpu.config import ConfigPresets
+from luminaai_tpu.ops import flash_attention as fa
+from luminaai_tpu.ops import ragged_paged_attention as rpa
+
+CFG = ConfigPresets.flagship()
+B, S = CFG.batch_size, CFG.seq_length  # 16 x 2048
+HQ, HKV = CFG.num_heads, CFG.num_kv_heads  # 16 / 8
+D = CFG.hidden_size // CFG.num_heads  # 64
+BQ, BKV = CFG.flash_block_q, CFG.flash_block_kv  # 1024 x 1024
+E, H = CFG.num_experts, CFG.hidden_size
+F = CFG.intermediate_size
+ROUTED = B * S * CFG.moe_top_k  # 65,536 rows into the grouped matmuls
+SLOTS, PAGE = 8, 128  # serving/server.py build_server defaults
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_persistent_cache():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(autouse=True)
+def _compile_kernels_not_interpreter(monkeypatch):
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    monkeypatch.setattr(rpa, "_interpret", lambda: False)
+
+
+def _compile(fn, one_chip, *shapes):
+    args = [
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        for shape, dtype in shapes
+    ]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+    return text
+
+
+def _qkv(seq=S):
+    return (
+        ((B, seq, HQ, D), BF16), ((B, seq, HKV, D), BF16),
+        ((B, seq, HKV, D), BF16),
+    )
+
+
+def _flash_fwd(q, k, v, window=0):
+    return fa._fwd(
+        q, k, v, scale=D**-0.5, causal=True, block_q=BQ, block_kv=BKV,
+        window=window,
+    )
+
+
+def _flash_bwd(q, k, v, o, lse, do, window=0):
+    return fa._bwd(
+        D**-0.5, True, BQ, BKV, window, (q, k, v, o, lse), do
+    )
+
+
+_BWD_SHAPES = _qkv() + (
+    ((B, S, HQ, D), BF16), ((B, HQ, S), jnp.float32),
+    ((B, S, HQ, D), BF16),
+)
+
+
+@pytest.mark.parametrize(
+    "fn,shapes,n_kernels",
+    [
+        (_flash_fwd, _qkv(), 1),
+        # dq and dkv are separate pallas_calls of one backward.
+        (_flash_bwd, _BWD_SHAPES, 2),
+        # Banded (windowed) grid: the kv/q axes shrink to the band.
+        (functools.partial(_flash_fwd, window=512), _qkv(), 1),
+        (functools.partial(_flash_bwd, window=512), _BWD_SHAPES, 2),
+    ],
+    ids=["fwd", "bwd_dq_dkv", "banded_fwd", "banded_bwd_dq_dkv"],
+)
+def test_flash_attention_compiles_at_flagship_shapes(
+    one_chip, fn, shapes, n_kernels
+):
+    """B 16, S 2048, 16/8 heads, D 64 (half a lane tile), 1024x1024
+    blocks: the default scoped VMEM limit must hold the fp32 score tile
+    plus scratch — no compiler_params are passed at any pallas_call."""
+    text = _compile(fn, one_chip, *shapes)
+    assert text.count('custom_call_target="tpu_custom_call"') == n_kernels
+
+
+def test_flash_vjp_is_three_kernels(one_chip):
+    """The differentiable entry point the model calls: forward, dq and
+    dkv all reach the program (save_attn keeps (out, lse), so no second
+    forward)."""
+
+    def loss(q, k, v):
+        out = fa.flash_attention(q, k, v, block_q=BQ, block_kv=BKV)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    text = _compile(
+        jax.grad(loss, argnums=(0, 1, 2)), one_chip, *_qkv()
+    )
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
+@pytest.mark.parametrize(
+    "transpose",
+    [False, True],
+    ids=["fwd_gate_up_and_down", "bwd_dlhs_and_tgmm"],
+)
+def test_megablox_gmm_compiles_at_expert_shapes(one_chip, transpose):
+    """8 experts, 65,536 routed rows, H 1024 -> 2F and F -> H — the two
+    grouped matmuls of _gmm_local; the backward adds the transposed-rhs
+    gmm (d_lhs) and tgmm (d_rhs) through megablox's custom VJP."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    def expert_ffn(rows, wi, wo, group_sizes):
+        fused = gmm(rows, wi, group_sizes, preferred_element_type=BF16)
+        gate, up = jnp.split(fused, 2, axis=-1)
+        return gmm(
+            jax.nn.silu(gate) * up, wo, group_sizes,
+            preferred_element_type=BF16,
+        )
+
+    def loss(rows, wi, wo, group_sizes):
+        out = expert_ffn(rows, wi, wo, group_sizes)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if transpose else expert_ffn
+    text = _compile(
+        fn, one_chip,
+        ((ROUTED, H), BF16), ((E, H, 2 * F), BF16), ((E, F, H), BF16),
+        ((E,), jnp.int32),
+    )
+    # fwd: 2 gmm. bwd: those 2 again + a d_lhs gmm and a tgmm for each.
+    n = text.count('custom_call_target="tpu_custom_call"')
+    assert n == (6 if transpose else 2), n
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_ragged_paged_decode_compiles_at_server_defaults(one_chip, kv_dtype):
+    """The Pallas decode kernel at the server's 8 slots x 128-row pages
+    over a 2048-token slot: one q row per lane, page table chased by
+    scalar prefetch. int8 KV is (codes, per-row scales) dequantized in
+    front of the kernel, as models/layers.py reads it."""
+    C = S
+
+    def decode(q, k, v, lengths, table):
+        if kv_dtype == "int8":
+            k, v = (
+                (codes.astype(jnp.float32) * scales).astype(BF16)
+                for codes, scales in (k, v)
+            )
+        meta = rpa.LaneMeta(
+            lengths=lengths, page_table=table, page_size=PAGE,
+            kind="decode",
+        )
+        assert rpa.ragged_eligible(PAGE, D, 1)
+        return rpa.ragged_paged_attention(q, k, v, meta)
+
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    if kv_dtype == "int8":
+        kv = (
+            sds((SLOTS, C, HKV, D), jnp.int8),
+            sds((SLOTS, C, HKV, 1), jnp.float32),
+        )
+    else:
+        kv = sds((SLOTS, C, HKV, D), BF16)
+    compiled = jax.jit(decode).lower(
+        sds((SLOTS, 1, HQ, D), BF16), kv, kv,
+        sds((SLOTS,), jnp.int32), sds((SLOTS, C // PAGE), jnp.int32),
+    ).compile()
+    assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()

@@ -73,7 +73,7 @@ def run_layer(mode, x, mesh_kw, dcn=1, chunks=2, **cfg_kw):
     layer = MoELayer(cfg, dtype=jnp.float32)
     mesh = build_mesh(cfg)
     with use_mesh(mesh):
-        params = layer.init(jax.random.PRNGKey(0), x)
+        params = jax.jit(layer.init)(jax.random.PRNGKey(0), x)
 
         def loss(p, xx):
             out, m = layer.apply(p, xx)
@@ -403,14 +403,14 @@ def test_a2a_without_mesh_falls_back_to_local_gmm():
     cfg = moe_config(moe_dispatch="a2a", expert_parallel_size=2)
     layer = MoELayer(cfg, dtype=jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(3), (2, 32, 64))
-    params = layer.init(jax.random.PRNGKey(0), x)
-    out, metrics = layer.apply(params, x)
+    params = jax.jit(layer.init)(jax.random.PRNGKey(0), x)
+    out, metrics = jax.jit(layer.apply)(params, x)
     assert out.shape == x.shape
     assert float(metrics["ep_tokens_routed"]) == 0.0
 
     cfg_s = dataclasses.replace(cfg, moe_dispatch="sort")
     layer_s = MoELayer(cfg_s, dtype=jnp.float32)
-    out_s, _ = layer_s.apply(params, x)
+    out_s, _ = jax.jit(layer_s.apply)(params, x)
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(out_s), atol=1e-5, rtol=1e-5
     )

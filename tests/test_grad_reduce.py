@@ -44,7 +44,7 @@ def train_cfg(**kw) -> Config:
     base = dict(
         vocab_size=128,
         hidden_size=32,
-        num_layers=2,
+        num_layers=1,
         num_heads=2,
         num_kv_heads=1,
         seq_length=32,
@@ -72,9 +72,21 @@ def _batch(cfg, seed):
     }
 
 
+_TRAJ_CACHE = {}
+
+
 def _traj(cfg, steps=3):
     """Loss trajectory over `steps` optimizer steps on deterministic
-    batches, plus the step handle (for the plan box)."""
+    batches, plus the step handle (for the plan box). Several tests ask
+    for the same (config, steps) — the dp8 hierarchical and flat
+    baselines — so a trajectory is run once per module."""
+    key = (repr(cfg), steps)
+    if key not in _TRAJ_CACHE:
+        _TRAJ_CACHE[key] = _run_traj(cfg, steps)
+    return _TRAJ_CACHE[key]
+
+
+def _run_traj(cfg, steps):
     model = LuminaTransformer(cfg)
     schedule = make_schedule(cfg, 100)
     tx = make_optimizer(cfg, 100, schedule)

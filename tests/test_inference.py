@@ -25,14 +25,14 @@ from luminaai_tpu.models.transformer import LuminaTransformer
 def setup():
     tok = ConversationTokenizer()
     cfg = Config(
-        vocab_size=tok.vocab_size, hidden_size=64, num_layers=2,
+        vocab_size=tok.vocab_size, hidden_size=64, num_layers=1,
         num_heads=4, num_kv_heads=2, seq_length=256,
         use_flash_attention=False, precision="fp32",
         gradient_checkpointing=False, max_new_tokens=16,
     )
     model = LuminaTransformer(cfg)
     ids = jnp.ones((1, 8), jnp.int32)
-    params = model.init(jax.random.key(0), ids)["params"]
+    params = jax.jit(model.init)(jax.random.key(0), ids)["params"]
     from flax import linen as nn
 
     params = jax.tree.map(
@@ -119,13 +119,17 @@ def test_generate_matches_no_cache_forward(setup):
         repetition_penalty=1.0,
     )
     # Reference: grow the sequence, full forward each step (ref Chat.py way).
+    # One fixed-length jitted forward: causal logits at position len-1
+    # ignore the zero padding after it, so the growing sequence reuses one
+    # compile instead of tracing a fresh length per token.
     seq = list(prompt)
     expect = []
+    width = len(prompt) + len(tokens)
+    fwd = jax.jit(lambda ids: model.apply({"params": params}, ids)[0])
     for _ in range(len(tokens)):
-        logits, _ = model.apply(
-            {"params": params}, jnp.asarray([seq], jnp.int32)
-        )
-        nxt = int(jnp.argmax(logits[0, -1]))
+        ids = np.zeros((1, width), np.int32)
+        ids[0, : len(seq)] = seq
+        nxt = int(jnp.argmax(fwd(jnp.asarray(ids))[0, len(seq) - 1]))
         expect.append(nxt)
         seq.append(nxt)
     assert tokens == expect
@@ -139,7 +143,7 @@ def test_rolling_window_cache_matches_no_cache_forward(kv_dtype):
     forwards. Covers bf16 and int8 cache layouts."""
     tok = ConversationTokenizer()
     cfg = Config(
-        vocab_size=tok.vocab_size, hidden_size=64, num_layers=2,
+        vocab_size=tok.vocab_size, hidden_size=64, num_layers=1,
         num_heads=4, num_kv_heads=2, seq_length=512,
         attention_window=100, use_flash_attention=False,
         precision="fp32", gradient_checkpointing=False,
@@ -147,7 +151,7 @@ def test_rolling_window_cache_matches_no_cache_forward(kv_dtype):
         **({"kv_cache_dtype": kv_dtype} if kv_dtype else {}),
     )
     model = LuminaTransformer(cfg)
-    params = model.init(jax.random.key(0), jnp.ones((1, 8), jnp.int32))[
+    params = jax.jit(model.init)(jax.random.key(0), jnp.ones((1, 8), jnp.int32))[
         "params"
     ]
     from flax import linen as nn
@@ -183,11 +187,13 @@ def test_rolling_window_cache_matches_no_cache_forward(kv_dtype):
     ref_caches = model.init_cache(
         1, engine.max_context, kv_cache_dtype=kv_dtype
     )
-    ref_logits, ref_caches, _ = model.apply(
-        {"params": params}, jnp.asarray([short], jnp.int32),
-        positions=jnp.arange(L)[None, :], kv_caches=ref_caches,
-        cache_index=0, deterministic=True,
-    )
+    ref_logits, ref_caches, _ = jax.jit(
+        lambda ids, caches: model.apply(
+            {"params": params}, ids,
+            positions=jnp.arange(L)[None, :], kv_caches=caches,
+            cache_index=0, deterministic=True,
+        )
+    )(jnp.asarray([short], jnp.int32), ref_caches)
     ck_pad = pad_caches[0][0]
     ck_ref = ref_caches[0][0]
     if isinstance(ck_pad, tuple):
@@ -380,7 +386,7 @@ def test_infer_config_moe():
     model = LuminaTransformer(cfg)
     from flax import linen as nn
 
-    params = model.init(jax.random.key(0), jnp.ones((1, 8), jnp.int32))["params"]
+    params = jax.jit(model.init)(jax.random.key(0), jnp.ones((1, 8), jnp.int32))["params"]
     params = jax.tree.map(
         lambda x: x.unbox() if isinstance(x, nn.meta.AxisMetadata) else x,
         params, is_leaf=lambda x: isinstance(x, nn.meta.AxisMetadata),
@@ -643,7 +649,7 @@ def test_int8_kv_cache_decode_parity(setup, scan):
 
         qparams = jax.tree.map(
             lambda x: x.unbox() if isinstance(x, nn.meta.AxisMetadata) else x,
-            qmodel.init(jax.random.key(0), ids)["params"],
+            jax.jit(qmodel.init)(jax.random.key(0), ids)["params"],
             is_leaf=lambda x: isinstance(x, nn.meta.AxisMetadata),
         )
         bcfg = dataclasses.replace(cfg, scan_layers=True)
@@ -740,7 +746,7 @@ def test_stepwise_ragged_backends_match_dense_streams(backend, window):
     kernel in interpret mode, not the fallback."""
     tok = ConversationTokenizer()
     base = Config(
-        vocab_size=tok.vocab_size, hidden_size=64, num_layers=2,
+        vocab_size=tok.vocab_size, hidden_size=64, num_layers=1,
         num_heads=1, num_kv_heads=1, seq_length=256,
         use_flash_attention=False, precision="fp32",
         gradient_checkpointing=False, max_new_tokens=16,
@@ -748,7 +754,7 @@ def test_stepwise_ragged_backends_match_dense_streams(backend, window):
     )
     model = LuminaTransformer(base)
     params = _unbox(
-        model.init(jax.random.key(0), jnp.ones((1, 8), jnp.int32))["params"]
+        jax.jit(model.init)(jax.random.key(0), jnp.ones((1, 8), jnp.int32))["params"]
     )
     prompts = [
         tok.encode_text("hello world"),

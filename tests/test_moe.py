@@ -66,7 +66,7 @@ class TestMoELayer:
         rng = jax.random.PRNGKey(0)
         x = jax.random.normal(rng, (2, cfg.seq_length, cfg.hidden_size), jnp.float32)
         layer = MoELayer(cfg, dtype=jnp.float32)
-        (out, metrics), _ = layer.init_with_output({"params": rng}, x)
+        (out, metrics), _ = jax.jit(layer.init_with_output)({"params": rng}, x)
         assert out.shape == x.shape
         assert 0.0 <= float(metrics["moe_drop_rate"]) <= 1.0
         assert metrics["expert_utilization"].shape == (cfg.num_experts,)
@@ -79,7 +79,7 @@ class TestMoELayer:
         rng = jax.random.PRNGKey(0)
         x = jax.random.normal(rng, (4, 64, cfg.hidden_size))
         layer = MoELayer(cfg, dtype=jnp.float32)
-        (_, metrics), _ = layer.init_with_output({"params": rng}, x)
+        (_, metrics), _ = jax.jit(layer.init_with_output)({"params": rng}, x)
         # with random init the router is near-uniform → aux ≈ 1.0 (its minimum)
         assert float(metrics["moe_aux_loss"]) == pytest.approx(1.0, rel=0.2)
 
@@ -88,9 +88,9 @@ class TestMoELayer:
         rng = jax.random.PRNGKey(0)
         x = jax.random.normal(rng, (1, 32, cfg.hidden_size))
         layer_train = MoELayer(cfg, dtype=jnp.float32, deterministic=False)
-        variables = layer_train.init({"params": rng, "routing": rng}, x)
-        out1, _ = layer_train.apply(variables, x, rngs={"routing": jax.random.PRNGKey(1)})
-        out2, _ = layer_train.apply(variables, x, rngs={"routing": jax.random.PRNGKey(2)})
+        variables = jax.jit(layer_train.init)({"params": rng, "routing": rng}, x)
+        out1, _ = jax.jit(layer_train.apply)(variables, x, rngs={"routing": jax.random.PRNGKey(1)})
+        out2, _ = jax.jit(layer_train.apply)(variables, x, rngs={"routing": jax.random.PRNGKey(2)})
         assert not jnp.allclose(out1, out2)
 
     def test_expert_dropout_starves_dropped_experts(self):
@@ -101,11 +101,11 @@ class TestMoELayer:
         rng = jax.random.PRNGKey(0)
         x = jax.random.normal(rng, (2, 64, cfg.hidden_size))
         layer_train = MoELayer(cfg, dtype=jnp.float32, deterministic=False)
-        variables = layer_train.init({"params": rng, "routing": rng}, x)
+        variables = jax.jit(layer_train.init)({"params": rng, "routing": rng}, x)
         # Find an rng whose mask actually drops >=1 expert (rate 0.5, E=4:
         # overwhelmingly likely per draw; scan a few keys to be deterministic).
         for seed in range(8):
-            _, metrics = layer_train.apply(
+            _, metrics = jax.jit(layer_train.apply)(
                 variables, x, rngs={"routing": jax.random.PRNGKey(seed)}
             )
             util = np.asarray(metrics["expert_utilization"])
@@ -116,8 +116,8 @@ class TestMoELayer:
             raise AssertionError("no expert ever dropped across 8 rngs")
         # Deterministic (eval) path ignores the dropout config entirely.
         layer_eval = MoELayer(cfg, dtype=jnp.float32, deterministic=True)
-        out_a, _ = layer_eval.apply(variables, x)
-        out_b, _ = layer_eval.apply(variables, x)
+        out_a, _ = jax.jit(layer_eval.apply)(variables, x)
+        out_b, _ = jax.jit(layer_eval.apply)(variables, x)
         assert jnp.allclose(out_a, out_b)
 
     def test_grad_flows_to_router(self):
@@ -125,15 +125,15 @@ class TestMoELayer:
         rng = jax.random.PRNGKey(0)
         x = jax.random.normal(rng, (2, 32, cfg.hidden_size))
         layer = MoELayer(cfg, dtype=jnp.float32)
-        variables = layer.init({"params": rng}, x)
+        variables = jax.jit(layer.init)({"params": rng}, x)
 
         def loss(params):
-            out, metrics = layer.apply({"params": params}, x)
+            out, metrics = jax.jit(layer.apply)({"params": params}, x)
             return out.sum() + metrics["moe_aux_loss"]
 
         from flax.linen import meta
 
-        g = jax.grad(loss)(variables["params"])
+        g = jax.jit(jax.grad(loss))(variables["params"])
         router_g = meta.unbox(g)["router"]
         assert float(jnp.abs(router_g).max()) > 0
 
@@ -143,7 +143,7 @@ class TestMoD:
         rng = jax.random.PRNGKey(0)
         x = jax.random.normal(rng, (2, 64, 32))
         router = MoDRouter(capacity_factor=0.5, dtype=jnp.float32)
-        (idx, gate, aux), _ = router.init_with_output(rng, x)
+        (idx, gate, aux), _ = jax.jit(router.init_with_output)(rng, x)
         assert idx.shape == (2, 32)
         assert gate.shape == (2, 32)
         # indices sorted & unique per row
@@ -163,7 +163,7 @@ class TestMoD:
                 return apply_mod(self.router, lambda s: s * 100.0, x)
 
         mod = Wrapper()
-        (out, metrics), _ = mod.init_with_output(rng, x)
+        (out, metrics), _ = jax.jit(mod.init_with_output)(rng, x)
         # exactly 4 of 16 positions get the (large) FFN output added
         changed = (jnp.abs(out[0]).sum(-1) > 1.0).sum()
         assert int(changed) == 4
@@ -182,18 +182,18 @@ class TestDispatchModes:
             moe_config(routing_noise_std=0.0), moe_dispatch=mode
         )
         layer = MoELayer(cfg, dtype=jnp.float32)
-        params = layer.init(jax.random.PRNGKey(0), x)
+        params = jax.jit(layer.init)(jax.random.PRNGKey(0), x)
 
         def loss(p, x):
-            out, _ = layer.apply(p, x)
+            out, _ = jax.jit(layer.apply)(p, x)
             return jnp.sum(out**2)
 
-        out, metrics = layer.apply(params, x)
+        out, metrics = jax.jit(layer.apply)(params, x)
         # argnums=(0, 1): the INPUT gradient is the one place the gather
         # path's hand-written _dispatch_gather adjoint executes — param
         # grads inside a standalone layer never route through d_x, so a
         # params-only comparison would leave it unpinned.
-        grads = jax.grad(loss, argnums=(0, 1))(params, x)
+        grads = jax.jit(jax.grad(loss, argnums=(0, 1)))(params, x)
         return out, metrics, grads
 
     def test_modes_equivalent(self):
@@ -228,8 +228,10 @@ class TestDispatchModes:
         lhs = jnp.asarray(rng.randn(256, 64), jnp.float32)
         rhs = jnp.asarray(rng.randn(4, 64, 96), jnp.float32)
         gs = jnp.array([128, 0, 96, 32], jnp.int32)  # ragged + empty group
-        out = gmm(lhs, rhs, gs, preferred_element_type=jnp.float32,
-                  interpret=True)
+        out = jax.jit(
+            lambda l: gmm(l, rhs, gs, preferred_element_type=jnp.float32,
+                          interpret=True)
+        )(lhs)
         bounds = np.cumsum(np.asarray(gs))
         ref = np.concatenate([
             np.asarray(lhs[(0 if e == 0 else bounds[e - 1]):bounds[e]])
@@ -239,12 +241,12 @@ class TestDispatchModes:
         np.testing.assert_allclose(
             np.asarray(out)[: bounds[-1]], ref, atol=1e-4, rtol=1e-4
         )
-        g = jax.grad(
+        g = jax.jit(jax.grad(
             lambda l: jnp.sum(
                 gmm(l, rhs, gs, preferred_element_type=jnp.float32,
                     interpret=True) ** 2
             )
-        )(lhs)
+        ))(lhs)
         assert bool(jnp.isfinite(g).all())
 
     def test_megablox_kernel_tail_rows_masked(self):
@@ -287,18 +289,22 @@ class TestDispatchModes:
                 out = out + (l * sel) @ r[e]
             return out
 
-        out_k = kernel(jnp.where(row_kept, lhs, 0), rhs, gs, jnp.float32)
-        out_r = dense_ref(jnp.where(row_kept, lhs, 0), rhs, gs, jnp.float32)
+        out_k = jax.jit(kernel, static_argnums=3)(
+            jnp.where(row_kept, lhs, 0), rhs, gs, jnp.float32
+        )
+        out_r = jax.jit(dense_ref, static_argnums=3)(
+            jnp.where(row_kept, lhs, 0), rhs, gs, jnp.float32
+        )
         np.testing.assert_allclose(
             np.asarray(out_k)[:kept], np.asarray(out_r)[:kept],
             atol=1e-4, rtol=1e-4,
         )
-        gl_k, gr_k = jax.grad(
+        gl_k, gr_k = jax.jit(jax.grad(
             lambda l, r: masked_loss(kernel, l, r), argnums=(0, 1)
-        )(lhs, rhs)
-        gl_r, gr_r = jax.grad(
+        ))(lhs, rhs)
+        gl_r, gr_r = jax.jit(jax.grad(
             lambda l, r: masked_loss(dense_ref, l, r), argnums=(0, 1)
-        )(lhs, rhs)
+        ))(lhs, rhs)
         # (b) the select-VJP annihilates tail cotangents exactly — any
         # kernel garbage (NaN included) past the kept region must not leak.
         assert np.all(np.asarray(gl_k)[kept:] == 0.0)
@@ -326,15 +332,15 @@ class TestDispatchModes:
                 moe_config(routing_noise_std=0.0), moe_dispatch=mode
             )
             layer = MoELayer(cfg, dtype=jnp.float32)
-            params = layer.init(jax.random.PRNGKey(0), x)
+            params = jax.jit(layer.init)(jax.random.PRNGKey(0), x)
 
             def loss(p, xx):
-                out, m = layer.apply(p, xx)
+                out, m = jax.jit(layer.apply)(p, xx)
                 return jnp.sum(out**2), (out, m)
 
-            (_, (out, m)), grads = jax.value_and_grad(
+            (_, (out, m)), grads = jax.jit(jax.value_and_grad(
                 loss, argnums=(0, 1), has_aux=True
-            )(params, x)
+            ))(params, x)
             results[mode] = (out, m, grads)
         out_s, m_s, g_s = results["sort"]
         out_g, m_g, g_g = results["gmm"]
@@ -419,15 +425,15 @@ class TestDispatchModes:
             capacity_factor=0.5,  # force drops: total_kept < N
         )
         layer = MoELayer(cfg, dtype=jnp.float32)
-        params = layer.init(jax.random.PRNGKey(0), x)
+        params = jax.jit(layer.init)(jax.random.PRNGKey(0), x)
 
         def loss(p, xx):
-            out, m = layer.apply(p, xx)
+            out, m = jax.jit(layer.apply)(p, xx)
             return jnp.sum(out**2), m
 
-        (val, metrics), (gp, gx) = jax.value_and_grad(
+        (val, metrics), (gp, gx) = jax.jit(jax.value_and_grad(
             loss, argnums=(0, 1), has_aux=True
-        )(params, x)
+        ))(params, x)
         assert float(metrics["moe_drop_rate"]) > 0.0  # tail is non-empty
         assert bool(jnp.isfinite(val))
         assert bool(jnp.isfinite(gx).all()), "NaN leaked into d_x"
@@ -440,12 +446,12 @@ class TestDispatchModes:
         layer_s = MoELayer(cfg_sort, dtype=jnp.float32)
 
         def loss_s(p, xx):
-            out, m = layer_s.apply(p, xx)
+            out, m = jax.jit(layer_s.apply)(p, xx)
             return jnp.sum(out**2), m
 
-        (_, _), (gp_s, gx_s) = jax.value_and_grad(
+        (_, _), (gp_s, gx_s) = jax.jit(jax.value_and_grad(
             loss_s, argnums=(0, 1), has_aux=True
-        )(params, x)
+        ))(params, x)
         np.testing.assert_allclose(
             np.asarray(gx), np.asarray(gx_s), atol=1e-4, rtol=1e-4
         )
@@ -465,8 +471,8 @@ class TestDispatchModes:
                 capacity_factor=0.5,  # force real drops
             )
             layer = MoELayer(cfg, dtype=jnp.float32)
-            params = layer.init(jax.random.PRNGKey(0), x)
-            out, m = layer.apply(params, x)
+            params = jax.jit(layer.init)(jax.random.PRNGKey(0), x)
+            out, m = jax.jit(layer.apply)(params, x)
             outs[mode], drops[mode] = out, float(m["moe_drop_rate"])
         assert drops["sort"] > 0.0  # pressure actually dropped pairs
         assert drops["gmm"] == pytest.approx(drops["sort"], abs=1e-6)

@@ -1,12 +1,32 @@
 """Ops tests: flash attention vs XLA reference, fused loss semantics."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from luminaai_tpu.ops.flash_attention import flash_attention
-from luminaai_tpu.ops.fused import clip_by_global_norm, cross_entropy_loss, global_norm
+from luminaai_tpu.ops import flash_attention as _fa
+from luminaai_tpu.ops import fused as _fused
+from luminaai_tpu.ops.fused import clip_by_global_norm, global_norm
+
+
+# Every call below runs as ONE jitted program: eager dispatch compiles
+# each primitive (and each interpreted Pallas grid step) on its own,
+# which cost 4-14 s a test on one core. Options are bound statically.
+def flash_attention(q, k, v, **kw):
+    return jax.jit(functools.partial(_fa.flash_attention, **kw))(q, k, v)
+
+
+def cross_entropy_loss(logits, labels, loss_mask=None, loss_weights=None, **kw):
+    return jax.jit(functools.partial(_fused.cross_entropy_loss, **kw))(
+        logits, labels, loss_mask, loss_weights
+    )
+
+
+def _grad3(f):
+    return jax.jit(jax.grad(f, argnums=(0, 1, 2)))
 
 
 def ref_attention(q, k, v, causal=True, window=None):
@@ -36,7 +56,7 @@ class TestFlashAttention:
         k = jax.random.normal(ks[1], (B, S, hkv, D), jnp.float32)
         v = jax.random.normal(ks[2], (B, S, hkv, D), jnp.float32)
         out = flash_attention(q, k, v, block_q=128, block_kv=128)
-        ref = ref_attention(q, k, v)
+        ref = jax.jit(ref_attention)(q, k, v)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
     def test_backward_matches_reference(self):
@@ -47,8 +67,8 @@ class TestFlashAttention:
         v = jax.random.normal(ks[2], (B, S, Hkv, D), jnp.float32)
         f = lambda q, k, v: (flash_attention(q, k, v, block_q=128, block_kv=128) ** 2).sum()
         r = lambda q, k, v: (ref_attention(q, k, v) ** 2).sum()
-        gf = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
-        gr = jax.grad(r, argnums=(0, 1, 2))(q, k, v)
+        gf = _grad3(f)(q, k, v)
+        gr = _grad3(r)(q, k, v)
         for a, b in zip(gf, gr):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4)
 
@@ -82,8 +102,8 @@ class TestFlashAttention:
                             window=window) ** 2
         ).sum()
         r = lambda q, k, v: (ref_attention(q, k, v, window=window) ** 2).sum()
-        gf = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
-        gr = jax.grad(r, argnums=(0, 1, 2))(q, k, v)
+        gf = _grad3(f)(q, k, v)
+        gr = _grad3(r)(q, k, v)
         for a, b in zip(gf, gr):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4)
 
@@ -185,10 +205,11 @@ class TestFusedLMHeadCE:
             )[0]
 
         np.testing.assert_allclose(
-            float(plain(hidden, emb)), float(fused(hidden, emb)), atol=2e-6
+            float(jax.jit(plain)(hidden, emb)),
+            float(jax.jit(fused)(hidden, emb)), atol=2e-6
         )
-        gp = jax.grad(plain, argnums=(0, 1))(hidden, emb)
-        gf = jax.grad(fused, argnums=(0, 1))(hidden, emb)
+        gp = jax.jit(jax.grad(plain, argnums=(0, 1)))(hidden, emb)
+        gf = jax.jit(jax.grad(fused, argnums=(0, 1)))(hidden, emb)
         for a, b in zip(gp, gf):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
 
@@ -197,7 +218,9 @@ class TestFusedLMHeadCE:
         logits = jnp.einsum("bsh,vh->bsv", hidden, emb)
         _, m_plain = cross_entropy_loss(logits, labels, mask, weights)
         # chunk_size not dividing S falls back to the largest divisor.
-        _, m_fused = fused_fn(hidden, emb, labels, mask, weights, chunk_size=23)
+        _, m_fused = jax.jit(functools.partial(fused_fn, chunk_size=23))(
+            hidden, emb, labels, mask, weights
+        )
         for key in ("ce_loss", "tokens_in_loss", "total_loss"):
             np.testing.assert_allclose(
                 float(m_plain[key]), float(m_fused[key]), rtol=1e-5

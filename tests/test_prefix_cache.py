@@ -123,14 +123,14 @@ def setup():
     # head_dim = 64 so the 'ragged' backend runs the REAL Pallas kernel
     # (interpret mode) rather than the fallback.
     cfg = Config(
-        vocab_size=tok.vocab_size, hidden_size=64, num_layers=2,
+        vocab_size=tok.vocab_size, hidden_size=64, num_layers=1,
         num_heads=1, num_kv_heads=1, seq_length=256,
         use_flash_attention=False, precision="fp32",
         gradient_checkpointing=False, max_new_tokens=16,
         prefill_chunk_size=32,
     )
     model = LuminaTransformer(cfg)
-    params = model.init(
+    params = jax.jit(model.init)(
         jax.random.key(0), jnp.ones((1, 8), jnp.int32)
     )["params"]
     from flax import linen as nn

@@ -19,7 +19,7 @@ def tiny_config(**kw) -> Config:
     base = dict(
         vocab_size=256,
         hidden_size=64,
-        num_layers=2,
+        num_layers=1,
         num_heads=4,
         num_kv_heads=2,
         seq_length=64,
@@ -92,15 +92,19 @@ def test_quantized_model_forward_close_and_generates():
     ids = jnp.asarray(
         np.random.RandomState(0).randint(1, 256, (2, 32)), jnp.int32
     )
-    params = model.init(jax.random.key(0), ids)["params"]
-    logits, _ = model.apply({"params": params}, ids, deterministic=True)
+    params = jax.jit(model.init)(jax.random.key(0), ids)["params"]
+    logits, _ = jax.jit(model.apply, static_argnames="deterministic")(
+        {"params": params}, ids, deterministic=True
+    )
 
     manager = QuantizationManager(cfg)
     qparams = manager.quantize_for_inference(params)
     assert manager.is_quantized
     assert manager.quantization_info["compression"] > 1.5
     deq = manager.materialize(qparams, jnp.float32)
-    qlogits, _ = model.apply({"params": deq}, ids, deterministic=True)
+    qlogits, _ = jax.jit(model.apply, static_argnames="deterministic")(
+        {"params": deq}, ids, deterministic=True
+    )
     # int8 weight-only: logits shift a little; argmax should mostly agree.
     agree = float(
         (jnp.argmax(logits, -1) == jnp.argmax(qlogits, -1)).mean()
@@ -200,7 +204,7 @@ def test_quantize_for_serving_axes_and_roles():
     cfg = tiny_config(use_moe=True, num_experts=4, moe_top_k=2)
     model = LuminaTransformer(cfg)
     ids = jnp.ones((1, 32), jnp.int32)
-    params = model.init(jax.random.key(0), ids)["params"]
+    params = jax.jit(model.init)(jax.random.key(0), ids)["params"]
     qp, info = quantize_for_serving(params, min_size=1024)
     assert info["quantized_leaves"] > 0
     flat = jax.tree_util.tree_flatten_with_path(
@@ -245,7 +249,7 @@ def test_quantize_for_serving_idempotent():
     ids = jnp.asarray(
         np.random.RandomState(0).randint(1, 256, (2, 32)), jnp.int32
     )
-    params = model.init(jax.random.key(0), ids)["params"]
+    params = jax.jit(model.init)(jax.random.key(0), ids)["params"]
     qp1, info1 = quantize_for_serving(params, min_size=1024)
     qp2, info2 = quantize_for_serving(qp1, min_size=1024)
     assert info2["quantized_leaves"] == info1["quantized_leaves"]
@@ -260,7 +264,9 @@ def test_quantize_for_serving_idempotent():
             assert b is a  # passed through, not re-quantized
             assert not isinstance(a.q, QuantizedTensor)
     # The re-quantized tree still traces and runs the int8 path.
-    qlogits, _ = model.apply({"params": qp2}, ids, deterministic=True)
+    qlogits, _ = jax.jit(model.apply, static_argnames="deterministic")(
+        {"params": qp2}, ids, deterministic=True
+    )
     assert bool(jnp.isfinite(qlogits).all())
     # quantize_tree (storage path) is idempotent the same way.
     qt1, i1 = quantize_tree(params, bits=8, min_size=1024)
@@ -278,7 +284,9 @@ def test_quantize_for_serving_idempotent():
     # Storage-layout trees fed to quantize_for_serving get re-quantized
     # into the serving (contraction-axis) layout, then trace fine.
     qs, _ = quantize_for_serving(qt1, min_size=1024)
-    slogits, _ = model.apply({"params": qs}, ids, deterministic=True)
+    slogits, _ = jax.jit(model.apply, static_argnames="deterministic")(
+        {"params": qs}, ids, deterministic=True
+    )
     assert bool(jnp.isfinite(slogits).all())
 
 
@@ -324,10 +332,14 @@ def test_int8_compute_model_forward_close(use_moe):
     ids = jnp.asarray(
         np.random.RandomState(0).randint(1, 256, (2, 32)), jnp.int32
     )
-    params = model.init(jax.random.key(0), ids)["params"]
-    logits, _ = model.apply({"params": params}, ids, deterministic=True)
+    params = jax.jit(model.init)(jax.random.key(0), ids)["params"]
+    logits, _ = jax.jit(model.apply, static_argnames="deterministic")(
+        {"params": params}, ids, deterministic=True
+    )
     qp, _ = quantize_for_serving(params, min_size=1024)
-    qlogits, _ = model.apply({"params": qp}, ids, deterministic=True)
+    qlogits, _ = jax.jit(model.apply, static_argnames="deterministic")(
+        {"params": qp}, ids, deterministic=True
+    )
     assert qlogits.shape == logits.shape
     agree = float(
         (jnp.argmax(logits, -1) == jnp.argmax(qlogits, -1)).mean()

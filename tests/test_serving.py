@@ -1141,7 +1141,7 @@ def test_engine_stream_speculative_matches_greedy_stream():
         precision="fp32", gradient_checkpointing=False, max_new_tokens=16,
     )
     model = LuminaTransformer(cfg)
-    params = model.init(jax.random.key(0), jnp.ones((1, 8), jnp.int32))[
+    params = jax.jit(model.init)(jax.random.key(0), jnp.ones((1, 8), jnp.int32))[
         "params"
     ]
     params = jax.tree.map(
@@ -1249,7 +1249,7 @@ def test_speculative_stream_window_degrade_keeps_deadline():
         gradient_checkpointing=False, max_new_tokens=8,
     )
     model = LuminaTransformer(cfg)
-    params = model.init(jax.random.key(0), jnp.ones((1, 8), jnp.int32))[
+    params = jax.jit(model.init)(jax.random.key(0), jnp.ones((1, 8), jnp.int32))[
         "params"
     ]
     params = jax.tree.map(

@@ -5,6 +5,7 @@ import time
 from pathlib import Path
 
 import jax.numpy as jnp
+import pytest
 
 from luminaai_tpu.utils.profiling import (
     SectionTimer,
@@ -125,11 +126,11 @@ def test_trainer_profile_window(tmp_path):
 
 
 def test_tpu_runtime_diagnostics_cpu_backend():
-    """Probe runs a real matmul in a subprocess (CPU here), reports
+    """Probe runs a real matmul in this process (CPU here), reports
     status/timings, and inspects the compile-cache state."""
     from luminaai_tpu.utils.environment import tpu_runtime_diagnostics
 
-    rt = tpu_runtime_diagnostics(probe_timeout=120)
+    rt = tpu_runtime_diagnostics()
     assert rt["backend"]["status"] == "ok", rt
     assert rt["backend"]["platform"] == "cpu"
     assert rt["backend"]["devices"] >= 1
@@ -137,20 +138,27 @@ def test_tpu_runtime_diagnostics_cpu_backend():
     assert "compile_cache" in rt
 
 
-def test_tpu_runtime_diagnostics_hung_probe(monkeypatch):
-    """A wedged backend (dead-tunnel signature) is reported as hung with
-    the recovery hint, not by hanging the diagnosing tool."""
+def test_tpu_runtime_diagnostics_never_starts_a_child(monkeypatch):
+    """A chip belongs to one process: the probe asks in-process, and a
+    backend that fails is reported as an error, not raised."""
     import subprocess as sp
 
     from luminaai_tpu.utils import environment
 
-    def fake_run(*a, timeout=None, **k):
-        raise sp.TimeoutExpired(a[0], timeout)
+    def no_child(*a, **k):
+        raise AssertionError("diagnostics must not start a process")
 
-    monkeypatch.setattr(sp, "run", fake_run)
-    rt = environment.tpu_runtime_diagnostics(probe_timeout=5)
-    assert rt["backend"]["status"] == "hung"
-    assert "tunnel" in rt["backend"]["hint"]
+    monkeypatch.setattr(sp, "run", no_child)
+    monkeypatch.setattr(sp, "Popen", no_child)
+    assert environment.tpu_runtime_diagnostics()["backend"]["status"] == "ok"
+
+    def dead():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(environment, "_backend_probe", dead)
+    rt = environment.tpu_runtime_diagnostics()
+    assert rt["backend"]["status"] == "error"
+    assert "Unable to initialize" in rt["backend"]["last_error"]
 
 
 def test_device_peak_flops_table():
@@ -163,5 +171,5 @@ def test_device_peak_flops_table():
     assert device_peak_flops(D("TPU v5 lite")) == 197e12
     assert device_peak_flops(D("TPU v5p")) == 459e12
     assert device_peak_flops(D("TPU v6e")) == 918e12
-    assert device_peak_flops(D("cpu")) == 197e12  # unknown → default
-    assert device_peak_flops(D("cpu"), default=1.0) == 1.0
+    with pytest.raises(ValueError, match="no peak"):
+        device_peak_flops(D("cpu"))  # unknown kind is an error, no default
