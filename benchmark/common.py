@@ -107,6 +107,24 @@ def start_trace(trace_dir: str) -> None:
     jax.profiler.start_trace(trace_dir, profiler_options=options)
 
 
+def warm_profiler(tracer) -> float:
+    """--trace 2, once the window's numbers are taken: start and stop the
+    profiler once through the program's capture control and throw that
+    trace away, so that the cost of its first start falls into no number.
+    Returns the seconds it took."""
+    import shutil
+    import tempfile
+
+    t0 = time.time()
+    junk = tempfile.mkdtemp(prefix="benchmark_trace_warm_")
+    try:
+        if tracer.start_capture(junk):
+            tracer.stop_capture()
+    finally:
+        shutil.rmtree(junk, ignore_errors=True)
+    return time.time() - t0
+
+
 def fold_seed(seed: int) -> int:
     """--seed may exceed 31 bits; jax keys and the Config take an int32."""
     return int(seed) % (2**31 - 1)

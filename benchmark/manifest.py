@@ -27,6 +27,9 @@ UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
 TOP_KEYS = {"command", "paths", "run_seconds", "configs", "workloads",
             "end_to_end", "per_layer"}
+# `trace_in_run`: the driver passes --trace 0 and --trace 2 (one process
+# measures, then traces) and no longer --trace 1.
+OPTIONAL_TOP_KEYS = {"trace_in_run"}
 WIDTH_WORDS = ("latent", "state_", "head_dim", "expansion",
                "experts_per_tok")
 
@@ -98,9 +101,12 @@ def check(bench: Dict[str, Any]) -> List[str]:
     """Every fault found in BENCHMARK.json against the contract and the
     files under benchmark/; empty when sound."""
     bad: List[str] = []
-    if set(bench) != TOP_KEYS:
-        bad.append(f"top-level keys {sorted(bench)} != {sorted(TOP_KEYS)}")
+    if not TOP_KEYS <= set(bench) <= TOP_KEYS | OPTIONAL_TOP_KEYS:
+        bad.append(f"top-level keys {sorted(bench)} != {sorted(TOP_KEYS)} "
+                   f"(+ optional {sorted(OPTIONAL_TOP_KEYS)})")
         return bad
+    if not isinstance(bench.get("trace_in_run", False), bool):
+        bad.append("trace_in_run is not a boolean")
     if not 1 <= int(bench["run_seconds"]) <= 51:
         bad.append("run_seconds outside 1..51")
     for p in bench["paths"]:

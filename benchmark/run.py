@@ -1,8 +1,10 @@
-"""python3 -m benchmark.run --workload <name> --seed <n> --seconds <s> --trace <0|1>
+"""python3 -m benchmark.run --workload <name> --seed <n> --seconds <s> --trace <0|1|2>
 
 One process: loads a cell, warms every program it will use (set-up), measures
 for --seconds, prints earlier lines and then ONE last line with the contract's
-keys. Exit code 2 and no result line when the program is not importable,
+keys. --trace 2 is a --trace 0 run that, once the window has closed and its
+numbers are taken, traces a few seconds of the same traffic through the
+program's capture control and adds the per-layer metrics to that line. Exit code 2 and no result line when the program is not importable,
 when jax finds no TPU, when the device kind is not in peaks.json or when the
 device count is not the cell's `chips`. There is no CPU mode.
 """
@@ -20,7 +22,7 @@ def main(argv=None) -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
-    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--trace", type=int, choices=(0, 1, 2), default=0)
     ap.add_argument("--keep-trace", default=None, metavar="PATH",
                     help="builder only: also copy the traced run's "
                          ".xplane.pb to PATH.xplane.pb, to look at by hand")

@@ -157,7 +157,7 @@ def warm_up(sched, decoder, vocab: int, max_tokens: int) -> int:
     return len(extents)
 
 
-def set_up(cell, args, tracer=None) -> Dict[str, Any]:
+def set_up(cell, args, tracer) -> Dict[str, Any]:
     """Weights, the reference phase, the scheduler `ChatServer` would
     build, every program warm, and the paged path checked: all of set-up."""
     import jax
@@ -165,7 +165,6 @@ def set_up(cell, args, tracer=None) -> Dict[str, Any]:
     from luminaai_tpu.inference.generate import GenerationEngine
     from luminaai_tpu.models.transformer import LuminaTransformer
     from luminaai_tpu.monitoring.telemetry import MetricsRegistry
-    from luminaai_tpu.monitoring.tracing import NULL_TRACER
     from luminaai_tpu.serving.server import ContinuousScheduler
 
     mix, dep = cell.traffic, cell.config["deployment"]
@@ -193,7 +192,7 @@ def set_up(cell, args, tracer=None) -> Dict[str, Any]:
         page_size=int(dep["page_size"]),
         max_slot_tokens=int(dep["max_slot_tokens"]),
         prefix_cache_pages=int(dep.get("prefix_cache_pages", 0)),
-        registry=registry, tracer=tracer or NULL_TRACER,
+        registry=registry, tracer=tracer,
     )
     t0 = time.time()
     longest = sum(
@@ -215,23 +214,35 @@ def run(cell, args, device: Dict[str, Any]) -> Dict[str, Any]:
 
     mix, dep = cell.traffic, cell.config["deployment"]
     compiles = common.CompileCounter()
-    trace_dir = tempfile.mkdtemp(prefix="benchmark_trace_") if args.trace else None
+    # --trace 1 mirrors spans into the profiler from the start; --trace 0
+    # and 2 run with a tracer that is off (and, under 2, switched on by
+    # its own capture control once the window's numbers are taken).
+    tracer = (SpanTracer(use_jax_profiler=True) if args.trace == 1
+              else SpanTracer(enabled=False))
+    tracing = None
     try:
-        up = set_up(cell, args, SpanTracer(use_jax_profiler=True)
-                    if args.trace else None)
+        up = set_up(cell, args, tracer)
         cfg, sched, registry = up["cfg"], up["sched"], up["registry"]
         prompt, first_answer = up["prompt"], up["first_answer"]
         decoder = sched.decoder
+        tracing = Tracing(args.trace, float(mix.get("trace_seconds", 5)),
+                          registry, tracer)
 
         if mix["kind"] == "open_loop":
             res = drive_open_loop(sched, mix, args, cfg.vocab_size, compiles,
-                                  registry, trace_dir)
+                                  registry, tracing)
         elif mix["kind"] == "closed_loop":
             res = drive_closed_loop(sched, mix, args, cfg.vocab_size,
-                                    compiles, registry, trace_dir,
+                                    compiles, registry, tracing,
                                     int(dep["num_slots"]))
         else:
             raise ValueError(f"traffic kind {mix['kind']!r} is not serving")
+        if args.trace == 2:
+            # The window ended, drained and gave its numbers exactly as
+            # under --trace 0. Only now does anything of the profiler run.
+            say("traced_tail", **traced_tail(
+                sched, mix, args, cfg.vocab_size, tracing,
+                int(dep["num_slots"])))
 
         again = correct.decode_through_scheduler(
             sched, prompt, SAMPLE_NEW_TOKENS)
@@ -248,33 +259,44 @@ def run(cell, args, device: Dict[str, Any]) -> Dict[str, Any]:
             pool=decoder.pool.stats() if hasattr(decoder.pool, "stats") else None)
         device_out = {k: device[k] for k in ("platform", "kind", "count")}
         device_out["memory_peak_bytes"] = peak_bytes
+        out = {"correct": ok, "attempted": res["attempted"],
+               "failed": res["failed"], "device": device_out,
+               "metrics": common.metric_values(cell.end_to_end, host)}
         if not args.trace:
-            return {"correct": ok, "attempted": res["attempted"],
-                    "failed": res["failed"],
-                    "metrics": common.metric_values(cell.end_to_end, host),
-                    "device": device_out}
+            return out
         values, busy, win_s, breakdown, notes = layer_readers.reduce_traced_run(
-            trace_dir, cell,
-            dict(steps=int(res["traced_decode_steps"]),
+            tracing.dir, cell,
+            dict(steps=int(tracing.steps),
                  registry_delta=res["registry_delta"], host=host,
                  body=cell.config, shapes={}, peak=device["peak"]),
             keep_as=getattr(args, "keep_trace", None))
         device_out.update(busy_s=busy, window_s=win_s)
         say("per_layer", notes=notes, values=values)
-        return {"correct": ok, "attempted": res["attempted"],
-                "failed": res["failed"],
-                "metrics": common.metric_values(cell.per_layer, values),
-                "device": device_out, "breakdown": breakdown}
+        per_layer = common.metric_values(cell.per_layer, values)
+        # --trace 1 prints the per-layer metrics alone (its end-to-end
+        # numbers were taken under the profiler); --trace 2 took them
+        # from the untraced window, so both kinds stand side by side.
+        out["metrics"] = ({**out["metrics"], **per_layer}
+                          if args.trace == 2 else per_layer)
+        out["breakdown"] = breakdown
+        return out
     finally:
-        if trace_dir:
-            shutil.rmtree(trace_dir, ignore_errors=True)
+        if tracing is not None:
+            tracing.discard()
 
 
 class Tracing:
-    """A device trace of the first `seconds` of the window."""
+    """The traced seconds of a run. --trace 1: the first `seconds` of the
+    measured window, the profiler started by the harness. --trace 2: the
+    same seconds of the same traffic AFTER the window has closed and its
+    numbers are taken, through the program's own capture control
+    (`SpanTracer.start_capture`), so that nothing of the profiler exists
+    in the process before then. --trace 0: every call is a no-op."""
 
-    def __init__(self, trace_dir: Optional[str], seconds: float, registry):
-        self.dir, self.seconds, self.registry = trace_dir, seconds, registry
+    def __init__(self, mode: int, seconds: float, registry, tracer):
+        self.mode, self.seconds = mode, seconds
+        self.registry, self.tracer = registry, tracer
+        self.dir: Optional[str] = None
         self.on = False
         self.steps = 0.0
         self._steps0 = 0.0
@@ -283,20 +305,40 @@ class Tracing:
         return layer_readers.registry_view(self.registry).get(
             "counter:serve_decode_steps_total", 0.0)
 
-    def start(self) -> None:
-        if self.dir:
+    def at_window_open(self) -> None:
+        """--trace 1 alone: trace the window's first seconds."""
+        if self.mode == 1:
+            self.dir = tempfile.mkdtemp(prefix="benchmark_trace_")
             self._steps0 = self._steps()
             common.start_trace(self.dir)
             self.on = True
             threading.Timer(self.seconds, self.stop).start()
+
+    def start_capture(self) -> None:
+        """--trace 2: the traced seconds begin."""
+        self.dir = tempfile.mkdtemp(prefix="benchmark_trace_")
+        self._steps0 = self._steps()
+        if not self.tracer.start_capture(self.dir):
+            raise RuntimeError("the program's capture control refused")
+        self.on = True
 
     def stop(self) -> None:
         import jax
 
         if self.on:
             self.on = False
-            jax.profiler.stop_trace()
+            # Before the stop: writing the trace out takes seconds, and
+            # the scheduler keeps stepping meanwhile.
             self.steps = self._steps() - self._steps0
+            if self.mode == 2:
+                self.tracer.stop_capture()
+            else:
+                jax.profiler.stop_trace()
+
+    def discard(self) -> None:
+        self.stop()
+        if self.dir:
+            shutil.rmtree(self.dir, ignore_errors=True)
 
 
 class WindowMarks:
@@ -308,7 +350,7 @@ class WindowMarks:
         self._registry, self._compiles = registry, compiles
         self._reg_open = layer_readers.registry_view(registry)
         self._built_open = compiles.lowered
-        tracing.start()
+        tracing.at_window_open()
 
     def close(self):
         """(programs built in the window, registry delta over it)."""
@@ -350,24 +392,23 @@ def _failed(r: Record) -> bool:
     return bool(r.error) or not r.done or len(r.stamps) != r.req.max_new
 
 
-def drive_open_loop(sched, mix, args, vocab, compiles, registry, trace_dir):
-    schedule = traffic_gen.open_loop_schedule(mix, args.seed, args.seconds,
-                                              vocab)
-    pre_s = float(mix["preroll_s"])
-    stop = threading.Event()  # never set: every request runs to its end
-    tracing = Tracing(trace_dir, float(mix.get("trace_seconds", 5)), registry)
+def send_schedule(sched, schedule, t_start: float, pre_s: float,
+                  stop: threading.Event, at_window_open):
+    """Send each request of an open-loop schedule when it is due, one
+    consumer thread a request. `at_window_open` is called at the fixed
+    point of the schedule where the pre-roll ends, on a server the
+    pre-roll has loaded. Returns (records, threads, what that call
+    returned)."""
     records: List[Record] = []
     threads: List[threading.Thread] = []
-    t_start = now()
-    marks: Optional[WindowMarks] = None
+    opened, marks = False, None
     for req in schedule:
-        if req.measured and marks is None:
-            # The window opens at a fixed point of the schedule, on a
-            # server loaded by the pre-roll.
+        if req.measured and not opened:
             delay = t_start + pre_s - now()
             if delay > 0:
                 time.sleep(delay)
-            marks = WindowMarks(registry, compiles, tracing)
+            marks = at_window_open()
+            opened = True
         t_due = t_start + req.due_s
         delay = t_due - now()
         if delay > 0:
@@ -378,6 +419,19 @@ def drive_open_loop(sched, mix, args, vocab, compiles, registry, trace_dir):
         th.start()
         records.append(rec)
         threads.append(th)
+    return records, threads, marks
+
+
+def drive_open_loop(sched, mix, args, vocab, compiles, registry,
+                    tracing: Tracing):
+    schedule = traffic_gen.open_loop_schedule(mix, args.seed, args.seconds,
+                                              vocab)
+    pre_s = float(mix["preroll_s"])
+    stop = threading.Event()  # never set: every request runs to its end
+    t_start = now()
+    records, threads, marks = send_schedule(
+        sched, schedule, t_start, pre_s, stop,
+        lambda: WindowMarks(registry, compiles, tracing))
     t_end = t_start + pre_s + float(args.seconds)
     if t_end - now() > 0:
         time.sleep(t_end - now())
@@ -414,19 +468,16 @@ def drive_open_loop(sched, mix, args, vocab, compiles, registry, trace_dir):
     }
     return {"attempted": len(measured), "failed": failed, "host": host,
             "report": report, "registry_delta": reg,
-            "built_in_window": built_in_window,
-            "traced_decode_steps": tracing.steps}
+            "built_in_window": built_in_window}
 
 
-def drive_closed_loop(sched, mix, args, vocab, compiles, registry, trace_dir,
-                      num_slots):
+def start_clients(sched, mix, seed: int, vocab: int, num_slots: int,
+                  stop: threading.Event):
+    """Closed-loop clients, each sending its next request when the last
+    has ended, until `stop`. Returns (records, their lock, threads)."""
     n_clients = int(mix["clients_per_slot"] * num_slots)
-    pre_s = float(mix["preroll_s"])
-    per_client = int(mix.get("requests_per_client", 64))
-    queues = traffic_gen.closed_loop_clients(mix, args.seed, n_clients,
-                                             per_client, vocab)
-    stop = threading.Event()
-    tracing = Tracing(trace_dir, float(mix.get("trace_seconds", 5)), registry)
+    queues = traffic_gen.closed_loop_clients(
+        mix, seed, n_clients, int(mix.get("requests_per_client", 64)), vocab)
     records: List[Record] = []
     lock = threading.Lock()
 
@@ -443,7 +494,50 @@ def drive_closed_loop(sched, mix, args, vocab, compiles, registry, trace_dir,
                for q in queues]
     for th in threads:
         th.start()
-    time.sleep(pre_s)
+    return records, lock, threads
+
+
+def traced_tail(sched, mix, args, vocab, tracing: Tracing,
+                num_slots: int) -> Dict[str, Any]:
+    """--trace 2, once the window's requests have drained and its numbers
+    are taken: fresh traffic of the same mix from the same generator. The
+    profiler is started and stopped once for nothing, the pre-roll loads
+    the server, `trace_seconds` are captured (the same seconds of a run
+    that --trace 1 traces), and what still runs then is cancelled."""
+    warm_s = common.warm_profiler(tracing.tracer)
+    pre_s = float(mix["preroll_s"])
+    stop = threading.Event()
+    t_start = now()
+    if mix["kind"] == "open_loop":
+        schedule = traffic_gen.open_loop_schedule(
+            mix, args.seed, tracing.seconds, vocab)
+        _, threads, _ = send_schedule(sched, schedule, t_start, pre_s, stop,
+                                      tracing.start_capture)
+    else:
+        _, _, threads = start_clients(sched, mix, args.seed, vocab,
+                                      num_slots, stop)
+        time.sleep(pre_s)
+        tracing.start_capture()
+    left = t_start + pre_s + tracing.seconds - now()
+    if left > 0:
+        time.sleep(left)
+    tracing.stop()  # writes the trace out: seconds
+    stop.set()
+    deadline = now() + float(mix["drain_s"])
+    for th in threads:
+        th.join(max(0.0, deadline - now()))
+    return {"profiler_first_start_s": warm_s,
+            "decode_steps": tracing.steps,
+            "tail_s": warm_s + now() - t_start,
+            "still_running": sum(th.is_alive() for th in threads)}
+
+
+def drive_closed_loop(sched, mix, args, vocab, compiles, registry,
+                      tracing: Tracing, num_slots):
+    stop = threading.Event()
+    records, lock, threads = start_clients(sched, mix, args.seed, vocab,
+                                           num_slots, stop)
+    time.sleep(float(mix["preroll_s"]))
     t_open = now()
     marks = WindowMarks(registry, compiles, tracing)
     time.sleep(float(args.seconds))
@@ -473,7 +567,7 @@ def drive_closed_loop(sched, mix, args, vocab, compiles, registry, trace_dir,
         "output_tokens_in_window": float(in_window),
     }
     report = {
-        "kind": "closed_loop", "clients": n_clients,
+        "kind": "closed_loop", "clients": len(threads),
         "requests_sent_in_window": len(measured), "failed": failed,
         "completed_in_window": sum(1 for r in measured if r.done),
         "window_s": seconds, "output_tokens_in_window": in_window,
@@ -484,8 +578,7 @@ def drive_closed_loop(sched, mix, args, vocab, compiles, registry, trace_dir,
     }
     return {"attempted": len(measured), "failed": failed, "host": host,
             "report": report, "registry_delta": reg,
-            "built_in_window": built_in_window,
-            "traced_decode_steps": tracing.steps}
+            "built_in_window": built_in_window}
 
 
 def _ms(summary: Dict[str, Any]) -> Dict[str, Any]:

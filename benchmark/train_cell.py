@@ -9,6 +9,11 @@ syncs over the host seconds between them. The run is ended from the same
 hook by an exception of the harness' own, so `Trainer.train()`'s final
 blocking checkpoint (about 10 B a parameter to disk) is never written: it
 would be paid by every run of every later check and measures nothing.
+
+--trace 1 traces the window's first log window with a profiler the harness
+starts. --trace 2 closes the window as --trace 0 does, takes its numbers,
+and only then asks the trainer for a capture of one more log window
+(`Trainer.request_profile`), ending the run at the sync after it.
 """
 
 from __future__ import annotations
@@ -37,10 +42,11 @@ class Window:
     """Host-side accounting from the trainer's step hook (called after
     each log sync)."""
 
-    def __init__(self, seconds: float, trace_dir,
+    def __init__(self, seconds: float, trace_mode: int,
                  compiles: common.CompileCounter, trainer):
         self.seconds = seconds
-        self.trace_dir = trace_dir
+        self.trace_mode = trace_mode
+        self.trace_dir: Optional[str] = None
         self.compiles = compiles
         self.trainer = trainer
         self.t_open: Optional[float] = None
@@ -49,15 +55,22 @@ class Window:
         self.step_close = 0
         self.losses: List[float] = []
         self.lowered_at_open = 0
+        self.lowered_in_window = 0
         self.goodput_open: Dict[str, float] = {}
         self.goodput_close: Dict[str, float] = {}
         self.registry_open: Dict[str, float] = {}
+        self.registry_delta: Dict[str, float] = {}
         self.tracing = False
         self.trace_steps = 0
         self.trace_until_step = 0
+        self.profiler_first_start_s = 0.0
 
     def _goodput(self) -> Dict[str, float]:
         return dict(self.trainer.goodput.snapshot().get("seconds", {}))
+
+    def _log_window_steps(self) -> int:
+        return TRACE_LOG_WINDOWS * max(
+            1, self.trainer.config.health_check_interval // 10)
 
     def on_sync(self, step: int, metrics: Dict[str, Any]) -> None:
         import jax
@@ -70,11 +83,19 @@ class Window:
             self.goodput_open = self._goodput()
             self.registry_open = layer_readers.registry_view(
                 self.trainer.registry)
-            if self.trace_dir:
+            if self.trace_mode == 1:
+                self.trace_dir = tempfile.mkdtemp(prefix="benchmark_trace_")
                 common.start_trace(self.trace_dir)
                 self.tracing = True
-                self.trace_until_step = step + TRACE_LOG_WINDOWS * max(
-                    1, self.trainer.config.health_check_interval // 10)
+                self.trace_until_step = step + self._log_window_steps()
+            return
+        if self.t_close is not None:
+            # --trace 2's tail: the sync that ends the captured steps.
+            # Its losses are not the window's, so `correct` leaves them.
+            if step >= self.trace_until_step:
+                self.trainer.stop_profile()
+                self.tracing = False
+                raise _WindowClosed()
             return
         self.losses.append(float(metrics.get("loss", float("nan"))))
         if self.tracing and step >= self.trace_until_step:
@@ -84,7 +105,35 @@ class Window:
         if now - self.t_open >= self.seconds and not self.tracing:
             self.t_close, self.step_close = now, step
             self.goodput_close = self._goodput()
-            raise _WindowClosed()
+            self.registry_delta = layer_readers.delta(
+                layer_readers.registry_view(self.trainer.registry),
+                self.registry_open)
+            self.lowered_in_window = (
+                self.compiles.lowered - self.lowered_at_open)
+            if self.trace_mode != 2:
+                raise _WindowClosed()
+            # The window's numbers are taken. Start and stop the profiler
+            # once for nothing (its first start is the slow one), then
+            # have the trainer capture the next log window of steps.
+            self.profiler_first_start_s = common.warm_profiler(
+                self.trainer.tracer)
+            self.trace_dir = tempfile.mkdtemp(prefix="benchmark_trace_")
+            self.trace_steps = self._log_window_steps()
+            self.trace_until_step = step + self.trace_steps
+            self.trainer.request_profile(self.trace_steps, self.trace_dir)
+            self.tracing = True
+
+    def discard(self) -> None:
+        import jax
+
+        if self.tracing:
+            self.tracing = False
+            if self.trace_mode == 2:
+                self.trainer.stop_profile()
+            else:
+                jax.profiler.stop_trace()
+        if self.trace_dir:
+            shutil.rmtree(self.trace_dir, ignore_errors=True)
 
 
 def _sample_ids(seed: int, rows: int, vocab: int) -> np.ndarray:
@@ -130,7 +179,7 @@ def run(cell, args, device: Dict[str, Any]) -> Dict[str, Any]:
 
     from luminaai_tpu import cli
     from luminaai_tpu.monitoring.telemetry import MetricsRegistry
-    from luminaai_tpu.monitoring.tracing import NULL_TRACER, SpanTracer
+    from luminaai_tpu.monitoring.tracing import SpanTracer
     from luminaai_tpu.training.orchestrator import (
         AdaptiveTrainingOrchestrator,
     )
@@ -141,8 +190,8 @@ def run(cell, args, device: Dict[str, Any]) -> Dict[str, Any]:
     seq = int(mix["seq_length"])
     batch = int(mix["sequences_per_chip"]) * chips
     out_dir = tempfile.mkdtemp(prefix="benchmark_train_")
-    trace_dir = tempfile.mkdtemp(prefix="benchmark_trace_") if args.trace else None
     compiles = common.CompileCounter()
+    window = None
     try:
         cfg = model_config.build_config(
             cell.config, batch_size=batch, seq_length=seq,
@@ -154,7 +203,9 @@ def run(cell, args, device: Dict[str, Any]) -> Dict[str, Any]:
                  "num_layers": cfg.num_layers,
                  "gradient_accumulation_steps": cfg.gradient_accumulation_steps}
         data = cli._synthetic_batches(cfg, seed=args.seed % (2**31))
-        tracer = SpanTracer(use_jax_profiler=True) if args.trace else NULL_TRACER
+        # Off under --trace 0 and 2 (2 switches it on for its capture).
+        tracer = (SpanTracer(use_jax_profiler=True) if args.trace == 1
+                  else SpanTracer(enabled=False))
         t0 = time.time()
         trainer = Trainer(cfg, train_data=data, registry=MetricsRegistry(),
                           tracer=tracer)
@@ -167,7 +218,7 @@ def run(cell, args, device: Dict[str, Any]) -> Dict[str, Any]:
         say("correct", seconds=time.time() - t0, **verdict)
 
         tokens_per_step = batch * seq
-        window = Window(args.seconds, trace_dir, compiles, trainer)
+        window = Window(args.seconds, args.trace, compiles, trainer)
         orch = AdaptiveTrainingOrchestrator(trainer)
         inner = orch.on_metrics
 
@@ -181,9 +232,6 @@ def run(cell, args, device: Dict[str, Any]) -> Dict[str, Any]:
             raise RuntimeError("training ended before the window closed")
         except _WindowClosed:
             pass
-        finally:
-            if window.tracing:
-                jax.profiler.stop_trace()
         setup_s = window.t_open - common.PROCESS_T0
         steps = window.step_close - window.step_open
         wall = window.t_close - window.t_open
@@ -196,11 +244,9 @@ def run(cell, args, device: Dict[str, Any]) -> Dict[str, Any]:
                "num_layers": trainer.config.num_layers,
                "gradient_accumulation_steps":
                    trainer.config.gradient_accumulation_steps}
-        reg = layer_readers.delta(
-            layer_readers.registry_view(trainer.registry),
-            window.registry_open)
+        reg = window.registry_delta
         rebuilt = reg.get("counter:train_recompiles_total", 0.0)
-        lowered_in_window = compiles.lowered - window.lowered_at_open
+        lowered_in_window = window.lowered_in_window
         finite = all(math.isfinite(x) for x in window.losses)
         data_wait = (window.goodput_close.get("data_wait", 0.0)
                      - window.goodput_open.get("data_wait", 0.0))
@@ -212,7 +258,10 @@ def run(cell, args, device: Dict[str, Any]) -> Dict[str, Any]:
             programs_built_in_window=lowered_in_window, step_rebuilds=rebuilt,
             compile_seconds_total=compiles.backend_s,
             goodput_seconds=window.goodput_close, setup_s=setup_s,
-            memory_peak_bytes=peak_bytes)
+            memory_peak_bytes=peak_bytes,
+            **({"profiler_first_start_s": window.profiler_first_start_s,
+                "tail_s": time.time() - window.t_close}
+               if args.trace == 2 else {}))
         ok = bool(verdict["ok"] and finite and ran == asked
                   and lowered_in_window == 0 and rebuilt == 0 and steps > 0)
         host = {
@@ -223,25 +272,30 @@ def run(cell, args, device: Dict[str, Any]) -> Dict[str, Any]:
         trainer.close()
         device_out = {k: device[k] for k in ("platform", "kind", "count")}
         device_out["memory_peak_bytes"] = peak_bytes
+        out = {"correct": ok, "attempted": steps, "failed": 0,
+               "metrics": common.metric_values(cell.end_to_end, host),
+               "device": device_out}
         if not args.trace:
-            return {"correct": ok, "attempted": steps, "failed": 0,
-                    "metrics": common.metric_values(cell.end_to_end, host),
-                    "device": device_out}
+            return out
         shapes = {"seq": seq, "seqs_per_chip": int(mix["sequences_per_chip"]),
                   "chips": chips,
                   "mesh": cell.config.get("deployment", {}).get("mesh", {})}
         values, busy, win_s, breakdown, notes = layer_readers.reduce_traced_run(
-            trace_dir, cell,
+            window.trace_dir, cell,
             dict(steps=window.trace_steps, registry_delta=reg, host=host,
                  body=cell.config, shapes=shapes, peak=device["peak"]),
             keep_as=getattr(args, "keep_trace", None))
         device_out.update(busy_s=busy, window_s=win_s)
         say("per_layer", traced_steps=window.trace_steps, notes=notes,
             values=values)
-        return {"correct": ok, "attempted": steps, "failed": 0,
-                "metrics": common.metric_values(cell.per_layer, values),
-                "device": device_out, "breakdown": breakdown}
+        per_layer = common.metric_values(cell.per_layer, values)
+        # --trace 2 took the end-to-end numbers from the untraced window,
+        # so both kinds stand side by side; --trace 1 prints per-layer only.
+        out["metrics"] = ({**out["metrics"], **per_layer}
+                          if args.trace == 2 else per_layer)
+        out["breakdown"] = breakdown
+        return out
     finally:
+        if window is not None:
+            window.discard()
         shutil.rmtree(out_dir, ignore_errors=True)
-        if trace_dir:
-            shutil.rmtree(trace_dir, ignore_errors=True)

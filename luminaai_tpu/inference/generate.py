@@ -28,6 +28,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from luminaai_tpu.config import Config
+from luminaai_tpu.monitoring.goodput import (
+    SERVE_TICK_PHASES,
+    ThreadPhaseLedger,
+)
+from luminaai_tpu.monitoring.tracing import SpanTracer
 
 logger = logging.getLogger(__name__)
 
@@ -1235,6 +1240,15 @@ class StepwiseDecoder:
         self._landed_keys: List[str] = []
         self.remote_hits = 0
         self.remote_pull_failures = 0
+        # The owning scheduler replaces both with its own (serving/
+        # server.py): spans around each transfer / program call / device
+        # read of a step, and the scheduler thread's phase ledger those
+        # three switch. Standalone, both are off and cost a branch.
+        self.tracer = SpanTracer(enabled=False)
+        self.phases = ThreadPhaseLedger(
+            SERVE_TICK_PHASES, "serve_tick_{cause}_seconds_total",
+            enabled=False,
+        )
         self._refresh_table()
 
     def _refresh_table(self) -> None:
@@ -1499,13 +1513,20 @@ class StepwiseDecoder:
         bucket = min(_bucket_len(L), self.slot_tokens)
         ids = np.zeros((1, bucket), dtype=np.int32)
         ids[0, :L] = prompt
-        logits, fresh = self._get_prefill(bucket)(
-            self.params, jnp.asarray(ids), jnp.asarray(L, jnp.int32)
-        )
-        self.pool.caches = self._get_insert()(
-            self.pool.caches, fresh, jnp.asarray(slot, jnp.int32)
-        )
-        self._refresh_table()
+        span, region = self.tracer.span, self.phases.region
+        with region("put"), span("prefill.put"):
+            ids_d = jnp.asarray(ids)
+            len_d = jnp.asarray(L, jnp.int32)
+            slot_d = jnp.asarray(slot, jnp.int32)
+        with region("dispatch"), span("prefill.dispatch"):
+            logits, fresh = self._get_prefill(bucket)(
+                self.params, ids_d, len_d
+            )
+            self.pool.caches = self._get_insert()(
+                self.pool.caches, fresh, slot_d
+            )
+        with region("put"), span("prefill.put"):
+            self._refresh_table()  # the page table, host to device
         return self._finish_prefill(slot, logits, L, max_new, sample_key,
                                     seed)
 
@@ -1514,28 +1535,33 @@ class StepwiseDecoder:
         #1, set the host lane state, return prefill_into_slot's info
         contract. Used by the whole-prompt path above and by the final
         chunk of a chunked prefill."""
-        rng = jax.random.PRNGKey(
-            seed if seed is not None else (time.time_ns() & 0xFFFFFFFF)
-        )
-        rng, first_rng = jax.random.split(rng)
-        first = int(
-            sample_token(
-                first_rng,
-                logits[0],
-                jnp.zeros((logits.shape[-1],), jnp.int32),
-                temperature=sample_key[0], top_k=sample_key[1],
-                top_p=sample_key[2], repetition_penalty=sample_key[3],
+        with self.tracer.span("prefill.sample", slot=slot):
+            rng = jax.random.PRNGKey(
+                seed if seed is not None else (time.time_ns() & 0xFFFFFFFF)
             )
-        )
-        is_stop = first in self.engine._stop_set
-        self.pool.lengths[slot] = L
-        self._tokens[slot] = first
-        self._pos[slot] = L
-        self._active[slot] = (not is_stop) and max_new > 1
-        self._counts = self._counts.at[slot].set(0)
-        if not is_stop:
-            self._counts = self._counts.at[slot, first].add(1)
-        self._rngs = self._rngs.at[slot].set(rng)
+            rng, first_rng = jax.random.split(rng)
+            # int() blocks until the prefill program has produced the
+            # logits: the device wait of an admission.
+            with self.phases.region("device_wait"):
+                first = int(
+                    sample_token(
+                        first_rng,
+                        logits[0],
+                        jnp.zeros((logits.shape[-1],), jnp.int32),
+                        temperature=sample_key[0], top_k=sample_key[1],
+                        top_p=sample_key[2],
+                        repetition_penalty=sample_key[3],
+                    )
+                )
+            is_stop = first in self.engine._stop_set
+            self.pool.lengths[slot] = L
+            self._tokens[slot] = first
+            self._pos[slot] = L
+            self._active[slot] = (not is_stop) and max_new > 1
+            self._counts = self._counts.at[slot].set(0)
+            if not is_stop:
+                self._counts = self._counts.at[slot, first].add(1)
+            self._rngs = self._rngs.at[slot].set(rng)
         return {
             "token": None if is_stop else first,
             "prompt_tokens": L,
@@ -1877,28 +1903,30 @@ class StepwiseDecoder:
         slot = st["slot"]
         base = int(st.get("start_rows", 0))
         start = base + c * chunk
-        if self.prefix_cache is not None:
-            fn = self._get_chunk_prefill_cached()
-            logits, caches = fn(
-                self.params,
-                self.pool.caches,
+        cached = self.prefix_cache is not None
+        fn = (self._get_chunk_prefill_cached() if cached
+              else self._get_chunk_prefill())
+        with self.phases.region("put"), self.tracer.span("prefill.put"):
+            args = [
                 jnp.asarray(st["ids"][:, start:start + chunk]),
                 jnp.asarray(slot, jnp.int32),
-                jnp.asarray(self._gtable[slot]),
-                jnp.asarray(int(st.get("p0", 0)), jnp.int32),
+            ]
+            if cached:
+                args += [
+                    jnp.asarray(self._gtable[slot]),
+                    jnp.asarray(int(st.get("p0", 0)), jnp.int32),
+                ]
+            args += [
                 jnp.asarray(start, jnp.int32),
                 jnp.asarray(st["length"], jnp.int32),
-            )
-        else:
-            fn = self._get_chunk_prefill()
-            logits, caches = fn(
-                self.params,
-                self.pool.caches,
-                jnp.asarray(st["ids"][:, start:start + chunk]),
-                jnp.asarray(slot, jnp.int32),
-                jnp.asarray(start, jnp.int32),
-                jnp.asarray(st["length"], jnp.int32),
-            )
+            ]
+        with self.phases.region("dispatch"), \
+                self.tracer.span("prefill.dispatch"):
+            logits, caches = fn(self.params, self.pool.caches, *args)
+            # Dropped while the chunk runs, as call-site temporaries
+            # would be: the runtime then frees them behind the program,
+            # not on this thread after the first-token sync.
+            del args
         self.pool.caches = caches
         st["next"] = c + 1
         if st["next"] < st["n_chunks"]:
@@ -1955,6 +1983,9 @@ class StepwiseDecoder:
             (slot * P + j, pid) for j, pid in assignments
         )
         return len(assignments)
+
+    def harvests_pending(self) -> bool:
+        return bool(self._harvest_queue)
 
     def flush_harvests(self) -> int:
         """Execute every queued harvest as ONE jitted bulk page copy
@@ -2164,13 +2195,17 @@ class StepwiseDecoder:
         matching generate()) and were deactivated — the scheduler frees
         their slots."""
         was_active = self._active.copy()
-        fn, fn_args = self.step_fn_and_args(sample_key)
-        caches, nxt, eos, counts, rngs = fn(*fn_args)
+        span, region = self.tracer.span, self.phases.region
+        with region("put"), span("decode.put"):
+            fn, fn_args = self.step_fn_and_args(sample_key)
+        with region("dispatch"), span("decode.dispatch"):
+            caches, nxt, eos, counts, rngs = fn(*fn_args)
         self.pool.caches = caches
         self._counts = counts
         self._rngs = rngs
-        nxt_h = np.asarray(nxt)
-        eos_h = np.asarray(eos)
+        with region("device_wait"), span("decode.fetch"):
+            nxt_h = np.asarray(nxt)
+            eos_h = np.asarray(eos)
         self._tokens = nxt_h.copy()
         self._pos[was_active] += 1
         self.pool.lengths[was_active] += 1

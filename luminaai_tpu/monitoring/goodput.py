@@ -31,6 +31,12 @@ Cost: a couple of float ops + a lock per transition, transitions happen
 at loop boundaries (not per device op), and nothing here ever touches a
 jax value — zero new host syncs on the step path by construction.
 
+The same mechanics partition any single thread's wall clock: an
+instance may name its own causes (`causes=`; the last one absorbs a
+stopped gap, as `idle` does for a run). `ThreadPhaseLedger` is that for
+a thread's hot loop: the serving scheduler's tick (SERVE_TICK_PHASES,
+one unlabelled `serve_tick_<phase>_seconds_total` counter a phase).
+
 Exports (docs/observability.md "Goodput & sentinels"):
   - `training_time_seconds_total{cause}` counter — incremented as
     segments close / reattribute (monotone: attribution only adds).
@@ -44,7 +50,8 @@ import threading
 import time
 from typing import Any, Dict, Optional, Tuple
 
-__all__ = ["CAUSES", "GoodputLedger"]
+__all__ = ["CAUSES", "SERVE_TICK_PHASES", "GoodputLedger",
+           "ThreadPhaseLedger"]
 
 # The canonical partition of a run's wall clock. Every snapshot carries
 # every key (zeros included) so dashboards and the CI check never probe
@@ -61,6 +68,18 @@ CAUSES = (
 )
 
 
+# The serving scheduler thread's partition (serving/server.py opens the
+# ledger; inference/generate.py::StepwiseDecoder switches the first
+# three around its transfers, program calls and device reads).
+SERVE_TICK_PHASES = (
+    "put",          # host->device transfers of lane state and arguments
+    "dispatch",     # calls of the jitted step / chunk program
+    "device_wait",  # blocked reading a device value
+    "sched",        # the scheduler's own Python: everything else in a tick
+    "queue_idle",   # blocked on the request queue with nothing to run
+)
+
+
 class GoodputLedger:
     """Wall-clock attribution ledger with a partition-by-construction
     invariant. Thread-safe: the owning loop switches causes, the
@@ -72,17 +91,19 @@ class GoodputLedger:
         clock=time.monotonic,
         kind: str = "training",
         enabled: bool = True,
+        causes: Tuple[str, ...] = CAUSES,
     ):
         self.enabled = bool(enabled)
+        self.causes = tuple(causes)
+        self._rest = self.causes[-1]  # absorbs a stopped gap
         self._clock = clock
         self._lock = threading.Lock()
-        self._totals: Dict[str, float] = {c: 0.0 for c in CAUSES}
+        self._totals: Dict[str, float] = {c: 0.0 for c in self.causes}
         self._cause: Optional[str] = None  # open segment's cause
         self._seg_t0: float = 0.0          # open segment's start
         self._t_start: Optional[float] = None
         self._t_stop: Optional[float] = None
         self._m_seconds = None
-        self._m_fraction = None
         if registry is not None and self.enabled:
             from luminaai_tpu.monitoring.telemetry import weak_callback
 
@@ -99,10 +120,11 @@ class GoodputLedger:
             ).set_function(weak_callback(self, lambda l: l.fraction()))
 
     # -- attribution ------------------------------------------------------
-    def start(self, cause: str = "idle") -> None:
+    def start(self, cause: Optional[str] = None) -> None:
         """Open the ledger (idempotent). Elapsed counts from here."""
         if not self.enabled:
             return
+        cause = self._check(cause or self._rest)
         with self._lock:
             if self._t_start is not None and self._t_stop is None:
                 return  # already running
@@ -112,16 +134,16 @@ class GoodputLedger:
             elif self._t_stop is not None:
                 # Restart after stop(): the stopped gap is still part of
                 # elapsed, so book it as idle or the partition breaks.
-                self._totals["idle"] += max(0.0, now - self._t_stop)
+                self._totals[self._rest] += max(0.0, now - self._t_stop)
             self._t_stop = None
-            self._cause = self._check(cause)
+            self._cause = cause
             self._seg_t0 = now
 
     def switch(self, cause: str) -> str:
         """Close the open segment and open one for `cause`. Returns the
         previous cause (so callers can restore it)."""
         if not self.enabled:
-            return "idle"
+            return self._rest
         cause = self._check(cause)
         with self._lock:
             prev = self._close_open_segment()
@@ -211,23 +233,24 @@ class GoodputLedger:
         return self._totals_elapsed_locked()[0]
 
     def fraction(self) -> float:
-        """productive / elapsed — the headline goodput number."""
+        """productive / elapsed — the headline goodput number (0 for
+        an instance whose causes name no `productive`)."""
         secs, el = self._totals_elapsed_locked()
         if el <= 0:
             return 0.0
-        return min(1.0, secs["productive"] / el)
+        return min(1.0, secs.get("productive", 0.0) / el)
 
     def snapshot(self) -> Dict[str, Any]:
         """JSON-friendly record for bench artifacts and summaries."""
         if not self.enabled:
             return {"available": False, "reason": "goodput ledger disabled"}
         secs, el = self._totals_elapsed_locked()
-        frac = min(1.0, secs["productive"] / el) if el > 0 else 0.0
+        frac = min(1.0, secs.get("productive", 0.0) / el) if el > 0 else 0.0
         return {
             "available": True,
             "elapsed_s": round(el, 4),
             "goodput_fraction": round(frac, 4),
-            "seconds": {c: round(secs[c], 4) for c in CAUSES},
+            "seconds": {c: round(secs[c], 4) for c in self.causes},
             # |sum - elapsed|: ~0 by construction (same instant for both
             # sides); the contract test and the CI check read this
             # instead of re-deriving it.
@@ -236,7 +259,7 @@ class GoodputLedger:
 
     # -- internals (lock held) -------------------------------------------
     def _close_open_segment(self) -> str:
-        prev = self._cause or "idle"
+        prev = self._cause or self._rest
         now = self._clock()
         if self._cause is not None:
             dt = max(0.0, now - self._seg_t0)
@@ -246,10 +269,62 @@ class GoodputLedger:
         self._seg_t0 = now
         return prev
 
-    @staticmethod
-    def _check(cause: str) -> str:
-        if cause not in CAUSES:
+    def _check(self, cause: str) -> str:
+        if cause not in self._totals:
             raise ValueError(
-                f"unknown goodput cause {cause!r} (one of {CAUSES})"
+                f"unknown goodput cause {cause!r} (one of {self.causes})"
             )
         return cause
+
+
+class ThreadPhaseLedger(GoodputLedger):
+    """The ledger of ONE thread's hot loop (the serving scheduler's
+    tick: ~14 switches every 45 ms). Only the owning thread switches,
+    so `switch()` takes no lock and touches no metric: a clock read and
+    a float add. The owner calls `publish()` (once a tick) to push what
+    accrued to one UNLABELLED counter per cause, `counter_format`
+    filled with the cause (a reader that sums a family's labelled
+    children could not split a label). A snapshot taken on another
+    thread may miss the one segment being switched at that instant; the
+    counters, written by the owner alone, never do."""
+
+    def __init__(self, causes: Tuple[str, ...], counter_format: str,
+                 registry=None, clock=time.monotonic, enabled: bool = True):
+        super().__init__(clock=clock, enabled=enabled, causes=causes)
+        self._counters: Dict[str, Any] = {}
+        if registry is not None and self.enabled:
+            self._counters = {
+                c: registry.counter(
+                    counter_format.format(cause=c),
+                    f"Wall-clock of the owning thread attributed to "
+                    f"'{c}' (one of {len(self.causes)} causes that "
+                    "partition its elapsed time)",
+                )
+                for c in self.causes
+            }
+        self._published = dict.fromkeys(self.causes, 0.0)
+
+    def switch(self, cause: str) -> str:
+        if not self.enabled:
+            return self._rest
+        totals = self._totals
+        if cause not in totals:
+            self._check(cause)
+        now = self._clock()
+        prev = self._cause
+        if prev is None:
+            prev = self._rest
+        else:
+            totals[prev] += max(0.0, now - self._seg_t0)
+        self._cause = cause
+        self._seg_t0 = now
+        return prev
+
+    def publish(self) -> None:
+        """Push the seconds accrued since the last call to the
+        counters. Owner thread only."""
+        for cause, counter in self._counters.items():
+            total = self._totals[cause]
+            if total > self._published[cause]:
+                counter.inc(total - self._published[cause])
+                self._published[cause] = total

@@ -19,6 +19,12 @@ able to take down serving.
 
 Disabled tracers (the default for serving: `--trace-jsonl` opts in) cost
 one attribute check per span — no objects, no lock, no I/O.
+
+Capture on demand: `start_capture(trace_dir)` / `stop_capture()` switch
+a RUNNING tracer on together with the device profiler, so one process
+can serve or train untraced and then trace a few seconds of the same
+work (benchmark `--trace 2`, `Trainer.request_profile`). This module is
+the only place in the package that starts or stops `jax.profiler`.
 """
 
 from __future__ import annotations
@@ -36,6 +42,16 @@ logger = logging.getLogger(__name__)
 __all__ = ["Span", "SpanTracer", "NULL_TRACER"]
 
 _ids = itertools.count(1)
+
+# The device profiler is one per process: whichever tracer started it
+# owns it until its stop_capture().
+_capture_lock = threading.Lock()
+_capture_owner: Optional["SpanTracer"] = None
+
+# JSONL spans are written through the file's own buffer and pushed to the
+# OS this often (and on close / stop_capture / flush): a flush per span
+# was ~200 syscalls a second on the scheduler thread.
+FLUSH_EVERY_SPANS = 256
 
 
 class Span:
@@ -171,6 +187,8 @@ class SpanTracer:
     ):
         self.enabled = bool(enabled)
         self.use_jax_profiler = bool(use_jax_profiler)
+        self._capture_dir: Optional[str] = None
+        self._flags_before_capture = (self.enabled, self.use_jax_profiler)
         self.jsonl_path = jsonl_path
         self._write_lock = threading.Lock()
         self._file: Optional[IO[str]] = None
@@ -222,9 +240,101 @@ class SpanTracer:
             if self._file is not None:
                 try:
                     self._file.write(json.dumps(span.to_dict()) + "\n")
+                    if self.spans_recorded % FLUSH_EVERY_SPANS == 0:
+                        self._file.flush()
+                except (OSError, ValueError):
+                    self.dropped_writes += 1
+
+    def flush(self) -> None:
+        """Push buffered JSONL spans to the file (close() and
+        stop_capture() do; a reader of a live file calls this first)."""
+        with self._write_lock:
+            if self._file is not None:
+                try:
                     self._file.flush()
                 except (OSError, ValueError):
                     self.dropped_writes += 1
+
+    # -- capture on demand ------------------------------------------------
+    @property
+    def capturing(self) -> bool:
+        return self._capture_dir is not None
+
+    def start_capture(self, trace_dir: str) -> bool:
+        """Switch this tracer on, mirrored into the device profiler, and
+        start a profiler trace into `trace_dir` (host events at level 2,
+        python frames off: they slow the host and swell the file). True
+        if THIS call started a capture; False (and a log line) when one
+        is already running in the process or the profiler refuses.
+        Never raises: the caller may be a serving or training thread."""
+        global _capture_owner
+        with _capture_lock:
+            if _capture_owner is not None:
+                logger.warning("capture not started: one is already running")
+                return False
+            try:
+                import jax
+
+                options = jax.profiler.ProfileOptions()
+                options.python_tracer_level = 0
+                options.host_tracer_level = 2
+                jax.profiler.start_trace(trace_dir, profiler_options=options)
+            except Exception as e:  # unsupported backend, bad directory
+                logger.warning("capture not started: %s", e)
+                return False
+            _capture_owner = self
+            self._capture_dir = trace_dir
+            self._flags_before_capture = (self.enabled, self.use_jax_profiler)
+            self.use_jax_profiler = True
+            self.enabled = True
+            self._mark_clock(jax)
+            return True
+
+    def _mark_clock(self, jax) -> None:
+        """One annotation whose NAME carries the host's wall clock, and
+        the same instant as a span: the pair lays `Span.ts` (wall clock,
+        also of request spans that cross threads) on the profiler's
+        clock. Short, not empty: trace readers drop zero-length events."""
+        span = Span("capture_clock", next(self._trace_ids), None, {})
+        unix_ns = time.time_ns()
+        t0 = time.perf_counter()
+        try:
+            with jax.profiler.TraceAnnotation(
+                f"capture_clock unix_ns={unix_ns}"
+            ):
+                while time.perf_counter() - t0 < 5e-5:
+                    pass
+        except Exception:
+            pass
+        span.t0 = unix_ns / 1e9
+        span.attrs["unix_ns"] = unix_ns
+        span.duration_s = time.perf_counter() - t0
+        self._record(span)
+
+    def stop_capture(self) -> Optional[str]:
+        """Stop the capture this tracer started, restore `enabled` and
+        `use_jax_profiler` to what they were, flush the JSONL sink.
+        Returns the trace directory, or None when this tracer had no
+        capture open (idempotent). Never raises."""
+        global _capture_owner
+        with _capture_lock:
+            if _capture_owner is not self:
+                return None
+            trace_dir = self._capture_dir
+            # Flags first: the profiler takes seconds to write a trace
+            # out, and the traced threads should stop paying for spans
+            # the moment the capture is over.
+            self.enabled, self.use_jax_profiler = self._flags_before_capture
+            try:
+                import jax
+
+                jax.profiler.stop_trace()
+            except Exception as e:
+                logger.warning("capture stop failed: %s", e)
+            self._capture_dir = None
+            _capture_owner = None
+        self.flush()
+        return trace_dir
 
     def recent(self, name: Optional[str] = None) -> list:
         with self._write_lock:
@@ -234,6 +344,7 @@ class SpanTracer:
         return spans
 
     def close(self) -> None:
+        self.stop_capture()
         with self._write_lock:
             if self._file is not None:
                 try:
@@ -243,6 +354,17 @@ class SpanTracer:
                 self._file = None
 
 
-# Shared disabled tracer: the zero-cost default every instrumented
-# component falls back to when tracing is off.
-NULL_TRACER = SpanTracer(enabled=False)
+class _SharedNullTracer(SpanTracer):
+    """A capture switched on here would switch on every call site that
+    shares the instance, so it refuses one."""
+
+    def start_capture(self, trace_dir: str) -> bool:
+        logger.warning("capture not started: NULL_TRACER is shared")
+        return False
+
+
+# Shared disabled tracer for call sites that will never trace. Whatever
+# may be asked for a capture later (Trainer, ContinuousScheduler,
+# ChatServer, StepwiseDecoder) builds its own `SpanTracer(enabled=False)`
+# instead, which costs the same while off.
+NULL_TRACER = _SharedNullTracer(enabled=False)

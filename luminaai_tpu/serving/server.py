@@ -75,7 +75,11 @@ from luminaai_tpu.monitoring.telemetry import (
     register_build_info,
     weak_callback,
 )
-from luminaai_tpu.monitoring.tracing import NULL_TRACER, SpanTracer
+from luminaai_tpu.monitoring.goodput import (
+    SERVE_TICK_PHASES,
+    ThreadPhaseLedger,
+)
+from luminaai_tpu.monitoring.tracing import SpanTracer
 from luminaai_tpu.security.auth import ANON_TENANT, tenant_hash
 
 logger = logging.getLogger(__name__)
@@ -312,8 +316,11 @@ class ContinuousScheduler:
         tenant_weights: Optional[Dict[str, int]] = None,
         watchdog: Optional[HangWatchdog] = None,
         page_share=None,
+        clock=time.monotonic,
     ):
         self.engine = engine
+        # Clock of the scheduler thread's phase ledger (tests inject one).
+        self._clock = clock
         # Hang watchdog (monitoring/watchdog.py): armed per generation,
         # beaten once per decode step — a stuck decode executable fires
         # hang_suspected + serving_hangs_total and dumps forensics
@@ -421,8 +428,30 @@ class ContinuousScheduler:
         which cost nothing until /metrics is scraped."""
         self.telemetry = bool(telemetry)
         self.registry = registry or get_registry()
-        self.tracer = tracer or NULL_TRACER
+        # A tracer of its own when none is given, so that a capture can
+        # be switched on later (SpanTracer.start_capture); off, it costs
+        # what the shared NULL_TRACER does.
+        self.tracer = (
+            tracer if tracer is not None else SpanTracer(enabled=False)
+        )
         r = self.registry
+        # Phase ledger of the scheduler THREAD (docs/observability.md
+        # "Tracing"): exactly one of put / dispatch / device_wait / sched
+        # / queue_idle accrues at any instant, so the five
+        # serve_tick_*_seconds_total counters partition the thread's
+        # elapsed time. The decoder switches the first three around its
+        # transfers, program calls and device reads; a switch is a clock
+        # read and a float add, and the counters are written once a tick
+        # (publish()). Rides the telemetry off switch like every other
+        # hot-path metric.
+        self._phases = ThreadPhaseLedger(
+            SERVE_TICK_PHASES, "serve_tick_{cause}_seconds_total",
+            registry=r, clock=self._clock, enabled=self.telemetry,
+        )
+        for attr, mine in (("tracer", self.tracer),
+                           ("phases", self._phases)):
+            if hasattr(self.decoder, attr):
+                setattr(self.decoder, attr, mine)
         self._m_queue_wait = r.histogram(
             "serve_queue_wait_seconds",
             "Submit-to-admission wait (slot contention + key parking)",
@@ -896,6 +925,21 @@ class ContinuousScheduler:
         slot = self.decoder.acquire_slot()
         t_admit = time.perf_counter()
         queue_wait = max(0.0, time.time() - req.t0)
+        with self.tracer.span(
+            "sched.admit", request_id=req.request_id, slot=slot,
+            prompt_tokens=len(req.prompt),
+            queue_wait_s=round(queue_wait, 4),
+        ):
+            info = self._admit_into_slot(req, slot, t_admit, queue_wait)
+        if info is not None:
+            self._prefill_done(req, slot, info, t_admit, active)
+
+    def _admit_into_slot(self, req, slot, t_admit, queue_wait):
+        """The `sched.admit` span's body: admission bookkeeping, then
+        `start_prefill` (chunked path: None, the chunks run from the
+        worker loop) or the whole-prompt `prefill_into_slot` (its info).
+        None too when the admission failed (request failed, slot
+        released)."""
         if self.telemetry:
             # Queue wait = submit to slot acquisition: covers both slot
             # contention and sampling-key parking.
@@ -928,7 +972,7 @@ class ContinuousScheduler:
                 logger.exception("start-prefill failed")
                 self._release_slot(slot)
                 self._fail(req, e)
-                return
+                return None
             if st is not None:
                 # Chunks run from the worker loop, one per tick,
                 # interleaved with decode steps (_advance_prefills). The
@@ -936,12 +980,12 @@ class ContinuousScheduler:
                 # serve_prefill_seconds stays a prefill-cost histogram
                 # rather than absorbing every interleaved decode tick.
                 self._prefilling[slot] = (req, st, t_admit, 0.0)
-                return
+                return None
         try:
             with self.tracer.span(
                 "prefill", slot=slot, prompt_tokens=len(req.prompt)
             ):
-                info = self.decoder.prefill_into_slot(
+                return self.decoder.prefill_into_slot(
                     slot,
                     req.prompt,
                     max_new_tokens=req.max_new,
@@ -952,8 +996,7 @@ class ContinuousScheduler:
             logger.exception("prefill-into-slot failed")
             self._release_slot(slot)
             self._fail(req, e)
-            return
-        self._prefill_done(req, slot, info, t_admit, active)
+            return None
 
     def _prefill_done(self, req, slot, info, t_admit, active,
                       prefill_s=None) -> None:
@@ -1052,8 +1095,10 @@ class ContinuousScheduler:
         bytes just landed (this flush or a remote pull) are reported
         to the router's fleet index off-thread."""
         flush = getattr(self.decoder, "flush_harvests", None)
-        if flush is not None:
-            flush()
+        pending = getattr(self.decoder, "harvests_pending", None)
+        if flush is not None and (pending is None or pending()):
+            with self.tracer.span("sched.harvest"):
+                flush()
         if self.page_share is not None:
             drain = getattr(self.decoder, "drain_landed_keys", None)
             if drain is not None:
@@ -1103,7 +1148,9 @@ class ContinuousScheduler:
             was_waiting = bool(st.get("waiting"))
             try:
                 t_chunk = time.perf_counter()
-                with self.tracer.span("prefill_chunk", slot=slot):
+                with self.tracer.span(
+                    "prefill_chunk", slot=slot, request_id=req.request_id
+                ):
                     info = self.decoder.advance_prefill(st)
                 spent += time.perf_counter() - t_chunk
                 # A chunk advance is real progress: stamp liveness here
@@ -1142,7 +1189,16 @@ class ContinuousScheduler:
                                prefill_s=spent)
             return
 
+    def _wait_for_request(self, timeout: Optional[float] = None):
+        """Block on the intake queue with nothing to run: the
+        `sched.wait` span and the ledger's queue_idle phase."""
+        self._phases.publish()  # what ran up to here; then it may be long
+        with self.tracer.span("sched.wait"), \
+                self._phases.region("queue_idle"):
+            return self.q.get(timeout=timeout)
+
     def _loop(self) -> None:
+        self._phases.start("sched")
         while True:
             if self._pending:
                 req = self._pending.pop(0)
@@ -1152,7 +1208,7 @@ class ContinuousScheduler:
                 if req is None:
                     # Nothing parked anywhere: block for the next submit,
                     # then run it through the same fair-share path.
-                    self._enqueue_tenant(self.q.get())
+                    self._enqueue_tenant(self._wait_for_request())
                     self._drain_intake()
                     req = self._next_queued()
             self._busy = True
@@ -1191,101 +1247,23 @@ class ContinuousScheduler:
             return contextlib.nullcontext()
         return self.watchdog.pause()
 
-    def _run_generation_inner(self, first: _ContinuousRequest) -> None:
-        key = first.sample_key
-        active: Dict[int, _ContinuousRequest] = {}
-        self._admit(first, active)
-        # Optional admission window: wait briefly for same-key peers so
-        # the first step already carries a batch (a latency/throughput
-        # knob, NOT required for joining — lanes join at any later step).
-        # Peers are dequeued through the same fair-share WRR path as
-        # steady-state admission, so requests already parked in tenant
-        # queues go first and a burst inside the window cannot jump them.
-        deadline = time.time() + self.window
-        while (
-            self.window > 0
-            and self.decoder.has_free_slot()
-            and not self._pending
-        ):
-            left = deadline - time.time()
-            if left <= 0:
-                break
-            self._drain_intake()
-            nxt = self._next_queued()
-            if nxt is None:
-                try:
-                    self._enqueue_tenant(self.q.get(timeout=left))
-                except queue.Empty:
-                    break
-                continue
-            if nxt.sample_key == key:
-                self._admit(nxt, active)
-            else:
-                self._pending.append(nxt)
-        # Decode-tick accumulator: one SUMMARY event per tick_every steps
-        # (per-step events would be all the ring buffer ever holds).
-        tick_steps = tick_tokens = 0
-        tick_t0 = time.perf_counter()
-        while active or self._prefilling:
-            self._admit_queued(key, active)
-            # One prefill chunk per tick: a long admission progresses
-            # without ever costing the decode batch more than one
-            # chunk-sized forward between steps (_admit/_advance_prefills
-            # pause the watchdog internally, exactly around real prefill
-            # work — never on a merely-busy queue).
-            self._advance_prefills(active)
-            # Harvest batching (ROADMAP item 2): every prefix-cache
-            # harvest that landed this tick rides ONE jitted bulk page
-            # copy instead of one pool-copy dispatch per admission.
-            self._flush_harvests()
-            if not active:
-                if self._prefilling:
-                    continue
-                break
-            try:
-                t_step = time.perf_counter()
-                toks, produced, eos = self.decoder.decode_step(key)
-                step_dt = time.perf_counter() - t_step
-            except Exception as e:
-                logger.exception("decode step failed")
-                for r in list(active.values()):
-                    self._fail(r, e)
-                    self._release(r, active)
-                for slot, (r, *_) in list(self._prefilling.items()):
-                    self._fail(r, e)
-                    self._release_slot(slot)
-                self._prefilling.clear()
-                return
-            if self.watchdog is not None:
-                self.watchdog.beat()
-            self.last_tick_ts = time.time()
-            n_produced = sum(1 for slot in active if produced[slot])
-            if self.telemetry:
-                self._m_step.observe(step_dt)
-                self._sentinel.observe(
-                    step_dt, step=int(getattr(self.decoder, "steps", 0))
-                )
-                self._m_decode_steps.inc()
-                # Per-token decode latency: the step IS the inter-token
-                # gap for every lane that emitted this step.
-                self._m_token.observe(step_dt, count=max(0, n_produced))
-            tick_steps += 1
-            tick_tokens += max(0, n_produced)
-            if tick_steps >= self.tick_every:
-                dt_tick = time.perf_counter() - tick_t0
-                self._event(
-                    "decode_tick",
-                    step=int(getattr(self.decoder, "steps", 0)),
-                    steps=tick_steps, tokens=tick_tokens,
-                    active_lanes=len(active),
-                    queue_depth=self.queue_depth(),
-                    tokens_per_sec=round(
-                        tick_tokens / max(dt_tick, 1e-9), 1
-                    ),
-                )
-                tick_steps = tick_tokens = 0
-                tick_t0 = time.perf_counter()
-            now = time.time()
+    def _tick_span(self, active: dict):
+        """`sched.tick`: one iteration of the generation loop, parent of
+        every other scheduler-thread span. The attributes cost a lock
+        (queue_depth), so they are gathered only while tracing."""
+        if not self.tracer.enabled:
+            return self.tracer.span("sched.tick")
+        return self.tracer.span(
+            "sched.tick", active_lanes=len(active),
+            prefilling=len(self._prefilling),
+            queue_depth=self.queue_depth(),
+        )
+
+    def _emit_lanes(self, active: dict, toks, produced, eos) -> None:
+        """The per-lane tail of a decode step (`sched.emit`): stream each
+        lane's token, finish and release the lanes that ended."""
+        now = time.time()
+        with self.tracer.span("sched.emit", lanes=len(active)):
             for slot, r in list(active.items()):
                 if r.cancelled:
                     self._finish(r, "cancelled")
@@ -1309,6 +1287,105 @@ class ContinuousScheduler:
                     ):
                         self._finish(r, "length")
                         self._release(r, active)
+
+    def _run_generation_inner(self, first: _ContinuousRequest) -> None:
+        key = first.sample_key
+        active: Dict[int, _ContinuousRequest] = {}
+        self._admit(first, active)
+        # Optional admission window: wait briefly for same-key peers so
+        # the first step already carries a batch (a latency/throughput
+        # knob, NOT required for joining — lanes join at any later step).
+        # Peers are dequeued through the same fair-share WRR path as
+        # steady-state admission, so requests already parked in tenant
+        # queues go first and a burst inside the window cannot jump them.
+        deadline = time.time() + self.window
+        while (
+            self.window > 0
+            and self.decoder.has_free_slot()
+            and not self._pending
+        ):
+            left = deadline - time.time()
+            if left <= 0:
+                break
+            self._drain_intake()
+            nxt = self._next_queued()
+            if nxt is None:
+                try:
+                    self._enqueue_tenant(self._wait_for_request(left))
+                except queue.Empty:
+                    break
+                continue
+            if nxt.sample_key == key:
+                self._admit(nxt, active)
+            else:
+                self._pending.append(nxt)
+        # Decode-tick accumulator: one SUMMARY event per tick_every steps
+        # (per-step events would be all the ring buffer ever holds).
+        tick_steps = tick_tokens = 0
+        tick_t0 = time.perf_counter()
+        while active or self._prefilling:
+            with self._tick_span(active):
+                self._admit_queued(key, active)
+                # One prefill chunk per tick: a long admission progresses
+                # without ever costing the decode batch more than one
+                # chunk-sized forward between steps (_admit/_advance_prefills
+                # pause the watchdog internally, exactly around real prefill
+                # work — never on a merely-busy queue).
+                self._advance_prefills(active)
+                # Harvest batching (ROADMAP item 2): every prefix-cache
+                # harvest that landed this tick rides ONE jitted bulk page
+                # copy instead of one pool-copy dispatch per admission.
+                self._flush_harvests()
+                if not active:
+                    if self._prefilling:
+                        continue
+                    break
+                try:
+                    t_step = time.perf_counter()
+                    with self.tracer.span("decode_step"):
+                        toks, produced, eos = self.decoder.decode_step(key)
+                    step_dt = time.perf_counter() - t_step
+                except Exception as e:
+                    logger.exception("decode step failed")
+                    for r in list(active.values()):
+                        self._fail(r, e)
+                        self._release(r, active)
+                    for slot, (r, *_) in list(self._prefilling.items()):
+                        self._fail(r, e)
+                        self._release_slot(slot)
+                    self._prefilling.clear()
+                    return
+                if self.watchdog is not None:
+                    self.watchdog.beat()
+                self.last_tick_ts = time.time()
+                n_produced = sum(1 for slot in active if produced[slot])
+                if self.telemetry:
+                    self._m_step.observe(step_dt)
+                    self._sentinel.observe(
+                        step_dt, step=int(getattr(self.decoder, "steps", 0))
+                    )
+                    self._m_decode_steps.inc()
+                    # Per-token decode latency: the step IS the inter-token
+                    # gap for every lane that emitted this step.
+                    self._m_token.observe(step_dt, count=max(0, n_produced))
+                tick_steps += 1
+                tick_tokens += max(0, n_produced)
+                if tick_steps >= self.tick_every:
+                    dt_tick = time.perf_counter() - tick_t0
+                    self._event(
+                        "decode_tick",
+                        step=int(getattr(self.decoder, "steps", 0)),
+                        steps=tick_steps, tokens=tick_tokens,
+                        active_lanes=len(active),
+                        queue_depth=self.queue_depth(),
+                        tokens_per_sec=round(
+                            tick_tokens / max(dt_tick, 1e-9), 1
+                        ),
+                    )
+                    tick_steps = tick_tokens = 0
+                    tick_t0 = time.perf_counter()
+                self._emit_lanes(active, toks, produced, eos)
+            self._phases.publish()
         # A harvest landing on the generation's last tick must not wait
         # for the next admission's defensive flush.
         self._flush_harvests()
@@ -1397,7 +1474,9 @@ class ChatServer:
         self.engine = engine
         self.telemetry = bool(telemetry)
         self.registry = registry or get_registry()
-        self.tracer = tracer or NULL_TRACER
+        self.tracer = (
+            tracer if tracer is not None else SpanTracer(enabled=False)
+        )
         # Wide-event trail (monitoring/events.py): request identity is
         # minted at the HTTP layer, lifecycle events come from the
         # scheduler, and drain dumps the ring into flight_dir for
@@ -2815,11 +2894,12 @@ def build_server(
         # Peers reach this replica at the address it serves on; an
         # explicit --page-share-self overrides (NAT, name-based LBs).
         page_share_self_url = f"http://{host}:{port}"
-    tracer = NULL_TRACER
-    if trace_jsonl or trace_jax:
-        tracer = SpanTracer(
-            jsonl_path=trace_jsonl, use_jax_profiler=trace_jax
-        )
+    # Off unless asked for, but always switchable: start_capture() on
+    # the server's tracer traces a running replica.
+    tracer = SpanTracer(
+        jsonl_path=trace_jsonl, enabled=bool(trace_jsonl or trace_jax),
+        use_jax_profiler=trace_jax,
+    )
     return ChatServer(
         chat.engine, secure=secure, bootstrap_user=bootstrap_user,
         continuous=continuous, num_slots=num_slots, page_size=page_size,

@@ -47,7 +47,7 @@ from luminaai_tpu.monitoring.timeseries import (
     get_history,
     set_history,
 )
-from luminaai_tpu.monitoring.tracing import NULL_TRACER, SpanTracer
+from luminaai_tpu.monitoring.tracing import SpanTracer
 from luminaai_tpu.monitoring.watchdog import (
     HangWatchdog,
     StepTimeSentinel,
@@ -66,6 +66,8 @@ from luminaai_tpu.training.optimizer import make_optimizer, make_schedule
 from luminaai_tpu.training.precision import PrecisionManager
 
 logger = logging.getLogger(__name__)
+
+_NO_ANNOTATION = contextlib.nullcontext()
 
 
 def put_process_local_batch(
@@ -171,7 +173,22 @@ class Trainer:
         # stack exports through /metrics, so training step/throughput/
         # recompile counters and health gauges ride one exposition path.
         self.registry = registry or get_registry()
-        self.tracer = tracer or NULL_TRACER
+        # A tracer of its own when none is given: request_profile()
+        # switches it on for the profiled steps (the shared NULL_TRACER
+        # is never switched on); off, a span costs one attribute check.
+        self.tracer = (
+            tracer if tracer is not None else SpanTracer(enabled=False)
+        )
+        # A capture armed by request_profile() and not yet started, and
+        # the open one: (stop step, trace_dir, attribute).
+        self._profile_request: Optional[tuple] = None
+        self._profile_open: Optional[tuple] = None
+        if config.profile_start_step:
+            self.request_profile(
+                config.profile_num_steps,
+                config.profile_dir or f"{config.output_dir}/profile",
+                attribute=True, at_step=config.profile_start_step,
+            )
         # Wide-event flight recorder (monitoring/events.py): step/router/
         # recompile/preemption events land in the process ring; the
         # emergency-save paths dump it next to the checkpoints.
@@ -1042,7 +1059,8 @@ class Trainer:
             self.train_data, "consume_resume_replay_seconds", None
         )
         while True:
-            with self.goodput.region("data_wait"):
+            with self.goodput.region("data_wait"), \
+                    self.tracer.span("train.data_wait"):
                 try:
                     batch = next(it)
                 except StopIteration:
@@ -1089,7 +1107,15 @@ class Trainer:
                     # window; the ledger flips back to productive (and
                     # the watchdog arms) once the sync lands.
                     self.goodput.switch("compile")
-                self.state, metrics = self.train_step(self.state, batch)
+                # Step marker on the profiler's timeline; a branch when
+                # nothing mirrors spans into the profiler.
+                with (
+                    jax.profiler.StepTraceAnnotation(
+                        "train_step", step_num=self.global_step
+                    )
+                    if self.tracer.use_jax_profiler else _NO_ANNOTATION
+                ):
+                    self.state, metrics = self.train_step(self.state, batch)
                 self.global_step += 1
                 self._batch_in_epoch += 1
                 # Liveness stamp for /healthz staleness (host clock read,
@@ -1119,11 +1145,14 @@ class Trainer:
                     window_t0, window_tokens, window_steps = time.time(), 0, 0
 
                 if self.global_step % log_every == 0:
-                    scalars = {
-                        k: float(v)  # ← device sync happens here
-                        for k, v in metrics.items()
-                        if getattr(v, "ndim", 1) == 0
-                    }
+                    with self.tracer.span(
+                        "train.log_sync", step=self.global_step
+                    ):
+                        scalars = {
+                            k: float(v)  # ← device sync happens here
+                            for k, v in metrics.items()
+                            if getattr(v, "ndim", 1) == 0
+                        }
                     now = time.time()
                     scalars["tokens_per_sec"] = window_tokens / max(
                         now - window_t0, 1e-9
@@ -1452,37 +1481,62 @@ class Trainer:
         return self.recorder.dump_to_dir(str(self.checkpoints.dir), reason)
 
     # -- profiling (SURVEY §5 tracing) -------------------------------------
+    def request_profile(self, num_steps: int, trace_dir: str,
+                        attribute: bool = False,
+                        at_step: Optional[int] = None) -> None:
+        """Arm a device-profiler capture of `num_steps` steps into
+        `trace_dir`: it starts at the next step boundary (or the one
+        before step `at_step`) and stops `num_steps` boundaries later,
+        through the tracer's capture control (monitoring/tracing.py), so
+        the trainer's spans and step markers land in the same trace.
+        `attribute` runs the per-subsystem attribution on the finished
+        trace. Callable from the step hook of a running `train()`; it
+        replaces a request not yet started."""
+        self._profile_request = (max(1, int(num_steps)), trace_dir,
+                                 bool(attribute), at_step)
+
+    @property
+    def profiling(self) -> bool:
+        return self._profile_open is not None
+
+    def stop_profile(self) -> Optional[str]:
+        """Stop the open capture now (idempotent): waits for the device
+        so that the last profiled step is whole. Returns the trace
+        directory when this call stopped one."""
+        if self._profile_open is None:
+            return None
+        _, trace_dir, attribute = self._profile_open
+        self._profile_open = None
+        jax.block_until_ready(self.state.params)
+        if self.tracer.stop_capture() is None:
+            return None
+        logger.info("profiler trace stopped -> %s", trace_dir)
+        if attribute:
+            self._attribute_profile(trace_dir)
+        return trace_dir
+
     def _maybe_profile(self) -> None:
-        """Start/stop a jax.profiler device trace around the configured
-        step window (config.profile_start_step / profile_num_steps, CLI
-        `--profile-steps N --profile-dir DIR`). When the window closes,
-        the trace is attributed per subsystem (monitoring/attribution.py)
-        into registry gauges + <trace_dir>/attribution.jsonl."""
-        cfg = self.config
-        if not cfg.profile_start_step:
+        """At a step boundary: start an armed capture, stop an open one
+        whose steps are done. The config's `profile_start_step` /
+        `profile_num_steps` (CLI `--profile-steps N --profile-dir DIR`)
+        is a request made at construction for that step; its trace is
+        attributed per subsystem (monitoring/attribution.py) into
+        registry gauges + <trace_dir>/attribution.jsonl."""
+        if self._profile_open is not None:
+            if self.global_step >= self._profile_open[0]:
+                self.stop_profile()
             return
-        if self.global_step == cfg.profile_start_step:
-            trace_dir = cfg.profile_dir or f"{cfg.output_dir}/profile"
-            try:
-                jax.profiler.start_trace(trace_dir)
-                self._profiling = True
-                self._profile_trace_dir = trace_dir
-                logger.info("profiler trace started -> %s", trace_dir)
-            except Exception as e:  # already tracing / unsupported backend
-                logger.warning("profiler start failed: %s", e)
-                self._profiling = False
-        elif (
-            getattr(self, "_profiling", False)
-            and self.global_step >= cfg.profile_start_step + cfg.profile_num_steps
-        ):
-            jax.block_until_ready(self.state.params)
-            jax.profiler.stop_trace()
-            self._profiling = False
-            logger.info("profiler trace stopped")
-            self._attribute_profile(
-                getattr(self, "_profile_trace_dir", None)
-                or f"{cfg.output_dir}/profile"
+        if self._profile_request is None:
+            return
+        num_steps, trace_dir, attribute, at_step = self._profile_request
+        if at_step is not None and self.global_step != at_step:
+            return
+        self._profile_request = None
+        if self.tracer.start_capture(trace_dir):
+            self._profile_open = (
+                self.global_step + num_steps, trace_dir, attribute
             )
+            logger.info("profiler trace started -> %s", trace_dir)
 
     def _attribute_profile(self, trace_dir: str) -> None:
         """Per-subsystem breakdown of the just-captured window. Requires
@@ -1636,12 +1690,7 @@ class Trainer:
         return False
 
     def close(self) -> None:
-        if getattr(self, "_profiling", False):  # run ended inside the window
-            try:
-                jax.profiler.stop_trace()
-            except Exception:
-                pass
-            self._profiling = False
+        self.stop_profile()  # the run ended inside the profiled window
         if self.watchdog is not None:
             self.watchdog.close()
         if self.history is not None:

@@ -1,70 +1,14 @@
-"""utils package tests: reporting, profiling, environment (SURVEY §2/§5)."""
+"""utils package tests: reporting, environment (SURVEY §2/§5)."""
 
 import json
-import time
 from pathlib import Path
 
-import jax.numpy as jnp
 import pytest
 
-from luminaai_tpu.utils.profiling import (
-    SectionTimer,
-    StepTimer,
-    annotate,
-    profile_function,
-    profiling_context,
-)
 from luminaai_tpu.utils.reporting import (
     create_data_summary_report,
     create_training_report,
 )
-
-
-def test_profile_function_records_synced_timings():
-    @profile_function
-    def work(x):
-        return jnp.sum(x * x)
-
-    out = work(jnp.arange(128, dtype=jnp.float32))
-    assert float(out) > 0
-    s = work.summary()
-    assert s["count"] == 1 and s["mean_s"] > 0
-
-
-def test_step_timer_window_and_summary():
-    timer = StepTimer()
-    timer.start()
-    val = jnp.ones((8,)).sum()
-    time.sleep(0.01)
-    w = timer.stop(n_steps=2, n_tokens=1000, sync=val)
-    assert w["seconds"] >= 0.01
-    assert w["tokens_per_sec"] > 0
-    s = timer.summary()
-    assert s["windows"] == 1 and s["steps"] == 2
-
-
-def test_section_timer():
-    timer = SectionTimer()
-    with timer.section("io"):
-        time.sleep(0.005)
-    with timer.section("io"):
-        pass
-    s = timer.summary()
-    assert s["io"]["count"] == 2 and s["io"]["total_s"] >= 0.005
-
-
-def test_profiling_context_noop_and_annotate():
-    with profiling_context(None):  # disabled: must be a clean no-op
-        with annotate("label"):
-            x = jnp.ones(4) + 1
-    assert float(x.sum()) == 8.0
-
-
-def test_profiling_context_writes_trace(tmp_path):
-    trace_dir = tmp_path / "trace"
-    with profiling_context(str(trace_dir)):
-        jnp.ones((64, 64)).sum().block_until_ready()
-    assert any(trace_dir.rglob("*")), "no trace output written"
 
 
 def test_training_report(tmp_path):
