@@ -96,17 +96,6 @@ def setup_compile_cache() -> str:
     return path
 
 
-def start_trace(trace_dir: str) -> None:
-    """Device trace + TraceAnnotation host spans; python frames off (they
-    slow the host and swell the file)."""
-    import jax
-
-    options = jax.profiler.ProfileOptions()
-    options.python_tracer_level = 0
-    options.host_tracer_level = 2
-    jax.profiler.start_trace(trace_dir, profiler_options=options)
-
-
 def warm_profiler(tracer) -> float:
     """--trace 2, once the window's numbers are taken: start and stop the
     profiler once through the program's capture control and throw that

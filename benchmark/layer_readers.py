@@ -21,10 +21,13 @@ regular expression; all must match.
 
 from __future__ import annotations
 
+import os
 import statistics
+import time
 from typing import Any, Dict, Optional
 
 from benchmark import flops, trace_reduce
+from benchmark.common import say
 
 
 class Context:
@@ -135,13 +138,22 @@ def reduce_traced_run(trace_dir: str, cell, ctx_kwargs: Dict[str, Any],
     """The traced run's tail, the same for every kind of cell: load the
     trace, read the cell's per-layer metrics, and name the heaviest device
     operations and the longest idle gaps. Returns (values, busy_s,
-    window_s, breakdown, notes)."""
+    window_s, breakdown, notes). Prints one `[benchmark:reduce]` line with
+    the seconds each of those took and the counts they grow with, so that
+    the log of a run that is cut names the phase it was cut in."""
     import shutil
+
+    marks = [time.time()]
+
+    def lap() -> float:
+        marks.append(time.time())
+        return marks[-1] - marks[-2]
 
     xplane = trace_reduce.find_xplane(trace_dir)
     if keep_as:
         shutil.copy(xplane, keep_as + ".xplane.pb")
     tr = trace_reduce.load(xplane)
+    spent = {"load_s": lap()}
     win = trace_reduce.window_of(tr)
     busy, win_s = trace_reduce.busy_and_window_s(tr, win)
     ctx = Context(trace=tr, window=win, **ctx_kwargs)
@@ -149,8 +161,16 @@ def reduce_traced_run(trace_dir: str, cell, ctx_kwargs: Dict[str, Any],
         name: read(name, spec, ctx)
         for name, spec in cell.layer_metric_specs().items()
     }
+    spent["per_layer_s"] = lap()
+    device_ops = trace_reduce.top_device_ops(tr, 10, win)
+    spent["top_device_ops_s"] = lap()
+    gaps = trace_reduce.device_gaps(tr, win)
     breakdown = {
-        "device_ops": trace_reduce.top_device_ops(tr, 10, win),
-        "idle_gaps": trace_reduce.idle_gaps(tr, 10, win),
+        "device_ops": device_ops,
+        "idle_gaps": trace_reduce.name_gaps(gaps, tr.host_spans, 10),
     }
+    spent["idle_gaps_s"] = lap()
+    say("reduce", **spent, xplane_bytes=os.path.getsize(xplane),
+        device_ops=sum(len(evs) for evs in tr.device_ops.values()),
+        gaps=len(gaps), host_spans=len(tr.host_spans))
     return values, busy, win_s, breakdown, ctx.notes

@@ -1,12 +1,15 @@
-"""python3 -m benchmark.run --workload <name> --seed <n> --seconds <s> --trace <0|1|2>
+"""python3 -m benchmark.run --workload <name> --seed <n> --seconds <s> --trace <0|2>
 
 One process: loads a cell, warms every program it will use (set-up), measures
 for --seconds, prints earlier lines and then ONE last line with the contract's
 keys. --trace 2 is a --trace 0 run that, once the window has closed and its
 numbers are taken, traces a few seconds of the same traffic through the
-program's capture control and adds the per-layer metrics to that line. Exit code 2 and no result line when the program is not importable,
-when jax finds no TPU, when the device kind is not in peaks.json or when the
-device count is not the cell's `chips`. There is no CPU mode.
+program's capture control and adds the per-layer metrics to that line.
+--trace 1 (a window traced from its first second by a profiler of the
+harness, retired in PR 27) is still taken and runs as --trace 2. Exit code 2
+and no result line when the program is not importable, when jax finds no
+TPU, when the device kind is not in peaks.json or when the device count is
+not the cell's `chips`. There is no CPU mode.
 """
 
 from __future__ import annotations
@@ -27,6 +30,9 @@ def main(argv=None) -> int:
                     help="builder only: also copy the traced run's "
                          ".xplane.pb to PATH.xplane.pb, to look at by hand")
     args = ap.parse_args(argv)
+    retired = args.trace == 1
+    if retired:
+        args.trace = 2
 
     from benchmark import manifest
 
@@ -48,7 +54,10 @@ def main(argv=None) -> int:
         return 2
     common.say("start", workload=cell.name, config=cell.config_name,
                traffic=cell.traffic_name, seed=args.seed,
-               seconds=args.seconds, trace=args.trace, compile_cache=cache,
+               seconds=args.seconds, trace=args.trace,
+               **({"note": "--trace 1 is retired and runs as --trace 2"}
+                  if retired else {}),
+               compile_cache=cache,
                device={k: device[k] for k in ("platform", "kind", "count")})
     kind = cell.traffic["kind"]
     if kind == "train":

@@ -214,32 +214,29 @@ def run(cell, args, device: Dict[str, Any]) -> Dict[str, Any]:
 
     mix, dep = cell.traffic, cell.config["deployment"]
     compiles = common.CompileCounter()
-    # --trace 1 mirrors spans into the profiler from the start; --trace 0
-    # and 2 run with a tracer that is off (and, under 2, switched on by
-    # its own capture control once the window's numbers are taken).
-    tracer = (SpanTracer(use_jax_profiler=True) if args.trace == 1
-              else SpanTracer(enabled=False))
+    # The tracer is off; --trace 2 switches it on through its own capture
+    # control once the window's numbers are taken.
+    tracer = SpanTracer(enabled=False)
     tracing = None
     try:
         up = set_up(cell, args, tracer)
         cfg, sched, registry = up["cfg"], up["sched"], up["registry"]
         prompt, first_answer = up["prompt"], up["first_answer"]
         decoder = sched.decoder
-        tracing = Tracing(args.trace, float(mix.get("trace_seconds", 5)),
-                          registry, tracer)
 
         if mix["kind"] == "open_loop":
             res = drive_open_loop(sched, mix, args, cfg.vocab_size, compiles,
-                                  registry, tracing)
+                                  registry)
         elif mix["kind"] == "closed_loop":
             res = drive_closed_loop(sched, mix, args, cfg.vocab_size,
-                                    compiles, registry, tracing,
-                                    int(dep["num_slots"]))
+                                    compiles, registry, int(dep["num_slots"]))
         else:
             raise ValueError(f"traffic kind {mix['kind']!r} is not serving")
-        if args.trace == 2:
+        if args.trace:
             # The window ended, drained and gave its numbers exactly as
             # under --trace 0. Only now does anything of the profiler run.
+            tracing = Tracing(float(mix.get("trace_seconds", 5)), registry,
+                              tracer)
             say("traced_tail", **traced_tail(
                 sched, mix, args, cfg.vocab_size, tracing,
                 int(dep["num_slots"])))
@@ -272,12 +269,9 @@ def run(cell, args, device: Dict[str, Any]) -> Dict[str, Any]:
             keep_as=getattr(args, "keep_trace", None))
         device_out.update(busy_s=busy, window_s=win_s)
         say("per_layer", notes=notes, values=values)
-        per_layer = common.metric_values(cell.per_layer, values)
-        # --trace 1 prints the per-layer metrics alone (its end-to-end
-        # numbers were taken under the profiler); --trace 2 took them
-        # from the untraced window, so both kinds stand side by side.
-        out["metrics"] = ({**out["metrics"], **per_layer}
-                          if args.trace == 2 else per_layer)
+        # The end-to-end numbers were taken from the untraced window, so
+        # both kinds stand side by side.
+        out["metrics"].update(common.metric_values(cell.per_layer, values))
         out["breakdown"] = breakdown
         return out
     finally:
@@ -286,15 +280,13 @@ def run(cell, args, device: Dict[str, Any]) -> Dict[str, Any]:
 
 
 class Tracing:
-    """The traced seconds of a run. --trace 1: the first `seconds` of the
-    measured window, the profiler started by the harness. --trace 2: the
-    same seconds of the same traffic AFTER the window has closed and its
-    numbers are taken, through the program's own capture control
-    (`SpanTracer.start_capture`), so that nothing of the profiler exists
-    in the process before then. --trace 0: every call is a no-op."""
+    """The traced seconds of a --trace 2 run: `seconds` of the window's
+    traffic AFTER the window has closed and its numbers are taken, through
+    the program's own capture control (`SpanTracer.start_capture`), so
+    that nothing of the profiler exists in the process before then."""
 
-    def __init__(self, mode: int, seconds: float, registry, tracer):
-        self.mode, self.seconds = mode, seconds
+    def __init__(self, seconds: float, registry, tracer):
+        self.seconds = seconds
         self.registry, self.tracer = registry, tracer
         self.dir: Optional[str] = None
         self.on = False
@@ -305,17 +297,8 @@ class Tracing:
         return layer_readers.registry_view(self.registry).get(
             "counter:serve_decode_steps_total", 0.0)
 
-    def at_window_open(self) -> None:
-        """--trace 1 alone: trace the window's first seconds."""
-        if self.mode == 1:
-            self.dir = tempfile.mkdtemp(prefix="benchmark_trace_")
-            self._steps0 = self._steps()
-            common.start_trace(self.dir)
-            self.on = True
-            threading.Timer(self.seconds, self.stop).start()
-
     def start_capture(self) -> None:
-        """--trace 2: the traced seconds begin."""
+        """The traced seconds begin."""
         self.dir = tempfile.mkdtemp(prefix="benchmark_trace_")
         self._steps0 = self._steps()
         if not self.tracer.start_capture(self.dir):
@@ -323,17 +306,12 @@ class Tracing:
         self.on = True
 
     def stop(self) -> None:
-        import jax
-
         if self.on:
             self.on = False
             # Before the stop: writing the trace out takes seconds, and
             # the scheduler keeps stepping meanwhile.
             self.steps = self._steps() - self._steps0
-            if self.mode == 2:
-                self.tracer.stop_capture()
-            else:
-                jax.profiler.stop_trace()
+            self.tracer.stop_capture()
 
     def discard(self) -> None:
         self.stop()
@@ -343,14 +321,13 @@ class Tracing:
 
 class WindowMarks:
     """Read when the window opens, differenced when it closes: set-up
-    seconds, the registry, the count of programs built; starts the trace."""
+    seconds, the registry, the count of programs built."""
 
-    def __init__(self, registry, compiles, tracing: Tracing):
+    def __init__(self, registry, compiles):
         self.setup_s = time.time() - common.PROCESS_T0
         self._registry, self._compiles = registry, compiles
         self._reg_open = layer_readers.registry_view(registry)
         self._built_open = compiles.lowered
-        tracing.at_window_open()
 
     def close(self):
         """(programs built in the window, registry delta over it)."""
@@ -422,8 +399,7 @@ def send_schedule(sched, schedule, t_start: float, pre_s: float,
     return records, threads, marks
 
 
-def drive_open_loop(sched, mix, args, vocab, compiles, registry,
-                    tracing: Tracing):
+def drive_open_loop(sched, mix, args, vocab, compiles, registry):
     schedule = traffic_gen.open_loop_schedule(mix, args.seed, args.seconds,
                                               vocab)
     pre_s = float(mix["preroll_s"])
@@ -431,7 +407,7 @@ def drive_open_loop(sched, mix, args, vocab, compiles, registry,
     t_start = now()
     records, threads, marks = send_schedule(
         sched, schedule, t_start, pre_s, stop,
-        lambda: WindowMarks(registry, compiles, tracing))
+        lambda: WindowMarks(registry, compiles))
     t_end = t_start + pre_s + float(args.seconds)
     if t_end - now() > 0:
         time.sleep(t_end - now())
@@ -439,7 +415,6 @@ def drive_open_loop(sched, mix, args, vocab, compiles, registry,
     deadline = now() + float(mix["drain_s"])
     for th in threads:
         th.join(max(0.0, deadline - now()))
-    tracing.stop()
     drained_s = now() - t_end
     measured = [r for r in records if r.req.measured]
     failed = sum(_failed(r) for r in measured)
@@ -502,8 +477,8 @@ def traced_tail(sched, mix, args, vocab, tracing: Tracing,
     """--trace 2, once the window's requests have drained and its numbers
     are taken: fresh traffic of the same mix from the same generator. The
     profiler is started and stopped once for nothing, the pre-roll loads
-    the server, `trace_seconds` are captured (the same seconds of a run
-    that --trace 1 traces), and what still runs then is cancelled."""
+    the server, `trace_seconds` are captured, and what still runs then is
+    cancelled."""
     warm_s = common.warm_profiler(tracing.tracer)
     pre_s = float(mix["preroll_s"])
     stop = threading.Event()
@@ -533,13 +508,13 @@ def traced_tail(sched, mix, args, vocab, tracing: Tracing,
 
 
 def drive_closed_loop(sched, mix, args, vocab, compiles, registry,
-                      tracing: Tracing, num_slots):
+                      num_slots):
     stop = threading.Event()
     records, lock, threads = start_clients(sched, mix, args.seed, vocab,
                                            num_slots, stop)
     time.sleep(float(mix["preroll_s"]))
     t_open = now()
-    marks = WindowMarks(registry, compiles, tracing)
+    marks = WindowMarks(registry, compiles)
     time.sleep(float(args.seconds))
     t_close = now()
     built_in_window, reg = marks.close()
@@ -547,7 +522,6 @@ def drive_closed_loop(sched, mix, args, vocab, compiles, registry,
     deadline = now() + float(mix["drain_s"])
     for th in threads:
         th.join(max(0.0, deadline - now()))
-    tracing.stop()
     with lock:
         snapshot = list(records)
     # Requests sent inside the window are the attempted ones; one that the

@@ -16,6 +16,7 @@ hand before writing a selector against it.
 from __future__ import annotations
 
 import glob
+import heapq
 import os
 import re
 import sys
@@ -233,26 +234,57 @@ def top_device_ops(trace: Trace, n: int = 10,
     return [[k[:160], v] for k, v in ranked]
 
 
+def device_gaps(trace: Trace, window: Optional[Interval] = None,
+                plane: Optional[str] = None) -> List[Interval]:
+    """The idle intervals of one device plane (the first by name) inside
+    the window: disjoint, in time order."""
+    lo, hi = window or window_of(trace)
+    name = plane or sorted(trace.device_ops)[0]
+    busy = clip(union((e.start_ns, e.end_ns)
+                      for e in trace.device_ops[name]), lo, hi)
+    return subtract([(lo, hi)], busy)
+
+
+def name_gaps(gaps: Sequence[Interval], host_spans: Sequence[Event],
+              n: int = 10) -> List[List[Any]]:
+    """[[host span, seconds], ...]: the gaps' seconds, summed by the host
+    span that covers each gap's middle; "(no host span)" where none does.
+    Of the spans that cover a middle the one named is the first in the
+    order (python threads before the runtime's, shorter before longer,
+    then as `host_spans` lists them): the innermost.
+
+    `gaps` are disjoint and in time order, so their middles rise: one
+    sweep pushes each span onto a heap (keyed by its place in that order)
+    once the middle has reached its start, and pops it from the top once
+    the middle has passed its end. (gaps + spans) x log spans; the scan of
+    every span for every gap that this replaces took 61-98 s of a traced
+    serving run (PERF.md, PR 27)."""
+    in_order = sorted(host_spans,
+                      key=lambda e: (not e.stats.get("python"), e.dur_ns))
+    by_start = sorted((sp.start_ns, place, sp.end_ns, sp.name)
+                      for place, sp in enumerate(in_order))
+    started = 0
+    open_spans: List[Tuple[int, float, str]] = []  # (place, end_ns, name)
+    acc: Dict[str, float] = {}
+    for s, e in gaps:
+        mid = (s + e) / 2
+        while started < len(by_start) and by_start[started][0] <= mid:
+            heapq.heappush(open_spans, by_start[started][1:])
+            started += 1
+        while open_spans and open_spans[0][1] <= mid:
+            heapq.heappop(open_spans)
+        cover = open_spans[0][2] if open_spans else "(no host span)"
+        acc[cover] = acc.get(cover, 0.0) + (e - s) / 1e9
+    ranked = sorted(acc.items(), key=lambda kv: -kv[1])[:n]
+    return [[k[:160], v] for k, v in ranked]
+
+
 def idle_gaps(trace: Trace, n: int = 10, window: Optional[Interval] = None,
               plane: Optional[str] = None) -> List[List[Any]]:
     """[[host span, seconds], ...]: idle seconds on one device plane (the
     first by name), summed by the innermost host span covering each gap's
     middle; "(no host span)" where none does."""
-    lo, hi = window or window_of(trace)
-    name = plane or sorted(trace.device_ops)[0]
-    busy = clip(union((e.start_ns, e.end_ns)
-                      for e in trace.device_ops[name]), lo, hi)
-    gaps = subtract([(lo, hi)], busy)
-    spans = sorted(trace.host_spans,
-                   key=lambda e: (not e.stats.get("python"), e.dur_ns))
-    acc: Dict[str, float] = {}
-    for s, e in gaps:
-        mid = (s + e) / 2
-        cover = next((sp.name for sp in spans
-                      if sp.start_ns <= mid < sp.end_ns), "(no host span)")
-        acc[cover] = acc.get(cover, 0.0) + (e - s) / 1e9
-    ranked = sorted(acc.items(), key=lambda kv: -kv[1])[:n]
-    return [[k[:160], v] for k, v in ranked]
+    return name_gaps(device_gaps(trace, window, plane), trace.host_spans, n)
 
 
 # -- looking at a trace by hand ---------------------------------------------------

@@ -10,9 +10,8 @@ hook by an exception of the harness' own, so `Trainer.train()`'s final
 blocking checkpoint (about 10 B a parameter to disk) is never written: it
 would be paid by every run of every later check and measures nothing.
 
---trace 1 traces the window's first log window with a profiler the harness
-starts. --trace 2 closes the window as --trace 0 does, takes its numbers,
-and only then asks the trainer for a capture of one more log window
+--trace 2 closes the window as --trace 0 does, takes its numbers, and only
+then asks the trainer for a capture of one more log window
 (`Trainer.request_profile`), ending the run at the sync after it.
 """
 
@@ -42,10 +41,10 @@ class Window:
     """Host-side accounting from the trainer's step hook (called after
     each log sync)."""
 
-    def __init__(self, seconds: float, trace_mode: int,
+    def __init__(self, seconds: float, trace_after: bool,
                  compiles: common.CompileCounter, trainer):
         self.seconds = seconds
-        self.trace_mode = trace_mode
+        self.trace_after = trace_after  # --trace 2
         self.trace_dir: Optional[str] = None
         self.compiles = compiles
         self.trainer = trainer
@@ -73,8 +72,6 @@ class Window:
             1, self.trainer.config.health_check_interval // 10)
 
     def on_sync(self, step: int, metrics: Dict[str, Any]) -> None:
-        import jax
-
         now = time.time()
         if self.t_open is None:
             # First sync after the compile step: the warm-up ends here.
@@ -83,11 +80,6 @@ class Window:
             self.goodput_open = self._goodput()
             self.registry_open = layer_readers.registry_view(
                 self.trainer.registry)
-            if self.trace_mode == 1:
-                self.trace_dir = tempfile.mkdtemp(prefix="benchmark_trace_")
-                common.start_trace(self.trace_dir)
-                self.tracing = True
-                self.trace_until_step = step + self._log_window_steps()
             return
         if self.t_close is not None:
             # --trace 2's tail: the sync that ends the captured steps.
@@ -98,11 +90,7 @@ class Window:
                 raise _WindowClosed()
             return
         self.losses.append(float(metrics.get("loss", float("nan"))))
-        if self.tracing and step >= self.trace_until_step:
-            jax.profiler.stop_trace()
-            self.tracing = False
-            self.trace_steps = step - self.step_open
-        if now - self.t_open >= self.seconds and not self.tracing:
+        if now - self.t_open >= self.seconds:
             self.t_close, self.step_close = now, step
             self.goodput_close = self._goodput()
             self.registry_delta = layer_readers.delta(
@@ -110,7 +98,7 @@ class Window:
                 self.registry_open)
             self.lowered_in_window = (
                 self.compiles.lowered - self.lowered_at_open)
-            if self.trace_mode != 2:
+            if not self.trace_after:
                 raise _WindowClosed()
             # The window's numbers are taken. Start and stop the profiler
             # once for nothing (its first start is the slow one), then
@@ -124,14 +112,9 @@ class Window:
             self.tracing = True
 
     def discard(self) -> None:
-        import jax
-
         if self.tracing:
             self.tracing = False
-            if self.trace_mode == 2:
-                self.trainer.stop_profile()
-            else:
-                jax.profiler.stop_trace()
+            self.trainer.stop_profile()
         if self.trace_dir:
             shutil.rmtree(self.trace_dir, ignore_errors=True)
 
@@ -203,9 +186,8 @@ def run(cell, args, device: Dict[str, Any]) -> Dict[str, Any]:
                  "num_layers": cfg.num_layers,
                  "gradient_accumulation_steps": cfg.gradient_accumulation_steps}
         data = cli._synthetic_batches(cfg, seed=args.seed % (2**31))
-        # Off under --trace 0 and 2 (2 switches it on for its capture).
-        tracer = (SpanTracer(use_jax_profiler=True) if args.trace == 1
-                  else SpanTracer(enabled=False))
+        # Off; --trace 2 switches it on for its capture.
+        tracer = SpanTracer(enabled=False)
         t0 = time.time()
         trainer = Trainer(cfg, train_data=data, registry=MetricsRegistry(),
                           tracer=tracer)
@@ -218,7 +200,7 @@ def run(cell, args, device: Dict[str, Any]) -> Dict[str, Any]:
         say("correct", seconds=time.time() - t0, **verdict)
 
         tokens_per_step = batch * seq
-        window = Window(args.seconds, args.trace, compiles, trainer)
+        window = Window(args.seconds, bool(args.trace), compiles, trainer)
         orch = AdaptiveTrainingOrchestrator(trainer)
         inner = orch.on_metrics
 
@@ -261,7 +243,7 @@ def run(cell, args, device: Dict[str, Any]) -> Dict[str, Any]:
             memory_peak_bytes=peak_bytes,
             **({"profiler_first_start_s": window.profiler_first_start_s,
                 "tail_s": time.time() - window.t_close}
-               if args.trace == 2 else {}))
+               if args.trace else {}))
         ok = bool(verdict["ok"] and finite and ran == asked
                   and lowered_in_window == 0 and rebuilt == 0 and steps > 0)
         host = {
@@ -288,11 +270,9 @@ def run(cell, args, device: Dict[str, Any]) -> Dict[str, Any]:
         device_out.update(busy_s=busy, window_s=win_s)
         say("per_layer", traced_steps=window.trace_steps, notes=notes,
             values=values)
-        per_layer = common.metric_values(cell.per_layer, values)
-        # --trace 2 took the end-to-end numbers from the untraced window,
-        # so both kinds stand side by side; --trace 1 prints per-layer only.
-        out["metrics"] = ({**out["metrics"], **per_layer}
-                          if args.trace == 2 else per_layer)
+        # The end-to-end numbers were taken from the untraced window, so
+        # both kinds stand side by side.
+        out["metrics"].update(common.metric_values(cell.per_layer, values))
         out["breakdown"] = breakdown
         return out
     finally:
