@@ -1075,7 +1075,7 @@ class StepwiseDecoder:
         prefix_cache_pages: Optional[int] = None,
         prefix_cache_tenant_quota: Optional[int] = None,
     ):
-        from luminaai_tpu.inference.kv_pool import PagedKVPool, to_paged
+        from luminaai_tpu.inference.kv_pool import PagedKVPool
 
         self.engine = engine
         self.model = engine.model
@@ -1124,23 +1124,18 @@ class StepwiseDecoder:
             prefix_cache_pages > 0
         ) else 0
         self.total_slots = num_slots + arena_slots
-        caches = engine.model.init_cache(
-            self.total_slots,
-            pages * page_size,
-            kv_cache_dtype=getattr(engine.config, "kv_cache_dtype", None),
-            rolling=False,
-        )
+        self.num_slots = num_slots
+        self.slot_tokens = pages * page_size
         # Lane accounting covers ONLY the first num_slots rows; the arena
         # slots are never allocatable — their pages are addressed purely
         # through global page-table entries.
         self.pool = PagedKVPool(
-            to_paged(caches, pages, page_size),
+            None,
             num_slots=num_slots,
             pages=pages,
             page_size=page_size,
         )
-        self.num_slots = num_slots
-        self.slot_tokens = pages * page_size
+        self.pool.caches = self._init_pool_caches()
         # The decode budget honors the ENGINE's context contract: the
         # page rounding above may leave slack rows past max_context, and
         # decoding into them would silently run the model at
@@ -1149,13 +1144,7 @@ class StepwiseDecoder:
         # two paths serve identical tokens for over-length prompts too.
         self.token_capacity = min(self.slot_tokens, engine.max_context)
         # Host-side lane state; device state is the pool + counts + rngs.
-        self._tokens = np.zeros((num_slots,), np.int32)
-        self._pos = np.zeros((num_slots,), np.int32)
-        self._active = np.zeros((num_slots,), bool)
-        self._counts = jnp.zeros(
-            (num_slots, engine.config.vocab_size), jnp.int32
-        )
-        self._rngs = jax.random.split(jax.random.PRNGKey(0), num_slots)
+        self._reset_lane_state()
         self.steps = 0
         self._fns: Dict[Any, Any] = {}
         # Serving attention backend (config.attention_backend): 'dense'
@@ -1199,10 +1188,7 @@ class StepwiseDecoder:
         # arena pages. Authoritative only when the prefix cache is on —
         # without it the pool's per-slot LOCAL identity table keeps the
         # PR-8 contract (and its no-alias tests) unchanged.
-        self._gtable = (
-            np.arange(num_slots, dtype=np.int32)[:, None] * pages
-            + np.arange(pages, dtype=np.int32)[None, :]
-        )
+        self._gtable = self._identity_gtable()
         # Arena page ids each lane currently references (released with
         # the slot in release_slot -> refcounts drop, pages survive).
         self._leases: Dict[int, List[int]] = {}
@@ -1251,6 +1237,81 @@ class StepwiseDecoder:
         )
         self._refresh_table()
 
+    def _init_pool_caches(self):
+        """A zeroed cache tree at the pool's geometry, paged layout.
+        Built in ONE jitted call: eager, the flat tree and its paged
+        reshape would be two pools live at once."""
+        config = self.engine.config
+
+        def init():
+            return self._paged(
+                self.engine.model.init_cache(
+                    self.total_slots,
+                    self.slot_tokens,
+                    kv_cache_dtype=getattr(config, "kv_cache_dtype", None),
+                    rolling=False,
+                )
+            )
+
+        return jax.jit(init)()
+
+    def _reset_lane_state(self) -> None:
+        """Every lane idle at row 0; the device half (repetition counts,
+        per-lane rng) is rewritten by the decode step, which donates it
+        together with the pool."""
+        S = self.num_slots
+        self._tokens = np.zeros((S,), np.int32)
+        self._pos = np.zeros((S,), np.int32)
+        self._active = np.zeros((S,), bool)
+        self._counts = jnp.zeros(
+            (S, self.engine.config.vocab_size), jnp.int32
+        )
+        self._rngs = jax.random.split(jax.random.PRNGKey(0), S)
+
+    def _identity_gtable(self) -> np.ndarray:
+        P = self.pool.pages
+        return (
+            np.arange(self.num_slots, dtype=np.int32)[:, None] * P
+            + np.arange(P, dtype=np.int32)[None, :]
+        )
+
+    def recover_pool(self) -> bool:
+        """Call after ANY exception out of a program that rewrites the
+        pool (decode step, prefill chunk, slot insert, page copy): each
+        donates `pool.caches`, so a call the runtime had already taken
+        the buffers for leaves them deleted. Decided by what can be
+        observed, not by the kind of error:
+
+        - buffers alive (the call failed before the runtime took them:
+          a bad argument, a Python error): nothing is touched, returns
+          False, the same pool serves on and the caller fails only what
+          the call was for;
+        - buffers deleted: the pool is rebuilt zeroed at the same
+          geometry (the old one is already freed, so memory allows it),
+          every lane goes idle, the page tables return to identity and
+          the prefix cache forgets every page with its pins, leases,
+          pending claims and queued harvests (arena pages lived in the
+          lost buffers: none may be spliced again). Returns True: every
+          lane's KV is gone, so the caller must fail every request it
+          had admitted and release their slots; slot allocation stays
+          the caller's."""
+        if self.pool.buffers_alive():
+            return False
+        self.pool.caches = self._init_pool_caches()
+        self.pool.rebuilds += 1
+        self.pool.lengths[:] = 0
+        self._reset_lane_state()
+        self._gtable = self._identity_gtable()
+        self._leases.clear()
+        self._pending_claims.clear()
+        self._harvest_queue.clear()
+        self._queued_dst.clear()
+        self._landed_keys.clear()
+        if self.prefix_cache is not None:
+            self.prefix_cache.clear()
+        self._refresh_table()
+        return True
+
     def _refresh_table(self) -> None:
         """Device copy of the authoritative page table: the decoder's
         global table when the prefix cache is on (splices retarget it),
@@ -1271,12 +1332,14 @@ class StepwiseDecoder:
         return self.pool.has_free()
 
     def acquire_slot(self) -> int:
-        slot = self.pool.alloc()
         if self.prefix_cache is not None:
             # A queued harvest may source from a slot being recycled:
             # its pages must land in the arena before the new occupant
-            # writes over them.
+            # writes over them. (Before the alloc: a flush that loses
+            # the pool raises, and must not leak a slot.)
             self.flush_harvests()
+        slot = self.pool.alloc()
+        if self.prefix_cache is not None:
             # Fresh occupants start from identity; a prefix splice
             # retargets entries AFTER acquire, never across realloc.
             self._reset_gtable_row(slot)
@@ -1385,7 +1448,7 @@ class StepwiseDecoder:
 
                 return jax.tree.map(put, pool_caches, fresh)
 
-            self._fns["insert"] = jax.jit(insert)
+            self._fns["insert"] = jax.jit(insert, donate_argnums=(0,))
         return self._fns["insert"]
 
     def _active_extent(self) -> int:
@@ -1478,12 +1541,12 @@ class StepwiseDecoder:
                 )
                 return self._paged(flat), nxt, eos, counts, new_rngs
 
-            # No donation, deliberately: the scheduler catches a failed
-            # step (transient XlaRuntimeError), fails the active lanes,
-            # and keeps serving from the SAME pool — donating the cache
-            # operand would delete pool.caches on the failed call and
-            # turn one transient error into permanent dead buffers.
-            self._fns[key] = jax.jit(step)  # lumina: disable=LX006 -- pool must survive failed steps; see comment above
+            # Everything the step rewrites is donated (the pool, the
+            # repetition counts, the lane rngs): the one-row scatter
+            # lands in place instead of in a copy of the pool. A call
+            # that fails after the runtime took the buffers leaves them
+            # deleted; recover_pool() is what the caller does about it.
+            self._fns[key] = jax.jit(step, donate_argnums=(1, 5, 6))
         return self._fns[key]
 
     # -- scheduler-facing API ----------------------------------------------
@@ -1620,9 +1683,9 @@ class StepwiseDecoder:
 
                 return last, jax.tree.map(put, pool_caches, paged_lane)
 
-            # Same no-donation rationale as the decode step: the pool
-            # must survive a failed chunk call.
-            self._fns[key] = jax.jit(chunk_fn)  # lumina: disable=LX006 -- pool must survive failed chunk calls; see decode-step comment
+            # The pool is donated (as in the decode step): the lane
+            # lands back in place.
+            self._fns[key] = jax.jit(chunk_fn, donate_argnums=(1,))
         return self._fns[key]
 
     def start_prefill(
@@ -1993,7 +2056,9 @@ class StepwiseDecoder:
         Called by the scheduler once per tick, and defensively before
         any cache acquire / slot realloc (see _harvest). Returns pages
         flushed; on copy failure the queued inserts are forgotten so
-        the index never points at unwritten arena pages."""
+        the index never points at unwritten arena pages, and the error
+        is re-raised only if the failed call took the donated pool with
+        it (the lanes' KV is then gone too: recover_pool())."""
         if not self._harvest_queue:
             return 0
         pairs, self._harvest_queue = self._harvest_queue, []
@@ -2026,6 +2091,8 @@ class StepwiseDecoder:
             self.prefix_cache.release([d for _, d in pairs])
             self.prefix_cache.forget([d for _, d in pairs])
             self._queued_dst.difference_update(d for _, d in pairs)
+            if not self.pool.buffers_alive():
+                raise
             return 0
         self.prefix_cache.release([d for _, d in pairs])
         # Bytes are on device as of the (synchronous) copy above —
@@ -2080,9 +2147,7 @@ class StepwiseDecoder:
 
                 return jax.lax.fori_loop(0, K, body, caches)
 
-            # Same no-donation rationale as the decode step: the pool
-            # must survive a failed call.
-            self._fns[key] = jax.jit(copy)
+            self._fns[key] = jax.jit(copy, donate_argnums=(0,))
         return self._fns[key]
 
     def _get_chunk_prefill_cached(self):
@@ -2156,9 +2221,7 @@ class StepwiseDecoder:
 
                 return last, jax.tree.map(put, pool_caches, lane)
 
-            # Same no-donation rationale as the decode step: the pool
-            # must survive a failed chunk call.
-            self._fns[key] = jax.jit(chunk_fn)
+            self._fns[key] = jax.jit(chunk_fn, donate_argnums=(1,))
         return self._fns[key]
 
     def step_fn_and_args(
@@ -2169,7 +2232,11 @@ class StepwiseDecoder:
         monitoring/attribution.py can AOT-lower the decode executable for
         compiled-cost accounting without executing a step (bench
         extras.ragged_attention compares the dense and ragged backends'
-        compiled bytes through exactly this handle)."""
+        compiled bytes through exactly this handle). For LOWERING only:
+        the function donates the pool, the counts and the rngs, so a
+        caller that RUNS it must rebind all three from the result as
+        decode_step does, or the decoder is left holding deleted
+        buffers."""
         extent = (
             self._active_extent() if self.backend != "dense" else None
         )

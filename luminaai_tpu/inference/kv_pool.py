@@ -147,6 +147,9 @@ class PagedKVPool:
         self._free: List[int] = list(range(num_slots - 1, -1, -1))
         self._allocated: set = set()
         self.reuses = 0
+        # Times the owner replaced a cache tree that a failed donating
+        # call had taken (StepwiseDecoder.recover_pool).
+        self.rebuilds = 0
         self.slot_uses = np.zeros((num_slots,), np.int64)
         # Accounting lock: the scheduler worker mutates the free-list
         # while /healthz and /metrics HTTP threads read stats() —
@@ -226,18 +229,27 @@ class PagedKVPool:
         with self._lock:
             return self.lengths.astype(np.int32)
 
+    def buffers_alive(self) -> bool:
+        """False once a program that DONATES the cache tree (every one
+        that rewrites it does) failed after the runtime had taken the
+        buffers: the leaves are deleted and no new tree came back.
+        Asked after an exception, never on the hot path."""
+        import jax
+
+        return not any(
+            leaf.is_deleted() for leaf in jax.tree.leaves(self.caches)
+        )
+
     # -- cross-replica page serialization (ISSUE 20) ---------------------
-    def _locate(self, gid: int):
+    def _locate(self, gid: int, leaves):
         """Global page id -> physical (slot, page). Bounds-checked
-        against the PHYSICAL slot axis of the cache tree, not
+        against the PHYSICAL slot axis of the cache tree (`leaves`), not
         `num_slots`: the prefix-cache arena lives in extra slots past
         the lane pool (generate.py carves them out as
         total_slots > num_slots), and arena pages are exactly what the
         cross-replica tier exports and imports."""
-        import jax
-
         slot, page = divmod(int(gid), self.pages)
-        physical = jax.tree.leaves(self.caches)[0].shape[-5]
+        physical = leaves[0].shape[-5]
         if not (0 <= slot < physical):
             raise ValueError(
                 f"page id {gid} outside pool "
@@ -251,14 +263,22 @@ class PagedKVPool:
         dim] slice (plus any leading scan_layers axes), device_get'd
         here — the transfer tier runs OFF the decode hot path, never
         inside a jitted step. int8 pools carry codes AND scales because
-        both are tree leaves of the same paged layout."""
+        both are tree leaves of the same paged layout.
+
+        Runs on an HTTP thread while the scheduler thread rebinds
+        `self.caches` every call and DONATES the old tree: the tree is
+        read once, so a page comes from one tree or, when those leaves
+        are taken mid-export, the read raises (export_page_by_key turns
+        that into "not servable")."""
         import jax
 
-        if self.caches is None:
+        caches = self.caches
+        if caches is None:
             raise RuntimeError("accounting-only pool has no cache tree")
-        slot, page = self._locate(gid)
+        leaves = jax.tree.leaves(caches)
+        slot, page = self._locate(gid, leaves)
         metas, blobs = [], []
-        for leaf in jax.tree.leaves(self.caches):
+        for leaf in leaves:
             # slot axis at ndim-5, page axis at ndim-4 (the ellipsis
             # absorbs scan_layers' leading segment axis when present).
             arr = np.ascontiguousarray(
@@ -286,9 +306,9 @@ class PagedKVPool:
 
         if self.caches is None:
             raise RuntimeError("accounting-only pool has no cache tree")
-        slot, page = self._locate(gid)
         arrs = parse_page_payload(payload)
         leaves, treedef = jax.tree.flatten(self.caches)
+        slot, page = self._locate(gid, leaves)
         if len(arrs) != len(leaves):
             raise ValueError(
                 f"page payload has {len(arrs)} leaves, pool has "
@@ -357,6 +377,7 @@ class PagedKVPool:
                 "in_use": len(self._allocated),
                 "free": len(self._free),
                 "reuses": self.reuses,
+                "rebuilds": self.rebuilds,
                 "pages_in_use": self.pages_in_use(),
                 "pages_total": self.num_slots * self.pages,
                 "fragmentation_rows": self.fragmentation_rows(),

@@ -373,6 +373,22 @@ class RadixPrefixCache:
                 removed += 1
         return removed
 
+    def clear(self) -> int:
+        """Forget EVERY cached page and pending claim, pinned or not:
+        the arena they lived in is gone (StepwiseDecoder.recover_pool
+        rebuilt the pool), so no page may be spliced or exported again.
+        A release() of a pin taken before the clear finds nothing and
+        does nothing. Counters keep their history; returns the pages
+        dropped."""
+        with self._lock:
+            dropped = len(self._index)
+            self._free.extend(e.page_id for e in self._index.values())
+            self._index.clear()
+            self._by_page.clear()
+            self._tenant_pages.clear()
+            self._pending.clear()
+            return dropped
+
     # -- in-flight dedup ---------------------------------------------------
     def has_pending_prefix(self, keys: Sequence[str]) -> bool:
         """True when this prompt's FIRST non-resident page is being

@@ -560,6 +560,11 @@ class ContinuousScheduler:
             pool_gauge("kv_pool_slot_reuses_total",
                        "Times a previously-used slot was re-issued",
                        "reuses")
+            pool_gauge("serve_pool_rebuilds_total",
+                       "Times the KV pool was rebuilt after a failed call "
+                       "had taken its donated buffers (every in-flight "
+                       "request failed once; the server kept serving)",
+                       "rebuilds")
             pool_gauge("kv_pool_pages_in_use",
                        "Pages holding live KV rows", "pages_in_use")
             pool_gauge("kv_pool_pages_total", "Total pool pages",
@@ -900,6 +905,38 @@ class ContinuousScheduler:
         active.pop(req.slot, None)
         self._active_lanes = len(active)
 
+    def _fail_all(self, active: dict, err: BaseException) -> None:
+        """Fail every admitted request, decoding or mid-prefill, and
+        give their slots back."""
+        for r in list(active.values()):
+            self._release(r, active)
+            self._fail(r, err)
+        for slot, (r, *_) in list(self._prefilling.items()):
+            self._release_slot(slot)
+            self._fail(r, err)
+        self._prefilling.clear()
+
+    def _pool_lost(self, active: dict, err: BaseException) -> bool:
+        """After an exception out of a decoder call that rewrites the
+        KV pool. Those programs donate the pool, so a call that failed
+        once the runtime had taken the buffers leaves none: the decoder
+        then rebuilds it (StepwiseDecoder.recover_pool), every lane's KV
+        is gone, and every admitted request fails with `err`: the
+        server keeps serving from the new pool. With the buffers still
+        alive (the call failed before the runtime took them) this does
+        nothing and the caller fails only what the call was for."""
+        recover = getattr(self.decoder, "recover_pool", None)
+        if recover is None or not recover():
+            return False
+        in_flight = len(active) + len(self._prefilling)
+        logger.error(
+            "KV pool lost to a failed call and rebuilt; failing %d "
+            "in-flight request(s)", in_flight,
+        )
+        self._event("pool_rebuilt", error=str(err)[:200], failed=in_flight)
+        self._fail_all(active, err)
+        return True
+
     def _admit(self, req: _ContinuousRequest, active: dict) -> None:
         """Prefill-then-join: the request's prompt KV lands in a freed
         slot and its first token streams out immediately; the lane joins
@@ -922,7 +959,13 @@ class ContinuousScheduler:
         prefill work happens — not per tick: pausing on a merely-nonempty
         queue would exclude every interval on a saturated server and
         starve the warmup, leaving real decode hangs undetectable."""
-        slot = self.decoder.acquire_slot()
+        try:
+            slot = self.decoder.acquire_slot()
+        except Exception as e:  # its defensive harvest flush lost the pool
+            logger.exception("slot acquisition failed")
+            self._pool_lost(active, e)
+            self._fail(req, e)
+            return
         t_admit = time.perf_counter()
         queue_wait = max(0.0, time.time() - req.t0)
         with self.tracer.span(
@@ -930,11 +973,12 @@ class ContinuousScheduler:
             prompt_tokens=len(req.prompt),
             queue_wait_s=round(queue_wait, 4),
         ):
-            info = self._admit_into_slot(req, slot, t_admit, queue_wait)
+            info = self._admit_into_slot(req, slot, t_admit, queue_wait,
+                                         active)
         if info is not None:
             self._prefill_done(req, slot, info, t_admit, active)
 
-    def _admit_into_slot(self, req, slot, t_admit, queue_wait):
+    def _admit_into_slot(self, req, slot, t_admit, queue_wait, active):
         """The `sched.admit` span's body: admission bookkeeping, then
         `start_prefill` (chunked path: None, the chunks run from the
         worker loop) or the whole-prompt `prefill_into_slot` (its info).
@@ -971,6 +1015,7 @@ class ContinuousScheduler:
             except Exception as e:
                 logger.exception("start-prefill failed")
                 self._release_slot(slot)
+                self._pool_lost(active, e)
                 self._fail(req, e)
                 return None
             if st is not None:
@@ -995,6 +1040,7 @@ class ContinuousScheduler:
         except Exception as e:
             logger.exception("prefill-into-slot failed")
             self._release_slot(slot)
+            self._pool_lost(active, e)
             self._fail(req, e)
             return None
 
@@ -1088,17 +1134,22 @@ class ContinuousScheduler:
             else:
                 self._pending.append(nxt)
 
-    def _flush_harvests(self) -> None:
+    def _flush_harvests(self, active: dict) -> None:
         """One bulk device copy for every harvest queued this tick
         (StepwiseDecoder.flush_harvests; no-op without a prefix cache
         or an empty queue). With page sharing on, chain keys whose
         bytes just landed (this flush or a remote pull) are reported
-        to the router's fleet index off-thread."""
+        to the router's fleet index off-thread. A failed copy costs
+        only the harvest, unless it took the pool (_pool_lost)."""
         flush = getattr(self.decoder, "flush_harvests", None)
         pending = getattr(self.decoder, "harvests_pending", None)
         if flush is not None and (pending is None or pending()):
             with self.tracer.span("sched.harvest"):
-                flush()
+                try:
+                    flush()
+                except Exception as e:
+                    if not self._pool_lost(active, e):
+                        raise
         if self.page_share is not None:
             drain = getattr(self.decoder, "drain_landed_keys", None)
             if drain is not None:
@@ -1161,6 +1212,7 @@ class ContinuousScheduler:
             except Exception as e:
                 logger.exception("chunked prefill failed")
                 self._release_slot(slot)
+                self._pool_lost(active, e)
                 self._fail(req, e)
                 return
             if info is None and was_waiting and st.get("waiting"):
@@ -1335,7 +1387,7 @@ class ContinuousScheduler:
                 # Harvest batching (ROADMAP item 2): every prefix-cache
                 # harvest that landed this tick rides ONE jitted bulk page
                 # copy instead of one pool-copy dispatch per admission.
-                self._flush_harvests()
+                self._flush_harvests(active)
                 if not active:
                     if self._prefilling:
                         continue
@@ -1347,13 +1399,8 @@ class ContinuousScheduler:
                     step_dt = time.perf_counter() - t_step
                 except Exception as e:
                     logger.exception("decode step failed")
-                    for r in list(active.values()):
-                        self._fail(r, e)
-                        self._release(r, active)
-                    for slot, (r, *_) in list(self._prefilling.items()):
-                        self._fail(r, e)
-                        self._release_slot(slot)
-                    self._prefilling.clear()
+                    self._pool_lost(active, e)
+                    self._fail_all(active, e)  # alive or rebuilt alike
                     return
                 if self.watchdog is not None:
                     self.watchdog.beat()
@@ -1388,7 +1435,7 @@ class ContinuousScheduler:
             self._phases.publish()
         # A harvest landing on the generation's last tick must not wait
         # for the next admission's defensive flush.
-        self._flush_harvests()
+        self._flush_harvests(active)
 
 
 class _SlotStream:

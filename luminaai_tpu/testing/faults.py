@@ -228,6 +228,59 @@ def slow_tick(decoder, delay_s: float = 0.5, after: int = 3) -> Iterator[dict]:
 
 
 @contextlib.contextmanager
+def fail_pool_call(
+    decoder,
+    method: str = "decode_step",
+    at: int = 1,
+    lose_pool: bool = False,
+    exc_factory: Optional[Callable[[], BaseException]] = None,
+) -> Iterator[dict]:
+    """Serving fault injector: the `at`-th call (1-based, counted from
+    entry) of `decoder.<method>` raises instead of running; every other
+    call passes through. `method` is one of the decoder calls that
+    rewrite the KV pool: `decode_step`, `advance_prefill` (a chunk),
+    `prefill_into_slot` (the slot insert), `flush_harvests` (the page
+    copy). Two flavours, the two states a failed donating call can
+    leave behind:
+
+    - `lose_pool=False`: raised before the program is called, the
+      pool's buffers are alive (a bad argument, a Python error);
+    - `lose_pool=True`: the pool's leaves are deleted first, which is
+      what a call that fails after the runtime took the donated buffers
+      leaves (StepwiseDecoder.recover_pool rebuilds from there).
+
+    Default error: a JaxRuntimeError. Yields {'calls', 'raised'}."""
+    if exc_factory is None:
+        import jax
+
+        def exc_factory():
+            return jax.errors.JaxRuntimeError(
+                f"INTERNAL: injected fault in {method}"
+            )
+
+    stats = {"calls": 0, "raised": 0}
+    original = getattr(decoder, method)
+
+    def wrapper(*args, **kwargs):
+        stats["calls"] += 1
+        if stats["calls"] == at:
+            stats["raised"] += 1
+            if lose_pool:
+                import jax
+
+                for leaf in jax.tree.leaves(decoder.pool.caches):
+                    leaf.delete()
+            raise exc_factory()
+        return original(*args, **kwargs)
+
+    setattr(decoder, method, wrapper)
+    try:
+        yield stats
+    finally:
+        _restore(decoder, method, wrapper, original)
+
+
+@contextlib.contextmanager
 def flaky_storage(
     times: int = 3,
     ops: Optional[tuple] = None,
