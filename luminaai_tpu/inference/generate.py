@@ -18,6 +18,7 @@ XLA rather than translated:
 
 from __future__ import annotations
 
+import collections
 import functools
 import logging
 import time
@@ -1045,7 +1046,12 @@ class StepwiseDecoder:
       decode_step(sample_key) advances ALL active lanes one token in one
         jit call and reports per-lane (token, produced, eos) — the
         scheduler evicts finished slots and admits queued requests into
-        the freed lanes BETWEEN steps.
+        the freed lanes BETWEEN steps. It is dispatch_step() +
+        collect_step(): the scheduler calls the halves itself, the
+        dispatch of step N+1 BEFORE the collect of step N, so the
+        device always has its next program queued while the host reads
+        and works on the previous one (docs/serving.md "The scheduler
+        loop").
 
     Greedy step-wise decode is token-identical to generate() (same
     prefill bucketing, same sampling math, same rng split discipline —
@@ -1146,6 +1152,10 @@ class StepwiseDecoder:
         # Host-side lane state; device state is the pool + counts + rngs.
         self._reset_lane_state()
         self.steps = 0
+        # Lane-steps whose token the host dropped: the lane ended (a
+        # stop token, a cancel, an eviction) while the step was in
+        # flight (_drop_ahead).
+        self.lane_steps_dropped = 0
         self._fns: Dict[Any, Any] = {}
         # Serving attention backend (config.attention_backend): 'dense'
         # keeps the legacy full-extent per-lane mask; the ragged backends
@@ -1260,6 +1270,7 @@ class StepwiseDecoder:
         per-lane rng) is rewritten by the decode step, which donates it
         together with the pool."""
         S = self.num_slots
+        # As of the last COLLECTED step (the host has read its tokens).
         self._tokens = np.zeros((S,), np.int32)
         self._pos = np.zeros((S,), np.int32)
         self._active = np.zeros((S,), bool)
@@ -1267,6 +1278,18 @@ class StepwiseDecoder:
             (S, self.engine.config.vocab_size), jnp.int32
         )
         self._rngs = jax.random.split(jax.random.PRNGKey(0), S)
+        # Steps dispatched and not yet collected, oldest first, each
+        # with the lanes it stepped. The next step's token input is
+        # the newest step's device output (`_nxt_dev`), except for
+        # lanes whose token the host set since (`_host_tok`: a lane
+        # _finish_prefill just activated). `_budget` is the number of
+        # decode steps each lane's request still allows (max_new - 1 at
+        # activation): with a step in flight it is how the host knows,
+        # without reading that step, which lanes it ends.
+        self._inflight: collections.deque = collections.deque()
+        self._budget = np.zeros((S,), np.int32)
+        self._host_tok = np.ones((S,), bool)
+        self._nxt_dev = jnp.zeros((S,), jnp.int32)
 
     def _identity_gtable(self) -> np.ndarray:
         P = self.pool.pages
@@ -1348,6 +1371,7 @@ class StepwiseDecoder:
 
     def release_slot(self, slot: int) -> None:
         self._active[slot] = False
+        self._drop_ahead(slot)
         if self.prefix_cache is not None:
             # Refcounted release: the lane's spliced arena pages drop
             # their pin (they stay cached — shared pages survive lane
@@ -1451,17 +1475,20 @@ class StepwiseDecoder:
             self._fns["insert"] = jax.jit(insert, donate_argnums=(0,))
         return self._fns["insert"]
 
-    def _active_extent(self) -> int:
+    def _active_extent(self, pos=None, live=None) -> int:
         """Resident-extent bound in ROWS for the ragged decode step: a
         power-of-two page count covering every active lane's rows
         (>= 1 page, <= the slot's pages). The step executable is
         specialized per extent — O(log pages) executables, the same
         ladder discipline as prompt buckets — and within one extent the
-        kernel/length mask still skips per-lane."""
+        kernel/length mask still skips per-lane. `pos` / `live`: the
+        write rows and lanes of a step about to be dispatched (the
+        host's prediction, an upper bound); default, the collected
+        state."""
+        if pos is None:
+            pos, live = self._pos, self._active
         ps = self.pool.page_size
-        need = 1
-        if self._active.any():
-            need = int(self._pos[self._active].max()) + 1
+        need = int(pos[live].max()) + 1 if live.any() else 1
         pages_needed = -(-need // ps)
         p = 1
         while p < pages_needed:
@@ -1481,8 +1508,16 @@ class StepwiseDecoder:
             window = getattr(self.engine.config, "attention_window", None)
             page_size = self.pool.page_size
 
-            def step(params, caches, tokens, pos, active, counts, rngs,
-                     table):
+            def step(params, caches, prev_nxt, lanes, counts, rngs, table):
+                # `lanes` is everything the host sends a step, one
+                # [4, S] int32 transfer (_pack_lanes): the write row,
+                # the lanes to step, and the token of each lane the host
+                # set since the last step. Every other lane's token is
+                # the previous step's output, which never left the
+                # device.
+                pos = lanes[0]
+                active = lanes[1] != 0
+                tokens = jnp.where(lanes[2] != 0, lanes[3], prev_nxt)
                 flat = self._flat(caches)
                 split2 = jax.vmap(lambda r: jax.random.split(r, 2))(rngs)
                 new_rngs, step_rngs = split2[:, 0], split2[:, 1]
@@ -1546,7 +1581,9 @@ class StepwiseDecoder:
             # lands in place instead of in a copy of the pool. A call
             # that fails after the runtime took the buffers leaves them
             # deleted; recover_pool() is what the caller does about it.
-            self._fns[key] = jax.jit(step, donate_argnums=(1, 5, 6))
+            # (Not the previous tokens: the host may not have read them
+            # yet.)
+            self._fns[key] = jax.jit(step, donate_argnums=(1, 4, 5))
         return self._fns[key]
 
     # -- scheduler-facing API ----------------------------------------------
@@ -1619,8 +1656,10 @@ class StepwiseDecoder:
             is_stop = first in self.engine._stop_set
             self.pool.lengths[slot] = L
             self._tokens[slot] = first
+            self._host_tok[slot] = True
             self._pos[slot] = L
             self._active[slot] = (not is_stop) and max_new > 1
+            self._budget[slot] = max_new - 1
             self._counts = self._counts.at[slot].set(0)
             if not is_stop:
                 self._counts = self._counts.at[slot, first].add(1)
@@ -2224,34 +2263,147 @@ class StepwiseDecoder:
             self._fns[key] = jax.jit(chunk_fn, donate_argnums=(1,))
         return self._fns[key]
 
+    def _drop_ahead(self, slot: int) -> None:
+        """The lane ended (a stop token, a release) with steps in flight
+        that step it: the host drops their tokens. Each wrote one KV row
+        into the lane's OWN slot, past every row attended so far, and
+        bumped its own `counts` / `rngs` rows; the slot's next admission
+        resets all three, and its programs queue behind those steps."""
+        for step in self._inflight:
+            if step["stepped"][slot]:
+                step["stepped"][slot] = False
+                self.lane_steps_dropped += 1
+
+    def _steps_ahead(self) -> np.ndarray:
+        """Per lane, how many of the steps in flight step it."""
+        ahead = np.zeros((self.num_slots,), np.int32)
+        for step in self._inflight:
+            ahead += step["stepped"]
+        return ahead
+
+    def _pack_lanes(self) -> Tuple[np.ndarray, np.ndarray]:
+        """What the next step would be called with, right now: the one
+        [4, S] int32 array the host sends (write row, stepped, token
+        from the host, that token) and the lanes it steps.
+
+        With steps in flight the rows and the lanes are the host's
+        PREDICTION of the state behind them: a lane stepped by k of
+        them writes k rows further, and is left out once those k steps
+        use up its request's budget or its slot's rows (the scheduler
+        ends such a lane when it collects the step, `max_new` /
+        `lane_full`). What cannot be known before a step is read is a
+        stop token: that lane is stepped once more and _drop_ahead
+        drops the token. A lane is never stepped at a row past
+        `token_capacity`, in flight or not."""
+        ahead = self._steps_ahead()
+        pos = self._pos + ahead
+        live = self._active & (pos < self.token_capacity) & (
+            (ahead == 0) | (ahead < self._budget)
+        )
+        lanes = np.empty((4, self.num_slots), np.int32)
+        lanes[0] = pos
+        lanes[1] = live
+        lanes[2] = self._host_tok
+        lanes[3] = self._tokens
+        return lanes, live
+
+    def _next_step(self, sample_key: Optional[Tuple]):
+        """(step function, packed lanes, lanes stepped) of the step
+        that would be dispatched right now."""
+        lanes, live = self._pack_lanes()
+        extent = (
+            self._active_extent(lanes[0], live)
+            if self.backend != "dense" else None
+        )
+        fn = self._get_step(sample_key or GREEDY_SAMPLE_KEY, extent)
+        return fn, lanes, live
+
     def step_fn_and_args(
         self, sample_key: Optional[Tuple] = None
     ) -> Tuple[Any, Tuple]:
         """The jitted decode-step function and the argument tuple
-        decode_step would call it with right now. Exposed so
+        dispatch_step would call it with right now. Exposed so
         monitoring/attribution.py can AOT-lower the decode executable for
         compiled-cost accounting without executing a step (bench
         extras.ragged_attention compares the dense and ragged backends'
         compiled bytes through exactly this handle). For LOWERING only:
         the function donates the pool, the counts and the rngs, so a
         caller that RUNS it must rebind all three from the result as
-        decode_step does, or the decoder is left holding deleted
+        dispatch_step does, or the decoder is left holding deleted
         buffers."""
-        extent = (
-            self._active_extent() if self.backend != "dense" else None
-        )
-        fn = self._get_step(sample_key or GREEDY_SAMPLE_KEY, extent)
+        fn, lanes, _ = self._next_step(sample_key)
         args = (
             self.params,
             self.pool.caches,
-            jnp.asarray(self._tokens),
-            jnp.asarray(self._pos),
-            jnp.asarray(self._active),
+            self._nxt_dev,
+            jax.device_put(lanes),
             self._counts,
             self._rngs,
             self._table,
         )
         return fn, args
+
+    @property
+    def steps_in_flight(self) -> int:
+        return len(self._inflight)
+
+    def dispatch_step(self, sample_key: Optional[Tuple] = None) -> bool:
+        """Enqueue one decode step on the device and return at once;
+        collect_step() reads it. Called with the previous step still in
+        flight (the scheduler's steady state) it steps the lanes that
+        step cannot end (_pack_lanes), and returns False, enqueueing
+        nothing, when there is none."""
+        fn, lanes, live = self._next_step(sample_key)
+        if self._inflight and not live.any():
+            return False
+        span, region = self.tracer.span, self.phases.region
+        with region("put"), span("decode.put"):
+            lanes_d = jax.device_put(lanes)
+        with region("dispatch"), span("decode.dispatch"):
+            caches, nxt, eos, counts, rngs = fn(
+                self.params, self.pool.caches, self._nxt_dev, lanes_d,
+                self._counts, self._rngs, self._table,
+            )
+        self.pool.caches = caches
+        self._counts = counts
+        self._rngs = rngs
+        self._nxt_dev = nxt
+        # The copies to the host start behind the step, not at the read.
+        nxt.copy_to_host_async()
+        eos.copy_to_host_async()
+        self._host_tok[:] = False
+        self._inflight.append({"nxt": nxt, "eos": eos, "stepped": live})
+        return True
+
+    def collect_step(self) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """Read the oldest step in flight (blocks until the device has
+        run it) and do its host bookkeeping. Returns decode_step's
+        (tokens[S], produced[S], eos[S]), for the lanes that step
+        stepped and that have not been released since."""
+        step = self._inflight.popleft()
+        with self.phases.region("device_wait"), \
+                self.tracer.span("decode.fetch"):
+            nxt_h = np.asarray(step["nxt"])
+            eos_h = np.asarray(step["eos"])
+        stepped = step["stepped"]
+        eos_h = eos_h & stepped
+        self._tokens[stepped] = nxt_h[stepped]
+        self._pos[stepped] += 1
+        self.pool.lengths[stepped] += 1
+        self._budget[stepped] -= 1
+        self._active &= ~eos_h
+        for slot in np.flatnonzero(eos_h):
+            self._drop_ahead(int(slot))
+        self.steps += 1
+        return nxt_h, stepped & ~eos_h, eos_h
+
+    def abandon_steps(self) -> None:
+        """Forget every step in flight: nothing of them is read. For
+        the caller whose dispatch or collect raised; it must release
+        every lane they stepped (the scheduler fails them all)."""
+        for step in self._inflight:
+            self.lane_steps_dropped += int(step["stepped"].sum())
+        self._inflight.clear()
 
     def decode_step(
         self, sample_key: Optional[Tuple] = None
@@ -2260,26 +2412,10 @@ class StepwiseDecoder:
         (tokens[S], produced[S], eos[S]): `produced` lanes emitted
         tokens[slot] this step; `eos` lanes hit a stop token (dropped,
         matching generate()) and were deactivated — the scheduler frees
-        their slots."""
-        was_active = self._active.copy()
-        span, region = self.tracer.span, self.phases.region
-        with region("put"), span("decode.put"):
-            fn, fn_args = self.step_fn_and_args(sample_key)
-        with region("dispatch"), span("decode.dispatch"):
-            caches, nxt, eos, counts, rngs = fn(*fn_args)
-        self.pool.caches = caches
-        self._counts = counts
-        self._rngs = rngs
-        with region("device_wait"), span("decode.fetch"):
-            nxt_h = np.asarray(nxt)
-            eos_h = np.asarray(eos)
-        self._tokens = nxt_h.copy()
-        self._pos[was_active] += 1
-        self.pool.lengths[was_active] += 1
-        self._active &= ~eos_h
-        self.steps += 1
-        produced = was_active & ~eos_h
-        return nxt_h, produced, eos_h
+        their slots. dispatch_step() + collect_step() with nothing else
+        in flight: the serial form, for callers that own their loop."""
+        self.dispatch_step(sample_key)
+        return self.collect_step()
 
 
 def _per_layer_view(params: Dict[str, Any]) -> Tuple[Dict[str, Any], bool]:

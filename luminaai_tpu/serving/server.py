@@ -267,6 +267,35 @@ class _ContinuousRequest:
         self.t0 = time.time()
 
 
+class _StepAtCollect:
+    """The split step API (dispatch_step / collect_step /
+    steps_in_flight / abandon_steps, StepwiseDecoder's) over a
+    duck-typed decoder that has `decode_step()` alone: the dispatch only
+    notes the key, and refuses while a step is noted, so nothing runs
+    ahead and the scheduler's one loop steps such a decoder in the
+    order admit, step, emit."""
+
+    def __init__(self, decoder):
+        self.decoder = decoder
+        self._keys: List[Any] = []
+
+    @property
+    def steps_in_flight(self) -> int:
+        return len(self._keys)
+
+    def dispatch_step(self, sample_key=None) -> bool:
+        if self._keys:
+            return False
+        self._keys.append(sample_key)
+        return True
+
+    def collect_step(self):
+        return self.decoder.decode_step(self._keys.pop())
+
+    def abandon_steps(self) -> None:
+        self._keys.clear()
+
+
 class ContinuousScheduler:
     """Continuous (in-flight) batching over a slot-paged KV pool.
 
@@ -280,6 +309,13 @@ class ContinuousScheduler:
     because max_new is host state, not a compile key — mixed-length
     workloads share one decode executable instead of splitting into
     per-length micro-batches.
+
+    The loop looks ONE step ahead: it dispatches step N+1 before it
+    reads step N's tokens, so everything the host does about step N
+    (the fetch, the per-lane emit, admission, a prefill chunk's
+    transfers) happens while the device runs N+1, and the chunk queues
+    behind it (_run_generation_inner; docs/serving.md "The scheduler
+    loop").
 
     Sampling parameters DO remain a compile key (the sampling math traces
     them), so one "generation" admits only requests with an identical
@@ -352,6 +388,12 @@ class ContinuousScheduler:
                 kw["prefix_cache_tenant_quota"] = prefix_cache_tenant_quota
             decoder = engine.make_stepwise(**kw)
         self.decoder = decoder
+        # What the loop steps through: the decoder's own dispatch /
+        # collect halves, or decode_step() behind the same four names.
+        self._steps = (
+            decoder if hasattr(decoder, "dispatch_step")
+            else _StepAtCollect(decoder)
+        )
         # Cross-replica page plane (serving/page_share.py): inject the
         # client into the decoder so cold admissions consult the fleet
         # index; only meaningful when the decoder actually has a prefix
@@ -502,6 +544,21 @@ class ContinuousScheduler:
         self._m_decode_steps = r.counter(
             "serve_decode_steps_total", "Scheduler decode steps executed"
         )
+        # The lookahead, as it engages: under load nearly every step is
+        # dispatched ahead, and only a lane that ended where the host
+        # could not foresee it (a stop token, a cancel, an eviction)
+        # has a step dropped.
+        self._m_steps_ahead = r.counter(
+            "serve_steps_dispatched_ahead_total",
+            "Decode steps enqueued on the device while the previous "
+            "step's tokens were not yet read",
+        )
+        self._m_lane_steps_dropped = r.counter(
+            "serve_lane_steps_dropped_total",
+            "Lane-steps whose token was dropped: the lane ended while "
+            "the step was in flight",
+        )
+        self._dropped_seen = 0
         # Which attention path the decode step compiled (value is always
         # 1; the label is the payload): a scrape shows what served, not
         # what a config asked for.
@@ -928,6 +985,7 @@ class ContinuousScheduler:
         recover = getattr(self.decoder, "recover_pool", None)
         if recover is None or not recover():
             return False
+        self._steps.abandon_steps()  # nothing of the old pool is read
         in_flight = len(active) + len(self._prefilling)
         logger.error(
             "KV pool lost to a failed call and rebuilt; failing %d "
@@ -1284,6 +1342,8 @@ class ContinuousScheduler:
         try:
             self._run_generation_inner(first)
         finally:
+            # Only an error the loop did not expect leaves a step behind.
+            self._steps.abandon_steps()
             if self.watchdog is not None:
                 self.watchdog.disarm()
 
@@ -1373,10 +1433,18 @@ class ContinuousScheduler:
                 self._pending.append(nxt)
         # Decode-tick accumulator: one SUMMARY event per tick_every steps
         # (per-step events would be all the ring buffer ever holds).
-        tick_steps = tick_tokens = 0
-        tick_t0 = time.perf_counter()
-        while active or self._prefilling:
+        self._tick_steps = self._tick_tokens = 0
+        self._tick_t0 = self._t_collect = time.perf_counter()
+        steps = self._steps
+        while active or self._prefilling or steps.steps_in_flight:
             with self._tick_span(active):
+                # One step ahead: step N+1 goes onto the device's queue
+                # BEFORE the host reads step N, so the read, the emit
+                # and everything below run under N+1, and their
+                # programs queue behind it. What N+1 steps is the
+                # decoder's prediction of the lanes N leaves alive.
+                if steps.steps_in_flight and not self._step(key, active):
+                    return
                 self._admit_queued(key, active)
                 # One prefill chunk per tick: a long admission progresses
                 # without ever costing the decode batch more than one
@@ -1388,54 +1456,89 @@ class ContinuousScheduler:
                 # harvest that landed this tick rides ONE jitted bulk page
                 # copy instead of one pool-copy dispatch per admission.
                 self._flush_harvests(active)
-                if not active:
-                    if self._prefilling:
-                        continue
-                    break
-                try:
-                    t_step = time.perf_counter()
-                    with self.tracer.span("decode_step"):
-                        toks, produced, eos = self.decoder.decode_step(key)
-                    step_dt = time.perf_counter() - t_step
-                except Exception as e:
-                    logger.exception("decode step failed")
-                    self._pool_lost(active, e)
-                    self._fail_all(active, e)  # alive or rebuilt alike
+                # Nothing queued (a generation's first step, or every
+                # lane of the last one ended or joined since): start.
+                if active and not steps.steps_in_flight and (
+                    not self._step(key, active, collect=False)
+                ):
                     return
-                if self.watchdog is not None:
-                    self.watchdog.beat()
-                self.last_tick_ts = time.time()
-                n_produced = sum(1 for slot in active if produced[slot])
-                if self.telemetry:
-                    self._m_step.observe(step_dt)
-                    self._sentinel.observe(
-                        step_dt, step=int(getattr(self.decoder, "steps", 0))
-                    )
-                    self._m_decode_steps.inc()
-                    # Per-token decode latency: the step IS the inter-token
-                    # gap for every lane that emitted this step.
-                    self._m_token.observe(step_dt, count=max(0, n_produced))
-                tick_steps += 1
-                tick_tokens += max(0, n_produced)
-                if tick_steps >= self.tick_every:
-                    dt_tick = time.perf_counter() - tick_t0
-                    self._event(
-                        "decode_tick",
-                        step=int(getattr(self.decoder, "steps", 0)),
-                        steps=tick_steps, tokens=tick_tokens,
-                        active_lanes=len(active),
-                        queue_depth=self.queue_depth(),
-                        tokens_per_sec=round(
-                            tick_tokens / max(dt_tick, 1e-9), 1
-                        ),
-                    )
-                    tick_steps = tick_tokens = 0
-                    tick_t0 = time.perf_counter()
-                self._emit_lanes(active, toks, produced, eos)
             self._phases.publish()
         # A harvest landing on the generation's last tick must not wait
         # for the next admission's defensive flush.
         self._flush_harvests(active)
+
+    def _step(self, key, active: dict, collect: bool = True) -> bool:
+        """`decode_step`: dispatch the next step, then (`collect`) read
+        the previous one and do everything that follows a read: the
+        step-time metrics, the watchdog's beat, the per-lane emit.
+        False when either half raised: both steps in flight are then
+        abandoned and every lane failed (the pool rebuilt if the call
+        had taken it), and the generation is over."""
+        steps = self._steps
+        try:
+            with self.tracer.span("decode_step"):
+                ahead = steps.steps_in_flight > 0
+                if steps.dispatch_step(key):
+                    if not ahead:
+                        self._t_collect = time.perf_counter()
+                    elif self.telemetry:
+                        self._m_steps_ahead.inc()
+                if not collect:
+                    return True
+                toks, produced, eos = steps.collect_step()
+        except Exception as e:
+            logger.exception("decode step failed")
+            steps.abandon_steps()
+            self._pool_lost(active, e)
+            self._fail_all(active, e)  # alive or rebuilt alike
+            self._count_dropped()
+            return False
+        # Collect to collect: with a step always queued behind the one
+        # being read, that IS the gap between a lane's tokens (from its
+        # own dispatch for a step nothing was queued ahead of).
+        now = time.perf_counter()
+        step_dt, self._t_collect = now - self._t_collect, now
+        if self.watchdog is not None:
+            self.watchdog.beat()
+        self.last_tick_ts = time.time()
+        n_produced = sum(1 for slot in active if produced[slot])
+        if self.telemetry:
+            self._m_step.observe(step_dt)
+            self._sentinel.observe(
+                step_dt, step=int(getattr(self.decoder, "steps", 0))
+            )
+            self._m_decode_steps.inc()
+            # Per-token decode latency: the step IS the inter-token
+            # gap for every lane that emitted this step.
+            self._m_token.observe(step_dt, count=max(0, n_produced))
+        self._tick_steps += 1
+        self._tick_tokens += max(0, n_produced)
+        if self._tick_steps >= self.tick_every:
+            dt_tick = time.perf_counter() - self._tick_t0
+            self._event(
+                "decode_tick",
+                step=int(getattr(self.decoder, "steps", 0)),
+                steps=self._tick_steps, tokens=self._tick_tokens,
+                active_lanes=len(active),
+                queue_depth=self.queue_depth(),
+                tokens_per_sec=round(
+                    self._tick_tokens / max(dt_tick, 1e-9), 1
+                ),
+            )
+            self._tick_steps = self._tick_tokens = 0
+            self._tick_t0 = time.perf_counter()
+        self._emit_lanes(active, toks, produced, eos)
+        self._count_dropped()
+        return True
+
+    def _count_dropped(self) -> None:
+        """The decoder counts the lane-steps it drops (where a lane is
+        released or meets a stop token); the registry follows."""
+        n = getattr(self.decoder, "lane_steps_dropped", 0)
+        if n != self._dropped_seen:
+            if self.telemetry:
+                self._m_lane_steps_dropped.inc(n - self._dropped_seen)
+            self._dropped_seen = n
 
 
 class _SlotStream:

@@ -27,6 +27,18 @@ from typing import Callable, Iterator, Optional
 logger = logging.getLogger(__name__)
 
 
+def _step_method(decoder) -> str:
+    """Where a decode step can be stalled or failed whoever drives it:
+    `collect_step` on a decoder with the split step API (its
+    decode_step() and the scheduler both go through it; the fault then
+    acts where the host reads a step, with the next one already
+    dispatched), else `decode_step`."""
+    return (
+        "collect_step" if hasattr(decoder, "collect_step")
+        else "decode_step"
+    )
+
+
 def _restore(obj, name, wrapper, original) -> None:
     """Put `original` back only if our wrapper is still installed — a
     recovery path that legitimately rebuilt the attribute (the thing
@@ -212,7 +224,8 @@ def slow_tick(decoder, delay_s: float = 0.5, after: int = 3) -> Iterator[dict]:
     slow_decode, which slows every step uniformly for deadline-eviction
     tests). Yields {'steps'}."""
     stats = {"steps": 0}
-    original = decoder.decode_step
+    method = _step_method(decoder)
+    original = getattr(decoder, method)
 
     def wrapper(*args, **kwargs):
         stats["steps"] += 1
@@ -220,11 +233,11 @@ def slow_tick(decoder, delay_s: float = 0.5, after: int = 3) -> Iterator[dict]:
             time.sleep(delay_s)
         return original(*args, **kwargs)
 
-    decoder.decode_step = wrapper
+    setattr(decoder, method, wrapper)
     try:
         yield stats
     finally:
-        _restore(decoder, "decode_step", wrapper, original)
+        _restore(decoder, method, wrapper, original)
 
 
 @contextlib.contextmanager
@@ -238,10 +251,11 @@ def fail_pool_call(
     """Serving fault injector: the `at`-th call (1-based, counted from
     entry) of `decoder.<method>` raises instead of running; every other
     call passes through. `method` is one of the decoder calls that
-    rewrite the KV pool: `decode_step`, `advance_prefill` (a chunk),
-    `prefill_into_slot` (the slot insert), `flush_harvests` (the page
-    copy). Two flavours, the two states a failed donating call can
-    leave behind:
+    rewrite the KV pool: `decode_step` (failed where the step is read,
+    `collect_step`, on a decoder that splits it; `dispatch_step` names
+    the other half), `advance_prefill` (a chunk), `prefill_into_slot`
+    (the slot insert), `flush_harvests` (the page copy). Two flavours,
+    the two states a failed donating call can leave behind:
 
     - `lose_pool=False`: raised before the program is called, the
       pool's buffers are alive (a bad argument, a Python error);
@@ -259,6 +273,8 @@ def fail_pool_call(
             )
 
     stats = {"calls": 0, "raised": 0}
+    if method == "decode_step":
+        method = _step_method(decoder)
     original = getattr(decoder, method)
 
     def wrapper(*args, **kwargs):
@@ -529,15 +545,16 @@ def slow_decode(decoder, delay_s: float = 0.2) -> Iterator[dict]:
     serving request with a deadline goes overdue mid-decode and the
     scheduler's eviction path fires. Yields {'steps': n}."""
     stats = {"steps": 0}
-    original = decoder.decode_step
+    method = _step_method(decoder)
+    original = getattr(decoder, method)
 
     def wrapper(*args, **kwargs):
         stats["steps"] += 1
         time.sleep(delay_s)
         return original(*args, **kwargs)
 
-    decoder.decode_step = wrapper
+    setattr(decoder, method, wrapper)
     try:
         yield stats
     finally:
-        _restore(decoder, "decode_step", wrapper, original)
+        _restore(decoder, method, wrapper, original)

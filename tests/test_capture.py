@@ -371,6 +371,12 @@ def test_a_capture_holds_the_scheduler_threads_span_tree(tiny_engine,
     prompt = list(range(5, 5 + 40))  # 3 chunks of 16
     warm, _ = sched.submit(prompt, {"max_new_tokens": 4, "temperature": 0.0})
     assert tracer.spans_recorded == 0  # off: nothing was recorded
+    # The tick that emitted the last token still admits and publishes
+    # after it: a capture switched on under it would have no tick span.
+    for _ in range(1000):
+        if sched.idle():
+            break
+        threading.Event().wait(0.005)
     assert tracer.start_capture(str(tmp_path / "trace"))
     try:
         toks, _ = sched.submit(

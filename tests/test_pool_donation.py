@@ -332,6 +332,11 @@ def _submit_all(sched, jobs):
         # active and prefilling request fails once, one rebuild.
         ("decode_step", 1, True, set(), 1),
         ("advance_prefill", 2, True, set(), 1),
+        # The scheduler runs one step ahead (ISSUE 32): "decode_step"
+        # above fails where step 1 is READ, with step 2 already on the
+        # device; these fail the dispatch of step 2 with step 1 unread.
+        ("dispatch_step", 2, False, set(), 0),
+        ("dispatch_step", 2, True, set(), 1),
     ],
 )
 def test_scheduler_survives_a_failed_pool_call(
