@@ -286,7 +286,6 @@ def phase_serve(ckpt_dir: str, expect_backend: str = "ragged_xla",
     threading.Thread(target=httpd.serve_forever, daemon=True).start()
     url = f"http://127.0.0.1:{httpd.server_address[1]}"
     try:
-        check(srv.continuous, "server did not build a ContinuousScheduler")
         greedy = {"max_new_tokens": max_new_tokens, "temperature": 0.0}
         warm = _post(url, {"prompt": "warm up", **greedy}, timeout)
         ready_s = time.time() - t0

@@ -1,5 +1,5 @@
-"""Command-line entry point: train / resume / chat / benchmark / data /
-diagnose / presets.
+"""Command-line entry point: train / resume / chat / data / diagnose /
+presets.
 
 Covers the reference CLI surface (ref: Src/Main_Scripts/Main.py:1506 main()
 with config selection + adaptive-vs-standard training, :619 system
@@ -9,7 +9,6 @@ Chat.py's interactive entry) as a proper argparse program:
     python -m luminaai_tpu train --preset debug --synthetic --steps 30
     python -m luminaai_tpu resume --output-dir runs/exp1
     python -m luminaai_tpu chat --checkpoint runs/exp1/checkpoints
-    python -m luminaai_tpu benchmark
     python -m luminaai_tpu data sample --out data/sample.jsonl
     python -m luminaai_tpu diagnose
 """
@@ -438,19 +437,6 @@ def cmd_chat(args) -> int:
     return 0
 
 
-def cmd_benchmark(args) -> int:
-    """Run the repo bench harness (one JSON line, same as the driver)."""
-    import subprocess
-
-    bench = Path(__file__).resolve().parent.parent / "bench.py"
-    if args.ops:
-        bench = Path(__file__).resolve().parent.parent / "bench_ops.py"
-    if not bench.exists():
-        print(f"benchmark harness not found: {bench}", file=sys.stderr)
-        return 2
-    return subprocess.call([sys.executable, str(bench)])
-
-
 def cmd_data(args) -> int:
     from luminaai_tpu.data.processing import (
         create_sample_data,
@@ -801,9 +787,6 @@ def cmd_serve(args) -> int:
         num_slots=getattr(args, "num_slots", 8),
         page_size=getattr(args, "page_size", 128),
         admission_window_ms=getattr(args, "admission_window_ms", 0.0),
-        continuous=(
-            False if getattr(args, "no_continuous", False) else "auto"
-        ),
         telemetry=not getattr(args, "no_telemetry", False),
         trace_jsonl=getattr(args, "trace_jsonl", None),
         trace_jax=getattr(args, "trace_jax", False),
@@ -1962,9 +1945,6 @@ def build_parser() -> argparse.ArgumentParser:
                     type=float, default=0.0,
                     help="wait this long for same-key peers before a "
                          "generation's first decode step")
-    sv.add_argument("--no-continuous", dest="no_continuous",
-                    action="store_true",
-                    help="legacy run-to-completion micro-batching")
     sv.add_argument("--no-telemetry", dest="no_telemetry",
                     action="store_true",
                     help="skip hot-path metric recording (/metrics stays "
@@ -2135,11 +2115,6 @@ def build_parser() -> argparse.ArgumentParser:
                          "here on exit (flightrec-*.jsonl)")
     rt.set_defaults(fn=cmd_route)
 
-    b = sub.add_parser("benchmark", help="run the bench harness")
-    b.add_argument("--ops", action="store_true",
-                   help="op-level microbenchmarks instead of train throughput")
-    b.set_defaults(fn=cmd_benchmark)
-
     d = sub.add_parser("data", help="dataset utilities")
     d.add_argument(
         "action",
@@ -2290,8 +2265,8 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 _COMPILING_COMMANDS = (
-    cmd_train, cmd_chat, cmd_finetune, cmd_serve, cmd_benchmark,
-    cmd_evaluate, cmd_diagnose,
+    cmd_train, cmd_chat, cmd_finetune, cmd_serve, cmd_evaluate,
+    cmd_diagnose,
 )
 
 

@@ -132,7 +132,7 @@ class Config:
     # GATHER through an inverted slot→token index table (the H-wide scatter
     # moves to the backward pass — TPUs execute row gathers much better);
     # 'einsum' = GShard one-hot dispatch (O(S·E·C) memory, MXU-only data
-    # movement — useful for A/B in bench_ops);
+    # movement — an A/B baseline);
     # 'a2a' = cross-host expert parallelism: tokens shard over
     # (data, fsdp, expert) and are ROUTED to their experts' shards via
     # the hierarchical (ici-then-dcn) all-to-all subsystem
@@ -1274,9 +1274,9 @@ class ConfigPresets:
     def flagship(
         n_chips: int = 1, tuned: bool = True, small: bool = False
     ) -> Config:
-        """The 757M-total / 238M-active MoE that bench.py and
-        chip_smoke.py run: sized to load the MXU on one v5e chip (state
-        ~9GB of 16GB HBM). Batch scales with the chip count so per-chip
+        """The 757M-total / 238M-active MoE that chip_smoke.py runs:
+        sized to load the MXU on one v5e chip (state ~9GB of 16GB
+        HBM). Batch scales with the chip count so per-chip
         load is constant. `tuned` is the flagship_tuned lever set:
         dropless megablox gmm dispatch, bf16 RoPE, save_attn remat and
         bf16 Adam mu. Not a --preset: its sizes name one chip, not a

@@ -425,30 +425,6 @@ class GenerationEngine:
             )
         return self._decode_fn[key]
 
-    def _get_batch_decode(self, lanes: int, gen_key):
-        """vmap of the single-sequence decode over `lanes` rows. JAX's
-        while_loop batching runs until every lane's cond is false and
-        freezes finished lanes via select — exactly batched decode. Each
-        lane keeps its own cache/start, so ragged prompt lengths need no
-        left-padding or mask surgery."""
-        key = ("batch", lanes, gen_key)
-        if key not in self._decode_fn:
-            self._decode_fn[key] = jax.jit(
-                jax.vmap(
-                    self._make_decode(gen_key),
-                    in_axes=(None, 0, 0, 0, 0, 0, 0),
-                )
-            )
-        return self._decode_fn[key]
-
-    def _get_batch_prefill(self, lanes: int, bucket: int):
-        key = ("batch", lanes, bucket)
-        if key not in self._decode_fn:
-            self._decode_fn[key] = jax.jit(
-                jax.vmap(self._make_prefill_fn(bucket), in_axes=(None, 0, 0))
-            )
-        return self._decode_fn[key]
-
     # -- shared request plumbing -------------------------------------------
     @property
     def _stop_set(self):
@@ -869,121 +845,6 @@ class GenerationEngine:
             "stopped": stopped,
         }
 
-    def generate_batch(
-        self,
-        prompts: Sequence[Sequence[int]],
-        max_new_tokens: Optional[int] = None,
-        temperature: Optional[float] = None,
-        top_p: Optional[float] = None,
-        top_k: Optional[int] = None,
-        repetition_penalty: Optional[float] = None,
-        seed: Optional[int] = None,
-    ) -> List[Tuple[List[int], Dict[str, Any]]]:
-        """Decode B prompts concurrently on one chip (ragged lengths OK).
-
-        Each row keeps its own KV cache and absolute positions via vmap
-        lanes; the batched while_loop freezes rows at their stop token and
-        runs until all rows finish. Throughput: one model step now serves
-        B tokens, so the MXU sees [B, ...] matmuls instead of [1, ...] —
-        the single biggest lever over the reference's one-stream Chat.py
-        loop. Batch is padded to a power of two lanes so recompiles stay
-        O(log B); pad lanes start done and are never sampled.
-        """
-        if not prompts:
-            return []
-        if len(prompts) == 1:
-            tokens, stats = self.generate(
-                prompts[0], max_new_tokens, temperature, top_p, top_k,
-                repetition_penalty, seed,
-            )
-            stats["batch_size"] = 1
-            stats["batch_tokens_per_second"] = stats["tokens_per_second"]
-            return [(tokens, stats)]
-        gen_key = self._resolve_gen_key(
-            max_new_tokens, temperature, top_p, top_k, repetition_penalty
-        )
-        max_new = gen_key[0]
-        t0 = time.time()
-        B = len(prompts)
-        lanes = _bucket_len(B, minimum=2)
-        rows = [self._trim_prompt(p, max_new) for p in prompts]
-        lengths = [max(1, len(r)) for r in rows]
-        bucket = min(_bucket_len(max(lengths)), self.max_context)
-        ids = np.zeros((lanes, 1, bucket), dtype=np.int32)
-        for i, r in enumerate(rows):
-            ids[i, 0, : len(r)] = r
-        len_arr = np.ones((lanes,), np.int32)
-        len_arr[:B] = lengths
-
-        first_logits, caches = self._get_batch_prefill(lanes, bucket)(
-            self.params, jnp.asarray(ids), jnp.asarray(len_arr)
-        )  # [lanes, 1, V], caches with leading lanes dim
-
-        vocab = first_logits.shape[-1]
-        counts = jnp.zeros((lanes, vocab), jnp.int32)
-        base = seed if seed is not None else (time.time_ns() & 0xFFFFFFFF)
-        rngs = jax.random.split(jax.random.key(base), (lanes, 2))
-        first_tokens = jax.vmap(
-            lambda r, l, c: sample_token(
-                r, l, c,
-                temperature=gen_key[1], top_k=gen_key[2], top_p=gen_key[3],
-                repetition_penalty=gen_key[4],
-            )
-        )(rngs[:, 0], first_logits[:, 0], counts).astype(jnp.int32)
-
-        stop_set = self._stop_set
-        first_host = np.asarray(first_tokens)
-        done0 = np.zeros((lanes,), bool)
-        done0[B:] = True  # pad lanes never decode
-        for i in range(B):
-            if int(first_host[i]) in stop_set:
-                done0[i] = True
-        counts = counts.at[jnp.arange(lanes), first_tokens].add(1)
-
-        out, n, hit_stop = self._get_batch_decode(lanes, gen_key)(
-            self.params, rngs[:, 1], first_tokens, caches, counts,
-            jnp.asarray(len_arr), jnp.asarray(done0),
-        )
-        out = np.asarray(out)
-        n = np.asarray(n)
-        hit = np.asarray(hit_stop)
-        dt = time.time() - t0
-
-        results: List[Tuple[List[int], Dict[str, Any]]] = []
-        total_tokens = 0
-        for i in range(B):
-            if done0[i]:
-                tokens: List[int] = (
-                    [] if int(first_host[i]) in stop_set
-                    else [int(first_host[i])]
-                )
-                stopped = "eos" if not tokens else "length"
-            else:
-                tokens = [int(first_host[i])] + [
-                    t for t in out[i, : int(n[i])].tolist() if t >= 0
-                ]
-                stopped = "eos" if bool(hit[i]) else "length"
-            total_tokens += len(tokens)
-            results.append(
-                (
-                    tokens,
-                    {
-                        "tokens_generated": len(tokens),
-                        "prompt_tokens": lengths[i],
-                        "stopped": stopped,
-                        "seconds": round(dt, 3),
-                        "tokens_per_second": round(
-                            len(tokens) / max(dt, 1e-9), 1
-                        ),
-                        "batch_size": B,
-                    },
-                )
-            )
-        agg = round(total_tokens / max(dt, 1e-9), 1)
-        for _, s in results:
-            s["batch_tokens_per_second"] = agg
-        return results
-
     def encode_chat(self, messages: List[Dict[str, str]]) -> List[int]:
         """Conversation → prompt ids, with an open assistant turn for the
         model to complete."""
@@ -1015,9 +876,9 @@ class GenerationEngine:
     ) -> "StepwiseDecoder":
         """Build a StepwiseDecoder: the scheduler-owned decode API
         (prefill_into_slot + decode_step) continuous batching runs on.
-        The single-sequence generate()/generate_batch() paths above are
-        untouched — this is an additional serving surface, not a
-        replacement."""
+        The single-sequence generate() above stays as it is: the oracle
+        the step-wise parity tests compare against, and the chat REPL's
+        path."""
         return StepwiseDecoder(
             self,
             num_slots=num_slots,
@@ -1035,10 +896,9 @@ GREEDY_SAMPLE_KEY = (0.0, 0, 1.0, 1.0)  # (temperature, top_k, top_p, rep)
 class StepwiseDecoder:
     """Step-wise decode over a slot-paged KV pool (continuous batching).
 
-    The run-to-completion paths (generate / generate_batch) trace the
-    whole decode into one lax.while_loop, so a batch admits requests only
-    at its start and every early-finishing lane rides along as a frozen
-    row until the slowest request completes. Here the HOST owns the loop:
+    The run-to-completion path (generate) traces the whole decode into
+    one lax.while_loop: one request from start to end, nothing admitted
+    in between. Here the HOST owns the loop:
 
       prefill_into_slot(slot, prompt, ...) writes a request's prompt KV
         into its pool slot (one jit call, bucketed like generate's

@@ -11,8 +11,8 @@ TPU-native replacement for the reference's NCCL expert-parallel path.
 
 Capacity-factor semantics: each expert processes at most
 C = ceil(cf * S * k / E) tokens per sequence-group; overflow tokens fall back
-to the residual stream (tracked as drop_rate, a headline metric in
-BASELINE.json). Aux losses: Switch load-balance (f·P·E) and router z-loss.
+to the residual stream (tracked as drop_rate). Aux losses: Switch
+load-balance (f·P·E) and router z-loss.
 """
 
 from __future__ import annotations

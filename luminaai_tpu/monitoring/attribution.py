@@ -16,9 +16,8 @@ Three layers, each usable alone:
    backend returns no cost model rather than raising.
 
 2. **Trace attribution** (`classify_op` / `attribute_trace`): the
-   per-subsystem step breakdown that produced the r3 MFU attack table
-   (BENCHMARKS.md "Flagship profile"), promoted out of the throwaway
-   `scripts/analyze_trace.py` into a tested API. `classify_op` maps an
+   per-subsystem step breakdown behind `scripts/analyze_trace.py`, as a
+   tested API. `classify_op` maps an
    XLA op's framework name / category / source line onto the model's
    subsystems (flash-attention kernels, MoE dispatch vs expert matmul,
    CE loss, ...); `attribute_trace` folds a whole hlo_stats table into
@@ -236,8 +235,8 @@ def attribute_xplane_dir(
     outdir: str, n_steps: int = 1, top_k: int = 10
 ) -> TraceAttribution:
     """Attribute a saved jax.profiler trace directory (the
-    `plugins/profile/*/*.xplane.pb` layout both the trainer's windowed
-    capture and scripts/profile_flagship.py write). Requires the xprof
+    `plugins/profile/*/*.xplane.pb` layout the trainer's windowed
+    capture writes). Requires the xprof
     package; raises RuntimeError with a actionable message when it (or
     the trace) is missing — callers on the training path catch and log."""
     import glob

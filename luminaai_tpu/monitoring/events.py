@@ -12,8 +12,7 @@ every producer in the stack appends to:
     request_completed, each carrying request_id + tenant hash;
   - training step records (training/trainer.py via
     monitoring/logger.py): train_step, router_health, recompile, alert,
-    preemption;
-  - bench provenance (bench.py --smoke): bench_window.
+    preemption.
 
 Design constraints, in order:
 

@@ -9,8 +9,8 @@ Prometheus-shaped sink: a thread-safe registry of counters, gauges
 (including pull-time callback gauges for things like KV-pool occupancy)
 and fixed-bucket histograms with interpolated p50/p95/p99, rendered as
 Prometheus text exposition (`GET /metrics` in serving/server.py) and
-snapshot-able as plain JSON (bench.py embeds it so perf claims carry
-their own telemetry provenance).
+snapshot-able as plain JSON (a run can carry its own telemetry
+provenance).
 
 Design constraints, in order:
 
@@ -505,12 +505,12 @@ class MetricsRegistry:
                     )
         return "\n".join(out) + "\n"
 
-    # -- JSON snapshot (bench provenance) --------------------------------
+    # -- JSON snapshot ---------------------------------------------------
     def snapshot(self) -> Dict[str, Any]:
         """Plain-JSON view of every metric: counters/gauges as values,
-        histograms as {count, sum, p50, p95, p99}. bench.py embeds this
-        in its artifact so a throughput claim ships with the latency
-        distribution and occupancy counters behind it."""
+        histograms as {count, sum, p50, p95, p99}, so a throughput
+        figure can ship with the latency distribution and occupancy
+        counters behind it."""
         snap: Dict[str, Any] = {}
         for fam in self.families():
             per_child: Dict[str, Any] = {}

@@ -7,7 +7,6 @@ from luminaai_tpu.serving.router import (
 from luminaai_tpu.serving.server import (
     ChatServer,
     ContinuousScheduler,
-    MicroBatcher,
     build_server,
     serve,
 )
@@ -17,7 +16,6 @@ __all__ = [
     "CircuitBreaker",
     "ContinuousScheduler",
     "HttpTransport",
-    "MicroBatcher",
     "Replica",
     "Router",
     "build_server",

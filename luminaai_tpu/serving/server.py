@@ -9,11 +9,8 @@ ContinuousScheduler owns a step-wise decode loop over a slot-paged KV
 pool (engine.make_stepwise), admitting queued requests into slots freed
 by finished ones at every token step — no lane ever idles behind a
 slower request, and mixed max_new_tokens workloads share one decode
-executable. Engines without the step-wise API (and continuous=False)
-fall back to the legacy MicroBatcher, which groups same-parameter
-requests into run-to-completion generate_batch calls. The security stack
-(auth, rate limiting, input validation) is optional on the same
-endpoints either way.
+executable. The security stack (auth, rate limiting, input validation)
+is optional on the same endpoints.
 
 Endpoints:
   GET  /health            liveness + model info (ref HEALTHCHECK contract)
@@ -33,8 +30,8 @@ Endpoints:
 Both generation endpoints accept {"stream": true} and then respond as
 text/event-stream: one `data: {"token", "delta"}` frame per generated
 token, a final `data: {"done": true, <text|reply>, tokens, latency_s,
-stopped}` frame, and a `data: [DONE]` terminator (engine.generate_stream's
-chunked decode; scripts/serve_load.py drives both modes under load).
+stopped}` frame, and a `data: [DONE]` terminator (the scheduler's
+submit_stream: the lane's tokens as each decode step yields them).
 {"speculative": true} composes with both shapes on greedy requests: the
 JSON path runs generate_speculative, the SSE path streams the
 draft/verify loop (generate_stream_speculative, tokens in
@@ -107,136 +104,6 @@ class RequestTimeout(Exception):
     as HTTP 504; SSE streams get an error frame (docs/resilience.md)."""
 
 
-class MicroBatcher:
-    """Collects concurrent generation requests into one batched decode.
-
-    Handler threads `submit()` and block; a single worker thread pulls the
-    first request, waits up to `window_ms` for more with IDENTICAL
-    sampling parameters (the decode loop compiles per parameter set), and
-    runs them through `engine.generate_batch` — one chip step then serves
-    every stream's next token instead of one. Mismatched-parameter
-    requests are requeued for the next cycle, so nothing starves.
-    """
-
-    def __init__(self, engine, max_batch: int = 8, window_ms: float = 15.0,
-                 recorder: Optional[FlightRecorder] = None,
-                 telemetry: bool = True):
-        self.engine = engine
-        self.max_batch = max(1, int(max_batch))
-        self.window = max(0.0, float(window_ms)) / 1000.0
-        self.q: "queue.Queue" = queue.Queue()
-        self.batches = 0
-        self.max_batch_seen = 0
-        self._busy = False  # a batch is being generated right now
-        # Identity-aware accounting parity with the continuous path:
-        # submit() strips the request_id/tenant riders the server
-        # attaches (they must never reach generate_batch) and emits the
-        # same admitted/completed lifecycle events, so /metrics
-        # per-tenant series and the flight trail stay honest when the
-        # fallback path (--no-continuous) is serving.
-        self.telemetry = bool(telemetry)
-        self.recorder = recorder if recorder is not None else get_recorder()
-        self._worker = threading.Thread(target=self._loop, daemon=True)
-        self._worker.start()
-
-    def queue_depth(self) -> int:
-        return self.q.qsize()
-
-    def idle(self) -> bool:
-        """Nothing queued and nothing generating (drain completion)."""
-        return self.q.empty() and not self._busy
-
-    def submit(
-        self, prompt_tokens: List[int], gen_kwargs: Dict[str, Any]
-    ) -> Tuple[List[int], Dict[str, Any]]:
-        # Identity riders are host metadata, never engine kwargs (the
-        # same strip-before-compile-key contract the continuous
-        # scheduler's _make_request applies).
-        gen_kwargs = dict(gen_kwargs)
-        request_id = gen_kwargs.pop("request_id", None)
-        tenant = gen_kwargs.pop("tenant", None) or ANON_TENANT
-        gen_kwargs.pop("timeout_s", None)  # run-to-completion path
-        t0 = time.time()
-        if self.telemetry and request_id is not None:
-            self.recorder.emit(
-                "request_admitted", request_id=request_id, tenant=tenant,
-                scheduler="micro_batch",
-                prompt_tokens=len(prompt_tokens),
-            )
-        ev = threading.Event()
-        slot: Dict[str, Any] = {}
-        resolve = getattr(self.engine, "_resolve_gen_key", None)
-        if resolve is not None:
-            # Group by the RESOLVED compile key, so a request passing an
-            # explicit config-default value still batches with one that
-            # omitted it.
-            key = resolve(
-                gen_kwargs.get("max_new_tokens"),
-                gen_kwargs.get("temperature"),
-                gen_kwargs.get("top_p"),
-                gen_kwargs.get("top_k"),
-                gen_kwargs.get("repetition_penalty"),
-            )
-        else:  # duck-typed engines without the helper
-            key = tuple(sorted(gen_kwargs.items()))
-        self.q.put((prompt_tokens, key, gen_kwargs, ev, slot))
-        ev.wait()
-        if "error" in slot:
-            raise slot["error"]
-        tokens, stats = slot["result"]
-        if request_id is not None:
-            # The reply payload correlates on these like the continuous
-            # path's stats do.
-            stats = {**stats, "request_id": request_id, "tenant": tenant}
-            if self.telemetry:
-                self.recorder.emit(
-                    "request_completed", request_id=request_id,
-                    tenant=tenant, scheduler="micro_batch",
-                    tokens=len(tokens),
-                    seconds=round(time.time() - t0, 3),
-                    stopped=stats.get("stopped"),
-                )
-        return tokens, stats
-
-    def _loop(self) -> None:
-        while True:
-            first = self.q.get()
-            self._busy = True
-            batch = [first]
-            requeue = []
-            deadline = time.time() + self.window
-            while len(batch) < self.max_batch:
-                left = deadline - time.time()
-                if left <= 0:
-                    break
-                try:
-                    nxt = self.q.get(timeout=left)
-                except queue.Empty:
-                    break
-                if nxt[1] == first[1]:
-                    batch.append(nxt)
-                else:
-                    requeue.append(nxt)
-            for item in requeue:
-                self.q.put(item)
-            try:
-                results = self.engine.generate_batch(
-                    [item[0] for item in batch], **batch[0][2]
-                )
-                for item, res in zip(batch, results):
-                    item[4]["result"] = res
-            except Exception as e:  # deliver, don't kill the worker
-                logger.exception("batched generation failed")
-                for item in batch:
-                    item[4]["error"] = e
-            finally:
-                self.batches += 1
-                self.max_batch_seen = max(self.max_batch_seen, len(batch))
-                for item in batch:
-                    item[3].set()
-                self._busy = False
-
-
 class _ContinuousRequest:
     """One in-flight request inside the ContinuousScheduler: its prompt,
     resolved budgets, and the sink its tokens stream into (a Queue for
@@ -299,9 +166,9 @@ class _StepAtCollect:
 class ContinuousScheduler:
     """Continuous (in-flight) batching over a slot-paged KV pool.
 
-    Replaces the MicroBatcher's run-to-completion batches for engines
-    exposing the step-wise decode API (GenerationEngine.make_stepwise):
-    a single worker owns the decode loop, and EVERY step it (1) frees the
+    Serves engines exposing the step-wise decode API
+    (GenerationEngine.make_stepwise): a single worker owns the decode
+    loop, and EVERY step it (1) frees the
     slots of finished lanes, (2) admits queued requests into freed slots
     (prefill-then-join), and (3) advances all active lanes one token in
     one jit call. Early finishers stop costing chip steps the moment they
@@ -323,11 +190,11 @@ class ContinuousScheduler:
     admissions pause, the active lanes drain, and the scheduler switches
     keys — bounded-latency FIFO across keys rather than starvation.
 
-    Tokens stream out per-slot as they decode: `submit()` blocks like the
-    MicroBatcher, `submit_stream()` returns a generator with the engine
-    generate_stream contract (ints, then a stats dict) that the existing
-    SSE path consumes unchanged; closing it cancels the lane at the next
-    step, so a gone client stops costing decode immediately.
+    Tokens stream out per-slot as they decode: `submit()` blocks until
+    the lane ends, `submit_stream()` returns a generator with the engine
+    generate_stream contract (ints, then a stats dict) that the SSE path
+    consumes; closing it cancels the lane at the next step, so a gone
+    client stops costing decode immediately.
     """
 
     def __init__(
@@ -437,9 +304,8 @@ class ContinuousScheduler:
         self._prefilling: Dict[int, Tuple[Any, Any, float, float]] = {}
         self.q: "queue.Queue" = queue.Queue()
         self.window = max(0.0, float(admission_window_ms)) / 1000.0
-        # Stat names shared with MicroBatcher so /stats stays stable:
-        # batches = generations (one sampling key each), max_batch_seen =
-        # peak concurrent lanes.
+        # /stats names: batches = generations (one sampling key each),
+        # max_batch_seen = peak concurrent lanes.
         self.batches = 0
         self.max_batch_seen = 0
         self.requests_served = 0
@@ -1546,7 +1412,8 @@ class _SlotStream:
     once — on exhaustion, error, or close(). A plain generator's finally
     block never runs if the generator is closed before its first next()
     (e.g. the handler's header write fails for an already-gone client),
-    which would slowly leak stream slots into permanent 503s."""
+    which would slowly leak speculative slots until the hint never
+    engages."""
 
     def __init__(self, inner, release):
         self._inner = inner
@@ -1585,10 +1452,7 @@ class ChatServer:
         bootstrap_user: Optional[tuple] = None,
         users_path: str = "users.json",
         max_new_tokens_cap: int = 2048,
-        max_batch: int = 8,
-        batch_window_ms: float = 15.0,
         max_streams: int = 4,
-        continuous: Any = "auto",
         num_slots: int = 8,
         page_size: int = 128,
         admission_window_ms: float = 0.0,
@@ -1649,90 +1513,82 @@ class ChatServer:
         # background and sets the gate when it completes; in-process
         # embedders/tests default to immediately-ready.
         self._ready = threading.Event()
-        # Continuous batching (step-level admission over a slot-paged KV
-        # pool) whenever the engine exposes the step-wise decode API;
-        # duck-typed engines without it keep the legacy MicroBatcher
-        # (continuous=False forces the legacy path for A/B).
-        self.continuous = bool(
-            continuous is True
-            or (continuous == "auto" and hasattr(engine, "make_stepwise"))
-        )
-        if self.continuous:
-            # Serving hang watchdog: "auto" builds one over the flight
-            # dir (hang forensics land next to the drain dumps); pass
-            # None/False to disable, or a configured HangWatchdog to
-            # control thresholds (tests do).
-            if watchdog == "auto":
-                wd_kw = {}
-                if watchdog_k is not None:
-                    wd_kw["k"] = float(watchdog_k)
-                if watchdog_floor_s is not None:
-                    # --watchdog-floor: on cold fleets, raise above the
-                    # worst-case decode compile before enabling abort.
-                    wd_kw["floor_s"] = float(watchdog_floor_s)
-                watchdog = HangWatchdog(
-                    kind="serving",
-                    registry=self.registry,
-                    recorder=self.recorder,
-                    dump_dir=flight_dir,
-                    abort=watchdog_abort,
-                    **wd_kw,
-                )
-            self.watchdog = watchdog or None
-            # Operator-supplied tenant weights are keyed by RAW identity
-            # (or the literal "anon"); hash them here so raw identities
-            # never live in scheduler state — the same tenant_hash the
-            # gate resolves request identities through.
-            weights = {
-                (k if k == ANON_TENANT else tenant_hash(str(k))): v
-                for k, v in (tenant_weights or {}).items()
-            }
-            # Cross-replica page sharing (serving/page_share.py):
-            # `page_share` is the ROUTER url; the client reports
-            # harvested chain keys there and pulls indexed pages
-            # replica-to-replica. self_url is how peers reach THIS
-            # replica — serve() fills it from host/port; tests binding
-            # port 0 set client.self_url after the listener exists.
-            self.page_share = None
-            if page_share:
-                from luminaai_tpu.serving.page_share import (
-                    PageShareClient,
-                )
-
-                self.page_share = PageShareClient(
-                    router_url=str(page_share),
-                    self_url=page_share_self_url or "",
-                    timeout_s=page_pull_timeout_s,
-                    max_inflight=page_share_max_inflight,
-                    registry=self.registry if telemetry else None,
-                    recorder=self.recorder if telemetry else None,
-                )
-            self.batcher = ContinuousScheduler(
-                engine,
-                num_slots=num_slots,
-                page_size=page_size,
-                admission_window_ms=admission_window_ms,
+        # One scheduler: continuous batching (step-level admission over a
+        # slot-paged KV pool) needs the engine's step-wise decode API.
+        if not hasattr(engine, "make_stepwise"):
+            raise TypeError(
+                f"ChatServer needs an engine with make_stepwise() (the "
+                f"step-wise decode API the ContinuousScheduler drives); "
+                f"{type(engine).__name__} has none"
+            )
+        # Serving hang watchdog: "auto" builds one over the flight
+        # dir (hang forensics land next to the drain dumps); pass
+        # None/False to disable, or a configured HangWatchdog to
+        # control thresholds (tests do).
+        if watchdog == "auto":
+            wd_kw = {}
+            if watchdog_k is not None:
+                wd_kw["k"] = float(watchdog_k)
+            if watchdog_floor_s is not None:
+                # --watchdog-floor: on cold fleets, raise above the
+                # worst-case decode compile before enabling abort.
+                wd_kw["floor_s"] = float(watchdog_floor_s)
+            watchdog = HangWatchdog(
+                kind="serving",
                 registry=self.registry,
-                tracer=self.tracer,
-                telemetry=telemetry,
-                latency_buckets=latency_buckets,
-                request_timeout_s=request_timeout_s,
                 recorder=self.recorder,
-                max_tenants=self.max_tenants,
-                prefill_chunk_tokens=prefill_chunk_tokens,
-                prefix_cache_pages=prefix_cache_pages,
-                prefix_cache_tenant_quota=prefix_cache_tenant_quota,
-                tenant_weights=weights,
-                watchdog=self.watchdog,
-                page_share=self.page_share,
+                dump_dir=flight_dir,
+                abort=watchdog_abort,
+                **wd_kw,
             )
-        else:
-            self.watchdog = None
-            self.page_share = None
-            self.batcher = MicroBatcher(
-                engine, max_batch=max_batch, window_ms=batch_window_ms,
-                recorder=self.recorder, telemetry=telemetry,
+        self.watchdog = watchdog or None
+        # Operator-supplied tenant weights are keyed by RAW identity
+        # (or the literal "anon"); hash them here so raw identities
+        # never live in scheduler state — the same tenant_hash the
+        # gate resolves request identities through.
+        weights = {
+            (k if k == ANON_TENANT else tenant_hash(str(k))): v
+            for k, v in (tenant_weights or {}).items()
+        }
+        # Cross-replica page sharing (serving/page_share.py):
+        # `page_share` is the ROUTER url; the client reports
+        # harvested chain keys there and pulls indexed pages
+        # replica-to-replica. self_url is how peers reach THIS
+        # replica — serve() fills it from host/port; tests binding
+        # port 0 set client.self_url after the listener exists.
+        self.page_share = None
+        if page_share:
+            from luminaai_tpu.serving.page_share import (
+                PageShareClient,
             )
+
+            self.page_share = PageShareClient(
+                router_url=str(page_share),
+                self_url=page_share_self_url or "",
+                timeout_s=page_pull_timeout_s,
+                max_inflight=page_share_max_inflight,
+                registry=self.registry if telemetry else None,
+                recorder=self.recorder if telemetry else None,
+            )
+        self.batcher = ContinuousScheduler(
+            engine,
+            num_slots=num_slots,
+            page_size=page_size,
+            admission_window_ms=admission_window_ms,
+            registry=self.registry,
+            tracer=self.tracer,
+            telemetry=telemetry,
+            latency_buckets=latency_buckets,
+            request_timeout_s=request_timeout_s,
+            recorder=self.recorder,
+            max_tenants=self.max_tenants,
+            prefill_chunk_tokens=prefill_chunk_tokens,
+            prefix_cache_pages=prefix_cache_pages,
+            prefix_cache_tenant_quota=prefix_cache_tenant_quota,
+            tenant_weights=weights,
+            watchdog=self.watchdog,
+            page_share=self.page_share,
+        )
         # Build identity for fleet debugging (docs/observability.md):
         # which commit/jax/config answers this /metrics.
         register_build_info(self.registry, config=engine.config)
@@ -1853,10 +1709,10 @@ class ChatServer:
             threading.Thread(target=self._warmup, daemon=True).start()
         else:
             self._ready.set()
-        # Streams bypass the MicroBatcher, so each holds its own KV cache
-        # + decode loop on the device; unlike the single-worker batched
-        # path they'd be unbounded without a cap (ThreadingHTTPServer is
-        # thread-per-connection).
+        # Speculative requests (JSON and SSE) run outside the scheduler:
+        # each holds its own KV cache + decode loop on the device, so
+        # without a cap they'd be unbounded (ThreadingHTTPServer is
+        # thread-per-connection). Every other stream is a scheduler lane.
         self._stream_slots = threading.Semaphore(max(1, int(max_streams)))
         # Auth/limiter/counter state is shared across handler threads;
         # SecurityManager and RateLimiter are not thread-safe themselves.
@@ -1894,16 +1750,12 @@ class ChatServer:
             self._draining = True
             if self.telemetry:
                 self.recorder.emit(
-                    "drain_started", queue_depth=self._queue_depth()
+                    "drain_started", queue_depth=self.batcher.queue_depth()
                 )
             logger.warning(
                 "drain started: new generations rejected, in-flight work "
-                "finishing (queue_depth=%d)", self._queue_depth(),
+                "finishing (queue_depth=%d)", self.batcher.queue_depth(),
             )
-
-    def _idle(self) -> bool:
-        idle = getattr(self.batcher, "idle", None)
-        return bool(idle()) if callable(idle) else True
 
     def drain(self, timeout_s: Optional[float] = None) -> bool:
         """begin_drain + wait (bounded) for in-flight generations to
@@ -1916,13 +1768,13 @@ class ChatServer:
         )
         idle = False
         while time.time() < deadline:
-            if self._idle():
+            if self.batcher.idle():
                 logger.info("drain complete: scheduler idle")
                 idle = True
                 break
             time.sleep(0.05)
         if not idle:
-            idle = self._idle()
+            idle = self.batcher.idle()
             if not idle:
                 logger.warning(
                     "drain grace expired with work still in flight; "
@@ -1960,10 +1812,6 @@ class ChatServer:
             )
         return self.recorder.dump_to_dir(self.flight_dir, reason)
 
-    def _queue_depth(self) -> int:
-        qd = getattr(self.batcher, "queue_depth", None)
-        return int(qd()) if callable(qd) else 0
-
     def _shed(self):
         """Load-shedding gate for generation endpoints: draining servers
         and full admission queues answer 503 + Retry-After immediately
@@ -1973,14 +1821,12 @@ class ChatServer:
                 "error": "server draining; retry against another replica",
                 "retry_after": 2,
             }
-        depth = self._queue_depth()
+        depth = self.batcher.queue_depth()
         if self.max_queue_depth and depth >= self.max_queue_depth:
             if self.telemetry:
                 self._m_overload.inc()
             # Rough time-to-queue-space: a slot's worth of queued work.
-            slots = getattr(self.batcher, "max_batch", None) or getattr(
-                getattr(self.batcher, "decoder", None), "num_slots", 8
-            )
+            slots = getattr(self.batcher.decoder, "num_slots", 8)
             return 503, {
                 "error": f"overloaded: admission queue at {depth}; "
                          "retry later",
@@ -2032,19 +1878,13 @@ class ChatServer:
 
     def _scheduler_state(self) -> Dict[str, Any]:
         """Live scheduler occupancy for /healthz and /stats consumers."""
-        if self.continuous:
-            st = self.batcher.stats()
-            return {
-                "scheduler": "continuous",
-                "active_lanes": st.get("active_lanes", 0),
-                "queue_depth": st.get("queue_depth", 0),
-                "slots_free": st.get("kv_pool", {}).get("free"),
-                "decode_steps": st.get("decode_steps", 0),
-            }
+        st = self.batcher.stats()
         return {
-            "scheduler": "micro_batch",
-            "queue_depth": self.batcher.q.qsize(),
-            "batches": self.batcher.batches,
+            "scheduler": st["scheduler"],
+            "active_lanes": st.get("active_lanes", 0),
+            "queue_depth": st.get("queue_depth", 0),
+            "slots_free": st.get("kv_pool", {}).get("free"),
+            "decode_steps": st.get("decode_steps", 0),
         }
 
     def _staleness(self) -> Dict[str, Any]:
@@ -2055,16 +1895,14 @@ class ChatServer:
         advancing — an idle scheduler is quiet, not stale."""
         out: Dict[str, Any] = {}
         now = time.time()
-        busy = False
-        if self.continuous:
-            last = getattr(self.batcher, "last_tick_ts", None)
-            if last is not None:
-                out["last_decode_tick_age_seconds"] = round(now - last, 3)
-            st = self._scheduler_state()
-            busy = bool(
-                st.get("active_lanes") or st.get("queue_depth")
-                or getattr(self.batcher, "_prefilling", None)
-            )
+        last = self.batcher.last_tick_ts
+        if last is not None:
+            out["last_decode_tick_age_seconds"] = round(now - last, 3)
+        st = self._scheduler_state()
+        busy = bool(
+            st.get("active_lanes") or st.get("queue_depth")
+            or self.batcher._prefilling
+        )
         fam = self.registry.get("train_last_step_ts")
         if fam is not None:
             try:
@@ -2195,14 +2033,8 @@ class ChatServer:
                 "requests": self.requests,
                 "tokens_out": self.tokens_out,
                 "uptime_s": round(time.time() - self.t0, 1),
-                "batches": self.batcher.batches,
-                "max_batch_seen": self.batcher.max_batch_seen,
-                "scheduler": (
-                    "continuous" if self.continuous else "micro_batch"
-                ),
             }
-            if self.continuous:
-                out.update(self.batcher.stats())
+            out.update(self.batcher.stats())
             return 200, out
         if method == "POST" and path == "/v1/auth":
             if not self.secure:
@@ -2377,15 +2209,12 @@ class ChatServer:
             # Not eligible (sampling params / engine support): fall
             # through to the batched path silently — speculation is an
             # accelerator hint, not a contract.
-        # Concurrent requests with the same sampling params ride one
-        # batched decode (MicroBatcher); sampling overrides go as generate
-        # kwargs, so there is no config mutation to serialize.
+        # Concurrent requests with the same sampling params share one
+        # generation of the scheduler; sampling overrides go as kwargs,
+        # so there is no config mutation to serialize.
         timeout_s = self._effective_timeout(body)
-        # Identity riders ride BOTH schedulers' submit (each strips them
-        # before its compile key / engine kwargs), so per-tenant series
-        # and the flight trail stay honest on the --no-continuous
-        # fallback path too. The deadline is a continuous-scheduler
-        # contract (step-level eviction); MicroBatcher drops it.
+        # Identity riders and the deadline ride the scheduler's submit,
+        # which strips them before its compile key.
         overrides = {
             **overrides, "request_id": request_id, "tenant": tenant,
         }
@@ -2470,9 +2299,9 @@ class ChatServer:
                          request_id=None, tenant=None):
         """Greedy requests with {"speculative": true} run the engine's
         prompt-lookup speculative decode (exactly the greedy sequence,
-        several tokens per device call on repetitive text). Single-stream
-        like SSE, so it borrows the stream slot cap instead of the
-        MicroBatcher; returns None when not eligible (sampling requested
+        several tokens per device call on repetitive text). It runs
+        outside the scheduler under the speculative slot cap
+        (max_streams); returns None when not eligible (sampling requested
         or the engine lacks the method) so the caller falls back."""
         if not hasattr(self.engine, "generate_speculative"):
             return None
@@ -2503,12 +2332,11 @@ class ChatServer:
                      token: Optional[str],
                      request_id: Optional[str] = None):
         """Begin a streamed generation. Returns (error_tuple | None,
-        events_generator | None). Streaming runs the engine's chunked
-        decode directly (one stream per request thread) rather than the
-        MicroBatcher — each stream owns its decode cadence; batched SSE
-        would couple every client's latency to the slowest stream.
-        An inbound `X-Request-Id` (router-minted) is honored like
-        handle()'s, so stream events correlate across tiers."""
+        events_generator | None). A stream is a lane of the scheduler
+        (submit_stream), or, on a greedy {"speculative": true} request
+        with a free slot, the engine's draft/verify loop. An inbound
+        `X-Request-Id` (router-minted) is honored like handle()'s, so
+        stream events correlate across tiers."""
         request_id = request_id or new_request_id()
         shed = self._shed()  # drain/overload applies to streams too
         if shed is not None:
@@ -2519,10 +2347,6 @@ class ChatServer:
             err, tenant = self._gate(body, token)
         if err is not None:
             return err, None
-        if not self.continuous and not hasattr(
-            self.engine, "generate_stream"
-        ):
-            return (501, {"error": "engine does not support streaming"}), None
         err, prompt_ids, overrides, reply_key = self._parse_request(path, body)
         if err is not None:
             return err, None
@@ -2538,14 +2362,14 @@ class ChatServer:
             # Greedy SSE with {"speculative": true}: the draft/verify
             # loop composes with the streaming contract — tokens arrive
             # in accepted-prefix bursts (engine
-            # generate_stream_speculative). Single-stream like the JSON
-            # speculative path, so it borrows the stream slot cap even
-            # under the continuous scheduler; slots busy or sampled
-            # params fall through to the normal stream — the hint never
-            # makes a servable request fail. The per-request deadline
-            # applies: speculative streams run outside the continuous
-            # scheduler's overdue-lane eviction, so the engine's decode
-            # loop enforces it instead (stopped='timeout').
+            # generate_stream_speculative). Like the JSON speculative
+            # path it runs outside the scheduler, so it takes one of
+            # the speculative slots; slots busy or sampled params fall
+            # through to the scheduler's stream — the hint never makes
+            # a servable request fail. The per-request deadline
+            # applies: speculative streams run outside the scheduler's
+            # overdue-lane eviction, so the engine's decode loop
+            # enforces it instead (stopped='timeout').
             if timeout_s:
                 overrides = {**overrides, "timeout_s": timeout_s}
             return None, _SlotStream(
@@ -2555,31 +2379,19 @@ class ChatServer:
                 ),
                 self._stream_slots.release,
             )
-        if self.continuous:
-            # Identity riders for the scheduler's lifecycle events
-            # (stripped before the compile key) + the deadline.
-            overrides = {
-                **overrides, "request_id": request_id, "tenant": tenant,
-            }
-            if timeout_s:
-                overrides["timeout_s"] = timeout_s
-            # Streams ride the shared continuous decode loop like any
-            # other request — concurrency is bounded by the KV pool's
-            # slots (excess queues), so the legacy per-stream slot cap
-            # does not apply. Closing the generator cancels the lane.
-            return None, self._stream_events(
-                prompt_ids, overrides, reply_key,
-                request_id=request_id, tenant=tenant,
-            )
-        if not self._stream_slots.acquire(blocking=False):
-            return (
-                503,
-                {"error": "too many concurrent streams; retry shortly"},
-            ), None
-        return None, _SlotStream(
-            self._stream_events(prompt_ids, overrides, reply_key,
-                                request_id=request_id, tenant=tenant),
-            self._stream_slots.release,
+        # Identity riders for the scheduler's lifecycle events (stripped
+        # before the compile key) + the deadline.
+        overrides = {
+            **overrides, "request_id": request_id, "tenant": tenant,
+        }
+        if timeout_s:
+            overrides["timeout_s"] = timeout_s
+        # Streams ride the shared decode loop like any other request:
+        # concurrency is bounded by the KV pool's slots (excess queues).
+        # Closing the generator cancels the lane.
+        return None, self._stream_events(
+            prompt_ids, overrides, reply_key,
+            request_id=request_id, tenant=tenant,
         )
 
     def _stream_events(self, prompt_ids, overrides, reply_key,
@@ -2625,10 +2437,9 @@ class ChatServer:
             span.set(tokens=n)
             self.mark_ready()
 
-        # Continuous mode streams per-slot out of the shared scheduler
-        # loop; legacy engines run their own chunked decode; speculative
+        # A stream is a lane of the shared scheduler loop; speculative
         # greedy streams run the engine's draft/verify loop directly.
-        # Every source honors the same contract (token ints, then a
+        # Both sources honor the same contract (token ints, then a
         # stats dict).
         if speculative:
             src = self.engine.generate_stream_speculative(
@@ -2636,10 +2447,8 @@ class ChatServer:
                 max_new_tokens=overrides.get("max_new_tokens"),
                 timeout_s=overrides.get("timeout_s"),
             )
-        elif self.continuous:
-            src = self.batcher.submit_stream(prompt_ids, overrides)
         else:
-            src = self.engine.generate_stream(prompt_ids, **overrides)
+            src = self.batcher.submit_stream(prompt_ids, overrides)
         try:
             for item in src:
                 if isinstance(item, dict):  # final stats yield
@@ -2706,7 +2515,7 @@ class ChatServer:
             stream_span.__exit__(None, None, None)
             close = getattr(src, "close", None)
             if close is not None:
-                close()  # continuous: flags the lane cancelled
+                close()  # scheduler stream: flags the lane cancelled
 
     # -- socket layer ------------------------------------------------------
     def export_page_by_key(self, key: str) -> Optional[bytes]:
@@ -2717,7 +2526,7 @@ class ChatServer:
         local prefill, so refusing is always safe. The page is
         refcount-pinned across the device_get so eviction pressure
         cannot reassign its arena slot mid-serialization."""
-        decoder = getattr(self.batcher, "decoder", None)
+        decoder = self.batcher.decoder
         cache = getattr(decoder, "prefix_cache", None)
         pool = getattr(decoder, "pool", None)
         if cache is None or pool is None or pool.caches is None:
@@ -3001,7 +2810,6 @@ def build_server(
     kv_cache_dtype: Optional[str] = None,
     num_slots: int = 8,
     page_size: int = 128,
-    continuous: Any = "auto",
     admission_window_ms: float = 0.0,
     telemetry: bool = True,
     trace_jsonl: Optional[str] = None,
@@ -3052,7 +2860,7 @@ def build_server(
     )
     return ChatServer(
         chat.engine, secure=secure, bootstrap_user=bootstrap_user,
-        continuous=continuous, num_slots=num_slots, page_size=page_size,
+        num_slots=num_slots, page_size=page_size,
         admission_window_ms=admission_window_ms,
         prefill_chunk_tokens=prefill_chunk_tokens,
         prefix_cache_pages=prefix_cache_pages,

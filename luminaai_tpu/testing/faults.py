@@ -454,9 +454,8 @@ def slow_replica(server_or_engine, delay_s: float = 0.2) -> Iterator[dict]:
     """Inflate every decode tick on ONE replica's engine — the slow-
     replica fleet shape hedged dispatch exists for: the affine target
     still answers, just late, so only a hedge (not a failover) recovers
-    the tail. Wraps the engine's generate / generate_batch /
-    generate_stream; pass a ChatServer or the engine itself. Yields
-    {'calls'}."""
+    the tail. Wraps the engine's generate / generate_stream; pass a
+    ChatServer or the engine itself. Yields {'calls'}."""
     engine = getattr(server_or_engine, "engine", server_or_engine)
     stats = {"calls": 0}
     wrapped = []
@@ -479,7 +478,7 @@ def slow_replica(server_or_engine, delay_s: float = 0.2) -> Iterator[dict]:
         setattr(engine, name, wrapper)
         wrapped.append((name, wrapper, original))
 
-    for name in ("generate", "generate_batch", "generate_stream"):
+    for name in ("generate", "generate_stream"):
         _wrap(name)
     try:
         yield stats

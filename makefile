@@ -1,6 +1,6 @@
 # Development makefile (ref makefile:1 — its desktop dev commands; these
 # target the TPU framework's actual workflows).
-.PHONY: help install test test-fast analyze lint bench bench-ops dryrun serve load docker
+.PHONY: help install test test-fast analyze lint dryrun serve docker
 
 PY ?= python
 
@@ -24,20 +24,11 @@ lint: ## Sub-second lint-only loop (no jax tracing); + ruff if installed
 	lumina analyze --no-audit
 	@if command -v ruff >/dev/null 2>&1; then ruff check .; else echo "ruff not installed; skipping (CI runs it)"; fi
 
-bench: ## Driver-contract benchmark (one JSON line)
-	$(PY) bench.py
-
-bench-ops: ## Op-level microbenchmarks
-	$(PY) bench_ops.py
-
 dryrun: ## 8-device multichip sharding dry run (virtual CPU mesh)
 	$(PY) __graft_entry__.py 8
 
 serve: ## Serve the latest checkpoint found under . (API + chat UI at /)
 	lumina serve
-
-load: ## Serving load test against an in-process tiny model
-	JAX_PLATFORMS=cpu $(PY) scripts/serve_load.py
 
 docker: ## Build the serving image
 	docker build -t lumina-tpu .

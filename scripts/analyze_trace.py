@@ -1,14 +1,13 @@
 #!/usr/bin/env python
-"""Turn a jax.profiler xplane trace into the per-subsystem step breakdown
-used for the r3 MFU attack (BENCHMARKS.md "Flagship profile" table).
+"""Turn a jax.profiler xplane trace into a per-subsystem step breakdown.
 
 Usage:
-    python scripts/profile_flagship.py [variant] [outdir]   # capture
+    lumina train ... --profile-steps N                      # capture
     python scripts/analyze_trace.py <outdir> [n_steps]      # analyze
 
-n_steps = how many steps the trace window covered (profile_flagship
-captures 3). Requires the xprof package (baked into the image); the
-conversion runs on CPU — no TPU needed to analyze a saved trace.
+n_steps = how many steps the trace window covered. Requires the xprof
+package (baked into the image); the conversion runs on CPU — no TPU
+needed to analyze a saved trace.
 
 The classifier and aggregation live in
 luminaai_tpu/monitoring/attribution.py (tested API; the trainer's

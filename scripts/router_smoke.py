@@ -37,6 +37,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 def build_engine():
     """Host-only synthetic engine speaking GenerationEngine's contract."""
+    import numpy as np
+
     from luminaai_tpu.config import Config
 
     class _TokBackend:
@@ -49,6 +51,50 @@ def build_engine():
         def decode(self, tokens):
             return "tok:" + ",".join(str(t) for t in tokens)
 
+    class _Stepper:
+        """decode_step() alone: a lane plays its prompt's first four ids
+        and ends. The scheduler steps it through its _StepAtCollect
+        seam, as the tests' fakes are stepped."""
+
+        def __init__(self, num_slots=2):
+            self.num_slots = num_slots
+            self.steps = 0
+            self._free = list(range(num_slots))
+            self._lanes = [None] * num_slots
+
+        def has_free_slot(self):
+            return bool(self._free)
+
+        def acquire_slot(self):
+            return self._free.pop()
+
+        def release_slot(self, slot):
+            self._lanes[slot] = None
+            self._free.append(slot)
+
+        def prefill_into_slot(self, slot, prompt, max_new_tokens=1,
+                              sample_key=None, seed=None):
+            lane = iter(list(prompt)[:4])
+            first = next(lane, None)
+            self._lanes[slot] = lane if max_new_tokens > 1 else None
+            return {"token": first or 0, "prompt_tokens": len(prompt),
+                    "is_stop": first is None}
+
+        def decode_step(self, sample_key=None):
+            toks = np.zeros((self.num_slots,), np.int64)
+            produced = np.zeros((self.num_slots,), bool)
+            eos = np.zeros((self.num_slots,), bool)
+            for s, lane in enumerate(self._lanes):
+                if lane is None:
+                    continue
+                nxt = next(lane, None)
+                if nxt is None:
+                    eos[s], self._lanes[s] = True, None
+                else:
+                    toks[s], produced[s] = nxt, True
+            self.steps += 1
+            return toks, produced, eos
+
     class _Eng:
         def __init__(self):
             self.config = Config(
@@ -57,20 +103,11 @@ def build_engine():
             )
             self.tokenizer = _Tok()
 
-        def generate(self, prompt_tokens, **kw):
-            toks = list(prompt_tokens)[:4]
-            return toks, {"tokens_generated": len(toks), "stopped": "eos"}
-
-        def generate_batch(self, prompts, **kw):
-            return [self.generate(p, **kw) for p in prompts]
+        def make_stepwise(self, **kw):
+            return _Stepper()
 
         def encode_chat(self, messages):
             return self.tokenizer.backend.encode(messages[-1]["content"])
-
-        def generate_stream(self, prompt_tokens, **kw):
-            toks, stats = self.generate(prompt_tokens, **kw)
-            yield from toks
-            yield stats
 
     return _Eng()
 
@@ -126,7 +163,7 @@ def paged_replica_main(port: int, router_url: str) -> int:
     )
     engine = GenerationEngine(model, params, tok, cfg)
     srv = ChatServer(
-        engine, registry=MetricsRegistry(), continuous=True,
+        engine, registry=MetricsRegistry(),
         num_slots=2, page_size=32, prefix_cache_pages=6,
         page_share=router_url,
         page_share_self_url=f"http://127.0.0.1:{port}",

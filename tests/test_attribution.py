@@ -1,17 +1,13 @@
-"""Performance attribution + bench provenance tests (ISSUE 3).
+"""Performance attribution tests (ISSUE 3).
 
 Covers: the op-classifier goldens, attribute_trace on a synthetic
 fixture, compiled-cost gauges present-or-gracefully-absent on CPU, the
-analytic-vs-compiled MFU cross-check, the bench_gate pass/fail rules,
-and the donation audit."""
+analytic-vs-compiled MFU cross-check, and the donation audit."""
 
-import importlib.util
 import json
-import os
 
 import pytest
 
-import bench
 from luminaai_tpu.monitoring.attribution import (
     MFU_DIVERGENCE_THRESHOLD,
     OpRow,
@@ -24,17 +20,6 @@ from luminaai_tpu.monitoring.attribution import (
     tree_bytes,
 )
 from luminaai_tpu.monitoring.telemetry import MetricsRegistry
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _load_script(name):
-    spec = importlib.util.spec_from_file_location(
-        name, os.path.join(REPO, "scripts", f"{name}.py")
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 # ---------------------------------------------------------------------------
@@ -275,82 +260,6 @@ def test_connectivity_probe_reports_degraded_slice(monkeypatch):
     assert snap["diagnose_device_visibility_ok"] == 0.0
     assert snap["diagnose_processes"] == n + 2
     assert "diagnose_allreduce_seconds" not in snap
-
-
-# ---------------------------------------------------------------------------
-# bench_gate
-# ---------------------------------------------------------------------------
-
-def _fresh(value, platform="tpu", config="flagship_tuned"):
-    return {
-        "metric": bench.METRIC,
-        "value": value,
-        "unit": "tokens/sec/chip",
-        "vs_baseline": 0.5,
-        "extras": {"platform": platform, "config": config},
-    }
-
-
-def test_bench_gate_pass_fail_and_no_baseline(tmp_path):
-    gate_mod = _load_script("bench_gate")
-    # Trajectory: an early slow round, the best round, and a wrapped
-    # driver artifact (parsed-key shape) on another config.
-    (tmp_path / "BENCH_r01.json").write_text(json.dumps(_fresh(25000.0)))
-    (tmp_path / "BENCH_r02.json").write_text(json.dumps(_fresh(31557.0)))
-    (tmp_path / "BENCH_r03.json").write_text(
-        json.dumps({"n": 3, "rc": 0, "parsed": _fresh(1_474_875.0,
-                                                      config="ref_debug_moe")})
-    )
-    traj = gate_mod.load_trajectory(str(tmp_path))
-    assert len(traj) == 3
-
-    ok = gate_mod.gate(_fresh(30000.0), traj)
-    assert ok["verdict"] == "pass"
-    assert ok["best_prior"]["value"] == 31557.0
-    assert ok["compared"] == 2  # same config+platform only
-
-    bad = gate_mod.gate(_fresh(20000.0), traj)
-    assert bad["verdict"] == "fail"
-    assert bad["ratio"] == pytest.approx(20000.0 / 31557.0, abs=1e-4)
-
-    # >10% regression vs BEST prior, even if the latest was slower.
-    drift = gate_mod.gate(_fresh(26000.0), traj)
-    assert drift["verdict"] == "fail"
-
-    # Same config on a different platform: availability, not regression.
-    cpu = gate_mod.gate(_fresh(4000.0, platform="cpu"), traj)
-    assert cpu["verdict"] == "no_baseline"
-
-    none = gate_mod.gate(_fresh(1.0, config="smoke"), traj)
-    assert none["verdict"] == "no_baseline"
-
-
-def test_bench_gate_cli_exit_codes(tmp_path):
-    gate_mod = _load_script("bench_gate")
-    (tmp_path / "BENCH_r01.json").write_text(json.dumps(_fresh(31557.0)))
-    fresh_ok = tmp_path / "ok.json"
-    fresh_ok.write_text(json.dumps(_fresh(31000.0)))
-    fresh_bad = tmp_path / "bad.json"
-    fresh_bad.write_text(json.dumps(_fresh(10000.0)))
-    assert gate_mod.main([str(fresh_ok), "--root", str(tmp_path)]) == 0
-    assert gate_mod.main([str(fresh_bad), "--root", str(tmp_path)]) == 1
-    assert gate_mod.main(
-        [str(tmp_path / "missing.json"), "--root", str(tmp_path)]
-    ) == 2
-
-
-def test_bench_gate_ignores_errored_and_cpu_trajectory(tmp_path):
-    gate_mod = _load_script("bench_gate")
-    errored = _fresh(50000.0)
-    errored["error"] = "boom"
-    (tmp_path / "BENCH_r01.json").write_text(json.dumps(errored))
-    (tmp_path / "BENCH_r02.json").write_text(
-        json.dumps(_fresh(9000.0, platform="cpu"))
-    )
-    verdict = gate_mod.gate(
-        _fresh(30000.0), gate_mod.load_trajectory(str(tmp_path))
-    )
-    assert verdict["verdict"] == "no_baseline"
 
 
 # -- donation audit (r6) ----------------------------------------------------

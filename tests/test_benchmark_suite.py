@@ -1,0 +1,54 @@
+"""The benchmark's own CPU tests, inside tier-1.
+
+`benchmark/tests/test_*.py` and each architecture's `test_*.py` (the
+manifest's rules, the traffic generator, the trace reduction on a
+recorded v5e trace, the references against the program at a tiny size)
+guard the yardstick every PR is measured with, and the driver's command
+names only `tests/`. The star-imports below bring their test functions
+and fixtures into this module, so they are collected, and counted, here;
+nothing under `benchmark/` is edited for it. They run under
+tests/conftest.py's eight-device CPU mesh, as under
+`python -m pytest benchmark`.
+"""
+
+import glob
+import importlib
+import os
+
+from benchmark.architectures.prenorm_decoder.test_reference import *  # noqa: F401,F403
+from benchmark.tests.test_architectures import *  # noqa: F401,F403
+from benchmark.tests.test_control_serve import *  # noqa: F401,F403
+from benchmark.tests.test_manifest import *  # noqa: F401,F403
+from benchmark.tests.test_trace_in_run import *  # noqa: F401,F403
+from benchmark.tests.test_trace_reduce import *  # noqa: F401,F403
+from benchmark.tests.test_traffic import *  # noqa: F401,F403
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+COLLECTED = (
+    "benchmark/architectures/prenorm_decoder/test_reference.py",
+    "benchmark/tests/test_architectures.py",
+    "benchmark/tests/test_control_serve.py",
+    "benchmark/tests/test_manifest.py",
+    "benchmark/tests/test_trace_in_run.py",
+    "benchmark/tests/test_trace_reduce.py",
+    "benchmark/tests/test_traffic.py",
+)
+
+
+def test_every_benchmark_test_file_is_collected_here():
+    """A new test_*.py under benchmark/ must be star-imported above (and
+    named in COLLECTED), and no test of one file may hide a test of
+    another behind the same name."""
+    on_disk = sorted(
+        os.path.relpath(p, REPO)
+        for p in glob.glob(
+            os.path.join(REPO, "benchmark", "**", "test_*.py"),
+            recursive=True,
+        )
+    )
+    assert on_disk == sorted(COLLECTED)
+    for path in COLLECTED:
+        mod = importlib.import_module(path[:-3].replace("/", "."))
+        for name, obj in vars(mod).items():
+            if name.startswith("test_") and callable(obj):
+                assert globals().get(name) is obj, (path, name)

@@ -24,7 +24,7 @@ from luminaai_tpu.monitoring.tracing import (
     NULL_TRACER,
     SpanTracer,
 )
-from tests.test_serving import FakeContinuousEngine, FakeStepper
+from tests.test_serving import FakeEngine, FakeStepper
 
 
 def _host_event_names(trace_dir):
@@ -251,14 +251,7 @@ class ClockedStepper(FakeStepper):
         self._phase("put", self.PUT, "decode.put")
         self._phase("dispatch", self.DISPATCH, "decode.dispatch")
         self._phase("device_wait", self.WAIT, "decode.fetch")
-        toks = np.zeros((self.num_slots,), np.int64)
-        produced = np.asarray(self._active, bool).copy()
-        for s in range(self.num_slots):
-            if self._active[s]:
-                toks[s] = self._next[s]
-                self._next[s] += 1
-        self.steps += 1
-        return toks, produced, np.zeros((self.num_slots,), bool)
+        return super().decode_step(sample_key)
 
 
 def _phase_counters(registry):
@@ -279,7 +272,7 @@ def test_tick_phase_counters_partition_the_scheduler_threads_time():
     registry = MetricsRegistry()
     stepper = ClockedStepper(clock, num_slots=2)
     sched = ContinuousScheduler(
-        FakeContinuousEngine(), decoder=stepper, registry=registry,
+        FakeEngine(), decoder=stepper, registry=registry,
         clock=clock,
     )
     assert stepper.phases is sched._phases  # the decoder got the ledger
@@ -316,7 +309,7 @@ def test_telemetry_off_switches_the_phase_ledger_off():
 
     registry = MetricsRegistry()
     sched = ContinuousScheduler(
-        FakeContinuousEngine(), decoder=FakeStepper(num_slots=2),
+        FakeEngine(), decoder=FakeStepper(num_slots=2),
         registry=registry, telemetry=False,
     )
     toks, _ = sched.submit([7], {"max_new_tokens": 2})

@@ -3,7 +3,7 @@
 The script itself only passes on a TPU; what is pinned here is that it
 cannot pass anywhere else, the shape of its result line, the rules it
 leans on (compile cache placed from outside, one peak table with no
-default, one chip for each fleet child, bench.py refusing a CPU), and —
+default, one chip for each fleet child), and —
 once, at a tiny size — that its phases still drive train -> checkpoint ->
 serve through the real entry points.
 """
@@ -18,7 +18,6 @@ import types
 import jax
 import pytest
 
-import bench
 import chip_smoke
 from luminaai_tpu import cli
 from luminaai_tpu.config import Config
@@ -270,14 +269,11 @@ def test_compile_cache_defaults_to_the_checkout(monkeypatch,
                                                 restore_cache_dir):
     """Unset: the fixed <checkout>/.jax_cache (the path is part of the
     cache key) — never tempfile, a pid or the time; and the same for
-    every caller (lumina train/serve, chip_smoke.py, the bench children)."""
+    every caller (lumina train/serve, chip_smoke.py, benchmark.run)."""
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
     want = os.path.join(REPO, ".jax_cache")
     assert environment.configure_compile_cache() == want
     assert jax.config.jax_compilation_cache_dir == want
-    import bench_common
-
-    assert bench_common.enable_compile_cache() == want
     with open(os.path.join(REPO, ".gitignore")) as f:
         assert ".jax_cache/" in f.read().split()
 
@@ -377,14 +373,3 @@ def test_wait_ready_without_procs_still_times_out():
 
     with pytest.raises(TimeoutError, match="never became ready"):
         wait_ready(["http://127.0.0.1:9"], timeout_s=0.2, poll_s=0.05)
-
-
-# -- bench.py: no chip, no number ---------------------------------------------------
-def test_bench_child_refuses_a_cpu(capsys):
-    """A real rung's child on the CPU exits non-zero before it builds
-    anything (the parent's side is tests/test_bench_contract.py)."""
-    with pytest.raises(SystemExit) as e:
-        bench._child_main("flagship_tuned")
-    assert e.value.code not in (0, None)
-    assert "needs a TPU" in str(e.value.code)
-    assert capsys.readouterr().out.strip() == ""

@@ -401,7 +401,7 @@ def test_goodput_off_switch(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# overhead (the sentinel A/B; performance_overhead.md row)
+# overhead (the sentinel A/B)
 # ---------------------------------------------------------------------------
 def test_ledger_and_beat_per_op_overhead_is_negligible():
     """Tier-1 microbench: the per-boundary cost is two clock reads + a
@@ -445,47 +445,6 @@ def test_watchdog_and_ledger_overhead_ab(tmp_path):
     dt_off = run("off", goodput=False, watchdog=False, step_anomaly=False)
     dt_on = run("on")
     assert dt_on < dt_off * 1.5 + 0.5, (dt_on, dt_off)
-
-
-# ---------------------------------------------------------------------------
-# capture rung (scripts/capture_multichip.py)
-# ---------------------------------------------------------------------------
-def test_capture_next_index_numbering(tmp_path):
-    import sys
-
-    sys.path.insert(
-        0, os.path.join(os.path.dirname(__file__), "..", "scripts")
-    )
-    from capture_multichip import next_capture_path
-
-    assert next_capture_path(str(tmp_path)).endswith("MULTICHIP_r01.json")
-    (tmp_path / "MULTICHIP_r07.json").write_text("{}")
-    assert next_capture_path(str(tmp_path)).endswith("MULTICHIP_r08.json")
-
-
-@pytest.mark.slow
-def test_capture_multichip_records_both_dcn_paths(tmp_path):
-    """The one-command ROADMAP item 3 capture: both probes' stage
-    timings land in one MULTICHIP_r*.json (simulated dcn on the 8-CPU
-    harness, flagged as such)."""
-    import sys
-
-    sys.path.insert(
-        0, os.path.join(os.path.dirname(__file__), "..", "scripts")
-    )
-    from capture_multichip import main as capture_main
-
-    out = str(tmp_path / "MULTICHIP_rXX.json")
-    rc = capture_main(["--out", out, "--payload-mb", "0.25",
-                       "--iters", "1", "--tag", "ci-cpu"])
-    assert rc == 0
-    rec = json.load(open(out))
-    assert rec["ok"] and rec["tag"] == "ci-cpu"
-    for path_name in ("expert_a2a", "grad_reduce"):
-        stages = rec[path_name]["stages"]
-        assert stages, rec[path_name]
-        assert any("mean_seconds" in v for v in stages.values()), stages
-        assert rec[path_name]["simulated_dcn"] is True
 
 
 def test_prefetch_loader_banks_replay_on_early_termination():

@@ -443,25 +443,6 @@ def test_chat_from_checkpoint(tmp_path):
     assert "messages: 1" in chat.handle_command("/stats")
 
 
-def test_generate_batch_matches_single_greedy(setup):
-    """Batched decode is vmap lanes of the single-sequence machinery:
-    under greedy sampling each row must reproduce the single-stream
-    output exactly (ragged prompt lengths included)."""
-    engine = setup[0]
-    prompts = [[5, 6, 7], [9, 10, 11, 12, 13, 14], [20]]
-    batch = engine.generate_batch(
-        prompts, temperature=0.0, max_new_tokens=8, seed=0
-    )
-    assert len(batch) == 3
-    for p, (toks, st) in zip(prompts, batch):
-        single, _ = engine.generate(
-            p, temperature=0.0, max_new_tokens=8, seed=0
-        )
-        assert toks == single, (p, toks, single)
-        assert st["batch_size"] == 3
-        assert st["prompt_tokens"] == len(p)
-
-
 def test_stepwise_decode_matches_generate(setup):
     """The continuous-batching step-wise API (prefill_into_slot +
     decode_step over the slot-paged pool) must reproduce generate()
@@ -572,8 +553,7 @@ def test_continuous_scheduler_matches_generate_and_reuses_slots(setup):
     """Acceptance: with more requests than slots and mixed budgets, the
     ContinuousScheduler (a) returns exactly generate()'s greedy tokens
     per request, and (b) admits a queued request into a finished lane's
-    slot BEFORE the longest request completes — the step-level admission
-    the legacy MicroBatcher structurally cannot do."""
+    slot BEFORE the longest request completes (step-level admission)."""
     import threading
 
     from luminaai_tpu.serving.server import ContinuousScheduler
@@ -620,15 +600,6 @@ def test_continuous_scheduler_matches_generate_and_reuses_slots(setup):
     late = max((r[1] for r in results), key=lambda s: s["admitted_step"])
     assert late["admitted_step"] > 0
     assert late["admitted_step"] < long_stats["finished_step"]
-
-
-def test_generate_batch_single_row_delegates(setup):
-    engine = setup[0]
-    out = engine.generate_batch([[7, 8, 9]], temperature=0.0,
-                                max_new_tokens=4, seed=0)
-    single, _ = engine.generate([7, 8, 9], temperature=0.0,
-                                max_new_tokens=4, seed=0)
-    assert out[0][0] == single
 
 
 @pytest.mark.parametrize("scan", [False, True])

@@ -3,8 +3,7 @@ forward/backward speed sanity + memory-leak detection; SURVEY §4).
 
 Speed bounds are deliberately loose — CPU CI boxes vary wildly — the
 point is catching order-of-magnitude regressions (accidental recompiles
-per step, O(S²) fallbacks) and buffer leaks, not micro-benchmarks
-(bench_ops.py owns those).
+per step, O(S²) fallbacks) and buffer leaks, not micro-benchmarks.
 """
 
 import time
@@ -167,9 +166,8 @@ def test_sort_and_gather_dispatch_not_costlier_than_einsum():
 def test_save_attn_removes_flash_fwd_from_backward():
     """The save_attn remat policy stores the flash (out, lse) residuals,
     so the backward must contain one fewer pallas call per layer than
-    save_outs (fwd + dq + dkv vs fwd + recomputed-fwd + dq + dkv) —
-    ~115ms/step at flagship scale (BENCHMARKS.md r3). Counting calls in
-    the jaxpr pins the mechanism without hardware."""
+    save_outs (fwd + dq + dkv vs fwd + recomputed-fwd + dq + dkv).
+    Counting calls in the jaxpr pins the mechanism without hardware."""
     import dataclasses
 
     base = Config(

@@ -739,34 +739,6 @@ def test_cli_route_rejects_duplicate_replicas(capsys):
     assert "duplicate" in capsys.readouterr().err
 
 
-# -- bench contract ---------------------------------------------------------
-
-@pytest.mark.faults
-def test_router_bench_smoke_contract(capsys):
-    """satellite 5: `bench.py --smoke-router` emits one JSON line whose
-    extras.router pins failover + breaker behavior for the CI CHECK."""
-    import bench
-
-    bench._router_bench_main(smoke=True)
-    out = capsys.readouterr().out.strip().splitlines()
-    doc = json.loads(out[-1])
-    assert doc["metric"] == "router_tokens_per_sec_2replica"
-    assert "error" not in doc
-    r = doc["extras"]["router"]
-    assert r["replicas"] == 2
-    assert r["failovers"] >= 1
-    assert r["post_kill_success_rate"] == 1.0
-    assert r["breaker_opened"] is True
-    assert r["routed_ok"] == r["routed_requests"]
-    # ISSUE 20: the shared-prefix rung prices cache-on vs cache-off.
-    ps = doc["extras"]["page_share"]
-    assert "error" not in ps, ps
-    assert ps["cross_replica_hit_rate"] > 0
-    assert ps["remote_hit_admissions"] >= 1 and ps["pull_failures"] == 0
-    assert ps["prefill_tokens_cache_on"] < ps["prefill_tokens_cache_off"]
-    assert ps["prefill_seconds_cache_off"] > 0
-
-
 # -- fleet page index (ISSUE 20: cross-replica page sharing) ----------------
 
 def test_page_report_registered_replicas_only_then_fifo_cap():

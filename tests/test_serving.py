@@ -1,5 +1,6 @@
 """HTTP serving surface (ref Dockerfile.backend Flask-on-:5001 contract)."""
 
+import itertools
 import json
 import threading
 import urllib.error
@@ -24,10 +25,76 @@ class FakeTokenizer:
         return "tok:" + ",".join(str(t) for t in tokens)
 
 
+class FakeStepper:
+    """Hermetic StepwiseDecoder double: deterministic token streams
+    (by default prompt[0], prompt[0]+1, ...; `lane_tokens(prompt)` gives
+    another, and a lane whose tokens run out reports eos) over a real
+    PagedKVPool's slot accounting, so scheduler logic (admission,
+    eviction, reuse ordering, cancellation) is testable without jax."""
+
+    def __init__(self, num_slots=2, slot_tokens=64, lane_tokens=None):
+        from luminaai_tpu.inference.kv_pool import PagedKVPool
+
+        self.num_slots = num_slots
+        self.slot_tokens = slot_tokens
+        self.pool = PagedKVPool(None, num_slots, 1, slot_tokens)
+        self.steps = 0
+        self.prefills = 0
+        self.lane_tokens = lane_tokens or (
+            lambda prompt: itertools.count(int(prompt[0]))
+        )
+        self._lanes = [None] * num_slots  # slot -> its tokens' iterator
+
+    def has_free_slot(self):
+        return self.pool.has_free()
+
+    def acquire_slot(self):
+        return self.pool.alloc()
+
+    def release_slot(self, slot):
+        self._lanes[slot] = None
+        self.pool.free(slot)
+
+    def lane_full(self, slot):
+        return False
+
+    def prefill_into_slot(self, slot, prompt, max_new_tokens=1,
+                          sample_key=None, seed=None):
+        self.prefills += 1
+        lane = iter(self.lane_tokens(prompt))
+        first = next(lane, None)
+        self._lanes[slot] = lane if max_new_tokens > 1 else None
+        self.pool.lengths[slot] = len(prompt)
+        return {"token": 0 if first is None else int(first),
+                "prompt_tokens": len(prompt), "is_stop": first is None}
+
+    def decode_step(self, sample_key=None):
+        import time as _time
+
+        import numpy as np
+
+        _time.sleep(0.01)  # a "device step": keeps admission ordering real
+        toks = np.zeros((self.num_slots,), np.int64)
+        eos = np.zeros((self.num_slots,), bool)
+        produced = np.zeros((self.num_slots,), bool)
+        for s, lane in enumerate(self._lanes):
+            if lane is None:
+                continue
+            nxt = next(lane, None)
+            if nxt is None:
+                eos[s] = True
+                self._lanes[s] = None
+            else:
+                toks[s], produced[s] = nxt, True
+        self.steps += 1
+        return toks, produced, eos
+
+
 class FakeEngine:
-    """Engine double mirroring GenerationEngine's contract: generate /
-    generate_batch map token ids -> (token ids, stats); encode_chat maps
-    messages -> prompt ids; .tokenizer does the text round-trip."""
+    """Engine double mirroring GenerationEngine's contract as ChatServer
+    uses it: make_stepwise hands the scheduler a FakeStepper whose lanes
+    play lane_tokens(prompt); encode_chat maps messages -> prompt ids;
+    .tokenizer does the text round-trip."""
 
     def __init__(self):
         self.config = Config(
@@ -35,27 +102,29 @@ class FakeEngine:
             num_kv_heads=2, seq_length=64, use_flash_attention=False,
         )
         self.tokenizer = FakeTokenizer()
-        self.batch_sizes = []
+        self.stepper = FakeStepper(
+            num_slots=2, lane_tokens=self.lane_tokens
+        )
 
-    def generate(self, prompt_tokens, **kw):
-        toks = list(prompt_tokens)[:3]
-        return toks, {"tokens_generated": len(toks), "stopped": "eos"}
+    def lane_tokens(self, prompt):
+        """What a request generates: its first three prompt ids, then
+        the lane ends (eos)."""
+        return list(prompt)[:3]
 
-    def generate_batch(self, prompts, **kw):
-        self.batch_sizes.append(len(prompts))
-        return [self.generate(p, **kw) for p in prompts]
+    def _resolve_gen_key(self, mnt, temp, top_p, top_k, rep):
+        return (
+            int(mnt or 8),
+            float(0.0 if temp is None else temp),
+            int(top_k or 0),
+            float(1.0 if top_p is None else top_p),
+            float(1.0 if rep is None else rep),
+        )
+
+    def make_stepwise(self, **kw):
+        return self.stepper
 
     def encode_chat(self, messages):
         return self.tokenizer.backend.encode(messages[-1]["content"])
-
-    def chat_response(self, messages):
-        reply, stats = self.generate(self.encode_chat(messages))
-        return self.tokenizer.decode(reply), stats
-
-    def generate_stream(self, prompt_tokens, **kw):
-        toks, stats = self.generate(prompt_tokens, **kw)
-        yield from toks
-        yield stats
 
 
 @pytest.fixture()
@@ -110,7 +179,7 @@ def test_generate_and_stats(server_url):
     assert code == 200 and body["text"].startswith("tok:")
     assert body["tokens"] == 3
     code, body = _post(url, "/v1/chat", {"message": "yo"})
-    # Chat rides the same batched path: encode_chat -> generate -> decode.
+    # Chat rides the same scheduler: encode_chat -> a lane -> decode.
     assert code == 200 and body["reply"] == "tok:121,111"
     code, body = _get(url, "/stats")
     assert body["requests"] == 2 and body["tokens_out"] == 5
@@ -240,10 +309,8 @@ def test_streaming_multibyte_delta_hold():
             super().__init__()
             self.tokenizer = ByteTokenizer()
 
-        def generate_stream(self, prompt_tokens, **kw):
-            out = list("héllo".encode())  # é = 2 bytes, split mid-stream
-            yield from out
-            yield {"tokens_generated": len(out), "stopped": "eos"}
+        def lane_tokens(self, prompt):
+            return list("héllo".encode())  # é = 2 bytes, split mid-stream
 
     srv = ChatServer(ByteEngine())
     events = list(srv._stream_events([1], {}, "text"))
@@ -261,8 +328,8 @@ def test_streaming_midflight_error_emits_error_frame(server_url):
     open stream body."""
 
     class ExplodingEngine(FakeEngine):
-        def generate_stream(self, prompt_tokens, **kw):
-            yield int(prompt_tokens[0])
+        def lane_tokens(self, prompt):
+            yield int(prompt[0])
             raise RuntimeError("device fell over")
 
     srv = ChatServer(ExplodingEngine())
@@ -283,32 +350,37 @@ def test_streaming_midflight_error_emits_error_frame(server_url):
         httpd.server_close()
 
 
-def test_stream_concurrency_cap():
-    """Streams bypass the MicroBatcher, so a slot semaphore caps them:
-    over the limit → 503; slots release on completion AND on a close
-    before the first event (the leak path)."""
-    import time as _time
+def test_speculative_stream_slot_cap():
+    """Speculative streams run outside the scheduler, so a slot
+    semaphore (max_streams) caps them: over the limit the hint is dropped
+    and the request is a scheduler lane, never a 503; slots release on
+    completion AND on a close before the first event (the leak path)."""
+    from luminaai_tpu.serving.server import _SlotStream
 
-    class SlowEngine(FakeEngine):
-        def generate_stream(self, prompt_tokens, **kw):
+    class SpecEngine(FakeEngine):
+        def generate_stream_speculative(self, prompt_tokens,
+                                        max_new_tokens=None,
+                                        timeout_s=None):
             yield 1
-            _time.sleep(0.5)
             yield 2
-            yield {"tokens_generated": 2, "stopped": "eos"}
+            yield {"tokens_generated": 2, "stopped": "eos",
+                   "verify_calls": 1, "tokens_per_verify": 2.0}
 
-    srv = ChatServer(SlowEngine(), max_streams=1)
-    err1, ev1 = srv.start_stream("/v1/generate", {"prompt": "a"}, None)
-    assert err1 is None
-    err2, ev2 = srv.start_stream("/v1/generate", {"prompt": "b"}, None)
-    assert err2 is not None and err2[0] == 503
+    srv = ChatServer(SpecEngine(), max_streams=1)
+    body = {"prompt": "a", "temperature": 0, "speculative": True}
+    err1, ev1 = srv.start_stream("/v1/generate", dict(body), None)
+    assert err1 is None and isinstance(ev1, _SlotStream)
+    err2, ev2 = srv.start_stream("/v1/generate", dict(body), None)
+    assert err2 is None and not isinstance(ev2, _SlotStream)
+    assert "speculative" not in list(ev2)[-1]
     # Closing BEFORE the first next() must still release the slot.
     ev1.close()
-    err3, ev3 = srv.start_stream("/v1/generate", {"prompt": "c"}, None)
-    assert err3 is None
+    err3, ev3 = srv.start_stream("/v1/generate", dict(body), None)
+    assert err3 is None and isinstance(ev3, _SlotStream)
     # Draining to exhaustion releases too.
-    list(ev3)
-    err4, ev4 = srv.start_stream("/v1/generate", {"prompt": "d"}, None)
-    assert err4 is None
+    assert list(ev3)[-1]["speculative"]["verify_calls"] == 1
+    err4, ev4 = srv.start_stream("/v1/generate", dict(body), None)
+    assert err4 is None and isinstance(ev4, _SlotStream)
     ev4.close()
 
 
@@ -331,10 +403,8 @@ def test_stream_tail_flush_on_done_frame():
             super().__init__()
             self.tokenizer = ByteTokenizer()
 
-        def generate_stream(self, prompt_tokens, **kw):
-            out = list("hé".encode())[:-1] + [0xC3]  # ends mid-codepoint
-            yield from out
-            yield {"tokens_generated": len(out), "stopped": "length"}
+        def lane_tokens(self, prompt):
+            return list("hé".encode())[:-1] + [0xC3]  # ends mid-codepoint
 
     srv = ChatServer(TruncatedEngine())
     events = list(srv._stream_events([1], {}, "text"))
@@ -347,16 +417,12 @@ def test_stream_tail_flush_on_done_frame():
 def test_speculative_request_path():
     """{"speculative": true} on a greedy request runs the engine's
     speculative path (stats surfaced); sampling requests silently fall
-    back to the batched path; engines without the method fall back."""
+    back to the scheduler; engines without the method fall back."""
 
     class SpecEngine(FakeEngine):
         def __init__(self):
             super().__init__()
             self.spec_calls = 0
-
-        def _resolve_gen_key(self, mnt, temp, top_p, top_k, rep):
-            return (int(mnt or 8), float(0.0 if temp is None else temp),
-                    0, 1.0, 1.0)
 
         def generate_speculative(self, prompt_tokens, max_new_tokens=None):
             self.spec_calls += 1
@@ -380,7 +446,7 @@ def test_speculative_request_path():
         assert code == 200 and eng.spec_calls == 1
         assert body["speculative"]["verify_calls"] == 2
         assert body["text"].startswith("tok:")
-        # Sampling + speculative: silently rides the batcher.
+        # Sampling + speculative: silently rides the scheduler.
         code, body = _post(url, "/v1/generate",
                            {"prompt": "hiya", "temperature": 0.7,
                             "speculative": True})
@@ -400,7 +466,7 @@ def test_speculative_request_path():
     )
     assert code == 200 and "speculative" not in body
 
-    # Slots exhausted: falls back to the batched path, never 503s — the
+    # Slots exhausted: falls back to the scheduler, never 503s — the
     # hint must not make a servable request fail.
     eng3 = SpecEngine()
     srv3 = ChatServer(eng3, max_streams=1)
@@ -425,25 +491,19 @@ def test_aborted_stream_still_counted():
     assert srv.tokens_out == 2
 
 
-def test_streaming_unsupported_engine():
-    eng = FakeEngine()
-    del type(eng).generate_stream  # class attr removal affects this type
-    try:
-        srv = ChatServer(eng)
-        httpd = ThreadingHTTPServer(("127.0.0.1", 0), srv.make_handler())
-        t = threading.Thread(target=httpd.serve_forever, daemon=True)
-        t.start()
-        url = f"http://127.0.0.1:{httpd.server_address[1]}"
-        code, body = _post(url, "/v1/generate",
-                           {"prompt": "x", "stream": True})
-        assert code == 501
-        httpd.shutdown()
-        httpd.server_close()
-    finally:
-        FakeEngine.generate_stream = _FAKE_STREAM_BACKUP
+def test_engine_without_stepwise_api_is_refused():
+    """ChatServer has one scheduler: an engine that cannot hand it a
+    step-wise decoder is refused at construction, by name."""
 
+    class RunToCompletionEngine:
+        config = FakeEngine().config
+        tokenizer = FakeTokenizer()
 
-_FAKE_STREAM_BACKUP = FakeEngine.generate_stream
+        def generate(self, prompt_tokens, **kw):
+            return list(prompt_tokens), {"stopped": "eos"}
+
+    with pytest.raises(TypeError, match="make_stepwise.*RunToCompletion"):
+        ChatServer(RunToCompletionEngine())
 
 
 def test_override_clamps(server_url):
@@ -469,118 +529,7 @@ def test_malformed_chat_messages(server_url):
     assert code == 400 and "role" in body["error"]
 
 
-def test_concurrent_requests_ride_one_batch():
-    """N clients in flight together must be served by batched decode
-    (MicroBatcher groups same-param requests within the window)."""
-    srv = ChatServer(FakeEngine(), batch_window_ms=300, max_batch=8)
-    httpd = ThreadingHTTPServer(("127.0.0.1", 0), srv.make_handler())
-    t = threading.Thread(target=httpd.serve_forever, daemon=True)
-    t.start()
-    url = f"http://127.0.0.1:{httpd.server_address[1]}"
-    try:
-        codes = []
-        lock = threading.Lock()
-
-        def hit(i):
-            code, body = _post(url, "/v1/generate", {"prompt": f"hey{i}"})
-            with lock:
-                codes.append(code)
-
-        threads = [
-            threading.Thread(target=hit, args=(i,)) for i in range(6)
-        ]
-        for th in threads:
-            th.start()
-        for th in threads:
-            th.join()
-        assert codes == [200] * 6
-        assert max(srv.engine.batch_sizes, default=1) >= 2, (
-            srv.engine.batch_sizes
-        )
-        _, stats = _get(url, "/stats")
-        assert stats["max_batch_seen"] >= 2
-        assert stats["requests"] == 6
-    finally:
-        httpd.shutdown()
-        httpd.server_close()
-
-
 # -- continuous batching ---------------------------------------------------
-class FakeStepper:
-    """Hermetic StepwiseDecoder double: deterministic token streams
-    (prompt[0], prompt[0]+1, ...) over a real PagedKVPool's slot
-    accounting, so scheduler logic (admission, eviction, reuse ordering,
-    cancellation) is testable without jax."""
-
-    def __init__(self, num_slots=2, slot_tokens=64):
-        from luminaai_tpu.inference.kv_pool import PagedKVPool
-
-        self.num_slots = num_slots
-        self.slot_tokens = slot_tokens
-        self.pool = PagedKVPool(None, num_slots, 1, slot_tokens)
-        self.steps = 0
-        self._active = [False] * num_slots
-        self._next = [0] * num_slots
-
-    def has_free_slot(self):
-        return self.pool.has_free()
-
-    def acquire_slot(self):
-        return self.pool.alloc()
-
-    def release_slot(self, slot):
-        self._active[slot] = False
-        self.pool.free(slot)
-
-    def lane_full(self, slot):
-        return False
-
-    def prefill_into_slot(self, slot, prompt, max_new_tokens=1,
-                          sample_key=None, seed=None):
-        first = int(prompt[0])
-        self._active[slot] = max_new_tokens > 1
-        self._next[slot] = first + 1
-        self.pool.lengths[slot] = len(prompt)
-        return {"token": first, "prompt_tokens": len(prompt),
-                "is_stop": False}
-
-    def decode_step(self, sample_key=None):
-        import time as _time
-
-        import numpy as np
-
-        _time.sleep(0.01)  # a "device step": keeps admission ordering real
-        toks = np.zeros((self.num_slots,), np.int64)
-        eos = np.zeros((self.num_slots,), bool)
-        produced = np.asarray(self._active, bool).copy()
-        for s in range(self.num_slots):
-            if self._active[s]:
-                toks[s] = self._next[s]
-                self._next[s] += 1
-        self.steps += 1
-        return toks, produced, eos
-
-
-class FakeContinuousEngine(FakeEngine):
-    """FakeEngine + the step-wise API surface ChatServer auto-detects."""
-
-    def __init__(self):
-        super().__init__()
-        self.stepper = FakeStepper(num_slots=2)
-
-    def _resolve_gen_key(self, mnt, temp, top_p, top_k, rep):
-        return (
-            int(mnt or 3),
-            float(0.0 if temp is None else temp),
-            int(top_k or 0),
-            float(1.0 if top_p is None else top_p),
-            float(1.0 if rep is None else rep),
-        )
-
-    def make_stepwise(self, **kw):
-        return self.stepper
-
-
 def test_paged_pool_free_list_never_double_allocates():
     """The slot free-list is the continuous scheduler's safety invariant:
     exhaustion raises (never hands out a live slot), free() of a
@@ -615,7 +564,7 @@ def test_continuous_scheduler_admits_mid_decode():
     from luminaai_tpu.serving.server import ContinuousScheduler
 
     stepper = FakeStepper(num_slots=2)
-    sched = ContinuousScheduler(FakeContinuousEngine(), decoder=stepper)
+    sched = ContinuousScheduler(FakeEngine(), decoder=stepper)
     results = {}
     lock = threading.Lock()
 
@@ -655,7 +604,7 @@ def test_continuous_scheduler_switches_sampling_keys():
     from luminaai_tpu.serving.server import ContinuousScheduler
 
     sched = ContinuousScheduler(
-        FakeContinuousEngine(), decoder=FakeStepper(num_slots=2)
+        FakeEngine(), decoder=FakeStepper(num_slots=2)
     )
     results = []
     lock = threading.Lock()
@@ -685,7 +634,7 @@ def test_continuous_stream_cancel_frees_slot():
     from luminaai_tpu.serving.server import ContinuousScheduler
 
     stepper = FakeStepper(num_slots=1)
-    sched = ContinuousScheduler(FakeContinuousEngine(), decoder=stepper)
+    sched = ContinuousScheduler(FakeEngine(), decoder=stepper)
     gen = sched.submit_stream([70], {"max_new_tokens": 10_000})
     assert next(gen) == 70
     gen.close()
@@ -701,11 +650,12 @@ def test_continuous_stream_cancel_frees_slot():
 
 
 def test_continuous_server_http_end_to_end():
-    """ChatServer auto-detects the step-wise engine API: generation and
-    SSE ride the continuous scheduler, /stats reports it."""
-    eng = FakeContinuousEngine()
-    srv = ChatServer(eng)
-    assert srv.continuous
+    """Generation and SSE ride the continuous scheduler, /stats reports
+    it."""
+    from luminaai_tpu.serving.server import ContinuousScheduler
+
+    srv = ChatServer(FakeEngine())
+    assert isinstance(srv.batcher, ContinuousScheduler)
     httpd = ThreadingHTTPServer(("127.0.0.1", 0), srv.make_handler())
     t = threading.Thread(target=httpd.serve_forever, daemon=True)
     t.start()
@@ -737,21 +687,10 @@ def test_continuous_server_http_end_to_end():
         httpd.server_close()
 
 
-def test_legacy_engine_falls_back_to_micro_batcher():
-    """Engines without the step-wise API keep the MicroBatcher path, and
-    continuous=False forces it even when the API exists."""
-    from luminaai_tpu.serving.server import MicroBatcher
-
-    srv = ChatServer(FakeEngine())
-    assert not srv.continuous and isinstance(srv.batcher, MicroBatcher)
-    srv2 = ChatServer(FakeContinuousEngine(), continuous=False)
-    assert not srv2.continuous and isinstance(srv2.batcher, MicroBatcher)
-
-
 def test_mismatched_params_requeue_not_starve():
-    """Requests with different sampling params fall into separate batches
-    but all complete."""
-    srv = ChatServer(FakeEngine(), batch_window_ms=100, max_batch=8)
+    """Requests with different sampling params fall into separate
+    generations but all complete."""
+    srv = ChatServer(FakeEngine())
     httpd = ThreadingHTTPServer(("127.0.0.1", 0), srv.make_handler())
     t = threading.Thread(target=httpd.serve_forever, daemon=True)
     t.start()
@@ -786,7 +725,7 @@ def test_healthz_warming_then_ready():
     """/healthz is the READINESS probe: 503 while the engine is
     compiling/warming (so the Dockerfile HEALTHCHECK holds traffic),
     200 with scheduler state once serving."""
-    srv = ChatServer(FakeContinuousEngine())
+    srv = ChatServer(FakeEngine())
     httpd = ThreadingHTTPServer(("127.0.0.1", 0), srv.make_handler())
     t = threading.Thread(target=httpd.serve_forever, daemon=True)
     t.start()
@@ -821,7 +760,7 @@ def test_healthz_warmup_flow_marks_ready():
     batcher path in the background, and flips the gate when it completes."""
     import time as _time
 
-    srv = ChatServer(FakeContinuousEngine(), warmup=True)
+    srv = ChatServer(FakeEngine(), warmup=True)
     assert srv._ready.wait(timeout=10), "warmup never marked ready"
     assert srv.batcher.requests_served >= 1  # warmup used the real path
     code, body = srv.handle("GET", "/healthz", {}, None)
@@ -838,7 +777,7 @@ def test_healthz_warmup_failure_still_serves():
         def prefill_into_slot(self, *a, **kw):
             raise RuntimeError("compile exploded")
 
-    eng = FakeContinuousEngine()
+    eng = FakeEngine()
     eng.stepper = BrokenPrefill(num_slots=2)
     srv = ChatServer(eng, warmup=True)
     assert srv._ready.wait(timeout=10)
@@ -847,11 +786,11 @@ def test_healthz_warmup_failure_still_serves():
     assert "compile exploded" in body.get("warmup_error", "")
 
 
-def test_healthz_micro_batcher_state():
+def test_healthz_scheduler_state():
     srv = ChatServer(FakeEngine())
     code, body = srv.handle("GET", "/healthz", {}, None)
     assert code == 200
-    assert body["scheduler"] == "micro_batch"
+    assert body["scheduler"] == "continuous"
     assert body["queue_depth"] == 0
 
 
@@ -866,7 +805,7 @@ def test_metrics_endpoint_round_trips_and_covers_serving():
     from prom_parser import check_histogram_wellformed, parse_prometheus_text
 
     registry = MetricsRegistry()
-    srv = ChatServer(FakeContinuousEngine(), registry=registry)
+    srv = ChatServer(FakeEngine(), registry=registry)
     # Training flows into the SAME registry (the unified-sink contract).
     monitor = TrainingHealthMonitor(registry=registry)
     monitor.log_step(5, {"loss": 2.0, "grad_norm": 0.5})
@@ -940,7 +879,7 @@ def test_decode_parity_with_telemetry_on_off():
     outs = {}
     for on in (True, False):
         sched = ContinuousScheduler(
-            FakeContinuousEngine(),
+            FakeEngine(),
             decoder=FakeStepper(num_slots=2),
             registry=MetricsRegistry(),
             telemetry=on,
@@ -996,7 +935,7 @@ def test_telemetry_overhead_within_budget():
 
     def run_once(telemetry_on):
         sched = ContinuousScheduler(
-            FakeContinuousEngine(),
+            FakeEngine(),
             decoder=FastStepper(num_slots=4),
             registry=MetricsRegistry(),
             telemetry=telemetry_on,
@@ -1027,24 +966,14 @@ def test_speculative_stream_path():
     """{"speculative": true} on an SSE request composes the draft/verify
     loop with the streaming contract (VERDICT r5 #5 slice): greedy
     streams ride generate_stream_speculative (done frame carries the
-    acceptance stats), sampled streams silently use the plain stream,
-    slot exhaustion falls back rather than failing, and the slot
+    acceptance stats), sampled streams silently use the scheduler's
+    stream, slot exhaustion falls back rather than failing, and the slot
     releases on drain."""
 
     class SpecStreamEngine(FakeEngine):
         def __init__(self):
             super().__init__()
             self.spec_streams = 0
-            self.plain_streams = 0
-
-        def _resolve_gen_key(self, mnt, temp, top_p, top_k, rep):
-            return (int(mnt or 8), float(0.0 if temp is None else temp),
-                    0, 1.0, 1.0)
-
-        def generate_stream(self, prompt_tokens, **kw):
-            self.plain_streams += 1
-            yield from (1, 2, 3)
-            yield {"tokens_generated": 3, "stopped": "length"}
 
         def generate_stream_speculative(self, prompt_tokens,
                                         max_new_tokens=None,
@@ -1065,7 +994,7 @@ def test_speculative_stream_path():
     )
     assert err is None
     events = list(ev)
-    assert eng.spec_streams == 1 and eng.plain_streams == 0
+    assert eng.spec_streams == 1 and eng.stepper.prefills == 0
     assert [e["token"] for e in events[:-1]] == [1, 2, 3]
     done = events[-1]
     assert done["done"] and done["stopped"] == "eos"
@@ -1081,7 +1010,7 @@ def test_speculative_stream_path():
     list(ev)
     assert eng.spec_streams == 2
 
-    # Sampled + speculative: silently the plain stream (hint ignored).
+    # Sampled + speculative: silently a scheduler lane (hint ignored).
     err, ev = srv.start_stream(
         "/v1/generate",
         {"prompt": "abcabc", "temperature": 0.7, "speculative": True},
@@ -1089,23 +1018,20 @@ def test_speculative_stream_path():
     )
     assert err is None
     events = list(ev)
-    assert eng.plain_streams == 1 and eng.spec_streams == 2
+    assert eng.stepper.prefills == 1 and eng.spec_streams == 2
     assert "speculative" not in events[-1]
 
-    # Slot hogged: the hint falls back to the plain stream, never 503s
-    # for a request the normal path could serve (legacy mode also caps
-    # plain streams by the same semaphore, so this would 503 — but the
-    # SPECULATIVE branch itself must not consume the last slot).
+    # Slot hogged: the hint falls back to the scheduler's stream, never
+    # 503s for a request the normal path can serve.
     assert srv._stream_slots.acquire(blocking=False)
     err, ev = srv.start_stream(
         "/v1/generate",
         {"prompt": "abcabc", "temperature": 0, "speculative": True},
         None,
     )
-    # Legacy mode still needs a slot for the plain stream -> 503 here is
-    # the pre-existing cap behavior, not a speculative failure.
-    assert err is not None and err[0] == 503
-    assert eng.spec_streams == 2
+    assert err is None
+    assert "speculative" not in list(ev)[-1]
+    assert eng.spec_streams == 2 and eng.stepper.prefills == 2
     srv._stream_slots.release()
 
 
@@ -1286,7 +1212,7 @@ def test_wrr_dequeue_interleaves_tenants():
     from luminaai_tpu.serving.server import ContinuousScheduler
 
     sched = ContinuousScheduler(
-        FakeContinuousEngine(), decoder=FakeStepper(num_slots=2),
+        FakeEngine(), decoder=FakeStepper(num_slots=2),
         tenant_weights={"vip": 2},
     )
     # The worker thread is parked in q.get(); the tenant queues are
@@ -1328,7 +1254,7 @@ def test_fair_share_keeps_starved_tenant_draining():
     from luminaai_tpu.serving.server import ContinuousScheduler
 
     sched = ContinuousScheduler(
-        FakeContinuousEngine(), decoder=FakeStepper(num_slots=1)
+        FakeEngine(), decoder=FakeStepper(num_slots=1)
     )
     done = []
     lock = threading.Lock()
@@ -1399,46 +1325,3 @@ def test_secure_gate_limiter_keys_are_hashed_tenants():
     assert keys, "limiter recorded nothing"
     assert all(ident == tenant_hash("alice") for ident, _ in keys)
     assert all(ident != "alice" for ident, _ in keys)
-
-
-def test_microbatcher_fallback_tenant_accounting_parity():
-    """Satellite: identity riders thread through MicroBatcher.submit —
-    per-tenant /metrics series and lifecycle events match the
-    continuous path for the same workload."""
-    from luminaai_tpu.monitoring.events import FlightRecorder
-    from luminaai_tpu.monitoring.telemetry import MetricsRegistry
-
-    workload = [{"prompt": "hello"}, {"prompt": "worlds"}]
-
-    def run(engine, continuous):
-        reg = MetricsRegistry()
-        rec = FlightRecorder(capacity=256)
-        srv = ChatServer(
-            engine, continuous=continuous, registry=reg, recorder=rec
-        )
-        for body in workload:
-            code, payload = srv.handle(
-                "POST", "/v1/generate", dict(body), None
-            )
-            assert code == 200
-            assert payload["request_id"]
-            assert payload["tenant"] == "anon"
-        snap = reg.snapshot()
-        return {
-            "requests": snap["tenant_requests_total"].get("tenant=anon"),
-            "tokens_in": snap["tenant_tokens_in_total"].get("tenant=anon"),
-            "tokens_out": snap["tenant_tokens_out_total"].get(
-                "tenant=anon"
-            ),
-        }, rec
-
-    cont, _ = run(FakeContinuousEngine(), True)
-    legacy, rec = run(FakeEngine(), False)
-    assert cont == legacy
-    # The fallback path emits the same lifecycle spine, tagged with its
-    # scheduler (riders stripped in submit, never reaching the engine).
-    admitted = rec.snapshot(type="request_admitted")
-    completed = rec.snapshot(type="request_completed")
-    assert len(admitted) == 2 and len(completed) == 2
-    assert all(e["scheduler"] == "micro_batch" for e in admitted)
-    assert all(e.get("tenant") == "anon" for e in completed)
