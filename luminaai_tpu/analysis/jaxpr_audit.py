@@ -761,8 +761,11 @@ def _decode_variants(cfg) -> List[Dict[str, Any]]:
         )
     )
 
-    # Batched cache_index decode: the continuous-batching step over the
-    # slot-paged pool (cache_index is a [slots] vector).
+    # Batched cache_index decode: the continuous-batching TICK over the
+    # slot-paged pool (one row a lane plus the rows of one prefill chunk;
+    # cache_index is a [slots + chunk] vector). A served prompt's chunks
+    # ride this executable: the serving path has no chunk program, so the
+    # prefill scenarios above are the single-stream generate()'s.
     decoder = engine.make_stepwise(num_slots=2, page_size=16)
     fn, args = decoder.step_fn_and_args()
     abstract_args = jax.tree.map(

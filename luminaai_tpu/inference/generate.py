@@ -911,7 +911,14 @@ class StepwiseDecoder:
         dispatch of step N+1 BEFORE the collect of step N, so the
         device always has its next program queued while the host reads
         and works on the previous one (docs/serving.md "The scheduler
-        loop").
+        loop");
+      start_prefill(slot, prompt, ...) begins a CHUNKED prefill of a
+        longer prompt: dispatch_step(key, chunk=state) carries its next
+        `prefill_chunk` rows in the SAME forward pass as the lanes (one
+        program a tick, _get_step), and the tick that carries the last
+        chunk samples the first token on the device. advance_prefill()
+        is that tick with no lane stepped, for callers that own their
+        loop.
 
     Greedy step-wise decode is token-identical to generate() (same
     prefill bucketing, same sampling math, same rng split discipline —
@@ -920,9 +927,11 @@ class StepwiseDecoder:
     bounds prompt+max_new to the slot capacity, so positions never wrap,
     and attention_window configs are served by the per-lane band mask.
 
-    One decode-step compile per sampling parameter set (max_new is host
-    state now, NOT part of the compile key — mixed-length workloads share
-    one executable, the core of the continuous-batching win).
+    One tick-program compile per sampling parameter set and page extent
+    (max_new is host state, NOT part of the compile key — mixed-length
+    workloads share one executable, the core of the continuous-batching
+    win — and so is whether a chunk is pending: an empty chunk is
+    padding rows).
     """
 
     # In-flight dedup safety bound: a parked follower proceeds cold
@@ -1016,6 +1025,10 @@ class StepwiseDecoder:
         # stop token, a cancel, an eviction) while the step was in
         # flight (_drop_ahead).
         self.lane_steps_dropped = 0
+        # Prefill chunks that rode a tick in which a lane was stepped,
+        # and the live prompt rows all chunks carried.
+        self.chunks_carried = 0
+        self.chunk_rows = 0
         self._fns: Dict[Any, Any] = {}
         # Serving attention backend (config.attention_backend): 'dense'
         # keeps the legacy full-extent per-lane mask; the ragged backends
@@ -1141,11 +1154,12 @@ class StepwiseDecoder:
         # Steps dispatched and not yet collected, oldest first, each
         # with the lanes it stepped. The next step's token input is
         # the newest step's device output (`_nxt_dev`), except for
-        # lanes whose token the host set since (`_host_tok`: a lane
-        # _finish_prefill just activated). `_budget` is the number of
-        # decode steps each lane's request still allows (max_new - 1 at
-        # activation): with a step in flight it is how the host knows,
-        # without reading that step, which lanes it ends.
+        # lanes whose token the host set since (`_host_tok`: a lane the
+        # whole-prompt path's _finish_prefill just activated; a chunked
+        # prompt's first token is already in that output). `_budget` is
+        # the number of decode steps each lane's request still allows
+        # (max_new - 1 at activation): with a step in flight it is how
+        # the host knows, without reading that step, which lanes it ends.
         self._inflight: collections.deque = collections.deque()
         self._budget = np.zeros((S,), np.int32)
         self._host_tok = np.ones((S,), bool)
@@ -1356,37 +1370,70 @@ class StepwiseDecoder:
         return min(p, self.pool.pages) * ps
 
     def _get_step(self, sample_key, extent: Optional[int] = None):
+        """The TICK program, one executable per (sample key, backend,
+        extent, table kind): one forward pass over `num_slots` decode
+        rows AND the `prefill_chunk` rows of one prompt being prefilled,
+        so a tick that admits a chunk streams every weight once, not
+        once for the step and once for the chunk. A tick with no chunk
+        pending runs the same program with the chunk's rows as padding
+        (position -1: they write nothing and their output is unread):
+        no second shape exists for a warm-up to miss."""
         use_global = self.prefix_cache is not None
         key = ("step", sample_key, self.backend, extent, use_global)
         if key not in self._fns:
+            from luminaai_tpu.models.layers import Embedder
+
             temperature, top_k, top_p, rep_penalty = sample_key
             stop_ids = jnp.asarray(
                 sorted(self.engine._stop_set), dtype=jnp.int32
             )
             S = self.num_slots
+            n = self.prefill_chunk
             backend = self.backend
             window = getattr(self.engine.config, "attention_window", None)
             page_size = self.pool.page_size
+            lm_head = Embedder(self.model.config, dtype=self.model.dtype)
 
-            def step(params, caches, prev_nxt, lanes, counts, rngs, table):
-                # `lanes` is everything the host sends a step, one
-                # [4, S] int32 transfer (_pack_lanes): the write row,
-                # the lanes to step, and the token of each lane the host
-                # set since the last step. Every other lane's token is
-                # the previous step's output, which never left the
-                # device.
-                pos = lanes[0]
-                active = lanes[1] != 0
-                tokens = jnp.where(lanes[2] != 0, lanes[3], prev_nxt)
-                flat = self._flat(caches)
-                split2 = jax.vmap(lambda r: jax.random.split(r, 2))(rngs)
-                new_rngs, step_rngs = split2[:, 0], split2[:, 1]
+            def sample(rng, logits, counts):
+                return sample_token(
+                    rng, logits, counts,
+                    temperature=temperature, top_k=top_k, top_p=top_p,
+                    repetition_penalty=rep_penalty,
+                ).astype(jnp.int32)
+
+            def step(params, caches, prev_nxt, tick, counts, rngs, table):
+                # `tick` is everything the host sends, one int32
+                # transfer (_pack_tick): per lane the write row, whether
+                # it is stepped, and the token of each lane the host set
+                # since the last step (every other lane's token is the
+                # previous step's output, which never left the device);
+                # then the chunk: its slot, first row, the prompt's
+                # length, whether it is the prompt's last, the request's
+                # seed, and its token ids.
                 from luminaai_tpu.ops.ragged_paged_attention import (
                     LaneMeta,
                 )
 
+                lanes = tick[: 4 * S].reshape(4, S)
+                pos = lanes[0]
+                active = lanes[1] != 0
+                tokens = jnp.where(lanes[2] != 0, lanes[3], prev_nxt)
+                c_slot, c_start, c_len, c_last, c_seed = (
+                    tick[4 * S + i] for i in range(5)
+                )
+                c_pos = c_start + jnp.arange(n)
+                c_pos = jnp.where(c_pos < c_len, c_pos, -1)
+                chunk = dict(
+                    chunk_rows=n, chunk_slot=c_slot, chunk_start=c_start
+                ) if n else {}
+                flat = self._flat(caches)
+                split2 = jax.vmap(lambda r: jax.random.split(r, 2))(rngs)
+                # Only a stepped lane's stream moves on: a tick that
+                # steps no lane (a chunk alone) leaves every rng as it is.
+                new_rngs = jnp.where(active[:, None], split2[:, 0], rngs)
+                step_rngs = split2[:, 1]
                 if backend == "dense":
-                    meta = LaneMeta(lengths=None, backend="dense")
+                    meta = LaneMeta(lengths=None, backend="dense", **chunk)
                 else:
                     # lengths INCLUDE the row this step writes (pos);
                     # 0 marks lanes with nothing attendable (free or
@@ -1409,23 +1456,37 @@ class StepwiseDecoder:
                         backend=backend,
                         identity_pages=not use_global,
                         global_pages=use_global,
+                        **chunk,
                     )
-                logits, flat, _ = self.model.apply(
+                # One token a row: S lanes, then the chunk's rows. A row
+                # at position -1 writes no K/V: a lane not stepped (a
+                # slot being prefilled among them) and the chunk's
+                # padding.
+                hidden, flat, _ = self.model.apply(
                     {"params": params},
-                    tokens[:, None],
-                    positions=pos[:, None],
+                    jnp.concatenate([tokens, tick[4 * S + 5:]])[:, None],
+                    positions=jnp.concatenate(
+                        [jnp.where(active, pos, -1), c_pos]
+                    )[:, None],
                     kv_caches=flat,
-                    cache_index=pos,  # [S]: per-lane offsets
+                    cache_index=jnp.concatenate([pos, c_pos]),
                     deterministic=True,
                     lane_meta=meta,
+                    return_hidden=True,
                 )
-                nxt = jax.vmap(
-                    lambda r, l, c: sample_token(
-                        r, l, c,
-                        temperature=temperature, top_k=top_k, top_p=top_p,
-                        repetition_penalty=rep_penalty,
-                    )
-                )(step_rngs, logits[:, -1], counts).astype(jnp.int32)
+                # The LM head runs over the rows a token is sampled
+                # from: the lanes, and the chunk's last live row.
+                rows = hidden[:S]
+                if n:
+                    last = S + jnp.clip(c_len - 1 - c_start, 0, n - 1)
+                    rows = jnp.concatenate([
+                        rows,
+                        jax.lax.dynamic_slice_in_dim(hidden, last, 1, 0),
+                    ])
+                logits = lm_head.apply(
+                    {"params": params["embedder"]}, rows, method="decode"
+                )[:, 0]
+                nxt = jax.vmap(sample)(step_rngs, logits[:S], counts)
                 nxt = jnp.where(active, nxt, tokens)
                 counts = counts.at[jnp.arange(S), nxt].add(
                     active.astype(counts.dtype)
@@ -1434,10 +1495,36 @@ class StepwiseDecoder:
                     active,
                     jnp.any(nxt[:, None] == stop_ids[None, :], axis=1),
                 )
+                if n:
+                    # The prompt's last chunk: its first token, sampled
+                    # as _finish_prefill samples it (the same key
+                    # derivation from the request's seed, empty counts),
+                    # lands in nxt[slot] for the next step to read, and
+                    # the slot's counts and rng start over.
+                    is_last = c_last != 0
+                    rng, first_rng = jax.random.split(
+                        jax.random.PRNGKey(c_seed)
+                    )
+                    first = sample(
+                        first_rng, logits[S], jnp.zeros_like(counts[0])
+                    )
+                    nxt = jnp.where(
+                        jnp.logical_and(is_last, jnp.arange(S) == c_slot),
+                        first, nxt,
+                    )
+                    fresh = jnp.zeros_like(counts[0]).at[first].add(
+                        1 - jnp.any(first == stop_ids).astype(counts.dtype)
+                    )
+                    counts = counts.at[c_slot].set(
+                        jnp.where(is_last, fresh, counts[c_slot])
+                    )
+                    new_rngs = new_rngs.at[c_slot].set(
+                        jnp.where(is_last, rng, new_rngs[c_slot])
+                    )
                 return self._paged(flat), nxt, eos, counts, new_rngs
 
             # Everything the step rewrites is donated (the pool, the
-            # repetition counts, the lane rngs): the one-row scatter
+            # repetition counts, the lane rngs): the scatter of its rows
             # lands in place instead of in a copy of the pool. A call
             # that fails after the runtime took the buffers leaves them
             # deleted; recover_pool() is what the caller does about it.
@@ -1491,10 +1578,12 @@ class StepwiseDecoder:
                                     seed)
 
     def _finish_prefill(self, slot, logits, L, max_new, sample_key, seed):
-        """Shared prompt-KV-written → lane-activated tail: sample token
-        #1, set the host lane state, return prefill_into_slot's info
-        contract. Used by the whole-prompt path above and by the final
-        chunk of a chunked prefill."""
+        """The whole-prompt path's prompt-KV-written → lane-activated
+        tail: sample token #1 (a device sync), set the host lane state,
+        return prefill_into_slot's info contract. A chunked prefill's
+        first token is sampled inside the tick program that carries its
+        last chunk (_get_step), with this key derivation, and read with
+        that step (_first_token)."""
         with self.tracer.span("prefill.sample", slot=slot):
             rng = jax.random.PRNGKey(
                 seed if seed is not None else (time.time_ns() & 0xFFFFFFFF)
@@ -1531,62 +1620,6 @@ class StepwiseDecoder:
         }
 
     # -- chunked prefill (scheduler-interleaved admission) -----------------
-    def _get_chunk_prefill(self):
-        """One fixed-shape prefill step writing `prefill_chunk` rows of
-        one lane DIRECTLY into the pool slot (no fresh-cache + insert):
-        slice the lane off the slot axis, run the per-lane multi-row
-        path at absolute positions, land the updated lane back. ONE
-        executable for every prompt length; the scheduler interleaves
-        these calls with decode steps so a long admission stalls the
-        decode batch for at most ~one chunk's step time."""
-        key = "chunk_prefill"
-        if key not in self._fns:
-            engine = self.engine
-            chunk = self.prefill_chunk
-            hint = engine._lane_hint()
-
-            def chunk_fn(params, pool_caches, ids, slot, start, length):
-                def lane_of(p):
-                    return jax.lax.dynamic_slice_in_dim(
-                        p, slot, 1, axis=p.ndim - 5
-                    )
-
-                lane = jax.tree.map(lane_of, pool_caches)
-                flat = self._flat(lane)
-                pos = start + jnp.arange(chunk)
-                positions = jnp.where(pos < length, pos, -1)[None, :]
-                logits, flat, _ = engine.model.apply(
-                    {"params": params},
-                    ids,
-                    positions=positions,
-                    kv_caches=flat,
-                    # [1]-shaped start offset selects the per-lane
-                    # multi-row path: rows land at absolute positions,
-                    # -1-marked padding drops into the dummy row.
-                    cache_index=jnp.reshape(start, (1,)),
-                    deterministic=True,
-                    lane_meta=hint,
-                )
-                last_idx = jnp.clip(length - 1 - start, 0, chunk - 1)
-                last = jnp.take_along_axis(
-                    logits, last_idx[None, None, None], axis=1
-                )[:, 0, :]
-                paged_lane = self._paged(flat)
-
-                def put(p, fresh):
-                    starts = [0] * p.ndim
-                    starts[p.ndim - 5] = slot
-                    return jax.lax.dynamic_update_slice(
-                        p, fresh, tuple(starts)
-                    )
-
-                return last, jax.tree.map(put, pool_caches, paged_lane)
-
-            # The pool is donated (as in the decode step): the lane
-            # lands back in place.
-            self._fns[key] = jax.jit(chunk_fn, donate_argnums=(1,))
-        return self._fns[key]
-
     def start_prefill(
         self,
         slot: int,
@@ -1776,14 +1809,9 @@ class StepwiseDecoder:
             client.end_pull()
 
     def _park_lane(self, slot: int, rows: int) -> None:
-        """Interleaved decode steps still write one (garbage) row at
-        _pos for every lane, active or not; park the mid-prefill
-        lane's write row at the slot's LAST row — admission bounds
-        prompts to token_capacity - 1, so no chunk writes it, and a
-        lane that eventually decodes there overwrites it before its
-        mask first admits it. (The last row is always a PRIVATE page:
-        splices cover at most (L-1)//ps full pages.)"""
-        self._pos[slot] = self.slot_tokens - 1
+        """A slot being prefilled is no lane: it is not stepped, and a
+        row that is not stepped writes nothing (_get_step). `rows`: what
+        is resident so far (a spliced prefix)."""
         self._active[slot] = False
         self.pool.lengths[slot] = rows
 
@@ -1830,25 +1858,14 @@ class StepwiseDecoder:
         )
         st.pop("waiting", None)
 
-    def advance_prefill(
-        self, st: Dict[str, Any]
-    ) -> Optional[Dict[str, Any]]:
-        """Run ONE prefill chunk (one jit call). Returns None while
-        chunks remain; the final chunk samples token #1, activates the
-        lane, and returns prefill_into_slot's info dict (plus a
-        `prefix` block when the cache is on: hit/harvest accounting for
-        the scheduler's counters and prefix_hit events).
-
-        Chunks start at `start_rows` (the spliced prefix extent, 0 when
-        cold) — the suffix-only prefill that turns a prefix hit into
-        skipped FLOPs.
-
-        A `waiting` state (in-flight dedup, see start_prefill) burns a
-        tick re-checking the leader instead of computing: once the
-        leader's harvest lands the acquire books a real HIT and the
-        suffix-only prefill runs; if the leader dies (release_pending
-        in release_slot) or the wait budget expires, the lane proceeds
-        cold. Either way no chunk FLOPs are spent while parked."""
+    def prefill_ready(self, st: Dict[str, Any]) -> bool:
+        """Whether `st` has a chunk to run now. A `waiting` state
+        (in-flight dedup, see start_prefill) burns a tick re-checking
+        the leader instead of computing: once the leader's harvest lands
+        the acquire books a real HIT and the suffix-only prefill runs;
+        if the leader dies (release_pending in release_slot) or the wait
+        budget expires, the lane proceeds cold. Either way no chunk
+        FLOPs are spent while parked."""
         if st.get("waiting"):
             st["wait_ticks"] += 1
             cache = self.prefix_cache
@@ -1857,51 +1874,83 @@ class StepwiseDecoder:
                 and cache.has_pending_prefix(st["chain"])
                 and st["wait_ticks"] < self.DEDUP_WAIT_TICKS
             ):
-                return None
+                return False
             self._arm_prefill(st)
-            # Fall through: this tick runs the first real chunk.
-        c = st["next"]
-        chunk = st["chunk"]
-        slot = st["slot"]
-        base = int(st.get("start_rows", 0))
-        start = base + c * chunk
-        cached = self.prefix_cache is not None
-        fn = (self._get_chunk_prefill_cached() if cached
-              else self._get_chunk_prefill())
-        with self.phases.region("put"), self.tracer.span("prefill.put"):
-            args = [
-                jnp.asarray(st["ids"][:, start:start + chunk]),
-                jnp.asarray(slot, jnp.int32),
-            ]
-            if cached:
-                args += [
-                    jnp.asarray(self._gtable[slot]),
-                    jnp.asarray(int(st.get("p0", 0)), jnp.int32),
-                ]
-            args += [
-                jnp.asarray(start, jnp.int32),
-                jnp.asarray(st["length"], jnp.int32),
-            ]
-        with self.phases.region("dispatch"), \
-                self.tracer.span("prefill.dispatch"):
-            logits, caches = fn(self.params, self.pool.caches, *args)
-            # Dropped while the chunk runs, as call-site temporaries
-            # would be: the runtime then frees them behind the program,
-            # not on this thread after the first-token sync.
-            del args
-        self.pool.caches = caches
-        st["next"] = c + 1
-        if st["next"] < st["n_chunks"]:
-            # Residency telemetry tracks rows as they land; the lane
-            # itself stays inactive until the final chunk.
-            self.pool.lengths[slot] = min(
-                base + (c + 1) * chunk, st["length"]
-            )
+        return st["next"] < st["n_chunks"]
+
+    def advance_prefill(
+        self, st: Dict[str, Any]
+    ) -> Optional[Dict[str, Any]]:
+        """Run ONE prefill chunk: the tick program with that chunk and
+        no lane stepped, read at once. Returns None while chunks remain
+        (or while `st` is parked, prefill_ready); the final chunk samples
+        token #1, activates the lane, and returns prefill_into_slot's
+        info dict (plus a `prefix` block when the cache is on: hit /
+        harvest accounting for the scheduler's counters and prefix_hit
+        events). For callers that own their loop: the scheduler hands
+        the chunk to the step it dispatches (dispatch_step(chunk=st))
+        and the chunk costs no forward pass of its own.
+
+        Chunks start at `start_rows` (the spliced prefix extent, 0 when
+        cold) — the suffix-only prefill that turns a prefix hit into
+        skipped FLOPs."""
+        if not self.prefill_ready(st):
             return None
-        info = self._finish_prefill(
-            slot, logits, st["length"], st["max_new"],
-            st["sample_key"], st["seed"],
-        )
+        step = self._dispatch(st["sample_key"], st, step_lanes=False)
+        if step["chunk"]["last"]:
+            with self.phases.region("device_wait"), \
+                    self.tracer.span("decode.fetch"):
+                first = int(np.asarray(step["nxt"])[st["slot"]])
+            self._first_token(st, first)
+        return st.pop("info", None)
+
+    @staticmethod
+    def _chunk_start(st: Dict[str, Any]) -> int:
+        """The row `st`'s next chunk starts at (past a spliced prefix)."""
+        return int(st["start_rows"]) + st["next"] * st["chunk"]
+
+    def _chunk_dispatched(
+        self, st: Dict[str, Any], carried: bool
+    ) -> Dict[str, Any]:
+        """Host bookkeeping of a chunk, once the tick that carries it is
+        on the device's queue. After a prompt's LAST chunk the slot is a
+        lane by the host's prediction (row `length`, a budget of
+        max_new - 1), so the next step dispatched already steps it, with
+        the first token it reads from the device; what the prediction
+        cannot know, a first token that is a stop id, is settled where
+        the tick is read (_first_token)."""
+        slot, L = st["slot"], st["length"]
+        start = self._chunk_start(st)
+        end = min(start + st["chunk"], L)
+        self.chunk_rows += end - start
+        self.chunks_carried += int(carried)
+        st["next"] += 1
+        last = st["next"] >= st["n_chunks"]
+        # Residency telemetry tracks rows as they land.
+        self.pool.lengths[slot] = end
+        if last:
+            self._pos[slot] = L
+            self._budget[slot] = st["max_new"] - 1
+            self._active[slot] = st["max_new"] > 1
+        return {"st": st, "last": last}
+
+    def _first_token(self, st: Dict[str, Any], first: int) -> None:
+        """The tick that carried `st`'s last chunk has been read: book
+        the prompt's first token and leave prefill_into_slot's info dict
+        in st["info"]. A stop id ends the lane here, and the step already
+        dispatched for it is dropped (_drop_ahead), as for any stop
+        token."""
+        slot = st["slot"]
+        is_stop = first in self.engine._stop_set
+        self._tokens[slot] = first
+        if is_stop:
+            self._active[slot] = False
+            self._drop_ahead(slot)
+        info: Dict[str, Any] = {
+            "token": None if is_stop else first,
+            "prompt_tokens": st["length"],
+            "is_stop": is_stop,
+        }
         if self.prefix_cache is not None:
             harvested = self._harvest(slot, st)
             # Harvest landed (or failed and was unwound): release this
@@ -1912,7 +1961,7 @@ class StepwiseDecoder:
                 self.prefix_cache.release_pending(claims)
             info["prefix"] = {
                 "hit_pages": int(st.get("p0", 0)),
-                "tokens_saved": base,
+                "tokens_saved": int(st["start_rows"]),
                 "pages_harvested": harvested,
                 "tenant": st.get("tenant", "anon"),
                 "dedup_wait_ticks": int(st.get("wait_ticks", 0)),
@@ -1921,7 +1970,7 @@ class StepwiseDecoder:
                 # and prefix_remote_hit events from this.
                 "remote": st.get("remote"),
             }
-        return info
+        st["info"] = info
 
     def _harvest(self, slot: int, st: Dict[str, Any]) -> int:
         """Register this prompt's freshly-computed full pages in the
@@ -2049,90 +2098,21 @@ class StepwiseDecoder:
             self._fns[key] = jax.jit(copy, donate_argnums=(0,))
         return self._fns[key]
 
-    def _get_chunk_prefill_cached(self):
-        """Prefix-cache-aware chunk prefill: the lane's LOGICAL cache
-        view is gathered through its global page table (spliced arena
-        pages read in place), the chunk runs the identical per-lane
-        multi-row path the legacy executable runs, and the updated view
-        is blended back so only PRIVATE pages (>= p0) land in the lane's
-        own storage — shared prefix bytes are never copied into the
-        slot. ONE executable serves cold (identity table, p0 = 0) and
-        hit admissions alike."""
-        key = "chunk_prefill_cached"
-        if key not in self._fns:
-            engine = self.engine
-            chunk = self.prefill_chunk
-            hint = engine._lane_hint()
-            P = self.pool.pages
-            ps = self.pool.page_size
-
-            def chunk_fn(params, pool_caches, ids, slot, table_row, p0,
-                         start, length):
-                def view_of(leaf):
-                    nd = leaf.ndim
-                    lead = leaf.shape[:nd - 5]
-                    T_ = leaf.shape[nd - 5]
-                    flat = leaf.reshape(
-                        lead + (T_ * P,) + leaf.shape[nd - 3:]
-                    )
-                    view = jnp.take(flat, table_row, axis=nd - 5)
-                    return view.reshape(
-                        lead + (1, P * ps) + leaf.shape[nd - 2:]
-                    )
-
-                lane = jax.tree.map(view_of, pool_caches)
-                pos = start + jnp.arange(chunk)
-                positions = jnp.where(pos < length, pos, -1)[None, :]
-                logits, lane, _ = engine.model.apply(
-                    {"params": params},
-                    ids,
-                    positions=positions,
-                    kv_caches=lane,
-                    cache_index=jnp.reshape(start, (1,)),
-                    deterministic=True,
-                    lane_meta=hint,
-                )
-                last_idx = jnp.clip(length - 1 - start, 0, chunk - 1)
-                last = jnp.take_along_axis(
-                    logits, last_idx[None, None, None], axis=1
-                )[:, 0, :]
-                # Private pages only: the where keeps shared (< p0)
-                # pages' slots holding whatever the lane already had, so
-                # cached bytes never duplicate into lane storage and the
-                # arena pages stay the single physical copy.
-                keep = (jnp.arange(P) >= p0).reshape(1, P, 1, 1, 1)
-
-                def put(p, new_flat):
-                    nd = p.ndim
-                    lead = p.shape[:nd - 5]
-                    paged = new_flat.reshape(
-                        lead + (1, P) + p.shape[nd - 3:]
-                    )
-                    own = jax.lax.dynamic_slice_in_dim(
-                        p, slot, 1, axis=nd - 5
-                    )
-                    merged = jnp.where(keep, paged, own)
-                    starts = [0] * nd
-                    starts[nd - 5] = slot
-                    return jax.lax.dynamic_update_slice(
-                        p, merged, tuple(starts)
-                    )
-
-                return last, jax.tree.map(put, pool_caches, lane)
-
-            self._fns[key] = jax.jit(chunk_fn, donate_argnums=(1,))
-        return self._fns[key]
-
     def _drop_ahead(self, slot: int) -> None:
         """The lane ended (a stop token, a release) with steps in flight
         that step it: the host drops their tokens. Each wrote one KV row
         into the lane's OWN slot, past every row attended so far, and
         bumped its own `counts` / `rngs` rows; the slot's next admission
-        resets all three, and its programs queue behind those steps."""
+        resets all three, and its programs queue behind those steps. A
+        LAST chunk of the slot in flight has its first token dropped the
+        same way (only a release can meet one: the slot is no lane yet)."""
         for step in self._inflight:
             if step["stepped"][slot]:
                 step["stepped"][slot] = False
                 self.lane_steps_dropped += 1
+            chunk = step["chunk"]
+            if chunk is not None and chunk["st"]["slot"] == slot:
+                chunk["last"] = False
 
     def _steps_ahead(self) -> np.ndarray:
         """Per lane, how many of the steps in flight step it."""
@@ -2142,9 +2122,9 @@ class StepwiseDecoder:
         return ahead
 
     def _pack_lanes(self) -> Tuple[np.ndarray, np.ndarray]:
-        """What the next step would be called with, right now: the one
-        [4, S] int32 array the host sends (write row, stepped, token
-        from the host, that token) and the lanes it steps.
+        """The lanes' part of what the next step would be called with,
+        right now: [4, S] int32 (write row, stepped, token from the
+        host, that token) and the lanes it steps.
 
         With steps in flight the rows and the lanes are the host's
         PREDICTION of the state behind them: a lane stepped by k of
@@ -2167,22 +2147,56 @@ class StepwiseDecoder:
         lanes[3] = self._tokens
         return lanes, live
 
-    def _next_step(self, sample_key: Optional[Tuple]):
-        """(step function, packed lanes, lanes stepped) of the step
-        that would be dispatched right now."""
+    def _next_step(
+        self,
+        sample_key: Optional[Tuple],
+        chunk: Optional[Dict[str, Any]] = None,
+        step_lanes: bool = True,
+    ):
+        """(tick program, packed tick, lanes stepped) of the step that
+        would be dispatched right now: everything a tick sends is ONE
+        int32 array, the lanes (_pack_lanes), then five numbers of the
+        chunk (slot, first row, prompt length, is-last, seed) and its
+        `prefill_chunk` token ids. `chunk`: the prefill state whose next
+        chunk rides the step; None leaves the chunk's rows padding
+        (length 0). `step_lanes=False` steps no lane."""
+        sample_key = sample_key or GREEDY_SAMPLE_KEY
         lanes, live = self._pack_lanes()
+        if not step_lanes:
+            live = np.zeros_like(live)
+            lanes[1] = 0
+        S, n = self.num_slots, self.prefill_chunk
+        tick = np.zeros((4 * S + 5 + n,), np.int32)
+        tick[: 4 * S] = lanes.reshape(-1)
+        if chunk is not None:
+            if chunk["sample_key"] != sample_key:
+                # The program samples the prompt's first token too.
+                raise ValueError(
+                    "a chunk rides a step of its own sampling key: "
+                    f"{chunk['sample_key']} != {sample_key}"
+                )
+            start = self._chunk_start(chunk)
+            seed = chunk["seed"]
+            if seed is None:
+                seed = time.time_ns()
+            tick[4 * S: 4 * S + 5] = (
+                chunk["slot"], start, chunk["length"],
+                chunk["next"] + 1 >= chunk["n_chunks"],
+                # PRNGKey keeps a seed's low 32 bits; as int32 bits here.
+                np.array(seed & 0xFFFFFFFF, np.uint32).view(np.int32),
+            )
+            tick[4 * S + 5:] = chunk["ids"][0, start:start + n]
         extent = (
             self._active_extent(lanes[0], live)
             if self.backend != "dense" else None
         )
-        fn = self._get_step(sample_key or GREEDY_SAMPLE_KEY, extent)
-        return fn, lanes, live
+        return self._get_step(sample_key, extent), tick, live
 
     def step_fn_and_args(
         self, sample_key: Optional[Tuple] = None
     ) -> Tuple[Any, Tuple]:
-        """The jitted decode-step function and the argument tuple
-        dispatch_step would call it with right now. Exposed so
+        """The jitted tick program and the argument tuple dispatch_step
+        would call it with right now (no chunk pending). Exposed so
         monitoring/attribution.py can AOT-lower the decode executable for
         compiled-cost accounting without executing a step (bench
         extras.ragged_attention compares the dense and ragged backends'
@@ -2191,12 +2205,12 @@ class StepwiseDecoder:
         caller that RUNS it must rebind all three from the result as
         dispatch_step does, or the decoder is left holding deleted
         buffers."""
-        fn, lanes, _ = self._next_step(sample_key)
+        fn, tick, _ = self._next_step(sample_key)
         args = (
             self.params,
             self.pool.caches,
             self._nxt_dev,
-            jax.device_put(lanes),
+            jax.device_put(tick),
             self._counts,
             self._rngs,
             self._table,
@@ -2207,21 +2221,24 @@ class StepwiseDecoder:
     def steps_in_flight(self) -> int:
         return len(self._inflight)
 
-    def dispatch_step(self, sample_key: Optional[Tuple] = None) -> bool:
-        """Enqueue one decode step on the device and return at once;
-        collect_step() reads it. Called with the previous step still in
-        flight (the scheduler's steady state) it steps the lanes that
-        step cannot end (_pack_lanes), and returns False, enqueueing
-        nothing, when there is none."""
-        fn, lanes, live = self._next_step(sample_key)
-        if self._inflight and not live.any():
-            return False
+    def _dispatch(
+        self,
+        sample_key: Optional[Tuple],
+        chunk: Optional[Dict[str, Any]],
+        step_lanes: bool = True,
+    ) -> Optional[Dict[str, Any]]:
+        """Put one tick on the device's queue: the record collect_step
+        reads, or None (nothing enqueued) when a step is in flight and
+        there is neither a lane it leaves alive nor a chunk."""
+        fn, tick, live = self._next_step(sample_key, chunk, step_lanes)
+        if self._inflight and chunk is None and not live.any():
+            return None
         span, region = self.tracer.span, self.phases.region
         with region("put"), span("decode.put"):
-            lanes_d = jax.device_put(lanes)
+            tick_d = jax.device_put(tick)
         with region("dispatch"), span("decode.dispatch"):
             caches, nxt, eos, counts, rngs = fn(
-                self.params, self.pool.caches, self._nxt_dev, lanes_d,
+                self.params, self.pool.caches, self._nxt_dev, tick_d,
                 self._counts, self._rngs, self._table,
             )
         self.pool.caches = caches
@@ -2232,14 +2249,38 @@ class StepwiseDecoder:
         nxt.copy_to_host_async()
         eos.copy_to_host_async()
         self._host_tok[:] = False
-        self._inflight.append({"nxt": nxt, "eos": eos, "stepped": live})
+        return {
+            "nxt": nxt, "eos": eos, "stepped": live,
+            "chunk": None if chunk is None else self._chunk_dispatched(
+                chunk, bool(live.any())
+            ),
+        }
+
+    def dispatch_step(
+        self,
+        sample_key: Optional[Tuple] = None,
+        chunk: Optional[Dict[str, Any]] = None,
+    ) -> bool:
+        """Enqueue one tick on the device and return at once;
+        collect_step() reads it. `chunk`: a prefill state (start_prefill,
+        prefill_ready) whose next chunk rides this step, in the same
+        forward pass as the lanes. Called with the previous step still
+        in flight (the scheduler's steady state) it steps the lanes that
+        step cannot end (_pack_lanes), and returns False, enqueueing
+        nothing, when there is none and no chunk either."""
+        step = self._dispatch(sample_key, chunk)
+        if step is None:
+            return False
+        self._inflight.append(step)
         return True
 
     def collect_step(self) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
         """Read the oldest step in flight (blocks until the device has
         run it) and do its host bookkeeping. Returns decode_step's
         (tokens[S], produced[S], eos[S]), for the lanes that step
-        stepped and that have not been released since."""
+        stepped and that have not been released since. A prompt whose
+        last chunk rode the step has its first token booked here, and
+        prefill_into_slot's info dict left in its state's "info"."""
         step = self._inflight.popleft()
         with self.phases.region("device_wait"), \
                 self.tracer.span("decode.fetch"):
@@ -2254,13 +2295,17 @@ class StepwiseDecoder:
         self._active &= ~eos_h
         for slot in np.flatnonzero(eos_h):
             self._drop_ahead(int(slot))
+        chunk = step["chunk"]
+        if chunk is not None and chunk["last"]:
+            self._first_token(chunk["st"], int(nxt_h[chunk["st"]["slot"]]))
         self.steps += 1
         return nxt_h, stepped & ~eos_h, eos_h
 
     def abandon_steps(self) -> None:
         """Forget every step in flight: nothing of them is read. For
         the caller whose dispatch or collect raised; it must release
-        every lane they stepped (the scheduler fails them all)."""
+        every lane they stepped and every slot whose chunk they carried
+        (the scheduler fails them all)."""
         for step in self._inflight:
             self.lane_steps_dropped += int(step["stepped"].sum())
         self._inflight.clear()
@@ -2268,12 +2313,13 @@ class StepwiseDecoder:
     def decode_step(
         self, sample_key: Optional[Tuple] = None
     ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-        """Advance every active lane one token (one jit call). Returns
-        (tokens[S], produced[S], eos[S]): `produced` lanes emitted
-        tokens[slot] this step; `eos` lanes hit a stop token (dropped,
-        matching generate()) and were deactivated — the scheduler frees
-        their slots. dispatch_step() + collect_step() with nothing else
-        in flight: the serial form, for callers that own their loop."""
+        """Advance every active lane one token (one jit call): the tick
+        with no chunk. Returns (tokens[S], produced[S], eos[S]):
+        `produced` lanes emitted tokens[slot] this step; `eos` lanes hit
+        a stop token (dropped, matching generate()) and were deactivated
+        — the scheduler frees their slots. dispatch_step() +
+        collect_step() with nothing else in flight: the serial form, for
+        callers that own their loop."""
         self.dispatch_step(sample_key)
         return self.collect_step()
 

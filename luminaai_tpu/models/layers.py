@@ -397,6 +397,29 @@ class GQAttention(nn.Module):
                     )
                     return buf.at[rows, idx].set(fresh)[:, :C_cache]
 
+            elif per_lane:
+                # One row per batch row, each at its own (slot, position):
+                # a decode lane writes its own slot, and the rows of a
+                # prefill chunk riding the batch (lane_meta.chunk_rows)
+                # write the chunk's slot. A row at position -1 (a lane
+                # not stepped, the chunk's padding) writes NOTHING: its
+                # out-of-range index is dropped by the scatter.
+                n_chunk = getattr(lane_meta, "chunk_rows", 0)
+                row_slot = jnp.arange(B - n_chunk)
+                if n_chunk:
+                    row_slot = jnp.concatenate([
+                        row_slot,
+                        jnp.broadcast_to(lane_meta.chunk_slot, (n_chunk,)),
+                    ])
+                row_at = write_at if positions is None else jnp.where(
+                    positions[:, 0] >= 0, positions[:, 0], C_cache
+                )
+
+                def _row_scatter(cache_arr, fresh):
+                    return cache_arr.at[row_slot, row_at].set(
+                        fresh[:, 0], mode="drop"
+                    )
+
             if isinstance(ck, tuple):
                 # int8 KV cache (config.kv_cache_dtype='int8'): codes +
                 # per-row scales. Quantize the fresh rows at insert; read
@@ -412,9 +435,8 @@ class GQAttention(nn.Module):
                         codes = _scatter(codes, q8)
                         scales = _scatter(scales, s)
                     elif per_lane:
-                        lanes = jnp.arange(B)
-                        codes = codes.at[lanes, write_at].set(q8[:, 0])
-                        scales = scales.at[lanes, write_at].set(s[:, 0])
+                        codes = _row_scatter(codes, q8)
+                        scales = _row_scatter(scales, s)
                     else:
                         codes = jax.lax.dynamic_update_slice(
                             codes, q8, (0, write_at, 0, 0)
@@ -433,10 +455,7 @@ class GQAttention(nn.Module):
                 if S > 1 and (rolling or per_lane):
                     ck, cv = _scatter(ck, k), _scatter(cv, v)
                 elif per_lane:
-                    # One decode row per lane, each at its own offset.
-                    lanes = jnp.arange(B)
-                    ck = ck.at[lanes, write_at].set(k[:, 0])
-                    cv = cv.at[lanes, write_at].set(v[:, 0])
+                    ck, cv = _row_scatter(ck, k), _row_scatter(cv, v)
                 else:
                     ck = jax.lax.dynamic_update_slice(
                         ck, k, (0, write_at, 0, 0)
@@ -591,7 +610,11 @@ class GQAttention(nn.Module):
                 getattr(lane_meta, "backend", None)
                 or getattr(cfg, "attention_backend", "dense")
             )
-            if decoding_att and backend != "dense" and not rolling:
+            if getattr(lane_meta, "chunk_rows", 0):
+                out = self._tick_attention(
+                    q, k, v, lane_meta, cache_index, positions, backend
+                )
+            elif decoding_att and backend != "dense" and not rolling:
                 # Length-aware (LaneMeta) dispatch: scalar-offset decode,
                 # batched per-lane decode, and (chunked) prefill all
                 # describe themselves the same way and share ONE masking
@@ -609,6 +632,52 @@ class GQAttention(nn.Module):
 
         y = _out_proj(out)
         return y, new_cache
+
+    def _tick_attention(self, q, k, v, meta, cache_index, positions,
+                        backend):
+        """A decode batch with a prefill chunk riding it (LaneMeta.
+        chunk_rows): the one place the two kinds of rows part and meet
+        again. No weight is involved here, so each kind keeps the
+        arithmetic it has alone: the decode rows attend as a plain
+        decode batch does (their LaneMeta, their extent), and the chunk's
+        rows attend their own slot's rows, whole, as one multi-row query
+        at `positions`: what a stand-alone chunk program would run."""
+        n_c = meta.chunk_rows
+        n_d = q.shape[0] - n_c
+        q_c = q[n_d:, 0][None]  # [1, n_c, Hq, D]
+        pos_c = positions[n_d:, 0][None]
+        start = jnp.reshape(meta.chunk_start, (1,))
+
+        def own(a):  # the chunk's slot of a per-slot array
+            return jax.lax.dynamic_slice_in_dim(a, meta.chunk_slot, 1, 0)
+
+        if backend == "dense":
+            out_d = self._xla_attention(
+                q[:n_d], k, v, True, cache_index[:n_d]
+            )
+            out_c = self._xla_attention(q_c, own(k), own(v), True, start)
+        else:
+            lanes = meta.replace(chunk_rows=0, chunk_slot=None,
+                                 chunk_start=None)
+            out_d = self._ragged_attention(
+                q[:n_d], k, v, lanes, cache_index[:n_d], None, backend
+            )
+            if meta.global_pages:
+                # The slot's logical pages may live anywhere in the
+                # pool (a spliced prefix): gather them through its own
+                # row of the table.
+                k_c, v_c = k, v
+                own_meta = lanes.replace(
+                    lengths=jnp.max(pos_c, axis=1).astype(jnp.int32) + 1,
+                    page_table=own(meta.page_table),
+                    kind="prefill", extent=None,
+                )
+            else:
+                k_c, v_c, own_meta = own(k), own(v), None
+            out_c = self._ragged_attention(
+                q_c, k_c, v_c, own_meta, start, pos_c, backend
+            )
+        return jnp.concatenate([out_d, out_c[0][:, None]], axis=0)
 
     def _ragged_attention(self, q, k, v, meta, cache_index, positions,
                           backend):

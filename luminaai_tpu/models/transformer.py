@@ -338,6 +338,9 @@ class LuminaTransformer(nn.Module):
             # Caller fuses the LM head into the loss (ops/fused.py
             # fused_lm_head_cross_entropy) — full [B,S,V] logits never exist.
             aux = self._reduce_metrics(all_metrics)
+            if decoding:
+                # The serving tick projects only the rows it samples from.
+                return x, new_caches, aux
             return x, aux
         logits = embedder.decode(x)
         logits = nn.with_logical_constraint(

@@ -112,6 +112,19 @@ class LaneMeta:
     # prefix pages point into the cache arena. Implies a real gather
     # (identity_pages is ignored).
     global_pages: bool = struct.field(pytree_node=False, default=False)
+    # A prefill CHUNK riding a decode batch (StepwiseDecoder's one
+    # program a tick): the last `chunk_rows` rows of the batch are not
+    # lanes but `chunk_rows` consecutive prompt rows of pool slot
+    # `chunk_slot`, the first of them at position `chunk_start` (the
+    # `positions` operand carries each row's own position, -1 for the
+    # chunk's padding). Every weight then runs once over both kinds of
+    # rows; the attention layer writes the chunk's K/V into its slot
+    # and attends it against that slot alone (GQAttention._tick_
+    # attention). `lengths` / `page_table` describe the decode rows
+    # only. chunk_rows == 0: a plain decode batch.
+    chunk_rows: int = struct.field(pytree_node=False, default=0)
+    chunk_slot: Optional[jax.Array] = None
+    chunk_start: Optional[jax.Array] = None
 
 
 def ragged_eligible(page_size: int, head_dim: int, s_q: int) -> bool:

@@ -140,18 +140,26 @@ class _StepAtCollect:
     duck-typed decoder that has `decode_step()` alone: the dispatch only
     notes the key, and refuses while a step is noted, so nothing runs
     ahead and the scheduler's one loop steps such a decoder in the
-    order admit, step, emit."""
+    order admit, step, emit. A chunk handed to the dispatch is a call
+    of its own there (`advance_prefill`), made at once; `lanes()` is
+    the scheduler's count of live lanes, and with none nothing is
+    noted."""
 
-    def __init__(self, decoder):
+    def __init__(self, decoder, lanes):
         self.decoder = decoder
+        self._lanes = lanes
         self._keys: List[Any] = []
 
     @property
     def steps_in_flight(self) -> int:
         return len(self._keys)
 
-    def dispatch_step(self, sample_key=None) -> bool:
-        if self._keys:
+    def dispatch_step(self, sample_key=None, chunk=None) -> bool:
+        if chunk is not None:
+            info = self.decoder.advance_prefill(chunk)
+            if info is not None:
+                chunk["info"] = info
+        if self._keys or not self._lanes():
             return False
         self._keys.append(sample_key)
         return True
@@ -179,9 +187,10 @@ class ContinuousScheduler:
 
     The loop looks ONE step ahead: it dispatches step N+1 before it
     reads step N's tokens, so everything the host does about step N
-    (the fetch, the per-lane emit, admission, a prefill chunk's
-    transfers) happens while the device runs N+1, and the chunk queues
-    behind it (_run_generation_inner; docs/serving.md "The scheduler
+    (the fetch, the per-lane emit, admission) happens while the device
+    runs N+1. A tick is ONE program: the prefill chunk of the admission
+    at the head of the `_prefilling` ring rides the step, in the lanes'
+    forward pass (_run_generation_inner; docs/serving.md "The scheduler
     loop").
 
     Sampling parameters DO remain a compile key (the sampling math traces
@@ -259,7 +268,7 @@ class ContinuousScheduler:
         # collect halves, or decode_step() behind the same four names.
         self._steps = (
             decoder if hasattr(decoder, "dispatch_step")
-            else _StepAtCollect(decoder)
+            else _StepAtCollect(decoder, lambda: self._active_lanes)
         )
         # Cross-replica page plane (serving/page_share.py): inject the
         # client into the decoder so cold admissions consult the fleet
@@ -298,10 +307,11 @@ class ContinuousScheduler:
         # iteration".
         self._tq_lock = threading.Lock()
         # Admissions mid-prefill: slot -> (request, decoder chunk state,
-        # admission timestamp). The worker advances ONE chunk per loop
-        # tick, interleaved with decode steps, so a long prompt cannot
-        # stall concurrent lanes for more than ~one chunk's step time.
-        self._prefilling: Dict[int, Tuple[Any, Any, float, float]] = {}
+        # admission timestamp), a ring in admission order. ONE chunk a
+        # tick rides the decode step the worker dispatches (_next_chunk),
+        # so a long prompt costs concurrent lanes no forward pass of its
+        # own; an entry stays until its first token is read (_first_tokens).
+        self._prefilling: Dict[int, Tuple[Any, Any, float]] = {}
         self.q: "queue.Queue" = queue.Queue()
         self.window = max(0.0, float(admission_window_ms)) / 1000.0
         # /stats names: batches = generations (one sampling key each),
@@ -424,7 +434,23 @@ class ContinuousScheduler:
             "Lane-steps whose token was dropped: the lane ended while "
             "the step was in flight",
         )
-        self._dropped_seen = 0
+        # A chunk rides the decode step (one program a tick): how many
+        # did so beside at least one stepped lane, and the prompt rows
+        # they carried.
+        self._m_chunks_carried = r.counter(
+            "serve_chunks_carried_total",
+            "Prefill chunks that rode a decode step in which at least "
+            "one lane was stepped",
+        )
+        self._m_chunk_rows = r.counter(
+            "serve_chunk_rows_total",
+            "Live prompt rows carried by prefill chunks",
+        )
+        # The decoder counts these where they happen; the registry
+        # follows (_count_decoder).
+        self._decoder_seen = {
+            "lane_steps_dropped": 0, "chunks_carried": 0, "chunk_rows": 0,
+        }
         # Which attention path the decode step compiled (value is always
         # 1; the label is the payload): a scrape shows what served, not
         # what a config asked for.
@@ -943,12 +969,9 @@ class ContinuousScheduler:
                 self._fail(req, e)
                 return None
             if st is not None:
-                # Chunks run from the worker loop, one per tick,
-                # interleaved with decode steps (_advance_prefills). The
-                # trailing 0.0 accumulates per-chunk compute seconds so
-                # serve_prefill_seconds stays a prefill-cost histogram
-                # rather than absorbing every interleaved decode tick.
-                self._prefilling[slot] = (req, st, t_admit, 0.0)
+                # Its chunks ride the decode steps the worker loop
+                # dispatches, one a tick (_next_chunk).
+                self._prefilling[slot] = (req, st, t_admit)
                 return None
         try:
             with self.tracer.span(
@@ -968,17 +991,15 @@ class ContinuousScheduler:
             self._fail(req, e)
             return None
 
-    def _prefill_done(self, req, slot, info, t_admit, active,
-                      prefill_s=None) -> None:
+    def _prefill_done(self, req, slot, info, t_admit, active) -> None:
         """Shared prompt-prefilled tail for the whole-prompt and chunked
         admission paths: TTFT booking, first-token emission, lane
-        activation (or immediate finish). `prefill_s` is the prefill
-        COMPUTE time — the chunked path passes its per-chunk sum so the
-        histogram keeps one meaning across both admission paths (the
-        monolithic path's admission-to-done wall time IS its compute)."""
+        activation (or immediate finish). serve_prefill_seconds is
+        admission to first token on both: the whole-prompt path's
+        compute, and for a chunked prompt the ticks its chunks rode (a
+        chunk has no forward pass, so no compute time, of its own)."""
         ttft = max(0.0, time.time() - req.t0)
-        if prefill_s is None:
-            prefill_s = time.perf_counter() - t_admit
+        prefill_s = time.perf_counter() - t_admit
         if self.telemetry:
             self._m_prefill.observe(prefill_s)
             # First token is sampled inside prefill, so TTFT lands here.
@@ -1081,89 +1102,85 @@ class ContinuousScheduler:
                 if keys:
                     self.page_share.report_async(keys)
 
-    def _advance_prefills(self, active: dict) -> None:
-        """Advance ONE chunk of ONE mid-prefill admission (round-robin
-        in admission order). Called once per scheduler tick, so prefill
-        work interleaves with decode steps instead of stalling them —
-        the chunked-prefill latency contract (docs/serving.md).
+    def _next_chunk(self, active: dict) -> Optional[Tuple[int, Any, Any]]:
+        """Choose the ONE chunk that rides the next step: (slot, request,
+        prefill state) of the first runnable admission of the
+        `_prefilling` ring (round-robin in admission order), or None.
+        The chunk's rows join the decode rows in one forward pass, so
+        prefill work costs the decode batch no program of its own — the
+        chunked-prefill latency contract (docs/serving.md).
 
-        Dedup followers parked behind an in-flight identical prefix
-        (decoder `waiting` states) re-check for free but must NOT eat
-        the tick's single chunk advance — otherwise K parked followers
-        would slow their own leader's prefill (and every queued one)
-        (K+1)x. A parked re-check cycles to the ring's tail and the
-        scan moves on to the first runnable admission; only real chunk
-        compute (or a resolution running its first chunk) ends the
-        tick."""
-        if not self._prefilling:
-            return
-        with self._wd_pause():
-            self._advance_prefills_paused(active)
-
-    def _advance_prefills_paused(self, active: dict) -> None:
-        """_advance_prefills' body, watchdog-paused like _admit_paused:
-        a chunk advance can compile its executable on first use. Guarded
-        by the `_prefilling` check above, so steady decode-only ticks
-        never pause and the rolling stats keep warming."""
-        for _ in range(max(1, len(self._prefilling))):
-            if not self._prefilling:
-                return
-            slot, (req, st, t_admit, spent) = next(
-                iter(self._prefilling.items())
-            )
-            del self._prefilling[slot]
+        A cancelled or overdue admission is ended here. Dedup followers
+        parked behind an in-flight identical prefix (decoder `waiting`
+        states) re-check for free (`prefill_ready`) and never take the
+        tick's chunk — otherwise K parked followers would slow their own
+        leader's prefill (and every queued one) (K+1)x. An admission
+        whose last chunk is on the device waits for its first token
+        (_first_tokens)."""
+        ready = getattr(self.decoder, "prefill_ready", None)
+        for _ in range(len(self._prefilling)):
+            if not self._prefilling:  # a lost pool failed them all
+                break
+            slot, entry = next(iter(self._prefilling.items()))
+            req, st, _ = entry
+            del self._prefilling[slot]  # to the ring's tail, or out
+            waiting = bool(st.get("waiting"))
+            if not waiting and st["next"] >= st["n_chunks"]:
+                self._prefilling[slot] = entry  # last chunk in flight
+                continue
             if req.cancelled:
                 self._finish(req, "cancelled")
                 self._release_slot(slot)
-                return
+                continue
             if req.deadline is not None and time.time() > req.deadline:
                 self._timeout(req, "mid-prefill")
                 self._release_slot(slot)
-                return
-            was_waiting = bool(st.get("waiting"))
-            try:
-                t_chunk = time.perf_counter()
-                with self.tracer.span(
-                    "prefill_chunk", slot=slot, request_id=req.request_id
-                ):
-                    info = self.decoder.advance_prefill(st)
-                spent += time.perf_counter() - t_chunk
-                # A chunk advance is real progress: stamp liveness here
-                # too, or a prefill-only window (huge prompt, no active
-                # decode lanes) would read as stale to /healthz while
-                # the scheduler is genuinely working.
-                self.last_tick_ts = time.time()
-            except Exception as e:
-                logger.exception("chunked prefill failed")
-                self._release_slot(slot)
-                self._pool_lost(active, e)
-                self._fail(req, e)
-                return
-            if info is None and was_waiting and st.get("waiting"):
-                # Still parked: host-only bookkeeping, no chunk ran and
-                # no prefill_chunk event/counter — keep scanning for
-                # runnable work this tick.
-                self._prefilling[slot] = (req, st, t_admit, spent)
                 continue
-            if self.telemetry:
-                self._m_prefill_chunks.inc()
-            self._event(
-                "prefill_chunk", req, slot=slot,
-                chunk=int(st["next"]), chunks=int(st["n_chunks"]),
-                # Rows RESIDENT, spliced prefix included — must agree with
-                # the decoder's own residency booking for a prefix hit.
-                rows=int(min(
-                    int(st.get("start_rows", 0)) + st["next"] * st["chunk"],
-                    st["length"],
-                )),
-            )
-            if info is None:
-                # More chunks pending: back of the round-robin ring.
-                self._prefilling[slot] = (req, st, t_admit, spent)
-                return
-            self._prefill_done(req, slot, info, t_admit, active,
-                               prefill_s=spent)
-            return
+            runnable = True
+            if waiting and ready is not None:
+                # Resolving may flush harvests (a first-use compile).
+                try:
+                    with self._wd_pause():
+                        runnable = ready(st)
+                except Exception as e:
+                    logger.exception("chunked prefill failed")
+                    self._release_slot(slot)
+                    self._pool_lost(active, e)
+                    self._fail(req, e)
+                    continue
+            self._prefilling[slot] = entry
+            if runnable:
+                return slot, req, st
+        return None
+
+    def _book_chunk(self, slot: int, req, st, resident: int) -> None:
+        """A chunk is on its way (it rode the step just dispatched):
+        the counter, the `prefill_chunk` event, liveness. `resident`:
+        the slot's rows once it has run, spliced prefix included — it
+        must agree with the decoder's own residency booking for a
+        prefix hit."""
+        if self.telemetry:
+            self._m_prefill_chunks.inc()
+        self._event(
+            "prefill_chunk", req, slot=slot,
+            chunk=int(st["next"]), chunks=int(st["n_chunks"]),
+            rows=resident,
+        )
+        # A chunk is real progress: stamp liveness here too, or a
+        # prefill-only window (huge prompt, no active decode lanes)
+        # could read as stale to /healthz while the scheduler works.
+        self.last_tick_ts = time.time()
+
+    def _first_tokens(self, active: dict) -> None:
+        """The first token of every prompt whose last chunk rode a step
+        that has been read (the decoder leaves prefill_into_slot's info
+        in the prefill state): TTFT is stamped and the token emitted
+        here; the lane has been stepped since the next dispatch."""
+        for slot, (req, st, t_admit) in list(self._prefilling.items()):
+            info = st.pop("info", None)
+            if info is not None:
+                del self._prefilling[slot]
+                self._prefill_done(req, slot, info, t_admit, active)
 
     def _wait_for_request(self, timeout: Optional[float] = None):
         """Block on the intake queue with nothing to run: the
@@ -1309,24 +1326,21 @@ class ContinuousScheduler:
                 # and everything below run under N+1, and their
                 # programs queue behind it. What N+1 steps is the
                 # decoder's prediction of the lanes N leaves alive.
+                # N+1 carries one prefill chunk with its lanes (one
+                # program a tick: _next_chunk), so a request admitted
+                # below has its first chunk in the NEXT tick's step.
                 if steps.steps_in_flight and not self._step(key, active):
                     return
                 self._admit_queued(key, active)
-                # One prefill chunk per tick: a long admission progresses
-                # without ever costing the decode batch more than one
-                # chunk-sized forward between steps (_admit/_advance_prefills
-                # pause the watchdog internally, exactly around real prefill
-                # work — never on a merely-busy queue).
-                self._advance_prefills(active)
                 # Harvest batching (ROADMAP item 2): every prefix-cache
                 # harvest that landed this tick rides ONE jitted bulk page
                 # copy instead of one pool-copy dispatch per admission.
                 self._flush_harvests(active)
                 # Nothing queued (a generation's first step, or every
                 # lane of the last one ended or joined since): start.
-                if active and not steps.steps_in_flight and (
-                    not self._step(key, active, collect=False)
-                ):
+                if (active or self._prefilling) and (
+                    not steps.steps_in_flight
+                ) and not self._step(key, active, collect=False):
                     return
             self._phases.publish()
         # A harvest landing on the generation's last tick must not wait
@@ -1334,31 +1348,59 @@ class ContinuousScheduler:
         self._flush_harvests(active)
 
     def _step(self, key, active: dict, collect: bool = True) -> bool:
-        """`decode_step`: dispatch the next step, then (`collect`) read
-        the previous one and do everything that follows a read: the
-        step-time metrics, the watchdog's beat, the per-lane emit.
+        """`decode_step`: choose the chunk that rides the next step and
+        dispatch it, then (`collect`) read the previous one and do
+        everything that follows a read: the step-time metrics, the
+        watchdog's beat, the per-lane emit, the first token of a prompt
+        whose last chunk rode the step read.
         False when either half raised: both steps in flight are then
         abandoned and every lane failed (the pool rebuilt if the call
         had taken it), and the generation is over."""
         steps = self._steps
+        chunk = None
+        if self._prefilling:
+            with self.tracer.span("prefill_chunk") as sp:
+                chunk = self._next_chunk(active)
+                if chunk is not None:
+                    sp.set(slot=chunk[0], request_id=chunk[1].request_id)
         try:
-            with self.tracer.span("decode_step"):
+            with self.tracer.span("decode_step") as sp:
                 ahead = steps.steps_in_flight > 0
-                if steps.dispatch_step(key):
+                if chunk is None:
+                    # Parked admissions alone are no reason for a step.
+                    dispatched = (active or ahead) and (
+                        steps.dispatch_step(key)
+                    )
+                else:
+                    slot, req, st = chunk
+                    start = int(st.get("start_rows", 0)) + (
+                        st["next"] * st["chunk"]
+                    )
+                    end = int(min(start + st["chunk"], st["length"]))
+                    sp.set(chunk_slot=slot, chunk_rows=end - start)
+                    dispatched = steps.dispatch_step(key, chunk=st)
+                    self._book_chunk(slot, req, st, end)
+                if dispatched:
                     if not ahead:
                         self._t_collect = time.perf_counter()
                     elif self.telemetry:
                         self._m_steps_ahead.inc()
-                if not collect:
-                    return True
-                toks, produced, eos = steps.collect_step()
+                if collect and steps.steps_in_flight:
+                    toks, produced, eos = steps.collect_step()
+                else:
+                    collect = False
         except Exception as e:
             logger.exception("decode step failed")
             steps.abandon_steps()
             self._pool_lost(active, e)
             self._fail_all(active, e)  # alive or rebuilt alike
-            self._count_dropped()
+            self._count_decoder()
             return False
+        if not collect:
+            # (A decoder without the split API runs a chunk at once.)
+            self._first_tokens(active)
+            self._count_decoder()
+            return True
         # Collect to collect: with a step always queued behind the one
         # being read, that IS the gap between a lane's tokens (from its
         # own dispatch for a step nothing was queued ahead of).
@@ -1394,17 +1436,25 @@ class ContinuousScheduler:
             self._tick_steps = self._tick_tokens = 0
             self._tick_t0 = time.perf_counter()
         self._emit_lanes(active, toks, produced, eos)
-        self._count_dropped()
+        self._first_tokens(active)
+        self._count_decoder()
         return True
 
-    def _count_dropped(self) -> None:
+    def _count_decoder(self) -> None:
         """The decoder counts the lane-steps it drops (where a lane is
-        released or meets a stop token); the registry follows."""
-        n = getattr(self.decoder, "lane_steps_dropped", 0)
-        if n != self._dropped_seen:
-            if self.telemetry:
-                self._m_lane_steps_dropped.inc(n - self._dropped_seen)
-            self._dropped_seen = n
+        released or meets a stop token) and the chunks its steps carry;
+        the registry follows."""
+        for name, metric in (
+            ("lane_steps_dropped", self._m_lane_steps_dropped),
+            ("chunks_carried", self._m_chunks_carried),
+            ("chunk_rows", self._m_chunk_rows),
+        ):
+            n = getattr(self.decoder, name, 0)
+            seen = self._decoder_seen[name]
+            if n != seen:
+                if self.telemetry:
+                    metric.inc(n - seen)
+                self._decoder_seen[name] = n
 
 
 class _SlotStream:

@@ -253,8 +253,10 @@ def fail_pool_call(
     call passes through. `method` is one of the decoder calls that
     rewrite the KV pool: `decode_step` (failed where the step is read,
     `collect_step`, on a decoder that splits it; `dispatch_step` names
-    the other half), `advance_prefill` (a chunk), `prefill_into_slot`
-    (the slot insert), `flush_harvests` (the page copy). Two flavours,
+    the other half, which also carries a prefill chunk when the
+    scheduler drives it), `advance_prefill` (a chunk run alone, by a
+    caller that owns its loop), `prefill_into_slot` (the slot insert),
+    `flush_harvests` (the page copy). Two flavours,
     the two states a failed donating call can leave behind:
 
     - `lose_pool=False`: raised before the program is called, the

@@ -464,7 +464,10 @@ def test_recompile_surface_pins_current_counts(surface_report):
     unification took decode from 4 to 3 by collapsing the prefill
     bucket ladder; further reductions lower these pins deliberately. If
     a change RAISES them, a new forked variant slipped into the hot
-    path."""
+    path. Still 7 after ISSUE 34: the chunk program it deleted was the
+    SERVING path's (never enumerated here: the prefill scenarios are
+    generate()'s), and a served chunk now rides the batched-cache_index
+    executable, whose one signature covers lanes and chunk rows."""
     report, _ = surface_report
     train = report["programs"]["train"]
     decode = report["programs"]["decode"]
@@ -497,7 +500,10 @@ def test_prefill_scenarios_share_one_chunked_executable(surface_report):
     """Chunked prefill feeds every prompt length through one fixed-chunk
     step: the enumerated prompt-length scenarios must collapse to a
     SINGLE signature (the inversion of the old per-bucket pin — under
-    the bucket ladder these were two executables)."""
+    the bucket ladder these were two executables). These are
+    generate()'s; a SERVED prompt's chunks have no executable at all
+    (they ride the tick: tests/test_tick_program.py pins that the
+    decoder builds step programs and nothing else for them)."""
     report, _ = surface_report
     sigs = {
         v["variant"]: v["signature"]
@@ -506,6 +512,11 @@ def test_prefill_scenarios_share_one_chunked_executable(surface_report):
     }
     assert len(sigs) == 2
     assert len(set(sigs.values())) == 1
+    tick = [
+        v for v in report["programs"]["decode"]["variants"]
+        if v["variant"] == "decode/batched_cache_index"
+    ]
+    assert len(tick) == 1 and tick[0]["signature"] not in sigs.values()
 
 
 def test_sharding_coverage_full_on_cpu_mesh():

@@ -377,11 +377,13 @@ def test_a_capture_holds_the_scheduler_threads_span_tree(tiny_engine,
     finally:
         tracer.stop_capture()
     assert toks == warm
-    want = {"sched.tick", "sched.admit", "prefill_chunk", "prefill.put",
-            "prefill.dispatch", "prefill.sample", "decode_step",
+    want = {"sched.tick", "sched.admit", "prefill_chunk", "decode_step",
             "decode.put", "decode.dispatch", "decode.fetch", "sched.emit"}
     names = _host_event_names(str(tmp_path / "trace"))
     assert want <= names, want - names
+    # A chunk rides the decode step: no transfer, program call or
+    # first-token sync of its own is left on the chunked path.
+    assert not names & {"prefill.put", "prefill.dispatch", "prefill.sample"}
     assert any(n.startswith("capture_clock unix_ns=") for n in names)
     spans = tracer.recent()
     by_id = {s.span_id: s for s in spans}
@@ -393,8 +395,11 @@ def test_a_capture_holds_the_scheduler_threads_span_tree(tiny_engine,
             assert s.parent_id in ticks or s.name == "sched.admit", s.name
         if s.name in ("decode.put", "decode.dispatch", "decode.fetch"):
             assert by_id[s.parent_id].name == "decode_step"
-        if s.name in ("prefill.put", "prefill.dispatch", "prefill.sample"):
-            assert by_id[s.parent_id].name == "prefill_chunk"
+        if s.name == "decode_step" and "chunk_slot" in s.attrs:
+            assert 0 < s.attrs["chunk_rows"] <= 16
+    carried = [s.attrs["chunk_rows"] for s in spans
+               if s.name == "decode_step" and "chunk_slot" in s.attrs]
+    assert carried == [16, 16, 8]  # the prompt's three chunks
     admit = [s for s in spans if s.name == "sched.admit"]
     assert admit and admit[0].attrs["prompt_tokens"] == 40
     assert admit[0].attrs["request_id"]

@@ -377,8 +377,8 @@ def _record_halves(dec):
     log = []
     dispatch, collect = dec.dispatch_step, dec.collect_step
 
-    def dispatch_step(key=None):
-        sent = dispatch(key)
+    def dispatch_step(key=None, chunk=None):
+        sent = dispatch(key, chunk)
         if sent:
             log.append("dispatch")
         return sent
@@ -396,8 +396,11 @@ def test_step_n_plus_1_is_dispatched_before_step_n_is_collected(plain):
     log = _record_halves(w.dec)
     toks = w.ask(65)
     assert toks == w.ref(65) and len(toks) == 65
+    # The prompt is two chunks: each rides a tick (no lane is live yet),
+    # and the lane is stepped from the tick after its last chunk's, on
+    # the host's prediction, before its first token has been read.
     steps = log.count("collect")
-    assert steps == 64 and log.count("dispatch") == 64
+    assert steps == 2 + 64 and log.count("dispatch") == 2 + 64
     # Before the i-th collect, i + 1 steps have been dispatched: on
     # every tick but the last, whose lane the host knew would end.
     reads = sent = 0
@@ -408,9 +411,12 @@ def test_step_n_plus_1_is_dispatched_before_step_n_is_collected(plain):
             reads += 1
             assert sent == min(reads + 1, steps), (reads, sent)
     snap = w.registry.snapshot()
-    assert snap["serve_decode_steps_total"] == 64
+    assert snap["serve_decode_steps_total"] == 66
     ahead = snap["serve_steps_dispatched_ahead_total"]
-    assert ahead == 63 and ahead / snap["serve_decode_steps_total"] > 0.9
+    assert ahead == 65 and ahead / snap["serve_decode_steps_total"] > 0.9
+    assert snap["serving_prefill_chunks_total"] == 2
+    assert snap["serve_chunk_rows_total"] == 30  # the prompt's tokens
+    assert snap["serve_chunks_carried_total"] == 0  # no lane beside them
     assert snap["serve_lane_steps_dropped_total"] == 0
 
 
@@ -455,7 +461,8 @@ def test_a_step_that_fails_with_another_in_flight_is_survived(
 def test_the_decoder_runs_two_steps_deep_and_sends_one_array_a_step(tiny):
     """At the decoder: two steps dispatched before the first is read
     give the serial loop's tokens, and what the host sends a step is
-    one [4, slots] int32 array."""
+    one int32 array: four numbers a slot, then the chunk's five and its
+    token ids."""
     tok, cfg, model, params = tiny
     engine = GenerationEngine(model, params, _StopAt(tok), cfg)
     prompt = tok.encode_text("hello world")
@@ -467,10 +474,10 @@ def test_the_decoder_runs_two_steps_deep_and_sends_one_array_a_step(tiny):
     slot = dec.acquire_slot()
     info = dec.prefill_into_slot(slot, prompt, max_new_tokens=9, seed=0)
     _, args = dec.step_fn_and_args()
-    host_sent = [a for a in args[2:] if isinstance(a, jax.Array)
-                 and a.dtype == jnp.int32 and a.ndim == 2
-                 and a.shape[0] == 4]
-    assert [a.shape for a in host_sent] == [(4, 2)]
+    # (params, pool, previous tokens, TICK, counts, rngs, page table):
+    # the rest lives on the device from step to step.
+    assert args[3].dtype == jnp.int32
+    assert args[3].shape == (4 * 2 + 5 + dec.prefill_chunk,)
     out = [info["token"]]
     assert dec.dispatch_step() and dec.dispatch_step()
     assert dec.steps_in_flight == 2 and dec._steps_ahead()[slot] == 2

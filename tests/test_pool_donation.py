@@ -150,15 +150,9 @@ def _chunk_case(dec, tok):
     slot = dec.acquire_slot()
     st = dec.start_prefill(slot, _long(tok), max_new_tokens=4, seed=0)
     assert st is not None and st["n_chunks"] == 3
-    i32 = jnp.asarray(0, jnp.int32)
-    ids = jnp.zeros((1, dec.prefill_chunk), jnp.int32)
-    if dec.prefix_cache is None:
-        fn = dec._get_chunk_prefill()
-        args = (dec.params, dec.pool.caches, ids, i32, i32, i32)
-    else:
-        fn = dec._get_chunk_prefill_cached()
-        args = (dec.params, dec.pool.caches, ids, i32,
-                jnp.asarray(dec._gtable[slot]), i32, i32, i32)
+    # A chunk rides the tick program (local page ids, or with a prefix
+    # cache global ones): no program of its own rewrites the pool.
+    fn, args = dec.step_fn_and_args(GREEDY_SAMPLE_KEY)
     return fn, args, lambda: dec.advance_prefill(st)
 
 
@@ -323,15 +317,18 @@ def _submit_all(sched, jobs):
 @pytest.mark.parametrize(
     "method,at,lose_pool,survivors,rebuilds",
     [
-        # (a) buffers alive: today's behaviour. A failed chunk fails its
-        # one request and the decoding lane finishes with the right
-        # tokens; a failed step fails the lanes it was for.
-        ("advance_prefill", 2, False, {"short"}, 0),
+        # A chunk rides the step (ISSUE 34): the lanes and the prompt
+        # rows are one program, so a tick that fails fails the decoding
+        # lane and the request mid-prefill alike. (a) buffers alive:
+        # the pool serves on; here the first tick (chunk 1 beside the
+        # lane, nothing in flight) fails where it is dispatched,
+        ("dispatch_step", 1, False, set(), 0),
+        # and here where it is read;
         ("decode_step", 1, False, set(), 0),
-        # (b), (c) buffers deleted, in the step or in a chunk: every
-        # active and prefilling request fails once, one rebuild.
+        # (b), (c) buffers deleted: every active and prefilling request
+        # fails once, one rebuild.
         ("decode_step", 1, True, set(), 1),
-        ("advance_prefill", 2, True, set(), 1),
+        ("dispatch_step", 1, True, set(), 1),
         # The scheduler runs one step ahead (ISSUE 32): "decode_step"
         # above fails where step 1 is READ, with step 2 already on the
         # device; these fail the dispatch of step 2 with step 1 unread.

@@ -768,10 +768,10 @@ def test_prefill_chunk_advance_counts_as_liveness():
                                 recorder=FlightRecorder())
     req = _ContinuousRequest([40], 4, None, None, False)
     sched._track(req)
-    sched._prefilling[0] = (req, st, 0.0, 0.0)
+    sched._prefilling[0] = (req, st, 0.0)
     assert sched.last_tick_ts is None
-    sched._advance_prefills_paused({})
-    assert sched.last_tick_ts is not None
+    assert sched._step(None, {}, collect=False)  # a chunk and no lane
+    assert st["next"] == 1 and sched.last_tick_ts is not None
 
 
 def test_cmd_top_exit_codes_and_dump_dir(tmp_path, capsys):
