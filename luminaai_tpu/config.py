@@ -109,6 +109,25 @@ class Config:
     # TPU-first capability beyond the reference's surface (its attention
     # is always full causal).
     attention_window: Optional[int] = None
+    # Token mixer of each layer, a tuple of num_layers entries:
+    #   'attention' GQAttention (RoPE, the KV cache, every serving path);
+    #   'latent'    LatentAttention (models/layers.py): keys and values
+    #               expanded from one low-rank latent a token, no
+    #               positional rotation, scores over nope+rope dims and
+    #               values of v_head_dim (training only so far);
+    #   'kda'       KimiDeltaAttention (models/kda.py): the gated delta
+    #               rule as a linear-attention recurrence over a
+    #               [head_dim x head_dim] state a head, computed by the
+    #               chunked Pallas kernels of ops/kda.py (training only).
+    # None = every layer 'attention'. scan_layers needs one kind.
+    layer_mixers: Optional[tuple] = None
+    kda_num_heads: Optional[int] = None  # None = num_heads
+    kda_head_dim: int = 128
+    kda_conv_size: int = 4
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
 
     # --- MoE ---
     use_moe: bool = False
@@ -127,6 +146,29 @@ class Config:
     dense_start_layers: int = 2
     dense_end_layers: int = 2
     expert_output_scaling: float = 1.0
+    # The combine rule, as data read by the one routing helper
+    # (models/moe.py `_sort_routing`): scores are softmax or sigmoid of
+    # the router's logits; the k experts are the top-k of score (+ a
+    # learned-elsewhere `selection_bias` parameter, used for the choice
+    # alone); their scores are divided by their sum (renormalize) and
+    # multiplied by the scale. The defaults are what this layer always
+    # computed, bit for bit.
+    moe_score_func: str = "softmax"  # softmax|sigmoid
+    moe_selection_bias: bool = False
+    moe_renormalize: bool = True
+    moe_routed_scale: float = 1.0
+    # Width of one routed (and one shared) expert; None = intermediate_size.
+    moe_intermediate_size: Optional[int] = None
+    # Shared experts: one SwiGLU of num_shared_experts x the expert width
+    # that every token passes through, added to the routed result.
+    num_shared_experts: int = 0
+    # (offset, count): this program holds experts [offset, offset+count)
+    # of num_experts, as one chip of an expert-parallel group does. The
+    # router keeps its num_experts outputs and its top-k; the layer
+    # computes the held experts' part of the result (plus the shared
+    # expert) and nothing stands in for the rest. gmm dispatch, no expert
+    # mesh axis (docs/parallelism.md).
+    experts_held: Optional[tuple] = None
     # 'sort' = scatter/gather dispatch via flat slot ids (linear memory);
     # 'gather' = same routing, but the expert buffers are filled by a row
     # GATHER through an inverted slot→token index table (the H-wide scatter
@@ -477,6 +519,10 @@ class Config:
         # yaml/json roundtrips turn tuples into lists; normalize back so
         # to_dict() comparisons and static hashing stay stable.
         self.moe_stat_pmean_axes = tuple(self.moe_stat_pmean_axes)
+        if self.layer_mixers is not None:
+            self.layer_mixers = tuple(self.layer_mixers)
+        if self.experts_held is not None:
+            self.experts_held = tuple(int(x) for x in self.experts_held)
         if self.num_kv_heads is None:
             self.num_kv_heads = self.num_heads
         if self.intermediate_size is None:
@@ -730,6 +776,65 @@ class Config:
             assert 0.0 <= self.expert_dropout_rate <= 0.5, (
                 "expert_dropout_rate must be in [0, 0.5]"
             )
+            assert self.moe_score_func in ("softmax", "sigmoid"), (
+                f"invalid moe_score_func {self.moe_score_func}"
+            )
+            plain_rule = (
+                self.moe_score_func == "softmax" and self.moe_renormalize
+                and not self.moe_selection_bias
+                and self.moe_routed_scale == 1.0
+            )
+            assert plain_rule or self.moe_dispatch in (
+                "sort", "gather", "gmm"
+            ), (
+                "moe_score_func / moe_selection_bias / moe_renormalize / "
+                "moe_routed_scale other than the defaults need "
+                "moe_dispatch sort, gather or gmm (einsum and a2a route "
+                "by their own copies of the rule)"
+            )
+            assert self.num_shared_experts >= 0
+            if self.experts_held is not None:
+                off, cnt = self.experts_held
+                assert 0 <= off and cnt >= 1 and (
+                    off + cnt <= self.num_experts
+                ), f"experts_held {self.experts_held} outside num_experts"
+                assert self.moe_dispatch == "gmm", (
+                    "experts_held runs through moe_dispatch='gmm' (the "
+                    "ragged grouped matmul over the held experts' rows)"
+                )
+                assert self.total_mesh_size() == 1, (
+                    "experts_held is one chip's share, told by the "
+                    "configuration: it does not compose with a mesh yet "
+                    "(an 'expert' mesh axis derives the share itself)"
+                )
+                assert not self.use_mod, "experts_held does not compose with MoD"
+        if self.layer_mixers is not None:
+            assert len(self.layer_mixers) == self.num_layers, (
+                f"layer_mixers names {len(self.layer_mixers)} layers, "
+                f"num_layers is {self.num_layers}"
+            )
+            kinds = set(self.layer_mixers)
+            assert kinds <= {"attention", "latent", "kda"}, (
+                f"invalid layer_mixers {sorted(kinds)}"
+            )
+            assert not (self.scan_layers and len(kinds) > 1), (
+                "scan_layers needs a stack of one mixer kind; "
+                f"layer_mixers has {sorted(kinds)}"
+            )
+            if kinds - {"attention"}:
+                for name, size in (
+                    ("sequence", self.sequence_parallel_size),
+                    ("pipeline", self.pipeline_parallel_size),
+                    ("tensor", self.tensor_parallel_size),
+                ):
+                    assert size == 1, (
+                        f"'latent' and 'kda' mixers do not compose with "
+                        f"{name}_parallel_size={size} yet"
+                    )
+                assert self.attention_window is None, (
+                    "'latent' and 'kda' mixers take no attention_window"
+                )
+                assert self.kda_conv_size >= 1 and self.kda_head_dim >= 1
         if self.use_mod:
             assert 0.0 < self.mod_capacity_factor <= 1.0, (
                 "mod_capacity_factor must be in (0, 1]"
@@ -862,6 +967,21 @@ class Config:
     # -- derived quantities (ref config_manager.py:234,505,572) ----------
     def head_dim(self) -> int:
         return self.hidden_size // self.num_heads
+
+    def mixer_kind(self, layer_idx: int) -> str:
+        """'attention' | 'latent' | 'kda' for a layer (layer_mixers)."""
+        if self.layer_mixers is None:
+            return "attention"
+        return self.layer_mixers[layer_idx]
+
+    def recurrent_or_latent(self) -> bool:
+        """True when some layer's mixer has no serving path yet."""
+        return any(
+            kind != "attention" for kind in (self.layer_mixers or ())
+        )
+
+    def expert_width(self) -> int:
+        return self.moe_intermediate_size or self.intermediate_size
 
     def estimate_parameters(self) -> int:
         """Total parameter count (ref core/model.py:91 estimate_parameters)."""

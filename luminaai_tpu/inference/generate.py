@@ -177,6 +177,10 @@ class _NgramIndex:
         return []
 
 
+class UnservedMixerError(NotImplementedError):
+    """The Config names a token mixer that only training runs."""
+
+
 class GenerationEngine:
     """Single-sequence generation over a LuminaTransformer + params."""
 
@@ -191,6 +195,17 @@ class GenerationEngine:
         self.model = model
         self.tokenizer = tokenizer
         self.config = config or model.config
+        for cfg in (self.config, model.config):
+            if cfg.recurrent_or_latent():
+                raise UnservedMixerError(
+                    "this model cannot be served yet: layer_mixers="
+                    f"{cfg.layer_mixers} has 'kda' or 'latent' layers, and "
+                    "the engine keeps no recurrent state a lane and no "
+                    "latent cache entry (inference/kv_pool.py holds k/v "
+                    "pages alone). `lumina train` runs it; `lumina serve` "
+                    "and `lumina chat` need docs/serving.md's 'Mixers "
+                    "that are not served'."
+                )
         self.max_context = max_context or self.config.seq_length
         # Inference quantization (config.quantization_method = 'int8'/
         # 'int4'; ref trainer.py:575). int8 keeps QuantizedTensor leaves in

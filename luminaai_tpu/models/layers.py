@@ -795,6 +795,85 @@ class GQAttention(nn.Module):
         return out.reshape(B, Sq, n_q, d)
 
 
+class LatentAttention(nn.Module):
+    """Multi-head latent attention without positions (training form).
+
+    Keys and values of every head are expanded from ONE low-rank latent a
+    token: [c (kv_lora_rank); k_r (qk_rope_head_dim)] = W_kva x, c through
+    an RMSNorm, [k_n (qk_nope_head_dim); v (v_head_dim)] a head = W_kvb c,
+    and a head's key is [k_n; k_r] with k_r shared by all heads. Queries
+    are a plain projection to heads x (nope + rope). No rotation is
+    applied to either part (`mla_use_nope`: the model's recurrent layers
+    carry position). Scores run over nope + rope dims (192), values and
+    the output over v_head_dim (128): the flash kernels take the two
+    widths as they are. Training materialises k and v a head; the
+    absorbed form and the latent cache entry are serving's and not here.
+    """
+
+    config: Config
+    dtype: Dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        cfg = self.config
+        B, S, H = x.shape
+        n = cfg.num_heads
+        dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        dq, rank = dn + dr, cfg.kv_lora_rank
+
+        def mat(name, shape, axes, std=cfg.init_std):
+            return self.param(
+                name, nn.with_logical_partitioning(default_init(std), axes),
+                shape, jnp.float32)
+
+        wq = mat("wq", (H, n, dq), ("embed", "heads", "head_dim"))
+        wkva = mat("wkv_a", (H, rank + dr), ("embed", None))
+        wkvb = mat("wkv_b", (rank, n, dn + dv), (None, "heads", "head_dim"))
+        wo = mat("wo", (n, dv, H), ("heads", "head_dim", "embed"),
+                 cfg.init_std / jnp.sqrt(2.0))
+
+        x = x.astype(self.dtype)
+        q = jnp.einsum("bsh,hnd->bsnd", x, wq.astype(self.dtype))
+        kva = jnp.einsum("bsh,hr->bsr", x, wkva.astype(self.dtype))
+        c = RMSNorm(cfg.rms_norm_eps, dtype=self.dtype, name="kv_norm")(
+            kva[..., :rank])
+        kv = jnp.einsum("bsr,rnd->bsnd", c, wkvb.astype(self.dtype))
+        k_r = jnp.broadcast_to(kva[..., None, rank:], (B, S, n, dr))
+        k = jnp.concatenate([kv[..., :dn], k_r], axis=-1)
+        v = kv[..., dn:]
+        scale = 1.0 / float(dq) ** 0.5
+
+        from luminaai_tpu.ops.flash_attention import flash_eligible
+
+        with jax.named_scope("latent_attention"):
+            if (
+                cfg.use_flash_attention
+                and flash_eligible(S, dq, cfg.flash_block_q,
+                                   cfg.flash_block_kv)
+                and dv % 64 == 0
+                and not self.is_initializing()
+            ):
+                from luminaai_tpu.ops.flash_attention import (
+                    flash_attention_on_mesh,
+                )
+                from luminaai_tpu.parallel.mesh import active_mesh
+
+                spec = nn.logical_to_mesh_axes(
+                    ("activation_batch", None, "activation_heads", None))
+                out = flash_attention_on_mesh(
+                    q, k, v, active_mesh(), spec, spec, causal=True,
+                    scale=scale, block_q=cfg.flash_block_q,
+                    block_kv=cfg.flash_block_kv,
+                )
+            else:
+                s = jnp.einsum("bqnd,bknd->bnqk", q, k).astype(
+                    jnp.float32) * scale
+                keep = jnp.arange(S)[:, None] >= jnp.arange(S)[None, :]
+                p = jax.nn.softmax(jnp.where(keep, s, -1e30), axis=-1)
+                out = jnp.einsum("bnqk,bknd->bqnd", p.astype(q.dtype), v)
+        return jnp.einsum("bsnd,ndh->bsh", out, wo.astype(self.dtype))
+
+
 class Embedder(nn.Module):
     """Token embedding with optional stable scaling and tied decode
     (ref core/model.py:1618 embedding handling)."""

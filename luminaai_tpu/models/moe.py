@@ -33,8 +33,10 @@ Dtype = Any
 
 
 def _sort_routing(
-    router_probs: jax.Array, top_k: int, capacity: int
-) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    router_probs: jax.Array, top_k: int, capacity: int, *,
+    select_bias: Optional[jax.Array] = None, renormalize: bool = True,
+    scale: float = 1.0, with_chosen: bool = False,
+) -> Tuple[jax.Array, ...]:
     """Sort-based top-k assignment with per-expert capacity (no [S,E,C] maps).
 
     Replicates _top_k_routing's greedy semantics exactly — capacity is
@@ -47,20 +49,35 @@ def _sort_routing(
     stay dense [E,G,C,·] on the MXU. (Ref's CUDA dispatch kernels play this
     role: Src/Main_Scripts/core/moe_cuda_wrapper.py:628.)
 
-    router_probs: [G, S, E] softmax probabilities.
+    router_probs: [G, S, E] scores (softmax probabilities, or sigmoids).
+    The combine rule is data (Config.moe_*): the k experts are the top-k
+    of score + select_bias ([E], used for the choice alone), their scores
+    are divided by their sum if `renormalize` and multiplied by `scale`.
+    The defaults are the softmax-renormalised rule this layer always had.
     Returns (per group, vmapped):
       slot:  [G, S, k] int32 flat slot e*C + pos (E*C = dropped sentinel)
       gate:  [G, S, k] renormalized top-k probs (zeroed where dropped)
       dropped: [G, S] 1.0 where a token lost ≥1 of its k slots
       counts: [G, E] kept tokens per expert
+      (with_chosen: and chosen [G, E], the pairs per expert before capacity)
     """
     G, S, E = router_probs.shape
     C = capacity
 
     def per_group(probs):  # [S, E]
-        vals, choice = jax.lax.top_k(probs, top_k)  # [S, k] desc order
-        denom = vals.sum(-1, keepdims=True) + 1e-9
-        gates = vals / denom
+        if select_bias is None:
+            vals, choice = jax.lax.top_k(probs, top_k)  # [S, k] desc order
+        else:
+            _, choice = jax.lax.top_k(
+                probs + jax.lax.stop_gradient(select_bias), top_k
+            )
+            vals = jnp.take_along_axis(probs, choice, axis=-1)
+        gates = vals
+        if renormalize:
+            denom = vals.sum(-1, keepdims=True) + 1e-9
+            gates = vals / denom
+        if scale != 1.0:
+            gates = gates * scale
         # Pair index p = round*S + s → round-major FIFO priority, matching
         # the greedy loop (round r assigned before r+1, sequence order
         # within a round).
@@ -87,6 +104,8 @@ def _sort_routing(
             jnp.sum(1.0 - keep.astype(probs.dtype), axis=-1), 0.0, 1.0
         )
         counts = jnp.minimum(counts_all, C)
+        if with_chosen:
+            return slot, gate, dropped, counts, counts_all
         return slot, gate, dropped, counts
 
     return jax.vmap(per_group)(router_probs)
@@ -237,7 +256,7 @@ class MoELayer(nn.Module):
         deterministic = self.deterministic
         G, S, H = x.shape
         E, k = cfg.num_experts, cfg.moe_top_k
-        F = cfg.intermediate_size
+        F = cfg.expert_width()
         capacity = max(1, int(cfg.capacity_factor * S * k / E))
         # Round capacity to a multiple of 8 (fp32 sublane) when big enough —
         # keeps the [E, G, C, H] buffers tileable.
@@ -259,6 +278,25 @@ class MoELayer(nn.Module):
             if cfg.moe_manual_ep and cfg.expert_parallel_size > 1
             else E
         )
+        if cfg.experts_held is not None:
+            # One chip's share of an expert-parallel group: the weights of
+            # experts [offset, offset + count) alone; the router above
+            # keeps all E outputs.
+            E_w = cfg.experts_held[1]
+        rule = {"renormalize": cfg.moe_renormalize,
+                "scale": cfg.moe_routed_scale}
+        if cfg.moe_selection_bias:
+            # Moved by the balancing rule of whoever trains the router
+            # (aux-loss-free balancing), never by the loss: it takes part
+            # in the choice alone, so its gradient is zero.
+            rule["select_bias"] = self.param(
+                "selection_bias",
+                nn.with_logical_partitioning(
+                    nn.initializers.zeros, (None,)
+                ),
+                (E,),
+                jnp.float32,
+            )
         wi = self.param(
             "wi",
             nn.with_logical_partitioning(
@@ -298,7 +336,10 @@ class MoELayer(nn.Module):
             )
             keep = jnp.where(keep.any(), keep, jnp.ones_like(keep))
             gate_logits = jnp.where(keep[None, None, :], gate_logits, -1e9)
-        router_probs = jax.nn.softmax(gate_logits, axis=-1)
+        if cfg.moe_score_func == "sigmoid":
+            router_probs = jax.nn.sigmoid(gate_logits)
+        else:
+            router_probs = jax.nn.softmax(gate_logits, axis=-1)
 
         # Quantized serving: the gmm kernel is bf16-only, so int8 expert
         # weights route through the gather buffers (decode shapes rarely
@@ -329,15 +370,31 @@ class MoELayer(nn.Module):
             # padded-slot FLOPs (~20% of expert matmul work at cf 1.25).
             # Routing/capacity/drop semantics are _sort_routing's, so
             # outputs match the sort/gather paths exactly.
-            out, tokens_per_expert, dropped = self._gmm_path(
-                x, router_probs, wi, wo, capacity
-            )
+            if cfg.experts_held is not None:
+                # One chip's share, told by the configuration: no expert
+                # has a capacity of its own (a token picks an expert at
+                # most once, so S a group never binds); the one limit is
+                # the held experts' rows together, capacity_factor x the
+                # expected N * k * count / E.
+                off, cnt = cfg.experts_held
+                rows = int(cfg.capacity_factor * G * S * k * cnt / E)
+                with jax.named_scope("moe_held"):
+                    out, tokens_per_expert, dropped, ep_stats = _gmm_held(
+                        x, router_probs, wi, wo, top_k=k, num_experts=E,
+                        offset=off, dtype=self.dtype, gmm_fn=_pick_gmm(),
+                        rule=rule,
+                        row_bound=-(-rows // _GMM_ROW_TILE) * _GMM_ROW_TILE,
+                    )
+            else:
+                out, tokens_per_expert, dropped = self._gmm_path(
+                    x, router_probs, wi, wo, capacity, rule
+                )
         elif dispatch_mode in ("sort", "gather"):
             # Sort-based dispatch: scatter/gather via flat slot ids — no
             # [G,S,E,C] one-hot tensors (see _sort_routing). The expert FFN
             # below still runs dense [E,G,C,·] matmuls on the MXU.
             slot, gate, dropped, counts = _sort_routing(
-                router_probs, k, capacity
+                router_probs, k, capacity, **rule
             )
             gate = gate.astype(self.dtype)
             tok = jnp.broadcast_to(
@@ -464,10 +521,24 @@ class MoELayer(nn.Module):
                 out = jnp.einsum("gsec,egch->gsh", combine_w, expert_out)
         if cfg.expert_output_scaling != 1.0:
             out = out * cfg.expert_output_scaling
+        if cfg.num_shared_experts:
+            from luminaai_tpu.models.layers import SwiGLU
+
+            out = out + SwiGLU(
+                cfg.num_shared_experts * F,
+                dtype=self.dtype,
+                init_std=cfg.init_std,
+                name="shared_expert",
+            )(x.astype(self.dtype))
 
         # --- Aux losses + stats (ref :1244) ---
         # f_e: fraction of tokens whose slot went to expert e; P_e: mean prob.
         f = tokens_per_expert / (G * S * k + 1e-9)
+        if cfg.moe_score_func == "sigmoid":
+            # The balance statistics want a distribution over experts.
+            router_probs = router_probs / (
+                router_probs.sum(axis=-1, keepdims=True) + 1e-9
+            )
         p = router_probs.mean(axis=(0, 1))
         lse2 = jnp.mean(jax.nn.logsumexp(gate_logits, axis=-1) ** 2)
         drop = dropped.mean()
@@ -513,7 +584,8 @@ class MoELayer(nn.Module):
         return out.astype(self.dtype), metrics
 
     def _gmm_path(
-        self, x: jax.Array, router_probs: jax.Array, wi, wo, capacity: int
+        self, x: jax.Array, router_probs: jax.Array, wi, wo, capacity: int,
+        rule: Optional[Dict[str, Any]] = None,
     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
         """Ragged expert FFN via the Pallas megablox grouped matmul.
 
@@ -554,6 +626,7 @@ class MoELayer(nn.Module):
         G, S, H = x.shape
         E, k = cfg.num_experts, cfg.moe_top_k
         gmm = _pick_gmm()
+        rule = rule or {}
 
         from luminaai_tpu.parallel.mesh import active_mesh, shard_map
 
@@ -566,7 +639,7 @@ class MoELayer(nn.Module):
             return _gmm_local(
                 x, router_probs, wi, wo,
                 top_k=k, capacity=capacity, num_experts=E,
-                dtype=self.dtype, gmm_fn=gmm, ep_axis=None,
+                dtype=self.dtype, gmm_fn=gmm, ep_axis=None, rule=rule,
             )
 
         for ax in ("sequence", "pipe"):
@@ -594,6 +667,7 @@ class MoELayer(nn.Module):
                     x_l, probs_l, wi_l, wo_l,
                     top_k=k, capacity=capacity, num_experts=E,
                     dtype=self.dtype, gmm_fn=gmm, ep_axis="expert",
+                    rule=rule,
                 )
                 # Each pair's FFN output lives on the shard owning its
                 # expert; tokens are replicated over 'expert', so a psum
@@ -627,6 +701,7 @@ class MoELayer(nn.Module):
                 x_l, probs_l, wi_l, wo_l,
                 top_k=k, capacity=capacity, num_experts=E,
                 dtype=self.dtype, gmm_fn=gmm, ep_axis="expert",
+                rule=rule,
             )
             out = jax.lax.psum(out, ("expert", "tensor"))
             tpe = jax.lax.psum(tpe, ("data", "fsdp"))
@@ -837,7 +912,7 @@ def _pick_gmm():
 def _gmm_local(
     x: jax.Array, router_probs: jax.Array, wi, wo, *,
     top_k: int, capacity: int, num_experts: int, dtype, gmm_fn,
-    ep_axis: Optional[str],
+    ep_axis: Optional[str], rule: Optional[Dict[str, Any]] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One shard's ragged grouped-matmul expert FFN.
 
@@ -856,7 +931,9 @@ def _gmm_local(
     E_l = wi.shape[0]
     N = G * S * k
 
-    slot, gate, dropped, counts = _sort_routing(router_probs, k, C)
+    slot, gate, dropped, counts = _sort_routing(
+        router_probs, k, C, **(rule or {})
+    )
     gate = gate.astype(dtype)
 
     # Pair -> expert; dropped pairs get sentinel E_l and sort after every
@@ -927,3 +1004,85 @@ def _gmm_local(
     y_pairs = yrow[inv_perm].reshape(G, S, k, H)
     out = jnp.einsum("gskh,gsk->gsh", y_pairs, gate)
     return out, counts_e.astype(jnp.float32), dropped
+
+
+def _held_gmm_tiling(m: int, k: int, n: int) -> Tuple[int, int, int]:
+    """megablox (tm, tk, tn) for the held experts' calls, looked up by
+    the problem's sizes (forward, and the two transposed problems of its
+    backward). The kernel's default 128 x 128 x 128 walks [8192, 2304] x
+    [2304, 2048] in 18,432 grid steps and read 4% of its roofline on the
+    chip (PERF.md, PR 35); tiles of up to 512 x 768 x 1024 keep operands,
+    accumulator and output under the default scoped VMEM. tn must divide
+    n; a k tile that does not divide k is masked by the kernel."""
+    def fit(size, most):
+        for t in range(min(most, size) // 128 * 128, 127, -128):
+            if size % t == 0:
+                return t
+        return 128
+
+    return (512 if m % 512 == 0 else 128), fit(k, 768), fit(n, 1024)
+
+
+def _gmm_held(x, router_probs, wi, wo, *, top_k, num_experts, offset,
+              row_bound, dtype, gmm_fn, rule):
+    """The grouped-matmul expert FFN of a share the configuration names
+    (Config.experts_held): wi / wo hold experts [offset, offset + E_l) of
+    `num_experts`, routing runs over all of them, and the sort is
+    _gmm_local's (held experts' runs first, every other pair in the
+    excluded tail) with three differences: the sorted buffer is cut to
+    `row_bound` rows (the held pairs are 1/32 of all pairs where 8 of 256
+    are held; a buffer of all N would be 32 times the work), the combine
+    is a scatter-add of those rows into their tokens, and nothing is
+    psum'd. The operand masks and the kernel's uninitialised-tail
+    contract are _gmm_local's.
+
+    Returns (out [G,S,H], tokens_per_expert [E], dropped [G,S], and the
+    pair counts: routed, held (chosen for a held expert), held and not
+    computed (beyond the row bound: must read 0))."""
+    E, C = num_experts, x.shape[1]
+    slot, gate, dropped, counts, chosen = _sort_routing(
+        router_probs, top_k, C, with_chosen=True, **rule
+    )
+    gate = gate.astype(dtype)
+    e_pair = jnp.where(slot < E * C, slot // C, E).reshape(-1)
+    counts_e = counts.sum(axis=0).astype(jnp.int32)
+    chosen_e = chosen.sum(axis=0)  # [E] pairs before any capacity
+    G, S, H = x.shape
+    E_l = wi.shape[0]
+    k, R = top_k, row_bound
+    loc = e_pair - offset
+    e_sort = jnp.where((loc >= 0) & (loc < E_l), loc, E_l)
+    held = counts_e[offset:offset + E_l]  # pairs each held expert kept
+    # Cut the runs at the bound, the last experts' rows first to go.
+    ends = jnp.minimum(jnp.cumsum(held), R)
+    group_sizes = jnp.diff(ends, prepend=0).astype(jnp.int32)
+    total = ends[-1]
+    perm = jnp.argsort(e_sort, stable=True)[:R]  # held pairs, expert-major
+    if perm.shape[0] < R:  # fewer pairs than one row tile (flax init)
+        perm = jnp.pad(perm, (0, R - perm.shape[0]))
+    tok = perm // k
+    row_kept = jnp.arange(R)[:, None] < total
+    x_flat = x.astype(dtype).reshape(G * S, H)
+    lhs = jnp.where(row_kept, x_flat[tok], 0)
+    fused = gmm_fn(lhs, wi.astype(dtype), group_sizes,
+                   preferred_element_type=dtype, tiling=_held_gmm_tiling)
+    gate_act, up = jnp.split(fused, 2, axis=-1)
+    act = jnp.where(row_kept, nn.silu(gate_act) * up, 0)
+    yrow = gmm_fn(act, wo.astype(dtype), group_sizes,
+                  preferred_element_type=dtype, tiling=_held_gmm_tiling)
+    # Zero the kernel's uninitialised tail BEFORE the weights meet it: the
+    # product's cotangent for a weight is the row itself, and garbage
+    # times a zero cotangent is still NaN.
+    w_row = gate.reshape(-1)[perm][:, None].astype(jnp.float32)
+    yrow = jnp.where(row_kept, yrow, 0).astype(jnp.float32) * w_row
+    # A token has at most k rows here and most have none: a scatter-add of
+    # R rows in float32, not a gather of all N pairs.
+    out = jnp.zeros((G * S, H), jnp.float32).at[tok].add(yrow)
+    routed_here = chosen_e[offset:offset + E_l].sum()
+    stats = {
+        "moe_routed_pairs": jnp.float32(G * S * k),
+        "moe_held_pairs": routed_here.astype(jnp.float32),
+        "moe_held_pairs_dropped": (routed_here - total).astype(jnp.float32),
+    }
+    return (out.reshape(G, S, H).astype(dtype),
+            counts_e.astype(jnp.float32), dropped, stats)

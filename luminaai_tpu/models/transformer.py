@@ -19,7 +19,14 @@ from jax.ad_checkpoint import checkpoint_name
 from flax import linen as nn
 
 from luminaai_tpu.config import Config
-from luminaai_tpu.models.layers import Embedder, GQAttention, RMSNorm, SwiGLU
+from luminaai_tpu.models.kda import KimiDeltaAttention
+from luminaai_tpu.models.layers import (
+    Embedder,
+    GQAttention,
+    LatentAttention,
+    RMSNorm,
+    SwiGLU,
+)
 from luminaai_tpu.models.mod import MoDRouter, apply_mod
 from luminaai_tpu.models.moe import MoELayer
 
@@ -39,6 +46,14 @@ REMAT_POLICIES = {
     # (checkpoint's DCE drops it once its outputs are saved). Costs
     # ~[B,S,Hq,D] bf16 + [B,Hq,S] fp32 per layer (~105MB at flagship
     # scale); profiled at ~115ms/step of recompute removed (r3 trace).
+    # Every mixer's output carries the "attn_out" tag, the latent mixer's
+    # flash residuals the same two names. The delta-rule kernel tags its
+    # output and its chunk states too ("kda_out", "kda_states": ops/kda.py)
+    # and they are NOT kept here: at 2 x 8192 tokens and 32 heads of 128
+    # the states are 537 MB a layer, and the described-v5e compile of the
+    # kimi-linear cell's step reads 15.19 GB with them against 13.95
+    # without (PERF.md, PR 35), so the block's backward runs the forward
+    # kernel once more instead.
     "save_attn": jax.checkpoint_policies.save_only_these_names(
         "attn_out", "ffn_out", "flash_out", "flash_lse"
     ),
@@ -77,16 +92,38 @@ class TransformerBlock(nn.Module):
         deterministic = self.deterministic
         metrics: Dict[str, jax.Array] = {}
 
-        h, new_cache = GQAttention(
-            cfg, dtype=self.dtype,
-            multi_row_update=self.multi_row_update, name="attention",
-        )(
-            RMSNorm(cfg.rms_norm_eps, dtype=self.dtype, name="attn_norm")(x),
-            positions=positions,
-            kv_cache=kv_cache,
-            cache_index=cache_index,
-            lane_meta=lane_meta,
-        )
+        normed = RMSNorm(
+            cfg.rms_norm_eps, dtype=self.dtype, name="attn_norm"
+        )(x)
+        kind = cfg.mixer_kind(self.layer_idx)
+        if kind == "attention":
+            h, new_cache = GQAttention(
+                cfg, dtype=self.dtype,
+                multi_row_update=self.multi_row_update, name="attention",
+            )(
+                normed,
+                positions=positions,
+                kv_cache=kv_cache,
+                cache_index=cache_index,
+                lane_meta=lane_meta,
+            )
+        else:
+            if kv_cache is not None:
+                raise NotImplementedError(
+                    f"layer {self.layer_idx}'s {kind!r} mixer has no "
+                    "decode path (no recurrent state a lane, no latent "
+                    "cache entry yet)"
+                )
+            new_cache = None
+            if kind == "latent":
+                h = LatentAttention(
+                    cfg, dtype=self.dtype, name="latent_attention"
+                )(normed)
+            else:
+                h, kda_stats = KimiDeltaAttention(
+                    cfg, dtype=self.dtype, name="kda"
+                )(normed)
+                metrics.update(kda_stats)
         h = checkpoint_name(h, "attn_out")
         x = x + h
         x = nn.with_logical_constraint(
@@ -222,6 +259,9 @@ class _ScanUnit(nn.Module):
                 # companion so the model-level reduction can form the exact
                 # per-contributing-layer mean (identical weighting to the
                 # unscanned path, where every layer contributes equally).
+                if key.endswith("_min"):
+                    merged[key] = jnp.stack(vals).min(axis=0)
+                    continue
                 merged[key] = jnp.stack(vals).sum(axis=0)
                 if not key.endswith("_loss"):
                     merged[f"{key}__cnt"] = jnp.float32(len(vals))
@@ -400,7 +440,8 @@ class LuminaTransformer(nn.Module):
                 # diagnostic sums/__cnt pairs accumulate total contributors
                 # (count × per-unit contributors) for _reduce_metrics.
                 all_metrics.append(
-                    {k: v.sum(axis=0) for k, v in metrics.items()}
+                    {k: v.min(axis=0) if k.endswith("_min") else v.sum(axis=0)
+                     for k, v in metrics.items()}
                 )
         return x, new_caches, all_metrics
 
@@ -423,6 +464,10 @@ class LuminaTransformer(nn.Module):
                     [m[key] for m in all_metrics if key in m]
                 ).sum()
                 out["aux_loss"] = out["aux_loss"] + out[key]
+            elif key.endswith("_min"):
+                out[key] = jnp.stack(
+                    [m[key] for m in all_metrics if key in m]
+                ).min()
             else:
                 total = cnt = None
                 for m in all_metrics:

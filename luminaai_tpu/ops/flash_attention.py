@@ -6,7 +6,9 @@ flash_attention extensions). Online-softmax tiling keeps the [S, S] score
 matrix out of HBM: scores are computed block-by-block in VMEM with running
 max/denominator scratch, so HBM traffic is O(S·D) instead of O(S²).
 
-Layout: q [B, S, Hq, D] / k,v [B, S, Hkv, D] (GQA folds the query-head group
+Layout: q [B, S, Hq, D] / k [B, S, Hkv, D] / v [B, S, Hkv, Dv] (Dv = D
+unless the caller's values are narrower than its scores, as latent
+attention's 192 / 128 are; the output is Dv wide; GQA folds the query-head group
 via index arithmetic in the BlockSpec index maps — KV blocks are fetched once
 per group without materializing repeated heads). Backward uses the standard
 two-pass recomputation with the forward's logsumexp, as separate dq and dkv
@@ -165,6 +167,7 @@ def _kv_index_map(group, block_q, block_kv, window, n_kv):
 def _fwd(q, k, v, *, scale, causal, block_q, block_kv, window=0):
     B, Sq, Hq, D = q.shape
     _, Skv, Hkv, _ = k.shape
+    Dv = v.shape[-1]  # values may be narrower than the scores (latent attention)
     group = Hq // Hkv
     qt = q.transpose(0, 2, 1, 3)  # [B, Hq, Sq, D]
     kt = k.transpose(0, 2, 1, 3)
@@ -180,20 +183,20 @@ def _fwd(q, k, v, *, scale, causal, block_q, block_kv, window=0):
         in_specs=[
             pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, block_kv, D), kv_map),
-            pl.BlockSpec((1, 1, block_kv, D), kv_map),
+            pl.BlockSpec((1, 1, block_kv, Dv), kv_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, block_q, Dv), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, block_q, LANES), lambda b, h, i, j: (b, h, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, Hq, Sq, D), q.dtype),
+            jax.ShapeDtypeStruct((B, Hq, Sq, Dv), q.dtype),
             jax.ShapeDtypeStruct((B, Hq, Sq, LANES), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, LANES), jnp.float32),
             pltpu.VMEM((block_q, LANES), jnp.float32),
-            pltpu.VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q, Dv), jnp.float32),
         ],
         interpret=_interpret(),
         name="flash_fwd",
@@ -308,6 +311,7 @@ def _bwd(scale, causal, block_q, block_kv, window, res, g, g_lse=None):
     do = g
     B, Sq, Hq, D = q.shape
     _, Skv, Hkv, _ = k.shape
+    Dv = v.shape[-1]
     group = Hq // Hkv
 
     qt = q.transpose(0, 2, 1, 3)
@@ -330,8 +334,8 @@ def _bwd(scale, causal, block_q, block_kv, window, res, g, g_lse=None):
     common_specs = [
         pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
         pl.BlockSpec((1, 1, block_kv, D), kv_map),
-        pl.BlockSpec((1, 1, block_kv, D), kv_map),
-        pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
+        pl.BlockSpec((1, 1, block_kv, Dv), kv_map),
+        pl.BlockSpec((1, 1, block_q, Dv), lambda b, h, i, j: (b, h, i, 0)),
         pl.BlockSpec((1, 1, block_q, LANES), lambda b, h, i, j: (b, h, i, 0)),
         pl.BlockSpec((1, 1, block_q, LANES), lambda b, h, i, j: (b, h, i, 0)),
     ]
@@ -362,8 +366,8 @@ def _bwd(scale, causal, block_q, block_kv, window, res, g, g_lse=None):
     dkv_specs = [
         pl.BlockSpec((1, 1, block_q, D), q_map),
         pl.BlockSpec((1, 1, block_kv, D), lambda b, h, j, i: (b, h // group, j, 0)),
-        pl.BlockSpec((1, 1, block_kv, D), lambda b, h, j, i: (b, h // group, j, 0)),
-        pl.BlockSpec((1, 1, block_q, D), q_map),
+        pl.BlockSpec((1, 1, block_kv, Dv), lambda b, h, j, i: (b, h // group, j, 0)),
+        pl.BlockSpec((1, 1, block_q, Dv), q_map),
         pl.BlockSpec((1, 1, block_q, LANES), q_map),
         pl.BlockSpec((1, 1, block_q, LANES), q_map),
     ]
@@ -375,15 +379,15 @@ def _bwd(scale, causal, block_q, block_kv, window, res, g, g_lse=None):
         in_specs=dkv_specs,
         out_specs=[
             pl.BlockSpec((1, 1, block_kv, D), lambda b, h, j, i: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, block_kv, D), lambda b, h, j, i: (b, h, j, 0)),
+            pl.BlockSpec((1, 1, block_kv, Dv), lambda b, h, j, i: (b, h, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, Hq, Skv, D), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hq, Skv, D), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hq, Skv, Dv), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_kv, D), jnp.float32),
-            pltpu.VMEM((block_kv, D), jnp.float32),
+            pltpu.VMEM((block_kv, Dv), jnp.float32),
         ],
         interpret=_interpret(),
         name="flash_bwd_dkv",
@@ -391,7 +395,7 @@ def _bwd(scale, causal, block_q, block_kv, window, res, g, g_lse=None):
 
     # Sum GQA head groups back to the kv heads.
     dk = dk_h.reshape(B, Hkv, group, Skv, D).sum(axis=2).astype(k.dtype)
-    dv = dv_h.reshape(B, Hkv, group, Skv, D).sum(axis=2).astype(v.dtype)
+    dv = dv_h.reshape(B, Hkv, group, Skv, Dv).sum(axis=2).astype(v.dtype)
     return (
         dq.transpose(0, 2, 1, 3),
         dk.transpose(0, 2, 1, 3),

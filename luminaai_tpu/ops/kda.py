@@ -1,0 +1,321 @@
+"""Pallas TPU kernels for the chunked gated delta rule (Kimi Delta Attention).
+
+Per head, with a state S in R^{dk x dv} (S_0 = 0), a per-channel log decay
+g_t <= 0 and a write strength beta_t in (0, 1):
+
+    S'_t = Diag(exp(g_t)) S_{t-1}
+    S_t  = S'_t + beta_t k_t (v_t - S'_t^T k_t)^T
+    o_t  = S_t^T q_t
+
+`kda_recurrent` below is that recurrence token by token (the oracle the
+tests hold the kernels to, and the path for head sizes the kernel does not
+tile). The kernels compute the same thing a chunk of C = 64 tokens at a
+time. With G the running sum of g inside the chunk (inclusive) and S the
+state entering it:
+
+    A_ij = beta_i (k_i * e^{G_i - G_j}) . k_j        j <  i  (strictly lower)
+    P_ij = (q_i * e^{G_i - G_j}) . k_j               j <= i
+    T    = (I + A)^{-1}           A is nilpotent: six products, no substitution
+    W    = T (beta k * e^{G});   U = T (beta v) - W S
+    O    = (q * e^{G}) S + P U
+    S   <- Diag(e^{G_C}) S + (k * e^{G_C - G})^T U
+
+e^{G_i - G_j} is never formed from e^{G_i} and e^{-G_j} over the whole
+chunk: e^{-G_j} would leave float32 after a few dozen tokens of a strong
+gate. The scores are built a 16-token sub-chunk of rows at a time against
+that sub-chunk's first row n: (x_i * e^{G_i - G_n}) . (k_j * e^{G_n - G_j}).
+The first exponent is <= 0 always, the second is <= 0 for every j before
+the sub-chunk and inside it is at most 15 tokens of decay, so float32 holds
+it down to a summed gate of about -88 over 16 tokens (the `kda_decay_min`
+gauge of models/kda.py reports how near a run came). Nothing is clamped.
+
+Gates, running sums, A, P, T and S are float32 (float32 matmuls at HIGHEST
+precision); the matmuls against S and U take their operands in the
+activations' dtype (bfloat16 in training) and accumulate in float32.
+
+Forward: a grid over (batch, head, chunk), chunks innermost and in order,
+S^T in float32 VMEM scratch. Under differentiation it also writes each
+chunk's ENTERING state ([B, H, T/C, dv, dk] float32: 64 KB a chunk a
+head), which the backward reads instead of recomputing the scan. Backward:
+the same grid walked from the last chunk to the first with dS in scratch;
+each step differentiates the chunk's own forward math (`jax.vjp` of
+`_chunk_math`, traced into the kernel), so the two cannot drift apart.
+
+Layout: q, k, g [B, T, H, dk]; v [B, T, H, dv]; beta [B, T, H]. The
+kernels read [B, T, H*d] blocks of (1, C, d) directly, no head-major
+transpose. beta is folded into k and v outside the kernel (one fused
+elementwise pass in XLA, which also carries its gradient).
+
+Interpreted off the TPU: it asks `flash_attention._interpret()` through
+the module at call time, the one switch tests and benchmark/rehearse.py
+patch to compile the real kernels for a described chip.
+"""
+
+from __future__ import annotations
+
+import functools
+import jax
+import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from luminaai_tpu.ops import flash_attention as _fa
+
+CHUNK = 64
+SUB = 16
+_HI = jax.lax.Precision.HIGHEST
+_F32 = jnp.float32
+
+
+def _dot(a, b, dims, precision=None):
+    return jax.lax.dot_general(
+        a, b, (dims, ((), ())), precision=precision,
+        preferred_element_type=_F32,
+    )
+
+
+_NN = ((1,), (0,))
+_NT = ((1,), (1,))
+_TN = ((0,), (0,))
+
+
+def _chunk_math(q, k, kb, vb, g, st, *, sub: int, mxu):
+    """One chunk of one head. q, k, kb (= beta*k) [C, dk], vb (= beta*v)
+    [C, dv], g [C, dk] float32, st = S^T [dv, dk] float32 entering the
+    chunk; `mxu` is the operand dtype of the matmuls against S and U.
+    Returns (o [C, dv] float32, S^T leaving)."""
+    C, dk = g.shape
+    lo_p = None if mxu == jnp.bfloat16 else _HI  # fp32 runs stay exact
+    row = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    tok = jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0)
+    G = _dot((row >= col).astype(_F32), g, _NN, _HI)  # inclusive running sum
+    qf, kf, kbf = q.astype(_F32), k.astype(_F32), kb.astype(_F32)
+
+    a_rows, p_rows = [], []
+    for a in range(C // sub):
+        lo, hi = a * sub, (a + 1) * sub
+        Ga = G[lo:hi]
+        Gn = Ga[:1]                                  # the sub-chunk's first row
+        rf = jnp.exp(Ga - Gn)                        # <= 1
+        lhs = jnp.concatenate([kbf[lo:hi] * rf, qf[lo:hi] * rf], axis=0)
+        seen = tok < hi                              # rows this sub-chunk may see
+        rhs = jnp.where(seen, kf * jnp.exp(jnp.where(seen, Gn - G, 0.0)), 0.0)
+        s = _dot(lhs, rhs, _NT, _HI)                 # [2*sub, C]
+        a_rows.append(s[:sub])
+        p_rows.append(s[sub:])
+    A = jnp.where(row > col, jnp.concatenate(a_rows, axis=0), 0.0)
+    P = jnp.where(row >= col, jnp.concatenate(p_rows, axis=0), 0.0)
+
+    # (I + A)^{-1} = (I - A)(I + A^2)(I + A^4)...: A^C = 0.
+    M = -A
+    T = (row == col).astype(_F32) + M
+    steps = max(0, (C - 1).bit_length() - 1)
+    for _ in range(steps):
+        M = _dot(M, M, _NN, _HI)
+        T = T + _dot(T, M, _NN, _HI)
+
+    E = jnp.exp(G)                                   # <= 1: underflow is benign
+    W = _dot(T, kbf * E, _NN, _HI)                   # [C, dk]
+    Ur = _dot(T, vb.astype(_F32), _NN, _HI)          # [C, dv]
+    st_lo = st.astype(mxu)
+    U = Ur - _dot(W.astype(mxu), st_lo, _NT, lo_p)   # [C, dv]
+    U_lo = U.astype(mxu)
+    o = _dot((qf * E).astype(mxu), st_lo, _NT, lo_p) + _dot(
+        P.astype(mxu), U_lo, _NN, lo_p)
+    Gc = G[C - 1:]                                   # [1, dk] the chunk's decay
+    Kd = (kf * jnp.exp(Gc - G)).astype(mxu)
+    st_new = st * jnp.exp(Gc) + _dot(U_lo, Kd, _TN, lo_p)  # [dv, dk]
+    return o, st_new
+
+
+def _fwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, o_ref, *rest,
+                sub: int, mxu, keep_states: bool):
+    st_scr = rest[-1]
+
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        st_scr[...] = jnp.zeros_like(st_scr)
+
+    st = st_scr[...]
+    if keep_states:
+        rest[0][0, 0, 0] = st
+    o, st_new = _chunk_math(
+        q_ref[0], k_ref[0], kb_ref[0], vb_ref[0], g_ref[0], st,
+        sub=sub, mxu=mxu)
+    o_ref[0] = o.astype(o_ref.dtype)
+    st_scr[...] = st_new
+
+
+def _bwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, st_ref, do_ref,
+                dq_ref, dk_ref, dkb_ref, dvb_ref, dg_ref, dst_scr, *,
+                sub: int, mxu):
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        dst_scr[...] = jnp.zeros_like(dst_scr)
+
+    _, vjp = jax.vjp(
+        functools.partial(_chunk_math, sub=sub, mxu=mxu),
+        q_ref[0], k_ref[0], kb_ref[0], vb_ref[0], g_ref[0],
+        st_ref[0, 0, 0])
+    dq, dk, dkb, dvb, dg, dst = vjp(
+        (do_ref[0].astype(_F32), dst_scr[...]))
+    dq_ref[0] = dq.astype(dq_ref.dtype)
+    dk_ref[0] = dk.astype(dk_ref.dtype)
+    dkb_ref[0] = dkb.astype(dkb_ref.dtype)
+    dvb_ref[0] = dvb.astype(dvb_ref.dtype)
+    dg_ref[0] = dg.astype(dg_ref.dtype)
+    dst_scr[...] = dst
+
+
+def _params():
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+
+def _specs(C, dk, dv, order):
+    """BlockSpecs of q, k, kb (dk wide), vb (dv wide) and g over
+    [B, T, H*d] arrays; `order(t)` maps the grid's chunk axis to a chunk."""
+    def tok(d):
+        return pl.BlockSpec((1, C, d), lambda b, h, t: (b, order(t), h))
+
+    return tok(dk), tok(dv)
+
+
+def _fwd_call(q, k, kb, vb, g, *, H, C, mxu, keep_states):
+    B, T, _ = q.shape
+    dk, dv = q.shape[-1] // H, vb.shape[-1] // H
+    nt = T // C
+    kspec, vspec = _specs(C, dk, dv, lambda t: t)
+    out_specs = [vspec]
+    out_shape = [jax.ShapeDtypeStruct((B, T, H * dv), vb.dtype)]
+    if keep_states:
+        out_specs.append(pl.BlockSpec(
+            (1, 1, 1, dv, dk), lambda b, h, t: (b, h, t, 0, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((B, H, nt, dv, dk), _F32))
+    out = pl.pallas_call(
+        functools.partial(_fwd_kernel, sub=min(SUB, C), mxu=mxu,
+                          keep_states=keep_states),
+        grid=(B, H, nt),
+        in_specs=[kspec, kspec, kspec, vspec, kspec],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((dv, dk), _F32)],
+        compiler_params=_params(),
+        interpret=_fa._interpret(),
+        name="kda_fwd",
+    )(q, k, kb, vb, g)
+    return out if keep_states else (out[0], None)
+
+
+def _bwd_call(q, k, kb, vb, g, states, do, *, H, C, mxu):
+    B, T, _ = q.shape
+    dk, dv = q.shape[-1] // H, vb.shape[-1] // H
+    nt = T // C
+    kspec, vspec = _specs(C, dk, dv, lambda t: nt - 1 - t)
+    sspec = pl.BlockSpec(
+        (1, 1, 1, dv, dk), lambda b, h, t: (b, h, nt - 1 - t, 0, 0))
+    like = lambda x, dt=None: jax.ShapeDtypeStruct(x.shape, dt or x.dtype)  # noqa: E731
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, sub=min(SUB, C), mxu=mxu),
+        grid=(B, H, nt),
+        in_specs=[kspec, kspec, kspec, vspec, kspec, sspec, vspec],
+        out_specs=[kspec, kspec, kspec, vspec, kspec],
+        out_shape=[like(q), like(k), like(kb), like(vb), like(g)],
+        scratch_shapes=[pltpu.VMEM((dv, dk), _F32)],
+        compiler_params=_params(),
+        interpret=_fa._interpret(),
+        name="kda_bwd",
+    )(q, k, kb, vb, g, states, do)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _kda_flat(q, k, kb, vb, g, H, C, mxu):
+    return _fwd_call(q, k, kb, vb, g, H=H, C=C, mxu=mxu,
+                     keep_states=False)[0]
+
+
+def _kda_flat_fwd(q, k, kb, vb, g, H, C, mxu):
+    o, states = _fwd_call(q, k, kb, vb, g, H=H, C=C, mxu=mxu,
+                          keep_states=True)
+    # Named so that a remat policy can keep them across the forward /
+    # backward boundary (models/transformer.py `save_attn`): the block's
+    # backward then does not run the forward kernel a second time.
+    o = checkpoint_name(o, "kda_out")
+    states = checkpoint_name(states, "kda_states")
+    return o, (q, k, kb, vb, g, states)
+
+
+def _kda_flat_bwd(H, C, mxu, res, do):
+    q, k, kb, vb, g, states = res
+    return tuple(_bwd_call(q, k, kb, vb, g, states, do, H=H, C=C, mxu=mxu))
+
+
+_kda_flat.defvjp(_kda_flat_fwd, _kda_flat_bwd)
+
+
+def kda_eligible(dk: int, dv: int) -> bool:
+    """The kernels tile heads of a multiple of 128 on the chip; interpreted
+    (CPU tests) any size runs."""
+    return _fa._interpret() or (dk % 128 == 0 and dv % 128 == 0)
+
+
+def kda(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
+        beta: jax.Array, *, chunk: int = CHUNK, mxu_dtype=None) -> jax.Array:
+    """Chunked gated delta rule, differentiable in all five arguments.
+
+    q, k, g [B, T, H, dk]; v [B, T, H, dv]; beta [B, T, H]. g is the log
+    decay (<= 0) and is taken in float32. Returns o [B, T, H, dv] in v's
+    dtype. `mxu_dtype` (default: q's dtype) is the operand dtype of the
+    matmuls against the state: float32 q, k, v with mxu_dtype bfloat16
+    keeps the scores, the inverse and the output unrounded and only those
+    four matmuls in bf16. Any T: the tail is padded to a whole chunk with
+    tokens that write nothing (k = v = 0, g = 0) and cut off again.
+    """
+    B, T, H, dk = q.shape
+    dv = v.shape[-1]
+    if not kda_eligible(dk, dv):
+        return kda_recurrent(q, k, v, g, beta)
+    b = beta.astype(_F32)[..., None]
+    kb = (k.astype(_F32) * b).astype(k.dtype)
+    vb = (v.astype(_F32) * b).astype(v.dtype)
+    g = g.astype(_F32)
+    pad = -T % chunk
+    flat = lambda x: x.reshape(B, T, H * x.shape[-1])  # noqa: E731
+    args = [flat(x) for x in (q, k, kb, vb, g)]
+    if pad:
+        args = [jnp.pad(x, ((0, 0), (0, pad), (0, 0))) for x in args]
+    o = _kda_flat(*args, H, chunk, jnp.dtype(mxu_dtype or q.dtype))
+    return o[:, :T].reshape(B, T, H, dv)
+
+
+def kda_recurrent(q, k, v, g, beta) -> jax.Array:
+    """The delta rule token by token in float32 (`lax.scan` over T)."""
+    B, T, H, dk = q.shape
+    dv = v.shape[-1]
+    f = lambda x: jnp.moveaxis(x.astype(_F32), 1, 0)  # noqa: E731
+
+    def step(S, xs):
+        q_t, k_t, v_t, g_t, b_t = xs                   # [B, H, .]
+        S = S * jnp.exp(g_t)[..., None]                # [B, H, dk, dv]
+        pred = jnp.einsum("bhkv,bhk->bhv", S, k_t, precision=_HI)
+        u = b_t[..., None] * (v_t - pred)
+        S = S + k_t[..., None] * u[..., None, :]
+        return S, jnp.einsum("bhkv,bhk->bhv", S, q_t, precision=_HI)
+
+    S0 = jnp.zeros((B, H, dk, dv), _F32)
+    _, o = jax.lax.scan(step, S0, (f(q), f(k), f(v), f(g), f(beta)))
+    return jnp.moveaxis(o, 0, 1).astype(v.dtype)
+
+
+def chunk_decay_min(g: jax.Array) -> jax.Array:
+    """The most negative gate summed over one 16-token sub-chunk and one
+    channel: the exponent whose negation the kernel exponentiates. float32
+    holds e^{-x} down to about -88."""
+    B, T = g.shape[:2]
+    pad = -T % SUB
+    if pad:
+        g = jnp.pad(g, ((0, 0), (0, pad)) + ((0, 0),) * (g.ndim - 2))
+    g = g.reshape(B, (T + pad) // SUB, SUB, *g.shape[2:])
+    return jnp.min(jnp.sum(g.astype(_F32), axis=2))

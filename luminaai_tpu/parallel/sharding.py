@@ -16,6 +16,13 @@ backends for:
     all-to-alls over ICI.
   - 'activation_length' → sequence: context parallelism (ring attention).
 
+The delta-rule and latent mixers and the expert layer's additions name
+their parameters with the same axes (projections ('embed', 'heads'), the
+low-rank pairs ('embed', None) / (None, 'heads'), per-head vectors
+('heads',) / ('head_dim',), the shared expert as any SwiGLU, the
+selection bias replicated): no new rule, and every one is annotated
+(tests/test_kimi_linear.py).
+
 Optimizer state inherits parameter shardings (ZeRO-1/2 comes for free:
 Adam moments carry the same fsdp sharding as their parameter).
 """

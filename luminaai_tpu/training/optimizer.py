@@ -53,12 +53,21 @@ def make_schedule(config: Config, total_steps: int) -> optax.Schedule:
     return optax.join_schedules([warmup, decay], [warmup_steps])
 
 
+# Never decayed whatever their rank: the delta rule's gate parameters and
+# the router's selection bias are not weights of a linear map.
+NO_DECAY_NAMES = ("A_log", "dt_bias", "selection_bias")
+
+
 def _decay_mask(params):
     """Apply weight decay to matrices only — norms/scales/bias excluded
-    (ref trainer.py no_decay param groups)."""
+    (ref trainer.py no_decay param groups), and NO_DECAY_NAMES by name."""
     import jax
 
-    return jax.tree.map(lambda p: p.ndim >= 2, params)
+    def decayed(path, p):
+        last = getattr(path[-1], "key", None) if path else None
+        return p.ndim >= 2 and last not in NO_DECAY_NAMES
+
+    return jax.tree_util.tree_map_with_path(decayed, params)
 
 
 class ScaleByAdamInt8State(NamedTuple):

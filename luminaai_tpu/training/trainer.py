@@ -1184,6 +1184,7 @@ class Trainer:
                     window_t0, window_tokens, window_steps = now, 0, 0
                     self.monitor.log_step(self.global_step, scalars)
                     self._export_router_health(metrics, scalars)
+                    self._export_held_and_decay(scalars)
                     last_metrics = scalars
                     if self.step_callback is not None:
                         cb_metrics = dict(scalars)
@@ -1465,6 +1466,37 @@ class Trainer:
                 else {}
             ),
         )
+
+    def _export_held_and_decay(self, scalars) -> None:
+        """Held-expert pair counters and the delta rule's decay gauge, from
+        the scalars the log-window sync above already brought to the host
+        (no sync of its own). The counters grow by one step's per-layer
+        mean a log window, as ep_dispatch_tokens_total does: their RATIOS
+        are what is read (held / routed: the chip's share of the routing;
+        dropped / held: pairs chosen for a held expert and not computed)."""
+        r = self.registry
+        for key, text in (
+            ("moe_routed_pairs", "Routed (token, expert) pairs"),
+            ("moe_held_pairs",
+             "Routed pairs that fell on an expert this program holds "
+             "(Config.experts_held)"),
+            ("moe_held_pairs_dropped",
+             "Held pairs not computed: beyond the grouped matmul's static "
+             "row bound (must stay 0)"),
+        ):
+            v = scalars.get(key)
+            if v is not None:
+                r.counter(
+                    f"{key}_total",
+                    text + ", a layer, sampled at log cadence",
+                ).inc(v)
+        decay = scalars.get("kda_decay_min")
+        if decay is not None:
+            r.gauge(
+                "kda_decay_min",
+                "Most negative gate summed over one 16-token sub-chunk and "
+                "channel of a delta-rule layer (float32 holds down to ~-88)",
+            ).set(decay)
 
     # -- crash forensics (docs/observability.md "Flight recorder") --------
     def _dump_flight_record(self, reason: str) -> Optional[str]:

@@ -216,3 +216,46 @@ def test_ragged_paged_decode_compiles_at_server_defaults(one_chip, kv_dtype):
         sds((SLOTS,), jnp.int32), sds((SLOTS, C // PAGE), jnp.int32),
     ).compile()
     assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
+
+
+# -- the hybrid linear-attention cell's kernels (kimi-linear-train-8k) -------
+KB, KS, KH = 2, 8192, 32  # sequences a chip, tokens a sequence, heads
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_states_bwd"])
+def test_kda_kernels_compile_at_cell_shapes(one_chip, grad):
+    """The chunked delta rule at 32 heads of 128 over 2 x 8192 tokens:
+    the chunk's math (and, backward, its `jax.vjp`, traced into the
+    kernel) must lower in Mosaic and fit the scoped VMEM."""
+    from luminaai_tpu.ops import kda
+
+    def run(q, k, v, g, beta):
+        return kda.kda(q, k, v, g, beta)
+
+    def loss(q, k, v, g, beta):
+        return jnp.sum(run(q, k, v, g, beta).astype(jnp.float32))
+
+    wide = ((KB, KS, KH, 128), BF16)
+    shapes = (wide, wide, wide, ((KB, KS, KH, 128), jnp.float32),
+              ((KB, KS, KH), jnp.float32))
+    fn = jax.grad(loss, argnums=(0, 1, 2, 3, 4)) if grad else run
+    text = _compile(fn, one_chip, *shapes)
+    assert text.count('custom_call_target="tpu_custom_call"') == (
+        2 if grad else 1)
+    assert "kda_fwd" in text and ("kda_bwd" in text) is grad
+
+
+def test_flash_compiles_with_values_narrower_than_scores(one_chip):
+    """Latent attention's shapes: scores over 192, values and output of
+    128, forward and both backward kernels."""
+
+    def loss(q, k, v):
+        out = fa.flash_attention(q, k, v, block_q=BQ, block_kv=BKV)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    text = _compile(
+        jax.grad(loss, argnums=(0, 1, 2)), one_chip,
+        ((KB, KS, KH, 192), BF16), ((KB, KS, KH, 192), BF16),
+        ((KB, KS, KH, 128), BF16),
+    )
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
