@@ -225,8 +225,9 @@ KB, KS, KH = 2, 8192, 32  # sequences a chip, tokens a sequence, heads
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_states_bwd"])
 def test_kda_kernels_compile_at_cell_shapes(one_chip, grad):
     """The chunked delta rule at 32 heads of 128 over 2 x 8192 tokens:
-    the chunk's math (and, backward, its `jax.vjp`, traced into the
-    kernel) must lower in Mosaic and fit the scoped VMEM."""
+    the chunk's math (and, backward, its `jax.vjp` with the inverse's
+    closed-form adjoint -T^T dT T^T, traced into the kernel) must lower in
+    Mosaic and fit the scoped VMEM."""
     from luminaai_tpu.ops import kda
 
     def run(q, k, v, g, beta):

@@ -5,6 +5,8 @@ attention, the two mixers and the sigmoid-routed expert layer against the
 benchmark's plain reference, the routing rule's defaults against the rule
 as it was, and the fences around what does not compose yet."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -34,10 +36,12 @@ def _delta_inputs(B, T, H, dk, dv, gate, seed=0):
 # sub-chunk; a tail longer); gate: weak, as initialised, and strong enough
 # that e^{-G} over a whole chunk (64 tokens x ~2.4 = ~155) leaves float32
 # while over a 16-token sub-chunk (under 88) it does not.
-@pytest.mark.parametrize("T,gate", [(40, 1.0), (64, 1.0), (133, 0.05),
-                                    (150, 1.0), (96, 3.0)])
-def test_kda_kernels_match_the_recurrence(T, gate):
-    args = _delta_inputs(2, T, 2, 32, 16, gate)
+# The last case is the benchmark cell's head size: two whole chunks.
+@pytest.mark.parametrize("T,gate,dk,dv", [
+    (40, 1.0, 32, 16), (64, 1.0, 32, 16), (133, 0.05, 32, 16),
+    (150, 1.0, 32, 16), (96, 3.0, 32, 16), (128, 1.0, 128, 128)])
+def test_kda_kernels_match_the_recurrence(T, gate, dk, dv):
+    args = _delta_inputs(2, T, 2, dk, dv, gate)
     want = kda_ops.kda_recurrent(*args)
     got = kda_ops.kda(*args)
     assert np.isfinite(np.asarray(got)).all()
@@ -55,6 +59,67 @@ def test_kda_kernels_match_the_recurrence(T, gate):
     if gate == 3.0:
         assert -88.0 < float(kda_ops.chunk_decay_min(args[3])) < -45.0
         assert float(args[3][:, :64].sum(axis=1).min()) < -120.0
+
+
+def _inverse_by_products(A):
+    """(I + A)^{-1} as ops/kda.py multiplies it out, left to autodiff."""
+    C = A.shape[0]
+    M, T = -A, jnp.eye(C, dtype=A.dtype) - A
+    for _ in range((C - 1).bit_length() - 1):
+        M = jnp.matmul(M, M, precision=HI)
+        T = T + jnp.matmul(T, M, precision=HI)
+    return T
+
+
+# A as the kernel forms it, beta_i (k_i * e^{G_i - G_j}) . k_j below the
+# diagonal, from unit keys of 8 channels (so |A_ij| reaches ~1): with no
+# gate at all (the largest entries the delta rule can make), at C = 16,
+# and under a strong gate (entries that fall off within a few tokens).
+@pytest.mark.parametrize("C,gate", [(64, 0.0), (16, 0.0), (64, 3.0)])
+def test_inverse_adjoint_matches_autodiff_of_the_products(C, gate):
+    _, k, _, g, beta = (x[0, :, 0] for x in _delta_inputs(1, C, 1, 8, 8, gate))
+    G = jnp.cumsum(g, axis=0)
+    A = jnp.einsum("ic,jc,ijc->ij", beta[:, None] * k, k,
+                   jnp.exp(jnp.minimum(G[:, None] - G[None], 0.0)),
+                   precision=HI)
+    A = jnp.tril(A, -1)
+    assert gate or 0.5 < float(jnp.abs(A).max()) <= 1.0
+    dT = jax.random.normal(jax.random.key(C), (C, C))
+    got_T, vjp = jax.vjp(kda_ops._unit_lower_inverse, A)
+    want_T, want_vjp = jax.vjp(_inverse_by_products, A)
+    assert (np.asarray(got_T) == np.asarray(want_T)).all()
+    got, want = jnp.tril(vjp(dT)[0], -1), jnp.tril(want_vjp(dT)[0], -1)
+    assert float(jnp.abs(got - want).max()) <= 1e-5 * float(
+        jnp.abs(want).max())
+
+
+def _eqns(jaxpr):
+    for e in jaxpr.eqns:
+        yield e
+        for sub in jax.core.jaxprs_in_params(e.params):
+            yield from _eqns(sub)
+
+
+def test_chunk_backward_runs_twelve_square_products_not_thirty():
+    """The chunk's `jax.vjp` at the cell's shapes: ten [64,64] x [64,64]
+    products rebuild the inverse and two are its adjoint. Autodiff through
+    the ten would add twenty."""
+    C, d = 64, 128
+    lo = jax.ShapeDtypeStruct((C, d), jnp.bfloat16)
+    f32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32)
+
+    def fwd_and_bwd(q, k, kb, vb, g, st, do, dst):
+        _, vjp = jax.vjp(functools.partial(
+            kda_ops._chunk_math, sub=kda_ops.SUB, mxu=jnp.bfloat16),
+            q, k, kb, vb, g, st)
+        return vjp((do, dst))
+
+    jaxpr = jax.make_jaxpr(fwd_and_bwd)(
+        lo, lo, lo, lo, f32((C, d)), f32((d, d)), f32((C, d)), f32((d, d)))
+    square = [e for e in _eqns(jaxpr.jaxpr) if e.primitive.name == "dot_general"
+              and all(v.aval.shape == (C, C) for v in e.invars)]
+    assert len(square) == 12
+    assert all(e.params["precision"] == (HI, HI) for e in square)
 
 
 def test_kda_recurrence_is_the_references_delta_rule():
