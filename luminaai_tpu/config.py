@@ -118,9 +118,22 @@ class Config:
     #   'kda'       KimiDeltaAttention (models/kda.py): the gated delta
     #               rule as a linear-attention recurrence over a
     #               [head_dim x head_dim] state a head, computed by the
-    #               chunked Pallas kernels of ops/kda.py (training only).
+    #               chunked Pallas kernels of ops/kda.py (training only);
+    #   'ssm'       SelectiveSSM (models/ssm.py): Mamba-1's selective scan
+    #               with Jamba's inner norms over a [state x channels]
+    #               float32 state; trained by a chunked XLA scan, served
+    #               by ops/ssm.py's kernel with a fixed state a lane
+    #               beside the pages of k/v (every serving path but the
+    #               prefix cache, page pulls and speculation).
     # None = every layer 'attention'. scan_layers needs one kind.
     layer_mixers: Optional[tuple] = None
+    # False: GQAttention rotates nothing (a stack whose recurrent layers
+    # carry the position, as Jamba's).
+    use_rope: bool = True
+    ssm_state_size: int = 16
+    ssm_dt_rank: Optional[int] = None  # None = ceil(hidden_size / 16)
+    ssm_expand: int = 2
+    ssm_conv_size: int = 4
     kda_num_heads: Optional[int] = None  # None = num_heads
     kda_head_dim: int = 128
     kda_conv_size: int = 4
@@ -814,7 +827,7 @@ class Config:
                 f"num_layers is {self.num_layers}"
             )
             kinds = set(self.layer_mixers)
-            assert kinds <= {"attention", "latent", "kda"}, (
+            assert kinds <= {"attention", "latent", "kda", "ssm"}, (
                 f"invalid layer_mixers {sorted(kinds)}"
             )
             assert not (self.scan_layers and len(kinds) > 1), (
@@ -968,8 +981,15 @@ class Config:
     def head_dim(self) -> int:
         return self.hidden_size // self.num_heads
 
+    def ssm_inner(self) -> int:
+        return self.ssm_expand * self.hidden_size
+
+    def ssm_rank(self) -> int:
+        return self.ssm_dt_rank or -(-self.hidden_size // 16)
+
     def mixer_kind(self, layer_idx: int) -> str:
-        """'attention' | 'latent' | 'kda' for a layer (layer_mixers)."""
+        """'attention' | 'latent' | 'kda' | 'ssm' for a layer
+        (layer_mixers)."""
         if self.layer_mixers is None:
             return "attention"
         return self.layer_mixers[layer_idx]
@@ -977,8 +997,15 @@ class Config:
     def recurrent_or_latent(self) -> bool:
         """True when some layer's mixer has no serving path yet."""
         return any(
-            kind != "attention" for kind in (self.layer_mixers or ())
+            kind not in ("attention", "ssm")
+            for kind in (self.layer_mixers or ())
         )
+
+    def keeps_lane_state(self) -> bool:
+        """True when some layer keeps a fixed state a lane (models/ssm.py
+        LaneState) and not pages of k/v: what the prefix cache, page
+        pulls and speculation cannot share, copy or roll back yet."""
+        return "ssm" in (self.layer_mixers or ())
 
     def expert_width(self) -> int:
         return self.moe_intermediate_size or self.intermediate_size

@@ -200,11 +200,12 @@ class GenerationEngine:
                 raise UnservedMixerError(
                     "this model cannot be served yet: layer_mixers="
                     f"{cfg.layer_mixers} has 'kda' or 'latent' layers, and "
-                    "the engine keeps no recurrent state a lane and no "
-                    "latent cache entry (inference/kv_pool.py holds k/v "
-                    "pages alone). `lumina train` runs it; `lumina serve` "
-                    "and `lumina chat` need docs/serving.md's 'Mixers "
-                    "that are not served'."
+                    "the engine keeps no delta-rule state a lane and no "
+                    "latent cache entry (inference/kv_pool.py holds pages "
+                    "of k/v and an 'ssm' layer's fixed state, nothing "
+                    "else). `lumina train` runs it; `lumina serve` and "
+                    "`lumina chat` need docs/serving.md's 'Mixers that "
+                    "are not served'."
                 )
         self.max_context = max_context or self.config.seq_length
         # Inference quantization (config.quantization_method = 'int8'/
@@ -248,7 +249,13 @@ class GenerationEngine:
             return 0
         if getattr(self.config, "attention_window", None) is not None:
             return 0
-        return min(chunk, self.max_context)
+        chunk = min(chunk, self.max_context)
+        if self.config.keeps_lane_state() and self.max_context % chunk:
+            # _prefill_chunked re-feeds rows where the chunk grid
+            # overhangs the cache: k/v rows are rewritten bit for bit, a
+            # recurrent state would take them twice.
+            return 0
+        return chunk
 
     def _make_chunk_prefill_fn(self, chunk: int):
         """One fixed-shape prefill step: feed `chunk` prompt rows at
@@ -551,7 +558,19 @@ class GenerationEngine:
                 tokens.append(int(item))
         return tokens, stats
 
-    def generate_stream_speculative(
+    def generate_stream_speculative(self, *args, **kwargs):
+        """_stream_speculative, refused by name where a layer keeps a
+        fixed state a lane: a rejected draft would have to roll the state
+        back, and no snapshot of it is kept."""
+        if self.config.keeps_lane_state():
+            raise UnservedMixerError(
+                "speculation (generate_speculative) is not served with "
+                "'ssm' layers: a rejected draft cannot be rolled out of a "
+                "recurrent state, and the engine keeps no snapshot of it"
+            )
+        return self._stream_speculative(*args, **kwargs)
+
+    def _stream_speculative(
         self,
         prompt_tokens: Sequence[int],
         max_new_tokens: Optional[int] = None,
@@ -987,6 +1006,15 @@ class StepwiseDecoder:
             prefix_cache_tenant_quota = int(
                 getattr(engine.config, "prefix_cache_tenant_quota", 0) or 0
             )
+        if prefix_cache_pages > 0 and engine.config.keeps_lane_state():
+            from luminaai_tpu.inference.kv_pool import StateNotPagedError
+
+            raise StateNotPagedError(
+                f"prefix cache (prefix_cache_pages={prefix_cache_pages}) "
+                "is not served with 'ssm' layers: a cached page holds "
+                "k/v rows and no snapshot of the recurrent state at its "
+                "end, so a spliced prefix would start from the wrong state"
+            )
         backend = getattr(engine.config, "attention_backend", "dense")
         if prefix_cache_pages > 0 and backend == "dense":
             # The dense per-lane mask reads only the lane's own rows — it
@@ -1044,6 +1072,9 @@ class StepwiseDecoder:
         # and the live prompt rows all chunks carried.
         self.chunks_carried = 0
         self.chunk_rows = 0
+        # With state-space layers: live rows through the recurrence
+        # (stepped lanes + live chunk rows).
+        self.ssm_rows = 0
         self._fns: Dict[Any, Any] = {}
         # Serving attention backend (config.attention_backend): 'dense'
         # keeps the legacy full-extent per-lane mask; the ragged backends
@@ -1341,6 +1372,7 @@ class StepwiseDecoder:
 
     def _get_insert(self):
         if "insert" not in self._fns:
+            from luminaai_tpu.inference.kv_pool import map_pages
 
             page_size = self.pool.page_size
 
@@ -1359,7 +1391,9 @@ class StepwiseDecoder:
                     starts[p.ndim - 5] = slot
                     return jax.lax.dynamic_update_slice(p, fp, tuple(starts))
 
-                return jax.tree.map(put, pool_caches, fresh)
+                return map_pages(
+                    put, pool_caches, fresh,
+                    states=lambda p, f: p.insert(f, slot))
 
             self._fns["insert"] = jax.jit(insert, donate_argnums=(0,))
         return self._fns["insert"]
@@ -1939,6 +1973,8 @@ class StepwiseDecoder:
         end = min(start + st["chunk"], L)
         self.chunk_rows += end - start
         self.chunks_carried += int(carried)
+        if self.pool.keeps_state:
+            self.ssm_rows += end - start
         st["next"] += 1
         last = st["next"] >= st["n_chunks"]
         # Residency telemetry tracks rows as they land.
@@ -2264,6 +2300,8 @@ class StepwiseDecoder:
         nxt.copy_to_host_async()
         eos.copy_to_host_async()
         self._host_tok[:] = False
+        if self.pool.keeps_state:
+            self.ssm_rows += int(live.sum())
         return {
             "nxt": nxt, "eos": eos, "stepped": live,
             "chunk": None if chunk is None else self._chunk_dispatched(
@@ -2373,6 +2411,14 @@ def infer_config_from_params(params: Dict[str, Any]) -> Config:
         int(k.split("_")[1]) for k in params if k.startswith("layer_")
     )
     l0 = params["layer_0"]
+    if any("attention" not in params[f"layer_{i}"] for i in layers):
+        # Shapes cannot say what a mixed stack needs (whether attention
+        # rotates, which layer is of which kind past its parameters).
+        raise ValueError(
+            "a checkpoint with 'ssm', 'kda' or 'latent' layers is served "
+            "from the Config in its metadata; shape inference covers "
+            "attention-only stacks"
+        )
     wq = l0["attention"]["wq"]  # [H, n_heads, head_dim]
     n_heads = wq.shape[1]
     n_kv = l0["attention"]["wk"].shape[1]

@@ -10,6 +10,16 @@ paged-attention literature standardizes on (arxiv 2604.15464): page-
 aligned rows keep cache writes on (8,128)-tiled boundaries and leave the
 door open to page-level sharing/compaction without relayout.
 
+The pool holds TWO kinds of entry a layer, and the layer's mixer says
+which (`LuminaTransformer.init_cache`): pages of k/v as above, or a fixed
+state a lane (`models/ssm.py::LaneState`: a state-space layer's
+[state, channels] float32 state and its convolution's tail, `[num_slots,
+...]`, the same size whatever the lane holds). A state has no rows: it is
+never paged, sliced to an extent or addressed through the page table, a
+slot's admission starts it from zero on the device, and a page that is
+exported, imported or shared carries none of it, which is why a pool with
+states refuses those three by name (`StateNotPagedError`).
+
 Device arrays live here only as an opaque pytree (`self.caches`); all
 accounting — the free-list, per-slot length vector, reuse counters — is
 host-side numpy, so the scheduler never has to read device memory to
@@ -36,6 +46,39 @@ import numpy as np
 # int8 pools need no special casing — codes and scales are separate
 # tree leaves and each frames its own slice.
 PAGE_WIRE_MAGIC = b"LPG1"
+
+
+class StateNotPagedError(NotImplementedError):
+    """Asked of a pool that holds a fixed state a lane beside its pages:
+    something that moves or shares PAGES (the prefix cache, a page export
+    or import) and would leave the state behind."""
+
+
+def map_pages(fn, tree, *rest, states=None):
+    """`fn` over the paged (k/v) leaves of a cache tree; a lane's fixed
+    states go through `states` (as they are when it is None)."""
+    import jax
+
+    from luminaai_tpu.models.ssm import is_lane_state
+
+    def entry(x, *r):
+        if is_lane_state(x):
+            return x if states is None else states(x, *r)
+        return jax.tree.map(fn, x, *r)
+
+    return jax.tree.map(entry, tree, *rest, is_leaf=is_lane_state)
+
+
+def lane_states(tree) -> list:
+    """The fixed states (one a state-space layer) of a cache tree."""
+    import jax
+
+    from luminaai_tpu.models.ssm import is_lane_state
+
+    return [
+        x for x in jax.tree.leaves(tree, is_leaf=is_lane_state)
+        if is_lane_state(x)
+    ]
 
 
 def parse_page_payload(payload: bytes) -> List[np.ndarray]:
@@ -81,9 +124,7 @@ def to_paged(tree, pages: int, page_size: int):
     scan_layers layout with its extra leading segment axis ([count,
     slots, C, ...]). Pure metadata under jit (C == pages * page_size is
     contiguous)."""
-    import jax
-
-    return jax.tree.map(
+    return map_pages(
         lambda x: x.reshape(
             x.shape[:-3] + (pages, page_size) + x.shape[-2:]
         ),
@@ -94,9 +135,7 @@ def to_paged(tree, pages: int, page_size: int):
 def to_flat(tree, pages: int, page_size: int):
     """Inverse of to_paged: the [..., pages*page_size, heads, dim] view
     the model's attention layers consume."""
-    import jax
-
-    return jax.tree.map(
+    return map_pages(
         lambda x: x.reshape(
             x.shape[:-4] + (pages * page_size,) + x.shape[-2:]
         ),
@@ -127,6 +166,7 @@ class PagedKVPool:
                 f"{num_slots}/{pages}/{page_size}"
             )
         self.caches = caches
+        self._keeps_state: Optional[bool] = None  # read once from the tree
         self.num_slots = int(num_slots)
         self.pages = int(pages)
         self.page_size = int(page_size)
@@ -240,6 +280,24 @@ class PagedKVPool:
             leaf.is_deleted() for leaf in jax.tree.leaves(self.caches)
         )
 
+    @property
+    def keeps_state(self) -> bool:
+        """True when some layer keeps a fixed state a lane beside the
+        pages (read from the cache tree, whatever mixer made it)."""
+        if self._keeps_state is None:
+            if self.caches is None:
+                return False
+            self._keeps_state = bool(lane_states(self.caches))
+        return self._keeps_state
+
+    def _pages_only(self, what: str) -> None:
+        if self.keeps_state:
+            raise StateNotPagedError(
+                f"{what}: this pool keeps a fixed state a lane (an 'ssm' "
+                "layer) beside its pages of k/v, and a page carries none "
+                "of it"
+            )
+
     # -- cross-replica page serialization (ISSUE 20) ---------------------
     def _locate(self, gid: int, leaves):
         """Global page id -> physical (slot, page). Bounds-checked
@@ -275,6 +333,7 @@ class PagedKVPool:
         caches = self.caches
         if caches is None:
             raise RuntimeError("accounting-only pool has no cache tree")
+        self._pages_only("page export")
         leaves = jax.tree.leaves(caches)
         slot, page = self._locate(gid, leaves)
         metas, blobs = [], []
@@ -306,6 +365,7 @@ class PagedKVPool:
 
         if self.caches is None:
             raise RuntimeError("accounting-only pool has no cache tree")
+        self._pages_only("page import")
         arrs = parse_page_payload(payload)
         leaves, treedef = jax.tree.flatten(self.caches)
         slot, page = self._locate(gid, leaves)

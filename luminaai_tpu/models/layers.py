@@ -189,6 +189,36 @@ class GQAttention(nn.Module):
     # (the whole band is resident) instead of the raw prompt rows.
     multi_row_update: bool = False
 
+    @staticmethod
+    def init_cache(cfg: Config, batch_size: int, max_len: int, dtype,
+                   kv_cache_dtype: Optional[str] = None,
+                   rolling: bool = True, lead=()):
+        """What a lane keeps of an attention layer: a (k, v) pair of
+        [batch, rows, kv_heads, head_dim] (int8: codes and per-row
+        scales), which the pool pages (LuminaTransformer.init_cache says
+        when the rows roll)."""
+        choice = kv_cache_dtype or cfg.kv_cache_dtype
+        C = max_len
+        if (
+            rolling
+            and cfg.attention_window is not None
+            and max_len <= cfg.seq_length
+        ):
+            C = min(max_len, ((cfg.attention_window + 127) // 128) * 128)
+        shape = (*lead, batch_size, C, cfg.num_kv_heads, cfg.head_dim())
+
+        def one():
+            if choice == "int8":
+                # (codes, per-row scales): half the HBM of a bf16 cache,
+                # so max batch·context doubles (see config.kv_cache_dtype).
+                return (
+                    jnp.zeros(shape, dtype=jnp.int8),
+                    jnp.ones((*shape[:-1], 1), dtype=jnp.float32),
+                )
+            return jnp.zeros(shape, dtype=dtype)
+
+        return (one(), one())
+
     @nn.compact
     def __call__(
         self,
@@ -298,10 +328,11 @@ class GQAttention(nn.Module):
             max_len = max(cfg.seq_length, S, cache_len)
         else:
             max_len = max(cfg.seq_length, S)
-        cos, sin = rope_frequencies(d, max_len, cfg.rope_theta)
-        rope_ct = self.dtype if cfg.rope_dtype == "bf16" else jnp.float32
-        q = apply_rope(q, cos, sin, positions, compute_dtype=rope_ct)
-        k = apply_rope(k, cos, sin, positions, compute_dtype=rope_ct)
+        if cfg.use_rope:
+            cos, sin = rope_frequencies(d, max_len, cfg.rope_theta)
+            rope_ct = self.dtype if cfg.rope_dtype == "bf16" else jnp.float32
+            q = apply_rope(q, cos, sin, positions, compute_dtype=rope_ct)
+            k = apply_rope(k, cos, sin, positions, compute_dtype=rope_ct)
 
         new_cache = None
         rolling_prefill = False

@@ -28,6 +28,7 @@ from luminaai_tpu.models.layers import (
     SwiGLU,
 )
 from luminaai_tpu.models.mod import MoDRouter, apply_mod
+from luminaai_tpu.models.ssm import SelectiveSSM
 from luminaai_tpu.models.moe import MoELayer
 
 Dtype = Any
@@ -107,12 +108,20 @@ class TransformerBlock(nn.Module):
                 cache_index=cache_index,
                 lane_meta=lane_meta,
             )
+        elif kind == "ssm":
+            h, new_cache = SelectiveSSM(cfg, dtype=self.dtype, name="ssm")(
+                normed,
+                positions=positions,
+                cache=kv_cache,
+                cache_index=cache_index,
+                lane_meta=lane_meta,
+            )
         else:
             if kv_cache is not None:
                 raise NotImplementedError(
                     f"layer {self.layer_idx}'s {kind!r} mixer has no "
-                    "decode path (no recurrent state a lane, no latent "
-                    "cache entry yet)"
+                    "decode path (no latent cache entry, no delta-rule "
+                    "state a lane yet)"
                 )
             new_cache = None
             if kind == "latent":
@@ -512,36 +521,23 @@ class LuminaTransformer(nn.Module):
         (inference/kv_pool.py) is admission-bounded so positions never
         wrap, and its per-lane writes assume slot == position."""
         cfg = self.config
-        choice = kv_cache_dtype or cfg.kv_cache_dtype
-        d = cfg.head_dim()
-        C = max_len
-        if (
-            rolling
-            and cfg.attention_window is not None
-            and max_len <= cfg.seq_length
-        ):
-            C = min(max_len, ((cfg.attention_window + 127) // 128) * 128)
-        shape = (batch_size, C, cfg.num_kv_heads, d)
 
-        def one(lead):
-            if choice == "int8":
-                # (codes, per-row scales): half the HBM of a bf16 cache,
-                # so max batch·context doubles (see config.kv_cache_dtype).
-                return (
-                    jnp.zeros((*lead, *shape), dtype=jnp.int8),
-                    jnp.ones((*lead, *shape[:-1], 1), dtype=jnp.float32),
-                )
-            return jnp.zeros((*lead, *shape), dtype=self.dtype)
-
-        def pair(*lead):
-            return (one(lead), one(lead))
+        def entry(layer, *lead):
+            """What a lane keeps of `layer`, by its mixer: pages of k/v
+            or a fixed state."""
+            if cfg.mixer_kind(layer) == "ssm":
+                return SelectiveSSM.init_cache(
+                    cfg, batch_size, self.dtype, lead)
+            return GQAttention.init_cache(
+                cfg, batch_size, max_len, self.dtype,
+                kv_cache_dtype=kv_cache_dtype, rolling=rolling, lead=lead)
 
         if cfg.scan_layers:
             return [
-                tuple(pair(count) for _ in offsets)
-                for _, offsets, count in scan_segments(cfg)
+                tuple(entry(start + off, count) for off in offsets)
+                for start, offsets, count in scan_segments(cfg)
             ]
-        return [pair() for _ in range(cfg.num_layers)]
+        return [entry(i) for i in range(cfg.num_layers)]
 
 
 def count_params(params) -> int:

@@ -275,6 +275,15 @@ class ContinuousScheduler:
         # index; only meaningful when the decoder actually has a prefix
         # cache to land pulled pages in.
         self.page_share = page_share
+        pool = getattr(decoder, "pool", None)
+        if page_share is not None and getattr(pool, "keeps_state", False):
+            from luminaai_tpu.inference.kv_pool import StateNotPagedError
+
+            raise StateNotPagedError(
+                "page pull (page_share) is not served with 'ssm' layers: "
+                "a pulled page holds k/v rows and none of the recurrent "
+                "state a lane keeps beside them"
+            )
         if page_share is not None and (
             getattr(decoder, "prefix_cache", None) is not None
         ):
@@ -446,10 +455,18 @@ class ContinuousScheduler:
             "serve_chunk_rows_total",
             "Live prompt rows carried by prefill chunks",
         )
+        # State-space layers (an 'ssm' mixer): rows that went through
+        # the recurrence. 0 without such layers.
+        self._m_ssm_rows = r.counter(
+            "ssm_rows_total",
+            "Live rows through the state-space recurrence: stepped lanes "
+            "and live rows of prefill chunks",
+        )
         # The decoder counts these where they happen; the registry
         # follows (_count_decoder).
         self._decoder_seen = {
             "lane_steps_dropped": 0, "chunks_carried": 0, "chunk_rows": 0,
+            "ssm_rows": 0,
         }
         # Which attention path the decode step compiled (value is always
         # 1; the label is the payload): a scrape shows what served, not
@@ -1448,6 +1465,7 @@ class ContinuousScheduler:
             ("lane_steps_dropped", self._m_lane_steps_dropped),
             ("chunks_carried", self._m_chunks_carried),
             ("chunk_rows", self._m_chunk_rows),
+            ("ssm_rows", self._m_ssm_rows),
         ):
             n = getattr(self.decoder, name, 0)
             seen = self._decoder_seen[name]
@@ -2335,6 +2353,11 @@ class ChatServer:
         Shared by the JSON and SSE paths so the hint means one thing."""
         resolve = getattr(self.engine, "_resolve_gen_key", None)
         if resolve is None:
+            return False
+        config = getattr(self.engine, "config", None)
+        if config is not None and config.keeps_lane_state():
+            # No draft can be rolled out of a recurrent state: the hint
+            # falls back to the scheduler's path, as for sampled params.
             return False
         key = resolve(
             overrides.get("max_new_tokens"),

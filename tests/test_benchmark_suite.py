@@ -15,6 +15,7 @@ import glob
 import importlib
 import os
 
+from benchmark.architectures.jamba.test_reference import *  # noqa: F401,F403
 from benchmark.architectures.kimi_linear.test_reference import *  # noqa: F401,F403
 from benchmark.architectures.prenorm_decoder.test_reference import *  # noqa: F401,F403
 from benchmark.tests.test_architectures import *  # noqa: F401,F403
@@ -26,6 +27,7 @@ from benchmark.tests.test_traffic import *  # noqa: F401,F403
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 COLLECTED = (
+    "benchmark/architectures/jamba/test_reference.py",
     "benchmark/architectures/kimi_linear/test_reference.py",
     "benchmark/architectures/prenorm_decoder/test_reference.py",
     "benchmark/tests/test_architectures.py",
@@ -39,7 +41,7 @@ COLLECTED = (
 
 # benchmark/tests/test_architectures.py was written when the benchmark had
 # ONE architecture, and its resolver test asserts that every cell's is
-# `prenorm_decoder`. PR 35 adds a second one, and a PR that adds to the
+# `prenorm_decoder`. PR 35 adds a second one (PR 37 a third), and a PR that adds to the
 # benchmark may not edit a file the benchmark has: the same test is taken
 # here with each cell held to the architecture its own configuration file
 # names, under the same name so that it is counted once. A `benchmark` PR
@@ -64,7 +66,7 @@ def test_every_cell_resolves_its_architecture():  # noqa: F811
             for name in required:
                 assert hasattr(module, name), (part, name)
         assert set(arch.work.KERNEL_FNS) == manifest.kernel_names(arch.name)
-    assert seen == {"prenorm_decoder", "kimi_linear"}
+    assert seen == {"prenorm_decoder", "kimi_linear", "jamba"}
 
 
 def test_every_benchmark_test_file_is_collected_here():

@@ -260,3 +260,30 @@ def test_flash_compiles_with_values_narrower_than_scores(one_chip):
         ((KB, KS, KH, 128), BF16),
     )
     assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
+# -- the state-space serving cell's kernel (jamba2-3b-serve-burst) -----------
+@pytest.mark.parametrize("slots", [128, 256], ids=["128_lanes", "256_lanes"])
+def test_ssm_scan_compiles_at_the_ticks_shapes(one_chip, slots):
+    """One layer of one tick at Jamba2-3B's widths: `slots` lanes' states
+    of 16 x 5120 float32, their decode rows and a 64-row chunk in bf16.
+    A block of channels has room in VMEM for every lane's slab (the tick
+    in which all are stepped), copied in and out a stepped lane at a
+    time."""
+    from luminaai_tpu.ops import ssm
+
+    n, d, rows = 16, 5120, slots + 64
+
+    def run(state, x, z, dt, b, c, a, skip, pos, slot, start):
+        return ssm.ssm_scan(state, x, z, dt, b, c, a, skip, pos,
+                            lanes=slots, chunk_slot=slot, chunk_start=start)
+
+    f32, i32 = jnp.float32, jnp.int32
+    text = _compile(
+        run, one_chip, ((slots, n, d), f32), ((rows, d), BF16),
+        ((rows, d), BF16), ((rows, d), f32), ((rows, n), f32),
+        ((rows, n), f32), ((n, d), f32), ((d,), f32), ((rows,), i32),
+        ((), i32), ((), i32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "ssm_scan" in text
+    assert ssm._block_width(slots, n, d) == 1024
