@@ -48,15 +48,21 @@ REMAT_POLICIES = {
     # ~[B,S,Hq,D] bf16 + [B,Hq,S] fp32 per layer (~105MB at flagship
     # scale); profiled at ~115ms/step of recompute removed (r3 trace).
     # Every mixer's output carries the "attn_out" tag, the latent mixer's
-    # flash residuals the same two names. The delta-rule kernel tags its
-    # output and its chunk states too ("kda_out", "kda_states": ops/kda.py)
-    # and they are NOT kept here: at 2 x 8192 tokens and 32 heads of 128
-    # the states are 537 MB a layer, and the described-v5e compile of the
-    # kimi-linear cell's step reads 15.19 GB with them against 13.95
-    # without (PERF.md, PR 35), so the block's backward runs the forward
-    # kernel once more instead.
+    # flash residuals the same two names. The delta-rule kernels tag three
+    # things (ops/kda.py). "kda_inverse", each chunk's T = (I + A)^-1 as
+    # `kda_tri` wrote it, IS kept: 16 KB of float32 a chunk a head, 134 MB
+    # a layer at 2 x 8192 tokens and 32 heads, so the block's backward
+    # re-runs `kda_fwd` alone and `kda_bwd` reads the same T; a layer
+    # builds the inverse (ten float32 64^3 products a chunk) once a step
+    # where it built it three times. "kda_out" and "kda_states" are NOT
+    # kept: the states are 537 MB a layer, so the block's backward runs
+    # the forward kernel once more instead. The described-v5e compile of
+    # the kimi-linear cell's step reads 14.45 GB so (13.95 before T was
+    # carried, 15.19 with the states kept too: PERF.md, PR 35 and 38). A
+    # model without the delta-rule mixer has nothing of that name: its
+    # step is the same program.
     "save_attn": jax.checkpoint_policies.save_only_these_names(
-        "attn_out", "ffn_out", "flash_out", "flash_lse"
+        "attn_out", "ffn_out", "flash_out", "flash_lse", "kda_inverse"
     ),
     "dots_saveable": jax.checkpoint_policies.dots_saveable,
     # 'full' = save everything, i.e. no recomputation (jax.checkpoint with

@@ -15,7 +15,7 @@ state entering it:
 
     A_ij = beta_i (k_i * e^{G_i - G_j}) . k_j        j <  i  (strictly lower)
     P_ij = (q_i * e^{G_i - G_j}) . k_j               j <= i
-    T    = (I + A)^{-1}           A is nilpotent: six products, no substitution
+    T    = (I + A)^{-1}           A is nilpotent: ten products, no substitution
     W    = T (beta k * e^{G});   U = T (beta v) - W S
     O    = (q * e^{G}) S + P U
     S   <- Diag(e^{G_C}) S + (k * e^{G_C - G})^T U
@@ -33,18 +33,42 @@ Gates, running sums, A, P, T and S are float32 (float32 matmuls at HIGHEST
 precision); the matmuls against S and U take their operands in the
 activations' dtype (bfloat16 in training) and accumulate in float32.
 
-Forward: a grid over (batch, head, chunk), chunks innermost and in order,
-S^T in float32 VMEM scratch. Under differentiation it also writes each
-chunk's ENTERING state ([B, H, T/C, dv, dk] float32: 64 KB a chunk a
-head), which the backward reads instead of recomputing the scan. Backward:
-the same grid walked from the last chunk to the first with dS in scratch;
-each step differentiates the chunk's own forward math (`jax.vjp` of
-`_chunk_math`, traced into the kernel), so the two cannot drift apart.
-One piece of that math carries its own adjoint: T (I + A) = I gives
-dT = -T dA T, so the cotangent of A is -T^T dT T^T, two products against
-the T the step has just rebuilt, where autodiff through the inverse's ten
-products would run twenty. A chunk's backward is then 44 matmuls, 12 of
-them float32 [64, 64] x [64, 64] (62 and 30 without it).
+T depends on k, beta and g alone: not on the state, not on q. So it is built
+once and carried, in float32, as it was computed. Three kernels, each a
+grid over (batch, head, chunk); by MXU passes a chunk (a float32 product at
+HIGHEST is six bf16 passes, and on the chip the kernels' time follows the
+count):
+
+  `kda_tri`  reads k, beta*k, g; forms G and A's rows and writes
+             T = (I + A)^{-1}, [B, H, T/C, C/2, 2C] float32 (a chunk's
+             [C, C] with the second half of its rows beside the first:
+             whole 128-lane tiles, 16 KB a chunk). No scratch, no order: every
+             grid axis is parallel. G 6 + scores 24 + the inverse's ten
+             products 60 = 90 passes.
+  `kda_fwd`  reads q, k, beta*k, beta*v, g and the chunk's T; chunks
+             innermost and in order, S^T in float32 VMEM scratch. It forms
+             G and P's rows, never A: 6 + 24 + W and T (beta v) 12 + the
+             four matmuls against the state 4 = 46 passes (106 when it
+             built T itself). Under differentiation it also writes each
+             chunk's ENTERING state ([B, H, T/C, dv, dk] float32: 64 KB a
+             chunk a head), which the backward reads instead of
+             recomputing the scan.
+  `kda_bwd`  the same grid walked from the last chunk to the first with dS
+             in scratch, reading the same T and the entering state; each
+             step differentiates the chunk's own forward math (`jax.vjp`
+             of `_chunk_math`, traced into the kernel), so the two cannot
+             drift apart. There the inverse is `_carried_inverse(A, T)`:
+             its primal is the T that was read, and its cotangent reaches
+             k, beta*k and g through A by the inverse's own adjoint: from
+             T (I + A) = I, dT = -T dA T, so dA = -T^T dT T^T, two
+             products, where autodiff through the ten would run twenty. 34
+             matmuls, 2 of them float32 [64, 64] x [64, 64], 144 passes
+             (204 when it rebuilt T first).
+
+T carries the name "kda_inverse" (`checkpoint_name`): a remat policy that
+keeps it (models/transformer.py `save_attn`) re-runs `kda_fwd` alone in the
+block's backward, and a layer builds the inverse once a step: 90 + 2 x 46 +
+144 = 326 passes a chunk where it ran 106 + 106 + 204 = 416.
 
 Layout: q, k, g [B, T, H, dk]; v [B, T, H, dv]; beta [B, T, H]. The
 kernels read [B, T, H*d] blocks of (1, C, d) directly, no head-major
@@ -59,6 +83,8 @@ patch to compile the real kernels for a described chip.
 from __future__ import annotations
 
 import functools
+import math
+
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
@@ -69,6 +95,10 @@ from luminaai_tpu.ops import flash_attention as _fa
 
 CHUNK = 64
 SUB = 16
+# Chunks a grid step of `kda_tri`. On a v5e at 2 x 8192 tokens and 32 heads:
+# 14.71 ms a call at 1, 13.76 at 2, 13.29 at 4, 13.01 at 8 (a grid step's
+# fixed cost, 8192 of them at 1); 8 unrolls twice the code for 0.3 ms.
+_TRI_CHUNKS = 4
 _HI = jax.lax.Precision.HIGHEST
 _F32 = jnp.float32
 
@@ -115,35 +145,90 @@ def _unit_lower_inverse_bwd(T, dT):
 _unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
 
 
-def _chunk_math(q, k, kb, vb, g, st, *, sub: int, mxu):
-    """One chunk of one head. q, k, kb (= beta*k) [C, dk], vb (= beta*v)
-    [C, dv], g [C, dk] float32, st = S^T [dv, dk] float32 entering the
-    chunk; `mxu` is the operand dtype of the matmuls against S and U.
-    Returns (o [C, dv] float32, S^T leaving)."""
-    C, dk = g.shape
-    lo_p = None if mxu == jnp.bfloat16 else _HI  # fp32 runs stay exact
+@jax.custom_vjp
+def _carried_inverse(A, T):
+    """The T that `kda_tri` wrote from this chunk's A, as a function of A:
+    the primal is the carried T, the cotangent of A is the inverse's."""
+    del A
+    return T
+
+
+def _carried_inverse_fwd(A, T):
+    del A
+    return T, T
+
+
+def _carried_inverse_bwd(T, dT):
+    return _unit_lower_inverse_bwd(T, dT) + (None,)
+
+
+_carried_inverse.defvjp(_carried_inverse_fwd, _carried_inverse_bwd)
+
+
+def _running_sum(g):
+    C = g.shape[0]
     row = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
-    tok = jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0)
-    G = _dot((row >= col).astype(_F32), g, _NN, _HI)  # inclusive running sum
-    qf, kf, kbf = q.astype(_F32), k.astype(_F32), kb.astype(_F32)
+    return _dot((row >= col).astype(_F32), g, _NN, _HI)  # inclusive
 
-    a_rows, p_rows = [], []
+
+def _scores(xs, kf, G, sub: int):
+    """For each x of `xs` ([C, dk] float32) the [C, C] scores
+    (x_i * e^{G_i - G_j}) . k_j, unmasked, a `sub`-row sub-chunk at a time
+    against that sub-chunk's first row; the sub-chunk's rows of every x
+    ride one product."""
+    C = G.shape[0]
+    tok = jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0)
+    rows = [[] for _ in xs]
     for a in range(C // sub):
         lo, hi = a * sub, (a + 1) * sub
         Ga = G[lo:hi]
         Gn = Ga[:1]                                  # the sub-chunk's first row
         rf = jnp.exp(Ga - Gn)                        # <= 1
-        lhs = jnp.concatenate([kbf[lo:hi] * rf, qf[lo:hi] * rf], axis=0)
+        lhs = jnp.concatenate([x[lo:hi] * rf for x in xs], axis=0)
         seen = tok < hi                              # rows this sub-chunk may see
         rhs = jnp.where(seen, kf * jnp.exp(jnp.where(seen, Gn - G, 0.0)), 0.0)
-        s = _dot(lhs, rhs, _NT, _HI)                 # [2*sub, C]
-        a_rows.append(s[:sub])
-        p_rows.append(s[sub:])
-    A = jnp.where(row > col, jnp.concatenate(a_rows, axis=0), 0.0)
-    P = jnp.where(row >= col, jnp.concatenate(p_rows, axis=0), 0.0)
+        s = _dot(lhs, rhs, _NT, _HI)                 # [len(xs)*sub, C]
+        for n, r in enumerate(rows):
+            r.append(s[n * sub:(n + 1) * sub])
+    return [jnp.concatenate(r, axis=0) for r in rows]
 
-    T = _unit_lower_inverse(A)
+
+def _lower(a, *, strictly: bool):
+    C = a.shape[0]
+    row = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    return jnp.where(row > col if strictly else row >= col, a, 0.0)
+
+
+def _tri_math(k, kb, g, *, sub: int):
+    """One chunk of one head: T = (I + A)^{-1} [C, C] float32 from k, kb
+    (= beta*k) [C, dk] and g [C, dk] float32. No state and no q in it."""
+    G = _running_sum(g)
+    a, = _scores([kb.astype(_F32)], k.astype(_F32), G, sub)
+    return _unit_lower_inverse(_lower(a, strictly=True))
+
+
+def _chunk_math(q, k, kb, vb, g, st, T, *, sub: int, mxu,
+                through_inverse: bool = False):
+    """One chunk of one head. q, k, kb (= beta*k) [C, dk], vb (= beta*v)
+    [C, dv], g [C, dk] float32, st = S^T [dv, dk] float32 entering the
+    chunk, T [C, C] float32 the chunk's inverse as `_tri_math` made it;
+    `mxu` is the operand dtype of the matmuls against S and U.
+    `through_inverse` (the backward's `jax.vjp`) also forms A, beside P in
+    the same products, so that T's cotangent reaches k, kb and g.
+    Returns (o [C, dv] float32, S^T leaving)."""
+    C = g.shape[0]
+    lo_p = None if mxu == jnp.bfloat16 else _HI  # fp32 runs stay exact
+    G = _running_sum(g)
+    qf, kf, kbf = q.astype(_F32), k.astype(_F32), kb.astype(_F32)
+
+    if through_inverse:
+        a, p = _scores([kbf, qf], kf, G, sub)
+        T = _carried_inverse(_lower(a, strictly=True), T)
+    else:
+        p, = _scores([qf], kf, G, sub)
+    P = _lower(p, strictly=False)
 
     E = jnp.exp(G)                                   # <= 1: underflow is benign
     W = _dot(T, kbf * E, _NN, _HI)                   # [C, dk]
@@ -159,7 +244,29 @@ def _chunk_math(q, k, kb, vb, g, st, *, sub: int, mxu):
     return o, st_new
 
 
-def _fwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, o_ref, *rest,
+def _pack(T):
+    """A chunk's [C, C] as it lies in HBM: the second half of its rows
+    beside the first, [C/2, 2C], so that the chunk's block is whole
+    128-lane tiles (a last dimension of 64 is stored padded to 128: twice
+    the bytes)."""
+    half = T.shape[0] // 2
+    return jnp.concatenate([T[:half], T[half:]], axis=1)
+
+
+def _unpack(P):
+    C = P.shape[1] // 2
+    return jnp.concatenate([P[:, :C], P[:, C:]], axis=0)
+
+
+def _tri_kernel(k_ref, kb_ref, g_ref, t_ref, *, sub: int):
+    C = t_ref.shape[-1] // 2
+    for n in range(t_ref.shape[2]):
+        rows = slice(n * C, (n + 1) * C)
+        t_ref[0, 0, n] = _pack(_tri_math(
+            k_ref[0, rows], kb_ref[0, rows], g_ref[0, rows], sub=sub))
+
+
+def _fwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, t_ref, o_ref, *rest,
                 sub: int, mxu, keep_states: bool):
     st_scr = rest[-1]
 
@@ -172,12 +279,12 @@ def _fwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, o_ref, *rest,
         rest[0][0, 0, 0] = st
     o, st_new = _chunk_math(
         q_ref[0], k_ref[0], kb_ref[0], vb_ref[0], g_ref[0], st,
-        sub=sub, mxu=mxu)
+        _unpack(t_ref[0, 0, 0]), sub=sub, mxu=mxu)
     o_ref[0] = o.astype(o_ref.dtype)
     st_scr[...] = st_new
 
 
-def _bwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, st_ref, do_ref,
+def _bwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, st_ref, t_ref, do_ref,
                 dq_ref, dk_ref, dkb_ref, dvb_ref, dg_ref, dst_scr, *,
                 sub: int, mxu):
     @pl.when(pl.program_id(2) == 0)
@@ -185,7 +292,8 @@ def _bwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, st_ref, do_ref,
         dst_scr[...] = jnp.zeros_like(dst_scr)
 
     _, vjp = jax.vjp(
-        functools.partial(_chunk_math, sub=sub, mxu=mxu),
+        functools.partial(_chunk_math, T=_unpack(t_ref[0, 0, 0]), sub=sub,
+                          mxu=mxu, through_inverse=True),
         q_ref[0], k_ref[0], kb_ref[0], vb_ref[0], g_ref[0],
         st_ref[0, 0, 0])
     dq, dk, dkb, dvb, dg, dst = vjp(
@@ -198,87 +306,121 @@ def _bwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, st_ref, do_ref,
     dst_scr[...] = dst
 
 
-def _params():
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
+def _params(*semantics):
+    return pltpu.CompilerParams(dimension_semantics=semantics)
 
 
 def _specs(C, dk, dv, order):
-    """BlockSpecs of q, k, kb (dk wide), vb (dv wide) and g over
-    [B, T, H*d] arrays; `order(t)` maps the grid's chunk axis to a chunk."""
+    """BlockSpecs of q, k, kb, g (dk wide) and vb (dv wide) over
+    [B, T, H*d] arrays, and of a chunk's [.., r, c] float32 block of a
+    [B, H, T/C, r, c] array; `order(t)` maps the grid's chunk axis to a
+    chunk."""
     def tok(d):
         return pl.BlockSpec((1, C, d), lambda b, h, t: (b, order(t), h))
 
-    return tok(dk), tok(dv)
+    def per_chunk(r, c):
+        return pl.BlockSpec(
+            (1, 1, 1, r, c), lambda b, h, t: (b, h, order(t), 0, 0))
+
+    return tok(dk), tok(dv), per_chunk
 
 
-def _fwd_call(q, k, kb, vb, g, *, H, C, mxu, keep_states):
+def _tri_call(k, kb, g, *, H, C):
+    """T = (I + A)^{-1} of every chunk, packed: [B, H, T/C, C/2, 2C]
+    float32. No chunk waits for another, so every grid axis is parallel
+    and a grid step takes `_TRI_CHUNKS` chunks where that divides T/C."""
+    B, T, _ = k.shape
+    dk = k.shape[-1] // H
+    nt = T // C
+    n = math.gcd(nt, _TRI_CHUNKS)
+    kspec = pl.BlockSpec((1, n * C, dk), lambda b, h, t: (b, t, h))
+    return pl.pallas_call(
+        functools.partial(_tri_kernel, sub=min(SUB, C)),
+        grid=(B, H, nt // n),
+        in_specs=[kspec, kspec, kspec],
+        out_specs=pl.BlockSpec(
+            (1, 1, n, C // 2, 2 * C), lambda b, h, t: (b, h, t, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, H, nt, C // 2, 2 * C), _F32),
+        compiler_params=_params("parallel", "parallel", "parallel"),
+        interpret=_fa._interpret(),
+        name="kda_tri",
+    )(k, kb, g)
+
+
+def _fwd_call(q, k, kb, vb, g, inv, *, H, C, mxu, keep_states):
     B, T, _ = q.shape
     dk, dv = q.shape[-1] // H, vb.shape[-1] // H
     nt = T // C
-    kspec, vspec = _specs(C, dk, dv, lambda t: t)
+    kspec, vspec, per_chunk = _specs(C, dk, dv, lambda t: t)
     out_specs = [vspec]
     out_shape = [jax.ShapeDtypeStruct((B, T, H * dv), vb.dtype)]
     if keep_states:
-        out_specs.append(pl.BlockSpec(
-            (1, 1, 1, dv, dk), lambda b, h, t: (b, h, t, 0, 0)))
+        out_specs.append(per_chunk(dv, dk))
         out_shape.append(jax.ShapeDtypeStruct((B, H, nt, dv, dk), _F32))
     out = pl.pallas_call(
         functools.partial(_fwd_kernel, sub=min(SUB, C), mxu=mxu,
                           keep_states=keep_states),
         grid=(B, H, nt),
-        in_specs=[kspec, kspec, kspec, vspec, kspec],
+        in_specs=[kspec, kspec, kspec, vspec, kspec,
+                  per_chunk(C // 2, 2 * C)],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((dv, dk), _F32)],
-        compiler_params=_params(),
+        compiler_params=_params("parallel", "parallel", "arbitrary"),
         interpret=_fa._interpret(),
         name="kda_fwd",
-    )(q, k, kb, vb, g)
+    )(q, k, kb, vb, g, inv)
     return out if keep_states else (out[0], None)
 
 
-def _bwd_call(q, k, kb, vb, g, states, do, *, H, C, mxu):
+def _bwd_call(q, k, kb, vb, g, states, inv, do, *, H, C, mxu):
     B, T, _ = q.shape
     dk, dv = q.shape[-1] // H, vb.shape[-1] // H
     nt = T // C
-    kspec, vspec = _specs(C, dk, dv, lambda t: nt - 1 - t)
-    sspec = pl.BlockSpec(
-        (1, 1, 1, dv, dk), lambda b, h, t: (b, h, nt - 1 - t, 0, 0))
+    kspec, vspec, per_chunk = _specs(C, dk, dv, lambda t: nt - 1 - t)
     like = lambda x, dt=None: jax.ShapeDtypeStruct(x.shape, dt or x.dtype)  # noqa: E731
     return pl.pallas_call(
         functools.partial(_bwd_kernel, sub=min(SUB, C), mxu=mxu),
         grid=(B, H, nt),
-        in_specs=[kspec, kspec, kspec, vspec, kspec, sspec, vspec],
+        in_specs=[kspec, kspec, kspec, vspec, kspec, per_chunk(dv, dk),
+                  per_chunk(C // 2, 2 * C), vspec],
         out_specs=[kspec, kspec, kspec, vspec, kspec],
         out_shape=[like(q), like(k), like(kb), like(vb), like(g)],
         scratch_shapes=[pltpu.VMEM((dv, dk), _F32)],
-        compiler_params=_params(),
+        compiler_params=_params("parallel", "parallel", "arbitrary"),
         interpret=_fa._interpret(),
         name="kda_bwd",
-    )(q, k, kb, vb, g, states, do)
+    )(q, k, kb, vb, g, states, inv, do)
+
+
+def _inverse_then_fwd(q, k, kb, vb, g, H, C, mxu, keep_states):
+    """The two forward calls. T is named so that a remat policy can keep it
+    across the forward / backward boundary (models/transformer.py
+    `save_attn` does): the block's backward then re-runs `kda_fwd` alone,
+    for the states, and `kda_tri` is dead code there."""
+    inv = checkpoint_name(_tri_call(k, kb, g, H=H, C=C), "kda_inverse")
+    o, states = _fwd_call(q, k, kb, vb, g, inv, H=H, C=C, mxu=mxu,
+                          keep_states=keep_states)
+    return o, states, inv
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
 def _kda_flat(q, k, kb, vb, g, H, C, mxu):
-    return _fwd_call(q, k, kb, vb, g, H=H, C=C, mxu=mxu,
-                     keep_states=False)[0]
+    return _inverse_then_fwd(q, k, kb, vb, g, H, C, mxu, False)[0]
 
 
 def _kda_flat_fwd(q, k, kb, vb, g, H, C, mxu):
-    o, states = _fwd_call(q, k, kb, vb, g, H=H, C=C, mxu=mxu,
-                          keep_states=True)
-    # Named so that a remat policy can keep them across the forward /
-    # backward boundary (models/transformer.py `save_attn`): the block's
-    # backward then does not run the forward kernel a second time.
+    o, states, inv = _inverse_then_fwd(q, k, kb, vb, g, H, C, mxu, True)
+    # Named too, and kept by no policy of this repo: with them the block's
+    # backward would not run `kda_fwd` a second time (537 MB a layer at
+    # the kimi-linear cell's shapes).
     o = checkpoint_name(o, "kda_out")
     states = checkpoint_name(states, "kda_states")
-    return o, (q, k, kb, vb, g, states)
+    return o, (q, k, kb, vb, g, states, inv)
 
 
 def _kda_flat_bwd(H, C, mxu, res, do):
-    q, k, kb, vb, g, states = res
-    return tuple(_bwd_call(q, k, kb, vb, g, states, do, H=H, C=C, mxu=mxu))
+    return tuple(_bwd_call(*res, do, H=H, C=C, mxu=mxu))
 
 
 _kda_flat.defvjp(_kda_flat_fwd, _kda_flat_bwd)

@@ -225,9 +225,11 @@ KB, KS, KH = 2, 8192, 32  # sequences a chip, tokens a sequence, heads
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_states_bwd"])
 def test_kda_kernels_compile_at_cell_shapes(one_chip, grad):
     """The chunked delta rule at 32 heads of 128 over 2 x 8192 tokens:
-    the chunk's math (and, backward, its `jax.vjp` with the inverse's
-    closed-form adjoint -T^T dT T^T, traced into the kernel) must lower in
-    Mosaic and fit the scoped VMEM."""
+    `kda_tri` (every chunk's inverse, a grid with no order), the chunk's
+    math reading that inverse (and, backward, its `jax.vjp` with the
+    inverse's closed-form adjoint -T^T dT T^T against the carried T,
+    traced into the kernel) must lower in Mosaic and fit the scoped
+    VMEM."""
     from luminaai_tpu.ops import kda
 
     def run(q, k, v, g, beta):
@@ -242,8 +244,9 @@ def test_kda_kernels_compile_at_cell_shapes(one_chip, grad):
     fn = jax.grad(loss, argnums=(0, 1, 2, 3, 4)) if grad else run
     text = _compile(fn, one_chip, *shapes)
     assert text.count('custom_call_target="tpu_custom_call"') == (
-        2 if grad else 1)
-    assert "kda_fwd" in text and ("kda_bwd" in text) is grad
+        3 if grad else 2)
+    assert "kda_tri" in text and "kda_fwd" in text
+    assert ("kda_bwd" in text) is grad
 
 
 def test_flash_compiles_with_values_narrower_than_scores(one_chip):

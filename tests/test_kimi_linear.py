@@ -100,26 +100,149 @@ def _eqns(jaxpr):
             yield from _eqns(sub)
 
 
-def test_chunk_backward_runs_twelve_square_products_not_thirty():
-    """The chunk's `jax.vjp` at the cell's shapes: ten [64,64] x [64,64]
-    products rebuild the inverse and two are its adjoint. Autodiff through
-    the ten would add twenty."""
+def _one_function_chunk(q, k, kb, vb, g, st, *, sub, mxu):
+    """The chunk's math as ONE function, as ops/kda.py held it before the
+    inverse was carried (PR 36's `_chunk_math`): A's rows and P's in the
+    same products, T rebuilt in place. Returns (o, S^T leaving, T)."""
+    C = g.shape[0]
+    dot = lambda a, b, dims, prec=HI: jax.lax.dot_general(  # noqa: E731
+        a, b, (dims, ((), ())), precision=prec,
+        preferred_element_type=jnp.float32)
+    nn, nt, tn = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))
+    lo_p = None if mxu == jnp.bfloat16 else HI
+    row = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    tok = jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0)
+    G = dot((row >= col).astype(jnp.float32), g, nn)
+    qf, kf, kbf = (x.astype(jnp.float32) for x in (q, k, kb))
+    a_rows, p_rows = [], []
+    for a in range(C // sub):
+        lo, hi = a * sub, (a + 1) * sub
+        Gn = G[lo:lo + 1]
+        rf = jnp.exp(G[lo:hi] - Gn)
+        lhs = jnp.concatenate([kbf[lo:hi] * rf, qf[lo:hi] * rf], axis=0)
+        seen = tok < hi
+        rhs = jnp.where(seen, kf * jnp.exp(jnp.where(seen, Gn - G, 0.0)), 0.0)
+        s = dot(lhs, rhs, nt)
+        a_rows.append(s[:sub])
+        p_rows.append(s[sub:])
+    A = jnp.where(row > col, jnp.concatenate(a_rows, axis=0), 0.0)
+    P = jnp.where(row >= col, jnp.concatenate(p_rows, axis=0), 0.0)
+    T = kda_ops._unit_lower_inverse(A)
+    E = jnp.exp(G)
+    W = dot(T, kbf * E, nn)
+    st_lo = st.astype(mxu)
+    U_lo = (dot(T, vb.astype(jnp.float32), nn)
+            - dot(W.astype(mxu), st_lo, nt, lo_p)).astype(mxu)
+    o = dot((qf * E).astype(mxu), st_lo, nt, lo_p) + dot(
+        P.astype(mxu), U_lo, nn, lo_p)
+    Gc = G[C - 1:]
+    Kd = (kf * jnp.exp(Gc - G)).astype(mxu)
+    return o, st * jnp.exp(Gc) + dot(U_lo, Kd, tn, lo_p), T
+
+
+# One chunk, a tail that is padded, the cell's 128-wide heads over 16
+# chunks in training's bf16, and a strong gate: carrying T re-orders
+# nothing, so nothing may move by a bit.
+@pytest.mark.parametrize("T,gate,d,dtype", [
+    (64, 1.0, 32, jnp.float32), (200, 1.0, 32, jnp.float32),
+    (1024, 1.0, 128, jnp.bfloat16), (96, 3.0, 32, jnp.float32)])
+def test_carried_inverse_is_the_one_function_maths_bit_for_bit(T, gate, d, dtype):
+    B, H, C = 1, 2, kda_ops.CHUNK
+    q, k, v, g, beta = _delta_inputs(B, T, H, d, d, gate)
+    q, k, v = (x.astype(dtype) for x in (q, k, v))
+    got = kda_ops.kda(q, k, v, g, beta)
+
+    b = beta[..., None]
+    kb = (k.astype(jnp.float32) * b).astype(dtype)
+    vb = (v.astype(jnp.float32) * b).astype(dtype)
+    pad = ((0, 0), (0, -T % C), (0, 0), (0, 0))
+    q, k, kb, vb, g = (jnp.pad(x, pad) for x in (q, k, kb, vb, g))
+    nt = q.shape[1] // C
+    flat = lambda x: x.reshape(B, nt * C, H * d)  # noqa: E731
+    carried = kda_ops._tri_call(flat(k), flat(kb), flat(g), H=H, C=C)
+    assert carried.shape == (B, H, nt, C // 2, 2 * C)
+    assert carried.dtype == jnp.float32
+
+    chunk = jax.jit(functools.partial(
+        _one_function_chunk, sub=kda_ops.SUB, mxu=jnp.dtype(dtype)))
+    for h in range(H):
+        st = jnp.zeros((d, d), jnp.float32)
+        for t in range(nt):
+            o, st, inv = chunk(*(x[0, t * C:(t + 1) * C, h]
+                                 for x in (q, k, kb, vb, g)), st)
+            assert jnp.array_equal(
+                kda_ops._unpack(carried[0, h, t]), inv), (h, t)
+            rows = slice(t * C, min((t + 1) * C, T))
+            assert jnp.array_equal(
+                got[0, rows, h], o.astype(dtype)[:rows.stop - rows.start]
+            ), (h, t)
+
+
+def _chunk_fns():
+    math = functools.partial(
+        kda_ops._chunk_math, sub=kda_ops.SUB, mxu=jnp.bfloat16)
+
+    def tri(q, k, kb, vb, g, st, inv, do, dst):
+        return kda_ops._tri_math(k, kb, g, sub=kda_ops.SUB)
+
+    def fwd(q, k, kb, vb, g, st, inv, do, dst):
+        return math(q, k, kb, vb, g, st, inv)
+
+    def bwd(q, k, kb, vb, g, st, inv, do, dst):
+        _, vjp = jax.vjp(functools.partial(
+            math, T=inv, through_inverse=True), q, k, kb, vb, g, st)
+        return vjp((do, dst))
+
+    return {"kda_tri": tri, "kda_fwd": fwd, "kda_bwd": bwd}
+
+
+@pytest.mark.parametrize("kernel,want", [
+    ("kda_tri", 10), ("kda_fwd", 0), ("kda_bwd", 2)])
+def test_chunk_backward_runs_twelve_square_products_not_thirty(kernel, want):
+    """The three kernels' chunk functions at the cell's shapes: the ten
+    [64,64] x [64,64] products that build the inverse run in `kda_tri`
+    alone; the forward reads T and runs none; the backward's `jax.vjp`
+    runs the two of the adjoint -T^T dT T^T against the T it read (twelve
+    when it rebuilt T first; autodiff through the ten would add twenty)."""
     C, d = 64, 128
     lo = jax.ShapeDtypeStruct((C, d), jnp.bfloat16)
     f32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32)
-
-    def fwd_and_bwd(q, k, kb, vb, g, st, do, dst):
-        _, vjp = jax.vjp(functools.partial(
-            kda_ops._chunk_math, sub=kda_ops.SUB, mxu=jnp.bfloat16),
-            q, k, kb, vb, g, st)
-        return vjp((do, dst))
-
-    jaxpr = jax.make_jaxpr(fwd_and_bwd)(
-        lo, lo, lo, lo, f32((C, d)), f32((d, d)), f32((C, d)), f32((d, d)))
+    jaxpr = jax.make_jaxpr(_chunk_fns()[kernel])(
+        lo, lo, lo, lo, f32((C, d)), f32((d, d)), f32((C, C)),
+        f32((C, d)), f32((d, d)))
     square = [e for e in _eqns(jaxpr.jaxpr) if e.primitive.name == "dot_general"
               and all(v.aval.shape == (C, C) for v in e.invars)]
-    assert len(square) == 12
+    assert len(square) == want
     assert all(e.params["precision"] == (HI, HI) for e in square)
+
+
+# Engagement: T kept across the remat boundary means the block's backward
+# re-runs `kda_fwd` (for the chunk states) and NOT `kda_tri`; a policy that
+# keeps nothing re-runs both.
+@pytest.mark.parametrize("policy,tri,fwd", [
+    ("save_attn", 2, 4), ("nothing_saveable", 4, 4)])
+def test_remat_keeps_the_inverse_and_reruns_the_forward_alone(policy, tri, fwd):
+    from luminaai_tpu.models.transformer import LuminaTransformer
+
+    cfg = _tiny(layer_mixers=("kda", "kda"), seq_length=128,
+                gradient_checkpointing=True, remat_policy=policy)
+    ids = jnp.asarray(
+        np.random.RandomState(0).randint(1, 256, (2, 128)), jnp.int32)
+    model = LuminaTransformer(cfg)
+    params = jax.jit(model.init)(jax.random.key(0), ids)["params"]
+
+    def loss(p):
+        out, _ = model.apply({"params": p}, ids, deterministic=True)
+        return out.astype(jnp.float32).sum()
+
+    names = [e.params["name"] for e in _eqns(
+        jax.make_jaxpr(jax.grad(loss))(params).jaxpr)
+        if e.primitive.name == "pallas_call"]
+    assert sorted(set(names)) == ["kda_bwd", "kda_fwd", "kda_tri"]
+    assert names.count("kda_tri") == tri
+    assert names.count("kda_fwd") == fwd
+    assert names.count("kda_bwd") == 2
 
 
 def test_kda_recurrence_is_the_references_delta_rule():
