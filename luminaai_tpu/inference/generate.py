@@ -2281,10 +2281,13 @@ class StepwiseDecoder:
         """Put one tick on the device's queue: the record collect_step
         reads, or None (nothing enqueued) when a step is in flight and
         there is neither a lane it leaves alive nor a chunk."""
-        fn, tick, live = self._next_step(sample_key, chunk, step_lanes)
+        span, region = self.tracer.span, self.phases.region
+        # (`decode.pack` and `decode.book` are spans alone: the ledger
+        # books the host's packing and bookkeeping to `sched`.)
+        with span("decode.pack"):
+            fn, tick, live = self._next_step(sample_key, chunk, step_lanes)
         if self._inflight and chunk is None and not live.any():
             return None
-        span, region = self.tracer.span, self.phases.region
         with region("put"), span("decode.put"):
             tick_d = jax.device_put(tick)
         with region("dispatch"), span("decode.dispatch"):
@@ -2339,20 +2342,23 @@ class StepwiseDecoder:
                 self.tracer.span("decode.fetch"):
             nxt_h = np.asarray(step["nxt"])
             eos_h = np.asarray(step["eos"])
-        stepped = step["stepped"]
-        eos_h = eos_h & stepped
-        self._tokens[stepped] = nxt_h[stepped]
-        self._pos[stepped] += 1
-        self.pool.lengths[stepped] += 1
-        self._budget[stepped] -= 1
-        self._active &= ~eos_h
-        for slot in np.flatnonzero(eos_h):
-            self._drop_ahead(int(slot))
-        chunk = step["chunk"]
-        if chunk is not None and chunk["last"]:
-            self._first_token(chunk["st"], int(nxt_h[chunk["st"]["slot"]]))
-        self.steps += 1
-        return nxt_h, stepped & ~eos_h, eos_h
+        with self.tracer.span("decode.book"):
+            stepped = step["stepped"]
+            eos_h = eos_h & stepped
+            self._tokens[stepped] = nxt_h[stepped]
+            self._pos[stepped] += 1
+            self.pool.lengths[stepped] += 1
+            self._budget[stepped] -= 1
+            self._active &= ~eos_h
+            for slot in np.flatnonzero(eos_h):
+                self._drop_ahead(int(slot))
+            chunk = step["chunk"]
+            if chunk is not None and chunk["last"]:
+                self._first_token(
+                    chunk["st"], int(nxt_h[chunk["st"]["slot"]])
+                )
+            self.steps += 1
+            return nxt_h, stepped & ~eos_h, eos_h
 
     def abandon_steps(self) -> None:
         """Forget every step in flight: nothing of them is read. For

@@ -174,8 +174,10 @@ class SpanTracer:
     a Span; on exit the span (duration, attrs, error) is appended to the
     JSONL file under a lock. Nesting is per-thread: a span opened inside
     another on the same thread records it as parent, and the outermost
-    span starts a new trace id — in serving, the per-request span, so
-    every child carries the request's trace id.
+    span starts a new trace id: on the scheduler thread a tick's, on an
+    HTTP thread a request's. What crosses ticks and threads (a request
+    inside the scheduler) is written whole by `record()`, under a trace
+    id of its own.
     """
 
     def __init__(
@@ -230,6 +232,36 @@ class SpanTracer:
         else:
             s = Span(name, next(self._trace_ids), None, attrs)
         return _OpenSpan(self, s)
+
+    def record(
+        self,
+        name: str,
+        ts: float,
+        duration_s: float,
+        parent: Optional[Span] = None,
+        **attrs: Any,
+    ) -> Optional[Span]:
+        """Write a span whose two ends were no `with` block on one
+        thread: a request's stages, stamped where they happened and
+        written when the request ends. `ts` is the start on the wall
+        clock (`Span.ts`'s own), `parent` a span this call returned
+        before; without one the span is a root with a trace id of its
+        own. Returns the span, or None (nothing made) when disabled: a
+        caller with attributes to gather checks `enabled` first.
+
+        The JSONL sink and the ring alone: a `TraceAnnotation` cannot be
+        back-dated, so the profiler never sees these; `capture_clock`
+        lays their `ts` on its timeline."""
+        if not self.enabled:
+            return None
+        if parent is None:
+            s = Span(name, next(self._trace_ids), None, attrs)
+        else:
+            s = Span(name, parent.trace_id, parent.span_id, attrs)
+        s.t0 = ts
+        s.duration_s = duration_s
+        self._record(s)
+        return s
 
     def _record(self, span: Span) -> None:
         with self._write_lock:

@@ -110,7 +110,8 @@ class _ContinuousRequest:
     SSE streams, an Event + result for blocking submits)."""
 
     def __init__(self, prompt, max_new, sample_key, seed, stream,
-                 deadline=None, request_id=None, tenant=ANON_TENANT):
+                 deadline=None, request_id=None, tenant=ANON_TENANT,
+                 t_submit=None):
         self.prompt = list(prompt)
         self.max_new = int(max_new)
         self.sample_key = sample_key
@@ -131,7 +132,40 @@ class _ContinuousRequest:
         self.slot: Optional[int] = None
         self.prompt_tokens = 0
         self.admitted_step: Optional[int] = None
+        # WALL time, for the deadline and the events' `ts` alone: no
+        # duration is taken from it.
         self.t0 = time.time()
+        # The request's life. Every instant is read from ONE monotonic
+        # clock, the scheduler's `_clock` (submit() reads the same
+        # function on the caller's thread; the rest are the worker's),
+        # so the stages add up to the whole, per request and in the
+        # histograms' sums:
+        #   t_submit -> t_admit        queue wait   (slot acquired)
+        #   t_admit -> t_first_chunk   prefill wait (a turn of the ring)
+        #   t_first_chunk -> t_last_chunk  prefill ride (its chunks' ticks)
+        #   t_last_chunk -> t_first_token  first-token lag (that tick on
+        #       the device, read one dispatch later: the lookahead)
+        #   t_first_token -> t_finish  decode
+        # None = not reached. A span's wall-clock `ts` is derived once,
+        # where the spans are written (_write_request_spans).
+        self.t_submit = time.monotonic() if t_submit is None else t_submit
+        self.t_admit: Optional[float] = None
+        self.t_first_chunk: Optional[float] = None
+        self.t_last_chunk: Optional[float] = None
+        self.t_first_token: Optional[float] = None
+        self.t_finish: Optional[float] = None
+        # Chunks that rode a tick; steps dispatched before admission and
+        # from there through the last chunk's (ticks - chunks = turns of
+        # the ring it waited); whether a chunk of it could ride the next
+        # tick (ContinuousScheduler._runnable counts these).
+        self.chunks = 0
+        self.dispatched_at_admit = 0
+        self.ticks = 0
+        self.runnable = False
+
+    @property
+    def turns_waited(self) -> int:
+        return self.ticks - self.chunks
 
 
 class _StepAtCollect:
@@ -315,12 +349,17 @@ class ContinuousScheduler:
         # concurrent depth read with "dict changed size during
         # iteration".
         self._tq_lock = threading.Lock()
-        # Admissions mid-prefill: slot -> (request, decoder chunk state,
-        # admission timestamp), a ring in admission order. ONE chunk a
-        # tick rides the decode step the worker dispatches (_next_chunk),
-        # so a long prompt costs concurrent lanes no forward pass of its
-        # own; an entry stays until its first token is read (_first_tokens).
-        self._prefilling: Dict[int, Tuple[Any, Any, float]] = {}
+        # Admissions mid-prefill: slot -> (request, decoder chunk state),
+        # a ring in admission order. ONE chunk a tick rides the decode
+        # step the worker dispatches (_next_chunk), so a long prompt
+        # costs concurrent lanes no forward pass of its own; an entry
+        # stays until its first token is read (_first_tokens).
+        self._prefilling: Dict[int, Tuple[Any, Any]] = {}
+        # How many of them have a chunk that could ride the next tick
+        # (not parked behind a dedup leader, last chunk not yet
+        # dispatched): `request.runnable` of each, kept by _set_runnable
+        # so that a tick never walks the ring to count them.
+        self._runnable = 0
         self.q: "queue.Queue" = queue.Queue()
         self.window = max(0.0, float(admission_window_ms)) / 1000.0
         # /stats names: batches = generations (one sampling key each),
@@ -393,6 +432,31 @@ class ContinuousScheduler:
             "serve_ttft_seconds",
             "Submit-to-first-token latency per request",
             buckets=buckets,
+        )
+        # serve_prefill_seconds by stage, observed with it (once a
+        # request, _prefill_done): queue wait + these three = TTFT.
+        self._m_prefill_wait = r.histogram(
+            "serve_prefill_wait_seconds",
+            "Admission to the request's first prefill chunk dispatched "
+            "(a turn of the round-robin ring; a dedup follower's park)",
+            buckets=buckets,
+        )
+        self._m_prefill_ride = r.histogram(
+            "serve_prefill_ride_seconds",
+            "First prefill chunk dispatched to last chunk dispatched "
+            "(0 for a prompt of one chunk)",
+            buckets=buckets,
+        )
+        self._m_first_token_lag = r.histogram(
+            "serve_first_token_lag_seconds",
+            "Last prefill chunk dispatched to first token booked (that "
+            "tick on the device, read after the next one's dispatch)",
+            buckets=buckets,
+        )
+        self._m_turns_waited = r.counter(
+            "serve_prefill_turns_waited_total",
+            "Admissions with a chunk runnable that did not get a tick's "
+            "chunk rows, summed over the ticks that carried a chunk",
         )
         self._m_step = r.histogram(
             "serve_decode_step_seconds",
@@ -774,7 +838,7 @@ class ContinuousScheduler:
             prompt_tokens, max_new, sample_key,
             gen_kwargs.get("seed"), stream,
             deadline=(time.time() + float(timeout)) if timeout else None,
-            request_id=request_id, tenant=tenant,
+            request_id=request_id, tenant=tenant, t_submit=self._clock(),
         )
 
     def _emit(self, req: _ContinuousRequest, token: int) -> None:
@@ -796,7 +860,7 @@ class ContinuousScheduler:
     def _finish(self, req: _ContinuousRequest, stopped: str) -> None:
         if req.done:
             return  # terminal already delivered
-        dt = time.time() - req.t0
+        dt = self._close_request(req, stopped)
         n = len(req.tokens)
         stats = {
             "tokens_generated": n,
@@ -829,14 +893,14 @@ class ContinuousScheduler:
     def _fail(self, req: _ContinuousRequest, err: BaseException) -> None:
         if req.done:
             return  # terminal already delivered
+        reason = "timeout" if isinstance(err, RequestTimeout) else "error"
+        self._close_request(req, reason)
         req.done = True
         self._untrack()
         self._event(
             "request_evicted", req,
             slot=req.slot, tokens=len(req.tokens),
-            reason=(
-                "timeout" if isinstance(err, RequestTimeout) else "error"
-            ),
+            reason=reason,
             error=str(err)[:200],
         )
         if req.stream:
@@ -852,11 +916,66 @@ class ContinuousScheduler:
         if self.telemetry:
             self._m_timeouts.inc()
             self._m_tenant_timeouts.labels(tenant=req.tenant).inc()
-        waited = time.time() - req.t0
+        waited = self._clock() - req.t_submit
         self._fail(req, RequestTimeout(
             f"deadline exceeded after {waited:.1f}s ({where}; "
             f"{len(req.tokens)} tokens generated)"
         ))
+
+    def _set_runnable(self, req: _ContinuousRequest, runnable: bool) -> None:
+        """Keep `_runnable` the count of admissions whose next chunk
+        could ride the next tick."""
+        if req.runnable != runnable:
+            req.runnable = runnable
+            self._runnable += 1 if runnable else -1
+
+    def _dispatched(self) -> int:
+        """Steps the decoder has been handed so far: read and in flight."""
+        return int(getattr(self.decoder, "steps", 0)) + (
+            self._steps.steps_in_flight
+        )
+
+    def _close_request(self, req: _ContinuousRequest, stopped: str) -> float:
+        """The one end of a request's life, finished, failed or timed
+        out alike: the stage it was in closes here and nothing further
+        is observed for it. Returns submit to now, in seconds."""
+        req.t_finish = self._clock()
+        self._set_runnable(req, False)
+        if self.tracer.enabled:
+            self._write_request_spans(req, stopped)
+        return req.t_finish - req.t_submit
+
+    def _write_request_spans(self, req: _ContinuousRequest,
+                             stopped: str) -> None:
+        """A request as spans of its own, under a trace id of its own:
+        the root `request` and one child a stage it reached, written
+        from the stamps, so a capture switched on mid-request has the
+        true starts. The stamps are monotonic; `ts` is wall time, the
+        JSONL's and `capture_clock`'s: one offset, taken here. The ticks
+        that carried its chunks are the `decode_step` spans whose
+        `chunk_request_id` is its id."""
+        record = self.tracer.record
+        end = req.t_finish
+        wall = time.time() - self._clock()
+        root = record(
+            "request", wall + req.t_submit, end - req.t_submit,
+            request_id=req.request_id, tenant=req.tenant, slot=req.slot,
+            prompt_tokens=req.prompt_tokens or len(req.prompt),
+            tokens=len(req.tokens), stopped=stopped,
+        )
+        ride = {"chunks": req.chunks, "ticks": req.ticks,
+                "turns_waited": req.turns_waited}
+        for name, start, stop, attrs in (
+            ("req.queued", req.t_submit, req.t_admit, {}),
+            ("req.prefill_wait", req.t_admit, req.t_first_chunk, {}),
+            ("req.prefill_ride", req.t_first_chunk, req.t_last_chunk, ride),
+            ("req.first_token", req.t_last_chunk, req.t_first_token, {}),
+            ("req.decode", req.t_first_token, end, {}),
+        ):
+            if root is None or start is None:
+                break  # switched off under us, or a stage never reached
+            stop = end if stop is None else stop
+            record(name, wall + start, stop - start, root, **attrs)
 
     def _release_slot(self, slot: int) -> None:
         """Single choke point for giving a slot back: the decoder free +
@@ -933,19 +1052,20 @@ class ContinuousScheduler:
             self._pool_lost(active, e)
             self._fail(req, e)
             return
-        t_admit = time.perf_counter()
-        queue_wait = max(0.0, time.time() - req.t0)
+        req.slot = slot
+        req.t_admit = self._clock()
+        req.dispatched_at_admit = self._dispatched()
+        queue_wait = req.t_admit - req.t_submit
         with self.tracer.span(
             "sched.admit", request_id=req.request_id, slot=slot,
             prompt_tokens=len(req.prompt),
             queue_wait_s=round(queue_wait, 4),
         ):
-            info = self._admit_into_slot(req, slot, t_admit, queue_wait,
-                                         active)
+            info = self._admit_into_slot(req, slot, queue_wait, active)
         if info is not None:
-            self._prefill_done(req, slot, info, t_admit, active)
+            self._prefill_done(req, slot, info, active)
 
-    def _admit_into_slot(self, req, slot, t_admit, queue_wait, active):
+    def _admit_into_slot(self, req, slot, queue_wait, active):
         """The `sched.admit` span's body: admission bookkeeping, then
         `start_prefill` (chunked path: None, the chunks run from the
         worker loop) or the whole-prompt `prefill_into_slot` (its info).
@@ -987,9 +1107,14 @@ class ContinuousScheduler:
                 return None
             if st is not None:
                 # Its chunks ride the decode steps the worker loop
-                # dispatches, one a tick (_next_chunk).
-                self._prefilling[slot] = (req, st, t_admit)
+                # dispatches, one a tick (_next_chunk). A dedup follower
+                # parked behind its leader has none to run yet.
+                self._prefilling[slot] = (req, st)
+                self._set_runnable(req, not st.get("waiting"))
                 return None
+        # The whole prompt in one call of its own: no chunk waits for a
+        # turn or rides a tick, so all of it is first-token lag.
+        req.t_first_chunk = req.t_last_chunk = req.t_admit
         try:
             with self.tracer.span(
                 "prefill", slot=slot, prompt_tokens=len(req.prompt)
@@ -1008,16 +1133,26 @@ class ContinuousScheduler:
             self._fail(req, e)
             return None
 
-    def _prefill_done(self, req, slot, info, t_admit, active) -> None:
+    def _prefill_done(self, req, slot, info, active) -> None:
         """Shared prompt-prefilled tail for the whole-prompt and chunked
         admission paths: TTFT booking, first-token emission, lane
-        activation (or immediate finish). serve_prefill_seconds is
-        admission to first token on both: the whole-prompt path's
-        compute, and for a chunked prompt the ticks its chunks rode (a
-        chunk has no forward pass, so no compute time, of its own)."""
-        ttft = max(0.0, time.time() - req.t0)
-        prefill_s = time.perf_counter() - t_admit
+        activation (or immediate finish). The request's stages are
+        observed here, once, from its stamps: serve_prefill_seconds is
+        admission to first token on both paths and equals
+        serve_prefill_wait_seconds + serve_prefill_ride_seconds +
+        serve_first_token_lag_seconds; with serve_queue_wait_seconds
+        before them that is serve_ttft_seconds (a chunk has no forward
+        pass, so no compute time, of its own: its ticks are the ride)."""
+        now = req.t_first_token = self._clock()
+        wait = req.t_first_chunk - req.t_admit
+        ride = req.t_last_chunk - req.t_first_chunk
+        lag = now - req.t_last_chunk
+        prefill_s = now - req.t_admit
+        ttft = now - req.t_submit
         if self.telemetry:
+            self._m_prefill_wait.observe(wait)
+            self._m_prefill_ride.observe(ride)
+            self._m_first_token_lag.observe(lag)
             self._m_prefill.observe(prefill_s)
             # First token is sampled inside prefill, so TTFT lands here.
             self._m_ttft.observe(ttft)
@@ -1027,8 +1162,12 @@ class ContinuousScheduler:
             prefill_s=round(prefill_s, 4),
             prompt_tokens=int(info.get("prompt_tokens", 0)),
         )
-        self._event("request_first_token", req, slot=slot,
-                    ttft_s=round(ttft, 4))
+        self._event(
+            "request_first_token", req, slot=slot, ttft_s=round(ttft, 4),
+            prefill_wait_s=round(wait, 4), prefill_ride_s=round(ride, 4),
+            first_token_lag_s=round(lag, 4), chunks=req.chunks,
+            turns_waited=req.turns_waited,
+        )
         prefix = info.get("prefix") if isinstance(info, dict) else None
         if prefix is not None:
             if self.telemetry:
@@ -1063,7 +1202,6 @@ class ContinuousScheduler:
                     bytes=int(remote.get("bytes", 0)),
                     degraded=bool(remote.get("failed")),
                 )
-        req.slot = slot
         req.prompt_tokens = int(info.get("prompt_tokens", 0))
         req.admitted_step = int(getattr(self.decoder, "steps", 0))
         if info.get("is_stop"):
@@ -1139,7 +1277,7 @@ class ContinuousScheduler:
             if not self._prefilling:  # a lost pool failed them all
                 break
             slot, entry = next(iter(self._prefilling.items()))
-            req, st, _ = entry
+            req, st = entry
             del self._prefilling[slot]  # to the ring's tail, or out
             waiting = bool(st.get("waiting"))
             if not waiting and st["next"] >= st["n_chunks"]:
@@ -1167,15 +1305,32 @@ class ContinuousScheduler:
                     continue
             self._prefilling[slot] = entry
             if runnable:
+                self._set_runnable(req, True)  # a follower let go
                 return slot, req, st
         return None
 
     def _book_chunk(self, slot: int, req, st, resident: int) -> None:
         """A chunk is on its way (it rode the step just dispatched):
-        the counter, the `prefill_chunk` event, liveness. `resident`:
-        the slot's rows once it has run, spliced prefix included — it
-        must agree with the decoder's own residency booking for a
-        prefix hit."""
+        the request's stamps (the clock is read for a prompt's first
+        and last chunk alone), the counter, the `prefill_chunk` event,
+        liveness. `resident`: the slot's rows once it has run, spliced
+        prefix included — it must agree with the decoder's own
+        residency booking for a prefix hit."""
+        last = st["next"] >= st["n_chunks"]
+        req.chunks += 1
+        if req.chunks == 1 or last:
+            now = self._clock()
+            if req.chunks == 1:
+                req.t_first_chunk = now
+            if last:
+                req.t_last_chunk = now
+                # (At least its chunks: a decoder without the split API
+                # runs a chunk at once, in no step.)
+                req.ticks = max(
+                    req.chunks,
+                    self._dispatched() - req.dispatched_at_admit,
+                )
+                self._set_runnable(req, False)
         if self.telemetry:
             self._m_prefill_chunks.inc()
         self._event(
@@ -1193,11 +1348,11 @@ class ContinuousScheduler:
         that has been read (the decoder leaves prefill_into_slot's info
         in the prefill state): TTFT is stamped and the token emitted
         here; the lane has been stepped since the next dispatch."""
-        for slot, (req, st, t_admit) in list(self._prefilling.items()):
+        for slot, (req, st) in list(self._prefilling.items()):
             info = st.pop("info", None)
             if info is not None:
                 del self._prefilling[slot]
-                self._prefill_done(req, slot, info, t_admit, active)
+                self._prefill_done(req, slot, info, active)
 
     def _wait_for_request(self, timeout: Optional[float] = None):
         """Block on the intake queue with nothing to run: the
@@ -1374,12 +1529,7 @@ class ContinuousScheduler:
         abandoned and every lane failed (the pool rebuilt if the call
         had taken it), and the generation is over."""
         steps = self._steps
-        chunk = None
-        if self._prefilling:
-            with self.tracer.span("prefill_chunk") as sp:
-                chunk = self._next_chunk(active)
-                if chunk is not None:
-                    sp.set(slot=chunk[0], request_id=chunk[1].request_id)
+        chunk = self._next_chunk(active) if self._prefilling else None
         try:
             with self.tracer.span("decode_step") as sp:
                 ahead = steps.steps_in_flight > 0
@@ -1394,7 +1544,11 @@ class ContinuousScheduler:
                         st["next"] * st["chunk"]
                     )
                     end = int(min(start + st["chunk"], st["length"]))
-                    sp.set(chunk_slot=slot, chunk_rows=end - start)
+                    sp.set(chunk_slot=slot, chunk_rows=end - start,
+                           chunk_request_id=req.request_id)
+                    # The other admissions with a chunk to run wait a turn.
+                    if self._runnable > 1 and self.telemetry:
+                        self._m_turns_waited.inc(self._runnable - 1)
                     dispatched = steps.dispatch_step(key, chunk=st)
                     self._book_chunk(slot, req, st, end)
                 if dispatched:

@@ -24,6 +24,7 @@ from benchmark.tests.test_manifest import *  # noqa: F401,F403
 from benchmark.tests.test_trace_in_run import *  # noqa: F401,F403
 from benchmark.tests.test_trace_reduce import *  # noqa: F401,F403
 from benchmark.tests.test_traffic import *  # noqa: F401,F403
+from benchmark.tests.test_ttft_stages import *  # noqa: F401,F403
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 COLLECTED = (
@@ -36,6 +37,7 @@ COLLECTED = (
     "benchmark/tests/test_trace_in_run.py",
     "benchmark/tests/test_trace_reduce.py",
     "benchmark/tests/test_traffic.py",
+    "benchmark/tests/test_ttft_stages.py",
 )
 
 

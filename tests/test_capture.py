@@ -2,6 +2,7 @@
 tree and phase ledger, Trainer.request_profile, and the names of the
 jitted steps (ISSUE 25)."""
 
+import contextlib
 import glob
 import json
 import os
@@ -40,6 +41,36 @@ def _host_event_names(trace_dir):
             for line in plane.lines:
                 names.update(e.name for e in line.events)
     return names
+
+
+def until(cond, what=""):
+    """Poll `cond` (another thread makes it true) for at most 10 s."""
+    for _ in range(2000):
+        if cond():
+            return
+        threading.Event().wait(0.005)
+    raise AssertionError(f"timed out waiting: {what}")
+
+
+@contextlib.contextmanager
+def first_admission_held(sched):
+    """Admissions in a known order: whatever the scheduler admits inside
+    the block waits in `start_prefill` until the block ends, so a
+    request queued meanwhile is admitted on the generation's first tick
+    and the ring of two alternates A, B, A, B... from the first chunk."""
+    dec, gate = sched.decoder, threading.Event()
+    start = dec.start_prefill
+
+    def held(*args, **kw):
+        assert gate.wait(10)
+        return start(*args, **kw)
+
+    dec.start_prefill = held
+    try:
+        yield
+    finally:
+        gate.set()
+        dec.start_prefill = start
 
 
 # -- the capture control ---------------------------------------------------
@@ -377,35 +408,87 @@ def test_a_capture_holds_the_scheduler_threads_span_tree(tiny_engine,
     finally:
         tracer.stop_capture()
     assert toks == warm
-    want = {"sched.tick", "sched.admit", "prefill_chunk", "decode_step",
-            "decode.put", "decode.dispatch", "decode.fetch", "sched.emit"}
+    want = {"sched.tick", "sched.admit", "decode_step", "decode.pack",
+            "decode.put", "decode.dispatch", "decode.fetch", "decode.book",
+            "sched.emit"}
     names = _host_event_names(str(tmp_path / "trace"))
     assert want <= names, want - names
+    # The request's own spans are written from its stamps when it ends:
+    # the JSONL sink's alone (an annotation cannot be back-dated), and
+    # the host-only span around the chunk's choice is gone.
+    request_spans = {"request", "req.queued", "req.prefill_wait",
+                     "req.prefill_ride", "req.first_token", "req.decode"}
+    assert not names & (request_spans | {"prefill_chunk"})
     # A chunk rides the decode step: no transfer, program call or
     # first-token sync of its own is left on the chunked path.
     assert not names & {"prefill.put", "prefill.dispatch", "prefill.sample"}
     assert any(n.startswith("capture_clock unix_ns=") for n in names)
     spans = tracer.recent()
+    assert request_spans <= {s.name for s in spans}
     by_id = {s.span_id: s for s in spans}
     ticks = {s.span_id for s in spans if s.name == "sched.tick"}
     assert ticks
     for s in spans:
-        if s.name in ("decode_step", "sched.emit", "prefill_chunk",
-                      "sched.admit"):
+        if s.name in ("decode_step", "sched.emit", "sched.admit"):
             assert s.parent_id in ticks or s.name == "sched.admit", s.name
-        if s.name in ("decode.put", "decode.dispatch", "decode.fetch"):
+        if s.name in ("decode.pack", "decode.put", "decode.dispatch",
+                      "decode.fetch", "decode.book"):
             assert by_id[s.parent_id].name == "decode_step"
         if s.name == "decode_step" and "chunk_slot" in s.attrs:
             assert 0 < s.attrs["chunk_rows"] <= 16
-    carried = [s.attrs["chunk_rows"] for s in spans
+    carried = [s for s in spans
                if s.name == "decode_step" and "chunk_slot" in s.attrs]
-    assert carried == [16, 16, 8]  # the prompt's three chunks
+    assert [s.attrs["chunk_rows"] for s in carried] == [16, 16, 8]
     admit = [s for s in spans if s.name == "sched.admit"]
     assert admit and admit[0].attrs["prompt_tokens"] == 40
-    assert admit[0].attrs["request_id"]
+    rid = admit[0].attrs["request_id"]
+    # One request's stages and the ticks that did its work, by its id.
+    assert {s.attrs["chunk_request_id"] for s in carried} == {rid}
+    (root,) = [s for s in spans if s.name == "request"]
+    assert root.attrs["request_id"] == rid and root.parent_id is None
+    assert root.attrs["tokens"] == 4 and root.attrs["stopped"] == "length"
+    (ride,) = [s for s in spans if s.name == "req.prefill_ride"]
+    assert ride.trace_id == root.trace_id
+    assert ride.attrs == {"chunks": 3, "ticks": 3, "turns_waited": 0}
     got = _phase_counters(registry)
     assert all(got[c] > 0 for c in ("put", "dispatch", "device_wait",
                                     "sched"))
+
+
+def test_turns_waited_by_request_add_up_to_the_counter(tiny_engine):
+    """Two prompts of three and two chunks take turns on the real
+    decoder, one step ahead: each one's `turns_waited` is the steps
+    dispatched from its admission through its last chunk less its own
+    chunks, and together they are serve_prefill_turns_waited_total."""
+    from luminaai_tpu.monitoring.events import FlightRecorder
+    from luminaai_tpu.serving.server import ContinuousScheduler
+
+    registry, recorder = MetricsRegistry(), FlightRecorder()
+    sched = ContinuousScheduler(
+        tiny_engine, num_slots=2, page_size=16, max_slot_tokens=64,
+        registry=registry, recorder=recorder,
+    )
+    gen = {"max_new_tokens": 2, "temperature": 0.0}
+    threads = []
+    with first_admission_held(sched):  # the ring: A, B, A, B, A
+        for n_tokens in (40, 24):
+            threads.append(threading.Thread(
+                target=sched.submit,
+                args=(list(range(5, 5 + n_tokens)), gen), daemon=True))
+            threads[-1].start()
+            until(lambda: (sched.decoder.pool.stats()["in_use"],
+                           sched.queue_depth()) == (1, len(threads) - 1),
+                  "A holds its slot, then B is queued")
+    for th in threads:
+        th.join(60)
+    firsts = sorted(recorder.snapshot(type="request_first_token"),
+                    key=lambda e: e["chunks"])
+    assert [(e["chunks"], e["turns_waited"]) for e in firsts] == [
+        (2, 2), (3, 2)]
+    assert registry.counter(
+        "serve_prefill_turns_waited_total", "").value == 4
+    assert registry.counter("serving_prefill_chunks_total", "").value == 5
+    assert sched._runnable == 0
 
 
 # -- the trainer -----------------------------------------------------------

@@ -768,7 +768,7 @@ def test_prefill_chunk_advance_counts_as_liveness():
                                 recorder=FlightRecorder())
     req = _ContinuousRequest([40], 4, None, None, False)
     sched._track(req)
-    sched._prefilling[0] = (req, st, 0.0)
+    sched._prefilling[0] = (req, st)
     assert sched.last_tick_ts is None
     assert sched._step(None, {}, collect=False)  # a chunk and no lane
     assert st["next"] == 1 and sched.last_tick_ts is not None
