@@ -104,6 +104,34 @@ class RequestTimeout(Exception):
     as HTTP 504; SSE streams get an error frame (docs/resilience.md)."""
 
 
+# The two rules that take turns for a tick's prefill chunk, by the parity
+# of the chunks dispatched so far (pick_prefill).
+PICK_RULES = ("oldest", "shortest")
+
+
+def pick_prefill(remaining, turn: int) -> Tuple[int, str]:
+    """Which runnable admission gets the tick's one prefill chunk:
+    (its index in `remaining`, the rule that chose it). `remaining` is
+    the chunks each runnable admission still has to run, in admission
+    order; `turn` counts the chunks dispatched so far. On an even turn
+    the OLDEST admission (index 0), on an odd one the one with the
+    FEWEST chunks left (ties to the oldest). With one runnable
+    admission both rules name it.
+
+    Fewest-left-first alone gives the best mean time to a prompt's last
+    chunk and starves a long prompt behind a stream of short ones;
+    oldest-first alone bounds every wait and gives back little of the
+    mean; round-robin (every admission a chunk in turn) finishes them
+    all late together. Taking turns keeps most of the first's mean under
+    the second's bound: the oldest admission gets at least every second
+    chunk, so it waits at most twice the chunks of the admissions ahead
+    of it in admission order, whatever arrives behind it."""
+    rule = PICK_RULES[turn % 2]
+    if rule == "oldest":
+        return 0, rule
+    return min(range(len(remaining)), key=remaining.__getitem__), rule
+
+
 class _ContinuousRequest:
     """One in-flight request inside the ContinuousScheduler: its prompt,
     resolved budgets, and the sink its tokens stream into (a Queue for
@@ -141,7 +169,7 @@ class _ContinuousRequest:
         # so the stages add up to the whole, per request and in the
         # histograms' sums:
         #   t_submit -> t_admit        queue wait   (slot acquired)
-        #   t_admit -> t_first_chunk   prefill wait (a turn of the ring)
+        #   t_admit -> t_first_chunk   prefill wait (its first pick)
         #   t_first_chunk -> t_last_chunk  prefill ride (its chunks' ticks)
         #   t_last_chunk -> t_first_token  first-token lag (that tick on
         #       the device, read one dispatch later: the lookahead)
@@ -155,13 +183,11 @@ class _ContinuousRequest:
         self.t_first_token: Optional[float] = None
         self.t_finish: Optional[float] = None
         # Chunks that rode a tick; steps dispatched before admission and
-        # from there through the last chunk's (ticks - chunks = turns of
-        # the ring it waited); whether a chunk of it could ride the next
-        # tick (ContinuousScheduler._runnable counts these).
+        # from there through the last chunk's (ticks - chunks = the
+        # turns it waited while other admissions' chunks rode).
         self.chunks = 0
         self.dispatched_at_admit = 0
         self.ticks = 0
-        self.runnable = False
 
     @property
     def turns_waited(self) -> int:
@@ -222,9 +248,10 @@ class ContinuousScheduler:
     The loop looks ONE step ahead: it dispatches step N+1 before it
     reads step N's tokens, so everything the host does about step N
     (the fetch, the per-lane emit, admission) happens while the device
-    runs N+1. A tick is ONE program: the prefill chunk of the admission
-    at the head of the `_prefilling` ring rides the step, in the lanes'
-    forward pass (_run_generation_inner; docs/serving.md "The scheduler
+    runs N+1. A tick is ONE program: one prefill chunk rides the step, in
+    the lanes' forward pass, and it goes by turns to the oldest admission
+    mid-prefill and to the one with the fewest chunks left (_next_chunk,
+    pick_prefill; _run_generation_inner; docs/serving.md "The scheduler
     loop").
 
     Sampling parameters DO remain a compile key (the sampling math traces
@@ -350,16 +377,16 @@ class ContinuousScheduler:
         # iteration".
         self._tq_lock = threading.Lock()
         # Admissions mid-prefill: slot -> (request, decoder chunk state),
-        # a ring in admission order. ONE chunk a tick rides the decode
-        # step the worker dispatches (_next_chunk), so a long prompt
-        # costs concurrent lanes no forward pass of its own; an entry
-        # stays until its first token is read (_first_tokens).
+        # in admission order (never re-ordered: an entry is inserted at
+        # admission and deleted at its end). ONE chunk a tick rides the
+        # decode step the worker dispatches (_next_chunk), so a long
+        # prompt costs concurrent lanes no forward pass of its own; an
+        # entry stays until its first token is read (_first_tokens).
         self._prefilling: Dict[int, Tuple[Any, Any]] = {}
-        # How many of them have a chunk that could ride the next tick
-        # (not parked behind a dedup leader, last chunk not yet
-        # dispatched): `request.runnable` of each, kept by _set_runnable
-        # so that a tick never walks the ring to count them.
-        self._runnable = 0
+        # Chunks dispatched so far: the turn pick_prefill alternates on.
+        # It advances only when a chunk rides a step (_book_chunk), so
+        # ticks without one do not shift the phase.
+        self._chunk_turn = 0
         self.q: "queue.Queue" = queue.Queue()
         self.window = max(0.0, float(admission_window_ms)) / 1000.0
         # /stats names: batches = generations (one sampling key each),
@@ -438,7 +465,8 @@ class ContinuousScheduler:
         self._m_prefill_wait = r.histogram(
             "serve_prefill_wait_seconds",
             "Admission to the request's first prefill chunk dispatched "
-            "(a turn of the round-robin ring; a dedup follower's park)",
+            "(its first pick among the admissions mid-prefill; a dedup "
+            "follower's park)",
             buckets=buckets,
         )
         self._m_prefill_ride = r.histogram(
@@ -457,6 +485,21 @@ class ContinuousScheduler:
             "serve_prefill_turns_waited_total",
             "Admissions with a chunk runnable that did not get a tick's "
             "chunk rows, summed over the ticks that carried a chunk",
+        )
+        # How often the order of service engages (pick_prefill): the
+        # two rules' picks add up to serving_prefill_chunks_total.
+        picks = r.counter(
+            "serve_prefill_picks_total",
+            "Prefill chunks dispatched, by the rule whose turn chose "
+            "the admission (oldest / shortest = fewest chunks left)",
+            labelnames=("rule",),
+        )
+        self._m_picks = {rule: picks.labels(rule=rule) for rule in PICK_RULES}
+        self._m_picks_not_oldest = r.counter(
+            "serve_prefill_picks_not_oldest_total",
+            "Prefill chunks that went to another admission than the "
+            "oldest runnable one (0 while one admission prefills at a "
+            "time)",
         )
         self._m_step = r.histogram(
             "serve_decode_step_seconds",
@@ -922,13 +965,6 @@ class ContinuousScheduler:
             f"{len(req.tokens)} tokens generated)"
         ))
 
-    def _set_runnable(self, req: _ContinuousRequest, runnable: bool) -> None:
-        """Keep `_runnable` the count of admissions whose next chunk
-        could ride the next tick."""
-        if req.runnable != runnable:
-            req.runnable = runnable
-            self._runnable += 1 if runnable else -1
-
     def _dispatched(self) -> int:
         """Steps the decoder has been handed so far: read and in flight."""
         return int(getattr(self.decoder, "steps", 0)) + (
@@ -940,7 +976,6 @@ class ContinuousScheduler:
         out alike: the stage it was in closes here and nothing further
         is observed for it. Returns submit to now, in seconds."""
         req.t_finish = self._clock()
-        self._set_runnable(req, False)
         if self.tracer.enabled:
             self._write_request_spans(req, stopped)
         return req.t_finish - req.t_submit
@@ -1110,7 +1145,6 @@ class ContinuousScheduler:
                 # dispatches, one a tick (_next_chunk). A dedup follower
                 # parked behind its leader has none to run yet.
                 self._prefilling[slot] = (req, st)
-                self._set_runnable(req, not st.get("waiting"))
                 return None
         # The whole prompt in one call of its own: no chunk waits for a
         # turn or rides a tick, so all of it is first-token lag.
@@ -1257,65 +1291,78 @@ class ContinuousScheduler:
                 if keys:
                     self.page_share.report_async(keys)
 
-    def _next_chunk(self, active: dict) -> Optional[Tuple[int, Any, Any]]:
+    def _next_chunk(
+        self, active: dict
+    ) -> Optional[Tuple[int, Any, Any, str, int, int]]:
         """Choose the ONE chunk that rides the next step: (slot, request,
-        prefill state) of the first runnable admission of the
-        `_prefilling` ring (round-robin in admission order), or None.
-        The chunk's rows join the decode rows in one forward pass, so
-        prefill work costs the decode batch no program of its own — the
-        chunked-prefill latency contract (docs/serving.md).
+        prefill state, the rule that picked it, its place among the
+        runnable admissions, how many those were), or None. One scan of
+        `_prefilling` in admission order collects the admissions with a
+        chunk to run, and pick_prefill names one of them: by turns the
+        oldest and the one with the fewest chunks left, a turn a chunk
+        dispatched. The chunk's rows join the decode rows in one forward
+        pass, so prefill work costs the decode batch no program of its
+        own — the chunked-prefill latency contract (docs/serving.md).
 
         A cancelled or overdue admission is ended here. Dedup followers
         parked behind an in-flight identical prefix (decoder `waiting`
-        states) re-check for free (`prefill_ready`) and never take the
-        tick's chunk — otherwise K parked followers would slow their own
-        leader's prefill (and every queued one) (K+1)x. An admission
-        whose last chunk is on the device waits for its first token
+        states) re-check for free (`prefill_ready`), once a tick, and
+        while parked neither take the tick's chunk nor use up a turn —
+        otherwise K parked followers would slow their own leader's
+        prefill (and every queued one) (K+1)x. An admission whose last
+        chunk is on the device waits for its first token
         (_first_tokens)."""
         ready = getattr(self.decoder, "prefill_ready", None)
-        for _ in range(len(self._prefilling)):
-            if not self._prefilling:  # a lost pool failed them all
-                break
-            slot, entry = next(iter(self._prefilling.items()))
-            req, st = entry
-            del self._prefilling[slot]  # to the ring's tail, or out
+        now = time.time()
+        runnable = []
+        for slot, (req, st) in list(self._prefilling.items()):
             waiting = bool(st.get("waiting"))
             if not waiting and st["next"] >= st["n_chunks"]:
-                self._prefilling[slot] = entry  # last chunk in flight
-                continue
+                continue  # last chunk in flight
             if req.cancelled:
+                del self._prefilling[slot]
                 self._finish(req, "cancelled")
                 self._release_slot(slot)
                 continue
-            if req.deadline is not None and time.time() > req.deadline:
+            if req.deadline is not None and now > req.deadline:
+                del self._prefilling[slot]
                 self._timeout(req, "mid-prefill")
                 self._release_slot(slot)
                 continue
-            runnable = True
             if waiting and ready is not None:
                 # Resolving may flush harvests (a first-use compile).
                 try:
                     with self._wd_pause():
-                        runnable = ready(st)
+                        let_go = ready(st)
                 except Exception as e:
                     logger.exception("chunked prefill failed")
+                    del self._prefilling[slot]
                     self._release_slot(slot)
                     self._pool_lost(active, e)
                     self._fail(req, e)
+                    if not self._prefilling:  # a lost pool failed them all
+                        return None
                     continue
-            self._prefilling[slot] = entry
-            if runnable:
-                self._set_runnable(req, True)  # a follower let go
-                return slot, req, st
-        return None
+                if not let_go:
+                    continue
+            runnable.append((slot, req, st))
+        if not runnable:
+            return None
+        at, rule = pick_prefill(
+            [st["n_chunks"] - st["next"] for _, _, st in runnable],
+            self._chunk_turn,
+        )
+        return (*runnable[at], rule, at, len(runnable))
 
-    def _book_chunk(self, slot: int, req, st, resident: int) -> None:
+    def _book_chunk(self, slot: int, req, st, resident: int, rule: str,
+                    oldest: bool) -> None:
         """A chunk is on its way (it rode the step just dispatched):
         the request's stamps (the clock is read for a prompt's first
-        and last chunk alone), the counter, the `prefill_chunk` event,
-        liveness. `resident`: the slot's rows once it has run, spliced
-        prefix included — it must agree with the decoder's own
-        residency booking for a prefix hit."""
+        and last chunk alone), the turn and the counters (`rule` chose
+        it; `oldest`: it went to the oldest runnable admission), the
+        `prefill_chunk` event, liveness. `resident`: the slot's rows
+        once it has run, spliced prefix included — it must agree with
+        the decoder's own residency booking for a prefix hit."""
         last = st["next"] >= st["n_chunks"]
         req.chunks += 1
         if req.chunks == 1 or last:
@@ -1330,9 +1377,12 @@ class ContinuousScheduler:
                     req.chunks,
                     self._dispatched() - req.dispatched_at_admit,
                 )
-                self._set_runnable(req, False)
+        self._chunk_turn += 1
         if self.telemetry:
             self._m_prefill_chunks.inc()
+            self._m_picks[rule].inc()
+            if not oldest:
+                self._m_picks_not_oldest.inc()
         self._event(
             "prefill_chunk", req, slot=slot,
             chunk=int(st["next"]), chunks=int(st["n_chunks"]),
@@ -1539,18 +1589,24 @@ class ContinuousScheduler:
                         steps.dispatch_step(key)
                     )
                 else:
-                    slot, req, st = chunk
+                    slot, req, st, rule, at, runnable = chunk
                     start = int(st.get("start_rows", 0)) + (
                         st["next"] * st["chunk"]
                     )
                     end = int(min(start + st["chunk"], st["length"]))
                     sp.set(chunk_slot=slot, chunk_rows=end - start,
-                           chunk_request_id=req.request_id)
+                           chunk_request_id=req.request_id,
+                           chunk_pick=rule)
                     # The other admissions with a chunk to run wait a turn.
-                    if self._runnable > 1 and self.telemetry:
-                        self._m_turns_waited.inc(self._runnable - 1)
+                    if runnable > 1 and self.telemetry:
+                        self._m_turns_waited.inc(runnable - 1)
                     dispatched = steps.dispatch_step(key, chunk=st)
-                    self._book_chunk(slot, req, st, end)
+                    self._book_chunk(slot, req, st, end, rule, at == 0)
+                    if "info" in st:
+                        # A decoder without the split API ran the chunk
+                        # at once: a prompt it ended is a lane before
+                        # the step noted earlier steps it.
+                        self._first_tokens(active)
                 if dispatched:
                     if not ahead:
                         self._t_collect = time.perf_counter()
@@ -1568,8 +1624,6 @@ class ContinuousScheduler:
             self._count_decoder()
             return False
         if not collect:
-            # (A decoder without the split API runs a chunk at once.)
-            self._first_tokens(active)
             self._count_decoder()
             return True
         # Collect to collect: with a step always queued behind the one

@@ -15,6 +15,9 @@ import glob
 import importlib
 import os
 
+import pytest
+
+from benchmark.tests import test_ttft_stages as _ttft_stages
 from benchmark.architectures.jamba.test_reference import *  # noqa: F401,F403
 from benchmark.architectures.kimi_linear.test_reference import *  # noqa: F401,F403
 from benchmark.architectures.prenorm_decoder.test_reference import *  # noqa: F401,F403
@@ -48,7 +51,18 @@ COLLECTED = (
 # here with each cell held to the architecture its own configuration file
 # names, under the same name so that it is counted once. A `benchmark` PR
 # should move this body into benchmark/tests/ and drop the override.
-SUPERSEDED_HERE = ("test_every_cell_resolves_its_architecture",)
+#
+# benchmark/tests/test_ttft_stages.py holds the program to the order of
+# the round-robin ring ("Ticks A B A B A": 4 turns waited in 5 chunks).
+# Since PR 40 the tick's chunk goes by turns to the oldest admission and
+# to the one with the fewest chunks left (serving/server.py::pick_prefill),
+# so A's three chunks ride before B's two: 3 turns in 5. The same test is
+# taken here with that one line changed; a `benchmark` PR should change it
+# there and drop the override.
+SUPERSEDED_HERE = (
+    "test_every_cell_resolves_its_architecture",
+    "test_the_stage_means_add_up_to_the_programs_ttft",
+)
 
 
 def test_every_cell_resolves_its_architecture():  # noqa: F811
@@ -69,6 +83,27 @@ def test_every_cell_resolves_its_architecture():  # noqa: F811
                 assert hasattr(module, name), (part, name)
         assert set(arch.work.KERNEL_FNS) == manifest.kernel_names(arch.name)
     assert seen == {"prenorm_decoder", "kimi_linear", "jamba"}
+
+
+def test_the_stage_means_add_up_to_the_programs_ttft(window):  # noqa: F811
+    read, stages = _ttft_stages._read, tuple(_ttft_stages.STAGES)
+    got = {name: read(name, window) for name in (
+        *stages, _ttft_stages.TURNS, "queue_wait_mean_ms")}
+    assert all(v is not None and v >= 0.0 for v in got.values()), got
+    n = window["hist_count:serve_ttft_seconds"]
+    assert n == 3 == window["hist_count:serve_prefill_wait_seconds"]
+    ttft_mean_ms = 1e3 * window["hist_sum:serve_ttft_seconds"] / n
+    assert sum(got[name] for name in (*stages, "queue_wait_mean_ms")) == (
+        pytest.approx(ttft_mean_ms, rel=1e-9))
+    prefill_ms = 1e3 * window["hist_sum:serve_prefill_seconds"] / n
+    assert sum(got[name] for name in stages) == pytest.approx(
+        prefill_ms, rel=1e-9)
+    assert got["prefill_ride_mean_ms"] > 0.0
+    # Ticks A A A B B (A is the oldest and never the longer): the first
+    # three each leave one admission waiting.
+    assert window["counter:serving_prefill_chunks_total"] == 5
+    assert got[_ttft_stages.TURNS] == pytest.approx(3 / 5)
+    assert window["counter:serve_prefill_picks_not_oldest_total"] == 0
 
 
 def test_every_benchmark_test_file_is_collected_here():

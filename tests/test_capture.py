@@ -57,7 +57,7 @@ def first_admission_held(sched):
     """Admissions in a known order: whatever the scheduler admits inside
     the block waits in `start_prefill` until the block ends, so a
     request queued meanwhile is admitted on the generation's first tick
-    and the ring of two alternates A, B, A, B... from the first chunk."""
+    and both are mid-prefill from the first chunk on."""
     dec, gate = sched.decoder, threading.Event()
     start = dec.start_prefill
 
@@ -456,10 +456,12 @@ def test_a_capture_holds_the_scheduler_threads_span_tree(tiny_engine,
 
 
 def test_turns_waited_by_request_add_up_to_the_counter(tiny_engine):
-    """Two prompts of three and two chunks take turns on the real
-    decoder, one step ahead: each one's `turns_waited` is the steps
-    dispatched from its admission through its last chunk less its own
-    chunks, and together they are serve_prefill_turns_waited_total."""
+    """Two prompts of three and two chunks share the ticks' chunk rows
+    on the real decoder, one step ahead: each one's `turns_waited` is
+    the steps dispatched from its admission through its last chunk less
+    its own chunks, and together they are
+    serve_prefill_turns_waited_total. A is the oldest and never the
+    longer, so its three chunks ride first and B waits three turns."""
     from luminaai_tpu.monitoring.events import FlightRecorder
     from luminaai_tpu.serving.server import ContinuousScheduler
 
@@ -470,7 +472,7 @@ def test_turns_waited_by_request_add_up_to_the_counter(tiny_engine):
     )
     gen = {"max_new_tokens": 2, "temperature": 0.0}
     threads = []
-    with first_admission_held(sched):  # the ring: A, B, A, B, A
+    with first_admission_held(sched):  # the ticks' chunks: A, A, A, B, B
         for n_tokens in (40, 24):
             threads.append(threading.Thread(
                 target=sched.submit,
@@ -484,11 +486,16 @@ def test_turns_waited_by_request_add_up_to_the_counter(tiny_engine):
     firsts = sorted(recorder.snapshot(type="request_first_token"),
                     key=lambda e: e["chunks"])
     assert [(e["chunks"], e["turns_waited"]) for e in firsts] == [
-        (2, 2), (3, 2)]
+        (2, 3), (3, 0)]
     assert registry.counter(
-        "serve_prefill_turns_waited_total", "").value == 4
+        "serve_prefill_turns_waited_total", "").value == 3
     assert registry.counter("serving_prefill_chunks_total", "").value == 5
-    assert sched._runnable == 0
+    picks = registry.get("serve_prefill_picks_total")
+    assert [picks.labels(rule=r).value for r in ("oldest", "shortest")] == [
+        3, 2]
+    assert registry.counter(
+        "serve_prefill_picks_not_oldest_total", "").value == 0
+    assert not sched._prefilling
 
 
 # -- the trainer -----------------------------------------------------------
