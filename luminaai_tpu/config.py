@@ -77,16 +77,22 @@ class Config:
     #                (ragged_eligible), ragged_xla otherwise. Compiled on
     #                TPU, interpret mode on CPU (slow — use for parity
     #                tests, not CPU serving).
-    # Rolling (windowed O(window)) caches always take the dense path —
-    # their slot arithmetic is mod-C, which LaneMeta does not describe.
+    # The single-stream engine's rolling cache (one uniform
+    # attention_window, below) always takes the dense path: its slot
+    # arithmetic is mod-C, which LaneMeta does not describe. The slot-paged
+    # pool never rolls so: a layer with a window of its own
+    # (layer_windows) keeps a RING OF PAGES a lane there, addressed through
+    # a page table at absolute positions, and every backend reads it.
     attention_backend: str = "ragged_xla"
     # Chunked prefill: prompts prefill in fixed chunks of this many
     # tokens — ONE executable for every prompt length (instead of a
     # power-of-two bucket ladder), and the serving scheduler interleaves
     # chunks with decode steps so a long admission cannot stall the
     # decode batch for more than ~one chunk's step time. 0 disables
-    # (legacy bucketed prefill). Engines with a rolling windowed cache
-    # ignore it (chunk writes are only defined on non-wrapping layouts).
+    # (legacy bucketed prefill). The single-stream engine ignores it under
+    # one uniform attention_window (its rolling cache takes whole prompts);
+    # a pool with rings of pages (layer_windows) needs it: a ring is sized
+    # to the window plus one chunk.
     prefill_chunk_size: int = 64
     # Radix prefix cache over the serving KV pool (inference/
     # prefix_cache.py): budget of content-hash-keyed arena pages shared
@@ -104,11 +110,21 @@ class Config:
     # None = full causal. The flash kernels skip whole blocks outside the
     # band, so long-context attention cost becomes O(S·W) instead of
     # O(S²); ring sequence parallelism masks/skips the same band across
-    # shards; decode runs a ROLLING KV cache (slot = pos % C, C ≈ W) so
-    # serving cache HBM is O(window) instead of O(max_context). A
-    # TPU-first capability beyond the reference's surface (its attention
-    # is always full causal).
+    # shards; the single-stream engine (generate()) decodes from a ROLLING
+    # KV cache (slot = pos % C, C ≈ W), so its cache HBM is O(window)
+    # instead of O(max_context); the slot-paged pool keeps whole pages
+    # under this one uniform window and masks the band. A TPU-first
+    # capability beyond the reference's surface (its attention is always
+    # full causal).
     attention_window: Optional[int] = None
+    # A window a LAYER, data beside layer_mixers: num_layers entries, each
+    # a window or None (full causal). None = every attention layer takes
+    # attention_window. A layer with a window of its own is masked by it
+    # everywhere, and in the slot-paged pool keeps a ring of
+    # ceil((window + prefill_chunk_size) / page_size) + 1 pages a lane
+    # where a full layer keeps all of them (GQAttention.init_cache,
+    # docs/serving.md).
+    layer_windows: Optional[tuple] = None
     # Token mixer of each layer, a tuple of num_layers entries:
     #   'attention' GQAttention (RoPE, the KV cache, every serving path);
     #   'latent'    LatentAttention (models/layers.py): keys and values
@@ -130,6 +146,22 @@ class Config:
     # False: GQAttention rotates nothing (a stack whose recurrent layers
     # carry the position, as Jamba's).
     use_rope: bool = True
+    # A rotation a LAYER (num_layers booleans; None = use_rope for all):
+    # window layers that rotate beside full layers that do not.
+    layer_rope: Optional[tuple] = None
+    # Which pairs a rotation turns: 'split' (x[i], x[i + d/2]: rotate_half)
+    # or 'interleaved' (x[2i], x[2i+1]: GPT-J's). The same model under a
+    # permutation of each head's q and k columns.
+    rope_layout: str = "split"
+    # Size of one attention head; None = hidden_size // num_heads.
+    attn_head_dim: Optional[int] = None
+    # 'rms' (RMSNorm, rms_norm_eps) or 'layernorm' (mean-subtracting, no
+    # bias, layer_norm_eps): the blocks' norms and the final norm.
+    norm_kind: str = "rms"
+    layer_norm_eps: float = 1e-5
+    # True: ONE norm a layer feeds the mixer AND the feed-forward, and both
+    # are added to the residual (x + attn(n(x)) + ffn(n(x))); no ffn_norm.
+    parallel_block: bool = False
     ssm_state_size: int = 16
     ssm_dt_rank: Optional[int] = None  # None = ceil(hidden_size / 16)
     ssm_expand: int = 2
@@ -173,14 +205,18 @@ class Config:
     # Width of one routed (and one shared) expert; None = intermediate_size.
     moe_intermediate_size: Optional[int] = None
     # Shared experts: one SwiGLU of num_shared_experts x the expert width
-    # that every token passes through, added to the routed result.
+    # that every token passes through, added to the routed result: the SUM
+    # of num_shared_experts SwiGLUs of the expert width ('sum') or their
+    # mean ('average').
     num_shared_experts: int = 0
+    shared_expert_combine: str = "sum"
     # (offset, count): this program holds experts [offset, offset+count)
     # of num_experts, as one chip of an expert-parallel group does. The
     # router keeps its num_experts outputs and its top-k; the layer
     # computes the held experts' part of the result (plus the shared
     # expert) and nothing stands in for the rest. gmm dispatch, no expert
-    # mesh axis (docs/parallelism.md).
+    # mesh axis; trained (Trainer) and served (the tick program counts
+    # routed / held / dropped pairs over its live rows): docs/parallelism.md.
     experts_held: Optional[tuple] = None
     # 'sort' = scatter/gather dispatch via flat slot ids (linear memory);
     # 'gather' = same routing, but the expert buffers are filled by a row
@@ -536,6 +572,12 @@ class Config:
             self.layer_mixers = tuple(self.layer_mixers)
         if self.experts_held is not None:
             self.experts_held = tuple(int(x) for x in self.experts_held)
+        if self.layer_windows is not None:
+            self.layer_windows = tuple(
+                None if not w else int(w) for w in self.layer_windows
+            )
+        if self.layer_rope is not None:
+            self.layer_rope = tuple(bool(r) for r in self.layer_rope)
         if self.num_kv_heads is None:
             self.num_kv_heads = self.num_heads
         if self.intermediate_size is None:
@@ -604,8 +646,45 @@ class Config:
 
     # -- validation ------------------------------------------------------
     def validate(self) -> None:
-        assert self.hidden_size % self.num_heads == 0, (
+        assert self.attn_head_dim or self.hidden_size % self.num_heads == 0, (
             "hidden_size must be divisible by num_heads"
+        )
+        assert self.attn_head_dim is None or self.attn_head_dim > 0
+        assert self.rope_layout in ("split", "interleaved"), (
+            f"invalid rope_layout {self.rope_layout}"
+        )
+        assert self.norm_kind in ("rms", "layernorm"), (
+            f"invalid norm_kind {self.norm_kind}"
+        )
+        assert self.shared_expert_combine in ("sum", "average"), (
+            f"invalid shared_expert_combine {self.shared_expert_combine}"
+        )
+        for name in ("layer_windows", "layer_rope"):
+            per_layer = getattr(self, name)
+            assert per_layer is None or len(per_layer) == self.num_layers, (
+                f"{name} names {len(per_layer)} layers, num_layers is "
+                f"{self.num_layers}"
+            )
+        if self.layer_windows is not None:
+            assert all(w is None or w > 0 for w in self.layer_windows), (
+                f"layer_windows entries are positive or None: "
+                f"{self.layer_windows}"
+            )
+            assert self.attention_window is None, (
+                "layer_windows gives every layer its window; "
+                "attention_window is the one uniform window"
+            )
+            assert self.sequence_parallel_size == 1, (
+                "layer_windows does not compose with ring sequence "
+                "parallelism yet"
+            )
+        per_layer_differs = any(
+            t is not None and len(set(t)) > 1
+            for t in (self.layer_windows, self.layer_rope)
+        )
+        assert not (self.scan_layers and per_layer_differs), (
+            "scan_layers needs one kind of layer: layer_windows / "
+            "layer_rope differ by layer"
         )
         assert self.num_heads % self.num_kv_heads == 0, (
             "num_heads must be divisible by num_kv_heads"
@@ -979,7 +1058,32 @@ class Config:
 
     # -- derived quantities (ref config_manager.py:234,505,572) ----------
     def head_dim(self) -> int:
-        return self.hidden_size // self.num_heads
+        return self.attn_head_dim or self.hidden_size // self.num_heads
+
+    def window_of(self, layer_idx: Optional[int]) -> Optional[int]:
+        """The attention window of a layer (None: full causal)."""
+        if self.layer_windows is None or layer_idx is None:
+            return self.attention_window
+        return self.layer_windows[layer_idx]
+
+    def rope_of(self, layer_idx: Optional[int]) -> bool:
+        """Whether a layer's attention rotates q and k."""
+        if self.layer_rope is None or layer_idx is None:
+            return self.use_rope
+        return self.layer_rope[layer_idx]
+
+    def ring_pages(self, layer_idx: int, page_size: int,
+                   chunk: int) -> Optional[int]:
+        """Pages a lane keeps of a layer with a window of its own in the
+        slot-paged pool: the window, the chunk that is written before it
+        is read, and a page of slack for where the band starts inside a
+        page. None: the layer keeps whole pages."""
+        if self.layer_windows is None:
+            return None
+        window = self.layer_windows[layer_idx]
+        if window is None or self.mixer_kind(layer_idx) != "attention":
+            return None
+        return -(-(window + chunk) // page_size) + 1
 
     def ssm_inner(self) -> int:
         return self.ssm_expand * self.hidden_size
@@ -1016,7 +1120,8 @@ class Config:
         inter = self.intermediate_size
         kv_dim = self.num_kv_heads * self.head_dim()
         embed = v * h if self.tie_word_embeddings else 2 * v * h
-        attn = h * h + 2 * h * kv_dim + h * h  # q, k, v, o
+        q_dim = self.num_heads * self.head_dim()
+        attn = 2 * h * q_dim + 2 * h * kv_dim  # q, o, k, v
         ffn_dense = 3 * h * inter  # gate, up, down
         per_layer_norms = 2 * h
         total = embed + L * (attn + per_layer_norms) + h  # final norm

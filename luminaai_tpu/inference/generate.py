@@ -957,9 +957,13 @@ class StepwiseDecoder:
     Greedy step-wise decode is token-identical to generate() (same
     prefill bucketing, same sampling math, same rng split discipline —
     parity-tested), and sampled decode is bit-identical for the same
-    per-request seed. The pool is plain-layout (never rolling): admission
-    bounds prompt+max_new to the slot capacity, so positions never wrap,
-    and attention_window configs are served by the per-lane band mask.
+    per-request seed. Positions in the pool are absolute and never wrap:
+    admission bounds prompt+max_new to the slot capacity. One uniform
+    attention_window keeps whole pages and is served by the per-lane band
+    mask; a layer with a window of its own (Config.layer_windows) keeps a
+    RING of pages a lane beside the full layers' whole pages, addressed
+    through the pool's ring table (kv_pool.py), and what a ring cannot
+    honour is refused by name (RingKeepsWindowError).
 
     One tick-program compile per sampling parameter set and page extent
     (max_new is host state, NOT part of the compile key — mixed-length
@@ -1016,6 +1020,18 @@ class StepwiseDecoder:
                 "end, so a spliced prefix would start from the wrong state"
             )
         backend = getattr(engine.config, "attention_backend", "dense")
+        _chunk_eff = (
+            int(prefill_chunk_tokens)
+            if prefill_chunk_tokens is not None
+            else int(getattr(engine.config, "prefill_chunk_size", 0) or 0)
+        )
+        _chunk_eff = max(0, min(
+            _chunk_eff, pages * page_size, engine.max_context
+        ))
+        ring_pages = self._ring_pages_of(
+            engine.config, pages, page_size, _chunk_eff, backend,
+            prefix_cache_pages,
+        )
         if prefix_cache_pages > 0 and backend == "dense":
             # The dense per-lane mask reads only the lane's own rows — it
             # cannot follow a cross-slot page alias. Gated off rather
@@ -1025,11 +1041,6 @@ class StepwiseDecoder:
                 "read shared pages (use ragged_xla/ragged)"
             )
             prefix_cache_pages = 0
-        _chunk_eff = (
-            int(prefill_chunk_tokens)
-            if prefill_chunk_tokens is not None
-            else int(getattr(engine.config, "prefill_chunk_size", 0) or 0)
-        )
         if prefix_cache_pages > 0 and _chunk_eff <= 0:
             # The suffix-only prefill rides the chunked executables; a
             # cache without chunking has no splice path.
@@ -1052,7 +1063,9 @@ class StepwiseDecoder:
             num_slots=num_slots,
             pages=pages,
             page_size=page_size,
+            ring_pages=ring_pages,
         )
+        self._ring = (page_size, _chunk_eff) if ring_pages else None
         self.pool.caches = self._init_pool_caches()
         # The decode budget honors the ENGINE's context contract: the
         # page rounding above may leave slack rows past max_context, and
@@ -1061,6 +1074,13 @@ class StepwiseDecoder:
         # this, with exactly generate()'s _trim_prompt formula, so the
         # two paths serve identical tokens for over-length prompts too.
         self.token_capacity = min(self.slot_tokens, engine.max_context)
+        # A share of the experts (Config.experts_held): (token, expert)
+        # pairs of the ticks' live rows, summed over layers: routed, on a
+        # held expert, held and not computed. They ride the step's token
+        # fetch (_get_step).
+        self._held = bool(
+            engine.config.use_moe and engine.config.experts_held
+        )
         # Host-side lane state; device state is the pool + counts + rngs.
         self._reset_lane_state()
         self.steps = 0
@@ -1075,6 +1095,23 @@ class StepwiseDecoder:
         # With state-space layers: live rows through the recurrence
         # (stepped lanes + live chunk rows).
         self.ssm_rows = 0
+        # Rows of k/v the ticks' attention read, by kind of layer (window
+        # of its own / full), and the times a lane's rows came round its
+        # ring: counted on the host from the lengths it has
+        # (_kv_rows_of).
+        windows = [
+            engine.config.window_of(i)
+            for i in range(engine.config.num_layers)
+            if engine.config.mixer_kind(i) == "attention"
+        ]
+        self._n_window_layers = sum(w is not None for w in windows)
+        self._n_global_layers = len(windows) - self._n_window_layers
+        self.kv_window_rows = 0
+        self.kv_global_rows = 0
+        self.ring_wraps = 0
+        self.moe_routed_pairs = 0
+        self.moe_held_pairs = 0
+        self.moe_held_pairs_dropped = 0
         self._fns: Dict[Any, Any] = {}
         # Serving attention backend (config.attention_backend): 'dense'
         # keeps the legacy full-extent per-lane mask; the ragged backends
@@ -1086,16 +1123,13 @@ class StepwiseDecoder:
         # Device copy of the pool's page table, refreshed at admission
         # (identity today; a prefix cache would retarget entries there).
         self._table = jnp.asarray(self.pool.page_table_array())
+        self._ring_table = (
+            jnp.asarray(self.pool.ring_tables) if ring_pages else None
+        )
         # Chunked prefill: fixed chunk length (None -> the engine
         # config's prefill_chunk_size), clamped to the slot budget;
         # 0 disables, callers fall back to prefill_into_slot.
-        if prefill_chunk_tokens is None:
-            prefill_chunk_tokens = int(
-                getattr(engine.config, "prefill_chunk_size", 0) or 0
-            )
-        self.prefill_chunk = max(
-            0, min(int(prefill_chunk_tokens), self.token_capacity)
-        )
+        self.prefill_chunk = _chunk_eff
         self.prefix_cache = None
         if arena_slots > 0:
             from luminaai_tpu.inference.prefix_cache import RadixPrefixCache
@@ -1166,6 +1200,55 @@ class StepwiseDecoder:
         )
         self._refresh_table()
 
+    @staticmethod
+    def _ring_pages_of(config, pages, page_size, chunk, backend,
+                       prefix_cache_pages) -> int:
+        """Pages of the ring a lane keeps of each layer with a window of
+        its own (0: no layer keeps one, or a ring would be no smaller
+        than the whole lane), and the refusals, by name, of what a ring
+        cannot honour."""
+        from luminaai_tpu.inference.kv_pool import RingKeepsWindowError
+
+        if getattr(config, "layer_windows", None) is None:
+            return 0
+        rings = {
+            config.ring_pages(i, page_size, chunk)
+            for i in range(config.num_layers)
+        } - {None}
+        rings = {r for r in rings if r < pages}
+        if not rings:
+            return 0
+        if len(rings) > 1:
+            raise RingKeepsWindowError(
+                f"layer_windows {config.layer_windows} give rings of "
+                f"{sorted(rings)} pages: one pool keeps one ring table, "
+                "so one ring size"
+            )
+        for bad, why in (
+            (prefix_cache_pages > 0,
+             f"the prefix cache (prefix_cache_pages={prefix_cache_pages}): "
+             "a cached page chain starts at position 0, and a ring keeps "
+             "no page older than the window, so a spliced prefix would "
+             "find its window layers' pages overwritten"),
+            (chunk <= 0,
+             "a pool without chunked prefill (prefill_chunk_size=0): a "
+             "ring is sized to the window plus ONE chunk, and a whole "
+             "prompt written at once would overrun it"),
+            (getattr(config, "kv_cache_dtype", "bf16") == "int8",
+             "kv_cache_dtype='int8': the ring's write and read paths "
+             "carry no per-row scales yet"),
+            (backend == "dense",
+             "attention_backend='dense': its mask takes a row's number "
+             "for its position, and a ring's rows hold the positions the "
+             "ring table says"),
+        ):
+            if bad:
+                raise RingKeepsWindowError(
+                    f"not served beside a ring of pages "
+                    f"(layer_windows {config.layer_windows}): {why}"
+                )
+        return rings.pop()
+
     def _init_pool_caches(self):
         """A zeroed cache tree at the pool's geometry, paged layout.
         Built in ONE jitted call: eager, the flat tree and its paged
@@ -1179,6 +1262,7 @@ class StepwiseDecoder:
                     self.slot_tokens,
                     kv_cache_dtype=getattr(config, "kv_cache_dtype", None),
                     rolling=False,
+                    ring=self._ring,
                 )
             )
 
@@ -1209,7 +1293,9 @@ class StepwiseDecoder:
         self._inflight: collections.deque = collections.deque()
         self._budget = np.zeros((S,), np.int32)
         self._host_tok = np.ones((S,), bool)
-        self._nxt_dev = jnp.zeros((S,), jnp.int32)
+        # (With a share of the experts its three pair counts ride behind
+        # the lanes' tokens.)
+        self._nxt_dev = jnp.zeros((S + 3 * self._held,), jnp.int32)
 
     def _identity_gtable(self) -> np.ndarray:
         P = self.pool.pages
@@ -1319,12 +1405,12 @@ class StepwiseDecoder:
     def _flat(self, tree):
         from luminaai_tpu.inference.kv_pool import to_flat
 
-        return to_flat(tree, self.pool.pages, self.pool.page_size)
+        return to_flat(tree, self.pool.page_size)
 
     def _paged(self, tree):
         from luminaai_tpu.inference.kv_pool import to_paged
 
-        return to_paged(tree, self.pool.pages, self.pool.page_size)
+        return to_paged(tree, self.pool.page_size)
 
     def _get_prefill(self, bucket: int):
         key = ("prefill", bucket)
@@ -1441,6 +1527,9 @@ class StepwiseDecoder:
             backend = self.backend
             window = getattr(self.engine.config, "attention_window", None)
             page_size = self.pool.page_size
+            ring_table = self._ring_table
+            held = self._held
+            moe_layers = self.engine.config.num_moe_layers()
             lm_head = Embedder(self.model.config, dtype=self.model.dtype)
 
             def sample(rng, logits, counts):
@@ -1466,7 +1555,7 @@ class StepwiseDecoder:
                 lanes = tick[: 4 * S].reshape(4, S)
                 pos = lanes[0]
                 active = lanes[1] != 0
-                tokens = jnp.where(lanes[2] != 0, lanes[3], prev_nxt)
+                tokens = jnp.where(lanes[2] != 0, lanes[3], prev_nxt[:S])
                 c_slot, c_start, c_len, c_last, c_seed = (
                     tick[4 * S + i] for i in range(5)
                 )
@@ -1505,13 +1594,14 @@ class StepwiseDecoder:
                         backend=backend,
                         identity_pages=not use_global,
                         global_pages=use_global,
+                        ring_table=ring_table,
                         **chunk,
                     )
                 # One token a row: S lanes, then the chunk's rows. A row
                 # at position -1 writes no K/V: a lane not stepped (a
                 # slot being prefilled among them) and the chunk's
                 # padding.
-                hidden, flat, _ = self.model.apply(
+                hidden, flat, aux = self.model.apply(
                     {"params": params},
                     jnp.concatenate([tokens, tick[4 * S + 5:]])[:, None],
                     positions=jnp.concatenate(
@@ -1570,6 +1660,15 @@ class StepwiseDecoder:
                     new_rngs = new_rngs.at[c_slot].set(
                         jnp.where(is_last, rng, new_rngs[c_slot])
                     )
+                if held:
+                    # The share's pair counts of this tick's live rows
+                    # (a layer's mean x the expert layers), behind the
+                    # tokens: one fetch brings both.
+                    nxt = jnp.concatenate([nxt, jnp.round(jnp.stack([
+                        aux[k] for k in (
+                            "moe_routed_pairs", "moe_held_pairs",
+                            "moe_held_pairs_dropped")
+                    ]) * moe_layers).astype(jnp.int32)])
                 return self._paged(flat), nxt, eos, counts, new_rngs
 
             # Everything the step rewrites is donated (the pool, the
@@ -1597,6 +1696,14 @@ class StepwiseDecoder:
         stopped (or the budget is a single token)."""
         sample_key = sample_key or GREEDY_SAMPLE_KEY
         max_new = max(1, int(max_new_tokens))
+        if self.pool.ring_pages:
+            from luminaai_tpu.inference.kv_pool import RingKeepsWindowError
+
+            raise RingKeepsWindowError(
+                "prefill_into_slot writes a whole prompt at once; a pool "
+                "with rings of pages takes every prompt in chunks "
+                "(start_prefill never declines there)"
+            )
         if not list(prompt_tokens):
             raise ValueError("prefill_into_slot needs a non-empty prompt")
         # generate()'s own trim against the slot's budget — one shared
@@ -1753,7 +1860,7 @@ class StepwiseDecoder:
                     )
             if L <= chunk and not peek_keys:
                 return None
-        elif L <= chunk:
+        elif L <= chunk and not self.pool.ring_pages:
             # A one-chunk prompt can't stall anyone longer than a chunk
             # anyway, and the bucketed prefill_into_slot path moves only
             # a page-aligned prompt prefix where a chunk call round-trips
@@ -1946,10 +2053,12 @@ class StepwiseDecoder:
         if not self.prefill_ready(st):
             return None
         step = self._dispatch(st["sample_key"], st, step_lanes=False)
-        if step["chunk"]["last"]:
+        # (A share's pair counts ride every tick's tokens: read them all.)
+        if step["chunk"]["last"] or self._held:
             with self.phases.region("device_wait"), \
                     self.tracer.span("decode.fetch"):
-                first = int(np.asarray(step["nxt"])[st["slot"]])
+                first = int(self._read_tokens(step)[st["slot"]])
+        if step["chunk"]["last"]:
             self._first_token(st, first)
         return st.pop("info", None)
 
@@ -2204,7 +2313,7 @@ class StepwiseDecoder:
         chunk: Optional[Dict[str, Any]] = None,
         step_lanes: bool = True,
     ):
-        """(tick program, packed tick, lanes stepped) of the step that
+        """(tick program, packed tick, lanes stepped, extent) of the step that
         would be dispatched right now: everything a tick sends is ONE
         int32 array, the lanes (_pack_lanes), then five numbers of the
         chunk (slot, first row, prompt length, is-last, seed) and its
@@ -2241,7 +2350,34 @@ class StepwiseDecoder:
             self._active_extent(lanes[0], live)
             if self.backend != "dense" else None
         )
-        return self._get_step(sample_key, extent), tick, live
+        return self._get_step(sample_key, extent), tick, live, extent
+
+    def _kv_rows_of(self, extent, pos, live, chunk) -> None:
+        """Book what the tick about to be dispatched reads and wraps,
+        from lengths the host has: every lane's rows up to the tick's
+        extent in a full layer and its whole ring in a layer with a
+        window of its own (the program reads a lane whether or not it is
+        stepped), plus the chunk's lane up to the chunk's end; a wrap
+        each time a row is written onto the ring's first row again."""
+        ring = self.pool.ring_pages * self.pool.page_size
+        lanes_full = extent or self.slot_tokens
+        lanes_window = ring or lanes_full
+        c_full = c_window = 0
+        if chunk is not None:
+            start = self._chunk_start(chunk)
+            end = min(start + chunk["chunk"], chunk["length"])
+            c_full, c_window = end, min(end, lanes_window)
+            if ring:
+                self.ring_wraps += (end - 1) // ring - max(start - 1, 0) // ring
+        self.kv_global_rows += self._n_global_layers * (
+            self.num_slots * lanes_full + c_full
+        )
+        self.kv_window_rows += self._n_window_layers * (
+            self.num_slots * lanes_window + c_window
+        )
+        if ring:
+            at = pos[live]
+            self.ring_wraps += int(((at > 0) & (at % ring == 0)).sum())
 
     def step_fn_and_args(
         self, sample_key: Optional[Tuple] = None
@@ -2256,7 +2392,7 @@ class StepwiseDecoder:
         caller that RUNS it must rebind all three from the result as
         dispatch_step does, or the decoder is left holding deleted
         buffers."""
-        fn, tick, _ = self._next_step(sample_key)
+        fn, tick, _, _ = self._next_step(sample_key)
         args = (
             self.params,
             self.pool.caches,
@@ -2285,7 +2421,9 @@ class StepwiseDecoder:
         # (`decode.pack` and `decode.book` are spans alone: the ledger
         # books the host's packing and bookkeeping to `sched`.)
         with span("decode.pack"):
-            fn, tick, live = self._next_step(sample_key, chunk, step_lanes)
+            fn, tick, live, extent = self._next_step(
+                sample_key, chunk, step_lanes
+            )
         if self._inflight and chunk is None and not live.any():
             return None
         with region("put"), span("decode.put"):
@@ -2305,6 +2443,7 @@ class StepwiseDecoder:
         self._host_tok[:] = False
         if self.pool.keeps_state:
             self.ssm_rows += int(live.sum())
+        self._kv_rows_of(extent, tick[: self.num_slots], live, chunk)
         return {
             "nxt": nxt, "eos": eos, "stepped": live,
             "chunk": None if chunk is None else self._chunk_dispatched(
@@ -2340,7 +2479,7 @@ class StepwiseDecoder:
         step = self._inflight.popleft()
         with self.phases.region("device_wait"), \
                 self.tracer.span("decode.fetch"):
-            nxt_h = np.asarray(step["nxt"])
+            nxt_h = self._read_tokens(step)
             eos_h = np.asarray(step["eos"])
         with self.tracer.span("decode.book"):
             stepped = step["stepped"]
@@ -2359,6 +2498,19 @@ class StepwiseDecoder:
                 )
             self.steps += 1
             return nxt_h, stepped & ~eos_h, eos_h
+
+    def _read_tokens(self, step: Dict[str, Any]) -> np.ndarray:
+        """The lanes' tokens of a dispatched tick (blocks until it has
+        run). With a share of the experts (Config.experts_held) the same
+        fetch carries the tick's three pair counts behind them (a step
+        is read once: by collect_step, or by advance_prefill)."""
+        got = np.asarray(step["nxt"])
+        if self._held:
+            routed, here, dropped = (int(x) for x in got[self.num_slots:])
+            self.moe_routed_pairs += routed
+            self.moe_held_pairs += here
+            self.moe_held_pairs_dropped += dropped
+        return got[: self.num_slots]
 
     def abandon_steps(self) -> None:
         """Forget every step in flight: nothing of them is read. For

@@ -20,6 +20,18 @@ slot's admission starts it from zero on the device, and a page that is
 exported, imported or shared carries none of it, which is why a pool with
 states refuses those three by name (`StateNotPagedError`).
 
+A layer with a window of its own (`Config.layer_windows`) keeps a THIRD
+kind: a RING of pages, `[num_slots, ring_pages, page_size, kv_heads,
+head_dim]` with `ring_pages = ceil((window + prefill chunk) / page_size) +
+1`, where a full layer beside it keeps all `pages`. Positions stay
+absolute: logical page j of a slot's ring layer lives at physical page
+`ring_tables[slot, j]` (j mod ring_pages), written and read through that
+table (`ops/ragged_paged_attention.py::ring_key_positions`). A ring keeps
+no page older than the window, so what needs an old page is refused by
+name (`RingKeepsWindowError`): the prefix cache, a page export or import,
+speculation's k-row verify; so are int8 k/v and a pool without chunked
+prefill, which the ring's write path does not serve.
+
 Device arrays live here only as an opaque pytree (`self.caches`); all
 accounting — the free-list, per-slot length vector, reuse counters — is
 host-side numpy, so the scheduler never has to read device memory to
@@ -52,6 +64,12 @@ class StateNotPagedError(NotImplementedError):
     """Asked of a pool that holds a fixed state a lane beside its pages:
     something that moves or shares PAGES (the prefix cache, a page export
     or import) and would leave the state behind."""
+
+
+class RingKeepsWindowError(NotImplementedError):
+    """Asked of a pool in which some layer keeps a ring of pages a lane
+    (a window of its own): something that needs a page older than the
+    window, or a write path the ring does not have."""
 
 
 def map_pages(fn, tree, *rest, states=None):
@@ -116,28 +134,30 @@ def parse_page_payload(payload: bytes) -> List[np.ndarray]:
     return out
 
 
-def to_paged(tree, pages: int, page_size: int):
+def to_paged(tree, page_size: int):
     """Reshape a model-layout cache tree into the paged pool layout:
-    [..., C, heads, dim] leaves become [..., pages, page_size, heads,
-    dim]. The row axis is addressed from the TAIL (ndim-3) so the rule
-    covers both the plain per-layer layout ([slots, C, ...]) and the
-    scan_layers layout with its extra leading segment axis ([count,
-    slots, C, ...]). Pure metadata under jit (C == pages * page_size is
-    contiguous)."""
+    [..., C, heads, dim] leaves become [..., C / page_size, page_size,
+    heads, dim]: the pool's `pages` for a layer that keeps whole pages,
+    its own fewer for a layer that keeps a ring. The row axis is addressed
+    from the TAIL (ndim-3) so the rule covers both the plain per-layer
+    layout ([slots, C, ...]) and the scan_layers layout with its extra
+    leading segment axis ([count, slots, C, ...]). Pure metadata under
+    jit (the rows are contiguous)."""
     return map_pages(
         lambda x: x.reshape(
-            x.shape[:-3] + (pages, page_size) + x.shape[-2:]
+            x.shape[:-3] + (x.shape[-3] // page_size, page_size)
+            + x.shape[-2:]
         ),
         tree,
     )
 
 
-def to_flat(tree, pages: int, page_size: int):
-    """Inverse of to_paged: the [..., pages*page_size, heads, dim] view
-    the model's attention layers consume."""
+def to_flat(tree, page_size: int):
+    """Inverse of to_paged: the [..., rows, heads, dim] view
+    the model's attention layers consume (a ring's own pages for a ring)."""
     return map_pages(
         lambda x: x.reshape(
-            x.shape[:-4] + (pages * page_size,) + x.shape[-2:]
+            x.shape[:-4] + (x.shape[-4] * page_size,) + x.shape[-2:]
         ),
         tree,
     )
@@ -159,6 +179,7 @@ class PagedKVPool:
         num_slots: int,
         pages: int,
         page_size: int,
+        ring_pages: int = 0,
     ):
         if num_slots < 1 or pages < 1 or page_size < 1:
             raise ValueError(
@@ -181,6 +202,19 @@ class PagedKVPool:
         # tested).
         self.page_tables = np.tile(
             np.arange(pages, dtype=np.int32), (num_slots, 1)
+        )
+        # Layers that keep a RING of `ring_pages` pages a lane (0: none
+        # does): logical page j of slot s lives at physical page
+        # `ring_tables[s, j]` of the ring, the only place that says where
+        # a position's row is. Never retargeted: a ring's pages are the
+        # lane's own and are shared with nobody.
+        self.ring_pages = int(ring_pages)
+        self.ring_tables = (
+            np.tile(
+                np.arange(pages, dtype=np.int32) % self.ring_pages,
+                (num_slots, 1),
+            )
+            if self.ring_pages else None
         )
         # LIFO free-list: the most recently freed slot is re-issued first,
         # so its cache rows are the warmest in HBM when overwritten.
@@ -290,7 +324,36 @@ class PagedKVPool:
             self._keeps_state = bool(lane_states(self.caches))
         return self._keeps_state
 
+    def slot_bytes(self) -> dict:
+        """What ONE slot holds on the device, by entry kind: whole pages
+        (`pages` a layer), rings of pages (`ring_pages` a layer), fixed
+        states. Read from the cache tree's own shapes."""
+        import jax
+
+        from luminaai_tpu.models.ssm import is_lane_state
+
+        out = {"pages": 0, "ring": 0, "state": 0}
+        if self.caches is None:
+            return out
+        for entry in jax.tree.leaves(self.caches, is_leaf=is_lane_state):
+            if is_lane_state(entry):
+                out["state"] += entry.nbytes() // entry.state.shape[-3]
+                continue
+            ring = entry.shape[-4] != self.pages
+            out["ring" if ring else "pages"] += (
+                entry.nbytes // entry.shape[-5]
+            )
+        out["total"] = sum(out.values())
+        return out
+
     def _pages_only(self, what: str) -> None:
+        if self.ring_pages:
+            raise RingKeepsWindowError(
+                f"{what}: a layer of this pool keeps a ring of "
+                f"{self.ring_pages} pages a lane (a window of its own), "
+                "and a ring keeps no page older than the window: the page "
+                "asked for may hold another position's rows by now"
+            )
         if self.keeps_state:
             raise StateNotPagedError(
                 f"{what}: this pool keeps a fixed state a lane (an 'ssm' "
@@ -442,4 +505,5 @@ class PagedKVPool:
                 "pages_total": self.num_slots * self.pages,
                 "fragmentation_rows": self.fragmentation_rows(),
                 "lengths": self._length_summary(),
+                "ring_pages": self.ring_pages,
             }

@@ -98,11 +98,14 @@ def apply_rope(
     sin: jax.Array,
     positions: Optional[jax.Array] = None,
     compute_dtype: Optional[Any] = None,
+    layout: str = "split",
 ) -> jax.Array:
     """Rotate q/k (ref core/model.py:471 apply_rotary_pos_emb_optimized).
 
     x: [B, S, H, D]; cos/sin: [max_len, D//2]; positions: [B, S] (optional).
-    Split-halves convention (x1 = x[..., :D/2], x2 = x[..., D/2:]).
+    layout 'split': pair i is (x[..., i], x[..., i + D/2]) (rotate_half);
+    'interleaved': pair i is (x[..., 2i], x[..., 2i + 1]) (GPT-J's). The
+    two are one model under a permutation of the head's columns.
 
     compute_dtype: fp32 by default (exact table math; an [B,S,H,D] fp32
     intermediate + convert per projection). Passing the model compute
@@ -120,6 +123,10 @@ def apply_rope(
         c = cos[positions][:, :, None, :]
         s = sin[positions][:, :, None, :]
     c, s = c.astype(ct), s.astype(ct)
+    if layout == "interleaved":
+        x1, x2 = x[..., 0::2].astype(ct), x[..., 1::2].astype(ct)
+        out = jnp.stack([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
+        return out.reshape(x.shape).astype(x.dtype)
     x1, x2 = x[..., :d2].astype(ct), x[..., d2:].astype(ct)
     out = jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
     return out.astype(x.dtype)
@@ -173,6 +180,13 @@ class SwiGLU(nn.Module):
         return jnp.einsum("...f,fd->...d", act, wo.astype(self.dtype))
 
 
+# Above this many bytes of [chunk rows, heads, keys] float32 scores the
+# tick's chunk attends through the blocked kernel (GQAttention.
+# _tick_attention): 64 x 16 x 2,048 and 64 x 20 x 2,048 scores are 8 and
+# 10 MB and stay XLA's; 256 x 128 x 16,384 are 2.1 GB.
+_CHUNK_SCORES_LIMIT = 256 * 2**20
+
+
 class GQAttention(nn.Module):
     """Grouped-query attention with RoPE (ref core/model.py:565).
 
@@ -188,18 +202,33 @@ class GQAttention(nn.Module):
     # one — a rolling cache then attends the cache with the slot mask
     # (the whole band is resident) instead of the raw prompt rows.
     multi_row_update: bool = False
+    # The layer this is, for what Config says a layer (window_of,
+    # rope_of); None reads the model's one value.
+    layer_idx: Optional[int] = None
 
     @staticmethod
     def init_cache(cfg: Config, batch_size: int, max_len: int, dtype,
                    kv_cache_dtype: Optional[str] = None,
-                   rolling: bool = True, lead=()):
+                   rolling: bool = True, lead=(),
+                   layer: Optional[int] = None, ring=None):
         """What a lane keeps of an attention layer: a (k, v) pair of
         [batch, rows, kv_heads, head_dim] (int8: codes and per-row
-        scales), which the pool pages (LuminaTransformer.init_cache says
-        when the rows roll)."""
+        scales), which the pool pages. One of two entries by the layer's
+        window: whole pages (`max_len` rows), or, for a layer with a
+        window of its own in the slot-paged pool (`ring`: the pool's
+        (page_size, prefill chunk)), a RING of Config.ring_pages pages a
+        lane, read and written at absolute positions through the lane's
+        ring table (LaneMeta.ring_table). LuminaTransformer.init_cache
+        says when the single-stream engine's rows roll."""
         choice = kv_cache_dtype or cfg.kv_cache_dtype
         C = max_len
-        if (
+        n_ring = None
+        if ring is not None and layer is not None:
+            n_ring = cfg.ring_pages(layer, *ring)
+        if n_ring is not None:
+            # Whole pages when the ring would be no smaller.
+            C = min(max_len, n_ring * ring[0])
+        elif (
             rolling
             and cfg.attention_window is not None
             and max_len <= cfg.seq_length
@@ -328,11 +357,14 @@ class GQAttention(nn.Module):
             max_len = max(cfg.seq_length, S, cache_len)
         else:
             max_len = max(cfg.seq_length, S)
-        if cfg.use_rope:
+        window = cfg.window_of(self.layer_idx)
+        if cfg.rope_of(self.layer_idx):
             cos, sin = rope_frequencies(d, max_len, cfg.rope_theta)
             rope_ct = self.dtype if cfg.rope_dtype == "bf16" else jnp.float32
-            q = apply_rope(q, cos, sin, positions, compute_dtype=rope_ct)
-            k = apply_rope(k, cos, sin, positions, compute_dtype=rope_ct)
+            q = apply_rope(q, cos, sin, positions, compute_dtype=rope_ct,
+                           layout=cfg.rope_layout)
+            k = apply_rope(k, cos, sin, positions, compute_dtype=rope_ct,
+                           layout=cfg.rope_layout)
 
         new_cache = None
         rolling_prefill = False
@@ -352,14 +384,24 @@ class GQAttention(nn.Module):
                 cache_index is not None
                 and getattr(cache_index, "ndim", 0) == 1
             )
-            windowed = cfg.attention_window is not None
-            # The cache is ROLLING only when init_cache actually shrank it
-            # below the position span (see init_cache); otherwise slot ==
-            # position and every plain-layout path below applies.
+            # The single-stream engine's cache is ROLLING only under the
+            # one uniform window, when init_cache actually shrank it
+            # below the position span; otherwise slot == position and
+            # every plain-layout path below applies. (A layer with a
+            # window of its own never rolls so: the pool gives it a ring
+            # of pages, below.)
             rolling = (
-                windowed
+                cfg.attention_window is not None
                 and C_cache < max(cfg.seq_length, S)
                 and not per_lane
+            )
+            # A RING of pages (init_cache): the pool's tick hands the
+            # lanes' ring tables, and this layer's lane is shorter than
+            # the pages the table names.
+            ring_table = getattr(lane_meta, "ring_table", None)
+            ring = (
+                per_lane and window is not None and ring_table is not None
+                and C_cache < ring_table.shape[1] * lane_meta.page_size
             )
             # Rolling-cache write index: slot = pos % C; decode wraps.
             if rolling and S == 1:
@@ -445,12 +487,32 @@ class GQAttention(nn.Module):
                 row_at = write_at if positions is None else jnp.where(
                     positions[:, 0] >= 0, positions[:, 0], C_cache
                 )
+                if ring:
+                    # Logical page p // page_size lives where the lane's
+                    # ring table says; the position stays absolute.
+                    ps = lane_meta.page_size
+                    at = jnp.maximum(positions[:, 0], 0)
+                    page = ring_table[
+                        row_slot,
+                        jnp.minimum(at // ps, ring_table.shape[1] - 1),
+                    ]
+                    row_at = jnp.where(
+                        positions[:, 0] >= 0, page * ps + at % ps, C_cache
+                    )
 
                 def _row_scatter(cache_arr, fresh):
                     return cache_arr.at[row_slot, row_at].set(
                         fresh[:, 0], mode="drop"
                     )
 
+            if ring and S > 1:
+                from luminaai_tpu.inference.kv_pool import RingKeepsWindowError
+
+                raise RingKeepsWindowError(
+                    "a ring of pages is written one row a lane a tick: a "
+                    "multi-row write (speculation's k-row verify, a "
+                    "whole-prompt prefill) is not served over a ring"
+                )
             if isinstance(ck, tuple):
                 # int8 KV cache (config.kv_cache_dtype='int8'): codes +
                 # per-row scales. Quantize the fresh rows at insert; read
@@ -503,11 +565,11 @@ class GQAttention(nn.Module):
                 # earlier rows and the slot mask silently reads future
                 # draft K/V as the evicted position (review-caught with
                 # window % 128 == 0, where slack is zero).
-                if S - 1 > C_cache - cfg.attention_window:
+                if S - 1 > C_cache - window:
                     raise ValueError(
                         f"rolling-cache multi-row update of {S} rows "
                         f"needs cache slack >= {S - 1} (cache {C_cache} "
-                        f"slots, window {cfg.attention_window}); reduce "
+                        f"slots, window {window}); reduce "
                         "draft_k or use a non-multiple-of-128 window"
                     )
             if rolling and S > 1 and not self.multi_row_update:
@@ -550,13 +612,13 @@ class GQAttention(nn.Module):
                     causal=True,
                     block_q=min(cfg.flash_block_q, S),
                     block_kv=min(cfg.flash_block_kv, S),
-                    window=cfg.attention_window,
+                    window=window,
                 )
             else:
                 out = _ring_attention_shard(
                     q, k, v, axis_name="sequence", axis_size=sp,
                     causal=True,
-                    window=cfg.attention_window,
+                    window=window,
                 )
             y = _out_proj(out)
             return y, new_cache
@@ -592,7 +654,7 @@ class GQAttention(nn.Module):
                     use_flash=cfg.use_flash_attention,
                     block_q=cfg.flash_block_q,
                     block_kv=cfg.flash_block_kv,
-                    window=cfg.attention_window,
+                    window=window,
                 )
                 y = _out_proj(out)
                 return y, new_cache
@@ -630,7 +692,7 @@ class GQAttention(nn.Module):
                 causal=True,
                 block_q=cfg.flash_block_q,
                 block_kv=cfg.flash_block_kv,
-                window=cfg.attention_window,
+                window=window,
             )
         else:
             decoding_att = kv_cache is not None and not rolling_prefill
@@ -642,9 +704,15 @@ class GQAttention(nn.Module):
                 or getattr(cfg, "attention_backend", "dense")
             )
             if getattr(lane_meta, "chunk_rows", 0):
-                out = self._tick_attention(
-                    q, k, v, lane_meta, cache_index, positions, backend
-                )
+                with jax.named_scope(
+                    "attn_global" if window is None else "attn_window"
+                ):
+                    out = self._tick_attention(
+                        q, k, v, lane_meta, cache_index, positions,
+                        backend, ring,
+                    )
+            elif kv_cache is not None and ring:
+                out = self._ring_lanes(q, k, v, lane_meta)
             elif decoding_att and backend != "dense" and not rolling:
                 # Length-aware (LaneMeta) dispatch: scalar-offset decode,
                 # batched per-lane decode, and (chunked) prefill all
@@ -664,25 +732,100 @@ class GQAttention(nn.Module):
         y = _out_proj(out)
         return y, new_cache
 
+    def _window(self) -> Optional[int]:
+        return self.config.window_of(self.layer_idx)
+
+    def _ring_lanes(self, q, k, v, meta):
+        """The lanes' rows over their rings of pages, read in place: a
+        physical page's rows are masked by the positions of the logical
+        page the lane's ring table keeps there now, O(ring) rows a lane
+        whatever its context."""
+        from luminaai_tpu.ops.ragged_paged_attention import (
+            banded_attention_xla,
+            ring_key_positions,
+        )
+
+        n_d = q.shape[0]
+        lengths = meta.lengths[:n_d]
+        kpos = ring_key_positions(
+            meta.ring_table[:n_d], lengths, meta.page_size, k.shape[1]
+        )
+        return banded_attention_xla(
+            q, k[:n_d], v[:n_d], (lengths - 1)[:, None], kpos,
+            self._window(),
+        )
+
     def _tick_attention(self, q, k, v, meta, cache_index, positions,
-                        backend):
+                        backend, ring=False):
         """A decode batch with a prefill chunk riding it (LaneMeta.
         chunk_rows): the one place the two kinds of rows part and meet
         again. No weight is involved here, so each kind keeps the
         arithmetic it has alone: the decode rows attend as a plain
         decode batch does (their LaneMeta, their extent), and the chunk's
         rows attend their own slot's rows, whole, as one multi-row query
-        at `positions`: what a stand-alone chunk program would run."""
+        at `positions`: what a stand-alone chunk program would run.
+
+        Two things are new with a layer that keeps a ring (`ring`): both
+        kinds of rows read the ring in place, by the positions its pages
+        hold now; and where the chunk's [rows, heads, keys] float32
+        scores would not fit beside the weights (_CHUNK_SCORES_LIMIT),
+        ring or whole pages, the chunk's rows go through the blocked
+        online-softmax kernel (ops/ragged_paged_attention.py
+        chunk_attention) and no score leaves VMEM."""
+        from luminaai_tpu.ops.ragged_paged_attention import (
+            banded_attention_xla,
+            chunk_attention,
+            chunk_attention_eligible,
+            ring_key_positions,
+        )
+
         n_c = meta.chunk_rows
         n_d = q.shape[0] - n_c
         q_c = q[n_d:, 0][None]  # [1, n_c, Hq, D]
         pos_c = positions[n_d:, 0][None]
         start = jnp.reshape(meta.chunk_start, (1,))
+        window = self._window()
 
         def own(a):  # the chunk's slot of a per-slot array
             return jax.lax.dynamic_slice_in_dim(a, meta.chunk_slot, 1, 0)
 
-        if backend == "dense":
+        C = k.shape[1]
+        blocked = (
+            backend != "dense" and not meta.global_pages
+            and 4 * n_c * q.shape[2] * C > _CHUNK_SCORES_LIMIT
+            and chunk_attention_eligible(n_c, C, q.shape[3])
+        )
+        if ring or blocked:
+            lanes = meta.replace(chunk_rows=0, chunk_slot=None,
+                                 chunk_start=None)
+            if ring:
+                out_d = self._ring_lanes(q[:n_d], k, v, lanes)
+            else:
+                out_d = self._ragged_attention(
+                    q[:n_d], k, v, lanes, cache_index[:n_d], None, backend
+                )
+            # The chunk's keys, by position: a ring's pages hold what the
+            # table says after this chunk's rows are written; whole pages
+            # hold their own row numbers. `end` rows are live.
+            end = jnp.max(pos_c) + 1
+            if ring:
+                kpos = ring_key_positions(
+                    own(meta.ring_table), jnp.reshape(end, (1,)),
+                    meta.page_size, C,
+                )
+            else:
+                kpos = jnp.arange(C, dtype=jnp.int32)[None]
+                kpos = jnp.where(kpos < end, kpos, -1)
+            if blocked:
+                out_c = chunk_attention(
+                    q_c[0], own(k)[0], own(v)[0], pos_c[0], kpos[0],
+                    window, jnp.minimum(end, C),
+                )[None]
+            else:
+                out_c = banded_attention_xla(
+                    q_c, own(k), own(v), pos_c, kpos, window
+                )
+        elif backend == "dense":
             out_d = self._xla_attention(
                 q[:n_d], k, v, True, cache_index[:n_d]
             )
@@ -738,10 +881,13 @@ class GQAttention(nn.Module):
                 lengths = jnp.full((B,), cache_index + Sq, jnp.int32)
             meta = LaneMeta(
                 lengths=lengths,
-                window=self.config.attention_window,
+                window=self._window(),
                 kind="decode" if Sq == 1 else "prefill",
                 page_size=implied_page_size(k.shape[1]),
             )
+        if meta.window != self._window():
+            # The caller's one window for the model; this layer has its own.
+            meta = meta.replace(window=self._window())
         if getattr(meta, "global_pages", False):
             # Prefix-cache aliasing: physical pages may live in ANY slot
             # (including the cache arena), so the k/v rows cannot be
@@ -777,7 +923,7 @@ class GQAttention(nn.Module):
         scale = 1.0 / jnp.sqrt(d).astype(jnp.float32)
         logits = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k).astype(jnp.float32) * scale
 
-        w = self.config.attention_window
+        w = self._window()
         if (
             decoding
             and cache_index is not None

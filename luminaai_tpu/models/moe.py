@@ -251,7 +251,13 @@ class MoELayer(nn.Module):
     deterministic: bool = True
 
     @nn.compact
-    def __call__(self, x: jax.Array) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    def __call__(
+        self, x: jax.Array, live: Optional[jax.Array] = None
+    ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+        """`live` [G, S] bool: rows that are tokens (a serving tick's
+        other rows are lanes not stepped and a chunk's padding). Read by
+        a share's grouped matmul alone (experts_held): rows that are no
+        tokens take no row of it and count in none of its pair counters."""
         cfg = self.config
         deterministic = self.deterministic
         G, S, H = x.shape
@@ -384,6 +390,7 @@ class MoELayer(nn.Module):
                         offset=off, dtype=self.dtype, gmm_fn=_pick_gmm(),
                         rule=rule,
                         row_bound=-(-rows // _GMM_ROW_TILE) * _GMM_ROW_TILE,
+                        live=live,
                     )
             else:
                 out, tokens_per_expert, dropped = self._gmm_path(
@@ -524,12 +531,19 @@ class MoELayer(nn.Module):
         if cfg.num_shared_experts:
             from luminaai_tpu.models.layers import SwiGLU
 
-            out = out + SwiGLU(
-                cfg.num_shared_experts * F,
-                dtype=self.dtype,
-                init_std=cfg.init_std,
-                name="shared_expert",
-            )(x.astype(self.dtype))
+            # num_shared_experts SwiGLUs of width F side by side are one
+            # of width n * F whose output is their SUM; 'average' divides
+            # it by n.
+            with jax.named_scope("moe_shared"):
+                shared = SwiGLU(
+                    cfg.num_shared_experts * F,
+                    dtype=self.dtype,
+                    init_std=cfg.init_std,
+                    name="shared_expert",
+                )(x.astype(self.dtype))
+            if cfg.shared_expert_combine == "average":
+                shared = shared * (1.0 / cfg.num_shared_experts)
+            out = out + shared
 
         # --- Aux losses + stats (ref :1244) ---
         # f_e: fraction of tokens whose slot went to expert e; P_e: mean prob.
@@ -1024,7 +1038,7 @@ def _held_gmm_tiling(m: int, k: int, n: int) -> Tuple[int, int, int]:
 
 
 def _gmm_held(x, router_probs, wi, wo, *, top_k, num_experts, offset,
-              row_bound, dtype, gmm_fn, rule):
+              row_bound, dtype, gmm_fn, rule, live=None):
     """The grouped-matmul expert FFN of a share the configuration names
     (Config.experts_held): wi / wo hold experts [offset, offset + E_l) of
     `num_experts`, routing runs over all of them, and the sort is
@@ -1034,7 +1048,9 @@ def _gmm_held(x, router_probs, wi, wo, *, top_k, num_experts, offset,
     are held; a buffer of all N would be 32 times the work), the combine
     is a scatter-add of those rows into their tokens, and nothing is
     psum'd. The operand masks and the kernel's uninitialised-tail
-    contract are _gmm_local's.
+    contract are _gmm_local's. `live` [G, S] (a serving tick): rows that
+    are no tokens go to the excluded tail with the pairs of experts held
+    elsewhere, and the pair counts are over live rows.
 
     Returns (out [G,S,H], tokens_per_expert [E], dropped [G,S], and the
     pair counts: routed, held (chosen for a held expert), held and not
@@ -1051,8 +1067,18 @@ def _gmm_held(x, router_probs, wi, wo, *, top_k, num_experts, offset,
     E_l = wi.shape[0]
     k, R = top_k, row_bound
     loc = e_pair - offset
-    e_sort = jnp.where((loc >= 0) & (loc < E_l), loc, E_l)
+    here = (loc >= 0) & (loc < E_l)
     held = counts_e[offset:offset + E_l]  # pairs each held expert kept
+    routed = jnp.float32(G * S * k)
+    routed_here = chosen_e[offset:offset + E_l].sum()
+    if live is not None:
+        here = here & jnp.repeat(live.reshape(-1), k)
+        held = jnp.zeros((E_l,), jnp.int32).at[
+            jnp.where(here, loc, E_l)
+        ].add(1, mode="drop")
+        routed = live.sum().astype(jnp.float32) * k
+        routed_here = held.sum()
+    e_sort = jnp.where(here, loc, E_l)
     # Cut the runs at the bound, the last experts' rows first to go.
     ends = jnp.minimum(jnp.cumsum(held), R)
     group_sizes = jnp.diff(ends, prepend=0).astype(jnp.int32)
@@ -1078,9 +1104,8 @@ def _gmm_held(x, router_probs, wi, wo, *, top_k, num_experts, offset,
     # A token has at most k rows here and most have none: a scatter-add of
     # R rows in float32, not a gather of all N pairs.
     out = jnp.zeros((G * S, H), jnp.float32).at[tok].add(yrow)
-    routed_here = chosen_e[offset:offset + E_l].sum()
     stats = {
-        "moe_routed_pairs": jnp.float32(G * S * k),
+        "moe_routed_pairs": routed,
         "moe_held_pairs": routed_here.astype(jnp.float32),
         "moe_held_pairs_dropped": (routed_here - total).astype(jnp.float32),
     }

@@ -24,6 +24,7 @@ from luminaai_tpu.models.layers import (
     Embedder,
     GQAttention,
     LatentAttention,
+    LayerNorm,
     RMSNorm,
     SwiGLU,
 )
@@ -71,8 +72,20 @@ REMAT_POLICIES = {
 }
 
 
+def block_norm(cfg: Config, dtype, name: str):
+    """A block's (and the final) norm, by Config.norm_kind: RMSNorm, or
+    the mean-subtracting LayerNorm without bias."""
+    if cfg.norm_kind == "layernorm":
+        return LayerNorm(
+            cfg.layer_norm_eps, use_bias=False, dtype=dtype, name=name
+        )
+    return RMSNorm(cfg.rms_norm_eps, dtype=dtype, name=name)
+
+
 class TransformerBlock(nn.Module):
-    """Pre-norm block: x + attn(norm(x)); x + ffn(norm(x)).
+    """Pre-norm block: x + attn(norm(x)); x + ffn(norm(x)). Under
+    Config.parallel_block ONE norm feeds both and both are added to the
+    residual: x + attn(norm(x)) + ffn(norm(x)).
 
     FFN is one of: dense SwiGLU, MoE (per `config.is_moe_layer`), or
     MoD-gated SwiGLU on dense layers in hybrid mode (ref core/model.py:1304).
@@ -99,14 +112,13 @@ class TransformerBlock(nn.Module):
         deterministic = self.deterministic
         metrics: Dict[str, jax.Array] = {}
 
-        normed = RMSNorm(
-            cfg.rms_norm_eps, dtype=self.dtype, name="attn_norm"
-        )(x)
+        normed = block_norm(cfg, self.dtype, "attn_norm")(x)
         kind = cfg.mixer_kind(self.layer_idx)
         if kind == "attention":
             h, new_cache = GQAttention(
                 cfg, dtype=self.dtype,
-                multi_row_update=self.multi_row_update, name="attention",
+                multi_row_update=self.multi_row_update,
+                layer_idx=self.layer_idx, name="attention",
             )(
                 normed,
                 positions=positions,
@@ -140,16 +152,28 @@ class TransformerBlock(nn.Module):
                 )(normed)
                 metrics.update(kda_stats)
         h = checkpoint_name(h, "attn_out")
-        x = x + h
-        x = nn.with_logical_constraint(
-            x, ("activation_batch", "activation_length", "activation_embed")
-        )
-
-        y = RMSNorm(cfg.rms_norm_eps, dtype=self.dtype, name="ffn_norm")(x)
+        if cfg.parallel_block:
+            # The feed-forward reads the same normed rows as the mixer;
+            # the residual takes both below.
+            residual, y = x + h, normed
+        else:
+            x = x + h
+            x = nn.with_logical_constraint(
+                x,
+                ("activation_batch", "activation_length", "activation_embed"),
+            )
+            residual = x
+            y = block_norm(cfg, self.dtype, "ffn_norm")(x)
         if cfg.is_moe_layer(self.layer_idx):
+            # A serving tick's rows at position -1 (a lane not stepped, a
+            # chunk's padding) are no tokens: a share's grouped matmul and
+            # its pair counters leave them out.
+            live = None
+            if kv_cache is not None and positions is not None:
+                live = positions >= 0
             ffn_out, moe_metrics = MoELayer(
                 cfg, dtype=self.dtype, deterministic=deterministic, name="moe"
-            )(y)
+            )(y, live=live)
             metrics.update(moe_metrics)
         elif cfg.use_mod and kv_cache is None:
             # MoD skip-routing on dense layers (hybrid mode); decode path runs
@@ -180,7 +204,7 @@ class TransformerBlock(nn.Module):
             )(y)
 
         ffn_out = checkpoint_name(ffn_out, "ffn_out")
-        x = x + ffn_out
+        x = residual + ffn_out
         x = nn.with_logical_constraint(
             x, ("activation_batch", "activation_length", "activation_embed")
         )
@@ -384,7 +408,7 @@ class LuminaTransformer(nn.Module):
                 if metrics:
                     all_metrics.append(metrics)
 
-        x = RMSNorm(cfg.rms_norm_eps, dtype=self.dtype, name="final_norm")(x)
+        x = block_norm(cfg, self.dtype, "final_norm")(x)
         if n_prefix:
             # Strip virtual-token positions before the vocab matmul — the
             # [B, P, V] logits would be computed only to be discarded.
@@ -470,7 +494,10 @@ class LuminaTransformer(nn.Module):
         out: Dict[str, jax.Array] = {"aux_loss": jnp.float32(0.0)}
         if not all_metrics:
             return out
-        keys = set().union(*[m.keys() for m in all_metrics])
+        # Sorted: a set of strings iterates by the process's hash seed, and
+        # the order the sums are emitted in is part of the compiled program's
+        # cache key wherever a caller reads them (the tick's held-pair counts).
+        keys = sorted(set().union(*[m.keys() for m in all_metrics]))
         for key in keys:
             if key.endswith("__cnt"):
                 continue
@@ -502,6 +529,7 @@ class LuminaTransformer(nn.Module):
         max_len: int,
         kv_cache_dtype: str = None,
         rolling: bool = True,
+        ring=None,
     ):
         """Preallocated KV caches, shaped to match the layer-stack layout:
         per-layer pairs normally; per-segment stacked pairs under
@@ -525,7 +553,11 @@ class LuminaTransformer(nn.Module):
         rolling=False forces the plain position-addressed layout even
         under attention_window — the slot-paged continuous-batching pool
         (inference/kv_pool.py) is admission-bounded so positions never
-        wrap, and its per-lane writes assume slot == position."""
+        wrap, and its per-lane writes assume slot == position.
+
+        ring=(page_size, prefill chunk): the pool's; a layer with a window
+        of its own (Config.layer_windows) then keeps a ring of pages a
+        lane and not `max_len` rows (GQAttention.init_cache)."""
         cfg = self.config
 
         def entry(layer, *lead):
@@ -536,7 +568,8 @@ class LuminaTransformer(nn.Module):
                     cfg, batch_size, self.dtype, lead)
             return GQAttention.init_cache(
                 cfg, batch_size, max_len, self.dtype,
-                kv_cache_dtype=kv_cache_dtype, rolling=rolling, lead=lead)
+                kv_cache_dtype=kv_cache_dtype, rolling=rolling, lead=lead,
+                layer=layer, ring=ring)
 
         if cfg.scan_layers:
             return [

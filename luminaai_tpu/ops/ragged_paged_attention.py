@@ -125,6 +125,12 @@ class LaneMeta:
     chunk_rows: int = struct.field(pytree_node=False, default=0)
     chunk_slot: Optional[jax.Array] = None
     chunk_start: Optional[jax.Array] = None
+    # [num_slots, P] int32, for the layers that keep a RING of pages a
+    # lane (a window of their own, GQAttention.init_cache): logical page
+    # j of slot s lives at physical page `ring_table[s, j]` of the ring.
+    # Positions stay absolute; rows are written and read through this
+    # table (ring_key_positions). None: no layer of the pool keeps a ring.
+    ring_table: Optional[jax.Array] = None
 
 
 def ragged_eligible(page_size: int, head_dim: int, s_q: int) -> bool:
@@ -232,6 +238,207 @@ def ragged_paged_attention_xla(
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v)
     return out.reshape(B, Sq, n_q, d)
+
+
+# ---------------------------------------------------------------------------
+# Keys by position: a ring of pages read in place, and the tick's chunk
+# ---------------------------------------------------------------------------
+def ring_key_positions(
+    ring_table: jax.Array, lengths: jax.Array, page_size: int, rows: int
+) -> jax.Array:
+    """[B, rows] int32: the absolute position each physical row of a
+    lane's ring holds once `lengths` rows of the lane are written, -1
+    where it holds nothing of this lane. The ring has rows // page_size
+    pages; the logical pages still resident are the last that many up to
+    the page of row lengths - 1, and each sits where the ring table
+    (`ring_table[b, j]`: logical page j's physical page) put it. Rows of
+    the last page past lengths - 1 read as positions not yet written: a
+    causal mask drops them as it drops any future key."""
+    B, P = ring_table.shape
+    n_ring = rows // page_size
+    last = (lengths.astype(jnp.int32) - 1) // page_size  # -1: empty lane
+    logical = last[:, None] - jnp.arange(n_ring, dtype=jnp.int32)[None, :]
+    held = jnp.logical_and(logical >= 0, lengths[:, None] > 0)
+    physical = jnp.take_along_axis(
+        ring_table.astype(jnp.int32), jnp.clip(logical, 0, P - 1), axis=1
+    )
+    resident = jnp.full((B, n_ring), -1, jnp.int32).at[
+        jnp.arange(B)[:, None], jnp.where(held, physical, n_ring)
+    ].set(logical, mode="drop")
+    within = jnp.arange(page_size, dtype=jnp.int32)[None, None, :]
+    kpos = jnp.where(
+        resident[:, :, None] >= 0,
+        resident[:, :, None] * page_size + within, -1,
+    )
+    return kpos.reshape(B, n_ring * page_size)
+
+
+def banded_attention_xla(
+    q: jax.Array, k: jax.Array, v: jax.Array,
+    qpos: jax.Array, kpos: jax.Array, window: Optional[int],
+) -> jax.Array:
+    """Attention masked by POSITIONS given as data: q [B, Sq, Hq, D] at
+    qpos [B, Sq] over k / v [B, C, Hkv, D] whose row c holds position
+    kpos [B, c] (-1: nothing). Key j is seen by query i iff
+    0 <= i - j (< window). What ragged_paged_attention_xla computes when
+    kpos is the row number; a ring of pages hands the positions its rows
+    hold now."""
+    B, Sq, n_q, d = q.shape
+    n_kv = k.shape[2]
+    g = n_q // n_kv
+    qg = q.reshape(B, Sq, n_kv, g, d)
+    scale = 1.0 / jnp.sqrt(d).astype(jnp.float32)
+    logits = (
+        jnp.einsum("bqhgd,bkhd->bhgqk", qg, k).astype(jnp.float32) * scale
+    )
+    qp, kp = qpos[:, :, None], kpos[:, None, :]
+    mask = jnp.logical_and(kp >= 0, kp <= qp)
+    if window is not None:
+        mask = jnp.logical_and(mask, qp - kp < window)
+    logits = jnp.where(mask[:, None, None], logits, NEG_INF)
+    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+    out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v)
+    return out.reshape(B, Sq, n_q, d)
+
+
+def _chunk_key_block(rows: int) -> int:
+    """Keys a grid step of chunk_attention: the largest multiple of 128
+    up to 1,024 that divides the lane's rows (a ring of 35 pages of 128
+    is 5 x 896), else the rows whole."""
+    for t in range(1024, 127, -128):
+        if rows % t == 0:
+            return t
+    return rows
+
+
+def chunk_attention_eligible(n_rows: int, key_rows: int, head_dim: int
+                             ) -> bool:
+    """Where the blocked kernel compiles for the chip: sublane-aligned
+    chunk, lane-aligned head, key rows in 128-row blocks. Off the chip it
+    is interpreted at any size (tests)."""
+    if _interpret():
+        return True
+    return (
+        n_rows % 8 == 0 and head_dim % 128 == 0 and key_rows % 128 == 0
+    )
+
+
+def _chunk_kernel(
+    blocks_ref,  # scalar prefetch [1]: key blocks that hold live rows
+    qpos_ref, kpos_ref, q_ref, k_ref, v_ref, o_ref,
+    m_scr, l_scr, acc_scr, *, scale, window,
+):
+    j = pl.program_id(1)
+    nj = pl.num_programs(1)
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    @pl.when(j < blocks_ref[0])
+    def _compute():
+        q = q_ref[0]  # [n, D]
+        k = k_ref[0]  # [Bk, D]
+        v = v_ref[0]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # [n, Bk]
+        qp = qpos_ref[:, :1]  # [n, 1]
+        kp = kpos_ref[:1, :]  # [1, Bk]
+        keep = jnp.logical_and(kp >= 0, kp <= qp)
+        if window:
+            keep = jnp.logical_and(keep, qp - kp < window)
+        s = jnp.where(keep, s, NEG_INF)
+        m_prev = m_scr[:, :]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1)[:, None])
+        alpha = jnp.exp(m_prev - m_new)
+        # A row with no key in band yet keeps m == NEG_INF: its exp(0)
+        # terms must not count.
+        p = jnp.where(keep, jnp.exp(s - m_new[:, :1]), 0.0)
+        l_scr[:, :] = l_scr[:, :] * alpha + jnp.sum(p, axis=-1)[:, None]
+        acc_scr[:] = acc_scr[:] * alpha[:, :1] + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        m_scr[:, :] = m_new
+
+    @pl.when(j == nj - 1)
+    def _finalize():
+        l = l_scr[:, :]
+        safe_l = jnp.where(l == 0.0, 1.0, l)
+        o_ref[0] = (acc_scr[:] / safe_l[:, :1]).astype(o_ref.dtype)
+
+
+def chunk_attention(
+    q: jax.Array, k: jax.Array, v: jax.Array,
+    qpos: jax.Array, kpos: jax.Array, window: Optional[int],
+    live_rows: jax.Array,
+) -> jax.Array:
+    """The tick's prefill chunk over its own lane, blocked over the keys
+    with an online softmax: q [n, Hq, D] at positions qpos [n] (-1: a
+    padding row, which sees nothing) over ONE lane's k / v [C, Hkv, D]
+    whose row c holds position kpos [c] (-1: nothing; whole pages hand
+    their row numbers, a ring what ring_key_positions says). Key j is
+    seen by query i iff 0 <= i - j (< window): banded_attention_xla's
+    rule. `live_rows`: rows of the lane that may hold a key (a scalar,
+    traced); key blocks wholly past it cost neither a DMA nor a step's
+    arithmetic. Grid (q head, key block): a head's [n, D] queries stay
+    put while its k/v head's blocks stream past; [n, block] float32
+    scores live in VMEM and nowhere else. Returns [n, Hq, D]."""
+    n, Hq, D = q.shape
+    C, Hkv = k.shape[0], k.shape[1]
+    group = Hq // Hkv
+    bk = _chunk_key_block(C)
+    nb = C // bk
+    blocks = jnp.reshape(
+        (jnp.asarray(live_rows, jnp.int32) + bk - 1) // bk, (1,)
+    )
+
+    def kv_map(h, j, blocks):
+        return (h // group, jnp.minimum(j, jnp.maximum(blocks[0] - 1, 0)), 0)
+
+    def kpos_map(h, j, blocks):
+        return (0, jnp.minimum(j, jnp.maximum(blocks[0] - 1, 0)))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(Hq, nb),
+        in_specs=[
+            pl.BlockSpec((n, LANES), lambda h, j, blocks: (0, 0)),
+            pl.BlockSpec((8, bk), kpos_map),
+            pl.BlockSpec((1, n, D), lambda h, j, blocks: (h, 0, 0)),
+            pl.BlockSpec((1, bk, D), kv_map),
+            pl.BlockSpec((1, bk, D), kv_map),
+        ],
+        out_specs=pl.BlockSpec((1, n, D), lambda h, j, blocks: (h, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((n, LANES), jnp.float32),
+            pltpu.VMEM((n, LANES), jnp.float32),
+            pltpu.VMEM((n, D), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(
+            _chunk_kernel, scale=1.0 / (D**0.5), window=int(window or 0)
+        ),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((Hq, n, D), q.dtype),
+        interpret=_interpret(),
+        name="chunk_attention",
+    )(
+        blocks,
+        # Lane- and sublane-replicated so each is a tile the kernel reads
+        # whole: [n, 128] and [8, C] int32.
+        jnp.broadcast_to(qpos.astype(jnp.int32)[:, None], (n, LANES)),
+        jnp.broadcast_to(kpos.astype(jnp.int32)[None, :], (8, C)),
+        q.transpose(1, 0, 2),
+        k.transpose(1, 0, 2),
+        v.transpose(1, 0, 2),
+    )
+    return out.transpose(1, 0, 2)
 
 
 # ---------------------------------------------------------------------------

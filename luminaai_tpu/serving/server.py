@@ -345,6 +345,15 @@ class ContinuousScheduler:
                 "a pulled page holds k/v rows and none of the recurrent "
                 "state a lane keeps beside them"
             )
+        if page_share is not None and getattr(pool, "ring_pages", 0):
+            from luminaai_tpu.inference.kv_pool import RingKeepsWindowError
+
+            raise RingKeepsWindowError(
+                "page pull (page_share) is not served beside a ring of "
+                "pages (Config.layer_windows): a ring keeps no page older "
+                "than the window, so a pulled chain would find its window "
+                "layers' pages overwritten"
+            )
         if page_share is not None and (
             getattr(decoder, "prefix_cache", None) is not None
         ):
@@ -569,11 +578,60 @@ class ContinuousScheduler:
             "Live rows through the state-space recurrence: stepped lanes "
             "and live rows of prefill chunks",
         )
+        # Layers with a window of their own keep a ring of pages a lane
+        # beside the full layers' whole pages: rows of k/v the ticks'
+        # attention read in each kind, and the times a lane's rows came
+        # round its ring. Counted from lengths the host has.
+        self._m_kv_window_rows = r.counter(
+            "serve_kv_window_rows_read_total",
+            "Rows of k/v the ticks' attention read in layers with a "
+            "window of their own (a ring of pages a lane), summed over "
+            "those layers",
+        )
+        self._m_kv_global_rows = r.counter(
+            "serve_kv_global_rows_read_total",
+            "Rows of k/v the ticks' attention read in full-attention "
+            "layers (whole pages up to the tick's extent), summed over "
+            "those layers",
+        )
+        self._m_ring_wraps = r.counter(
+            "serve_ring_wraps_total",
+            "Times a lane's next row was written onto the first row of "
+            "its ring of pages again",
+        )
+        # One chip's share of the experts (Config.experts_held), served:
+        # pairs of the ticks' live rows, summed over the expert layers.
+        # They ride the step's token fetch. (Trainer feeds the same
+        # counters a layer's mean at log cadence; the ratios agree.)
+        self._m_routed_pairs = r.counter(
+            "moe_routed_pairs_total", "Routed (token, expert) pairs"
+        )
+        self._m_held_pairs = r.counter(
+            "moe_held_pairs_total",
+            "Routed pairs that fell on an expert this program holds "
+            "(Config.experts_held)",
+        )
+        self._m_held_dropped = r.counter(
+            "moe_held_pairs_dropped_total",
+            "Held pairs not computed: beyond the grouped matmul's static "
+            "row bound (must stay 0)",
+        )
         # The decoder counts these where they happen; the registry
         # follows (_count_decoder).
+        self._decoder_counters = (
+            ("lane_steps_dropped", self._m_lane_steps_dropped),
+            ("chunks_carried", self._m_chunks_carried),
+            ("chunk_rows", self._m_chunk_rows),
+            ("ssm_rows", self._m_ssm_rows),
+            ("kv_window_rows", self._m_kv_window_rows),
+            ("kv_global_rows", self._m_kv_global_rows),
+            ("ring_wraps", self._m_ring_wraps),
+            ("moe_routed_pairs", self._m_routed_pairs),
+            ("moe_held_pairs", self._m_held_pairs),
+            ("moe_held_pairs_dropped", self._m_held_dropped),
+        )
         self._decoder_seen = {
-            "lane_steps_dropped": 0, "chunks_carried": 0, "chunk_rows": 0,
-            "ssm_rows": 0,
+            name: 0 for name, _ in self._decoder_counters
         }
         # Which attention path the decode step compiled (value is always
         # 1; the label is the payload): a scrape shows what served, not
@@ -1669,12 +1727,7 @@ class ContinuousScheduler:
         """The decoder counts the lane-steps it drops (where a lane is
         released or meets a stop token) and the chunks its steps carry;
         the registry follows."""
-        for name, metric in (
-            ("lane_steps_dropped", self._m_lane_steps_dropped),
-            ("chunks_carried", self._m_chunks_carried),
-            ("chunk_rows", self._m_chunk_rows),
-            ("ssm_rows", self._m_ssm_rows),
-        ):
+        for name, metric in self._decoder_counters:
             n = getattr(self.decoder, name, 0)
             seen = self._decoder_seen[name]
             if n != seen:

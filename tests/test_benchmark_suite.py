@@ -18,6 +18,7 @@ import os
 import pytest
 
 from benchmark.tests import test_ttft_stages as _ttft_stages
+from benchmark.architectures.cohere2_moe.test_reference import *  # noqa: F401,F403
 from benchmark.architectures.jamba.test_reference import *  # noqa: F401,F403
 from benchmark.architectures.kimi_linear.test_reference import *  # noqa: F401,F403
 from benchmark.architectures.prenorm_decoder.test_reference import *  # noqa: F401,F403
@@ -31,6 +32,7 @@ from benchmark.tests.test_ttft_stages import *  # noqa: F401,F403
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 COLLECTED = (
+    "benchmark/architectures/cohere2_moe/test_reference.py",
     "benchmark/architectures/jamba/test_reference.py",
     "benchmark/architectures/kimi_linear/test_reference.py",
     "benchmark/architectures/prenorm_decoder/test_reference.py",
@@ -46,7 +48,7 @@ COLLECTED = (
 
 # benchmark/tests/test_architectures.py was written when the benchmark had
 # ONE architecture, and its resolver test asserts that every cell's is
-# `prenorm_decoder`. PR 35 adds a second one (PR 37 a third), and a PR that adds to the
+# `prenorm_decoder`. PR 35 adds a second one (PR 37 a third, PR 42 a fourth), and a PR that adds to the
 # benchmark may not edit a file the benchmark has: the same test is taken
 # here with each cell held to the architecture its own configuration file
 # names, under the same name so that it is counted once. A `benchmark` PR
@@ -59,9 +61,19 @@ COLLECTED = (
 # so A's three chunks ride before B's two: 3 turns in 5. The same test is
 # taken here with that one line changed; a `benchmark` PR should change it
 # there and drop the override.
+#
+# The same file holds `BENCHMARK.json` to the state PR 39 left it in: the
+# four stage metrics are the LAST entries of `per_layer`, reported by the
+# two chat cells alone. PR 42 appends a cell to their `workloads` lists and
+# nine entries behind them (what a PR that adds a cell does). The two tests
+# are taken here with "the last four" read as "in this order, nothing
+# between them" and "the two cells" as "those two first"; a `benchmark` PR
+# should change them there and drop the overrides.
 SUPERSEDED_HERE = (
     "test_every_cell_resolves_its_architecture",
     "test_the_stage_means_add_up_to_the_programs_ttft",
+    "test_the_manifest_is_sound_with_the_four_entries",
+    "test_a_stage_metric_resolves_for_the_two_chat_cells",
 )
 
 
@@ -82,7 +94,7 @@ def test_every_cell_resolves_its_architecture():  # noqa: F811
             for name in required:
                 assert hasattr(module, name), (part, name)
         assert set(arch.work.KERNEL_FNS) == manifest.kernel_names(arch.name)
-    assert seen == {"prenorm_decoder", "kimi_linear", "jamba"}
+    assert seen == {"prenorm_decoder", "kimi_linear", "jamba", "cohere2_moe"}
 
 
 def test_the_stage_means_add_up_to_the_programs_ttft(window):  # noqa: F811
@@ -104,6 +116,42 @@ def test_the_stage_means_add_up_to_the_programs_ttft(window):  # noqa: F811
     assert window["counter:serving_prefill_chunks_total"] == 5
     assert got[_ttft_stages.TURNS] == pytest.approx(3 / 5)
     assert window["counter:serve_prefill_picks_not_oldest_total"] == 0
+
+
+def test_the_manifest_is_sound_with_the_four_entries():  # noqa: F811
+    from benchmark import manifest
+
+    bench = _ttft_stages.BENCH
+    assert manifest.check(bench) == []
+    names = [m["name"] for m in bench["per_layer"]]
+    want = [*_ttft_stages.STAGES, _ttft_stages.TURNS]
+    at = names.index(want[0])
+    assert names[at:at + 4] == want  # appended together, nothing moved
+
+
+@pytest.mark.parametrize("name", [*_ttft_stages.STAGES, _ttft_stages.TURNS])
+@pytest.mark.parametrize("cell_name", _ttft_stages.CELLS)
+def test_a_stage_metric_resolves_for_the_two_chat_cells(  # noqa: F811
+        name, cell_name):
+    from benchmark import manifest
+
+    bench, stages = _ttft_stages.BENCH, _ttft_stages.STAGES
+    (entry,) = [m for m in bench["per_layer"] if m["name"] == name]
+    assert entry["moves"] == "ttft_mean_ms" and entry["layer"] == "scheduler"
+    assert entry["source"] == "program_counter" and entry["better"] == "lower"
+    cells = list(_ttft_stages.CELLS)
+    assert entry["workloads"][:len(cells)] == cells  # later cells appended
+    spec = manifest.Cell(bench, cell_name).layer_metric_specs()[name]
+    assert spec["from"] == "registry" and spec["reduce"] == "ratio"
+    if name == _ttft_stages.TURNS:
+        assert spec["num"] == {"counter": "serve_prefill_turns_waited_total"}
+        assert spec["den"] == {"counter": "serving_prefill_chunks_total"}
+    else:
+        assert spec["num"] == {"hist_sum": stages[name]}
+        assert spec["den"] == {"hist_count": stages[name]}
+        assert spec["scale"] == 1000.0
+    batch = manifest.Cell(bench, "olmoe-serve-batch").layer_metric_specs()
+    assert name not in batch
 
 
 def test_every_benchmark_test_file_is_collected_here():

@@ -290,3 +290,27 @@ def test_ssm_scan_compiles_at_the_ticks_shapes(one_chip, slots):
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert "ssm_scan" in text
     assert ssm._block_width(slots, n, d) == 1024
+
+
+# -- the mixed-window serving cell's kernel (command-a-plus-serve-mixed) -----
+@pytest.mark.parametrize("rows,window", [(35 * 128, 4096), (16384, None)],
+                         ids=["ring_of_35_pages", "whole_pages"])
+def test_chunk_attention_compiles_at_the_ticks_shapes(one_chip, rows, window):
+    """One layer of one tick at command-a-plus's widths: a 256-row chunk
+    of 128 query heads of 128 over one lane's k/v (8 heads), a ring of 35
+    pages under the 4,096 window or 16,384 whole rows, keys by position.
+    [256, block] float32 scores and the accumulators fit VMEM at key blocks
+    of 896 and 1,024 rows."""
+    i32 = jnp.int32
+
+    def run(q, k, v, qpos, kpos, live):
+        return rpa.chunk_attention(q, k, v, qpos, kpos, window, live)
+
+    text = _compile(
+        run, one_chip, ((256, 128, 128), BF16), ((rows, 8, 128), BF16),
+        ((rows, 8, 128), BF16), ((256,), i32), ((rows,), i32), ((), i32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "chunk_attention" in text
+    assert rpa._chunk_key_block(rows) == (896 if window else 1024)
+    assert rpa.chunk_attention_eligible(256, rows, 128)
+    assert not rpa.chunk_attention_eligible(256, rows, 64)
