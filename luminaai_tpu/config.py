@@ -68,15 +68,18 @@ class Config:
     # the slot-paged pool, and chunked prefill all dispatch through it
     # (ops/ragged_paged_attention.py):
     #   'dense'      legacy full-extent per-lane masking (parity oracle);
-    #   'ragged_xla' pure-XLA length-masked reference — the serving
-    #                default: bit-identical to 'dense' on resident rows,
-    #                and the decode step slices K/V to the resident page
-    #                extent so decode cost scales with tokens resident,
-    #                not pool capacity;
-    #   'ragged'     Pallas page-table-native decode kernel when eligible
-    #                (ragged_eligible), ragged_xla otherwise. Compiled on
-    #                TPU, interpret mode on CPU (slow — use for parity
-    #                tests, not CPU serving).
+    #   'ragged_xla' length-aware attention, the serving default. Off a
+    #                TPU the pure-XLA length-masked reference:
+    #                bit-identical to 'dense' on resident rows, K/V sliced
+    #                to the resident page extent so decode cost scales
+    #                with tokens resident, not pool capacity;
+    #   'ragged'     the same; off a TPU a decode batch's attention runs
+    #                the Pallas kernel (lane_attention) INTERPRETED (slow:
+    #                parity tests, not CPU serving).
+    # On a TPU the two strings run one program: a decode batch takes
+    # lane_attention where the static shapes are eligible
+    # (lane_attention_eligible: grouped query heads, 128-wide heads) and
+    # the XLA reference otherwise; nothing else chooses.
     # The single-stream engine's rolling cache (one uniform
     # attention_window, below) always takes the dense path: its slot
     # arithmetic is mod-C, which LaneMeta does not describe. The slot-paged

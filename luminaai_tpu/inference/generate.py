@@ -1106,9 +1106,16 @@ class StepwiseDecoder:
         ]
         self._n_window_layers = sum(w is not None for w in windows)
         self._n_global_layers = len(windows) - self._n_window_layers
+        self._windows = collections.Counter(windows)
         self.kv_window_rows = 0
         self.kv_global_rows = 0
         self.ring_wraps = 0
+        # Grid steps (lanes x key blocks) of the lanes' decode kernel
+        # (ops/ragged_paged_attention.py lane_attention), summed over
+        # attention layers, and those that fetched and computed: both 0
+        # where the shapes leave the lanes' attention to XLA.
+        self.lane_attention_blocks = 0
+        self.lane_attention_blocks_live = 0
         self.moe_routed_pairs = 0
         self.moe_held_pairs = 0
         self.moe_held_pairs_dropped = 0
@@ -1119,6 +1126,15 @@ class StepwiseDecoder:
         # through the decode step so attention reads O(tokens resident).
         self.backend = getattr(
             engine.config, "attention_backend", "dense"
+        )
+        from luminaai_tpu.ops.ragged_paged_attention import (
+            lane_attention_engaged,
+        )
+
+        self._lane_kernel = lane_attention_engaged(
+            self.backend, 1, engine.config.num_heads,
+            engine.config.num_kv_heads, engine.config.head_dim(),
+            self.pool.page_size,
         )
         # Device copy of the pool's page table, refreshed at admission
         # (identity today; a prefix cache would retarget entries there).
@@ -2354,11 +2370,16 @@ class StepwiseDecoder:
 
     def _kv_rows_of(self, extent, pos, live, chunk) -> None:
         """Book what the tick about to be dispatched reads and wraps,
-        from lengths the host has: every lane's rows up to the tick's
-        extent in a full layer and its whole ring in a layer with a
-        window of its own (the program reads a lane whether or not it is
-        stepped), plus the chunk's lane up to the chunk's end; a wrap
-        each time a row is written onto the ring's first row again."""
+        from lengths the host has. The chunk's lane: its rows up to the
+        chunk's end (a ring: at most the ring). The lanes, where XLA
+        attends them: every lane's rows up to the tick's extent in a full
+        layer and its whole ring in a layer with a window of its own (the
+        program reads a lane whether or not it is stepped). Where the
+        decode kernel does (`_lane_kernel`): for a stepped lane the rows
+        of the key blocks in which its query sees a key, nothing for a
+        lane not stepped (_lane_blocks_read: the kernel's own plan, on the
+        host), and the kernel's grid steps, all and live. A wrap each time
+        a row is written onto the ring's first row again."""
         ring = self.pool.ring_pages * self.pool.page_size
         lanes_full = extent or self.slot_tokens
         lanes_window = ring or lanes_full
@@ -2369,15 +2390,56 @@ class StepwiseDecoder:
             c_full, c_window = end, min(end, lanes_window)
             if ring:
                 self.ring_wraps += (end - 1) // ring - max(start - 1, 0) // ring
-        self.kv_global_rows += self._n_global_layers * (
-            self.num_slots * lanes_full + c_full
-        )
-        self.kv_window_rows += self._n_window_layers * (
-            self.num_slots * lanes_window + c_window
-        )
+        rows_full = self._n_global_layers * self.num_slots * lanes_full
+        rows_window = self._n_window_layers * self.num_slots * lanes_window
+        if self._lane_kernel:
+            rows_full = rows_window = 0
+            held = pos[live] + 1
+            for window, layers in self._windows.items():
+                steps, fetched, rows = self._lane_blocks_read(
+                    held, np.flatnonzero(live), window,
+                    lanes_window if window is not None else lanes_full,
+                    bool(ring) and window is not None,
+                )
+                self.lane_attention_blocks += layers * steps
+                self.lane_attention_blocks_live += layers * fetched
+                if window is None:
+                    rows_full += layers * fetched * rows
+                else:
+                    rows_window += layers * fetched * rows
+        self.kv_global_rows += rows_full + self._n_global_layers * c_full
+        self.kv_window_rows += rows_window + self._n_window_layers * c_window
         if ring:
             at = pos[live]
             self.ring_wraps += int(((at > 0) & (at % ring == 0)).sum())
+
+    def _lane_blocks_read(self, held, slots, window, rows, ring):
+        """(grid steps, steps that fetch and compute, rows of k/v such a
+        step fetches) of one layer's lane_attention call in a tick that
+        steps the lanes `slots`, holding `held` rows each, over `rows`
+        rows a lane (the tick's extent, or the ring): lane_pages_held and
+        lane_blocks, the kernel's own plan, over the host's lengths."""
+        from luminaai_tpu.ops.ragged_paged_attention import (
+            lane_blocks,
+            lane_pages_held,
+        )
+
+        cfg, ps = self.engine.config, self.pool.page_size
+        pages = rows // ps
+        per_block, _ = lane_blocks(
+            pages, ps, cfg.num_kv_heads, cfg.head_dim(),
+            jnp.dtype(self.model.dtype).itemsize,
+            chased=self.prefix_cache is not None,
+        )
+        seen = lane_pages_held(
+            held, ps, pages, window,
+            self.pool.ring_tables[slots] if ring else None, xp=np,
+        ) >= 0
+        fetched = int(
+            seen.reshape(len(slots), pages // per_block, per_block)
+            .any(axis=2).sum()
+        )
+        return self.num_slots * (pages // per_block), fetched, per_block * ps
 
     def step_fn_and_args(
         self, sample_key: Optional[Tuple] = None

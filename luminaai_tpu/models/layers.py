@@ -712,7 +712,7 @@ class GQAttention(nn.Module):
                         backend, ring,
                     )
             elif kv_cache is not None and ring:
-                out = self._ring_lanes(q, k, v, lane_meta)
+                out = self._ring_lanes(q, k, v, lane_meta, backend)
             elif decoding_att and backend != "dense" and not rolling:
                 # Length-aware (LaneMeta) dispatch: scalar-offset decode,
                 # batched per-lane decode, and (chunked) prefill all
@@ -735,17 +735,28 @@ class GQAttention(nn.Module):
     def _window(self) -> Optional[int]:
         return self.config.window_of(self.layer_idx)
 
-    def _ring_lanes(self, q, k, v, meta):
+    def _ring_lanes(self, q, k, v, meta, backend=None):
         """The lanes' rows over their rings of pages, read in place: a
         physical page's rows are masked by the positions of the logical
         page the lane's ring table keeps there now, O(ring) rows a lane
-        whatever its context."""
+        whatever its context. Where the shapes allow it on a TPU
+        (lane_attention_engaged) one kernel reads only the blocks of the
+        ring in which a stepped lane's query sees a key."""
         from luminaai_tpu.ops.ragged_paged_attention import (
             banded_attention_xla,
+            lane_attention,
+            lane_attention_engaged,
             ring_key_positions,
         )
 
         n_d = q.shape[0]
+        if lane_attention_engaged(
+            backend, q.shape[1], q.shape[2], k.shape[2], q.shape[3],
+            meta.page_size,
+        ):
+            return lane_attention(
+                q, k, v, meta.replace(window=self._window()), ring=True
+            )
         lengths = meta.lengths[:n_d]
         kpos = ring_key_positions(
             meta.ring_table[:n_d], lengths, meta.page_size, k.shape[1]
@@ -799,7 +810,7 @@ class GQAttention(nn.Module):
             lanes = meta.replace(chunk_rows=0, chunk_slot=None,
                                  chunk_start=None)
             if ring:
-                out_d = self._ring_lanes(q[:n_d], k, v, lanes)
+                out_d = self._ring_lanes(q[:n_d], k, v, lanes, backend)
             else:
                 out_d = self._ragged_attention(
                     q[:n_d], k, v, lanes, cache_index[:n_d], None, backend
@@ -888,20 +899,6 @@ class GQAttention(nn.Module):
         if meta.window != self._window():
             # The caller's one window for the model; this layer has its own.
             meta = meta.replace(window=self._window())
-        if getattr(meta, "global_pages", False):
-            # Prefix-cache aliasing: physical pages may live in ANY slot
-            # (including the cache arena), so the k/v rows cannot be
-            # pre-sliced — the op slices the page TABLE to the extent
-            # instead, and its gather output is still O(extent) rows.
-            pass
-        elif meta.extent is not None and meta.extent < k.shape[1]:
-            # Post-write resident-extent slice: decode reads O(tokens
-            # resident), not O(pool capacity). XLA prices a slice at its
-            # output bytes, so the compiled decode step's bytes-accessed
-            # drop with residency (bench extras.ragged_attention pins
-            # this against the dense baseline).
-            k = jax.lax.slice_in_dim(k, 0, meta.extent, axis=1)
-            v = jax.lax.slice_in_dim(v, 0, meta.extent, axis=1)
         return paged_attention(
             q, k, v, meta,
             backend=backend,

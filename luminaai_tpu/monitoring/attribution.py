@@ -320,7 +320,7 @@ def kernel_census(hlo_text: str) -> Dict[str, int]:
     """Count the Mosaic kernels (`tpu_custom_call`) in a compiled
     program's text by kernel name — the innermost scope in front of
     `/pallas_call` in the op's metadata (`flash_fwd`, `flash_bwd_dq`,
-    `flash_bwd_dkv`, megablox `gmm` / `tgmm`, `ragged_paged_decode`).
+    `flash_bwd_dkv`, megablox `gmm` / `tgmm`, `lane_attention`).
     {} means the program holds no Pallas TPU kernel: a branch that took
     the interpreter or an XLA stand-in shows up here, whatever it
     claimed."""

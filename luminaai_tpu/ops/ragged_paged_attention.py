@@ -12,20 +12,25 @@ one dispatcher:
 - `ragged_paged_attention_xla`: pure-XLA reference. Gathers the lane's
   pages through the page table (skippable when the table is the pool's
   identity layout — the gather would only copy bytes) and masks by
-  per-lane length. It is the parity oracle for the kernel AND the
-  fallback whenever the kernel is ineligible (odd head_dim/page_size,
-  multi-row q). Callers bound its cost by slicing the page axis to the
-  resident extent before calling (StepwiseDecoder does), so even the
-  fallback reads O(tokens resident), not O(pool capacity).
+  per-lane length. It is the parity oracle for the kernel AND what runs
+  wherever the kernel does not (multi-row q; on a TPU, shapes outside
+  lane_attention_eligible; off it, every backend but 'ragged'). The
+  dispatcher bounds its cost by slicing the page axis to the resident
+  extent (StepwiseDecoder picks it), so it reads every lane's rows up
+  to the extent: O(lanes x extent), not O(pool capacity).
 
-- `ragged_paged_attention` (Pallas): grid over (lane, head, kv-page)
-  with the page table and lengths as SCALAR-PREFETCH operands — the
-  K/V BlockSpec index maps chase the table directly, pages past a
-  lane's length are clamped to the last live page (a re-fetch Pallas
+- `lane_attention` (Pallas): grid over (lane, key block) with the
+  lengths and a small plan as SCALAR-PREFETCH operands. The pool is read
+  in place, a block of whole pages with every k/v head at a time; a
+  lane that is not stepped and a block in which the lane's query sees no
+  key (past the length, before the window, a ring page that holds
+  nothing) are pinned to the block fetched last (a re-fetch Pallas
   elides) and compute-skipped via `pl.when`, and the running
   (max, denominator, accumulator) online softmax means no [B, S_cache]
-  score row ever exists. Interpret mode on CPU, compiled on TPU — the
-  same pattern ops/flash_attention.py established.
+  score row ever exists. Whole pages (identity, a real page table,
+  global ids) and rings of pages go through the one kernel, masked by
+  position. Interpret mode on CPU, compiled on TPU — the same pattern
+  ops/flash_attention.py established.
 
 `LaneMeta` is the lane-metadata struct (lengths, page table, window,
 kind) that ROADMAP item 5 collapses the per-variant attention masking
@@ -38,10 +43,11 @@ a drop-in backend (`config.attention_backend`).
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from flax import struct
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -131,13 +137,6 @@ class LaneMeta:
     # Positions stay absolute; rows are written and read through this
     # table (ring_key_positions). None: no layer of the pool keeps a ring.
     ring_table: Optional[jax.Array] = None
-
-
-def ragged_eligible(page_size: int, head_dim: int, s_q: int) -> bool:
-    """When the Pallas decode kernel applies: one q row per lane,
-    sublane-aligned pages, lane-friendly head_dim (Mosaic pads 64→128).
-    Everything else takes the XLA reference path."""
-    return s_q == 1 and page_size % 8 == 0 and head_dim % 64 == 0
 
 
 def implied_page_size(cache_rows: int) -> int:
@@ -243,34 +242,48 @@ def ragged_paged_attention_xla(
 # ---------------------------------------------------------------------------
 # Keys by position: a ring of pages read in place, and the tick's chunk
 # ---------------------------------------------------------------------------
+def _ring_resident(
+    ring_table, lengths, page_size: int, n_ring: int, xp=jnp
+):
+    """[B, n_ring] int32: the logical page each physical page of a
+    lane's ring holds once `lengths` rows of the lane are written, -1
+    where it holds nothing of this lane. The logical pages still resident
+    are the last n_ring up to the page of row lengths - 1, and each sits
+    where the ring table (`ring_table[b, j]`: logical page j's physical
+    page) put it. `xp`: numpy for the host's count of what a tick reads
+    (StepwiseDecoder._kv_rows_of), jax.numpy in a program."""
+    P = ring_table.shape[1]
+    lengths = lengths.astype(xp.int32)
+    last = (lengths - 1) // page_size  # -1: empty lane
+    logical = last[:, None] - xp.arange(n_ring, dtype=xp.int32)[None, :]
+    held = xp.logical_and(logical >= 0, lengths[:, None] > 0)
+    physical = xp.take_along_axis(
+        ring_table.astype(xp.int32), xp.clip(logical, 0, P - 1), axis=1
+    )
+    here = xp.logical_and(
+        held[:, :, None],
+        physical[:, :, None]
+        == xp.arange(n_ring, dtype=xp.int32)[None, None, :],
+    )
+    return xp.max(xp.where(here, logical[:, :, None], -1), axis=1)
+
+
 def ring_key_positions(
     ring_table: jax.Array, lengths: jax.Array, page_size: int, rows: int
 ) -> jax.Array:
     """[B, rows] int32: the absolute position each physical row of a
     lane's ring holds once `lengths` rows of the lane are written, -1
-    where it holds nothing of this lane. The ring has rows // page_size
-    pages; the logical pages still resident are the last that many up to
-    the page of row lengths - 1, and each sits where the ring table
-    (`ring_table[b, j]`: logical page j's physical page) put it. Rows of
-    the last page past lengths - 1 read as positions not yet written: a
-    causal mask drops them as it drops any future key."""
-    B, P = ring_table.shape
+    where it holds nothing of this lane (_ring_resident, a row at a
+    time). Rows of the last page past lengths - 1 read as positions not
+    yet written: a causal mask drops them as it drops any future key."""
     n_ring = rows // page_size
-    last = (lengths.astype(jnp.int32) - 1) // page_size  # -1: empty lane
-    logical = last[:, None] - jnp.arange(n_ring, dtype=jnp.int32)[None, :]
-    held = jnp.logical_and(logical >= 0, lengths[:, None] > 0)
-    physical = jnp.take_along_axis(
-        ring_table.astype(jnp.int32), jnp.clip(logical, 0, P - 1), axis=1
-    )
-    resident = jnp.full((B, n_ring), -1, jnp.int32).at[
-        jnp.arange(B)[:, None], jnp.where(held, physical, n_ring)
-    ].set(logical, mode="drop")
+    resident = _ring_resident(ring_table, lengths, page_size, n_ring)
     within = jnp.arange(page_size, dtype=jnp.int32)[None, None, :]
     kpos = jnp.where(
         resident[:, :, None] >= 0,
         resident[:, :, None] * page_size + within, -1,
     )
-    return kpos.reshape(B, n_ring * page_size)
+    return kpos.reshape(ring_table.shape[0], n_ring * page_size)
 
 
 def banded_attention_xla(
@@ -442,26 +455,173 @@ def chunk_attention(
 
 
 # ---------------------------------------------------------------------------
-# Pallas decode kernel: grid (lane, q head, kv page), page-table-native
+# Pallas decode kernel: grid (lane, key block), the pool read in place
 # ---------------------------------------------------------------------------
-def _decode_kernel(
-    lengths_ref,  # scalar prefetch [B]
-    table_ref,  # scalar prefetch [B, P]
-    q_ref,
-    k_ref,
-    v_ref,
-    o_ref,
-    m_scr,
-    l_scr,
-    acc_scr,
-    *,
-    scale,
-    page_size,
-    window,
+# Of k (and as much of v) a grid step fetches, at most: a step that is
+# skipped costs ~0.35 us whatever it would have read, so a block is several
+# pages (8 of 128 rows at 8 k/v heads of 128; a ring of 35 pages is 5 x 7).
+_LANE_BLOCK_BYTES = 2 << 20
+# Score columns (keys x k/v heads) of one online-softmax update inside a
+# block: [query heads, 1,024] float32 is 128 vregs at 128 query heads.
+_LANE_TILE_COLS = 1024
+_LANE_VMEM_LIMIT = 64 << 20
+
+
+def lane_attention_eligible(
+    n_q: int, n_kv: int, head_dim: int, page_size: int
+) -> bool:
+    """Where `lane_attention` is the lanes' decode attention on a TPU: a
+    pure function of the shapes the dispatcher sees. A k/v head's query
+    heads are one MXU operand, so there must be enough of them (at one
+    query head a k/v head, MHA, the operand is one row and XLA's fusion
+    is the faster program); a pool row [kv_heads, head_dim] is whole
+    (8, 128) tiles or one head, so the pool flattens to [rows x kv_heads,
+    head_dim] for free; a page's score columns fill whole lanes."""
+    return (
+        n_q // n_kv >= 8
+        and head_dim % 128 == 0
+        and page_size % 8 == 0
+        and (n_kv == 1 or n_kv % 8 == 0)
+        and (page_size * n_kv) % 128 == 0
+    )
+
+
+def lane_attention_engaged(
+    backend: Optional[str], s_q: int, n_q: int, n_kv: int, head_dim: int,
+    page_size: int,
+) -> bool:
+    """Whether a decode batch's attention runs `lane_attention`: on a TPU
+    under either ragged backend when the shapes are eligible; off it only
+    under 'ragged' (interpreted, at any shape: tests), so a CPU program
+    under 'ragged_xla' is the XLA reference's."""
+    if s_q != 1 or backend not in ("ragged", "ragged_xla"):
+        return False
+    if _interpret():
+        return backend == "ragged"
+    return lane_attention_eligible(n_q, n_kv, head_dim, page_size)
+
+
+def _largest_divisor(n: int, cap: int) -> int:
+    return max(d for d in range(1, max(1, min(n, cap)) + 1) if n % d == 0)
+
+
+def lane_blocks(pages: int, page_size: int, n_kv: int, head_dim: int,
+                itemsize: int, chased: bool = False,
+                block_bytes: Optional[int] = None) -> Tuple[int, int]:
+    """(pages a key block, pages an inner tile) of `lane_attention` over
+    `pages` pages a lane. A block is what one grid step fetches: whole
+    pages that lie together in the pool, as many as _LANE_BLOCK_BYTES
+    hold; one page where a page table is chased (`chased`), since the
+    next logical page may live anywhere. A tile is what one online-softmax
+    update covers."""
+    if chased:
+        return 1, 1
+    page_bytes = page_size * n_kv * head_dim * itemsize
+    per_block = _largest_divisor(
+        pages, (block_bytes or _LANE_BLOCK_BYTES) // page_bytes
+    )
+    per_tile = _largest_divisor(
+        per_block, min(8, _LANE_TILE_COLS // (page_size * n_kv))
+    )
+    return per_block, per_tile
+
+
+def lane_pages_held(
+    lengths, page_size: int, pages: int, window: Optional[int],
+    ring_table=None, xp=jnp,
 ):
+    """[B, pages] int32: the logical page whose keys the lane's query (at
+    position lengths - 1) sees in each page the grid visits, -1 where it
+    sees none (a lane not stepped, a page past the length or wholly before
+    the window). Whole pages are visited in logical order; a ring's
+    (`ring_table`) in physical order, each holding what the table put
+    there. `xp` as in _ring_resident: one rule for the kernel's plan and
+    for the host's count of it."""
+    L = lengths.astype(xp.int32)[:, None]
+    if ring_table is None:
+        logical = xp.broadcast_to(
+            xp.arange(pages, dtype=xp.int32)[None], (L.shape[0], pages)
+        )
+    else:
+        logical = _ring_resident(ring_table, lengths, page_size, pages, xp)
+    seen = xp.logical_and(logical >= 0, logical * page_size < L)
+    if window is not None:
+        seen = xp.logical_and(
+            seen, (logical + 1) * page_size - 1 >= L - window
+        )
+    return xp.where(seen, logical, -1)
+
+
+def lane_plan(
+    meta: LaneMeta, lanes: int, pool_rows: int, n_kv: int, head_dim: int,
+    itemsize: int, ring: bool = False, block_bytes: Optional[int] = None,
+) -> Tuple[jax.Array, jax.Array, jax.Array, int, int]:
+    """What lane_attention's grid does, from the lanes' metadata alone:
+    (held [lanes, pages]: lane_pages_held over the pages the grid visits;
+    slot, block [lanes x blocks]: where each grid step's block of k/v lies
+    in the pool; pages a block; pages a tile). `meta.extent` bounds the
+    pages of a lane's whole pages, the ring is visited whole. A step that
+    reads nothing keeps the block of the live step before it (the first
+    live step's, ahead of that): same index, no DMA."""
+    ps = meta.page_size
+    pool_pages = pool_rows // ps
+    chased = not ring and (
+        meta.global_pages
+        or (meta.page_table is not None and not meta.identity_pages)
+    )
+    pages = pool_pages
+    if not ring and meta.extent is not None and meta.extent < pool_rows:
+        pages = meta.extent // ps
+    if chased:
+        pages = min(pages, meta.page_table.shape[1])
+    per_block, per_tile = lane_blocks(
+        pages, ps, n_kv, head_dim, itemsize, chased, block_bytes
+    )
+    nb = pages // per_block
+    held = lane_pages_held(
+        meta.lengths[:lanes], ps, pages, meta.window,
+        meta.ring_table[:lanes] if ring else None,
+    )
+    slot = jnp.asarray(np.repeat(np.arange(lanes, dtype=np.int32), nb))
+    blk = jnp.asarray(np.tile(np.arange(nb, dtype=np.int32), lanes))
+    if chased:
+        # A logical page lies where the table says: a page of the lane's
+        # own slot, or (global ids) of any slot.
+        blk = meta.page_table.astype(jnp.int32)[:lanes, :pages].reshape(-1)
+        if meta.global_pages:
+            slot, blk = blk // pool_pages, blk % pool_pages
+    live = jnp.any(
+        held.reshape(lanes, nb, per_block) >= 0, axis=2
+    ).reshape(-1)
+    step = jnp.arange(lanes * nb, dtype=jnp.int32)
+    last = jax.lax.cummax(jnp.where(live, step, -1), axis=0)
+    src = jnp.where(last >= 0, last, jnp.argmax(live).astype(jnp.int32))
+    return held, slot[src], blk[src], per_block, per_tile
+
+
+def _lane_kernel(
+    len_ref,  # scalar prefetch [B]
+    held_ref,  # scalar prefetch [B * pages]: lane_pages_held, flat
+    slot_ref,  # scalar prefetch [B * blocks]: the block's slot in the pool
+    blk_ref,  # scalar prefetch [B * blocks]: and its block of that slot
+    rowh_ref, colh_ref, colk_ref, q_ref, k_ref, v_ref, o_ref,
+    m_scr, l_scr, acc_scr, bias_scr,
+    *, scale, window, page_size, per_block, per_tile, cols,
+):
+    del slot_ref, blk_ref  # the index maps read them
     b = pl.program_id(0)
-    j = pl.program_id(2)
-    nj = pl.num_programs(2)
+    j = pl.program_id(1)
+    nj = pl.num_programs(1)
+
+    @pl.when(jnp.logical_and(b == 0, j == 0))
+    def _heads():
+        # Column c of a tile is key c // kv_heads under k/v head
+        # c % kv_heads; query row r belongs to k/v head r // group. One
+        # matmul scores every query head against every k/v head's keys;
+        # this keeps a head's own.
+        bias_scr[:] = jnp.where(
+            rowh_ref[:, :1] == colh_ref[:1, :], 0.0, NEG_INF
+        )
 
     @pl.when(j == 0)
     def _init():
@@ -469,163 +629,175 @@ def _decode_kernel(
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    length = lengths_ref[b]
-    # One q row per lane at position length-1. Pages wholly past the
-    # length (and, under a window, wholly before the band) cost neither
-    # compute nor a fresh DMA — the index map below pins skipped steps
-    # to an already-fetched page.
-    page_start = j * page_size
-    needed = page_start < length
-    if window:
-        needed = jnp.logical_and(
-            needed, page_start + page_size - 1 >= length - window
-        )
+    qpos = len_ref[b] - 1
+    first = (b * nj + j) * per_block  # this block's first page in held_ref
 
-    @pl.when(needed)
-    def _compute():
-        q = q_ref[0, 0, :, :]  # [1, D]
-        k = k_ref[0, 0, 0, :, :]  # [page_size, D]
-        v = v_ref[0, 0, 0, :, :]
-        s = (
-            jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
+    def tile(t):
+        page0 = first + t * per_tile
+        held = [held_ref[page0 + g] for g in range(per_tile)]
+        live = held[0] >= 0
+        for h in held[1:]:
+            live = jnp.logical_or(live, h >= 0)
+
+        @pl.when(live)
+        def _compute():
+            at = pl.ds(pl.multiple_of(t * cols, cols), cols)
+            k = k_ref[0, at, :]  # [cols, D]: keys x k/v heads
+            v = v_ref[0, at, :]
+            s = jax.lax.dot_general(
+                q_ref[0], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale + bias_scr[:]  # [Hq, cols]
+            colk = colk_ref[:1, :]  # the column's key, within the tile
+            kpos = jnp.where(held[0] >= 0, held[0] * page_size + colk, -1)
+            for g in range(1, per_tile):
+                kpos = jnp.where(
+                    colk >= g * page_size,
+                    jnp.where(
+                        held[g] >= 0, (held[g] - g) * page_size + colk, -1
+                    ),
+                    kpos,
+                )
+            keep = jnp.logical_and(kpos >= 0, kpos <= qpos)
+            if window:
+                keep = jnp.logical_and(keep, qpos - kpos < window)
+            s = jnp.where(keep, s, NEG_INF)
+            m_prev = m_scr[:, :]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1)[:, None])
+            alpha = jnp.exp(m_prev - m_new)
+            # A live tile holds a key the query sees, so every query row
+            # has a real maximum and a masked column's exp is 0.
+            p = jnp.exp(s - m_new[:, :1])
+            l_scr[:, :] = l_scr[:, :] * alpha + jnp.sum(p, axis=-1)[:, None]
+            acc_scr[:] = acc_scr[:] * alpha[:, :1] + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
-            * scale
-        )  # [1, page_size] fp32
-        kp = page_start + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1
-        )
-        keep = kp < length
-        if window:
-            keep = jnp.logical_and(keep, (length - 1) - kp < window)
-        s = jnp.where(keep, s, NEG_INF)
-        m_prev = m_scr[:, :]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1)[:, None])
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, :1])
-        l_scr[:, :] = l_scr[:, :] * alpha + jnp.sum(p, axis=-1)[:, None]
-        acc_scr[:] = acc_scr[:] * alpha[:, :1] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_scr[:, :] = m_new
+            m_scr[:, :] = m_new
+
+    n_tiles = per_block // per_tile
+    if n_tiles == 1:
+        tile(0)
+    else:
+        jax.lax.fori_loop(0, n_tiles, lambda t, c: (tile(t), c)[1], 0)
 
     @pl.when(j == nj - 1)
     def _finalize():
         l = l_scr[:, :]
         safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0, :, :] = (acc_scr[:] / safe_l[:, :1]).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[:] / safe_l[:, :1]).astype(o_ref.dtype)
 
 
-def _page_index_map(group, page_size, n_pages, window, pool_pages=None):
-    """K/V BlockSpec index map: chase the page table for live pages,
-    clamp skipped grid steps onto the lane's last live page (same block
-    index as a neighbouring step ⇒ Pallas skips the DMA entirely).
-
-    pool_pages: pages-per-slot of the pool when table entries are GLOBAL
-    (slot, page) ids (prefix-cache aliasing) — the map then decomposes
-    the id back into (slot, page) block coordinates, so a lane's logical
-    page can be fetched from another slot's storage."""
-
-    def index(b, h, j, lengths, table):
-        length = lengths[b]
-        last = jnp.maximum(length - 1, 0) // page_size
-        first = 0
-        if window:
-            first = jnp.maximum(length - window, 0) // page_size
-        jv = jnp.clip(j, first, last)
-        phys = table[b, jnp.minimum(jv, n_pages - 1)]
-        if pool_pages is not None:
-            return (phys // pool_pages, h // group, phys % pool_pages, 0, 0)
-        return (b, h // group, phys, 0, 0)
-
-    return index
-
-
-def ragged_paged_attention(
-    q: jax.Array,
-    k: jax.Array,
-    v: jax.Array,
-    meta: LaneMeta,
+def lane_attention(
+    q: jax.Array, k: jax.Array, v: jax.Array, meta: LaneMeta,
+    ring: bool = False,
 ) -> jax.Array:
-    """Pallas page-table-native decode attention.
+    """A decode batch's attention, one query row a lane, over the pool as
+    it lies: q [B, 1, Hq, D]; k / v [T, C, Hkv, D] with lane b in slot b
+    (T > B: a prefix-cache arena behind the lanes), whole pages or, with
+    `ring`, the lanes' rings of pages (LaneMeta.ring_table). Returns
+    [B, 1, Hq, D].
 
-    q: [B, 1, Hq, D]; k/v: [B, C, Hkv, D] flat, C == P * meta.page_size.
-    Returns [B, 1, Hq, D]. Gate with ragged_eligible(); interpret mode
-    off-TPU (CPU tests), compiled on TPU.
-    """
-    B, Sq, Hq, D = q.shape
-    C, Hkv = k.shape[1], k.shape[2]
+    Grid (lane, key block); a block is whole pages with every k/v head,
+    so a row of k/v is fetched once. `meta.lengths` say what a lane
+    holds; a block in which the lane's query (position lengths - 1) sees
+    no key (a lane not stepped, pages past the length, pages wholly
+    before the window, ring pages that hold nothing) is pinned by the
+    index map to the block fetched last, so it costs neither a DMA nor
+    arithmetic. Key j is seen iff 0 <= i - j (< window) and the row holds
+    it: whole pages hold their row numbers through the page table
+    (identity, a lane's own permutation, or `global_pages` ids into any
+    slot), a ring what its table put there (ring_key_positions' rule, a
+    page at a time). Online softmax in float32, probabilities cast to
+    v's dtype: banded_attention_xla's rounding. `meta.extent` bounds the
+    grid, not the operand: no slice of the pool is made.
+
+    Inside a block, all k/v heads go through the MXU together: k flat as
+    [keys x kv_heads, D] against every query head, and a constant mask
+    keeps each query head's own k/v head. Wasted MXU work (kv_heads
+    times) for no transpose of the pool's row."""
+    assert q.shape[1] == 1, "one query row a lane"
+    assert k.shape[1] % meta.page_size == 0, (k.shape, meta.page_size)
+    if ring:
+        # A ring is visited whole: without the tick's extent in it, the
+        # ring layers of every tick program share one trace.
+        meta = meta.replace(extent=None)
+    return _lane_attention(
+        q, k, v, meta, ring, _interpret(), _LANE_BLOCK_BYTES
+    )
+
+
+# Jitted with everything that shapes the kernel static, so that the layers
+# of one tick that share shapes (three rings) are traced and lowered once.
+@functools.partial(jax.jit, static_argnums=(4, 5, 6))
+def _lane_attention(q, k, v, meta, ring, interpret, block_bytes):
+    B, _, Hq, D = q.shape
+    T, C, Hkv = k.shape[0], k.shape[1], k.shape[2]
     ps = meta.page_size
-    assert Sq == 1, "the Pallas kernel is decode-shaped (one q row/lane)"
-    assert C % ps == 0, (C, ps)
-    P = C // ps
     group = Hq // Hkv
+    lengths = meta.lengths.astype(jnp.int32)[:B]
+    held, slot, blk, per_block, per_tile = lane_plan(
+        meta, B, C, Hkv, D, k.dtype.itemsize, ring, block_bytes
+    )
+    nb = held.shape[1] // per_block
 
-    lengths = meta.lengths.astype(jnp.int32)
-    pool_pages = None
-    if meta.global_pages:
-        # Global (slot, page) addressing: k/v are the full pool
-        # [T, C, ...]; the grid's page axis runs over each lane's
-        # LOGICAL pages (extent-sliced), and the index map decomposes
-        # global table ids into pool block coordinates.
-        pool_pages = P
-        table = meta.page_table.astype(jnp.int32)
-        if meta.extent is not None and meta.extent < C:
-            table = table[:, : meta.extent // ps]
-        P_grid = table.shape[1]
-    elif meta.page_table is not None:
-        table = meta.page_table.astype(jnp.int32)[:, :P]
-        P_grid = P
-    else:
-        table = jnp.tile(jnp.arange(P, dtype=jnp.int32)[None], (B, 1))
-        P_grid = P
+    Hp = -(-Hq // 16) * 16
+    cols = per_tile * ps * Hkv
+    rows = per_block * ps * Hkv
+    qf = jnp.pad(q[:, 0], ((0, 0), (0, Hp - Hq), (0, 0)))
+    # (Constants of the shapes: numpy, so nothing of them is lowered.) A
+    # padded query row belongs to no k/v head.
+    row_head = np.where(np.arange(Hp) < Hq, np.arange(Hp) // group, Hkv)
+    col = np.arange(cols, dtype=np.int32)
 
-    qt = q.transpose(0, 2, 1, 3)  # [B, Hq, 1, D]
-    T = k.shape[0]
-    kt = k.reshape(T, P, ps, Hkv, D).transpose(0, 3, 1, 2, 4)
-    vt = v.reshape(T, P, ps, Hkv, D).transpose(0, 3, 1, 2, 4)
+    def kv_map(b, j, lengths, held, slot, blk):
+        return (slot[b * nb + j], blk[b * nb + j], 0)
 
-    window = int(meta.window or 0)
+    const = lambda b, j, *_: (0, 0)  # noqa: E731
+    own = lambda b, j, *_: (b, 0, 0)  # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, Hq, P_grid),
+        num_scalar_prefetch=4,
+        grid=(B, nb),
         in_specs=[
-            pl.BlockSpec(
-                (1, 1, 1, D), lambda b, h, j, lengths, table: (b, h, 0, 0)
-            ),
-            pl.BlockSpec(
-                (1, 1, 1, ps, D),
-                _page_index_map(group, ps, P_grid, window, pool_pages),
-            ),
-            pl.BlockSpec(
-                (1, 1, 1, ps, D),
-                _page_index_map(group, ps, P_grid, window, pool_pages),
-            ),
+            pl.BlockSpec((Hp, LANES), const),
+            pl.BlockSpec((8, cols), const),
+            pl.BlockSpec((8, cols), const),
+            pl.BlockSpec((1, Hp, D), own),
+            pl.BlockSpec((1, rows, D), kv_map),
+            pl.BlockSpec((1, rows, D), kv_map),
         ],
-        out_specs=pl.BlockSpec(
-            (1, 1, 1, D), lambda b, h, j, lengths, table: (b, h, 0, 0)
-        ),
+        out_specs=pl.BlockSpec((1, Hp, D), own),
         scratch_shapes=[
-            pltpu.VMEM((1, LANES), jnp.float32),
-            pltpu.VMEM((1, LANES), jnp.float32),
-            pltpu.VMEM((1, D), jnp.float32),
+            pltpu.VMEM((Hp, LANES), jnp.float32),
+            pltpu.VMEM((Hp, LANES), jnp.float32),
+            pltpu.VMEM((Hp, D), jnp.float32),
+            pltpu.VMEM((Hp, cols), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(
-            _decode_kernel,
-            scale=1.0 / (D**0.5),
-            page_size=ps,
-            window=window,
+            _lane_kernel, scale=1.0 / (D**0.5), window=int(meta.window or 0),
+            page_size=ps, per_block=per_block, per_tile=per_tile, cols=cols,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hq, 1, D), q.dtype),
-        interpret=_interpret(),
-        name="ragged_paged_decode",
-    )(lengths, table, qt, kt, vt)
-    return out.transpose(0, 2, 1, 3)
+        out_shape=jax.ShapeDtypeStruct((B, Hp, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_LANE_VMEM_LIMIT,
+        ),
+        interpret=interpret,
+        name="lane_attention",
+    )(
+        lengths, held.reshape(-1), slot, blk,
+        np.broadcast_to(row_head.astype(np.int32)[:, None], (Hp, LANES)),
+        np.broadcast_to((col % Hkv)[None], (8, cols)),
+        np.broadcast_to((col // Hkv)[None], (8, cols)),
+        qf,
+        # The pool's row [Hkv, D] is whole tiles: flat for free.
+        k.reshape(T, C * Hkv, D),
+        v.reshape(T, C * Hkv, D),
+    )
+    return out[:, None, :Hq]
 
 
 def paged_attention(
@@ -637,16 +809,23 @@ def paged_attention(
     backend: str = "ragged",
     positions: Optional[jax.Array] = None,
 ) -> jax.Array:
-    """Backend dispatcher (config.attention_backend):
-
-    'ragged'      Pallas kernel when eligible, XLA reference otherwise
-    'ragged_xla'  always the XLA reference (the CPU-serving default —
-                  interpret-mode kernels cost interpreter time)
-
-    Prefill chunks (Sq > 1) always take the reference path; the kernel
-    is decode-specialized.
-    """
-    Sq, D = q.shape[1], q.shape[3]
-    if backend == "ragged" and ragged_eligible(meta.page_size, D, Sq):
-        return ragged_paged_attention(q, k, v, meta)
+    """Backend dispatcher (config.attention_backend) over k / v as the
+    pool keeps them, unsliced: a decode batch (one q row a lane) takes
+    `lane_attention` where lane_attention_engaged says so (on a TPU by
+    the shapes, under 'ragged' and 'ragged_xla' alike; off it under
+    'ragged' alone, interpreted), and the XLA reference otherwise, which
+    reads the `meta.extent` slice of the rows. Prefill chunks (Sq > 1)
+    always take the reference."""
+    _, Sq, Hq, D = q.shape
+    if lane_attention_engaged(backend, Sq, Hq, k.shape[2], D, meta.page_size):
+        return lane_attention(q, k, v, meta)
+    if (
+        not meta.global_pages
+        and meta.extent is not None and meta.extent < k.shape[1]
+    ):
+        # The resident-extent slice: the reference reads O(tokens
+        # resident), not O(pool capacity). (Under global_pages it slices
+        # the page TABLE instead: physical pages may live in any slot.)
+        k = jax.lax.slice_in_dim(k, 0, meta.extent, axis=1)
+        v = jax.lax.slice_in_dim(v, 0, meta.extent, axis=1)
     return ragged_paged_attention_xla(q, k, v, meta, positions=positions)

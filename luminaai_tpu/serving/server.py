@@ -586,13 +586,29 @@ class ContinuousScheduler:
             "serve_kv_window_rows_read_total",
             "Rows of k/v the ticks' attention read in layers with a "
             "window of their own (a ring of pages a lane), summed over "
-            "those layers",
+            "those layers: whole rings where XLA attends the lanes, the "
+            "stepped lanes' live key blocks where the decode kernel does",
         )
         self._m_kv_global_rows = r.counter(
             "serve_kv_global_rows_read_total",
             "Rows of k/v the ticks' attention read in full-attention "
-            "layers (whole pages up to the tick's extent), summed over "
-            "those layers",
+            "layers, summed over those layers: every lane up to the "
+            "tick's extent where XLA attends the lanes, the stepped "
+            "lanes' live key blocks where the decode kernel does",
+        )
+        # The lanes' decode kernel (ops/ragged_paged_attention.py
+        # lane_attention) skips by lane and by key block: how much of its
+        # grid is live says how often the skip engages. Both stay 0 where
+        # the shapes leave the lanes' attention to XLA.
+        self._m_lane_blocks = r.counter(
+            "serve_lane_attention_blocks_total",
+            "Grid steps (lanes x key blocks) of the lanes' decode "
+            "attention kernel, summed over attention layers and ticks",
+        )
+        self._m_lane_blocks_live = r.counter(
+            "serve_lane_attention_blocks_live_total",
+            "Grid steps of the lanes' decode attention kernel that "
+            "fetched a block of k/v and computed",
         )
         self._m_ring_wraps = r.counter(
             "serve_ring_wraps_total",
@@ -626,6 +642,8 @@ class ContinuousScheduler:
             ("kv_window_rows", self._m_kv_window_rows),
             ("kv_global_rows", self._m_kv_global_rows),
             ("ring_wraps", self._m_ring_wraps),
+            ("lane_attention_blocks", self._m_lane_blocks),
+            ("lane_attention_blocks_live", self._m_lane_blocks_live),
             ("moe_routed_pairs", self._m_routed_pairs),
             ("moe_held_pairs", self._m_held_pairs),
             ("moe_held_pairs_dropped", self._m_held_dropped),

@@ -182,40 +182,58 @@ def test_megablox_gmm_compiles_at_expert_shapes(one_chip, transpose):
     assert n == (6 if transpose else 2), n
 
 
+# -- the lanes' decode kernel, at the shapes of the cells that run it --------
+@pytest.mark.parametrize("lanes,rows,hq,hkv,window,extent,ring", [
+    (32, 35 * 128, 128, 8, 4096, None, True),
+    (32, 16384, 128, 8, None, 16384, False),
+    (32, 16384, 128, 8, None, 2048, False),
+    (128, 2048, 20, 1, None, 1024, False),
+], ids=["command_a_ring", "command_a_whole_pages", "command_a_extent_2048",
+        "jamba_one_kv_head"])
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
-def test_ragged_paged_decode_compiles_at_server_defaults(one_chip, kv_dtype):
-    """The Pallas decode kernel at the server's 8 slots x 128-row pages
-    over a 2048-token slot: one q row per lane, page table chased by
-    scalar prefetch. int8 KV is (codes, per-row scales) dequantized in
-    front of the kernel, as models/layers.py reads it."""
-    C = S
+def test_lane_attention_compiles_at_the_ticks_shapes(
+        one_chip, lanes, rows, hq, hkv, window, extent, ring, kv_dtype):
+    """One layer's decode rows of one tick: command-a-plus's 32 lanes of
+    128 query heads over 8 k/v heads (a ring of 35 pages under the 4,096
+    window; 16,384 whole rows at the widest and at a narrow extent) and
+    Jamba2's 128 lanes of 20 query heads over one. The pool goes in as it
+    lies: no copy of it is made in front of the kernel (the program's
+    temporaries stay under a hundredth of the k/v it reads), whatever the
+    extent. int8 KV is (codes, per-row scales) dequantized in front of the
+    kernel, as models/layers.py reads it: that copy is the path's own."""
+    assert rpa.lane_attention_eligible(hq, hkv, 128, PAGE)
+    assert rpa.lane_attention_engaged("ragged_xla", 1, hq, hkv, 128, PAGE)
 
-    def decode(q, k, v, lengths, table):
+    def decode(q, k, v, lengths, ring_table):
         if kv_dtype == "int8":
             k, v = (
                 (codes.astype(jnp.float32) * scales).astype(BF16)
                 for codes, scales in (k, v)
             )
         meta = rpa.LaneMeta(
-            lengths=lengths, page_table=table, page_size=PAGE,
-            kind="decode",
+            lengths=lengths, window=window, page_size=PAGE, extent=extent,
+            ring_table=ring_table if ring else None,
         )
-        assert rpa.ragged_eligible(PAGE, D, 1)
-        return rpa.ragged_paged_attention(q, k, v, meta)
+        return rpa.lane_attention(q, k, v, meta, ring=ring)
 
     sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
     if kv_dtype == "int8":
         kv = (
-            sds((SLOTS, C, HKV, D), jnp.int8),
-            sds((SLOTS, C, HKV, 1), jnp.float32),
+            sds((lanes, rows, hkv, 128), jnp.int8),
+            sds((lanes, rows, hkv, 1), jnp.float32),
         )
     else:
-        kv = sds((SLOTS, C, HKV, D), BF16)
+        kv = sds((lanes, rows, hkv, 128), BF16)
     compiled = jax.jit(decode).lower(
-        sds((SLOTS, 1, HQ, D), BF16), kv, kv,
-        sds((SLOTS,), jnp.int32), sds((SLOTS, C // PAGE), jnp.int32),
+        sds((lanes, 1, hq, 128), BF16), kv, kv,
+        sds((lanes,), jnp.int32), sds((lanes, 128), jnp.int32),
     ).compile()
-    assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "lane_attention" in text
+    if kv_dtype == "bf16":
+        pool = 2 * lanes * rows * hkv * 128 * 2
+        assert compiled.memory_analysis().temp_size_in_bytes < pool // 100
 
 
 # -- the hybrid linear-attention cell's kernels (kimi-linear-train-8k) -------

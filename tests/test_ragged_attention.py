@@ -22,9 +22,10 @@ import jax.numpy as jnp
 from luminaai_tpu.ops.ragged_paged_attention import (
     LaneMeta,
     implied_page_size,
+    lane_attention,
+    lane_attention_eligible,
+    lane_attention_engaged,
     paged_attention,
-    ragged_eligible,
-    ragged_paged_attention,
     ragged_paged_attention_xla,
 )
 
@@ -81,8 +82,9 @@ def test_kernel_and_reference_match_dense(B, P, ps, Hq, Hkv, D, window):
     # decode (qp = lengths-1) the restrictions coincide, so bit-exact.
     np.testing.assert_array_equal(np.asarray(ref), np.asarray(dense))
 
-    assert ragged_eligible(ps, D, 1)
-    out = ragged_paged_attention(q, k, v, meta)
+    # Off the chip the kernel is interpreted at any shape, under 'ragged'.
+    assert lane_attention_engaged("ragged", 1, Hq, Hkv, D, ps)
+    out = lane_attention(q, k, v, meta)
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), atol=2e-6, rtol=2e-5
     )
@@ -98,7 +100,7 @@ def test_zero_length_lane_is_safe():
     meta = LaneMeta(
         lengths=jnp.asarray([0, 17], jnp.int32), page_size=8
     )
-    for fn in (ragged_paged_attention_xla, ragged_paged_attention):
+    for fn in (ragged_paged_attention_xla, lane_attention):
         out = np.asarray(fn(q, k, v, meta))
         assert np.isfinite(out).all(), fn.__name__
 
@@ -133,7 +135,7 @@ def test_page_table_indirection_matches_physical_gather():
     np.testing.assert_array_equal(
         np.asarray(via_table_xla), np.asarray(ref)
     )
-    via_table_kernel = ragged_paged_attention(q, k, v, meta)
+    via_table_kernel = lane_attention(q, k, v, meta)
     np.testing.assert_allclose(
         np.asarray(via_table_kernel), np.asarray(ref),
         atol=2e-6, rtol=2e-5,
@@ -169,20 +171,33 @@ def test_prefill_positions_mask_padding_rows():
 
 
 def test_dispatcher_gating():
-    """'ragged' uses the kernel only when eligible; prefill shapes and
-    odd head dims fall back to the reference; 'ragged_xla' never runs
-    the kernel (CPU-serving default — interpret mode costs interpreter
-    time)."""
-    assert ragged_eligible(8, 64, 1)
-    assert not ragged_eligible(8, 64, 4)  # multi-row q
-    assert not ragged_eligible(12, 64, 1)  # unaligned page
-    assert not ragged_eligible(8, 48, 1)  # lane-hostile head_dim
+    """The kernel takes a decode batch (one q row a lane) alone; on a
+    TPU by the shapes (lane_attention_eligible), under 'ragged' and
+    'ragged_xla' alike; off it only 'ragged' runs it, interpreted, so a
+    CPU program under 'ragged_xla' is the XLA reference's (interpret
+    mode costs interpreter time) and 'dense' never sees it."""
+    assert lane_attention_eligible(128, 8, 128, 128)
+    assert lane_attention_eligible(20, 1, 128, 128)
+    assert not lane_attention_eligible(16, 16, 128, 128)  # MHA
+    assert not lane_attention_eligible(32, 8, 128, 128)  # group of 4
+    assert not lane_attention_eligible(128, 8, 64, 128)  # half a lane
+    assert not lane_attention_eligible(128, 8, 128, 12)  # unaligned page
+    assert not lane_attention_eligible(64, 4, 128, 128)  # half a tile a row
+    assert lane_attention_engaged("ragged", 1, 2, 1, 48, 8)
+    assert not lane_attention_engaged("ragged", 4, 2, 1, 64, 8)  # multi-row q
+    assert not lane_attention_engaged("ragged_xla", 1, 128, 8, 128, 128)
+    assert not lane_attention_engaged("dense", 1, 128, 8, 128, 128)
     rng = np.random.RandomState(3)
-    q, k, v = _rand_qkv(rng, 2, 32, 2, 1, 48)  # D=48: ineligible
+    q, k, v = _rand_qkv(rng, 2, 32, 2, 1, 48)
     meta = LaneMeta(lengths=jnp.asarray([9, 30], jnp.int32), page_size=8)
-    out = paged_attention(q, k, v, meta, backend="ragged")
+    out = paged_attention(q, k, v, meta, backend="ragged_xla")
     ref = ragged_paged_attention_xla(q, k, v, meta)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+    via_kernel = paged_attention(q, k, v, meta, backend="ragged")
+    assert not np.array_equal(np.asarray(via_kernel), np.asarray(ref))
+    np.testing.assert_allclose(
+        np.asarray(via_kernel), np.asarray(ref), atol=2e-6, rtol=2e-5
+    )
 
 
 def test_implied_page_size():

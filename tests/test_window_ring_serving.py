@@ -26,6 +26,7 @@ attention and the expert layer; sigmoid top-2 of 8 experts with 4 held and
 """
 
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -424,6 +425,14 @@ def test_the_scheduler_serves_generates_tokens_and_feeds_the_counters(tiny):
     for t in threads:
         t.join(120)
     assert got == want
+    # A tick's tokens reach the streams before its counts reach the
+    # registry (_emit_lanes, then _count_decoder): let the loop finish the
+    # last tick's books.
+    routed = 4 * 2 * (sum(len(p) for p in prompts) + 3 * 29)
+    deadline = time.time() + 30
+    while (_counters(registry)["moe_routed_pairs_total"] < routed
+           and time.time() < deadline):
+        time.sleep(0.01)
     snap = _counters(registry)
     steps = snap["serve_decode_steps_total"]
     ring = sched.decoder.pool.ring_pages * PAGE
@@ -433,8 +442,7 @@ def test_the_scheduler_serves_generates_tokens_and_feeds_the_counters(tiny):
     assert 0 < snap["serve_kv_global_rows_read_total"] <= (2 * CAP + CAP) * steps
     assert snap["serve_ring_wraps_total"] == sum(
         (len(p) + 30 - 1 - 1) // ring for p in prompts)
-    routed = snap["moe_routed_pairs_total"]
-    assert routed == 4 * 2 * (sum(len(p) for p in prompts) + 3 * 29)
+    assert snap["moe_routed_pairs_total"] == routed
     assert 0 < snap["moe_held_pairs_total"] < routed
     assert snap["moe_held_pairs_dropped_total"] == 0
 
@@ -483,3 +491,39 @@ def test_the_layers_metrics_are_reduced_in_one_order():
                       for i in range(4)]
     out = LuminaTransformer._reduce_metrics(None, layers_metrics)
     assert list(out) == ["aux_loss"] + sorted(names)
+
+
+def test_the_lanes_kernel_serves_the_same_rows(tiny, monkeypatch):
+    """The lanes' decode rows through lane_attention (interpreted here:
+    backend 'ragged'), rings and whole pages read in place: two lanes of
+    different lengths across the window's edge and the ring's wrap beside
+    a slot that is never stepped, the same rows to the same tolerance,
+    and the control (the reference's window off by one) fails."""
+    from luminaai_tpu.ops import ragged_paged_attention as rpa
+
+    calls = []
+    kernel = rpa.lane_attention
+
+    def counted(q, k, v, meta, ring=False):
+        calls.append((ring, k.shape[1]))
+        return kernel(q, k, v, meta, ring=ring)
+
+    monkeypatch.setattr(rpa, "lane_attention", counted)
+    served = dict(tiny, cfg=tiny_config(attention_backend="ragged"))
+    requests, _, chunk, _ = CASES["two_lanes"]
+    dec, rows, seqs = serve(served, requests, slots=3, chunk=chunk)
+    ring = dec.pool.ring_pages * PAGE
+    # Every tick program traced three rings and one layer of whole pages.
+    assert sorted(set(calls)) == [(False, CAP), (True, ring)]
+    assert calls.count((True, ring)) == 3 * calls.count((False, CAP))
+    for name, _, _ in requests:
+        assert sorted(p for p, _ in rows[name]) == list(range(CAP))
+        err, at = worst_row(tiny, rows[name], seqs[name][:CAP])
+        assert err < 1e-4, (name, err, at)
+        err, at = worst_row(tiny, rows[name], seqs[name][:CAP],
+                            window=WINDOW + 1)
+        assert err > 1e-2 and at >= WINDOW, (name, err, at)
+    # The third slot was never stepped: of the grid's steps (3 lanes x key
+    # blocks a layer) under two thirds fetched anything.
+    assert 0 < dec.lane_attention_blocks_live
+    assert 3 * dec.lane_attention_blocks_live < 2 * dec.lane_attention_blocks
