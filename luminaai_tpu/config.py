@@ -131,9 +131,14 @@ class Config:
     # Token mixer of each layer, a tuple of num_layers entries:
     #   'attention' GQAttention (RoPE, the KV cache, every serving path);
     #   'latent'    LatentAttention (models/layers.py): keys and values
-    #               expanded from one low-rank latent a token, no
-    #               positional rotation, scores over nope+rope dims and
-    #               values of v_head_dim (training only so far);
+    #               expanded from one low-rank latent a token, scores
+    #               over nope+rope dims and values of v_head_dim; the
+    #               rope parts rotate under latent_rope (YaRN's
+    #               frequencies under yarn_factor), queries are low-rank
+    #               under q_lora_rank; trained in the expanded form,
+    #               served in the absorbed form over a paged entry of
+    #               ONE latent row a token (every serving path but the
+    #               prefix cache);
     #   'kda'       KimiDeltaAttention (models/kda.py): the gated delta
     #               rule as a linear-attention recurrence over a
     #               [head_dim x head_dim] state a head, computed by the
@@ -176,6 +181,27 @@ class Config:
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+    # Low-rank queries of a 'latent' layer: q = W_qb RMSNorm(W_qa x).
+    # None: one plain projection.
+    q_lora_rank: Optional[int] = None
+    # True: a 'latent' layer rotates the qk_rope_head_dim parts of q and
+    # of the shared key (rope_theta, rope_layout). False: nothing rotates
+    # (a stack whose recurrent layers carry the position).
+    latent_rope: bool = False
+    # YaRN on a 'latent' layer's rotation (needs latent_rope): the
+    # frequencies that turn fewer than yarn_beta_slow times over
+    # yarn_original_max positions are divided by yarn_factor, those that
+    # turn more than yarn_beta_fast times are kept, a linear ramp
+    # between; cos and sin are multiplied by mscale(yarn_mscale) /
+    # mscale(yarn_mscale_all_dim) and the softmax scale by
+    # mscale(yarn_mscale_all_dim)^2, mscale(m) = 0.1 m ln(factor) + 1.
+    # None: plain frequencies.
+    yarn_factor: Optional[float] = None
+    yarn_original_max: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # --- MoE ---
     use_moe: bool = False
@@ -923,13 +949,30 @@ class Config:
                     ("tensor", self.tensor_parallel_size),
                 ):
                     assert size == 1, (
-                        f"'latent' and 'kda' mixers do not compose with "
-                        f"{name}_parallel_size={size} yet"
+                        f"'latent', 'kda' and 'ssm' mixers do not compose "
+                        f"with {name}_parallel_size={size} yet"
                     )
                 assert self.attention_window is None, (
-                    "'latent' and 'kda' mixers take no attention_window"
+                    "'latent', 'kda' and 'ssm' mixers take no "
+                    "attention_window (a 'latent' layer may rotate, "
+                    "latent_rope, and is always full causal)"
                 )
                 assert self.kda_conv_size >= 1 and self.kda_head_dim >= 1
+            assert not (
+                "latent" in kinds and self.kv_cache_dtype == "int8"
+            ), (
+                "kv_cache_dtype='int8' does not compose with 'latent' "
+                "layers: the latent entry carries no per-row scales"
+            )
+        assert self.yarn_factor is None or (
+            self.latent_rope and self.yarn_factor >= 1.0
+        ), (
+            "yarn_factor (>= 1) scales a 'latent' layer's rotation and "
+            "needs latent_rope; GQAttention's rotation takes no YaRN yet"
+        )
+        assert not self.latent_rope or self.qk_rope_head_dim % 2 == 0, (
+            "latent_rope rotates pairs: qk_rope_head_dim must be even"
+        )
         if self.use_mod:
             assert 0.0 < self.mod_capacity_factor <= 1.0, (
                 "mod_capacity_factor must be in (0, 1]"
@@ -1101,12 +1144,43 @@ class Config:
             return "attention"
         return self.layer_mixers[layer_idx]
 
+    def unserved_mixers(self) -> tuple:
+        """The mixer kinds of this stack that only training runs: 'kda'
+        (no delta-rule state a lane yet). 'attention', 'ssm' and 'latent'
+        layers are served."""
+        return tuple(sorted(
+            set(self.layer_mixers or ()) - {"attention", "ssm", "latent"}
+        ))
+
     def recurrent_or_latent(self) -> bool:
-        """True when some layer's mixer has no serving path yet."""
-        return any(
-            kind not in ("attention", "ssm")
-            for kind in (self.layer_mixers or ())
-        )
+        """True when some layer's mixer has no serving path yet
+        (unserved_mixers; the name dates from when 'latent' was one)."""
+        return bool(self.unserved_mixers())
+
+    def yarn(self) -> Optional[tuple]:
+        """(factor, original max positions, beta_fast, beta_slow) of
+        the latent layers' YaRN rotation, None without one."""
+        if self.yarn_factor is None:
+            return None
+        return (float(self.yarn_factor), int(self.yarn_original_max),
+                float(self.yarn_beta_fast), float(self.yarn_beta_slow))
+
+    def _yarn_mscale(self, m: float) -> float:
+        if self.yarn_factor is None or self.yarn_factor <= 1.0:
+            return 1.0
+        return 0.1 * m * math.log(self.yarn_factor) + 1.0
+
+    def latent_rope_mscale(self) -> float:
+        """What a 'latent' layer's cos and sin are multiplied by."""
+        return (self._yarn_mscale(self.yarn_mscale)
+                / self._yarn_mscale(self.yarn_mscale_all_dim))
+
+    def latent_softmax_scale(self) -> float:
+        """A 'latent' layer's score scale: (nope + rope dims)^-1/2,
+        times YaRN's mscale(yarn_mscale_all_dim)^2."""
+        dq = self.qk_nope_head_dim + self.qk_rope_head_dim
+        return (1.0 / float(dq) ** 0.5
+                * self._yarn_mscale(self.yarn_mscale_all_dim) ** 2)
 
     def keeps_lane_state(self) -> bool:
         """True when some layer keeps a fixed state a lane (models/ssm.py

@@ -196,16 +196,16 @@ class GenerationEngine:
         self.tokenizer = tokenizer
         self.config = config or model.config
         for cfg in (self.config, model.config):
-            if cfg.recurrent_or_latent():
+            if cfg.unserved_mixers():
                 raise UnservedMixerError(
                     "this model cannot be served yet: layer_mixers="
-                    f"{cfg.layer_mixers} has 'kda' or 'latent' layers, and "
-                    "the engine keeps no delta-rule state a lane and no "
-                    "latent cache entry (inference/kv_pool.py holds pages "
-                    "of k/v and an 'ssm' layer's fixed state, nothing "
-                    "else). `lumina train` runs it; `lumina serve` and "
-                    "`lumina chat` need docs/serving.md's 'Mixers that "
-                    "are not served'."
+                    f"{cfg.layer_mixers} has {list(cfg.unserved_mixers())} "
+                    "layers, and the engine keeps no delta-rule state a "
+                    "lane (inference/kv_pool.py holds pages of k/v, pages "
+                    "of one latent a token and an 'ssm' layer's fixed "
+                    "state, nothing else). `lumina train` runs it; "
+                    "`lumina serve` and `lumina chat` need docs/"
+                    "serving.md's 'Mixers that are not served'."
                 )
         self.max_context = max_context or self.config.seq_length
         # Inference quantization (config.quantization_method = 'int8'/
@@ -1019,6 +1019,16 @@ class StepwiseDecoder:
                 "k/v rows and no snapshot of the recurrent state at its "
                 "end, so a spliced prefix would start from the wrong state"
             )
+        latent_layers = (engine.config.layer_mixers or ()).count("latent")
+        if prefix_cache_pages > 0 and latent_layers:
+            from luminaai_tpu.inference.kv_pool import LatentPagesOwnedError
+
+            raise LatentPagesOwnedError(
+                f"prefix cache (prefix_cache_pages={prefix_cache_pages}) "
+                "is not served with 'latent' layers yet: a spliced prefix "
+                "lives in another slot's pages, and the absorbed attention "
+                "over a latent entry reads a lane's own pages in place"
+            )
         backend = getattr(engine.config, "attention_backend", "dense")
         _chunk_eff = (
             int(prefill_chunk_tokens)
@@ -1110,6 +1120,12 @@ class StepwiseDecoder:
         self.kv_window_rows = 0
         self.kv_global_rows = 0
         self.ring_wraps = 0
+        # Rows of one latent a token the lanes' attention read in the
+        # 'latent' layers, and the keys the chunk's attention spanned
+        # there (its lane up to the chunk's end), summed over those layers.
+        self._n_latent_layers = latent_layers
+        self.kv_latent_rows = 0
+        self.kv_latent_chunk_keys = 0
         # Grid steps (lanes x key blocks) of the lanes' decode kernel
         # (ops/ragged_paged_attention.py lane_attention), summed over
         # attention layers, and those that fetched and computed: both 0
@@ -1127,6 +1143,7 @@ class StepwiseDecoder:
         self.backend = getattr(
             engine.config, "attention_backend", "dense"
         )
+        from luminaai_tpu.models.layers import latent_entry_width
         from luminaai_tpu.ops.ragged_paged_attention import (
             lane_attention_engaged,
         )
@@ -1134,6 +1151,12 @@ class StepwiseDecoder:
         self._lane_kernel = lane_attention_engaged(
             self.backend, 1, engine.config.num_heads,
             engine.config.num_kv_heads, engine.config.head_dim(),
+            self.pool.page_size,
+        )
+        # A latent entry is one shared key row of its own width.
+        self._latent_row = (1, latent_entry_width(engine.config))
+        self._latent_lane_kernel = lane_attention_engaged(
+            self.backend, 1, engine.config.num_heads, *self._latent_row,
             self.pool.page_size,
         )
         # Device copy of the pool's page table, refreshed at admission
@@ -2409,16 +2432,31 @@ class StepwiseDecoder:
                     rows_window += layers * fetched * rows
         self.kv_global_rows += rows_full + self._n_global_layers * c_full
         self.kv_window_rows += rows_window + self._n_window_layers * c_window
+        if self._n_latent_layers:
+            layers = self._n_latent_layers
+            rows = self.num_slots * lanes_full
+            if self._latent_lane_kernel:
+                steps, fetched, per = self._lane_blocks_read(
+                    pos[live] + 1, np.flatnonzero(live), None, lanes_full,
+                    False, self._latent_row,
+                )
+                self.lane_attention_blocks += layers * steps
+                self.lane_attention_blocks_live += layers * fetched
+                rows = fetched * per
+            self.kv_latent_rows += layers * rows
+            self.kv_latent_chunk_keys += layers * c_full
         if ring:
             at = pos[live]
             self.ring_wraps += int(((at > 0) & (at % ring == 0)).sum())
 
-    def _lane_blocks_read(self, held, slots, window, rows, ring):
+    def _lane_blocks_read(self, held, slots, window, rows, ring, row=None):
         """(grid steps, steps that fetch and compute, rows of k/v such a
         step fetches) of one layer's lane_attention call in a tick that
         steps the lanes `slots`, holding `held` rows each, over `rows`
         rows a lane (the tick's extent, or the ring): lane_pages_held and
-        lane_blocks, the kernel's own plan, over the host's lengths."""
+        lane_blocks, the kernel's own plan, over the host's lengths.
+        `row`: (k/v heads, head size) of a pool row, the attention
+        layers' by default."""
         from luminaai_tpu.ops.ragged_paged_attention import (
             lane_blocks,
             lane_pages_held,
@@ -2427,7 +2465,7 @@ class StepwiseDecoder:
         cfg, ps = self.engine.config, self.pool.page_size
         pages = rows // ps
         per_block, _ = lane_blocks(
-            pages, ps, cfg.num_kv_heads, cfg.head_dim(),
+            pages, ps, *(row or (cfg.num_kv_heads, cfg.head_dim())),
             jnp.dtype(self.model.dtype).itemsize,
             chased=self.prefix_cache is not None,
         )

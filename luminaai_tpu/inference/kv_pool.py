@@ -32,6 +32,17 @@ name (`RingKeepsWindowError`): the prefix cache, a page export or import,
 speculation's k-row verify; so are int8 k/v and a pool without chunked
 prefill, which the ring's write path does not serve.
 
+A 'latent' layer (models/layers.py LatentAttention) keeps a FOURTH kind:
+pages of ONE row a token, `[num_slots, pages, page_size, 1, width]` with
+width = kv_lora_rank + qk_rope_head_dim padded to whole 128-lane tiles
+(576 -> 640), shared by every head (`LatentPages`; expanded k/v of 64
+heads x (192 + 128) would be 32 times that). Positions are absolute and
+the rotation is in the stored row, so it is paged, inserted, rebuilt and
+exported as k or v are, the axis of length one standing where they keep
+their heads; what reads it is the mixer's absorbed attention. The prefix
+cache over it is refused by name (`LatentPagesOwnedError`): the absorbed
+attention reads a lane's own pages in place and chases no table yet.
+
 Device arrays live here only as an opaque pytree (`self.caches`); all
 accounting — the free-list, per-slot length vector, reuse counters — is
 host-side numpy, so the scheduler never has to read device memory to
@@ -70,6 +81,13 @@ class RingKeepsWindowError(NotImplementedError):
     """Asked of a pool in which some layer keeps a ring of pages a lane
     (a window of its own): something that needs a page older than the
     window, or a write path the ring does not have."""
+
+
+class LatentPagesOwnedError(NotImplementedError):
+    """Asked of a pool in which some layer keeps pages of one latent a
+    token: something that reads a lane's pages through another slot's
+    (the prefix cache's splice), which the absorbed attention over a
+    latent entry does not follow yet."""
 
 
 def map_pages(fn, tree, *rest, states=None):
@@ -326,18 +344,26 @@ class PagedKVPool:
 
     def slot_bytes(self) -> dict:
         """What ONE slot holds on the device, by entry kind: whole pages
-        (`pages` a layer), rings of pages (`ring_pages` a layer), fixed
-        states. Read from the cache tree's own shapes."""
+        of k/v (`pages` a layer), rings of pages (`ring_pages` a layer),
+        pages of one latent a token, fixed states. Read from the cache
+        tree's own shapes."""
         import jax
 
+        from luminaai_tpu.models.layers import is_latent_pages
         from luminaai_tpu.models.ssm import is_lane_state
 
-        out = {"pages": 0, "ring": 0, "state": 0}
+        out = {"pages": 0, "ring": 0, "latent": 0, "state": 0}
         if self.caches is None:
             return out
-        for entry in jax.tree.leaves(self.caches, is_leaf=is_lane_state):
+        for entry in jax.tree.leaves(
+            self.caches,
+            is_leaf=lambda x: is_lane_state(x) or is_latent_pages(x),
+        ):
             if is_lane_state(entry):
                 out["state"] += entry.nbytes() // entry.state.shape[-3]
+                continue
+            if is_latent_pages(entry):
+                out["latent"] += entry.rows.nbytes // entry.rows.shape[-5]
                 continue
             ring = entry.shape[-4] != self.pages
             out["ring" if ring else "pages"] += (

@@ -10,11 +10,14 @@ under any dp/fsdp/tp/sp mesh layout.
 
 from __future__ import annotations
 
+import math
 from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from flax import linen as nn
+from flax import struct
 
 from luminaai_tpu.config import Config
 from luminaai_tpu.ops.quantized import QuantizedTensor
@@ -77,16 +80,41 @@ class LayerNorm(nn.Module):
         return y.astype(self.dtype)
 
 
+def yarn_ramp(head_dim: int, theta: float, yarn: Tuple) -> np.ndarray:
+    """[head_dim // 2] in [0, 1]: how far YaRN divides each rotation
+    frequency by its factor. yarn = (factor, original max positions,
+    beta_fast, beta_slow): the pair that turns `beta` times over the
+    original context sits at dim(beta) = head_dim ln(original / (2 pi
+    beta)) / (2 ln theta); pairs below floor(dim(beta_fast)) keep their
+    frequency, pairs above ceil(dim(beta_slow)) are divided by the
+    factor, a linear ramp between."""
+    _, original, fast, slow = yarn
+
+    def dim(turns):
+        return head_dim * math.log(original / (2 * math.pi * turns)) / (
+            2 * math.log(theta))
+
+    low = max(math.floor(dim(fast)), 0)
+    high = min(math.ceil(dim(slow)), head_dim - 1)
+    span = max(high - low, 1e-3)
+    return np.clip((np.arange(head_dim // 2) - low) / span, 0.0, 1.0)
+
+
 def rope_frequencies(
-    head_dim: int, max_len: int, theta: float = 10000.0
+    head_dim: int, max_len: int, theta: float = 10000.0,
+    yarn: Optional[Tuple] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Precompute RoPE cos/sin tables in fp32 (ref core/model.py:334).
+    `yarn` (Config.yarn()): YaRN's frequencies (yarn_ramp).
 
     Returns (cos, sin) of shape [max_len, head_dim//2].
     """
     inv_freq = 1.0 / (
         theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
     )
+    if yarn is not None:
+        ramp = jnp.asarray(yarn_ramp(head_dim, theta, yarn), jnp.float32)
+        inv_freq = inv_freq * (1.0 - ramp) + inv_freq / yarn[0] * ramp
     t = jnp.arange(max_len, dtype=jnp.float32)
     freqs = jnp.outer(t, inv_freq)
     return jnp.cos(freqs), jnp.sin(freqs)
@@ -969,26 +997,82 @@ class GQAttention(nn.Module):
         return out.reshape(B, Sq, n_q, d)
 
 
+@struct.dataclass
+class LatentPages:
+    """What a lane keeps of a 'latent' layer: pages of ONE row a token,
+    [batch, rows, 1, width], shared by every head: [c (kv_lora_rank, after
+    its norm); k_r (qk_rope_head_dim, rotated at the token's position);
+    zeros up to `latent_entry_width`]. One array, so that a key block is
+    fetched once and the value is its first kv_lora_rank columns; the
+    axis of length one stands where k/v keep their heads, so the pool
+    pages, inserts and exports it as it does k or v (inference/
+    kv_pool.py). The node marks the kind (PagedKVPool.slot_bytes)."""
+
+    rows: jax.Array
+
+
+def is_latent_pages(x) -> bool:
+    return isinstance(x, LatentPages)
+
+
+def latent_entry_width(cfg: Config) -> int:
+    """Columns of a latent entry's row: kv_lora_rank + qk_rope_head_dim,
+    padded with zeros to whole 128-lane tiles (576 -> 640), which is what
+    the chip's tiled layout would allocate anyway and lets the kernels'
+    blocks and both matmuls stay tile-aligned."""
+    return -(-(cfg.kv_lora_rank + cfg.qk_rope_head_dim) // 128) * 128
+
+
 class LatentAttention(nn.Module):
-    """Multi-head latent attention without positions (training form).
+    """Multi-head latent attention.
 
     Keys and values of every head are expanded from ONE low-rank latent a
     token: [c (kv_lora_rank); k_r (qk_rope_head_dim)] = W_kva x, c through
     an RMSNorm, [k_n (qk_nope_head_dim); v (v_head_dim)] a head = W_kvb c,
     and a head's key is [k_n; k_r] with k_r shared by all heads. Queries
-    are a plain projection to heads x (nope + rope). No rotation is
-    applied to either part (`mla_use_nope`: the model's recurrent layers
-    carry position). Scores run over nope + rope dims (192), values and
-    the output over v_head_dim (128): the flash kernels take the two
-    widths as they are. Training materialises k and v a head; the
-    absorbed form and the latent cache entry are serving's and not here.
+    are one projection to heads x (nope + rope), or low-rank
+    (`q_lora_rank`: W_qb RMSNorm(W_qa x)). Under `latent_rope` the rope
+    parts of q and the shared k_r rotate at the token's position (YaRN's
+    frequencies and scale under `yarn_factor`); without it nothing rotates
+    (a stack whose recurrent layers carry position). Scores run over
+    nope + rope dims (192) times Config.latent_softmax_scale, values and
+    the output over v_head_dim (128).
+
+    Two forms of one function. TRAINING (no cache) materialises k and v a
+    head and takes the flash kernels at their two widths. SERVING (a
+    cache: LatentPages, init_cache) stores the token's [c; rotated k_r]
+    row and attends in the ABSORBED form: W_kvb's key half is folded into
+    the query (q~ = W_kvb^K' q_n, 512 wide), the scores are q~ . c + q_r .
+    k_r against the stored rows, the softmax sums the rows' c, and W_kvb's
+    value half is applied after the sum: multi-query attention of every
+    head over one shared key of kv_lora_rank + qk_rope_head_dim columns
+    whose first kv_lora_rank are the value, with no expansion of what is
+    stored. The pool's tick goes through ops/ragged_paged_attention.py's
+    lane_attention (the lanes) and chunk_attention (the chunk) over the
+    entry in place; every other cached call through latent_attention_xla.
     """
 
     config: Config
     dtype: Dtype = jnp.bfloat16
 
+    @staticmethod
+    def init_cache(cfg: Config, batch_size: int, max_len: int, dtype,
+                   lead=()) -> LatentPages:
+        """What a lane keeps of a latent layer: `max_len` rows of one
+        latent a token (LatentPages), which the pool pages whole."""
+        return LatentPages(rows=jnp.zeros(
+            (*lead, batch_size, max_len, 1, latent_entry_width(cfg)), dtype))
+
     @nn.compact
-    def __call__(self, x: jax.Array) -> jax.Array:
+    def __call__(
+        self,
+        x: jax.Array,
+        *,
+        positions: Optional[jax.Array] = None,
+        kv_cache: Optional[LatentPages] = None,
+        cache_index: Optional[jax.Array] = None,
+        lane_meta: Optional[Any] = None,
+    ):
         cfg = self.config
         B, S, H = x.shape
         n = cfg.num_heads
@@ -1000,22 +1084,60 @@ class LatentAttention(nn.Module):
                 name, nn.with_logical_partitioning(default_init(std), axes),
                 shape, jnp.float32)
 
-        wq = mat("wq", (H, n, dq), ("embed", "heads", "head_dim"))
+        def norm(name, t):
+            return RMSNorm(cfg.rms_norm_eps, dtype=self.dtype, name=name)(t)
+
+        x = x.astype(self.dtype)
+        if cfg.q_lora_rank:
+            wqa = mat("wq_a", (H, cfg.q_lora_rank), ("embed", None))
+            wqb = mat("wq_b", (cfg.q_lora_rank, n, dq),
+                      (None, "heads", "head_dim"))
+            cq = norm("q_norm", jnp.einsum(
+                "bsh,hr->bsr", x, wqa.astype(self.dtype)))
+            q = jnp.einsum("bsr,rnd->bsnd", cq, wqb.astype(self.dtype))
+        else:
+            wq = mat("wq", (H, n, dq), ("embed", "heads", "head_dim"))
+            q = jnp.einsum("bsh,hnd->bsnd", x, wq.astype(self.dtype))
         wkva = mat("wkv_a", (H, rank + dr), ("embed", None))
         wkvb = mat("wkv_b", (rank, n, dn + dv), (None, "heads", "head_dim"))
         wo = mat("wo", (n, dv, H), ("heads", "head_dim", "embed"),
                  cfg.init_std / jnp.sqrt(2.0))
+        wkvb = wkvb.astype(self.dtype)
 
-        x = x.astype(self.dtype)
-        q = jnp.einsum("bsh,hnd->bsnd", x, wq.astype(self.dtype))
         kva = jnp.einsum("bsh,hr->bsr", x, wkva.astype(self.dtype))
-        c = RMSNorm(cfg.rms_norm_eps, dtype=self.dtype, name="kv_norm")(
-            kva[..., :rank])
-        kv = jnp.einsum("bsr,rnd->bsnd", c, wkvb.astype(self.dtype))
-        k_r = jnp.broadcast_to(kva[..., None, rank:], (B, S, n, dr))
-        k = jnp.concatenate([kv[..., :dn], k_r], axis=-1)
+        c = norm("kv_norm", kva[..., :rank])
+        q_n, q_r, k_r = q[..., :dn], q[..., dn:], kva[..., None, rank:]
+        if cfg.latent_rope:
+            cached = 0 if kv_cache is None else kv_cache.rows.shape[-3]
+            cos, sin = rope_frequencies(
+                dr, max(cfg.seq_length, S, cached), cfg.rope_theta,
+                yarn=cfg.yarn())
+            m = cfg.latent_rope_mscale()
+            if m != 1.0:
+                cos, sin = cos * m, sin * m
+            ct = self.dtype if cfg.rope_dtype == "bf16" else jnp.float32
+            q_r = apply_rope(q_r, cos, sin, positions, compute_dtype=ct,
+                             layout=cfg.rope_layout)
+            k_r = apply_rope(k_r, cos, sin, positions, compute_dtype=ct,
+                             layout=cfg.rope_layout)
+        scale = cfg.latent_softmax_scale()
+
+        def out_proj(o):
+            return jnp.einsum("bsnd,ndh->bsh", o, wo.astype(self.dtype))
+
+        if kv_cache is not None:
+            with jax.named_scope("latent_attention"):
+                o_lat, new_cache = self._cached(
+                    q_n, q_r, c, k_r[:, :, 0], wkvb[..., :dn], scale,
+                    kv_cache, positions, cache_index, lane_meta)
+            o = jnp.einsum("bsnr,rnd->bsnd", o_lat, wkvb[..., dn:])
+            return out_proj(o), new_cache
+
+        kv = jnp.einsum("bsr,rnd->bsnd", c, wkvb)
+        k = jnp.concatenate(
+            [kv[..., :dn], jnp.broadcast_to(k_r, (B, S, n, dr))], axis=-1)
+        q = jnp.concatenate([q_n, q_r], axis=-1)
         v = kv[..., dn:]
-        scale = 1.0 / float(dq) ** 0.5
 
         from luminaai_tpu.ops.flash_attention import flash_eligible
 
@@ -1045,7 +1167,102 @@ class LatentAttention(nn.Module):
                 keep = jnp.arange(S)[:, None] >= jnp.arange(S)[None, :]
                 p = jax.nn.softmax(jnp.where(keep, s, -1e30), axis=-1)
                 out = jnp.einsum("bnqk,bknd->bqnd", p.astype(q.dtype), v)
-        return jnp.einsum("bsnd,ndh->bsh", out, wo.astype(self.dtype))
+        return out_proj(out), None
+
+    def _cached(self, q_n, q_r, c, k_r, wk, scale, cache, positions,
+                cache_index, meta):
+        """The serving half: write the rows' latents into the entry at
+        their positions, then the absorbed attention of the rows over it.
+        Returns ([B, S, heads, kv_lora_rank] softmax sums of the stored c,
+        the new entry). Three callers, told apart as GQAttention tells
+        them: the pool's tick (one row a batch row, `cache_index` [B],
+        a chunk's rows riding behind the lanes: LaneMeta.chunk_rows), a
+        per-lane multi-row write (the whole-prompt bucket), and the
+        single-stream engine's scalar offset."""
+        from luminaai_tpu.ops.ragged_paged_attention import (
+            chunk_attention,
+            chunk_attention_eligible,
+            lane_attention,
+            lane_attention_engaged,
+            latent_attention_xla,
+        )
+
+        B, S, n, _ = q_n.shape
+        rank = c.shape[-1]
+        rows = cache.rows
+        C, W = rows.shape[1], rows.shape[3]
+        if positions is None:
+            raise ValueError(
+                "a cached latent layer needs each row's position (padding "
+                "rows marked -1): the stored key part is rotated at it"
+            )
+
+        def widen(parts, lead):
+            pad = W - sum(p.shape[-1] for p in parts)
+            return jnp.concatenate(
+                [*parts, jnp.zeros((*lead, pad), self.dtype)], axis=-1)
+
+        fresh = widen([c, k_r], (B, S))[:, :, None, :].astype(rows.dtype)
+        q = widen([jnp.einsum("bsnd,rnd->bsnr", q_n, wk), q_r], (B, S, n))
+        per_lane = getattr(cache_index, "ndim", 0) == 1
+        n_c = getattr(meta, "chunk_rows", 0) if per_lane and S == 1 else 0
+        n_d = B - n_c
+        if not per_lane:
+            rows = jax.lax.dynamic_update_slice(
+                rows, fresh, (0, cache_index, 0, 0))
+        else:
+            # Each row at its own (slot, position): a lane's own slot, a
+            # riding chunk's rows the chunk's slot. A row at position -1
+            # (a lane not stepped, padding) writes nothing: its index is
+            # out of range and the scatter drops it.
+            slot = jnp.arange(n_d)
+            if n_c:
+                slot = jnp.concatenate(
+                    [slot, jnp.broadcast_to(meta.chunk_slot, (n_c,))])
+            rows = rows.at[
+                slot[:, None], jnp.where(positions >= 0, positions, C)
+            ].set(fresh, mode="drop")
+        new_cache = LatentPages(rows=rows)
+
+        backend = getattr(meta, "backend", None) or getattr(
+            self.config, "attention_backend", "dense")
+        if not (per_lane and S == 1):
+            return latent_attention_xla(
+                q, rows[:B], positions, scale, rank), new_cache
+
+        # The tick: the lanes' rows over their own slots ...
+        if n_d and meta.lengths is not None and lane_attention_engaged(
+                backend, 1, n, 1, W, meta.page_size):
+            lanes = meta.replace(chunk_rows=0, chunk_slot=None,
+                                 chunk_start=None, window=None)
+            out = lane_attention(q[:n_d], rows, None, lanes, scale=scale,
+                                 v_dim=rank)
+        else:
+            extent = getattr(meta, "extent", None) or C
+            out = latent_attention_xla(
+                q[:n_d], rows[:n_d, :extent], positions[:n_d], scale, rank)
+        if not n_c:
+            return out, new_cache
+        # ... and the chunk's rows over the chunk's slot, as one
+        # multi-row query: blocked over the keys where the [rows, heads,
+        # keys] float32 scores would not fit (_CHUNK_SCORES_LIMIT).
+        own = jax.lax.dynamic_slice_in_dim(rows, meta.chunk_slot, 1, 0)
+        q_c, pos_c = q[n_d:, 0], positions[n_d:, 0]
+        if (
+            backend != "dense"
+            and 4 * n_c * n * C > _CHUNK_SCORES_LIMIT
+            and chunk_attention_eligible(n_c, C, W)
+        ):
+            end = jnp.minimum(jnp.max(pos_c) + 1, C)
+            kpos = jnp.arange(C, dtype=jnp.int32)
+            out_c = chunk_attention(
+                q_c, own[0], None, pos_c, jnp.where(kpos < end, kpos, -1),
+                None, end, scale=scale, v_dim=rank,
+            )
+        else:
+            out_c = latent_attention_xla(
+                q_c[None], own, pos_c[None], scale, rank)[0]
+        return jnp.concatenate([out, out_c[:, None]], axis=0), new_cache
 
 
 class Embedder(nn.Module):

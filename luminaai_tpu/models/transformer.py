@@ -134,23 +134,27 @@ class TransformerBlock(nn.Module):
                 cache_index=cache_index,
                 lane_meta=lane_meta,
             )
+        elif kind == "latent":
+            h, new_cache = LatentAttention(
+                cfg, dtype=self.dtype, name="latent_attention"
+            )(
+                normed,
+                positions=positions,
+                kv_cache=kv_cache,
+                cache_index=cache_index,
+                lane_meta=lane_meta,
+            )
         else:
             if kv_cache is not None:
                 raise NotImplementedError(
                     f"layer {self.layer_idx}'s {kind!r} mixer has no "
-                    "decode path (no latent cache entry, no delta-rule "
-                    "state a lane yet)"
+                    "decode path (no delta-rule state a lane yet)"
                 )
             new_cache = None
-            if kind == "latent":
-                h = LatentAttention(
-                    cfg, dtype=self.dtype, name="latent_attention"
-                )(normed)
-            else:
-                h, kda_stats = KimiDeltaAttention(
-                    cfg, dtype=self.dtype, name="kda"
-                )(normed)
-                metrics.update(kda_stats)
+            h, kda_stats = KimiDeltaAttention(
+                cfg, dtype=self.dtype, name="kda"
+            )(normed)
+            metrics.update(kda_stats)
         h = checkpoint_name(h, "attn_out")
         if cfg.parallel_block:
             # The feed-forward reads the same normed rows as the mixer;
@@ -561,11 +565,14 @@ class LuminaTransformer(nn.Module):
         cfg = self.config
 
         def entry(layer, *lead):
-            """What a lane keeps of `layer`, by its mixer: pages of k/v
-            or a fixed state."""
+            """What a lane keeps of `layer`, by its mixer: pages of k/v,
+            pages of one latent a token, or a fixed state."""
             if cfg.mixer_kind(layer) == "ssm":
                 return SelectiveSSM.init_cache(
                     cfg, batch_size, self.dtype, lead)
+            if cfg.mixer_kind(layer) == "latent":
+                return LatentAttention.init_cache(
+                    cfg, batch_size, max_len, self.dtype, lead)
             return GQAttention.init_cache(
                 cfg, batch_size, max_len, self.dtype,
                 kv_cache_dtype=kv_cache_dtype, rolling=rolling, lead=lead,

@@ -314,6 +314,30 @@ def banded_attention_xla(
     return out.reshape(B, Sq, n_q, d)
 
 
+def latent_attention_xla(
+    q: jax.Array, rows: jax.Array, qpos: jax.Array, scale: float,
+    v_dim: int,
+) -> jax.Array:
+    """Absorbed latent attention, reference semantics: multi-query
+    attention of q [B, Sq, Hq, W] at positions qpos [B, Sq] (-1: a row
+    that sees nothing) over ONE shared key a token, the latent entry's
+    rows [B, C, 1, W] at their row numbers, whose first `v_dim` columns
+    are the value. Key j is seen by query i iff j <= i. Returns
+    [B, Sq, Hq, v_dim]: what lane_attention and chunk_attention compute
+    over the same entry (`v=None`), and what runs wherever they do not."""
+    k = rows[:, :, 0]
+    s = jnp.einsum("bqnw,bcw->bnqc", q, k).astype(jnp.float32) * scale
+    seen = jnp.arange(k.shape[1])[None, None, :] <= qpos[:, :, None]
+    s = jnp.where(seen[:, None], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+    return jnp.einsum("bnqc,bcv->bqnv", p, k[..., :v_dim])
+
+
+# Stacked query rows (rows x folded heads) a grid step of chunk_attention
+# over a latent entry: [1,024, 1,024] float32 scores are 4 MB of VMEM.
+_CHUNK_FOLD_ROWS = 1024
+
+
 def _chunk_key_block(rows: int) -> int:
     """Keys a grid step of chunk_attention: the largest multiple of 128
     up to 1,024 that divides the lane's rows (a ring of 35 pages of 128
@@ -338,9 +362,11 @@ def chunk_attention_eligible(n_rows: int, key_rows: int, head_dim: int
 
 def _chunk_kernel(
     blocks_ref,  # scalar prefetch [1]: key blocks that hold live rows
-    qpos_ref, kpos_ref, q_ref, k_ref, v_ref, o_ref,
-    m_scr, l_scr, acc_scr, *, scale, window,
+    qpos_ref, kpos_ref, q_ref, k_ref, *rest, scale, window, v_dim,
 ):
+    # `v_dim`: no v operand, the value is the key's first v_dim columns.
+    v_ref = None if v_dim else rest[0]
+    o_ref, m_scr, l_scr, acc_scr = rest[0 if v_dim else 1:]
     j = pl.program_id(1)
     nj = pl.num_programs(1)
 
@@ -354,7 +380,7 @@ def _chunk_kernel(
     def _compute():
         q = q_ref[0]  # [n, D]
         k = k_ref[0]  # [Bk, D]
-        v = v_ref[0]
+        v = k[:, :v_dim] if v_dim else v_ref[0]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -386,9 +412,10 @@ def _chunk_kernel(
 
 
 def chunk_attention(
-    q: jax.Array, k: jax.Array, v: jax.Array,
+    q: jax.Array, k: jax.Array, v: Optional[jax.Array],
     qpos: jax.Array, kpos: jax.Array, window: Optional[int],
-    live_rows: jax.Array,
+    live_rows: jax.Array, *, scale: Optional[float] = None,
+    v_dim: int = 0,
 ) -> jax.Array:
     """The tick's prefill chunk over its own lane, blocked over the keys
     with an online softmax: q [n, Hq, D] at positions qpos [n] (-1: a
@@ -400,16 +427,42 @@ def chunk_attention(
     traced); key blocks wholly past it cost neither a DMA nor a step's
     arithmetic. Grid (q head, key block): a head's [n, D] queries stay
     put while its k/v head's blocks stream past; [n, block] float32
-    scores live in VMEM and nowhere else. Returns [n, Hq, D]."""
+    scores live in VMEM and nowhere else. Returns [n, Hq, D].
+
+    A latent entry (models/layers.py LatentPages): `v=None` and
+    `v_dim`, the value is the first v_dim columns of the ONE shared key
+    row, read once a block for both matmuls; `scale` is the mixer's own
+    (default D^-1/2); and as many query heads as keep a grid step's
+    stacked rows at _CHUNK_FOLD_ROWS or under share the step ([n x fold, D]
+    queries against the block: 4 heads at a 256-row chunk), so the lane's
+    rows stream past Hq / fold times and not Hq. Returns [n, Hq, v_dim]."""
+    n, Hq, D = q.shape
+    fold = 1
+    while v_dim and Hq % (2 * fold) == 0 and n * 2 * fold <= _CHUNK_FOLD_ROWS:
+        fold *= 2
+    if fold > 1:
+        assert k.shape[1] == 1, k.shape
+        out = _chunk_call(
+            q.reshape(n, Hq // fold, fold, D).transpose(0, 2, 1, 3)
+            .reshape(n * fold, Hq // fold, D),
+            k, v, jnp.repeat(qpos, fold), kpos, window, live_rows, scale,
+            v_dim,
+        )
+        return out.reshape(n, fold, Hq // fold, -1).transpose(
+            0, 2, 1, 3).reshape(n, Hq, -1)
+    return _chunk_call(q, k, v, qpos, kpos, window, live_rows, scale, v_dim)
+
+
+def _chunk_call(q, k, v, qpos, kpos, window, live_rows, scale, v_dim):
     n, Hq, D = q.shape
     C, Hkv = k.shape[0], k.shape[1]
+    Dv = v_dim or D
     group = Hq // Hkv
     bk = _chunk_key_block(C)
     nb = C // bk
     blocks = jnp.reshape(
         (jnp.asarray(live_rows, jnp.int32) + bk - 1) // bk, (1,)
     )
-
     def kv_map(h, j, blocks):
         return (h // group, jnp.minimum(j, jnp.maximum(blocks[0] - 1, 0)), 0)
 
@@ -423,22 +476,25 @@ def chunk_attention(
             pl.BlockSpec((n, LANES), lambda h, j, blocks: (0, 0)),
             pl.BlockSpec((8, bk), kpos_map),
             pl.BlockSpec((1, n, D), lambda h, j, blocks: (h, 0, 0)),
-            pl.BlockSpec((1, bk, D), kv_map),
-            pl.BlockSpec((1, bk, D), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, n, D), lambda h, j, blocks: (h, 0, 0)),
+        ] + [pl.BlockSpec((1, bk, D), kv_map)] * (1 if v_dim else 2),
+        out_specs=pl.BlockSpec((1, n, Dv), lambda h, j, blocks: (h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((n, LANES), jnp.float32),
             pltpu.VMEM((n, LANES), jnp.float32),
-            pltpu.VMEM((n, D), jnp.float32),
+            pltpu.VMEM((n, Dv), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(
-            _chunk_kernel, scale=1.0 / (D**0.5), window=int(window or 0)
+            _chunk_kernel, scale=scale or 1.0 / (D**0.5),
+            window=int(window or 0), v_dim=v_dim,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Hq, n, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((Hq, n, Dv), q.dtype),
+        # (Past the default scoped VMEM only with folded heads: the
+        # scores are [n x fold, block] float32.)
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_LANE_VMEM_LIMIT) if v_dim else None,
         interpret=_interpret(),
         name="chunk_attention",
     )(
@@ -449,7 +505,7 @@ def chunk_attention(
         jnp.broadcast_to(kpos.astype(jnp.int32)[None, :], (8, C)),
         q.transpose(1, 0, 2),
         k.transpose(1, 0, 2),
-        v.transpose(1, 0, 2),
+        *(() if v_dim else (v.transpose(1, 0, 2),)),
     )
     return out.transpose(1, 0, 2)
 
@@ -604,11 +660,13 @@ def _lane_kernel(
     held_ref,  # scalar prefetch [B * pages]: lane_pages_held, flat
     slot_ref,  # scalar prefetch [B * blocks]: the block's slot in the pool
     blk_ref,  # scalar prefetch [B * blocks]: and its block of that slot
-    rowh_ref, colh_ref, colk_ref, q_ref, k_ref, v_ref, o_ref,
-    m_scr, l_scr, acc_scr, bias_scr,
-    *, scale, window, page_size, per_block, per_tile, cols,
+    rowh_ref, colh_ref, colk_ref, q_ref, k_ref, *rest,
+    scale, window, page_size, per_block, per_tile, cols, v_dim,
 ):
     del slot_ref, blk_ref  # the index maps read them
+    # `v_dim`: no v operand, the value is the key's first v_dim columns.
+    v_ref = None if v_dim else rest[0]
+    o_ref, m_scr, l_scr, acc_scr, bias_scr = rest[0 if v_dim else 1:]
     b = pl.program_id(0)
     j = pl.program_id(1)
     nj = pl.num_programs(1)
@@ -643,7 +701,7 @@ def _lane_kernel(
         def _compute():
             at = pl.ds(pl.multiple_of(t * cols, cols), cols)
             k = k_ref[0, at, :]  # [cols, D]: keys x k/v heads
-            v = v_ref[0, at, :]
+            v = k[:, :v_dim] if v_dim else v_ref[0, at, :]
             s = jax.lax.dot_general(
                 q_ref[0], k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -689,8 +747,8 @@ def _lane_kernel(
 
 
 def lane_attention(
-    q: jax.Array, k: jax.Array, v: jax.Array, meta: LaneMeta,
-    ring: bool = False,
+    q: jax.Array, k: jax.Array, v: Optional[jax.Array], meta: LaneMeta,
+    ring: bool = False, *, scale: Optional[float] = None, v_dim: int = 0,
 ) -> jax.Array:
     """A decode batch's attention, one query row a lane, over the pool as
     it lies: q [B, 1, Hq, D]; k / v [T, C, Hkv, D] with lane b in slot b
@@ -715,7 +773,12 @@ def lane_attention(
     Inside a block, all k/v heads go through the MXU together: k flat as
     [keys x kv_heads, D] against every query head, and a constant mask
     keeps each query head's own k/v head. Wasted MXU work (kv_heads
-    times) for no transpose of the pool's row."""
+    times) for no transpose of the pool's row.
+
+    A latent entry (models/layers.py LatentPages: ONE row a token,
+    Hkv == 1): `v=None` and `v_dim`, the value is the first v_dim columns
+    of the key row, so a row is fetched once for both matmuls; `scale`
+    is the mixer's own (default D^-1/2). Returns [B, 1, Hq, v_dim]."""
     assert q.shape[1] == 1, "one query row a lane"
     assert k.shape[1] % meta.page_size == 0, (k.shape, meta.page_size)
     if ring:
@@ -723,15 +786,18 @@ def lane_attention(
         # ring layers of every tick program share one trace.
         meta = meta.replace(extent=None)
     return _lane_attention(
-        q, k, v, meta, ring, _interpret(), _LANE_BLOCK_BYTES
+        q, k, v, meta, ring, _interpret(), _LANE_BLOCK_BYTES,
+        scale or 1.0 / (q.shape[3]**0.5), v_dim,
     )
 
 
 # Jitted with everything that shapes the kernel static, so that the layers
 # of one tick that share shapes (three rings) are traced and lowered once.
-@functools.partial(jax.jit, static_argnums=(4, 5, 6))
-def _lane_attention(q, k, v, meta, ring, interpret, block_bytes):
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8))
+def _lane_attention(q, k, v, meta, ring, interpret, block_bytes, scale,
+                    v_dim):
     B, _, Hq, D = q.shape
+    Dv = v_dim or D
     T, C, Hkv = k.shape[0], k.shape[1], k.shape[2]
     ps = meta.page_size
     group = Hq // Hkv
@@ -763,24 +829,23 @@ def _lane_attention(q, k, v, meta, ring, interpret, block_bytes):
             pl.BlockSpec((8, cols), const),
             pl.BlockSpec((8, cols), const),
             pl.BlockSpec((1, Hp, D), own),
-            pl.BlockSpec((1, rows, D), kv_map),
-            pl.BlockSpec((1, rows, D), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, Hp, D), own),
+        ] + [pl.BlockSpec((1, rows, D), kv_map)] * (1 if v_dim else 2),
+        out_specs=pl.BlockSpec((1, Hp, Dv), own),
         scratch_shapes=[
             pltpu.VMEM((Hp, LANES), jnp.float32),
             pltpu.VMEM((Hp, LANES), jnp.float32),
-            pltpu.VMEM((Hp, D), jnp.float32),
+            pltpu.VMEM((Hp, Dv), jnp.float32),
             pltpu.VMEM((Hp, cols), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(
-            _lane_kernel, scale=1.0 / (D**0.5), window=int(meta.window or 0),
+            _lane_kernel, scale=scale, window=int(meta.window or 0),
             page_size=ps, per_block=per_block, per_tile=per_tile, cols=cols,
+            v_dim=v_dim,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hp, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hp, Dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=_LANE_VMEM_LIMIT,
@@ -795,7 +860,7 @@ def _lane_attention(q, k, v, meta, ring, interpret, block_bytes):
         qf,
         # The pool's row [Hkv, D] is whole tiles: flat for free.
         k.reshape(T, C * Hkv, D),
-        v.reshape(T, C * Hkv, D),
+        *(() if v_dim else (v.reshape(T, C * Hkv, D),)),
     )
     return out[:, None, :Hq]
 

@@ -596,6 +596,20 @@ class ContinuousScheduler:
             "tick's extent where XLA attends the lanes, the stepped "
             "lanes' live key blocks where the decode kernel does",
         )
+        # 'latent' layers keep pages of ONE row a token (LatentPages).
+        self._m_kv_latent_rows = r.counter(
+            "serve_kv_latent_rows_read_total",
+            "Rows of one latent a token the lanes' attention read in "
+            "'latent' layers, summed over those layers: every lane up to "
+            "the tick's extent where XLA attends the lanes, the stepped "
+            "lanes' live key blocks where the decode kernel does",
+        )
+        self._m_kv_latent_chunk_keys = r.counter(
+            "serve_kv_latent_chunk_keys_total",
+            "Stored latents the prefill chunks' attention spanned in "
+            "'latent' layers (the chunk's lane up to the chunk's end), "
+            "summed over those layers",
+        )
         # The lanes' decode kernel (ops/ragged_paged_attention.py
         # lane_attention) skips by lane and by key block: how much of its
         # grid is live says how often the skip engages. Both stay 0 where
@@ -641,6 +655,8 @@ class ContinuousScheduler:
             ("ssm_rows", self._m_ssm_rows),
             ("kv_window_rows", self._m_kv_window_rows),
             ("kv_global_rows", self._m_kv_global_rows),
+            ("kv_latent_rows", self._m_kv_latent_rows),
+            ("kv_latent_chunk_keys", self._m_kv_latent_chunk_keys),
             ("ring_wraps", self._m_ring_wraps),
             ("lane_attention_blocks", self._m_lane_blocks),
             ("lane_attention_blocks_live", self._m_lane_blocks_live),

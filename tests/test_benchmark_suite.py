@@ -20,6 +20,7 @@ import pytest
 from benchmark.tests import test_ttft_stages as _ttft_stages
 from benchmark.architectures.cohere2_moe.test_reference import *  # noqa: F401,F403
 from benchmark.architectures.jamba.test_reference import *  # noqa: F401,F403
+from benchmark.architectures.kimi_k2.test_reference import *  # noqa: F401,F403
 from benchmark.architectures.kimi_linear.test_reference import *  # noqa: F401,F403
 from benchmark.architectures.prenorm_decoder.test_reference import *  # noqa: F401,F403
 from benchmark.tests.test_architectures import *  # noqa: F401,F403
@@ -34,6 +35,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 COLLECTED = (
     "benchmark/architectures/cohere2_moe/test_reference.py",
     "benchmark/architectures/jamba/test_reference.py",
+    "benchmark/architectures/kimi_k2/test_reference.py",
     "benchmark/architectures/kimi_linear/test_reference.py",
     "benchmark/architectures/prenorm_decoder/test_reference.py",
     "benchmark/tests/test_architectures.py",
@@ -48,7 +50,8 @@ COLLECTED = (
 
 # benchmark/tests/test_architectures.py was written when the benchmark had
 # ONE architecture, and its resolver test asserts that every cell's is
-# `prenorm_decoder`. PR 35 adds a second one (PR 37 a third, PR 42 a fourth), and a PR that adds to the
+# `prenorm_decoder`. PR 35 adds a second one (PR 37 a third, PR 42 a fourth, PR 45 a
+# fifth), and a PR that adds to the
 # benchmark may not edit a file the benchmark has: the same test is taken
 # here with each cell held to the architecture its own configuration file
 # names, under the same name so that it is counted once. A `benchmark` PR
@@ -94,7 +97,8 @@ def test_every_cell_resolves_its_architecture():  # noqa: F811
             for name in required:
                 assert hasattr(module, name), (part, name)
         assert set(arch.work.KERNEL_FNS) == manifest.kernel_names(arch.name)
-    assert seen == {"prenorm_decoder", "kimi_linear", "jamba", "cohere2_moe"}
+    assert seen == {"prenorm_decoder", "kimi_linear", "jamba", "cohere2_moe",
+                    "kimi_k2"}
 
 
 def test_the_stage_means_add_up_to_the_programs_ttft(window):  # noqa: F811

@@ -332,3 +332,55 @@ def test_chunk_attention_compiles_at_the_ticks_shapes(one_chip, rows, window):
     assert rpa._chunk_key_block(rows) == (896 if window else 1024)
     assert rpa.chunk_attention_eligible(256, rows, 128)
     assert not rpa.chunk_attention_eligible(256, rows, 64)
+
+
+# -- the latent serving cell's kernels (kimi-k2-7-code-serve-longctx) --------
+@pytest.mark.parametrize("extent", [32768, 2048], ids=["widest", "narrow"])
+def test_lane_attention_compiles_over_a_latent_entry(one_chip, extent):
+    """One latent layer's decode rows of one tick at Kimi-K2's widths: 32
+    lanes of 64 absorbed query heads over ONE shared key row of 640
+    columns (512 latent + 64 rope + 64 of zeros) whose first 512 are the
+    value: no v operand, and no copy of the pool in front of the kernel."""
+    lanes, rows, width, rank = 32, 32768, 640, 512
+    assert rpa.lane_attention_engaged("ragged_xla", 1, 64, 1, width, PAGE)
+
+    def decode(q, entry, lengths):
+        meta = rpa.LaneMeta(lengths=lengths, page_size=PAGE, extent=extent)
+        return rpa.lane_attention(q, entry, None, meta, scale=0.14468,
+                                  v_dim=rank)
+
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    compiled = jax.jit(decode).lower(
+        sds((lanes, 1, 64, width), BF16), sds((lanes, rows, 1, width), BF16),
+        sds((lanes,), jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "lane_attention" in text
+    assert compiled.memory_analysis().output_size_in_bytes == (
+        lanes * 64 * rank * 2)
+    pool = lanes * rows * width * 2
+    assert compiled.memory_analysis().argument_size_in_bytes < 1.01 * pool
+    assert compiled.memory_analysis().temp_size_in_bytes < pool // 100
+
+
+@pytest.mark.parametrize("chunk", [256, 512])
+def test_chunk_attention_compiles_over_a_latent_entry(one_chip, chunk):
+    """One latent layer of one tick: a chunk's rows of 64 absorbed query
+    heads over one lane's 32,768 latent rows, heads folded so that a grid
+    step holds 1,024 stacked rows: [1,024, 1,024] float32 scores, the
+    [1,024, 512] accumulator and the key block fit the raised VMEM limit."""
+    i32 = jnp.int32
+
+    def run(q, entry, qpos, kpos, live):
+        return rpa.chunk_attention(q, entry, None, qpos, kpos, None, live,
+                                   scale=0.14468, v_dim=512)
+
+    text = _compile(
+        run, one_chip, ((chunk, 64, 640), BF16), ((32768, 1, 640), BF16),
+        ((chunk,), i32), ((32768,), i32), ((), i32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "chunk_attention" in text
+    # 1,024 // chunk heads a grid step: [64 // fold, chunk x fold, 512] out
+    assert f"bf16[{64 * chunk // 1024},1024,512]" in text
+    assert rpa.chunk_attention_eligible(chunk, 32768, 640)

@@ -378,15 +378,23 @@ def test_what_cannot_share_a_state_refuses_by_name(tiny):
 
 @pytest.mark.parametrize("kind", ["kda", "latent"])
 def test_the_other_mixers_still_refuse(tiny, kind):
+    """A delta-rule layer beside the state-space ones is refused by its
+    name; a latent layer (served since PR 45: a paged entry of one latent
+    a token) no longer is."""
     import dataclasses
 
     cfg = dataclasses.replace(
         tiny["cfg"], layer_mixers=("ssm", kind) * 4, kda_head_dim=16,
         kda_num_heads=4, kv_lora_rank=16, qk_nope_head_dim=8,
         qk_rope_head_dim=8, v_head_dim=8)
-    assert cfg.recurrent_or_latent() and cfg.keeps_lane_state()
-    with pytest.raises(UnservedMixerError, match="'kda' or 'latent'"):
+    assert cfg.keeps_lane_state()
+    if kind == "latent":
+        assert not cfg.recurrent_or_latent() and not cfg.unserved_mixers()
         GenerationEngine(LuminaTransformer(cfg), {}, tiny["tok"], cfg)
+    else:
+        assert cfg.recurrent_or_latent()
+        with pytest.raises(UnservedMixerError, match=r"\['kda'\]"):
+            GenerationEngine(LuminaTransformer(cfg), {}, tiny["tok"], cfg)
     assert not tiny["cfg"].recurrent_or_latent()
 
 

@@ -345,7 +345,8 @@ def test_a_slots_bytes_by_entry_kind(tiny):
     assert k_full.shape == (3, 24, PAGE, 2, 16)   # whole pages
     row = 2 * 16 * 4 * 2  # k/v heads x head x float32, k and v
     assert pool.slot_bytes() == {
-        "pages": CAP * row, "ring": 3 * 5 * PAGE * row, "state": 0,
+        "pages": CAP * row, "ring": 3 * 5 * PAGE * row, "latent": 0,
+        "state": 0,
         "total": (CAP + 3 * 5 * PAGE) * row}
     assert (pool.ring_tables == np.arange(24) % 5).all()
     assert pool.stats()["ring_pages"] == 5
