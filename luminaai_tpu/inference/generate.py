@@ -1169,6 +1169,16 @@ class StepwiseDecoder:
         # config's prefill_chunk_size), clamped to the slot budget;
         # 0 disables, callers fall back to prefill_into_slot.
         self.prefill_chunk = _chunk_eff
+        # How the tick's held expert layers take their sorted rows back
+        # to their tokens at this program's shapes (models/moe.py
+        # held_combine_is_product): {"T", "R", "H", "product"}; None
+        # without a share of the experts. Every extent's tick has these
+        # rows, so it is a fact of the decoder, not of a step.
+        from luminaai_tpu.models.moe import held_combine_form
+
+        self.held_combine = held_combine_form(
+            engine.config, num_slots + _chunk_eff, self.model.dtype
+        )
         self.prefix_cache = None
         if arena_slots > 0:
             from luminaai_tpu.inference.prefix_cache import RadixPrefixCache

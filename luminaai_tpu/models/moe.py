@@ -382,15 +382,12 @@ class MoELayer(nn.Module):
                 # most once, so S a group never binds); the one limit is
                 # the held experts' rows together, capacity_factor x the
                 # expected N * k * count / E.
-                off, cnt = cfg.experts_held
-                rows = int(cfg.capacity_factor * G * S * k * cnt / E)
                 with jax.named_scope("moe_held"):
                     out, tokens_per_expert, dropped, ep_stats = _gmm_held(
                         x, router_probs, wi, wo, top_k=k, num_experts=E,
-                        offset=off, dtype=self.dtype, gmm_fn=_pick_gmm(),
-                        rule=rule,
-                        row_bound=-(-rows // _GMM_ROW_TILE) * _GMM_ROW_TILE,
-                        live=live,
+                        offset=cfg.experts_held[0], dtype=self.dtype,
+                        gmm_fn=_pick_gmm(), rule=rule,
+                        row_bound=held_row_bound(cfg, G * S), live=live,
                     )
             else:
                 out, tokens_per_expert, dropped = self._gmm_path(
@@ -1037,6 +1034,120 @@ def _held_gmm_tiling(m: int, k: int, n: int) -> Tuple[int, int, int]:
     return (512 if m % 512 == 0 else 128), fit(k, 768), fit(n, 1024)
 
 
+def held_row_bound(cfg, tokens: int) -> int:
+    """Rows of the held experts' sorted buffer for a program of `tokens`
+    rows (Config.experts_held): capacity_factor x the expected tokens * k *
+    held / E, rounded up to megablox's row tile."""
+    _, cnt = cfg.experts_held
+    rows = int(
+        cfg.capacity_factor * tokens * cfg.moe_top_k * cnt / cfg.num_experts
+    )
+    return -(-rows // _GMM_ROW_TILE) * _GMM_ROW_TILE
+
+
+# What held_combine_is_product weighs, a v5e's: the MXU's bf16 peak, and
+# a serial float32 scatter-add's cost a row (a fixed part, and the row read,
+# added and written back: 0.56 ms for 2,304 rows of 7,168 in kimi-k2's tick,
+# PERF.md, PR 45).
+_MXU_FLOPS = 197e12
+_SCATTER_ROW_S = 0.1e-6
+_SCATTER_BYTES_S = 400e9
+
+
+def held_combine_is_product(tokens: int, rows: int, hidden: int,
+                            dtype) -> bool:
+    """Which form takes _gmm_held's sorted rows back to their tokens: a
+    pure function of the shapes the program has (T tokens, R sorted rows,
+    H wide, the model's dtype), as lane_attention_eligible is for the
+    lanes' kernel.
+
+    As ONE product on the MXU, out[T, H] = P[T, R] . yrow[R, H], it costs
+    2.T.R.H x passes / 197 TFLOP/s (one pass where both operands are bf16,
+    six for a float32 program's HIGHEST). As a scatter-add it costs R
+    serial read-modify-writes of a float32 row: R x (0.1 us + 8.H B /
+    400 GB/s). R cancels: the product does T multiply-adds for an element
+    of a sorted row where the scatter does one slow add, so the product is
+    a TICK's form (T a few hundred rows) and the scatter a training
+    batch's. The product is taken where the estimate has it win by 3 x or
+    more (the estimate is the MXU's peak, and a backward pass is two more
+    such products): up to T = 1,114 / 1,458 / 2,081 at H 7,168 / 4,096 /
+    2,304 in bf16. Measured on the chip (PERF.md, PR 46): the served
+    ticks (T 288, R 2,304; H 7,168) 0.048 ms against the scatter's 0.71
+    with its float32 widening; kimi-linear's step (T 16,384, R 8,192,
+    H 2,304) 3.4 ms against 2.6 forward, so it keeps the scatter."""
+    passes = 1 if jnp.dtype(dtype).itemsize <= 2 else 6
+    product = 2.0 * tokens * rows * hidden * passes / _MXU_FLOPS
+    scatter = rows * (_SCATTER_ROW_S + 8.0 * hidden / _SCATTER_BYTES_S)
+    return 3.0 * product <= scatter
+
+
+def held_combine_form(cfg, tokens: int, dtype):
+    """What a program of `tokens` rows built from `cfg` in `dtype` does in
+    its held expert layers, for the gauge moe_held_combine_product: T, R,
+    H and whether the combine is the product; None without
+    Config.experts_held."""
+    if not (cfg.use_moe and cfg.experts_held):
+        return None
+    rows = held_row_bound(cfg, tokens)
+    return {
+        "T": int(tokens), "R": rows, "H": int(cfg.hidden_size),
+        "product": held_combine_is_product(
+            tokens, rows, cfg.hidden_size, dtype
+        ),
+    }
+
+
+def export_held_combine(form, registry, log) -> None:
+    """Say which form a built program's held layers have
+    (held_combine_form's answer, not None): the gauge
+    moe_held_combine_product (1 the product, 0 the scatter-add) and one
+    log line. The scheduler and the trainer call it where they build their
+    program: the form is decided when that is traced, not step by step."""
+    registry.gauge(
+        "moe_held_combine_product",
+        "1 where the held experts' rows go back to their tokens as one "
+        "product on the MXU, 0 as a scatter-add (models/moe.py "
+        "held_combine_is_product)",
+    ).set(int(form["product"]))
+    log.info(
+        "held experts' combine: T=%(T)d R=%(R)d H=%(H)d product=%(product)s",
+        form,
+    )
+
+
+def _held_combine(yrow, tok, w_row, row_kept, tokens: int, product: bool):
+    """The held experts' sorted rows back to their tokens: out[t] = the
+    float32 sum of w_row[r] * yrow[r] over the kept rows r with
+    tok[r] == t. yrow [R, H] and w_row [R] are in the model's dtype (the
+    kernel's output; the gate as _gmm_held cast it), so each product is
+    exact in float32 and the two forms differ by the order of a token's at
+    most k additions. Returns float32 [tokens, H].
+
+    The kernel's uninitialised tail (row_kept [R, 1] false) is zeroed
+    BEFORE anything multiplies it, in both forms: a NaN there times a zero
+    weight, or times a zero of P, is NaN (and for the gradient, a weight's
+    cotangent is the row itself)."""
+    y = jnp.where(row_kept, yrow, 0)
+    if not product:
+        # Training shapes: a token has at most k rows here and most have
+        # none, so a scatter-add of R << N rows, not a gather of all N
+        # pairs.
+        y = y.astype(jnp.float32) * w_row[:, None].astype(jnp.float32)
+        return jnp.zeros((tokens, y.shape[1]), jnp.float32).at[tok].add(y)
+    # A tick's shapes: P[t, r] = the weight of sorted row r where it is
+    # token t's and kept, else 0; one pass with a float32 accumulator.
+    p = jnp.where(
+        (tok[None, :] == jnp.arange(tokens)[:, None]) & row_kept.T,
+        w_row[None, :], 0,
+    )
+    return jax.lax.dot_general(
+        p, y, (((1,), (0,)), ((), ())),
+        precision=(None if y.dtype.itemsize <= 2
+                   else jax.lax.Precision.HIGHEST),
+        preferred_element_type=jnp.float32,
+    )
+
+
 def _gmm_held(x, router_probs, wi, wo, *, top_k, num_experts, offset,
               row_bound, dtype, gmm_fn, rule, live=None):
     """The grouped-matmul expert FFN of a share the configuration names
@@ -1046,11 +1157,14 @@ def _gmm_held(x, router_probs, wi, wo, *, top_k, num_experts, offset,
     excluded tail) with three differences: the sorted buffer is cut to
     `row_bound` rows (the held pairs are 1/32 of all pairs where 8 of 256
     are held; a buffer of all N would be 32 times the work), the combine
-    is a scatter-add of those rows into their tokens, and nothing is
-    psum'd. The operand masks and the kernel's uninitialised-tail
-    contract are _gmm_local's. `live` [G, S] (a serving tick): rows that
-    are no tokens go to the excluded tail with the pairs of experts held
-    elsewhere, and the pair counts are over live rows.
+    takes those rows straight back to their tokens (_held_combine: one
+    product on the MXU at a tick's sizes, where the bound is every pair; a
+    scatter-add at a training step's, where it is a fraction of them:
+    held_combine_is_product), and nothing is psum'd. The operand masks and
+    the kernel's uninitialised-tail contract are _gmm_local's. `live`
+    [G, S] (a serving tick): rows that are no tokens go to the excluded
+    tail with the pairs of experts held elsewhere, and the pair counts are
+    over live rows.
 
     Returns (out [G,S,H], tokens_per_expert [E], dropped [G,S], and the
     pair counts: routed, held (chosen for a held expert), held and not
@@ -1096,14 +1210,11 @@ def _gmm_held(x, router_probs, wi, wo, *, top_k, num_experts, offset,
     act = jnp.where(row_kept, nn.silu(gate_act) * up, 0)
     yrow = gmm_fn(act, wo.astype(dtype), group_sizes,
                   preferred_element_type=dtype, tiling=_held_gmm_tiling)
-    # Zero the kernel's uninitialised tail BEFORE the weights meet it: the
-    # product's cotangent for a weight is the row itself, and garbage
-    # times a zero cotangent is still NaN.
-    w_row = gate.reshape(-1)[perm][:, None].astype(jnp.float32)
-    yrow = jnp.where(row_kept, yrow, 0).astype(jnp.float32) * w_row
-    # A token has at most k rows here and most have none: a scatter-add of
-    # R rows in float32, not a gather of all N pairs.
-    out = jnp.zeros((G * S, H), jnp.float32).at[tok].add(yrow)
+    with jax.named_scope("moe_held_combine"):
+        out = _held_combine(
+            yrow, tok, gate.reshape(-1)[perm], row_kept, G * S,
+            held_combine_is_product(G * S, R, H, dtype),
+        )
     stats = {
         "moe_routed_pairs": routed,
         "moe_held_pairs": routed_here.astype(jnp.float32),

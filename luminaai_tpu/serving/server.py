@@ -646,6 +646,14 @@ class ContinuousScheduler:
             "Held pairs not computed: beyond the grouped matmul's static "
             "row bound (must stay 0)",
         )
+        # Which form the held layers' combine has in the tick program
+        # (no series without a share of the experts).
+        form = getattr(self.decoder, "held_combine", None)
+        if form is not None:
+            from luminaai_tpu.models.moe import export_held_combine
+
+            export_held_combine(form, r, logger)
+            self._event("moe_held_combine", **form)
         # The decoder counts these where they happen; the registry
         # follows (_count_decoder).
         self._decoder_counters = (

@@ -334,6 +334,7 @@ class Trainer:
         )
 
         self.global_step = 0
+        self._export_held_combine()
         self._last_backup_time = time.time()
         # Chinchilla-mode convergence stop (ref chinchilla_scaler's
         # ConvergenceDetector): optional early end when eval loss flattens.
@@ -521,6 +522,7 @@ class Trainer:
             "recompile", step=self.global_step,
             reason=reason or "config_change",
         )
+        self._export_held_combine()
         # A rebuilt step is a NEW timing regime: the sentinel's rolling
         # stats would flag the first post-recompile window, and the
         # watchdog would misprice the recompile stall as a hang.
@@ -1466,6 +1468,28 @@ class Trainer:
                 else {}
             ),
         )
+
+    def _export_held_combine(self) -> None:
+        """Which form the held expert layers' combine takes at the
+        microbatch's shapes, for the step as built (models/moe.py
+        held_combine_is_product): gauge, log line and a flight-recorder
+        event; nothing without Config.experts_held."""
+        from luminaai_tpu.models.moe import (
+            export_held_combine, held_combine_form,
+        )
+
+        cfg = self.config
+        form = held_combine_form(
+            cfg,
+            cfg.batch_size // max(1, cfg.gradient_accumulation_steps)
+            * cfg.seq_length,
+            self.model.dtype,
+        )
+        if form is not None:
+            export_held_combine(form, self.registry, logger)
+            self.recorder.emit(
+                "moe_held_combine", step=self.global_step, **form
+            )
 
     def _export_held_and_decay(self, scalars) -> None:
         """Held-expert pair counters and the delta rule's decay gauge, from

@@ -384,3 +384,73 @@ def test_chunk_attention_compiles_over_a_latent_entry(one_chip, chunk):
     # 1,024 // chunk heads a grid step: [64 // fold, chunk x fold, 512] out
     assert f"bf16[{64 * chunk // 1024},1024,512]" in text
     assert rpa.chunk_attention_eligible(chunk, 32768, 640)
+
+
+# -- the held experts' layer: how its sorted rows go back to their tokens ----
+def _held_layer(tokens, groups, experts, held, cf, live):
+    """models/moe.py _gmm_held over megablox at a cell's shapes: x, the
+    router's scores, the held experts' weights, the live rows."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    from luminaai_tpu.models import moe
+
+    rows = -(-int(cf * tokens * 8 * held / experts) // 128) * 128
+
+    def layer(x, probs, wi, wo, lv):
+        return moe._gmm_held(
+            x, probs, wi, wo, top_k=8, num_experts=experts, offset=0,
+            row_bound=rows, dtype=BF16, gmm_fn=gmm,
+            rule=dict(select_bias=None, renormalize=True, scale=1.0),
+            live=lv if live else None,
+        )[0]
+
+    return layer, rows
+
+
+def _held_shapes(tokens, groups, hidden, ffn, experts, held):
+    seq = tokens // groups
+    return (
+        ((groups, seq, hidden), BF16), ((groups, seq, experts), jnp.float32),
+        ((held, hidden, 2 * ffn), BF16), ((held, ffn, hidden), BF16),
+        ((groups, seq), jnp.bool_),
+    )
+
+
+@pytest.mark.parametrize("hidden,ffn,experts,held,cf", [
+    (7168, 2048, 384, 12, 32.0), (4096, 4096, 128, 16, 8.0),
+], ids=["kimi_k2_tick", "command_a_plus_tick"])
+def test_a_ticks_held_rows_return_as_a_product(one_chip, hidden, ffn,
+                                               experts, held, cf):
+    """32 lanes + a 256-row chunk, every pair a row of the sorted buffer
+    (2,304): no float32 scatter of [288, H] is left in the program, and a
+    matmul under `moe_held_combine` is (the masks fused into it)."""
+    import re
+
+    layer, rows = _held_layer(288, 1, experts, held, cf, live=True)
+    assert rows == 2304
+    text = _compile(layer, one_chip,
+                    *_held_shapes(288, 1, hidden, ffn, experts, held))
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert not re.search(rf"f32\[288,{hidden}\]\S* scatter\(", text)
+    assert re.search(
+        rf"f32\[288,{hidden}\]\S* convolution\(.*moe_held_combine/dot_general",
+        text)
+
+
+def test_a_training_steps_held_rows_return_as_a_scatter(one_chip):
+    """kimi-linear-train-8k's step, forward and backward: 16,384 tokens,
+    8,192 sorted rows, H 2,304: the shape rule keeps the float32
+    scatter-add."""
+    import re
+
+    layer, rows = _held_layer(16384, 2, 256, 8, 2.0, live=False)
+    assert rows == 8192
+
+    def loss(x, probs, wi, wo, lv):
+        return jnp.sum(layer(x, probs, wi, wo, lv).astype(jnp.float32) ** 2)
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2, 3)), one_chip,
+                    *_held_shapes(16384, 2, 2304, 1024, 256, 8))
+    assert re.search(r"f32\[16384,2304\]\S* scatter\(", text)
+    assert "moe_held_combine)/scatter-add" in text
+    assert "moe_held_combine)/dot_general" not in text
