@@ -78,8 +78,10 @@ class Config:
     #                parity tests, not CPU serving).
     # On a TPU the two strings run one program: a decode batch takes
     # lane_attention where the static shapes are eligible
-    # (lane_attention_eligible: grouped query heads, 128-wide heads) and
-    # the XLA reference otherwise; nothing else chooses.
+    # (lane_attention_eligible: the pool's layout alone, 128-wide heads
+    # in whole (8, 128) tiles a row, at any number of query heads a k/v
+    # head, MHA included) and the XLA reference otherwise; nothing else
+    # chooses.
     # The single-stream engine's rolling cache (one uniform
     # attention_window, below) always takes the dense path: its slot
     # arithmetic is mod-C, which LaneMeta does not describe. The slot-paged

@@ -527,15 +527,23 @@ def lane_attention_eligible(
     n_q: int, n_kv: int, head_dim: int, page_size: int
 ) -> bool:
     """Where `lane_attention` is the lanes' decode attention on a TPU: a
-    pure function of the shapes the dispatcher sees. A k/v head's query
-    heads are one MXU operand, so there must be enough of them (at one
-    query head a k/v head, MHA, the operand is one row and XLA's fusion
-    is the faster program); a pool row [kv_heads, head_dim] is whole
-    (8, 128) tiles or one head, so the pool flattens to [rows x kv_heads,
-    head_dim] for free; a page's score columns fill whole lanes."""
+    pure function of the shapes the dispatcher sees, every term a fact of
+    the layout: heads fill whole lanes; a pool row [kv_heads, head_dim]
+    is whole (8, 128) tiles or one head, so the pool flattens to
+    [rows x kv_heads, head_dim] for free; a page's score columns fill
+    whole lanes. `n_q` is in no term: a tile scores EVERY query head
+    against every k/v head's keys in one matmul and a constant mask keeps
+    each head's own, so one query head a k/v head (MHA) is the same
+    program as sixteen, and the MXU's time a block follows the block's
+    bytes, not the group. Measured alone on a v5e against XLA's program
+    over the [lanes, extent] slice (PERF.md section 6, PR 47; 28 lanes of
+    2,048 rows, us a layer): 16 heads over 16 with 5 lanes stepped 40-78
+    for XLA's 198-674, with 27 stepped 216 for 352, every lane full 624
+    for 674; 32 over 8, 16 over 8, 64 over 16 and 128 over 16 likewise
+    ahead at both kinds of lengths."""
+    del n_q
     return (
-        n_q // n_kv >= 8
-        and head_dim % 128 == 0
+        head_dim % 128 == 0
         and page_size % 8 == 0
         and (n_kv == 1 or n_kv % 8 == 0)
         and (page_size * n_kv) % 128 == 0
@@ -569,15 +577,25 @@ def lane_blocks(pages: int, page_size: int, n_kv: int, head_dim: int,
     pages that lie together in the pool, as many as _LANE_BLOCK_BYTES
     hold; one page where a page table is chased (`chased`), since the
     next logical page may live anywhere. A tile is what one online-softmax
-    update covers."""
+    update covers: _LANE_TILE_COLS score columns, a page at the least.
+    Where ONE page's columns pass that (16 k/v heads at pages of 128:
+    2,048), a block is as many times fewer pages: its rows are rounded up
+    a lane, and a lane of so wide a row holds few. Measured (PERF.md
+    section 6, PR 47; 16 k/v heads of 128 in bf16, a layer): 2 pages a
+    block read 27 stepped lanes of 128-768 rows in 216 us where 4 take
+    252, and 5 stepped lanes in 40 / 57 / 78 us (extents 512 / 1,024 /
+    2,048) where 4 take 45 / 61 / 76; 1 page takes 196 and 41 / 62 / 93
+    (448 grid steps a call at extent 2,048)."""
     if chased:
         return 1, 1
-    page_bytes = page_size * n_kv * head_dim * itemsize
+    page_cols = page_size * n_kv
+    page_bytes = page_cols * head_dim * itemsize
+    page_tiles = max(1, page_cols // _LANE_TILE_COLS)
     per_block = _largest_divisor(
-        pages, (block_bytes or _LANE_BLOCK_BYTES) // page_bytes
+        pages, (block_bytes or _LANE_BLOCK_BYTES) // page_bytes // page_tiles
     )
     per_tile = _largest_divisor(
-        per_block, min(8, _LANE_TILE_COLS // (page_size * n_kv))
+        per_block, min(8, _LANE_TILE_COLS // page_cols)
     )
     return per_block, per_tile
 

@@ -188,19 +188,26 @@ def test_megablox_gmm_compiles_at_expert_shapes(one_chip, transpose):
     (32, 16384, 128, 8, None, 16384, False),
     (32, 16384, 128, 8, None, 2048, False),
     (128, 2048, 20, 1, None, 1024, False),
+    (28, 2048, 16, 16, None, 512, False),
+    (28, 2048, 16, 16, None, 2048, False),
 ], ids=["command_a_ring", "command_a_whole_pages", "command_a_extent_2048",
-        "jamba_one_kv_head"])
+        "jamba_one_kv_head", "olmoe_mha_extent_512", "olmoe_mha_extent_2048"])
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
 def test_lane_attention_compiles_at_the_ticks_shapes(
         one_chip, lanes, rows, hq, hkv, window, extent, ring, kv_dtype):
     """One layer's decode rows of one tick: command-a-plus's 32 lanes of
     128 query heads over 8 k/v heads (a ring of 35 pages under the 4,096
-    window; 16,384 whole rows at the widest and at a narrow extent) and
-    Jamba2's 128 lanes of 20 query heads over one. The pool goes in as it
-    lies: no copy of it is made in front of the kernel (the program's
-    temporaries stay under a hundredth of the k/v it reads), whatever the
-    extent. int8 KV is (codes, per-row scales) dequantized in front of the
-    kernel, as models/layers.py reads it: that copy is the path's own."""
+    window; 16,384 whole rows at the widest and at a narrow extent),
+    Jamba2's 128 lanes of 20 query heads over one, and OLMoE's 28 lanes of
+    16 over 16 (MHA: a page of 128 rows is 2,048 score columns, so two
+    pages a block, 1 MB of k) at its narrowest and widest extents. The
+    pool goes in as it lies: no copy of it is made in front of the kernel
+    (the program's temporaries stay under a hundredth of the k/v it
+    reads), whatever the extent, and the kernel asks for _LANE_VMEM_LIMIT
+    of scoped VMEM, of which two k and two v blocks in flight are an
+    eighth at most. int8 KV is (codes, per-row scales) dequantized in
+    front of the kernel, as models/layers.py reads it: that copy is the
+    path's own."""
     assert rpa.lane_attention_eligible(hq, hkv, 128, PAGE)
     assert rpa.lane_attention_engaged("ragged_xla", 1, hq, hkv, 128, PAGE)
 
@@ -231,6 +238,14 @@ def test_lane_attention_compiles_at_the_ticks_shapes(
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert "lane_attention" in text
+    call = next(ln for ln in text.splitlines()
+                if 'custom_call_target="tpu_custom_call"' in ln)
+    assert f'"size":"{rpa._LANE_VMEM_LIMIT}"' in call
+    pages = rows // PAGE if ring else extent // PAGE
+    per_block, _ = rpa.lane_blocks(pages, PAGE, hkv, 128, 2)
+    assert per_block == {8: 7 if ring else 8, 1: 8, 16: 2}[hkv]
+    in_flight = 2 * 2 * per_block * PAGE * hkv * 128 * 2
+    assert in_flight <= rpa._LANE_VMEM_LIMIT // 8
     if kv_dtype == "bf16":
         pool = 2 * lanes * rows * hkv * 128 * 2
         assert compiled.memory_analysis().temp_size_in_bytes < pool // 100

@@ -7,14 +7,20 @@ whole pages and rings of pages, skipping by lane and by key block.
     0, 1, a page's edge, a block's edge, exactly the extent; a window; a
     real page table and global (slot, page) ids; a ring not yet full, full,
     wrapped once and twice, and ending inside a page (stale rows behind the
-    newest); 16 query heads a k/v head over 8, and 20 over 1;
+    newest); 16 query heads a k/v head over 8, and 20 over 1; MHA (16 over
+    16 at OLMoE's own sizes, 4 over 4) and a group of 4 (32 over 8) over
+    lengths 0 / 1 / a page's and a block's edge / the extent;
   - its plan: a lane that is not stepped and a block with no key in the
     band keep the index of the block fetched last (no DMA), and the host's
     count of it (StepwiseDecoder._kv_rows_of, the two counters) against a
     hand count for a tick of three lanes;
-  - who runs it: the rule by shape, and off the chip 'ragged' alone; the
-    lowered text of an MHA-shaped tick does not know the kernel exists.
+  - who runs it: the rule by shape (every shape a benchmark cell serves,
+    by cell), and off the chip 'ragged' alone; an MHA tick lowered for a
+    TPU calls the kernel and makes no slice of the pool; the kernel's
+    program at the other cells' shapes is the one it was (PR 47).
 """
+
+import hashlib
 
 import numpy as np
 import pytest
@@ -92,6 +98,44 @@ def test_head_groups_match_the_xla_reference(Hq, Hkv, D, ps, dtype, tol):
     meta = LaneMeta(lengths=jnp.asarray(lengths, jnp.int32), page_size=ps)
     out = lane_attention(q, k, v, meta)
     assert out.shape == q.shape and out.dtype == q.dtype
+    _close(out, ragged_paged_attention_xla(q, k, v, meta),
+           np.asarray(lengths) > 0, tol)
+
+
+# One query head a k/v head (MHA) and a group of 4, two pages a block:
+# (Hq, Hkv, D, page, dtype, tol, _LANE_BLOCK_BYTES or None for the rule's own)
+SMALL_GROUPS = {
+    # OLMoE's sizes: a page of 128 rows is 2,048 score columns, twice a
+    # tile's, so lane_blocks halves the 2 MB block's 4 pages itself.
+    "mha_16_of_16": (16, 16, 128, 128, jnp.bfloat16, 2e-2, None),
+    "mha_4_of_4": (4, 4, 32, 8, jnp.float32, 2e-5, 2 * 8 * 4 * 32 * 4),
+    "group4_32_of_8": (32, 8, 128, 16, jnp.bfloat16, 2e-2,
+                       2 * 16 * 8 * 128 * 2),
+}
+
+
+@pytest.mark.parametrize("lengths", [
+    lambda ps: [0, 1, 0, ps],                      # nothing, a row, a page
+    lambda ps: [ps + 1, ps - 1, 0, 2 * ps],        # a page's, a block's edge
+    lambda ps: [2 * ps + 1, 2 * ps - 1, 8 * ps, 0],  # and the extent
+], ids=["one_row", "page_and_block_edge", "block_edge_and_the_extent"])
+@pytest.mark.parametrize("shape", list(SMALL_GROUPS))
+def test_mha_and_small_groups_match_the_xla_reference(monkeypatch, shape,
+                                                      lengths):
+    """The block-diagonal matmul at any group: every query head against
+    every k/v head's keys, the constant mask keeps a head's own; lanes not
+    stepped (length 0) among the stepped ones."""
+    Hq, Hkv, D, ps, dtype, tol, block_bytes = SMALL_GROUPS[shape]
+    if block_bytes:
+        monkeypatch.setattr(rpa, "_LANE_BLOCK_BYTES", block_bytes)
+    C, lengths = 8 * ps, lengths(ps)
+    assert lane_blocks(8, ps, Hkv, D, jnp.dtype(dtype).itemsize)[0] == 2
+    q, k, v = _qkv(Hq + sum(lengths), 4, 4, C, Hq, Hkv, D, dtype)
+    meta = LaneMeta(lengths=jnp.asarray(lengths, jnp.int32), page_size=ps,
+                    extent=C)
+    out = lane_attention(q, k, v, meta)
+    assert out.shape == q.shape and out.dtype == q.dtype
+    assert np.isfinite(np.asarray(out, np.float32)).all()
     _close(out, ragged_paged_attention_xla(q, k, v, meta),
            np.asarray(lengths) > 0, tol)
 
@@ -214,23 +258,73 @@ def test_a_step_that_reads_nothing_keeps_the_last_blocks_index(
         lane_pages_held(np.asarray([0, 40, 0, 100]), PS, PAGES, 20, xp=np))
 
 
-def test_the_rule_is_the_shapes_and_off_the_chip_ragged_alone(monkeypatch):
-    # command-a-plus and Jamba2 are served through it, OLMoE (MHA) is not
-    assert lane_attention_eligible(128, 8, 128, 128)
-    assert lane_attention_eligible(20, 1, 128, 128)
-    assert not lane_attention_eligible(16, 16, 128, 128)
-    assert not lane_attention_eligible(16, 8, 64, 128)   # the flagship preset
+# Every shape a cell of BENCHMARK.json serves, by cell: (query heads, k/v
+# heads, head size, page). The rule is the pool's layout alone (PR 47 took
+# the group term out on a measurement: PERF.md section 6).
+SERVED = {
+    "olmoe-serve-chat": (16, 16, 128, 128),            # MHA
+    "olmoe-serve-batch": (16, 16, 128, 128),
+    "jamba2-3b-serve-burst": (20, 1, 128, 128),        # one k/v head
+    "command-a-plus-serve-mixed": (128, 8, 128, 128),  # 16 a k/v head
+    "kimi-k2-7-code-serve-longctx": (64, 1, 640, 128),  # one latent row
+    "mistral-7b-v03, were it served": (32, 8, 128, 128),  # a group of 4
+}
+NOT_BY_LAYOUT = {
+    "the flagship preset: half a lane a head": (16, 8, 64, 128),
+    "half a tile a row": (64, 4, 128, 128),
+    "two k/v heads": (16, 2, 128, 128),
+    "an unaligned page": (128, 8, 128, 12),
+    "a page short of whole lanes": (16, 8, 128, 8),
+}
+
+
+@pytest.mark.parametrize("cell", list(SERVED))
+def test_the_rule_serves_every_cells_shape(monkeypatch, cell):
+    shape = SERVED[cell]
+    assert lane_attention_eligible(*shape)
     # on the CPU: 'ragged' interprets it at any shape, 'ragged_xla' never
-    assert lane_attention_engaged("ragged", 1, 16, 16, 128, 128)
-    assert not lane_attention_engaged("ragged_xla", 1, 128, 8, 128, 128)
-    # on a TPU: both strings, by the shapes
+    assert lane_attention_engaged("ragged", 1, *shape)
+    assert not lane_attention_engaged("ragged_xla", 1, *shape)
+    # on a TPU: both strings, one query row a lane
     monkeypatch.setattr(rpa, "_interpret", lambda: False)
     for backend in ("ragged", "ragged_xla"):
-        assert lane_attention_engaged(backend, 1, 128, 8, 128, 128)
-        assert lane_attention_engaged(backend, 1, 20, 1, 128, 128)
-        assert not lane_attention_engaged(backend, 1, 16, 16, 128, 128)
-        assert not lane_attention_engaged(backend, 2, 128, 8, 128, 128)
-    assert not lane_attention_engaged("dense", 1, 128, 8, 128, 128)
+        assert lane_attention_engaged(backend, 1, *shape)
+        assert not lane_attention_engaged(backend, 2, *shape)
+    assert not lane_attention_engaged("dense", 1, *shape)
+
+
+@pytest.mark.parametrize("why", list(NOT_BY_LAYOUT))
+def test_the_rule_is_the_layout_and_off_the_chip_ragged_alone(monkeypatch,
+                                                              why):
+    shape = NOT_BY_LAYOUT[why]
+    assert not lane_attention_eligible(*shape)
+    assert lane_attention_engaged("ragged", 1, *shape)  # interpreted
+    monkeypatch.setattr(rpa, "_interpret", lambda: False)
+    for backend in ("ragged", "ragged_xla"):
+        assert not lane_attention_engaged(backend, 1, *shape)
+
+
+def test_the_number_of_query_heads_is_in_no_term():
+    for n_kv in (1, 8, 16, 32):
+        assert all(lane_attention_eligible(g * n_kv, n_kv, 128, 128)
+                   for g in (1, 2, 4, 7, 8, 16))
+
+
+@pytest.mark.parametrize("pages,row,want", [
+    # OLMoE (16 k/v heads: a page is two tiles' columns): 2 pages a block
+    (4, (16, 128), (2, 1)), (8, (16, 128), (2, 1)), (16, (16, 128), (2, 1)),
+    # command-a-plus: 8 pages of whole pages, a ring of 35 in 5 x 7
+    (128, (8, 128), (8, 1)), (16, (8, 128), (8, 1)), (35, (8, 128), (7, 1)),
+    # Jamba2: the extent whole, tiles of 8 pages
+    (8, (1, 128), (8, 8)), (16, (1, 128), (16, 8)),
+    # the latent entry: 12 pages would fit, 8 divide the extent
+    (256, (1, 640), (8, 8)), (16, (1, 640), (8, 8)),
+], ids=lambda x: str(x).replace(" ", ""))
+def test_blocks_follow_the_rows_bytes_as_they_did(pages, row, want):
+    """lane_blocks at every served row, bf16: what the parent gave for the
+    shapes it ran (PR 47 may not move them), and OLMoE's."""
+    assert lane_blocks(pages, 128, *row, 2) == want
+    assert lane_blocks(pages, 128, *row, 2, chased=True) == (1, 1)
 
 
 class _Tok:
@@ -313,25 +407,132 @@ def test_smaller_blocks_skip_inside_a_lane(monkeypatch):
     assert dec.kv_window_rows == 2 * (1 + 3) * 4
 
 
-def test_an_mha_tick_lowers_to_the_text_it_lowered_to(monkeypatch):
-    """OLMoE's shape (one query head a k/v head): on a TPU neither string
-    engages the kernel, so the tick is XLA's program, the text a CPU lowers
-    under 'ragged_xla', with no kernel in it; a grouped model's tick on a
-    TPU does have it."""
-    mha = dict(num_heads=4, num_kv_heads=4, layer_windows=None,
-               attention_backend="ragged_xla")
+def test_the_host_counts_an_mha_tick_of_three_lanes(monkeypatch):
+    """One query head a k/v head, three full layers, pages of 4 rows:
+    lane 0 holds 3 rows, lane 1 30, lane 2 is never stepped; the tick's
+    extent is 32 rows = 8 pages, two pages a block (4 blocks a lane). By
+    hand, a layer: lane 0 fetches its first block (8 rows), lane 1 all
+    four (32 rows), lane 2 none: 5 of 12 grid steps live, 40 rows."""
+    mha = dict(num_heads=4, num_kv_heads=4, layer_windows=None)
+    monkeypatch.setattr(rpa, "_LANE_BLOCK_BYTES", 2 * 4 * 4 * 8 * 4)
+    dec = _decoder(**mha)
+    assert dec._lane_kernel and dec.pool.ring_pages == 0
+    assert lane_blocks(8, 4, 4, 8, 4) == (2, 2)
+    pos = np.asarray([2, 29, 11], np.int32)
+    live = np.asarray([True, True, False])
+    dec._kv_rows_of(32, pos, live, None)
+    assert dec.kv_global_rows == 3 * 40 and dec.kv_window_rows == 0
+    assert dec.lane_attention_blocks == 3 * 12
+    assert dec.lane_attention_blocks_live == 3 * 5
+    # XLA's count of the same tick: every lane up to the extent
+    xla = _decoder(attention_backend="ragged_xla", **mha)
+    assert not xla._lane_kernel
+    xla._kv_rows_of(32, pos, live, None)
+    assert xla.kv_global_rows == 3 * 3 * 32
+    assert xla.lane_attention_blocks == xla.lane_attention_blocks_live == 0
 
-    def text(dec):
+
+def _wide_decoder(heads, kv_heads):
+    """Heads of 128 in pages of 16 rows (eligible by the layout at 8 k/v
+    heads), two layers, three lanes of 256 rows, bf16."""
+    cfg = Config(
+        vocab_size=64, hidden_size=128 * heads, num_layers=2,
+        num_heads=heads, num_kv_heads=kv_heads, intermediate_size=64,
+        seq_length=256, precision="bf16", use_flash_attention=False,
+        use_stable_embedding=False, scan_layers=False, prefill_chunk_size=8,
+        attention_backend="ragged_xla", max_new_tokens=8,
+    )
+    cfg.validate()
+    model = LuminaTransformer(cfg)
+    params = unbox(jax.jit(model.init)(
+        jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    engine = GenerationEngine(model, params, _Tok(), cfg)
+    return engine.make_stepwise(num_slots=3, page_size=16,
+                                max_slot_tokens=256, prefill_chunk_tokens=8)
+
+
+def test_an_mha_tick_on_a_tpus_rule_calls_the_kernel_and_slices_no_pool(
+        monkeypatch):
+    """OLMoE's kind of shape (one query head a k/v head, heads of 128):
+    lowered for a TPU the tick's decode rows go through lane_attention,
+    one lowering for both layers, over the pool as it lies; the CPU's
+    program under 'ragged_xla' is XLA's, which slices every layer's k and
+    v to the tick's extent first and knows no kernel."""
+
+    def tick(dec, platform):
         dec._active[:] = True
         dec._pos[:] = 9
         fn, args = dec.step_fn_and_args(GREEDY_SAMPLE_KEY)
-        return fn.lower(*args).as_text()
+        return fn.trace(*args).lower(lowering_platforms=(platform,)).as_text()
 
-    on_cpu = text(_decoder(**mha))
+    pool = "tensor<3x256x8x128xbf16>"
+
+    def extent_slices(text):
+        return [ln for ln in text.splitlines()
+                if "stablehlo.slice" in ln and f"({pool})" in ln]
+
+    on_cpu = tick(_wide_decoder(8, 8), "cpu")
+    assert "lane_attention" not in on_cpu and "tpu_custom_call" not in on_cpu
+    assert len(extent_slices(on_cpu)) == 2 * 2  # k and v, two layers
     monkeypatch.setattr(rpa, "_interpret", lambda: False)
-    as_on_a_tpu = _decoder(**mha)
-    assert not as_on_a_tpu._lane_kernel
-    assert text(as_on_a_tpu) == on_cpu and "lane_attention" not in on_cpu
-    # (grouped heads at these tiny widths are not eligible either: the
-    # rule is the shapes, not the string)
+    dec = _wide_decoder(8, 8)
+    assert dec._lane_kernel
+    on_tpu = tick(dec, "tpu")
+    assert on_tpu.count("tpu_custom_call") == 1 and "lane_attention" in on_tpu
+    assert extent_slices(on_tpu) == []
+    # (the tiny heads of the other tests are not eligible on a TPU, MHA or
+    # grouped: the rule is the layout, not the string)
     assert not _decoder(attention_backend="ragged_xla")._lane_kernel
+
+
+# The kernel's program (its jaxpr as text: the plan, the grid, the index
+# maps, the kernel body; no source locations) at the shapes the other three
+# served configurations run, as the parent of PR 47 traced it: (lanes, rows a
+# lane, query heads, k/v heads, row width, window, extent, ring, v_dim,
+# scale) -> sha256. A change of lane_blocks' result or of _lane_attention's
+# static arguments for these shapes changes the text.
+AS_IT_WAS = {
+    "command_a_ring": (
+        (32, 35 * 128, 128, 8, 128, 4096, None, True, 0, None),
+        "f396f86a29272c92"),
+    "command_a_whole_pages": (
+        (32, 16384, 128, 8, 128, None, 16384, False, 0, None),
+        "ce7614e068e8eacf"),
+    "command_a_extent_2048": (
+        (32, 16384, 128, 8, 128, None, 2048, False, 0, None),
+        "76758399be4f3079"),
+    "jamba_extent_1024": (
+        (128, 2048, 20, 1, 128, None, 1024, False, 0, None),
+        "3a19117abb6ef2c0"),
+    "jamba_extent_2048": (
+        (128, 2048, 20, 1, 128, None, 2048, False, 0, None),
+        "3276d2b4334373a5"),
+    "latent_widest": (
+        (32, 32768, 64, 1, 640, None, 32768, False, 512, 0.14468),
+        "5bca9c6c1e7a4f19"),
+    "latent_extent_2048": (
+        (32, 32768, 64, 1, 640, None, 2048, False, 512, 0.14468),
+        "59bd186d6a475612"),
+}
+
+
+@pytest.mark.parametrize("case", list(AS_IT_WAS))
+def test_the_other_cells_kernel_is_the_program_it_was(monkeypatch, case):
+    (lanes, rows, hq, hkv, d, window, extent, ring, v_dim, scale), want = (
+        AS_IT_WAS[case])
+    monkeypatch.setattr(rpa, "_interpret", lambda: False)
+
+    def decode(q, k, v, lengths, table):
+        meta = LaneMeta(lengths=lengths, window=window, page_size=128,
+                        extent=extent, ring_table=table if ring else None)
+        if v_dim:
+            return lane_attention(q, k, None, meta, scale=scale, v_dim=v_dim)
+        return lane_attention(q, k, v, meta, ring=ring)
+
+    sds = jax.ShapeDtypeStruct
+    kv = sds((lanes, rows, hkv, d), jnp.bfloat16)
+    text = str(jax.make_jaxpr(decode)(
+        sds((lanes, 1, hq, d), jnp.bfloat16), kv, kv,
+        sds((lanes,), jnp.int32), sds((lanes, 128), jnp.int32)))
+    assert "lane_attention" in text
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == want

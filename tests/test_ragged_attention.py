@@ -176,10 +176,13 @@ def test_dispatcher_gating():
     'ragged_xla' alike; off it only 'ragged' runs it, interpreted, so a
     CPU program under 'ragged_xla' is the XLA reference's (interpret
     mode costs interpreter time) and 'dense' never sees it."""
+    # the cells' shapes: command-a-plus, Jamba2, the latent entry, OLMoE
     assert lane_attention_eligible(128, 8, 128, 128)
     assert lane_attention_eligible(20, 1, 128, 128)
-    assert not lane_attention_eligible(16, 16, 128, 128)  # MHA
-    assert not lane_attention_eligible(32, 8, 128, 128)  # group of 4
+    assert lane_attention_eligible(64, 1, 640, 128)
+    assert lane_attention_eligible(16, 16, 128, 128)  # MHA, since PR 47
+    assert lane_attention_eligible(32, 8, 128, 128)  # a group of 4
+    # the layout's terms, and nothing else
     assert not lane_attention_eligible(128, 8, 64, 128)  # half a lane
     assert not lane_attention_eligible(128, 8, 128, 12)  # unaligned page
     assert not lane_attention_eligible(64, 4, 128, 128)  # half a tile a row

@@ -22,7 +22,11 @@ attention and the expert layer; sigmoid top-2 of 8 experts with 4 held and
     still in some query's band (the ring table against a store of
     positions, for many windows, chunks and page sizes);
   - what a slot holds, by entry kind; what a ring cannot honour, refused
-    by name; the counters the tick and the host feed.
+    by name; the counters the tick and the host feed;
+  - the same recipe over an MHA stack of OLMoE's kind (pre-norm, RoPE,
+    all-expert layers; benchmark/architectures/prenorm_decoder): its lanes'
+    rows through lane_attention against the reference at every row, and
+    the control that fails (a lane's length off by one) (PR 47).
 """
 
 import threading
@@ -526,5 +530,76 @@ def test_the_lanes_kernel_serves_the_same_rows(tiny, monkeypatch):
         assert err > 1e-2 and at >= WINDOW, (name, err, at)
     # The third slot was never stepped: of the grid's steps (3 lanes x key
     # blocks a layer) under two thirds fetched anything.
+    assert 0 < dec.lane_attention_blocks_live
+    assert 3 * dec.lane_attention_blocks_live < 2 * dec.lane_attention_blocks
+
+
+# -- one query head a k/v head (OLMoE's kind of stack) through the kernel ----
+PRENORM = manifest.Architecture("prenorm_decoder")
+
+
+@pytest.fixture(scope="module")
+def tiny_mha():
+    """OLMoE in small: a pre-norm RoPE stack of three all-expert layers
+    (top-2 of 8 renormalised, sort dispatch with a dropless capacity), 4
+    query heads of 8 each over its own k/v head, whole pages alone."""
+    cfg = Config(
+        vocab_size=VOCAB, hidden_size=32, num_layers=3, num_heads=4,
+        num_kv_heads=4, intermediate_size=48, seq_length=CAP, use_moe=True,
+        moe_pattern="all", num_experts=8, moe_top_k=2, moe_dispatch="sort",
+        capacity_factor=4.0, routing_noise_std=0.0, precision="fp32",
+        use_flash_attention=False, use_stable_embedding=False,
+        scan_layers=False, prefill_chunk_size=6, attention_backend="ragged",
+        init_std=0.3, max_new_tokens=16, tie_word_embeddings=True,
+    )
+    cfg.validate()
+    model = LuminaTransformer(cfg)
+    params = unbox(jax.jit(model.init)(
+        jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"])
+
+    def reference(ids):
+        return np.asarray(PRENORM.reference.forward(
+            PRENORM.adapter.params_view(cfg, params), jnp.asarray(ids)[None],
+            eps=cfg.rms_norm_eps, theta=cfg.rope_theta, top_k=2,
+            combine="renormalised"))[0]
+
+    return dict(cfg=cfg, model=model, params=params, reference=reference)
+
+
+@pytest.mark.parametrize("off_by", [0, 1], ids=["as_served", "length_off_by_one"])
+def test_an_mha_stacks_lanes_go_through_the_kernel_at_every_row(
+        tiny_mha, monkeypatch, off_by):
+    """The lanes' decode rows of an MHA stack through lane_attention
+    (interpreted: backend 'ragged'), the rule PR 47 opened to it: two
+    lanes of different lengths beside a slot that is never stepped, the
+    cached path's logits against the plain reference at every row. The
+    control: the kernel told each lane holds one row fewer (its query no
+    longer sees its own key) is another model."""
+    from luminaai_tpu.ops import ragged_paged_attention as rpa
+
+    calls = []
+    kernel = rpa.lane_attention
+
+    def counted(q, k, v, meta, ring=False):
+        calls.append((q.shape[2], k.shape[2], k.shape[1]))
+        meta = meta.replace(lengths=jnp.maximum(meta.lengths - off_by, 0))
+        return kernel(q, k, v, meta, ring=ring)
+
+    monkeypatch.setattr(rpa, "lane_attention", counted)
+    requests = [("a", _prompt(8, 43), 0), ("b", _prompt(9, 11), 3)]
+    dec, rows, seqs = serve(tiny_mha, requests, slots=3, chunk=6)
+    assert dec._lane_kernel and dec.pool.ring_pages == 0
+    assert set(calls) == {(4, 4, CAP)}  # every layer, the pool unsliced
+    for name, prompt, _ in requests:
+        assert sorted(p for p, _ in rows[name]) == list(range(CAP))
+        # the chunk's rows are XLA's on both sides: the lanes' rows tell
+        stepped = [(p, got) for p, got in rows[name] if p >= len(prompt)]
+        err, at = worst_row(tiny_mha, stepped, seqs[name][:CAP])
+        if off_by:
+            assert err > 1e-2, (name, err, at)
+        else:
+            assert err < 1e-4, (name, err, at)
+            err, _ = worst_row(tiny_mha, rows[name], seqs[name][:CAP])
+            assert err < 1e-4, (name, err)
     assert 0 < dec.lane_attention_blocks_live
     assert 3 * dec.lane_attention_blocks_live < 2 * dec.lane_attention_blocks
