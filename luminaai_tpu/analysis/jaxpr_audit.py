@@ -46,7 +46,6 @@ __all__ = [
     "detect_host_transfers",
     "enumerate_collectives",
     "audit_ep_dispatch",
-    "audit_grad_reduce",
     "jaxpr_signature",
 ]
 
@@ -261,14 +260,6 @@ def enumerate_collectives(closed_jaxpr) -> Dict[str, Any]:
                 }
                 if name == "all_to_all":
                     rec["stage"] = _a2a_stage(params)
-                elif name in (
-                    "psum", "reduce_scatter", "all_gather"
-                ) and params.get("axis_index_groups"):
-                    # The hierarchical gradient sync factors ONE axis
-                    # the same way the a2a dispatch does: contiguous
-                    # groups = the in-host tier, strided rails = the
-                    # DCN tier (parallel/grad_reduce.py).
-                    rec["stage"] = _a2a_stage(params)
                 ops.append(rec)
             stack.extend(_iter_sub_jaxprs(eqn.params))
     counts: Dict[str, int] = {}
@@ -431,164 +422,6 @@ def audit_ep_dispatch(registry=None) -> Dict[str, Any]:
         )
         g.labels(path="a2a").set(float(a2a_dcn))
         g.labels(path="replicated_gather").set(float(gather_dcn))
-    except Exception:  # pragma: no cover
-        pass
-    return out
-
-
-def audit_grad_reduce(registry=None) -> Dict[str, Any]:
-    """Price the hierarchical gradient sync against the flat GSPMD
-    baseline on a simulated dcn×ici CPU mesh — abstractly (make_jaxpr
-    over the full train step, nothing executes), so bench --smoke can
-    embed the comparison without hardware.
-
-    Four train-step programs are traced on the same 8-shard data mesh
-    (dcn2 × ici4 factoring): grad_reduce flat/hierarchical × grad
-    accumulation off/on. The census pins the structural claim:
-
-      - flat: ZERO explicit collectives — GSPMD inserts the gradient
-        all-reduce at partition time, invisible to the jaxpr (and free
-        to psum inside the accumulation scan). Its DCN cost is the
-        analytic full-width ring: 2 x (dcn-1)/dcn x fp32 grad bytes.
-      - hierarchical: the sync's reduce_scatter / grouped-psum /
-        all_gather appear explicitly, classified per tier by their
-        axis_index_groups signature; inside the scan only scalar
-        loss-normalization psums remain. DCN bytes = the stage='dcn'
-        psum payloads x 2(dcn-1)/dcn — 1/ici_tier of the flat payload.
-
-    The acceptance pin (CI-asserted via extras.grad_reduce):
-    hier_dcn_bytes strictly below flat_dcn_bytes."""
-    import dataclasses as _dc
-
-    import jax
-    import jax.numpy as jnp
-
-    from luminaai_tpu.models.transformer import LuminaTransformer
-    from luminaai_tpu.parallel.mesh import build_mesh
-    from luminaai_tpu.parallel.sharding import (
-        make_init_fn,
-        state_shardings,
-        unbox,
-    )
-    from luminaai_tpu.parallel.train_step import make_train_step
-    from luminaai_tpu.training.optimizer import make_optimizer, make_schedule
-
-    n = jax.device_count()
-    if n < 4 or n % 2:
-        return {
-            "available": False,
-            "reason": f"needs >= 4 devices for a dcn tier (have {n})",
-        }
-    dp = min(8, n)
-    dcn = 2
-    base = audit_config(
-        batch_size=2 * dp,
-        data_parallel_size=dp,
-        use_moe=False,
-        grad_reduce="hierarchical",
-        gradient_dcn_size=dcn,
-        grad_reduce_overlap_chunks=2,
-        scan_layers=False,
-    )
-
-    def census(cfg):
-        model = LuminaTransformer(cfg)
-        schedule = make_schedule(cfg, 100)
-        tx = make_optimizer(cfg, 100, schedule)
-        mesh = build_mesh(cfg, jax.devices()[:dp])
-        shardings = state_shardings(cfg, model, tx, mesh)
-        abstract_state = jax.eval_shape(
-            make_init_fn(cfg, model, tx), jax.random.key(0)
-        )
-        step = make_train_step(cfg, model, shardings, mesh, schedule, tx)
-        batch = {
-            "input_ids": jax.ShapeDtypeStruct(
-                (cfg.batch_size, cfg.seq_length), jnp.int32
-            )
-        }
-        closed = jax.make_jaxpr(step.jitted)(abstract_state, batch)
-        rec = enumerate_collectives(closed)
-        rec["grad_elems"] = sum(
-            int(l.size)
-            for l in jax.tree.leaves(unbox(abstract_state.params))
-        )
-        return rec
-
-    variants: Dict[str, Any] = {}
-    for mode in ("flat", "hierarchical"):
-        for accum in (1, 2):
-            cfg = _dc.replace(
-                base,
-                grad_reduce=mode,
-                gradient_accumulation_steps=accum,
-                micro_batch_size=None,
-            )
-            variants[f"{mode}/accum{accum}"] = census(cfg)
-
-    hier = variants["hierarchical/accum1"]
-    grad_bytes = hier["grad_elems"] * 4
-    off_host = (dcn - 1) / dcn
-    hier_dcn = sum(
-        int(2 * rec["payload_bytes"] * off_host)
-        for rec in hier["ops"]
-        if rec["primitive"] == "psum" and rec.get("stage") == "dcn"
-    )
-    flat_dcn = int(2 * grad_bytes * off_host)
-
-    from luminaai_tpu.parallel.grad_reduce import make_grad_reduce_plan
-
-    plan = make_grad_reduce_plan(
-        grad_elems=hier["grad_elems"],
-        data_size=dp,
-        fsdp_size=1,
-        dcn_size=dcn,
-        bucket_mb=base.grad_reduce_bucket_mb,
-        overlap_chunks=base.grad_reduce_overlap_chunks,
-        dcn_dtype=base.grad_reduce_dcn_dtype,
-    )
-    out = {
-        "available": True,
-        "mesh": {"devices": n, "data": dp, "dcn": dcn, "ici": dp // dcn},
-        "grad_bytes": grad_bytes,
-        "plan": plan.to_dict(),
-        "variants": {
-            name: {
-                "counts": rec["counts"],
-                "bytes_by_primitive": rec["bytes_by_primitive"],
-            }
-            for name, rec in variants.items()
-        },
-        "hier_stages": {
-            stage: sum(
-                rec["payload_bytes"]
-                for rec in hier["ops"]
-                if rec.get("stage") == stage
-            )
-            for stage in ("ici", "dcn")
-        },
-        "hier_dcn_bytes": hier_dcn,
-        "flat_dcn_bytes": flat_dcn,
-        "hier_below_flat": bool(hier_dcn < flat_dcn),
-        "note": (
-            "abstract traces on a simulated dcn2 mesh: hierarchical dcn "
-            "bytes = stage='dcn' grouped-psum payloads x 2(dcn-1)/dcn; "
-            "flat baseline = the implicit GSPMD all-reduce's analytic "
-            "full-width ring (its collectives never reach the jaxpr, "
-            "which is itself part of the pin: flat counts are zero)"
-        ),
-    }
-    try:
-        from luminaai_tpu.monitoring.telemetry import get_registry
-
-        reg = registry or get_registry()
-        g = reg.gauge(
-            "grad_reduce_audit_dcn_bytes",
-            "DCN-crossing gradient-sync payload bytes per step at last "
-            "grad-reduce audit",
-            labelnames=("path",),
-        )
-        g.labels(path="hierarchical").set(float(hier_dcn))
-        g.labels(path="flat").set(float(flat_dcn))
     except Exception:  # pragma: no cover
         pass
     return out

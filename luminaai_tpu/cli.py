@@ -1162,27 +1162,6 @@ def cmd_diagnose(args) -> int:
                 print(f"    {k}: {v}")
     except Exception as e:
         print(f"expert-a2a probe unavailable: {e}")
-    # Gradient-reduction rung: a REAL timed two-stage (reduce-scatter →
-    # rail psum → all-gather) bucketed gradient sync over the same
-    # dcn×ici probe factorization (parallel/grad_reduce.py) — what a
-    # grad_reduce='hierarchical' optimizer step's sync costs on this
-    # fleet, exported as diagnose_grad_reduce_seconds{stage} gauges.
-    try:
-        from luminaai_tpu.parallel.grad_reduce import grad_reduce_probe
-
-        gr = grad_reduce_probe()
-        print("[grad-reduce]")
-        print(
-            f"  mesh: world={gr['world']} (dcn={gr['dcn']} x "
-            f"ici={gr['ici']}"
-            f"{', simulated dcn' if gr.get('simulated_dcn') else ''})"
-        )
-        for stage, rec in gr["stages"].items():
-            print(f"  {stage}:")
-            for k, v in rec.items():
-                print(f"    {k}: {v}")
-    except Exception as e:
-        print(f"grad-reduce probe unavailable: {e}")
     try:
         print(f"recommended preset for this fleet: {recommend_preset()}")
         if args.preset:

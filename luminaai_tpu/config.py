@@ -362,39 +362,6 @@ class Config:
     tensor_parallel_size: int = 1
     sequence_parallel_size: int = 1
     use_ring_attention: bool = False  # required when sequence_parallel_size > 1
-    # --- Gradient reduction across the data/fsdp axes ---------------------
-    # 'flat' = whatever GSPMD emits: implicit all-reduces at full fp32
-    # width, re-issued wherever the partitioner places them (invisible to
-    # the comms auditor, and under grad accumulation free to psum inside
-    # the scan). 'hierarchical' = the explicit shard_map gradient-sync
-    # stage (parallel/grad_reduce.py): gradients accumulate shard-locally
-    # in fp32 through the whole accumulation scan, then ONE post-scan
-    # sync flattens them into size-bucketed chunks, reduce-scatters over
-    # the ici tier, crosses DCN once per bucket, and all-gathers back —
-    # the Scalable-pjit / X-MoE two-tier cure for cross-host reduction
-    # (docs/parallelism.md "Hierarchical gradient reduction").
-    grad_reduce: str = "flat"
-    # hierarchical only: how much of the DATA axis spans the DCN tier
-    # (hosts). data_parallel_size must be divisible; 1 = single-stage
-    # fallback (one explicit reduce-scatter/all-gather, everything on
-    # ICI). Mirrors expert_dcn_size for the a2a expert dispatch.
-    gradient_dcn_size: int = 1
-    # hierarchical only: target bucket size for the flattened-gradient
-    # chunks. Smaller buckets start crossing DCN earlier (more overlap
-    # with the optimizer's wait), bigger buckets amortize latency.
-    grad_reduce_bucket_mb: float = 32.0
-    # hierarchical only: minimum number of buckets, so bucket k's DCN
-    # hop is data-independent of bucket k-1's all-gather and XLA's
-    # latency-hiding scheduler overlaps them. 1 disables the floor
-    # (bucket count then comes from grad_reduce_bucket_mb alone).
-    grad_reduce_overlap_chunks: int = 2
-    # hierarchical only: None = fp32 end to end; 'bf16' compresses the
-    # DCN hop only (in-host accumulation stays fp32 — each shard's
-    # scattered chunk is already the full in-host sum before it is cast
-    # down). Parity-gated: the fp32 default is loss-trajectory-exact vs
-    # the implicit path, bf16-over-DCN trades that for half the DCN
-    # bytes (tests/test_grad_reduce.py pins both behaviours).
-    grad_reduce_dcn_dtype: Optional[str] = None
     allow_split_physical_axes: bool = False
     multihost: bool = False  # call jax.distributed.initialize()
     coordinator_address: Optional[str] = None
@@ -1058,51 +1025,6 @@ class Config:
             assert self.num_experts % self.expert_parallel_size == 0, (
                 "num_experts must divide evenly over expert_parallel_size"
             )
-        assert self.grad_reduce in ("flat", "hierarchical"), (
-            f"invalid grad_reduce {self.grad_reduce}"
-        )
-        assert self.gradient_dcn_size >= 1, "gradient_dcn_size must be >= 1"
-        assert self.grad_reduce_overlap_chunks >= 1, (
-            "grad_reduce_overlap_chunks must be >= 1"
-        )
-        assert self.grad_reduce_bucket_mb > 0, (
-            "grad_reduce_bucket_mb must be positive"
-        )
-        assert self.grad_reduce_dcn_dtype in (None, "bf16"), (
-            f"invalid grad_reduce_dcn_dtype {self.grad_reduce_dcn_dtype}"
-        )
-        if self.grad_reduce == "hierarchical":
-            # The explicit sync runs the WHOLE grad computation inside a
-            # partial-auto shard_map manual over (data, fsdp). Nested
-            # manual regions over other axes (the gmm/a2a expert
-            # dispatches, ring attention's sequence shard_map, the 1F1B
-            # pipe region) cannot nest inside it on this jax line — the
-            # auto-GSPMD dispatch modes (sort/gather/einsum) and auto
-            # tensor/expert axes compose fine.
-            for name, size in (
-                ("pipeline", self.pipeline_parallel_size),
-                ("sequence", self.sequence_parallel_size),
-            ):
-                assert size == 1, (
-                    f"grad_reduce='hierarchical' composes with data/fsdp/"
-                    f"expert/tensor mesh axes only ({name}_parallel_size="
-                    f"{size}); use grad_reduce='flat' there"
-                )
-            if self.use_moe:
-                assert self.moe_dispatch not in ("gmm", "a2a"), (
-                    f"grad_reduce='hierarchical' cannot nest the "
-                    f"moe_dispatch='{self.moe_dispatch}' shard_map inside "
-                    "its manual (data, fsdp) region; use 'sort'/'gather'/"
-                    "'einsum' dispatch or grad_reduce='flat'"
-                )
-            if self.data_parallel_size > 0:
-                assert (
-                    self.data_parallel_size % self.gradient_dcn_size == 0
-                ), (
-                    f"gradient_dcn_size ({self.gradient_dcn_size}) must "
-                    f"divide data_parallel_size "
-                    f"({self.data_parallel_size})"
-                )
 
     # -- derived quantities (ref config_manager.py:234,505,572) ----------
     def head_dim(self) -> int:

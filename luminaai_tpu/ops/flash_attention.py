@@ -552,9 +552,9 @@ def flash_attention_on_mesh(q, k, v, mesh, q_spec, kv_spec, **kw):
     parallelism is ring attention's job. Heads are split only when q and
     kv heads split the same way, so every local q head still finds its kv
     group; a dimension its axes do not divide evenly stays whole. Inside
-    an enclosing manual region (1F1B stages, the hierarchical gradient
-    sync) the caller's axes are already manual and the kernel is called
-    as is.
+    an enclosing manual region (the 1F1B pipeline's stages are the one
+    such region in the package) the caller's axes are already manual and
+    the kernel is called as is.
     """
     from jax.sharding import PartitionSpec as P
 

@@ -133,35 +133,6 @@ def ppermute(x, axis_name, perm):
     return jax.lax.ppermute(x, axis_name, perm)
 
 
-def psum_scatter(x, axis_name, *, scatter_dimension=0, tiled=True,
-                 axis_index_groups=None):
-    """`lax.psum_scatter` through the sanctioned parallel/ entry point —
-    the ici-tier reduce-scatter of the hierarchical gradient sync
-    (parallel/grad_reduce.py). Grouped calls classify as a hierarchy
-    stage in the comms auditor exactly like the a2a exchanges."""
-    return jax.lax.psum_scatter(
-        x, axis_name, scatter_dimension=scatter_dimension, tiled=tiled,
-        axis_index_groups=axis_index_groups,
-    )
-
-
-def all_gather(x, axis_name, *, axis=0, tiled=True,
-               axis_index_groups=None):
-    """`lax.all_gather` through the sanctioned parallel/ entry point —
-    the gather leg of the hierarchical gradient sync."""
-    return jax.lax.all_gather(
-        x, axis_name, axis=axis, tiled=tiled,
-        axis_index_groups=axis_index_groups,
-    )
-
-
-def psum(x, axis_name, *, axis_index_groups=None):
-    """`lax.psum` with optional groups through the sanctioned parallel/
-    entry point — the DCN rail crossing of the hierarchical gradient
-    sync (strided groups = the cross-host tier)."""
-    return jax.lax.psum(x, axis_name, axis_index_groups=axis_index_groups)
-
-
 # Explicit registry for the mesh the current trace runs under. The train
 # step factories push here (use_mesh below); thread_resources is only a
 # legacy fallback for code that entered `with mesh:` directly.

@@ -99,35 +99,6 @@ def logical_axis_rules(config: Optional[Config] = None):
     return tuple(rules)
 
 
-def manual_axis_rules(config: Optional[Config], manual_axes) -> Tuple:
-    """logical_axis_rules with every rule touching a MANUAL mesh axis
-    dropped (mapped to None).
-
-    Inside a partial-auto shard_map region (the hierarchical gradient
-    sync's (data, fsdp) region, parallel/grad_reduce.py) the manual axes
-    are invisible to the SPMD partitioner: a with_sharding_constraint
-    naming one would ask it to reshard over an axis it no longer owns —
-    the same group-check crash class the 1F1B pipeline dodges by
-    dropping 'activation_length' (see logical_axis_rules above). Rules
-    over the remaining AUTO axes (tensor, expert, ...) pass through
-    untouched."""
-    manual = frozenset(manual_axes)
-
-    def touches_manual(mesh_axes) -> bool:
-        if mesh_axes is None:
-            return False
-        axes = (
-            mesh_axes if isinstance(mesh_axes, (tuple, list))
-            else (mesh_axes,)
-        )
-        return any(a in manual for a in axes)
-
-    return tuple(
-        (logical, None if touches_manual(mesh) else mesh)
-        for logical, mesh in logical_axis_rules(config)
-    )
-
-
 class TrainState(struct.PyTreeNode):
     """Minimal train state: params + optimizer state + step + rng.
 

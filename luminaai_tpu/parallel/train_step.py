@@ -225,29 +225,12 @@ def make_train_step(
     # Host-offloaded optimizer state (pinned_host memory kinds in the
     # shardings): the update streams it through device memory in-jit.
     offloaded = is_host_offloaded(state_shardings.opt_state)
-    # Explicit hierarchical gradient reduction (parallel/grad_reduce.py):
-    # forward/backward/accumulation run shard-locally inside a manual
-    # (data, fsdp) region and ONE post-scan bucketed sync replaces the
-    # implicit GSPMD all-reduce — reduce-scatter on ICI, one grouped
-    # DCN psum per bucket, all-gather back.
-    hier_grad_fn = None
-    if config.grad_reduce == "hierarchical":
-        from luminaai_tpu.parallel.grad_reduce import (
-            make_hierarchical_grad_fn,
-        )
-
-        hier_grad_fn = make_hierarchical_grad_fn(
-            config, loss_fn, mesh, accum
-        )
 
     def update(state: TrainState, batch: Batch):
         step_rng, new_rng = jax.random.split(state.rng)
-        if hier_grad_fn is not None:
-            grads, metrics = hier_grad_fn(state.params, batch, step_rng)
-        else:
-            grads, metrics = _accumulate_grads(
-                loss_fn, state.params, batch, step_rng, accum
-            )
+        grads, metrics = _accumulate_grads(
+            loss_fn, state.params, batch, step_rng, accum
+        )
         if config.grad_clip_norm > 0:
             grads, grad_norm = clip_by_global_norm(grads, config.grad_clip_norm)
         else:  # clipping off; still report the norm for monitoring
@@ -282,11 +265,6 @@ def make_train_step(
     # `call.jitted.lower(state, batch).compile().cost_analysis()` queries
     # XLA's cost model for THIS executable without executing it.
     call.jitted = jitted
-    # Static sync plan (grad_reduce='hierarchical' only): filled at first
-    # trace; trainer telemetry reads it after compile (no host syncs).
-    call.grad_reduce_plan = (
-        hier_grad_fn.plan_box if hier_grad_fn is not None else None
-    )
     return call
 
 

@@ -452,44 +452,6 @@ def replica_5xx_burst(server, times: int = 5,
 
 
 @contextlib.contextmanager
-def slow_replica(server_or_engine, delay_s: float = 0.2) -> Iterator[dict]:
-    """Inflate every decode tick on ONE replica's engine — the slow-
-    replica fleet shape hedged dispatch exists for: the affine target
-    still answers, just late, so only a hedge (not a failover) recovers
-    the tail. Wraps the engine's generate / generate_stream; pass a
-    ChatServer or the engine itself. Yields {'calls'}."""
-    engine = getattr(server_or_engine, "engine", server_or_engine)
-    stats = {"calls": 0}
-    wrapped = []
-
-    def _wrap(name):
-        original = getattr(engine, name, None)
-        if original is None:
-            return
-        if name == "generate_stream":
-            def wrapper(*args, **kwargs):
-                stats["calls"] += 1
-                for ev in original(*args, **kwargs):
-                    time.sleep(delay_s)
-                    yield ev
-        else:
-            def wrapper(*args, **kwargs):
-                stats["calls"] += 1
-                time.sleep(delay_s)
-                return original(*args, **kwargs)
-        setattr(engine, name, wrapper)
-        wrapped.append((name, wrapper, original))
-
-    for name in ("generate", "generate_stream"):
-        _wrap(name)
-    try:
-        yield stats
-    finally:
-        for name, wrapper, original in wrapped:
-            _restore(engine, name, wrapper, original)
-
-
-@contextlib.contextmanager
 def drop_page_pulls(client, times: int = 0) -> Iterator[dict]:
     """Make a PageShareClient's page fetches fail with a connection
     error — the dead/unreachable owner shape the remote-hit admission

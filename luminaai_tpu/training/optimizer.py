@@ -157,29 +157,6 @@ def scale_by_adam_int8(
     return optax.GradientTransformation(init_fn, update_fn)
 
 
-def describe_optimizer_memory(opt_state) -> dict:
-    """Resident bytes of the optimizer state, broken down by dtype — the
-    audited slice of the r3 profile's 15% "optimizer + misc" HBM bucket.
-    Works on concrete or abstract (eval_shape) trees; QuantizedTensor /
-    int8-moment states show up under their stored widths, so the
-    adam_mu_dtype / adam_state_quantization levers become visible bytes
-    in the bench artifact instead of a config flag taken on faith."""
-    from luminaai_tpu.monitoring.attribution import tree_bytes
-
-    by_dtype: dict = {}
-    for leaf in jax.tree.leaves(opt_state):
-        dtype = getattr(leaf, "dtype", None)
-        if dtype is None:
-            continue
-        key = str(dtype)
-        by_dtype[key] = by_dtype.get(key, 0) + tree_bytes([leaf])
-    total = sum(by_dtype.values())
-    return {
-        "total_bytes": total,
-        "by_dtype": dict(sorted(by_dtype.items(), key=lambda kv: -kv[1])),
-    }
-
-
 def make_optimizer(
     config: Config,
     total_steps: int,

@@ -2,16 +2,15 @@
 
 Covers the reference ChinchillaScaler (ref: Src/Main_Scripts/training/
 chinchilla_scaler.py — optimal token budget = tokens_per_param × N, epoch/
-step derivation from dataset size, convergence detector with patience,
-compute-efficiency tracking). Pure host-side planning: it shapes the step
-budget the Trainer runs to; nothing here touches the device.
+step derivation from dataset size, convergence detector with patience).
+Pure host-side planning: it shapes the step budget the Trainer runs to;
+nothing here touches the device.
 """
 
 from __future__ import annotations
 
 import math
-import time
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Any, Dict, List, Optional
 
 import numpy as np
@@ -150,40 +149,3 @@ class ConvergenceDetector:
         self.stale += 1
         return self.stale >= self.patience
 
-
-@dataclass
-class ComputeEfficiencyTracker:
-    """Track achieved vs peak FLOPs (MFU) (ref compute-efficiency tracker).
-
-    Peak defaults to TPU v5e bf16 (197 TFLOP/s/chip); pass `peak_flops` for
-    other parts. Model FLOPs use the standard 6·N·T transformer estimate on
-    ACTIVE params.
-    """
-
-    active_params: int
-    n_chips: int = 1
-    peak_flops: float = 197e12
-    _samples: List[Dict[str, float]] = field(default_factory=list)
-
-    def record(self, tokens: int, seconds: float) -> Dict[str, float]:
-        model_flops = 6.0 * self.active_params * tokens
-        achieved = model_flops / max(seconds, 1e-9)
-        mfu = achieved / (self.peak_flops * self.n_chips)
-        sample = {
-            "tokens_per_sec": tokens / max(seconds, 1e-9),
-            "tflops_per_sec": achieved / 1e12,
-            "mfu": mfu,
-            "ts": time.time(),
-        }
-        self._samples.append(sample)
-        return sample
-
-    def summary(self) -> Dict[str, float]:
-        if not self._samples:
-            return {}
-        n = len(self._samples)
-        return {
-            "mean_mfu": sum(s["mfu"] for s in self._samples) / n,
-            "mean_tokens_per_sec": sum(s["tokens_per_sec"] for s in self._samples) / n,
-            "samples": n,
-        }

@@ -1136,7 +1136,6 @@ class Trainer:
                     self._count_recompile("initial_compile")
                     if cfg.compiled_cost_analysis:
                         self._export_compiled_costs(batch)
-                    self._export_grad_reduce_plan()
                     self.goodput.switch("productive")
                     if self.watchdog is not None:
                         # Armed AFTER the compile sync: the watchdog's
@@ -1349,34 +1348,6 @@ class Trainer:
             }
         logger.info("training done: %s", summary)
         return summary
-
-    # -- gradient-sync telemetry (docs/parallelism.md) ---------------------
-    def _export_grad_reduce_plan(self) -> None:
-        """ep-a2a-style per-stage byte telemetry for the hierarchical
-        gradient sync: the static GradReducePlan the traced step
-        embedded (filled at first trace, read here AFTER the first
-        step's compile sync — no new host sync enters the step path).
-        The grad_reduce_bytes{stage} gauges were exported at trace time
-        by hierarchical_grad_sync; this adds the flight-recorder record
-        so bench/forensics dumps carry the sync layout."""
-        box = getattr(self.train_step, "grad_reduce_plan", None)
-        plan = (box or {}).get("plan")
-        if plan is None:
-            return
-        logger.info(
-            "hierarchical grad sync: %d buckets x %.1f KiB, dcn tier %d "
-            "(ici tier %d), dcn bytes/step %.1f KiB (flat baseline "
-            "%.1f KiB)",
-            plan.n_buckets,
-            plan.bucket_bytes / 1024,
-            plan.dcn,
-            plan.ici_tier,
-            plan.hier_dcn_bytes / 1024,
-            plan.flat_dcn_bytes / 1024,
-        )
-        self.recorder.emit(
-            "grad_reduce_plan", step=self.global_step, **plan.to_dict()
-        )
 
     # -- router health (docs/observability.md "Router health") ------------
     def _export_router_health(self, metrics, scalars) -> None:

@@ -1,6 +1,6 @@
 # Development makefile (ref makefile:1 — its desktop dev commands; these
 # target the TPU framework's actual workflows).
-.PHONY: help install test test-fast analyze lint dryrun serve docker
+.PHONY: help install test analyze lint dryrun serve docker
 
 PY ?= python
 
@@ -11,11 +11,8 @@ help: ## Show available commands
 install: ## Editable install with the lumina console script
 	pip install -e .[dev]
 
-test-fast: ## Fast test tier (CPU, ~10 min) — what CI runs on push
-	JAX_PLATFORMS=cpu $(PY) -m pytest tests/ -q -m "not slow"
-
-test: ## Full suite (includes 8-device mesh parity + e2e trains)
-	JAX_PLATFORMS=cpu $(PY) -m pytest tests/ -q
+test: ## The suite as the driver runs it (CPU, 8 virtual devices, six workers)
+	JAX_PLATFORMS=cpu $(PY) -m pytest tests/ -q -m "not slow" -p xdist -n 6 --dist loadfile
 
 analyze: ## Static-analysis gate (astlint rules + abstract-eval audits)
 	JAX_PLATFORMS=cpu lumina analyze

@@ -36,29 +36,6 @@ jax.config.update("jax_platforms", "cpu")
 
 import pytest  # noqa: E402
 
-# Fast-tier support: the files below hold the mesh-heavy / multi-process /
-# end-to-end tests that dominate suite wall-clock (pipeline parity grids,
-# two-OS-process multihost runs). The DEFAULT run is
-# unchanged — full coverage — but `pytest -q -m "not slow"` gives a
-# fast iteration tier, and multi-core machines can add `-n auto`
-# (pytest-xdist) for parallel full runs.
-_SLOW_FILES = {
-    "test_pipeline.py",
-    "test_multihost.py",
-    "test_sharding.py",
-    "test_ring_attention.py",
-    "test_scan_layers.py",
-    "test_orchestrator.py",
-    "test_adaptive.py",
-    "test_adapters.py",
-}
-
-
-def pytest_collection_modifyitems(config, items):
-    for item in items:
-        if item.fspath.basename in _SLOW_FILES:
-            item.add_marker(pytest.mark.slow)
-
 
 @pytest.fixture(scope="session", autouse=True)
 def _assert_cpu_mesh():

@@ -1,4 +1,5 @@
-"""Regression tests for round-1 advisor findings (ADVICE.md):
+"""Regression tests for the advisor findings (ADVICE.md listed them; it
+was deleted in PR 49 once every one was fixed, and these tests hold them):
 
 - loss_mask/loss_weights must be shifted with the labels so the loss for
   predicting token i+1 is gated by token i+1's mask, not token i's.

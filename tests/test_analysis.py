@@ -668,111 +668,6 @@ def test_ep_dispatch_audit_dcn_bytes_strictly_below_gather(
     assert plan["a2a_dcn_bytes"] < plan["baseline_dcn_bytes"]
 
 
-def test_reduction_collectives_stage_classification():
-    """The grad-sync collectives (grouped psum / reduce_scatter /
-    all_gather) carry the same contiguous-vs-strided tier signature as
-    the a2a exchanges; ungrouped ones stay unstaged (GSPMD-free psums
-    are not hierarchy members)."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    from jax.sharding import Mesh, PartitionSpec as P
-
-    from luminaai_tpu.analysis.jaxpr_audit import enumerate_collectives
-    from luminaai_tpu.parallel.expert_dispatch import hierarchical_groups
-    from luminaai_tpu.parallel.mesh import (
-        all_gather,
-        psum,
-        psum_scatter,
-        shard_map,
-    )
-
-    mesh = Mesh(np.array(jax.devices()), ("data",))
-    g1, g2 = hierarchical_groups(8, 2)
-
-    def body(x):  # x [8] per shard
-        c = psum_scatter(
-            x, "data", scatter_dimension=0, tiled=True,
-            axis_index_groups=g1,
-        )
-        c = psum(c, "data", axis_index_groups=g2)
-        c = all_gather(
-            c, "data", axis=0, tiled=True, axis_index_groups=g1
-        )
-        return c + jax.lax.psum(c.sum(), "data")  # ungrouped: no stage
-
-    closed = jax.make_jaxpr(
-        shard_map(
-            body, mesh=mesh, in_specs=P("data"), out_specs=P("data"),
-            check_vma=False,
-        )
-    )(jnp.ones((64,), jnp.float32))
-    census = enumerate_collectives(closed)
-    assert census["counts"] == {
-        "reduce_scatter": 1, "psum": 2, "all_gather": 1,
-    }
-    by = {
-        (rec["primitive"], rec.get("stage"))
-        for rec in census["ops"]
-    }
-    assert ("reduce_scatter", "ici") in by
-    assert ("psum", "dcn") in by
-    assert ("all_gather", "ici") in by
-    assert ("psum", None) in by  # ungrouped psum carries no stage
-
-
-@pytest.fixture(scope="module")
-def grad_reduce_report():
-    from luminaai_tpu.analysis.jaxpr_audit import audit_grad_reduce
-
-    return audit_grad_reduce()
-
-
-def test_grad_reduce_audit_pins_collective_counts(grad_reduce_report):
-    """Pinned per-program collective census for the train step, flat vs
-    hierarchical, grad accumulation off and on (ISSUE 12).
-
-    flat: ZERO explicit collectives — the GSPMD reduction never reaches
-    the jaxpr (that invisibility is the 'before' being replaced).
-    hierarchical: the H-wide payload collectives appear exactly once
-    post-scan — 2 buckets × (1 ici reduce_scatter + 1 dcn psum + 1 ici
-    all_gather on the dp8=dcn2×ici4 mesh) — plus 6 SCALAR psums from
-    the per-microbatch loss normalization/metrics. Accum on adds NO
-    collectives: the scan re-uses the same scalar psums and the payload
-    sync stays outside it (the deferred-reduction contract)."""
-    rep = grad_reduce_report
-    assert rep["available"], rep
-    for accum in (1, 2):
-        assert rep["variants"][f"flat/accum{accum}"]["counts"] == {}
-        assert rep["variants"][f"hierarchical/accum{accum}"]["counts"] == {
-            "reduce_scatter": 2, "psum": 8, "all_gather": 2,
-        }
-    stages = rep["hier_stages"]
-    assert stages["ici"] > 0 and stages["dcn"] > 0
-    # The dcn payload is the SCATTERED chunk: strictly below the ici
-    # tier's full-bucket payload.
-    assert stages["dcn"] < stages["ici"]
-
-
-def test_grad_reduce_audit_dcn_bytes_strictly_below_flat(
-    grad_reduce_report,
-):
-    """THE acceptance pin (mirrored in CI via extras.grad_reduce): the
-    hierarchical sync's DCN-crossing bytes are strictly below the flat
-    GSPMD all-reduce baseline on the simulated dcn2×ici4 mesh."""
-    rep = grad_reduce_report
-    assert rep["available"], rep
-    assert 0 < rep["hier_dcn_bytes"] < rep["flat_dcn_bytes"]
-    assert rep["hier_below_flat"] is True
-    # Structural ratio: the dcn tier carries ~1/ici_tier of the flat
-    # payload (ici_tier=4 on this mesh; padding aside).
-    assert rep["hier_dcn_bytes"] <= rep["flat_dcn_bytes"] // 3
-    # And the static GradReducePlan agrees with the traced direction.
-    plan = rep["plan"]
-    assert plan["hier_dcn_bytes"] > 0
-    assert plan["hier_dcn_bytes"] < plan["flat_dcn_bytes"]
-
-
 # ---------------------------------------------------------------------------
 # `lumina analyze` CLI contract (the CI blocking step)
 # ---------------------------------------------------------------------------

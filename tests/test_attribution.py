@@ -345,20 +345,3 @@ def test_tree_bytes_counts_mixed_dtypes_and_keys():
     assert total >= 64 + 8 + 8
 
 
-def test_describe_optimizer_memory_reflects_mu_dtype():
-    """The adam_mu_dtype lever shows up as actual bytes: bf16 mu halves
-    the first-moment dtype bucket vs fp32."""
-    import jax
-    import jax.numpy as jnp
-
-    from luminaai_tpu.training.optimizer import describe_optimizer_memory
-
-    params = {"w": jnp.zeros((64, 64), jnp.float32)}
-    import optax
-
-    fp32 = optax.adamw(1e-3).init(params)
-    bf16 = optax.adamw(1e-3, mu_dtype=jnp.bfloat16).init(params)
-    m32 = describe_optimizer_memory(fp32)
-    m16 = describe_optimizer_memory(bf16)
-    assert m32["total_bytes"] > m16["total_bytes"]
-    assert m16["by_dtype"].get("bfloat16", 0) > 0

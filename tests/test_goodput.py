@@ -421,6 +421,7 @@ def test_ledger_and_beat_per_op_overhead_is_negligible():
     assert dt < 1.0, f"sentinel layer per-op overhead too high: {dt:.3f}s"
 
 
+# slow: a wall-clock A/B of two whole Trainer runs; five other workers' load decides the ratio, not the code.
 @pytest.mark.slow
 def test_watchdog_and_ledger_overhead_ab(tmp_path):
     """Trainer-level A/B: sentinels on (default) vs fully off. The on-

@@ -902,6 +902,7 @@ def test_scheduler_chunked_prefill_parity_events_and_counter(setup):
     assert any(e["chunks"] == n_chunks_long for e in ev)
 
 
+# slow: compares two runs' worst inter-token gaps on the wall clock; a loaded machine flips the inequality.
 @pytest.mark.slow
 def test_chunked_prefill_does_not_stall_decode_lanes(setup):
     """Acceptance: a prompt >= 4x the chunk size admitted mid-stream

@@ -1,6 +1,7 @@
 """Model unit tests (mirrors ref Src/tests/test_model.py strategy)."""
 
 import dataclasses
+import json
 
 import jax
 import jax.numpy as jnp
@@ -263,6 +264,20 @@ class TestConfig:
         cfg.save(p)
         cfg2 = Config.load(p)
         assert cfg.to_dict() == cfg2.to_dict()
+
+    def test_load_drops_keys_of_removed_fields(self, tmp_path):
+        """A configuration saved beside an older checkpoint may name fields
+        that have since gone (PR 49 removed five): it loads, and runs what
+        the remaining fields say."""
+        cfg = ConfigPresets.debug()
+        p = str(tmp_path / "c.json")
+        cfg.save(p)
+        with open(p) as f:
+            saved = json.load(f)
+        saved.update(a_field_since_removed="hierarchical", its_size=2)
+        with open(p, "w") as f:
+            json.dump(saved, f)
+        assert Config.load(p).to_dict() == cfg.to_dict()
 
 
 def test_untied_embeddings_has_lm_head():

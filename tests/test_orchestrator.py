@@ -22,7 +22,6 @@ from luminaai_tpu.training.orchestrator import (
 )
 from luminaai_tpu.training.scaler import (
     ChinchillaScaler,
-    ComputeEfficiencyTracker,
     ConvergenceDetector,
 )
 from luminaai_tpu.training.trainer import Trainer
@@ -351,14 +350,6 @@ def test_convergence_detector():
     assert not d.update(1.501, 30)
     assert not d.update(1.502, 40)
     assert d.update(1.503, 50)  # 3rd stale
-
-
-def test_efficiency_tracker_mfu():
-    tr = ComputeEfficiencyTracker(active_params=1_000_000, n_chips=1,
-                                  peak_flops=100e12)
-    s = tr.record(tokens=10_000, seconds=1.0)
-    # 6*1e6*1e4 = 6e10 FLOPs in 1s → 0.06% of 100 TFLOPs.
-    assert abs(s["mfu"] - 6e-4) < 1e-6
 
 
 # -- production monitoring --------------------------------------------------

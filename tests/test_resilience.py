@@ -613,6 +613,7 @@ def test_drain_finishes_inflight_and_reports_healthz():
 # ---------------------------------------------------------------------------
 # end-to-end: SIGTERM → RESUMABLE_EXIT → resume (CLI)
 # ---------------------------------------------------------------------------
+# slow: two whole CLI child processes (start-up, train, emergency save, resume), ~22 s alone and several times that beside five busy workers; test_kill_and_resume_bitwise_identical above holds the same contract in-process in tier-1.
 @pytest.mark.slow
 def test_cli_sigterm_exits_resumable_and_resumes(tmp_path):
     """Full preemption loop through the CLI: SIGTERM mid-training →

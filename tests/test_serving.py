@@ -908,6 +908,7 @@ def test_decode_parity_with_telemetry_on_off():
         assert outs[True][f"r{i}"] == list(range(first, first + 3 + i))
 
 
+# slow: a wall-clock A/B of a sub-second scheduler loop; the budget is a ratio that a loaded machine breaks.
 @pytest.mark.slow
 def test_telemetry_overhead_within_budget():
     """Scheduler A/B with metrics on vs off: recording must stay inside

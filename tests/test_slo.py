@@ -955,6 +955,7 @@ def test_trainer_slo_off_switch(tmp_path):
     assert "slo" not in s
 
 
+# slow: a wall-clock A/B of two Trainer runs with a 50 ms sampler thread; other workers' load decides the ratio.
 @pytest.mark.slow
 def test_slo_sampler_overhead_ab(tmp_path):
     """Trainer-level A/B (the watchdog test's budget): SLO on — with an

@@ -104,7 +104,6 @@ def test_rollback_restores_earlier_step(tmp_path):
     t.close()
 
 
-@pytest.mark.slow
 def test_resume_across_evolution_boundary(tmp_path):
     """Resume after expert grow (VERDICT r4 #10): growing an expert resets
     optimizer moments and makes older checkpoints shape-incompatible —
