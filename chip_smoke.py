@@ -212,9 +212,12 @@ def check_train_run(cfg, steps: int, summary: Dict[str, Any],
     }
 
 
-def phase_checkpoint(out_dir: str) -> str:
+def phase_checkpoint(out_dir: str, live_before: int = 0) -> str:
     """The trainer's own orbax save finished (train() waits for it) and
-    its integrity manifest verifies; returns the checkpoint directory."""
+    its integrity manifest verifies; returns the checkpoint directory.
+    `live_before`: bytes of arrays alive in the process before `lumina
+    train` began (0 in a process of its own; a test that shares its
+    process with earlier tests hands what they left behind)."""
     import jax
 
     from luminaai_tpu import cli
@@ -226,7 +229,7 @@ def phase_checkpoint(out_dir: str) -> str:
     # nothing the training run built may still hold it on the device.
     gc.collect()
     jax.clear_caches()
-    live = sum(a.nbytes for a in jax.live_arrays())
+    live = sum(a.nbytes for a in jax.live_arrays()) - live_before
     stats = jax.devices()[0].memory_stats() or {}
     say("checkpoint", dir=ckpt_dir, live_array_bytes_after_free=live,
         bytes_in_use_after_free=stats.get("bytes_in_use"))

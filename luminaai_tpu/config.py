@@ -78,10 +78,12 @@ class Config:
     #                parity tests, not CPU serving).
     # On a TPU the two strings run one program: a decode batch takes
     # lane_attention where the static shapes are eligible
-    # (lane_attention_eligible: the pool's layout alone, 128-wide heads
-    # in whole (8, 128) tiles a row, at any number of query heads a k/v
-    # head, MHA included) and the XLA reference otherwise; nothing else
-    # chooses.
+    # (lane_attention_eligible: the pool's layout alone: every array of a
+    # layer's entry whole lanes wide, its row [kv_heads, width] whole
+    # (8, 128) tiles, one head, or 4 heads of one 128-lane tile (a key
+    # wider than its value lies in value-width parts: key_parts), at any
+    # number of query heads a k/v head, MHA included) and the XLA
+    # reference otherwise; nothing else chooses.
     # The single-stream engine's rolling cache (one uniform
     # attention_window, below) always takes the dense path: its slot
     # arithmetic is mod-C, which LaneMeta does not describe. The slot-paged
@@ -165,6 +167,31 @@ class Config:
     rope_layout: str = "split"
     # Size of one attention head; None = hidden_size // num_heads.
     attn_head_dim: Optional[int] = None
+    # GQAttention's k/v heads a LAYER (num_layers entries; None =
+    # num_kv_heads for all): window layers of 8 beside full layers of 4.
+    layer_kv_heads: Optional[tuple] = None
+    # Width of a GQAttention value head (and of the head's output); None
+    # = the key's (attn_head_dim). A key wider than its value is KEPT in
+    # value-width parts, the last zero-padded (Config.key_parts: 192 over
+    # 128 is two arrays of 128 columns a layer beside the value's one), so
+    # that every array of the entry is [rows, kv_heads, value width] and
+    # the pool's rows lie as the kernels read them.
+    attn_value_dim: Optional[int] = None
+    # Values are multiplied by this before attention (1.0: not at all).
+    attn_value_scale: float = 1.0
+    # Columns of a q / k head that a rotation turns, the FIRST rope_dim
+    # (pairs by rope_layout within them); the rest carry no position.
+    # None = the whole head.
+    rope_dim: Optional[int] = None
+    # Rotation base a LAYER (num_layers entries; None = rope_theta).
+    layer_rope_theta: Optional[tuple] = None
+    # A learned SINK a layer (num_layers booleans; None = nowhere): one
+    # float32 logit a query head (parameter `sink` [num_heads], there
+    # alone) that joins the softmax's denominator and gives no value:
+    # p_ij = exp(s_ij) / (sum_j' exp(s_ij') + exp(sink_h)). Drawn
+    # N(0, attn_sink_init_std) (0: a sink of logit 0, not none).
+    layer_sink: Optional[tuple] = None
+    attn_sink_init_std: float = 0.0
     # 'rms' (RMSNorm, rms_norm_eps) or 'layernorm' (mean-subtracting, no
     # bias, layer_norm_eps): the blocks' norms and the final norm.
     norm_kind: str = "rms"
@@ -231,6 +258,9 @@ class Config:
     # computed, bit for bit.
     moe_score_func: str = "softmax"  # softmax|sigmoid
     moe_selection_bias: bool = False
+    # The selection bias is drawn N(0, this) (0: zeros, as a router that
+    # nobody has balanced yet has it).
+    moe_selection_bias_init_std: float = 0.0
     moe_renormalize: bool = True
     moe_routed_scale: float = 1.0
     # Width of one routed (and one shared) expert; None = intermediate_size.
@@ -576,6 +606,13 @@ class Config:
             )
         if self.layer_rope is not None:
             self.layer_rope = tuple(bool(r) for r in self.layer_rope)
+        if self.layer_kv_heads is not None:
+            self.layer_kv_heads = tuple(int(n) for n in self.layer_kv_heads)
+        if self.layer_rope_theta is not None:
+            self.layer_rope_theta = tuple(
+                float(t) for t in self.layer_rope_theta)
+        if self.layer_sink is not None:
+            self.layer_sink = tuple(bool(b) for b in self.layer_sink)
         if self.num_kv_heads is None:
             self.num_kv_heads = self.num_heads
         if self.intermediate_size is None:
@@ -657,7 +694,8 @@ class Config:
         assert self.shared_expert_combine in ("sum", "average"), (
             f"invalid shared_expert_combine {self.shared_expert_combine}"
         )
-        for name in ("layer_windows", "layer_rope"):
+        for name in ("layer_windows", "layer_rope", "layer_kv_heads",
+                     "layer_rope_theta", "layer_sink"):
             per_layer = getattr(self, name)
             assert per_layer is None or len(per_layer) == self.num_layers, (
                 f"{name} names {len(per_layer)} layers, num_layers is "
@@ -678,15 +716,57 @@ class Config:
             )
         per_layer_differs = any(
             t is not None and len(set(t)) > 1
-            for t in (self.layer_windows, self.layer_rope)
+            for t in (self.layer_windows, self.layer_rope,
+                      self.layer_kv_heads, self.layer_rope_theta,
+                      self.layer_sink)
         )
         assert not (self.scan_layers and per_layer_differs), (
             "scan_layers needs one kind of layer: layer_windows / "
-            "layer_rope differ by layer"
+            "layer_rope / layer_kv_heads / layer_rope_theta / layer_sink "
+            "differ by layer"
         )
-        assert self.num_heads % self.num_kv_heads == 0, (
-            "num_heads must be divisible by num_kv_heads"
+        for n_kv in {self.num_kv_heads, *(self.layer_kv_heads or ())}:
+            assert n_kv > 0 and self.num_heads % n_kv == 0, (
+                f"num_heads {self.num_heads} must be divisible by the k/v "
+                f"heads of every layer, got {n_kv}"
+            )
+        if self.layer_rope_theta is not None:
+            assert all(t > 0 for t in self.layer_rope_theta), (
+                f"layer_rope_theta entries are positive: "
+                f"{self.layer_rope_theta}"
+            )
+        if self.rope_dim is not None:
+            assert 0 < self.rope_dim <= self.head_dim() and (
+                self.rope_dim % 2 == 0
+            ), (
+                f"rope_dim {self.rope_dim}: an even number of a head's "
+                f"{self.head_dim()} columns"
+            )
+        assert self.attn_value_scale > 0, (
+            f"attn_value_scale must be positive, got {self.attn_value_scale}"
         )
+        assert self.attn_sink_init_std >= 0, "attn_sink_init_std is a std"
+        if self.attn_value_dim is not None:
+            assert 0 < self.attn_value_dim <= self.head_dim(), (
+                f"attn_value_dim {self.attn_value_dim}: at most the key's "
+                f"{self.head_dim()} columns (a key wider than its value is "
+                "kept in value-width parts)"
+            )
+            assert self.sequence_parallel_size == 1, (
+                "attn_value_dim does not compose with ring sequence "
+                "parallelism: its chunks merge outputs of the key's width"
+            )
+            assert self.key_parts() == 1 or self.kv_cache_dtype != "int8", (
+                "kv_cache_dtype='int8' is not served over a key kept in "
+                "parts (attn_value_dim under attn_head_dim): a row's scale "
+                "would span the parts"
+            )
+        if self.layer_sink is not None and any(self.layer_sink):
+            assert self.sequence_parallel_size == 1, (
+                "layer_sink does not compose with ring sequence "
+                "parallelism: a chunk's partial softmax would count the "
+                "sink once a shard"
+            )
         assert self.precision in PRECISIONS, f"invalid precision {self.precision}"
         assert self.rope_dtype in ("fp32", "bf16"), (
             f"invalid rope_dtype {self.rope_dtype}"
@@ -1042,6 +1122,51 @@ class Config:
             return self.use_rope
         return self.layer_rope[layer_idx]
 
+    def kv_heads_of(self, layer_idx: Optional[int]) -> int:
+        """The k/v heads of a layer's GQAttention."""
+        if self.layer_kv_heads is None or layer_idx is None:
+            return self.num_kv_heads
+        return self.layer_kv_heads[layer_idx]
+
+    def rope_theta_of(self, layer_idx: Optional[int]) -> float:
+        """The rotation base of a layer's GQAttention."""
+        if self.layer_rope_theta is None or layer_idx is None:
+            return self.rope_theta
+        return self.layer_rope_theta[layer_idx]
+
+    def sink_of(self, layer_idx: Optional[int]) -> bool:
+        """Whether a layer's softmax has a learned sink."""
+        if self.layer_sink is None or layer_idx is None:
+            return False
+        return self.layer_sink[layer_idx]
+
+    def value_dim(self) -> int:
+        """Width of a GQAttention value head."""
+        return self.attn_value_dim or self.head_dim()
+
+    def key_parts(self) -> int:
+        """Arrays a lane's entry keeps a GQAttention key in: one of the
+        head's own width, or, where the value is narrower
+        (attn_value_dim), ceil(head / value) of the value's width."""
+        return -(-self.head_dim() // self.value_dim())
+
+    def key_width(self) -> int:
+        """Columns a lane's entry keeps of a GQAttention key head: the
+        head's own, or its parts' together (the last zero-padded)."""
+        parts = self.key_parts()
+        return parts * self.value_dim() if parts > 1 else self.head_dim()
+
+    def kv_row_bytes(self, layer_idx: int, itemsize: int = 2,
+                     kv_cache_dtype: Optional[str] = None) -> int:
+        """Bytes of k and v one token keeps in a layer's entry, as
+        stored: the layer's own k/v heads, a key in parts with its
+        padding, int8 codes with a float32 scale a head for k and for v."""
+        cols = self.key_width() + self.value_dim()
+        int8 = (kv_cache_dtype or self.kv_cache_dtype) == "int8"
+        return self.kv_heads_of(layer_idx) * (
+            cols + 8 if int8 else cols * itemsize
+        )
+
     def ring_pages(self, layer_idx: int, page_size: int,
                    chunk: int) -> Optional[int]:
         """Pages a lane keeps of a layer with a window of its own in the
@@ -1119,13 +1244,19 @@ class Config:
         """Total parameter count (ref core/model.py:91 estimate_parameters)."""
         h, v, L = self.hidden_size, self.vocab_size, self.num_layers
         inter = self.intermediate_size
-        kv_dim = self.num_kv_heads * self.head_dim()
         embed = v * h if self.tie_word_embeddings else 2 * v * h
-        q_dim = self.num_heads * self.head_dim()
-        attn = 2 * h * q_dim + 2 * h * kv_dim  # q, o, k, v
+        d, dv, n_q = self.head_dim(), self.value_dim(), self.num_heads
+        # q, o over the value's width, k, v over the layer's own k/v
+        # heads, a sink's logits where the layer has one.
+        attn = sum(
+            h * n_q * d + n_q * dv * h
+            + h * self.kv_heads_of(i) * (d + dv)
+            + (n_q if self.sink_of(i) else 0)
+            for i in range(L)
+        )
         ffn_dense = 3 * h * inter  # gate, up, down
         per_layer_norms = 2 * h
-        total = embed + L * (attn + per_layer_norms) + h  # final norm
+        total = embed + attn + L * per_layer_norms + h  # final norm
         moe_layers = self.num_moe_layers()
         dense_layers = L - moe_layers
         total += dense_layers * ffn_dense

@@ -1109,16 +1109,20 @@ class StepwiseDecoder:
         # of its own / full), and the times a lane's rows came round its
         # ring: counted on the host from the lengths it has
         # (_kv_rows_of).
-        windows = [
-            engine.config.window_of(i)
+        # (A kind of attention layer: its window and its k/v heads; a
+        # stack may have full layers of 4 beside window layers of 8.)
+        self._kinds = collections.Counter(
+            (engine.config.window_of(i), engine.config.kv_heads_of(i))
             for i in range(engine.config.num_layers)
             if engine.config.mixer_kind(i) == "attention"
-        ]
-        self._n_window_layers = sum(w is not None for w in windows)
-        self._n_global_layers = len(windows) - self._n_window_layers
-        self._windows = collections.Counter(windows)
+        )
         self.kv_window_rows = 0
         self.kv_global_rows = 0
+        # The same reads in bytes: each layer's rows x that layer's own
+        # row bytes (k and v as stored, _row_bytes): rows of two kinds
+        # are not one size.
+        self.kv_window_bytes = 0
+        self.kv_global_bytes = 0
         self.ring_wraps = 0
         # Rows of one latent a token the lanes' attention read in the
         # 'latent' layers, and the keys the chunk's attention spanned
@@ -1148,11 +1152,28 @@ class StepwiseDecoder:
             lane_attention_engaged,
         )
 
-        self._lane_kernel = lane_attention_engaged(
-            self.backend, 1, engine.config.num_heads,
-            engine.config.num_kv_heads, engine.config.head_dim(),
-            self.pool.page_size,
-        )
+        # By kind of layer (_kinds): whether the lanes' rows go through
+        # the kernel, from the shapes of the arrays the entry keeps (a
+        # key in parts: the value's width, Config.key_parts).
+        cfg = engine.config
+        self._key_width = cfg.key_width()
+        self._lane_kernels = {
+            kind: lane_attention_engaged(
+                self.backend, 1, cfg.num_heads, kind[1],
+                self._key_width // cfg.key_parts(), self.pool.page_size,
+                cfg.value_dim(),
+            )
+            for kind in self._kinds
+        }
+        self._lane_kernel = bool(self._lane_kernels) and all(
+            self._lane_kernels.values())
+        self._row_bytes = {
+            cfg.kv_heads_of(i): cfg.kv_row_bytes(
+                i, jnp.dtype(self.model.dtype).itemsize,
+                cfg.kv_cache_dtype)
+            for i in range(cfg.num_layers)
+            if cfg.mixer_kind(i) == "attention"
+        }
         # A latent entry is one shared key row of its own width.
         self._latent_row = (1, latent_entry_width(engine.config))
         self._latent_lane_kernel = lane_attention_engaged(
@@ -2423,25 +2444,26 @@ class StepwiseDecoder:
             c_full, c_window = end, min(end, lanes_window)
             if ring:
                 self.ring_wraps += (end - 1) // ring - max(start - 1, 0) // ring
-        rows_full = self._n_global_layers * self.num_slots * lanes_full
-        rows_window = self._n_window_layers * self.num_slots * lanes_window
-        if self._lane_kernel:
-            rows_full = rows_window = 0
-            held = pos[live] + 1
-            for window, layers in self._windows.items():
-                steps, fetched, rows = self._lane_blocks_read(
-                    held, np.flatnonzero(live), window,
-                    lanes_window if window is not None else lanes_full,
-                    bool(ring) and window is not None,
+        held, slots = pos[live] + 1, np.flatnonzero(live)
+        for (window, n_kv), layers in self._kinds.items():
+            full = window is None
+            lanes_rows = lanes_full if full else lanes_window
+            rows = layers * self.num_slots * lanes_rows
+            if self._lane_kernels[(window, n_kv)]:
+                steps, fetched, per = self._lane_blocks_read(
+                    held, slots, window, lanes_rows,
+                    bool(ring) and not full, (n_kv, self._key_width),
                 )
                 self.lane_attention_blocks += layers * steps
                 self.lane_attention_blocks_live += layers * fetched
-                if window is None:
-                    rows_full += layers * fetched * rows
-                else:
-                    rows_window += layers * fetched * rows
-        self.kv_global_rows += rows_full + self._n_global_layers * c_full
-        self.kv_window_rows += rows_window + self._n_window_layers * c_window
+                rows = layers * fetched * per
+            rows += layers * (c_full if full else c_window)
+            if full:
+                self.kv_global_rows += rows
+                self.kv_global_bytes += rows * self._row_bytes[n_kv]
+            else:
+                self.kv_window_rows += rows
+                self.kv_window_bytes += rows * self._row_bytes[n_kv]
         if self._n_latent_layers:
             layers = self._n_latent_layers
             rows = self.num_slots * lanes_full
@@ -2459,24 +2481,22 @@ class StepwiseDecoder:
             at = pos[live]
             self.ring_wraps += int(((at > 0) & (at % ring == 0)).sum())
 
-    def _lane_blocks_read(self, held, slots, window, rows, ring, row=None):
+    def _lane_blocks_read(self, held, slots, window, rows, ring, row):
         """(grid steps, steps that fetch and compute, rows of k/v such a
         step fetches) of one layer's lane_attention call in a tick that
         steps the lanes `slots`, holding `held` rows each, over `rows`
         rows a lane (the tick's extent, or the ring): lane_pages_held and
         lane_blocks, the kernel's own plan, over the host's lengths.
-        `row`: (k/v heads, head size) of a pool row, the attention
-        layers' by default."""
+        `row`: (k/v heads, key columns) of a pool row."""
         from luminaai_tpu.ops.ragged_paged_attention import (
             lane_blocks,
             lane_pages_held,
         )
 
-        cfg, ps = self.engine.config, self.pool.page_size
+        ps = self.pool.page_size
         pages = rows // ps
         per_block, _ = lane_blocks(
-            pages, ps, *(row or (cfg.num_kv_heads, cfg.head_dim())),
-            jnp.dtype(self.model.dtype).itemsize,
+            pages, ps, *row, jnp.dtype(self.model.dtype).itemsize,
             chased=self.prefix_cache is not None,
         )
         seen = lane_pages_held(

@@ -211,8 +211,9 @@ class SwiGLU(nn.Module):
 # Above this many bytes of [chunk rows, heads, keys] float32 scores the
 # tick's chunk attends through the blocked kernel (GQAttention.
 # _tick_attention): 64 x 16 x 2,048 and 64 x 20 x 2,048 scores are 8 and
-# 10 MB and stay XLA's; 256 x 128 x 16,384 are 2.1 GB.
-_CHUNK_SCORES_LIMIT = 256 * 2**20
+# 10 MB and stay XLA's; 256 x 64 x 512 (a ring of four pages) are 34 MB,
+# 256 x 128 x 16,384 are 2.1 GB.
+_CHUNK_SCORES_LIMIT = 16 * 2**20
 
 
 class GQAttention(nn.Module):
@@ -241,7 +242,14 @@ class GQAttention(nn.Module):
                    layer: Optional[int] = None, ring=None):
         """What a lane keeps of an attention layer: a (k, v) pair of
         [batch, rows, kv_heads, head_dim] (int8: codes and per-row
-        scales), which the pool pages. One of two entries by the layer's
+        scales), which the pool pages, in THIS layer's own shape: its
+        k/v heads (Config.kv_heads_of), and, where the value is narrower
+        than the key (Config.attn_value_dim), k as a tuple of
+        Config.key_parts() arrays of the value's width, the key's columns
+        in order and the last zero-padded, beside a v of the same shape
+        (192 over 128: two arrays and one, each [.., kv_heads, 128]: the
+        rows lie as the lanes' kernel reads them, lane_attention_eligible
+        says why). One of two entries by the layer's
         window: whole pages (`max_len` rows), or, for a layer with a
         window of its own in the slot-paged pool (`ring`: the pool's
         (page_size, prefill chunk)), a RING of Config.ring_pages pages a
@@ -262,7 +270,9 @@ class GQAttention(nn.Module):
             and max_len <= cfg.seq_length
         ):
             C = min(max_len, ((cfg.attention_window + 127) // 128) * 128)
-        shape = (*lead, batch_size, C, cfg.num_kv_heads, cfg.head_dim())
+        parts = cfg.key_parts()
+        shape = (*lead, batch_size, C, cfg.kv_heads_of(layer),
+                 cfg.head_dim() if parts == 1 else cfg.value_dim())
 
         def one():
             if choice == "int8":
@@ -274,6 +284,8 @@ class GQAttention(nn.Module):
                 )
             return jnp.zeros(shape, dtype=dtype)
 
+        if parts > 1:
+            return (tuple(one() for _ in range(parts)), one())
         return (one(), one())
 
     @nn.compact
@@ -289,8 +301,12 @@ class GQAttention(nn.Module):
     ):
         cfg = self.config
         B, S, H = x.shape
-        n_q, n_kv = cfg.num_heads, cfg.num_kv_heads
-        d = cfg.head_dim()
+        n_q, n_kv = cfg.num_heads, cfg.kv_heads_of(self.layer_idx)
+        d, dv = cfg.head_dim(), cfg.value_dim()
+        parts = cfg.key_parts()
+        # The score scale where a head is handed on wider than it is
+        # (below: a key kept in zero-padded parts); None: the width's own.
+        scale = None
 
         wq = self.param(
             "wq",
@@ -313,7 +329,7 @@ class GQAttention(nn.Module):
             nn.with_logical_partitioning(
                 default_init(cfg.init_std), ("embed", "kv_heads", "head_dim")
             ),
-            (H, n_kv, d),
+            (H, n_kv, dv),
             jnp.float32,
         )
         wo = self.param(
@@ -322,9 +338,24 @@ class GQAttention(nn.Module):
                 default_init(cfg.init_std / jnp.sqrt(2.0)),
                 ("heads", "head_dim", "embed"),
             ),
-            (n_q, d, H),
+            (n_q, dv, H),
             jnp.float32,
         )
+        sink = None
+        if cfg.sink_of(self.layer_idx):
+            # One logit a query head in the softmax's denominator
+            # (Config.layer_sink; ops/ragged_paged_attention.py
+            # sink_softmax): float32 in every form that attends.
+            sink = self.param(
+                "sink",
+                nn.with_logical_partitioning(
+                    nn.initializers.normal(cfg.attn_sink_init_std)
+                    if cfg.attn_sink_init_std else nn.initializers.zeros,
+                    ("heads",),
+                ),
+                (n_q,),
+                jnp.float32,
+            ).astype(jnp.float32)
 
         if any(isinstance(w, QuantizedTensor) for w in (wq, wk, wv)):
             # Serving path: int8 MXU projections (ops/quantized.py). The
@@ -352,14 +383,14 @@ class GQAttention(nn.Module):
                 [
                     wq.reshape(H, n_q * d),
                     wk.reshape(H, n_kv * d),
-                    wv.reshape(H, n_kv * d),
+                    wv.reshape(H, n_kv * dv),
                 ],
                 axis=1,
             ).astype(self.dtype)
             qkv = jnp.einsum("bsd,df->bsf", x, wqkv)
             q = qkv[..., : n_q * d].reshape(B, S, n_q, d)
             k = qkv[..., n_q * d : (n_q + n_kv) * d].reshape(B, S, n_kv, d)
-            v = qkv[..., (n_q + n_kv) * d :].reshape(B, S, n_kv, d)
+            v = qkv[..., (n_q + n_kv) * d :].reshape(B, S, n_kv, dv)
         else:
             q = jnp.einsum("bsd,dhk->bshk", x, wq.astype(self.dtype))
             k = jnp.einsum("bsd,dhk->bshk", x, wk.astype(self.dtype))
@@ -371,6 +402,9 @@ class GQAttention(nn.Module):
 
                 return int8_out_proj(out, wo, self.dtype)
             return jnp.einsum("bshk,hkd->bsd", out, wo.astype(self.dtype))
+
+        if cfg.attn_value_scale != 1.0:
+            v = v * jnp.asarray(cfg.attn_value_scale, v.dtype)
 
         # Runtime length can exceed cfg.seq_length (soft-prompt prefixes
         # prepend virtual tokens); the rope table covers whichever is larger.
@@ -387,12 +421,26 @@ class GQAttention(nn.Module):
             max_len = max(cfg.seq_length, S)
         window = cfg.window_of(self.layer_idx)
         if cfg.rope_of(self.layer_idx):
-            cos, sin = rope_frequencies(d, max_len, cfg.rope_theta)
+            # The head's first rope_dim columns turn (all of them by
+            # default), at this layer's own base.
+            turn = cfg.rope_dim or d
+            cos, sin = rope_frequencies(
+                turn, max_len, cfg.rope_theta_of(self.layer_idx))
             rope_ct = self.dtype if cfg.rope_dtype == "bf16" else jnp.float32
-            q = apply_rope(q, cos, sin, positions, compute_dtype=rope_ct,
-                           layout=cfg.rope_layout)
-            k = apply_rope(k, cos, sin, positions, compute_dtype=rope_ct,
-                           layout=cfg.rope_layout)
+
+            def rotate(t):
+                if turn == d:
+                    return apply_rope(t, cos, sin, positions,
+                                      compute_dtype=rope_ct,
+                                      layout=cfg.rope_layout)
+                return jnp.concatenate([
+                    apply_rope(t[..., :turn], cos, sin, positions,
+                               compute_dtype=rope_ct,
+                               layout=cfg.rope_layout),
+                    t[..., turn:],
+                ], axis=-1)
+
+            q, k = rotate(q), rotate(k)
 
         new_cache = None
         rolling_prefill = False
@@ -401,6 +449,13 @@ class GQAttention(nn.Module):
         if kv_cache is not None:
             ck, cv = kv_cache
             C_cache = (ck[0] if isinstance(ck, tuple) else ck).shape[1]
+            if parts > 1:
+                # The entry keeps the key in value-width parts, the last
+                # zero-padded (init_cache): q and k go on as wide as the
+                # parts together, under the head's own scale.
+                wide = ((0, 0),) * 3 + ((0, parts * dv - d),)
+                q, k = jnp.pad(q, wide), jnp.pad(k, wide)
+                scale = 1.0 / d**0.5
             # A cache_index of shape [B] means PER-LANE offsets: each
             # batch row is an independent slot of a paged pool at its own
             # sequence position (continuous batching — the scheduler owns
@@ -541,7 +596,27 @@ class GQAttention(nn.Module):
                     "multi-row write (speculation's k-row verify, a "
                     "whole-prompt prefill) is not served over a ring"
                 )
-            if isinstance(ck, tuple):
+            # One write for every array of the entry, by the kind of call.
+            if S > 1 and (rolling or per_lane):
+                put = _scatter
+            elif per_lane:
+                put = _row_scatter
+            else:
+                def put(cache_arr, fresh):
+                    return jax.lax.dynamic_update_slice(
+                        cache_arr, fresh, (0, write_at, 0, 0)
+                    )
+
+            if parts > 1:
+                # Parts to the kernels that read them in place; the XLA
+                # forms lay them side by side (whole_key).
+                ck = tuple(
+                    put(c, f)
+                    for c, f in zip(ck, jnp.split(k, parts, axis=-1))
+                )
+                cv = put(cv, v)
+                k_att, v_att = ck, cv
+            elif isinstance(ck, tuple):
                 # int8 KV cache (config.kv_cache_dtype='int8'): codes +
                 # per-row scales. Quantize the fresh rows at insert; read
                 # back the whole cache dequantized — XLA fuses the
@@ -550,21 +625,8 @@ class GQAttention(nn.Module):
                 from luminaai_tpu.ops.quantized import quantize_act
 
                 def _upd(cache, fresh):
-                    codes, scales = cache
                     q8, s = quantize_act(fresh)
-                    if S > 1 and (rolling or per_lane):
-                        codes = _scatter(codes, q8)
-                        scales = _scatter(scales, s)
-                    elif per_lane:
-                        codes = _row_scatter(codes, q8)
-                        scales = _row_scatter(scales, s)
-                    else:
-                        codes = jax.lax.dynamic_update_slice(
-                            codes, q8, (0, write_at, 0, 0)
-                        )
-                        scales = jax.lax.dynamic_update_slice(
-                            scales, s, (0, write_at, 0, 0)
-                        )
+                    codes, scales = put(cache[0], q8), put(cache[1], s)
                     deq = (codes.astype(jnp.float32) * scales).astype(
                         self.dtype
                     )
@@ -573,17 +635,7 @@ class GQAttention(nn.Module):
                 ck, k_att = _upd(ck, k)
                 cv, v_att = _upd(cv, v)
             else:
-                if S > 1 and (rolling or per_lane):
-                    ck, cv = _scatter(ck, k), _scatter(cv, v)
-                elif per_lane:
-                    ck, cv = _row_scatter(ck, k), _row_scatter(cv, v)
-                else:
-                    ck = jax.lax.dynamic_update_slice(
-                        ck, k, (0, write_at, 0, 0)
-                    )
-                    cv = jax.lax.dynamic_update_slice(
-                        cv, v, (0, write_at, 0, 0)
-                    )
+                ck, cv = put(ck, k), put(cv, v)
                 k_att, v_att = ck, cv
             new_cache = (ck, cv)
             if rolling and S > 1 and self.multi_row_update:
@@ -721,6 +773,8 @@ class GQAttention(nn.Module):
                 block_q=cfg.flash_block_q,
                 block_kv=cfg.flash_block_kv,
                 window=window,
+                scale=scale,
+                sink=sink,
             )
         else:
             decoding_att = kv_cache is not None and not rolling_prefill
@@ -737,10 +791,11 @@ class GQAttention(nn.Module):
                 ):
                     out = self._tick_attention(
                         q, k, v, lane_meta, cache_index, positions,
-                        backend, ring,
+                        backend, ring, scale, sink,
                     )
             elif kv_cache is not None and ring:
-                out = self._ring_lanes(q, k, v, lane_meta, backend)
+                out = self._ring_lanes(q, k, v, lane_meta, backend,
+                                       scale, sink)
             elif decoding_att and backend != "dense" and not rolling:
                 # Length-aware (LaneMeta) dispatch: scalar-offset decode,
                 # batched per-lane decode, and (chunked) prefill all
@@ -750,11 +805,12 @@ class GQAttention(nn.Module):
                 # oracle and the rolling-cache layouts, whose mod-C slot
                 # arithmetic LaneMeta deliberately does not model.
                 out = self._ragged_attention(
-                    q, k, v, lane_meta, cache_index, positions, backend
+                    q, k, v, lane_meta, cache_index, positions, backend,
+                    scale, sink,
                 )
             else:
                 out = self._xla_attention(
-                    q, k, v, decoding_att, cache_index
+                    q, k, v, decoding_att, cache_index, scale, sink
                 )
 
         y = _out_proj(out)
@@ -763,7 +819,8 @@ class GQAttention(nn.Module):
     def _window(self) -> Optional[int]:
         return self.config.window_of(self.layer_idx)
 
-    def _ring_lanes(self, q, k, v, meta, backend=None):
+    def _ring_lanes(self, q, k, v, meta, backend=None, scale=None,
+                    sink=None):
         """The lanes' rows over their rings of pages, read in place: a
         physical page's rows are masked by the positions of the logical
         page the lane's ring table keeps there now, O(ring) rows a lane
@@ -775,27 +832,30 @@ class GQAttention(nn.Module):
             lane_attention,
             lane_attention_engaged,
             ring_key_positions,
+            whole_key,
         )
 
         n_d = q.shape[0]
+        k0 = k[0] if isinstance(k, tuple) else k
         if lane_attention_engaged(
-            backend, q.shape[1], q.shape[2], k.shape[2], q.shape[3],
-            meta.page_size,
+            backend, q.shape[1], q.shape[2], k0.shape[2], k0.shape[3],
+            meta.page_size, v.shape[3],
         ):
             return lane_attention(
-                q, k, v, meta.replace(window=self._window()), ring=True
+                q, k, v, meta.replace(window=self._window()), ring=True,
+                scale=scale, sink=sink,
             )
         lengths = meta.lengths[:n_d]
         kpos = ring_key_positions(
-            meta.ring_table[:n_d], lengths, meta.page_size, k.shape[1]
+            meta.ring_table[:n_d], lengths, meta.page_size, k0.shape[1]
         )
         return banded_attention_xla(
-            q, k[:n_d], v[:n_d], (lengths - 1)[:, None], kpos,
-            self._window(),
+            q, whole_key(k)[:n_d], v[:n_d], (lengths - 1)[:, None], kpos,
+            self._window(), scale=scale, sink=sink,
         )
 
     def _tick_attention(self, q, k, v, meta, cache_index, positions,
-                        backend, ring=False):
+                        backend, ring=False, scale=None, sink=None):
         """A decode batch with a prefill chunk riding it (LaneMeta.
         chunk_rows): the one place the two kinds of rows part and meet
         again. No weight is involved here, so each kind keeps the
@@ -816,6 +876,7 @@ class GQAttention(nn.Module):
             chunk_attention,
             chunk_attention_eligible,
             ring_key_positions,
+            whole_key,
         )
 
         n_c = meta.chunk_rows
@@ -828,20 +889,27 @@ class GQAttention(nn.Module):
         def own(a):  # the chunk's slot of a per-slot array
             return jax.lax.dynamic_slice_in_dim(a, meta.chunk_slot, 1, 0)
 
-        C = k.shape[1]
+        def own_key(a):  # and of a key, its parts side by side
+            if isinstance(a, tuple):
+                return whole_key(tuple(own(part) for part in a))
+            return own(a)
+
+        C = v.shape[1]
         blocked = (
             backend != "dense" and not meta.global_pages
             and 4 * n_c * q.shape[2] * C > _CHUNK_SCORES_LIMIT
-            and chunk_attention_eligible(n_c, C, q.shape[3])
+            and chunk_attention_eligible(n_c, C, q.shape[3], v.shape[3])
         )
         if ring or blocked:
             lanes = meta.replace(chunk_rows=0, chunk_slot=None,
                                  chunk_start=None)
             if ring:
-                out_d = self._ring_lanes(q[:n_d], k, v, lanes, backend)
+                out_d = self._ring_lanes(q[:n_d], k, v, lanes, backend,
+                                         scale, sink)
             else:
                 out_d = self._ragged_attention(
-                    q[:n_d], k, v, lanes, cache_index[:n_d], None, backend
+                    q[:n_d], k, v, lanes, cache_index[:n_d], None, backend,
+                    scale, sink,
                 )
             # The chunk's keys, by position: a ring's pages hold what the
             # table says after this chunk's rows are written; whole pages
@@ -857,23 +925,27 @@ class GQAttention(nn.Module):
                 kpos = jnp.where(kpos < end, kpos, -1)
             if blocked:
                 out_c = chunk_attention(
-                    q_c[0], own(k)[0], own(v)[0], pos_c[0], kpos[0],
-                    window, jnp.minimum(end, C),
+                    q_c[0], own_key(k)[0], own(v)[0], pos_c[0], kpos[0],
+                    window, jnp.minimum(end, C), scale=scale, sink=sink,
                 )[None]
             else:
                 out_c = banded_attention_xla(
-                    q_c, own(k), own(v), pos_c, kpos, window
+                    q_c, own_key(k), own(v), pos_c, kpos, window,
+                    scale=scale, sink=sink,
                 )
         elif backend == "dense":
             out_d = self._xla_attention(
-                q[:n_d], k, v, True, cache_index[:n_d]
+                q[:n_d], k, v, True, cache_index[:n_d], scale, sink
             )
-            out_c = self._xla_attention(q_c, own(k), own(v), True, start)
+            out_c = self._xla_attention(
+                q_c, own_key(k), own(v), True, start, scale, sink
+            )
         else:
             lanes = meta.replace(chunk_rows=0, chunk_slot=None,
                                  chunk_start=None)
             out_d = self._ragged_attention(
-                q[:n_d], k, v, lanes, cache_index[:n_d], None, backend
+                q[:n_d], k, v, lanes, cache_index[:n_d], None, backend,
+                scale, sink,
             )
             if meta.global_pages:
                 # The slot's logical pages may live anywhere in the
@@ -886,14 +958,14 @@ class GQAttention(nn.Module):
                     kind="prefill", extent=None,
                 )
             else:
-                k_c, v_c, own_meta = own(k), own(v), None
+                k_c, v_c, own_meta = own_key(k), own(v), None
             out_c = self._ragged_attention(
-                q_c, k_c, v_c, own_meta, start, pos_c, backend
+                q_c, k_c, v_c, own_meta, start, pos_c, backend, scale, sink
             )
         return jnp.concatenate([out_d, out_c[0][:, None]], axis=0)
 
     def _ragged_attention(self, q, k, v, meta, cache_index, positions,
-                          backend):
+                          backend, scale=None, sink=None):
         """Dispatch decode/prefill attention through the ragged
         paged-attention interface. Callers on the slot-paged KV pool pass
         a LaneMeta carrying the pool's page table and a static resident-
@@ -922,7 +994,7 @@ class GQAttention(nn.Module):
                 lengths=lengths,
                 window=self._window(),
                 kind="decode" if Sq == 1 else "prefill",
-                page_size=implied_page_size(k.shape[1]),
+                page_size=implied_page_size(v.shape[1]),
             )
         if meta.window != self._window():
             # The caller's one window for the model; this layer has its own.
@@ -931,21 +1003,32 @@ class GQAttention(nn.Module):
             q, k, v, meta,
             backend=backend,
             positions=positions if Sq > 1 else None,
+            scale=scale, sink=sink,
         )
 
-    def _xla_attention(self, q, k, v, decoding: bool, cache_index):
+    def _xla_attention(self, q, k, v, decoding: bool, cache_index,
+                       scale=None, sink=None):
         """Einsum attention fallback (ref core/model.py:783 _standard_attention).
 
         Grouped heads handled by reshape [B,S,Kv,G,D] — XLA maps the group
         dim onto the MXU batch dims; no head replication materialized.
         Honors config.attention_window (sliding window) in both the full
-        and the decode (KV cache) paths.
+        and the decode (KV cache) paths. `scale`: the score scale where
+        q and k come wider than the head (a key kept in parts); `sink`:
+        the layer's sink logits (sink_softmax).
         """
+        from luminaai_tpu.ops.ragged_paged_attention import (
+            sink_softmax,
+            whole_key,
+        )
+
+        k = whole_key(k)
         B, Sq, n_q, d = q.shape
-        Skv, n_kv = k.shape[1], k.shape[2]
+        Skv, n_kv, dv = k.shape[1], k.shape[2], v.shape[3]
         g = n_q // n_kv
         qg = q.reshape(B, Sq, n_kv, g, d)
-        scale = 1.0 / jnp.sqrt(d).astype(jnp.float32)
+        if scale is None:
+            scale = 1.0 / jnp.sqrt(d).astype(jnp.float32)
         logits = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k).astype(jnp.float32) * scale
 
         w = self._window()
@@ -965,9 +1048,9 @@ class GQAttention(nn.Module):
             if w is not None:
                 mask = jnp.logical_and(mask, qp - kp < w)
             logits = jnp.where(mask[:, None, None], logits, -1e30)
-            probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+            probs = sink_softmax(logits, sink).astype(q.dtype)
             out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v)
-            return out.reshape(B, Sq, n_q, d)
+            return out.reshape(B, Sq, n_q, dv)
 
         q_pos = jnp.arange(Sq)[:, None]
         if decoding:
@@ -992,9 +1075,9 @@ class GQAttention(nn.Module):
             if w is not None:
                 mask = jnp.logical_and(mask, q_pos - k_pos < w)
         logits = jnp.where(mask[None, None, None], logits, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+        probs = sink_softmax(logits, sink).astype(q.dtype)
         out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v)
-        return out.reshape(B, Sq, n_q, d)
+        return out.reshape(B, Sq, n_q, dv)
 
 
 @struct.dataclass

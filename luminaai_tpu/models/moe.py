@@ -298,7 +298,10 @@ class MoELayer(nn.Module):
             rule["select_bias"] = self.param(
                 "selection_bias",
                 nn.with_logical_partitioning(
-                    nn.initializers.zeros, (None,)
+                    nn.initializers.normal(cfg.moe_selection_bias_init_std)
+                    if cfg.moe_selection_bias_init_std
+                    else nn.initializers.zeros,
+                    (None,),
                 ),
                 (E,),
                 jnp.float32,

@@ -526,6 +526,7 @@ def flash_attention(
     block_q: int = 512,
     block_kv: int = 512,
     window: Optional[int] = None,
+    sink: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Flash attention over [B, S, H, D] tensors (differentiable).
 
@@ -533,11 +534,24 @@ def flash_attention(
     downward to the largest divisor of the sequence length (>= 128, else
     this raises — gate with flash_eligible); head_dim should be a multiple
     of 64.
+
+    `sink` [Hq] float32 (Config.layer_sink): one more column of logit
+    sink[h] in every row's softmax that gives no value. The kernels are
+    the plain ones: with the row's logsumexp L, the sink's share of the
+    denominator is exp(sink) / (exp(L) + exp(sink)), so the output is the
+    plain one times sigmoid(L - sink), exactly; the gradients of q, k, v
+    and of the sink follow through the lse output's own cotangent.
     """
-    return flash_attention_with_lse(
+    out, lse = flash_attention_with_lse(
         q, k, v, causal=causal, scale=scale,
         block_q=block_q, block_kv=block_kv, window=window,
-    )[0]
+    )
+    if sink is None:
+        return out
+    keep = jax.nn.sigmoid(lse - sink.astype(jnp.float32)[None, :, None])
+    return (
+        out.astype(jnp.float32) * keep.transpose(0, 2, 1)[..., None]
+    ).astype(out.dtype)
 
 
 def flash_attention_on_mesh(q, k, v, mesh, q_spec, kv_spec, **kw):
@@ -560,9 +574,10 @@ def flash_attention_on_mesh(q, k, v, mesh, q_spec, kv_spec, **kw):
 
     from luminaai_tpu.parallel.mesh import shard_map
 
+    sink = kw.pop("sink", None)
     if mesh is None or mesh.size == 1 or jax.sharding.get_abstract_mesh(
     ).manual_axes:
-        return flash_attention(q, k, v, **kw)
+        return flash_attention(q, k, v, sink=sink, **kw)
 
     def fits(axes, *dims):
         # shard_map wants even splits; GSPMD would pad. A dim the axes do
@@ -576,6 +591,13 @@ def flash_attention_on_mesh(q, k, v, mesh, q_spec, kv_spec, **kw):
         fits(q_spec[0], q.shape[0]), None,
         fits(heads, q.shape[2], k.shape[2]), None,
     )
+    if sink is not None:
+        # A logit a query head: split as the heads are.
+        return shard_map(
+            lambda q, k, v, b: flash_attention(q, k, v, sink=b, **kw),
+            mesh, in_specs=(spec, spec, spec, P(spec[2])), out_specs=spec,
+            check_vma=False,
+        )(q, k, v, sink)
     return shard_map(
         lambda q, k, v: flash_attention(q, k, v, **kw),
         mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False,

@@ -43,6 +43,7 @@ a drop-in backend (`config.attention_backend`).
 from __future__ import annotations
 
 import functools
+import operator
 from typing import Optional, Tuple
 
 import jax
@@ -154,6 +155,38 @@ def implied_page_size(cache_rows: int) -> int:
     return cache_rows
 
 
+def whole_key(k):
+    """A key kept in parts (Config.key_parts: a tuple of [.., kv_heads,
+    value width] arrays, the last zero-padded) as one array of their
+    columns side by side; one array as it is."""
+    return jnp.concatenate(k, axis=-1) if isinstance(k, tuple) else k
+
+
+def sink_softmax(logits: jax.Array, sink: Optional[jax.Array]) -> jax.Array:
+    """Softmax over the last axis of float32 `logits` [B, Hkv, G, Sq, K]
+    with a learned SINK: one more column of logit sink[h] a query head
+    (`sink` [Hkv * G], Config.layer_sink) that takes its share of the
+    probability and gives no value, so a row's probabilities add up to
+    less than one. None: the plain softmax. A row that sees no key gives
+    zeros, not NaN."""
+    if sink is None:
+        return jax.nn.softmax(logits, axis=-1)
+    b = sink.astype(jnp.float32).reshape(1, *logits.shape[1:3], 1, 1)
+    m = jax.lax.stop_gradient(
+        jnp.maximum(jnp.max(logits, axis=-1, keepdims=True), b)
+    )
+    e = jnp.exp(logits - m)
+    return e / (jnp.sum(e, axis=-1, keepdims=True) + jnp.exp(b - m))
+
+
+def _scale_of(scale: Optional[float], d: int):
+    """The score scale: the caller's (a head padded past its own width
+    keeps its own D^-1/2), else the query's width's."""
+    if scale is not None:
+        return scale
+    return 1.0 / jnp.sqrt(d).astype(jnp.float32)
+
+
 # ---------------------------------------------------------------------------
 # Pure-XLA reference (parity oracle + fallback)
 # ---------------------------------------------------------------------------
@@ -163,11 +196,14 @@ def ragged_paged_attention_xla(
     v: jax.Array,
     meta: LaneMeta,
     positions: Optional[jax.Array] = None,
+    *, scale: Optional[float] = None, sink: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Length-masked paged attention, reference semantics.
 
-    q: [B, Sq, Hq, D]; k/v: [B, C, Hkv, D] flat with C == P * page_size
-    (the caller's resident-extent slice). positions: [B, Sq] absolute q
+    q: [B, Sq, Hq, D]; k: [B, C, Hkv, D] and v: [B, C, Hkv, Dv] flat with
+    C == P * page_size (the caller's resident-extent slice; k may come in
+    parts, whole_key). `scale` / `sink`: _scale_of, sink_softmax.
+    positions: [B, Sq] absolute q
     positions for prefill chunks (-1 rows are padding and fully masked);
     decode (Sq == 1) derives the q position from lengths.
 
@@ -183,8 +219,9 @@ def ragged_paged_attention_xla(
     into their slots. meta.extent slices the TABLE's logical pages, so
     compute/bytes still scale with tokens resident.
     """
+    k = whole_key(k)
     B, Sq, n_q, d = q.shape
-    C, n_kv = k.shape[1], k.shape[2]
+    C, n_kv, dv = k.shape[1], k.shape[2], v.shape[3]
     ps = meta.page_size
     if meta.global_pages:
         # Global gather: [T, C] pool rows -> [T*P_all, ps] physical
@@ -200,8 +237,8 @@ def ragged_paged_attention_xla(
             k.reshape(T * P_all, ps, n_kv, d), table, axis=0
         ).reshape(B, P_l * ps, n_kv, d)
         v = jnp.take(
-            v.reshape(T * P_all, ps, n_kv, d), table, axis=0
-        ).reshape(B, P_l * ps, n_kv, d)
+            v.reshape(T * P_all, ps, n_kv, dv), table, axis=0
+        ).reshape(B, P_l * ps, n_kv, dv)
         C = P_l * ps
     elif meta.page_table is not None and not meta.identity_pages:
         # Physical gather through the page table: [B, P] page ids pick
@@ -213,16 +250,16 @@ def ragged_paged_attention_xla(
         k = jnp.take_along_axis(
             paged, table[:, :, None, None, None], axis=1
         ).reshape(B, C, n_kv, d)
-        paged_v = v.reshape(B, P, ps, n_kv, d)
+        paged_v = v.reshape(B, P, ps, n_kv, dv)
         v = jnp.take_along_axis(
             paged_v, table[:, :, None, None, None], axis=1
-        ).reshape(B, C, n_kv, d)
+        ).reshape(B, C, n_kv, dv)
 
     g = n_q // n_kv
     qg = q.reshape(B, Sq, n_kv, g, d)
-    scale = 1.0 / jnp.sqrt(d).astype(jnp.float32)
     logits = (
-        jnp.einsum("bqhgd,bkhd->bhgqk", qg, k).astype(jnp.float32) * scale
+        jnp.einsum("bqhgd,bkhd->bhgqk", qg, k).astype(jnp.float32)
+        * _scale_of(scale, d)
     )
 
     if positions is not None:
@@ -234,9 +271,9 @@ def ragged_paged_attention_xla(
     if meta.window is not None:
         mask = jnp.logical_and(mask, qp - kp < meta.window)
     logits = jnp.where(mask[:, None, None], logits, NEG_INF)
-    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+    probs = sink_softmax(logits, sink).astype(q.dtype)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v)
-    return out.reshape(B, Sq, n_q, d)
+    return out.reshape(B, Sq, n_q, dv)
 
 
 # ---------------------------------------------------------------------------
@@ -289,29 +326,32 @@ def ring_key_positions(
 def banded_attention_xla(
     q: jax.Array, k: jax.Array, v: jax.Array,
     qpos: jax.Array, kpos: jax.Array, window: Optional[int],
+    *, scale: Optional[float] = None, sink: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Attention masked by POSITIONS given as data: q [B, Sq, Hq, D] at
-    qpos [B, Sq] over k / v [B, C, Hkv, D] whose row c holds position
-    kpos [B, c] (-1: nothing). Key j is seen by query i iff
-    0 <= i - j (< window). What ragged_paged_attention_xla computes when
-    kpos is the row number; a ring of pages hands the positions its rows
-    hold now."""
+    qpos [B, Sq] over k [B, C, Hkv, D] (or its parts, whole_key) and v
+    [B, C, Hkv, Dv] whose row c holds position kpos [B, c] (-1: nothing).
+    Key j is seen by query i iff 0 <= i - j (< window). What
+    ragged_paged_attention_xla computes when kpos is the row number; a
+    ring of pages hands the positions its rows hold now. `scale` /
+    `sink`: _scale_of, sink_softmax."""
+    k = whole_key(k)
     B, Sq, n_q, d = q.shape
     n_kv = k.shape[2]
     g = n_q // n_kv
     qg = q.reshape(B, Sq, n_kv, g, d)
-    scale = 1.0 / jnp.sqrt(d).astype(jnp.float32)
     logits = (
-        jnp.einsum("bqhgd,bkhd->bhgqk", qg, k).astype(jnp.float32) * scale
+        jnp.einsum("bqhgd,bkhd->bhgqk", qg, k).astype(jnp.float32)
+        * _scale_of(scale, d)
     )
     qp, kp = qpos[:, :, None], kpos[:, None, :]
     mask = jnp.logical_and(kp >= 0, kp <= qp)
     if window is not None:
         mask = jnp.logical_and(mask, qp - kp < window)
     logits = jnp.where(mask[:, None, None], logits, NEG_INF)
-    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+    probs = sink_softmax(logits, sink).astype(q.dtype)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v)
-    return out.reshape(B, Sq, n_q, d)
+    return out.reshape(B, Sq, n_q, v.shape[3])
 
 
 def latent_attention_xla(
@@ -348,32 +388,45 @@ def _chunk_key_block(rows: int) -> int:
     return rows
 
 
-def chunk_attention_eligible(n_rows: int, key_rows: int, head_dim: int
-                             ) -> bool:
+def chunk_attention_eligible(n_rows: int, key_rows: int, head_dim: int,
+                             v_dim: Optional[int] = None) -> bool:
     """Where the blocked kernel compiles for the chip: sublane-aligned
-    chunk, lane-aligned head, key rows in 128-row blocks. Off the chip it
-    is interpreted at any size (tests)."""
+    chunk, lane-aligned key and value widths (`head_dim` the key's as it
+    is handed over, a padded 192 being 256; `v_dim` the value's, None:
+    the same), key rows in 128-row blocks. Off the chip it is interpreted
+    at any size (tests)."""
     if _interpret():
         return True
     return (
-        n_rows % 8 == 0 and head_dim % 128 == 0 and key_rows % 128 == 0
+        n_rows % 8 == 0 and head_dim % 128 == 0
+        and (v_dim or head_dim) % 128 == 0 and key_rows % 128 == 0
     )
 
 
 def _chunk_kernel(
     blocks_ref,  # scalar prefetch [1]: key blocks that hold live rows
-    qpos_ref, kpos_ref, q_ref, k_ref, *rest, scale, window, v_dim,
+    qpos_ref, kpos_ref, q_ref, k_ref, *rest, scale, window, v_dim, sink,
 ):
     # `v_dim`: no v operand, the value is the key's first v_dim columns.
-    v_ref = None if v_dim else rest[0]
-    o_ref, m_scr, l_scr, acc_scr = rest[0 if v_dim else 1:]
+    # `sink`: one more operand, the head's sink logit on every lane.
+    rest = list(rest)
+    v_ref = None if v_dim else rest.pop(0)
+    sink_ref = rest.pop(0) if sink else None
+    o_ref, m_scr, l_scr, acc_scr = rest
     j = pl.program_id(1)
     nj = pl.num_programs(1)
 
     @pl.when(j == 0)
     def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
+        if sink:
+            # The sink is a column seen by every row that gives no value:
+            # the running maximum starts at its logit, the denominator
+            # at its exp(0).
+            m_scr[:] = jnp.broadcast_to(sink_ref[0, :1, :], m_scr.shape)
+            l_scr[:] = jnp.ones_like(l_scr)
+        else:
+            m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+            l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     @pl.when(j < blocks_ref[0])
@@ -415,11 +468,12 @@ def chunk_attention(
     q: jax.Array, k: jax.Array, v: Optional[jax.Array],
     qpos: jax.Array, kpos: jax.Array, window: Optional[int],
     live_rows: jax.Array, *, scale: Optional[float] = None,
-    v_dim: int = 0,
+    v_dim: int = 0, sink: Optional[jax.Array] = None,
 ) -> jax.Array:
     """The tick's prefill chunk over its own lane, blocked over the keys
     with an online softmax: q [n, Hq, D] at positions qpos [n] (-1: a
-    padding row, which sees nothing) over ONE lane's k / v [C, Hkv, D]
+    padding row, which sees nothing) over ONE lane's k [C, Hkv, D] and v
+    [C, Hkv, Dv] (Dv the value's own width, the output's)
     whose row c holds position kpos [c] (-1: nothing; whole pages hand
     their row numbers, a ring what ring_key_positions says). Key j is
     seen by query i iff 0 <= i - j (< window): banded_attention_xla's
@@ -427,7 +481,9 @@ def chunk_attention(
     traced); key blocks wholly past it cost neither a DMA nor a step's
     arithmetic. Grid (q head, key block): a head's [n, D] queries stay
     put while its k/v head's blocks stream past; [n, block] float32
-    scores live in VMEM and nowhere else. Returns [n, Hq, D].
+    scores live in VMEM and nowhere else. `sink` [Hq] float32
+    (sink_softmax's rule): a head's running maximum starts at its sink's
+    logit and its denominator at 1. Returns [n, Hq, Dv].
 
     A latent entry (models/layers.py LatentPages): `v=None` and
     `v_dim`, the value is the first v_dim columns of the ONE shared key
@@ -441,7 +497,7 @@ def chunk_attention(
     while v_dim and Hq % (2 * fold) == 0 and n * 2 * fold <= _CHUNK_FOLD_ROWS:
         fold *= 2
     if fold > 1:
-        assert k.shape[1] == 1, k.shape
+        assert k.shape[1] == 1 and sink is None, k.shape
         out = _chunk_call(
             q.reshape(n, Hq // fold, fold, D).transpose(0, 2, 1, 3)
             .reshape(n * fold, Hq // fold, D),
@@ -450,13 +506,15 @@ def chunk_attention(
         )
         return out.reshape(n, fold, Hq // fold, -1).transpose(
             0, 2, 1, 3).reshape(n, Hq, -1)
-    return _chunk_call(q, k, v, qpos, kpos, window, live_rows, scale, v_dim)
+    return _chunk_call(q, k, v, qpos, kpos, window, live_rows, scale, v_dim,
+                       sink)
 
 
-def _chunk_call(q, k, v, qpos, kpos, window, live_rows, scale, v_dim):
+def _chunk_call(q, k, v, qpos, kpos, window, live_rows, scale, v_dim,
+                sink=None):
     n, Hq, D = q.shape
     C, Hkv = k.shape[0], k.shape[1]
-    Dv = v_dim or D
+    Dv = v_dim or v.shape[2]
     group = Hq // Hkv
     bk = _chunk_key_block(C)
     nb = C // bk
@@ -476,7 +534,11 @@ def _chunk_call(q, k, v, qpos, kpos, window, live_rows, scale, v_dim):
             pl.BlockSpec((n, LANES), lambda h, j, blocks: (0, 0)),
             pl.BlockSpec((8, bk), kpos_map),
             pl.BlockSpec((1, n, D), lambda h, j, blocks: (h, 0, 0)),
-        ] + [pl.BlockSpec((1, bk, D), kv_map)] * (1 if v_dim else 2),
+            pl.BlockSpec((1, bk, D), kv_map),
+        ] + ([] if v_dim else [pl.BlockSpec((1, bk, Dv), kv_map)]) + (
+            [] if sink is None else
+            [pl.BlockSpec((1, 8, LANES), lambda h, j, blocks: (h, 0, 0))]
+        ),
         out_specs=pl.BlockSpec((1, n, Dv), lambda h, j, blocks: (h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((n, LANES), jnp.float32),
@@ -487,7 +549,7 @@ def _chunk_call(q, k, v, qpos, kpos, window, live_rows, scale, v_dim):
     out = pl.pallas_call(
         functools.partial(
             _chunk_kernel, scale=scale or 1.0 / (D**0.5),
-            window=int(window or 0), v_dim=v_dim,
+            window=int(window or 0), v_dim=v_dim, sink=sink is not None,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Hq, n, Dv), q.dtype),
@@ -506,6 +568,8 @@ def _chunk_call(q, k, v, qpos, kpos, window, live_rows, scale, v_dim):
         q.transpose(1, 0, 2),
         k.transpose(1, 0, 2),
         *(() if v_dim else (v.transpose(1, 0, 2),)),
+        *(() if sink is None else (jnp.broadcast_to(
+            sink.astype(jnp.float32)[:, None, None], (Hq, 8, LANES)),)),
     )
     return out.transpose(1, 0, 2)
 
@@ -524,14 +588,21 @@ _LANE_VMEM_LIMIT = 64 << 20
 
 
 def lane_attention_eligible(
-    n_q: int, n_kv: int, head_dim: int, page_size: int
+    n_q: int, n_kv: int, head_dim: int, page_size: int,
+    v_dim: Optional[int] = None,
 ) -> bool:
     """Where `lane_attention` is the lanes' decode attention on a TPU: a
     pure function of the shapes the dispatcher sees, every term a fact of
-    the layout: heads fill whole lanes; a pool row [kv_heads, head_dim]
-    is whole (8, 128) tiles or one head, so the pool flattens to
-    [rows x kv_heads, head_dim] for free; a page's score columns fill
-    whole lanes. `n_q` is in no term: a tile scores EVERY query head
+    the layout: heads fill whole lanes (`head_dim`: the width of an array
+    the entry keeps its key in, a whole key or one part of it; `v_dim`:
+    the value array's, None = the same); a pool row [kv_heads, width]
+    is whole (8, 128) tiles, one head, or 4 heads of one 128-lane tile
+    (the chip tiles such an array (4, 128), rows one after another), so
+    the pool flattens to [rows x kv_heads, width] for free: 4 k/v heads
+    of 256 columns do NOT (a copy of the pool in front of the kernel),
+    which is why a key wider than its value lies in value-width parts
+    (Config.key_parts); a page's score columns fill whole lanes. `n_q`
+    is in no term: a tile scores EVERY query head
     against every k/v head's keys in one matmul and a constant mask keeps
     each head's own, so one query head a k/v head (MHA) is the same
     program as sixteen, and the MXU's time a block follows the block's
@@ -543,16 +614,19 @@ def lane_attention_eligible(
     ahead at both kinds of lengths."""
     del n_q
     return (
-        head_dim % 128 == 0
-        and page_size % 8 == 0
-        and (n_kv == 1 or n_kv % 8 == 0)
+        page_size % 8 == 0
         and (page_size * n_kv) % 128 == 0
+        and all(
+            width % 128 == 0
+            and (n_kv == 1 or n_kv % 8 == 0 or (n_kv == 4 and width == 128))
+            for width in (head_dim, v_dim or head_dim)
+        )
     )
 
 
 def lane_attention_engaged(
     backend: Optional[str], s_q: int, n_q: int, n_kv: int, head_dim: int,
-    page_size: int,
+    page_size: int, v_dim: Optional[int] = None,
 ) -> bool:
     """Whether a decode batch's attention runs `lane_attention`: on a TPU
     under either ragged backend when the shapes are eligible; off it only
@@ -562,7 +636,7 @@ def lane_attention_engaged(
         return False
     if _interpret():
         return backend == "ragged"
-    return lane_attention_eligible(n_q, n_kv, head_dim, page_size)
+    return lane_attention_eligible(n_q, n_kv, head_dim, page_size, v_dim)
 
 
 def _largest_divisor(n: int, cap: int) -> int:
@@ -678,13 +752,19 @@ def _lane_kernel(
     held_ref,  # scalar prefetch [B * pages]: lane_pages_held, flat
     slot_ref,  # scalar prefetch [B * blocks]: the block's slot in the pool
     blk_ref,  # scalar prefetch [B * blocks]: and its block of that slot
-    rowh_ref, colh_ref, colk_ref, q_ref, k_ref, *rest,
-    scale, window, page_size, per_block, per_tile, cols, v_dim,
+    rowh_ref, colh_ref, colk_ref, q_ref, *rest,
+    scale, window, page_size, per_block, per_tile, cols, v_dim, parts, sink,
 ):
     del slot_ref, blk_ref  # the index maps read them
+    # `parts`: the key comes as that many operands, each as wide as the
+    # value, and the query's columns side by side in their order.
     # `v_dim`: no v operand, the value is the key's first v_dim columns.
-    v_ref = None if v_dim else rest[0]
-    o_ref, m_scr, l_scr, acc_scr, bias_scr = rest[0 if v_dim else 1:]
+    # `sink`: one more operand, each query head's sink logit on every lane.
+    rest = list(rest)
+    k_refs = [rest.pop(0) for _ in range(parts)]
+    v_ref = None if v_dim else rest.pop(0)
+    sink_ref = rest.pop(0) if sink else None
+    o_ref, m_scr, l_scr, acc_scr, bias_scr = rest
     b = pl.program_id(0)
     j = pl.program_id(1)
     nj = pl.num_programs(1)
@@ -701,8 +781,14 @@ def _lane_kernel(
 
     @pl.when(j == 0)
     def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
+        if sink:
+            # sink_softmax's rule: a column every query sees that gives
+            # no value. (A lane not stepped then reads 0 / 1.)
+            m_scr[:] = sink_ref[:]
+            l_scr[:] = jnp.ones_like(l_scr)
+        else:
+            m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+            l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     qpos = len_ref[b] - 1
@@ -718,12 +804,19 @@ def _lane_kernel(
         @pl.when(live)
         def _compute():
             at = pl.ds(pl.multiple_of(t * cols, cols), cols)
-            k = k_ref[0, at, :]  # [cols, D]: keys x k/v heads
-            v = k[:, :v_dim] if v_dim else v_ref[0, at, :]
-            s = jax.lax.dot_general(
-                q_ref[0], k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale + bias_scr[:]  # [Hq, cols]
+            # [cols, width] a part: keys x k/v heads
+            ks = [k_ref[0, at, :] for k_ref in k_refs]
+            v = ks[0][:, :v_dim] if v_dim else v_ref[0, at, :]
+            w = ks[0].shape[1]
+            s = functools.reduce(operator.add, [
+                jax.lax.dot_general(
+                    q_ref[0] if parts == 1 else q_ref[0, :, i * w:(i + 1) * w],
+                    k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                for i, k in enumerate(ks)
+            ])
+            s = s * scale + bias_scr[:]  # [Hq, cols]
             colk = colk_ref[:1, :]  # the column's key, within the tile
             kpos = jnp.where(held[0] >= 0, held[0] * page_size + colk, -1)
             for g in range(1, per_tile):
@@ -767,12 +860,17 @@ def _lane_kernel(
 def lane_attention(
     q: jax.Array, k: jax.Array, v: Optional[jax.Array], meta: LaneMeta,
     ring: bool = False, *, scale: Optional[float] = None, v_dim: int = 0,
+    sink: Optional[jax.Array] = None,
 ) -> jax.Array:
     """A decode batch's attention, one query row a lane, over the pool as
-    it lies: q [B, 1, Hq, D]; k / v [T, C, Hkv, D] with lane b in slot b
-    (T > B: a prefix-cache arena behind the lanes), whole pages or, with
-    `ring`, the lanes' rings of pages (LaneMeta.ring_table). Returns
-    [B, 1, Hq, D].
+    it lies: q [B, 1, Hq, D]; k [T, C, Hkv, D] and v [T, C, Hkv, Dv] with
+    lane b in slot b (T > B: a prefix-cache arena behind the lanes), whole
+    pages or, with `ring`, the lanes' rings of pages
+    (LaneMeta.ring_table). A key kept in parts (Config.key_parts): `k` a
+    tuple of [T, C, Hkv, Dv] arrays whose columns side by side are the D
+    of q; a tile's scores are the sum of a matmul a part. `sink` [Hq]
+    float32 (sink_softmax's rule): a head's running maximum starts at its
+    sink's logit and its denominator at 1. Returns [B, 1, Hq, Dv].
 
     Grid (lane, key block); a block is whole pages with every k/v head,
     so a row of k/v is fetched once. `meta.lengths` say what a lane
@@ -798,14 +896,17 @@ def lane_attention(
     of the key row, so a row is fetched once for both matmuls; `scale`
     is the mixer's own (default D^-1/2). Returns [B, 1, Hq, v_dim]."""
     assert q.shape[1] == 1, "one query row a lane"
-    assert k.shape[1] % meta.page_size == 0, (k.shape, meta.page_size)
+    ks = k if isinstance(k, tuple) else (k,)
+    assert ks[0].shape[1] % meta.page_size == 0, (ks[0].shape, meta.page_size)
+    assert sum(a.shape[3] for a in ks) == q.shape[3], (
+        q.shape, [a.shape for a in ks])
     if ring:
         # A ring is visited whole: without the tick's extent in it, the
         # ring layers of every tick program share one trace.
         meta = meta.replace(extent=None)
     return _lane_attention(
         q, k, v, meta, ring, _interpret(), _LANE_BLOCK_BYTES,
-        scale or 1.0 / (q.shape[3]**0.5), v_dim,
+        scale or 1.0 / (q.shape[3]**0.5), v_dim, sink,
     )
 
 
@@ -813,15 +914,16 @@ def lane_attention(
 # of one tick that share shapes (three rings) are traced and lowered once.
 @functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8))
 def _lane_attention(q, k, v, meta, ring, interpret, block_bytes, scale,
-                    v_dim):
+                    v_dim, sink=None):
     B, _, Hq, D = q.shape
-    Dv = v_dim or D
-    T, C, Hkv = k.shape[0], k.shape[1], k.shape[2]
+    ks = k if isinstance(k, tuple) else (k,)
+    Dv = v_dim or v.shape[3]
+    T, C, Hkv, W = ks[0].shape
     ps = meta.page_size
     group = Hq // Hkv
     lengths = meta.lengths.astype(jnp.int32)[:B]
     held, slot, blk, per_block, per_tile = lane_plan(
-        meta, B, C, Hkv, D, k.dtype.itemsize, ring, block_bytes
+        meta, B, C, Hkv, D, ks[0].dtype.itemsize, ring, block_bytes
     )
     nb = held.shape[1] // per_block
 
@@ -847,7 +949,9 @@ def _lane_attention(q, k, v, meta, ring, interpret, block_bytes, scale,
             pl.BlockSpec((8, cols), const),
             pl.BlockSpec((8, cols), const),
             pl.BlockSpec((1, Hp, D), own),
-        ] + [pl.BlockSpec((1, rows, D), kv_map)] * (1 if v_dim else 2),
+        ] + [pl.BlockSpec((1, rows, W), kv_map)] * len(ks) + (
+            [] if v_dim else [pl.BlockSpec((1, rows, Dv), kv_map)]
+        ) + ([] if sink is None else [pl.BlockSpec((Hp, LANES), const)]),
         out_specs=pl.BlockSpec((1, Hp, Dv), own),
         scratch_shapes=[
             pltpu.VMEM((Hp, LANES), jnp.float32),
@@ -860,7 +964,7 @@ def _lane_attention(q, k, v, meta, ring, interpret, block_bytes, scale,
         functools.partial(
             _lane_kernel, scale=scale, window=int(meta.window or 0),
             page_size=ps, per_block=per_block, per_tile=per_tile, cols=cols,
-            v_dim=v_dim,
+            v_dim=v_dim, parts=len(ks), sink=sink is not None,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hp, Dv), q.dtype),
@@ -876,9 +980,12 @@ def _lane_attention(q, k, v, meta, ring, interpret, block_bytes, scale,
         np.broadcast_to((col % Hkv)[None], (8, cols)),
         np.broadcast_to((col // Hkv)[None], (8, cols)),
         qf,
-        # The pool's row [Hkv, D] is whole tiles: flat for free.
-        k.reshape(T, C * Hkv, D),
-        *(() if v_dim else (v.reshape(T, C * Hkv, D),)),
+        # The pool's row [Hkv, width] is whole tiles: flat for free.
+        *(a.reshape(T, C * Hkv, W) for a in ks),
+        *(() if v_dim else (v.reshape(T, C * Hkv, Dv),)),
+        *(() if sink is None else (jnp.broadcast_to(
+            jnp.pad(sink.astype(jnp.float32), (0, Hp - Hq))[:, None],
+            (Hp, LANES)),)),
     )
     return out[:, None, :Hq]
 
@@ -891,6 +998,8 @@ def paged_attention(
     *,
     backend: str = "ragged",
     positions: Optional[jax.Array] = None,
+    scale: Optional[float] = None,
+    sink: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Backend dispatcher (config.attention_backend) over k / v as the
     pool keeps them, unsliced: a decode batch (one q row a lane) takes
@@ -899,9 +1008,12 @@ def paged_attention(
     'ragged' alone, interpreted), and the XLA reference otherwise, which
     reads the `meta.extent` slice of the rows. Prefill chunks (Sq > 1)
     always take the reference."""
-    _, Sq, Hq, D = q.shape
-    if lane_attention_engaged(backend, Sq, Hq, k.shape[2], D, meta.page_size):
-        return lane_attention(q, k, v, meta)
+    _, Sq, Hq, _ = q.shape
+    k0 = k[0] if isinstance(k, tuple) else k
+    if lane_attention_engaged(backend, Sq, Hq, k0.shape[2], k0.shape[3],
+                              meta.page_size, v.shape[3]):
+        return lane_attention(q, k, v, meta, scale=scale, sink=sink)
+    k = whole_key(k)
     if (
         not meta.global_pages
         and meta.extent is not None and meta.extent < k.shape[1]
@@ -911,4 +1023,5 @@ def paged_attention(
         # the page TABLE instead: physical pages may live in any slot.)
         k = jax.lax.slice_in_dim(k, 0, meta.extent, axis=1)
         v = jax.lax.slice_in_dim(v, 0, meta.extent, axis=1)
-    return ragged_paged_attention_xla(q, k, v, meta, positions=positions)
+    return ragged_paged_attention_xla(
+        q, k, v, meta, positions=positions, scale=scale, sink=sink)

@@ -596,6 +596,21 @@ class ContinuousScheduler:
             "tick's extent where XLA attends the lanes, the stepped "
             "lanes' live key blocks where the decode kernel does",
         )
+        # The same reads in bytes: rows of two kinds of layer are not one
+        # size (k/v heads a layer, a key kept in parts), so bytes are
+        # what a tick's attention costs.
+        self._m_kv_window_bytes = r.counter(
+            "serve_kv_window_bytes_read_total",
+            "Bytes of k/v behind serve_kv_window_rows_read_total: each "
+            "layer's rows x that layer's own row bytes (k and v as "
+            "stored)",
+        )
+        self._m_kv_global_bytes = r.counter(
+            "serve_kv_global_bytes_read_total",
+            "Bytes of k/v behind serve_kv_global_rows_read_total: each "
+            "layer's rows x that layer's own row bytes (k and v as "
+            "stored)",
+        )
         # 'latent' layers keep pages of ONE row a token (LatentPages).
         self._m_kv_latent_rows = r.counter(
             "serve_kv_latent_rows_read_total",
@@ -663,6 +678,8 @@ class ContinuousScheduler:
             ("ssm_rows", self._m_ssm_rows),
             ("kv_window_rows", self._m_kv_window_rows),
             ("kv_global_rows", self._m_kv_global_rows),
+            ("kv_window_bytes", self._m_kv_window_bytes),
+            ("kv_global_bytes", self._m_kv_global_bytes),
             ("kv_latent_rows", self._m_kv_latent_rows),
             ("kv_latent_chunk_keys", self._m_kv_latent_chunk_keys),
             ("ring_wraps", self._m_ring_wraps),

@@ -17,11 +17,13 @@ import os
 
 import pytest
 
+from benchmark.tests import test_manifest as _manifest_tests
 from benchmark.tests import test_ttft_stages as _ttft_stages
 from benchmark.architectures.cohere2_moe.test_reference import *  # noqa: F401,F403
 from benchmark.architectures.jamba.test_reference import *  # noqa: F401,F403
 from benchmark.architectures.kimi_k2.test_reference import *  # noqa: F401,F403
 from benchmark.architectures.kimi_linear.test_reference import *  # noqa: F401,F403
+from benchmark.architectures.mimo_v2.test_reference import *  # noqa: F401,F403
 from benchmark.architectures.prenorm_decoder.test_reference import *  # noqa: F401,F403
 from benchmark.tests.test_architectures import *  # noqa: F401,F403
 from benchmark.tests.test_control_serve import *  # noqa: F401,F403
@@ -37,6 +39,7 @@ COLLECTED = (
     "benchmark/architectures/jamba/test_reference.py",
     "benchmark/architectures/kimi_k2/test_reference.py",
     "benchmark/architectures/kimi_linear/test_reference.py",
+    "benchmark/architectures/mimo_v2/test_reference.py",
     "benchmark/architectures/prenorm_decoder/test_reference.py",
     "benchmark/tests/test_architectures.py",
     "benchmark/tests/test_control_serve.py",
@@ -51,7 +54,7 @@ COLLECTED = (
 # benchmark/tests/test_architectures.py was written when the benchmark had
 # ONE architecture, and its resolver test asserts that every cell's is
 # `prenorm_decoder`. PR 35 adds a second one (PR 37 a third, PR 42 a fourth, PR 45 a
-# fifth), and a PR that adds to the
+# fifth, PR 50 a sixth), and a PR that adds to the
 # benchmark may not edit a file the benchmark has: the same test is taken
 # here with each cell held to the architecture its own configuration file
 # names, under the same name so that it is counted once. A `benchmark` PR
@@ -72,12 +75,39 @@ COLLECTED = (
 # are taken here with "the last four" read as "in this order, nothing
 # between them" and "the two cells" as "those two first"; a `benchmark` PR
 # should change them there and drop the overrides.
+#
+# benchmark/tests/test_manifest.py's `test_faults_are_found[chips]` makes
+# TWO cells four-chip and expects "too many four-chip cells": true while
+# the benchmark had up to seven cells (a quarter, rounded down, is one).
+# PR 50 brings the eighth, and two of eight are allowed. The same test is
+# taken here with that one case made of THREE cells; a `benchmark` PR
+# should change it there and drop the override.
 SUPERSEDED_HERE = (
+    "test_faults_are_found",
     "test_every_cell_resolves_its_architecture",
     "test_the_stage_means_add_up_to_the_programs_ttft",
     "test_the_manifest_is_sound_with_the_four_entries",
     "test_a_stage_metric_resolves_for_the_two_chat_cells",
 )
+
+
+_FAULTS = _manifest_tests.test_faults_are_found.pytestmark[0]
+
+
+@pytest.mark.parametrize("mutate,word", [
+    case if word != "four-chip" else (
+        lambda b: [w.update(chips=4) for w in b["workloads"][:3]], word)
+    for case in _FAULTS.args[1] for word in [case[1]]
+], ids=_FAULTS.kwargs["ids"])
+def test_faults_are_found(mutate, word):  # noqa: F811
+    import copy
+
+    from benchmark import manifest
+
+    bad = copy.deepcopy(_manifest_tests.BENCH)
+    mutate(bad)
+    faults = manifest.check(bad)
+    assert any(word in f for f in faults), faults
 
 
 def test_every_cell_resolves_its_architecture():  # noqa: F811
@@ -98,7 +128,7 @@ def test_every_cell_resolves_its_architecture():  # noqa: F811
                 assert hasattr(module, name), (part, name)
         assert set(arch.work.KERNEL_FNS) == manifest.kernel_names(arch.name)
     assert seen == {"prenorm_decoder", "kimi_linear", "jamba", "cohere2_moe",
-                    "kimi_k2"}
+                    "kimi_k2", "mimo_v2"}
 
 
 def test_the_stage_means_add_up_to_the_programs_ttft(window):  # noqa: F811
@@ -178,3 +208,21 @@ def test_every_benchmark_test_file_is_collected_here():
                     assert globals()[name] is not obj
                     continue
                 assert globals().get(name) is obj, (path, name)
+
+
+@pytest.mark.parametrize("section", ["configs", "workloads", "per_layer"])
+def test_every_line_of_text_in_the_manifest_fits(section):
+    """`manifest.check` measures a cell's `why` alone; the driver holds a
+    configuration's `why` and `source` and a metric's `layer` to the same
+    200 printable characters on one line (PR 50 was refused for a
+    configuration's `why` of 223)."""
+    import json
+
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    for entry in bench[section]:
+        for key in ("why", "source", "layer"):
+            if key in entry:
+                text = entry[key]
+                assert 1 <= len(text) <= 200, (entry["name"], key, len(text))
+                assert text.isprintable(), (entry["name"], key)

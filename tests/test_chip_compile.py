@@ -469,3 +469,72 @@ def test_a_training_steps_held_rows_return_as_a_scatter(one_chip):
     assert re.search(r"f32\[16384,2304\]\S* scatter\(", text)
     assert "moe_held_combine)/scatter-add" in text
     assert "moe_held_combine)/dot_general" not in text
+
+
+# -- the sink-and-window serving cell's kernels (mimo-v2-flash-serve-reason) --
+@pytest.mark.parametrize("rows,hkv,window,extent,ring", [
+    (512, 8, 128, None, True),
+    (10240, 4, None, 10240, False),
+    (10240, 4, None, 2048, False),
+], ids=["window_ring_8_heads", "full_4_heads", "full_extent_2048"])
+def test_lane_attention_compiles_over_a_key_in_parts(
+        one_chip, rows, hkv, window, extent, ring):
+    """One attention layer's decode rows of one tick at MiMo-V2-Flash's
+    widths: 96 lanes of 64 query heads over a key of 192 columns kept as
+    two arrays of 128 (the second half zeros) beside a value of 128, 8 k/v
+    heads in a ring of 4 pages under the 128 window with the sink, 4 in
+    10,240 whole rows without. Every array of the entry goes in as it
+    lies: no copy of the pool in front of the kernel. (ONE array of 4
+    heads x 256 columns would be copied whole: the rule refuses it.)"""
+    lanes, hq = 96, 64
+    assert rpa.lane_attention_engaged("ragged_xla", 1, hq, hkv, 128, PAGE, 128)
+    assert not rpa.lane_attention_eligible(hq, 4, 256, PAGE, 128)
+
+    def decode(q, k0, k1, v, sink, lengths, ring_table):
+        meta = rpa.LaneMeta(
+            lengths=lengths, window=window, page_size=PAGE, extent=extent,
+            ring_table=ring_table if ring else None,
+        )
+        return rpa.lane_attention(q, (k0, k1), v, meta, ring=ring,
+                                  scale=192**-0.5,
+                                  sink=sink if ring else None)
+
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    kv = sds((lanes, rows, hkv, 128), BF16)
+    compiled = jax.jit(decode).lower(
+        sds((lanes, 1, hq, 256), BF16), kv, kv, kv, sds((hq,), jnp.float32),
+        sds((lanes,), jnp.int32), sds((lanes, 80), jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "lane_attention" in text
+    mem = compiled.memory_analysis()
+    pool = 3 * lanes * rows * hkv * 128 * 2
+    # (the queries are 3 MB beside a ring's 150)
+    assert mem.argument_size_in_bytes < pool + (4 << 20)
+    assert mem.temp_size_in_bytes < pool // 100
+    assert mem.output_size_in_bytes == lanes * hq * 128 * 2
+
+
+@pytest.mark.parametrize("rows,hkv,window", [(512, 8, 128), (10240, 4, None)],
+                         ids=["ring_of_4_pages", "whole_pages"])
+def test_chunk_attention_compiles_with_values_narrower_than_keys(
+        one_chip, rows, hkv, window):
+    """One attention layer of one tick at MiMo-V2-Flash's widths: a
+    256-row chunk of 64 query heads over one lane's key (its two parts
+    side by side: 256 columns) and value (128), with the window layers'
+    sink."""
+    i32 = jnp.int32
+
+    def run(q, k, v, sink, qpos, kpos, live):
+        return rpa.chunk_attention(q, k, v, qpos, kpos, window, live,
+                                   scale=192**-0.5,
+                                   sink=sink if window else None)
+
+    text = _compile(
+        run, one_chip, ((256, 64, 256), BF16), ((rows, hkv, 256), BF16),
+        ((rows, hkv, 128), BF16), ((64,), jnp.float32), ((256,), i32),
+        ((rows,), i32), ((), i32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "chunk_attention" in text
+    assert rpa.chunk_attention_eligible(256, rows, 256, 128)

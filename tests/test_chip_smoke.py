@@ -8,6 +8,7 @@ once, at a tiny size — that its phases still drive train -> checkpoint ->
 serve through the real entry points.
 """
 
+import gc
 import json
 import os
 import shutil
@@ -231,10 +232,14 @@ def test_phases_drive_train_checkpoint_serve_at_a_tiny_size(tmp_path):
         use_flash_attention=False, moe_dispatch="gmm",
         learning_rate=1e-3,
     )
+    # (What earlier tests of this worker's process left alive is not the
+    # training run's: tests/test_inference.py alone leaves 2 MB.)
+    gc.collect()
+    live_before = sum(a.nbytes for a in jax.live_arrays())
     train = chip_smoke.phase_train(cfg, 4, str(tmp_path), ())
     assert train["steps"] == 4 and train["kernels"] == {}
     assert train["last_loss"] < train["first_loss"]
-    ckpt = chip_smoke.phase_checkpoint(str(tmp_path))
+    ckpt = chip_smoke.phase_checkpoint(str(tmp_path), live_before)
     serve = chip_smoke.phase_serve(ckpt, max_new_tokens=3, timeout=120)
     assert serve["attention_backend"] == "ragged_xla"
     assert serve["decode_steps"] > 0 and all(serve["tokens"])

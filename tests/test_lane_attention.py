@@ -268,10 +268,13 @@ SERVED = {
     "command-a-plus-serve-mixed": (128, 8, 128, 128),  # 16 a k/v head
     "kimi-k2-7-code-serve-longctx": (64, 1, 640, 128),  # one latent row
     "mistral-7b-v03, were it served": (32, 8, 128, 128),  # a group of 4
+    # (PR 50) 4 k/v heads of ONE 128-lane tile: the chip tiles such an
+    # array (4, 128), rows one after another, so it flattens for free
+    "mimo-v2-flash-serve-reason, full layers": (64, 4, 128, 128),
 }
 NOT_BY_LAYOUT = {
     "the flagship preset: half a lane a head": (16, 8, 64, 128),
-    "half a tile a row": (64, 4, 128, 128),
+    "half a tile a row, two tiles wide": (64, 4, 256, 128),
     "two k/v heads": (16, 2, 128, 128),
     "an unaligned page": (128, 8, 128, 12),
     "a page short of whole lanes": (16, 8, 128, 8),

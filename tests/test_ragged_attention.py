@@ -185,7 +185,9 @@ def test_dispatcher_gating():
     # the layout's terms, and nothing else
     assert not lane_attention_eligible(128, 8, 64, 128)  # half a lane
     assert not lane_attention_eligible(128, 8, 128, 12)  # unaligned page
-    assert not lane_attention_eligible(64, 4, 128, 128)  # half a tile a row
+    # 4 k/v heads: one 128-lane tile a head flattens for free, two do not
+    assert lane_attention_eligible(64, 4, 128, 128)
+    assert not lane_attention_eligible(64, 4, 256, 128)
     assert lane_attention_engaged("ragged", 1, 2, 1, 48, 8)
     assert not lane_attention_engaged("ragged", 4, 2, 1, 64, 8)  # multi-row q
     assert not lane_attention_engaged("ragged_xla", 1, 128, 8, 128, 128)

@@ -142,7 +142,9 @@ def serve(tiny, requests, *, slots=2, chunk=6, cap=CAP, ticks=400):
     dec = engine.make_stepwise(num_slots=slots, page_size=PAGE,
                                max_slot_tokens=cap,
                                prefill_chunk_tokens=chunk)
-    emb = np.asarray(tiny["params"]["embedder"]["embedding"])
+    # (An untied stack hands its own head: tests/test_sink_window_serving.)
+    emb = np.asarray(
+        tiny.get("head", tiny["params"]["embedder"]["embedding"]))
     S, n = dec.num_slots, dec.prefill_chunk
     rows, seqs, lane, pending, done = {}, {}, {}, [], set()
     todo = sorted(requests, key=lambda r: r[2])
@@ -509,9 +511,9 @@ def test_the_lanes_kernel_serves_the_same_rows(tiny, monkeypatch):
     calls = []
     kernel = rpa.lane_attention
 
-    def counted(q, k, v, meta, ring=False):
+    def counted(q, k, v, meta, ring=False, **kw):
         calls.append((ring, k.shape[1]))
-        return kernel(q, k, v, meta, ring=ring)
+        return kernel(q, k, v, meta, ring=ring, **kw)
 
     monkeypatch.setattr(rpa, "lane_attention", counted)
     served = dict(tiny, cfg=tiny_config(attention_backend="ragged"))
@@ -580,10 +582,10 @@ def test_an_mha_stacks_lanes_go_through_the_kernel_at_every_row(
     calls = []
     kernel = rpa.lane_attention
 
-    def counted(q, k, v, meta, ring=False):
+    def counted(q, k, v, meta, ring=False, **kw):
         calls.append((q.shape[2], k.shape[2], k.shape[1]))
         meta = meta.replace(lengths=jnp.maximum(meta.lengths - off_by, 0))
-        return kernel(q, k, v, meta, ring=ring)
+        return kernel(q, k, v, meta, ring=ring, **kw)
 
     monkeypatch.setattr(rpa, "lane_attention", counted)
     requests = [("a", _prompt(8, 43), 0), ("b", _prompt(9, 11), 3)]
