@@ -15,6 +15,18 @@ around it, per token x in R^hidden:
     o        = delta_rule(q, k, v, g, beta)
     y        = W_o [rmsnorm_d(o; w) * sigmoid(W_g2 W_g1 x)]
 
+The first two lines (convolution, SiLU, l2 norm and q's scale, from the
+q/k/v projection's output to q, k, v in the activations' dtype) are ONE
+kernel forward and one backward wherever the recurrence's kernels run:
+ops/kda.py::qkv_prepare (float32 in VMEM alone; in XLA they were float32
+passes over [B, T, 3 * heads * d] forward, again under remat, and
+backward). `qkv_plain` below is the same arithmetic in plain jax: the form
+the tests hold the kernel to, init's path, and the path of a head size the
+kernels do not tile. q, k, v, g and o stay [B, T, heads * d] from the
+projections to the output norm, the layout the kernels read. The decay,
+beta's fold into k and v (ops/kda.py::kda_flat) and the output norm x gate
+are still XLA's.
+
 Training only: serving needs the state a lane, which inference/ does not
 carry yet (GenerationEngine refuses a Config with this mixer).
 """
@@ -54,6 +66,25 @@ def causal_conv(x: jax.Array, w: jax.Array) -> jax.Array:
     T = x.shape[1]
     xp = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
     return sum(xp[:, i:i + T] * w[i] for i in range(K))
+
+
+def qkv_plain(qkv: jax.Array, conv: jax.Array, head_dim: int):
+    """q, k, v [B, T, D] in qkv's dtype from the q/k/v projection's output
+    qkv [B, T, 3*D] and the three convolutions' taps conv [K, 3*D], in
+    plain jax: what ops/kda.py::qkv_prepare computes in one kernel, the
+    form its tests hold it to, and the path for head sizes the kernels do
+    not tile."""
+    B, T, D3 = qkv.shape
+    y = jax.nn.silu(causal_conv(qkv.astype(jnp.float32), conv))
+    q, k, v = (t.reshape(B, T, -1, head_dim)
+               for t in jnp.split(y, 3, axis=-1))
+
+    def unit(t):
+        return t * jax.lax.rsqrt(
+            jnp.sum(jnp.square(t), axis=-1, keepdims=True) + 1e-6)
+
+    return tuple(t.astype(qkv.dtype).reshape(B, T, D3 // 3)
+                 for t in (unit(q) * head_dim ** -0.5, unit(k), v))
 
 
 class KimiDeltaAttention(nn.Module):
@@ -99,33 +130,28 @@ class KimiDeltaAttention(nn.Module):
 
         x = x.astype(self.dtype)
         qkv = jnp.einsum("bth,hf->btf", x, wqkv.astype(self.dtype))
-        qkv = jax.nn.silu(causal_conv(qkv.astype(f32), conv))
-        q, k, v = (t.reshape(B, T, n, d) for t in jnp.split(qkv, 3, axis=-1))
-
-        def unit(t):
-            return t * jax.lax.rsqrt(
-                jnp.sum(jnp.square(t), axis=-1, keepdims=True) + 1e-6)
-
-        q = (unit(q) * d ** -0.5).astype(self.dtype)
-        k = unit(k).astype(self.dtype)
-        v = v.astype(self.dtype)
+        # init traces a one-row dummy; only the shapes survive it.
+        if self.is_initializing() or not kda_ops.qkv_prepare_eligible(
+                d, cfg.kda_conv_size):
+            q, k, v = qkv_plain(qkv, conv, d)
+        else:
+            q, k, v = kda_ops.qkv_prepare(qkv, conv, heads=n, head_dim=d)
 
         low = jnp.einsum("bth,hr->btr", x, jnp.concatenate(
             [w_a1, w_g1], axis=1).astype(self.dtype))
         a = jnp.einsum("btr,rf->btf", low[..., :r], w_a2.astype(self.dtype))
-        g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(
-            a.astype(f32) + dt_bias).reshape(B, T, n, d)
+        g = -jnp.repeat(jnp.exp(a_log), d) * jax.nn.softplus(
+            a.astype(f32) + dt_bias)
         beta = jax.nn.sigmoid(jnp.einsum(
             "bth,hn->btn", x, w_beta.astype(self.dtype)).astype(f32))
 
         if self.is_initializing():
-            # init traces a one-row dummy; only the shapes survive it.
-            o = jnp.zeros((B, T, n, d), self.dtype)
+            o = jnp.zeros((B, T, D), self.dtype)
         else:
             with jax.named_scope("kda"):
-                o = kda_ops.kda(q, k, v, g, beta)
+                o = kda_ops.kda_flat(q, k, v, g, beta)
 
-        o32 = o.astype(f32)
+        o32 = o.reshape(B, T, n, d).astype(f32)
         o32 = o32 * jax.lax.rsqrt(
             jnp.mean(jnp.square(o32), axis=-1, keepdims=True)
             + cfg.rms_norm_eps) * o_norm

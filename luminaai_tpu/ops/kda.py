@@ -72,8 +72,14 @@ block's backward, and a layer builds the inverse once a step: 90 + 2 x 46 +
 
 Layout: q, k, g [B, T, H, dk]; v [B, T, H, dv]; beta [B, T, H]. The
 kernels read [B, T, H*d] blocks of (1, C, d) directly, no head-major
-transpose. beta is folded into k and v outside the kernel (one fused
-elementwise pass in XLA, which also carries its gradient).
+transpose (`kda_flat` takes that layout as it is). beta is folded into k
+and v outside the kernel (one fused elementwise pass in XLA, which also
+carries its gradient).
+
+What makes q, k and v is here too (`qkv_prepare`, below the recurrence):
+two more kernels, `qkv_prepare_fwd` and `qkv_prepare_bwd`, named apart
+from the three above because the benchmark's `kda_ms_step` and
+`kda_roofline` read every op called `kda_*` as the recurrence.
 
 Interpreted off the TPU: it asks `flash_attention._interpret()` through
 the module at call time, the one switch tests and benchmark/rehearse.py
@@ -426,6 +432,255 @@ def _kda_flat_bwd(H, C, mxu, res, do):
 _kda_flat.defvjp(_kda_flat_fwd, _kda_flat_bwd)
 
 
+# q, k and v from the q/k/v projection's output. A grid step's block of
+# that output, tokens x channels (whole heads), and the rows of it the
+# arithmetic walks at a time (a head's [_PREP_STRIP, 128] float32 stays in
+# vector registers, not a VMEM array). On a v5e at [2, 8192, 3 * 4096] the
+# forward / backward kernel took 2.60 / 5.81 ms at 256 x 512 blocks in
+# strips of 16, 2.23 / 5.05 at 32, 2.03 / 4.57 at 64, 1.93 / 4.36 at 128,
+# and 1.87 / 4.17 at 512 x 512 in strips of 128; blocks from 256 x 256 to
+# 512 x 1024 lie within 5% of one another at one strip.
+_PREP_ROWS = 512
+_PREP_LANES = 512
+_PREP_STRIP = 128
+# The rows in front of a block come as the 16-row tile before it (one bf16
+# tile), of which the last _PREP_PAD (one float32 tile) are kept: room for
+# convolutions of up to _PREP_PAD + 1 taps.
+_PREP_HALO = 16
+_PREP_PAD = 8
+# (the l2 norm a head, the scale d ** -0.5 after it) of the q, k, v thirds
+_PREP_THIRDS = ((True, True), (True, False), (False, False))
+
+
+def _prep_post(c, *, norm: bool, scaled: bool):
+    """What follows the convolution, on one head's [rows, d] float32:
+    SiLU, then for q and k the l2 norm over the head (eps 1e-6) and for q
+    the scale d ** -0.5. models/kda.py's plain form, line for line."""
+    s = jax.nn.silu(c)
+    if norm:
+        s = s * jax.lax.rsqrt(
+            jnp.sum(jnp.square(s), axis=-1, keepdims=True) + 1e-6)
+    if scaled:
+        s = s * c.shape[-1] ** -0.5
+    return s
+
+
+def _prep_fill(x_ref, halo_ref, xp_scr, first):
+    """The block and the _PREP_PAD rows before it (zeros in front of the
+    sequence) in float32 scratch: row r of the block lies at _PREP_PAD + r,
+    so tap i of K reads the rows from _PREP_PAD - (K - 1) + i."""
+    halo = halo_ref[0].astype(_F32)[_PREP_HALO - _PREP_PAD:]
+    xp_scr[:_PREP_PAD] = jnp.where(first, 0.0, halo)
+    xp_scr[_PREP_PAD:] = x_ref[0].astype(_F32)
+
+
+def _prep_strips(rows, lanes, d, strip):
+    """A block as (head's lanes, that head's strips of rows)."""
+    n = math.gcd(rows, strip)
+    for c0 in range(0, lanes, d):
+        yield slice(c0, c0 + d), [
+            slice(r0, r0 + n) for r0 in range(0, rows, n)]
+
+
+def _prep_conv(xp_scr, w_ref, rows, cols):
+    """(the K shifted views of a strip, their weighted sum in the plain
+    form's order)."""
+    K = w_ref.shape[0]
+    lo = _PREP_PAD - (K - 1)
+    xs = [xp_scr[rows.start + lo + i:rows.stop + lo + i, cols]
+          for i in range(K)]
+    return xs, sum(x * w_ref[i:i + 1, cols] for i, x in enumerate(xs))
+
+
+def _prep_fwd_kernel(*refs, d: int, strip: int):
+    x_refs, halo_refs, w_refs, o_refs = (refs[3 * n:3 * n + 3]
+                                         for n in range(4))
+    xp_scr = refs[12]
+    first = pl.program_id(2) == 0
+    for x_ref, halo_ref, w_ref, o_ref, (norm, scaled) in zip(
+            x_refs, halo_refs, w_refs, o_refs, _PREP_THIRDS):
+        _prep_fill(x_ref, halo_ref, xp_scr, first)
+        for cols, strips in _prep_strips(*o_ref.shape[1:], d, strip):
+            for rows in strips:
+                _, c = _prep_conv(xp_scr, w_ref, rows, cols)
+                o_ref[0, rows, cols] = _prep_post(
+                    c, norm=norm, scaled=scaled).astype(o_ref.dtype)
+
+
+def _prep_bwd_block(x_ref, halo_ref, w_ref, dy_ref, dx_ref, dw_ref, xp_scr,
+                    dc_scr, *, d, strip, norm, scaled, first, last):
+    """One block of one third, walked from the sequence's end: dc, the
+    convolution's cotangent, is formed in float32 scratch and never
+    written; the _PREP_PAD rows of it that the NEXT block (the one walked
+    before) began with sit behind this block's, for dx's taps."""
+    K = w_ref.shape[0]
+    bt = x_ref.shape[1]
+    _prep_fill(x_ref, halo_ref, xp_scr, first)
+    dc_scr[bt:] = jnp.where(last, 0.0, dc_scr[:_PREP_PAD])
+    post = functools.partial(_prep_post, norm=norm, scaled=scaled)
+    for cols, strips in _prep_strips(*x_ref.shape[1:], d, strip):
+        dw = [0.0] * K
+        for rows in strips:
+            xs, c = _prep_conv(xp_scr, w_ref, rows, cols)
+            dc, = jax.vjp(post, c)[1](dy_ref[0, rows, cols].astype(_F32))
+            dc_scr[rows, cols] = dc
+            dw = [a + jnp.sum(dc * x, axis=0, keepdims=True)
+                  for a, x in zip(dw, xs)]
+        for i, a in enumerate(dw):
+            dw_ref[i:i + 1, cols] += a
+        for rows in strips:  # x_t is tap i of y_{t + K - 1 - i}
+            dx_ref[0, rows, cols] = sum(
+                dc_scr[rows.start + K - 1 - i:rows.stop + K - 1 - i, cols]
+                * w_ref[i:i + 1, cols] for i in range(K)).astype(dx_ref.dtype)
+
+
+def _prep_bwd_kernel(x_ref, halo_ref, w_ref, dq_ref, dk_ref, dv_ref, dx_ref,
+                     dw_ref, xp_scr, dc_scr, *, d: int, strip: int, J: int):
+    third = pl.program_id(0) // J
+    t, nt = pl.program_id(2), pl.num_programs(2)
+
+    @pl.when((pl.program_id(1) == 0) & (t == 0))
+    def _init():
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+
+    for n, (dy_ref, (norm, scaled)) in enumerate(
+            zip((dq_ref, dk_ref, dv_ref), _PREP_THIRDS)):
+        @pl.when(third == n)
+        def _third(dy_ref=dy_ref, norm=norm, scaled=scaled):
+            _prep_bwd_block(
+                x_ref, halo_ref, w_ref, dy_ref, dx_ref, dw_ref, xp_scr,
+                dc_scr, d=d, strip=strip, norm=norm, scaled=scaled,
+                first=t == nt - 1, last=t == 0)
+
+
+def _prep_blocks(T, D, d):
+    """(rows, lanes, rows of a strip) of a grid step's block for
+    x [B, T, 3*D] of heads of d: whole tiles of rows (T is padded to whole
+    blocks of them), whole heads, lanes dividing D."""
+    return (min(_PREP_ROWS, -(-T // _PREP_HALO) * _PREP_HALO),
+            d * math.gcd(D // d, max(1, _PREP_LANES // d)), _PREP_STRIP)
+
+
+# jitted: a step traces and lowers the kernel once, not once a layer and
+# again under remat (the bodies are unrolled strip by strip). `interpret`
+# is an argument because jit's cache outlives a patched `_interpret()`.
+@functools.partial(jax.jit, static_argnames=("d", "blocks", "interpret"))
+def _prep_fwd_call(x, w, *, d, blocks, interpret):
+    B, T, D3 = x.shape
+    D = D3 // 3
+    bt, bd, strip = blocks
+    J, per = D // bd, bt // _PREP_HALO
+
+    def third(n):
+        return (
+            pl.BlockSpec((1, bt, bd), lambda b, j, t: (b, t, n * J + j)),
+            pl.BlockSpec((1, _PREP_HALO, bd), lambda b, j, t: (
+                b, jnp.maximum(t * per - 1, 0), n * J + j)),
+            pl.BlockSpec((w.shape[0], bd), lambda b, j, t: (0, n * J + j)))
+
+    xs, halos, ws = zip(*(third(n) for n in range(3)))
+    out = pl.BlockSpec((1, bt, bd), lambda b, j, t: (b, t, j))
+    return pl.pallas_call(
+        functools.partial(_prep_fwd_kernel, d=d, strip=strip),
+        grid=(B, J, T // bt),
+        in_specs=[*xs, *halos, *ws],
+        out_specs=[out] * 3,
+        out_shape=[jax.ShapeDtypeStruct((B, T, D), x.dtype)] * 3,
+        scratch_shapes=[pltpu.VMEM((_PREP_PAD + bt, bd), _F32)],
+        compiler_params=_params("parallel", "parallel", "parallel"),
+        interpret=interpret,
+        name="qkv_prepare_fwd",
+    )(x, x, x, x, x, x, w, w, w)
+
+
+@functools.partial(jax.jit, static_argnames=("d", "blocks", "interpret"))
+def _prep_bwd_call(x, w, dq, dk, dv, *, d, blocks, interpret):
+    """(dx in x's dtype, the taps' gradient [K, 3*D] float32). The grid is
+    (channel blocks of all three thirds, B, T blocks from the end): a
+    channel block's taps' gradient stays in VMEM while its B x T rows go
+    by; dq, dk and dv each stand at their first block while another
+    third's channels are walked (an unchanged block is not fetched)."""
+    B, T, D3 = x.shape
+    D = D3 // 3
+    bt, bd, strip = blocks
+    J, nt, per = D // bd, T // bt, bt // _PREP_HALO
+
+    def rows(t):
+        return nt - 1 - t
+
+    def cotangent(n):
+        def index(j, b, t):
+            mine = j // J == n
+            return tuple(jnp.where(mine, i, 0)
+                         for i in (b, rows(t), j - n * J))
+        return pl.BlockSpec((1, bt, bd), index)
+
+    block = pl.BlockSpec((1, bt, bd), lambda j, b, t: (b, rows(t), j))
+    taps = pl.BlockSpec((w.shape[0], bd), lambda j, b, t: (0, j))
+    return pl.pallas_call(
+        functools.partial(_prep_bwd_kernel, d=d, strip=strip, J=J),
+        grid=(3 * J, B, nt),
+        in_specs=[block,
+                  pl.BlockSpec((1, _PREP_HALO, bd), lambda j, b, t: (
+                      b, jnp.maximum(rows(t) * per - 1, 0), j)),
+                  taps, cotangent(0), cotangent(1), cotangent(2)],
+        out_specs=[block, taps],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
+                   jax.ShapeDtypeStruct(w.shape, _F32)],
+        scratch_shapes=[pltpu.VMEM((_PREP_PAD + bt, bd), _F32),
+                        pltpu.VMEM((bt + _PREP_PAD, bd), _F32)],
+        compiler_params=_params("parallel", "arbitrary", "arbitrary"),
+        interpret=interpret,
+        name="qkv_prepare_bwd",
+    )(x, x, w, dq, dk, dv)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _qkv_prepare(x, w, d, blocks):
+    return tuple(_prep_fwd_call(
+        x, w, d=d, blocks=blocks, interpret=_fa._interpret()))
+
+
+def _qkv_prepare_fwd(x, w, d, blocks):
+    return _qkv_prepare(x, w, d, blocks), (x, w)
+
+
+def _qkv_prepare_bwd(d, blocks, res, cts):
+    return tuple(_prep_bwd_call(
+        *res, *cts, d=d, blocks=blocks, interpret=_fa._interpret()))
+
+
+_qkv_prepare.defvjp(_qkv_prepare_fwd, _qkv_prepare_bwd)
+
+
+def qkv_prepare_eligible(head_dim: int, taps: int) -> bool:
+    """Heads as `kda_eligible` takes them, and taps that fit the rows kept
+    in front of a block."""
+    return kda_eligible(head_dim, head_dim) and taps <= _PREP_PAD + 1
+
+
+def qkv_prepare(x: jax.Array, conv_w: jax.Array, *, heads: int,
+                head_dim: int):
+    """q, k, v [B, T, heads*head_dim] in x's dtype from the q/k/v
+    projection's output x [B, T, 3*heads*head_dim] and the three causal
+    depthwise convolutions' taps conv_w [K, 3*heads*head_dim] (float32;
+    conv_w[K-1] is the tap on the token itself): convolution, SiLU, for q
+    and k the l2 norm a head (eps 1e-6), for q the scale head_dim ** -0.5.
+    One kernel forward and one backward; x is widened to float32 in VMEM
+    and each result rounded once, as models/kda.py's plain form rounds it.
+    Differentiable in x (the cotangent comes back in x's dtype) and conv_w.
+    Any T: the tail is padded to a whole block and cut off again."""
+    T, D = x.shape[1], heads * head_dim
+    assert x.shape[2] == 3 * D == conv_w.shape[1], (x.shape, conv_w.shape)
+    assert qkv_prepare_eligible(head_dim, conv_w.shape[0]), conv_w.shape
+    blocks = _prep_blocks(T, D, head_dim)
+    pad = -T % blocks[0]
+    if pad:
+        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
+    q, k, v = _qkv_prepare(x, conv_w.astype(_F32), head_dim, blocks)
+    return q[:, :T], k[:, :T], v[:, :T]
+
+
 def kda_eligible(dk: int, dv: int) -> bool:
     """The kernels tile heads of a multiple of 128 on the chip; interpreted
     (CPU tests) any size runs."""
@@ -444,21 +699,35 @@ def kda(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     four matmuls in bf16. Any T: the tail is padded to a whole chunk with
     tokens that write nothing (k = v = 0, g = 0) and cut off again.
     """
-    B, T, H, dk = q.shape
-    dv = v.shape[-1]
+    B, T, H, _ = q.shape
+    o = kda_flat(*(x.reshape(B, T, -1) for x in (q, k, v, g)), beta,
+                 chunk=chunk, mxu_dtype=mxu_dtype)
+    return o.reshape(B, T, H, v.shape[-1])
+
+
+def kda_flat(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
+             beta: jax.Array, *, chunk: int = CHUNK,
+             mxu_dtype=None) -> jax.Array:
+    """`kda` in the layout the kernels read: q, k, g [B, T, H*dk],
+    v [B, T, H*dv], beta [B, T, H]; returns o [B, T, H*dv]. On the chip a
+    [B, T, H, d] view of such an array is a relayout (another tiling), and
+    the way back here a second: a caller that has the projections' outputs
+    as they come keeps them so."""
+    B, T, H = beta.shape
+    dk, dv = q.shape[-1] // H, v.shape[-1] // H
     if not kda_eligible(dk, dv):
-        return kda_recurrent(q, k, v, g, beta)
-    b = beta.astype(_F32)[..., None]
-    kb = (k.astype(_F32) * b).astype(k.dtype)
-    vb = (v.astype(_F32) * b).astype(v.dtype)
-    g = g.astype(_F32)
+        heads = lambda x: x.reshape(B, T, H, -1)  # noqa: E731
+        return kda_recurrent(
+            *(heads(x) for x in (q, k, v, g)), beta).reshape(v.shape)
+    b = beta.astype(_F32)
+    kb = (k.astype(_F32) * jnp.repeat(b, dk, axis=-1)).astype(k.dtype)
+    vb = (v.astype(_F32) * jnp.repeat(b, dv, axis=-1)).astype(v.dtype)
+    args = [q, k, kb, vb, g.astype(_F32)]
     pad = -T % chunk
-    flat = lambda x: x.reshape(B, T, H * x.shape[-1])  # noqa: E731
-    args = [flat(x) for x in (q, k, kb, vb, g)]
     if pad:
         args = [jnp.pad(x, ((0, 0), (0, pad), (0, 0))) for x in args]
     o = _kda_flat(*args, H, chunk, jnp.dtype(mxu_dtype or q.dtype))
-    return o[:, :T].reshape(B, T, H, dv)
+    return o[:, :T]
 
 
 def kda_recurrent(q, k, v, g, beta) -> jax.Array:

@@ -282,6 +282,40 @@ def test_kda_kernels_compile_at_cell_shapes(one_chip, grad):
     assert ("kda_bwd" in text) is grad
 
 
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
+def test_qkv_prepare_compiles_at_cell_shapes(one_chip, grad):
+    """The delta-rule mixer's q, k and v from the projection's
+    bf16[2, 8192, 3 * 4096] (32 heads of 128): the shifted float32 reads of
+    the scratch, the lane sums a head and the one-row updates of the taps'
+    gradient must lower in Mosaic, and nothing float32 of that size may
+    stand around the kernels: forward + backward need the three bf16
+    cotangents and little else (the plain form's float32 temporaries are
+    4.03 GB there)."""
+    from luminaai_tpu.ops import kda
+
+    def run(x, w):
+        return kda.qkv_prepare(x, w, heads=KH, head_dim=128)
+
+    def both(x, w, dq, dk, dv):
+        out, vjp = jax.vjp(run, x, w)
+        return out, vjp((dq, dk, dv))
+
+    third = ((KB, KS, KH * 128), BF16)
+    shapes = (((KB, KS, 3 * KH * 128), BF16), ((4, 3 * KH * 128), jnp.float32))
+    if grad:
+        shapes += (third,) * 3
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in shapes]
+    compiled = jax.jit(both if grad else run).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == (
+        2 if grad else 1)
+    assert "qkv_prepare_fwd" in text
+    assert ("qkv_prepare_bwd" in text) is grad
+    assert "f32[2,8192," not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+
+
 def test_flash_compiles_with_values_narrower_than_scores(one_chip):
     """Latent attention's shapes: scores over 192, values and output of
     128, forward and both backward kernels."""

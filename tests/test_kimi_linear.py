@@ -239,10 +239,15 @@ def test_remat_keeps_the_inverse_and_reruns_the_forward_alone(policy, tri, fwd):
     names = [e.params["name"] for e in _eqns(
         jax.make_jaxpr(jax.grad(loss))(params).jaxpr)
         if e.primitive.name == "pallas_call"]
-    assert sorted(set(names)) == ["kda_bwd", "kda_fwd", "kda_tri"]
+    assert sorted(set(names)) == [
+        "kda_bwd", "kda_fwd", "kda_tri", "qkv_prepare_bwd", "qkv_prepare_fwd"]
     assert names.count("kda_tri") == tri
     assert names.count("kda_fwd") == fwd
     assert names.count("kda_bwd") == 2
+    # q, k and v are kept by neither policy: the block's backward makes
+    # them again from the projection's output, then differentiates once.
+    assert names.count("qkv_prepare_fwd") == 4
+    assert names.count("qkv_prepare_bwd") == 2
 
 
 def test_kda_recurrence_is_the_references_delta_rule():
