@@ -15,17 +15,25 @@ around it, per token x in R^hidden:
     o        = delta_rule(q, k, v, g, beta)
     y        = W_o [rmsnorm_d(o; w) * sigmoid(W_g2 W_g1 x)]
 
-The first two lines (convolution, SiLU, l2 norm and q's scale, from the
-q/k/v projection's output to q, k, v in the activations' dtype) are ONE
-kernel forward and one backward wherever the recurrence's kernels run:
-ops/kda.py::qkv_prepare (float32 in VMEM alone; in XLA they were float32
-passes over [B, T, 3 * heads * d] forward, again under remat, and
-backward). `qkv_plain` below is the same arithmetic in plain jax: the form
-the tests hold the kernel to, init's path, and the path of a head size the
-kernels do not tile. q, k, v, g and o stay [B, T, heads * d] from the
-projections to the output norm, the layout the kernels read. The decay,
-beta's fold into k and v (ops/kda.py::kda_flat) and the output norm x gate
-are still XLA's.
+XLA keeps the matmuls (W_q, W_k, W_v, W_a, W_g, w_beta, W_o); everything
+between them is a kernel wherever the recurrence's kernels run, each pair
+(forward, backward) reading [B, T, heads * d] once in the layout the
+projections write, float32 in VMEM alone, every result rounded once:
+
+  ops/kda.py::qkv_prepare   the first two lines: convolution, SiLU, l2
+                            norm and q's scale (in XLA: float32 passes
+                            over [B, T, 3 * heads * d]).
+  ops/kda.py::gated_kda     the decay g (float32) and beta, beta's fold
+                            into k and v, then the recurrence; ONE
+                            backward, so that k's cotangent is written
+                            once (in XLA: a per-head number reduced and
+                            broadcast in a head-a-row tiling, re-tiled
+                            both ways).
+  ops/kda.py::mixer_out     the output norm x gate in front of W_o.
+
+`qkv_plain`, `gates_plain` and `out_plain` below are the same arithmetic
+in plain jax: the forms the tests hold the kernels to, init's path, and
+the path of a head size the kernels do not tile.
 
 Training only: serving needs the state a lane, which inference/ does not
 carry yet (GenerationEngine refuses a Config with this mixer).
@@ -87,6 +95,31 @@ def qkv_plain(qkv: jax.Array, conv: jax.Array, head_dim: int):
                  for t in (unit(q) * head_dim ** -0.5, unit(k), v))
 
 
+def gates_plain(a: jax.Array, beta_logits: jax.Array, dt_bias: jax.Array,
+                a_log: jax.Array):
+    """(g [B, T, D] float32, beta [B, T, heads] float32) from the decay
+    projection's output a [B, T, D], beta's logits [B, T, heads],
+    dt_bias [D] and A_log [heads], in plain jax: with
+    ops/kda.py::fold_beta what `mixer_gates_fwd` computes."""
+    d = a.shape[-1] // a_log.shape[0]
+    g = -jnp.repeat(jnp.exp(a_log), d) * jax.nn.softplus(
+        a.astype(jnp.float32) + dt_bias)
+    return g, jax.nn.sigmoid(beta_logits.astype(jnp.float32))
+
+
+def out_plain(o: jax.Array, gate_logits: jax.Array, o_norm: jax.Array,
+              eps: float) -> jax.Array:
+    """rmsnorm_d(o; o_norm) * sigmoid(gate_logits) in o's dtype, o and the
+    logits [B, T, heads * d], o_norm [d], in plain jax: what
+    ops/kda.py::mixer_out computes in one kernel."""
+    B, T, D = o.shape
+    o32 = o.reshape(B, T, -1, o_norm.shape[0]).astype(jnp.float32)
+    o32 = o32 * jax.lax.rsqrt(
+        jnp.mean(jnp.square(o32), axis=-1, keepdims=True) + eps) * o_norm
+    gate = jax.nn.sigmoid(gate_logits.astype(jnp.float32))
+    return (o32.reshape(B, T, D) * gate).astype(o.dtype)
+
+
 class KimiDeltaAttention(nn.Module):
     config: Config
     dtype: Dtype = jnp.bfloat16
@@ -131,33 +164,34 @@ class KimiDeltaAttention(nn.Module):
         x = x.astype(self.dtype)
         qkv = jnp.einsum("bth,hf->btf", x, wqkv.astype(self.dtype))
         # init traces a one-row dummy; only the shapes survive it.
-        if self.is_initializing() or not kda_ops.qkv_prepare_eligible(
-                d, cfg.kda_conv_size):
-            q, k, v = qkv_plain(qkv, conv, d)
-        else:
+        init = self.is_initializing()
+        kernels = not init and kda_ops.kda_eligible(d, d)
+        if kernels and kda_ops.qkv_prepare_eligible(d, cfg.kda_conv_size):
             q, k, v = kda_ops.qkv_prepare(qkv, conv, heads=n, head_dim=d)
+        else:
+            q, k, v = qkv_plain(qkv, conv, d)
 
         low = jnp.einsum("bth,hr->btr", x, jnp.concatenate(
             [w_a1, w_g1], axis=1).astype(self.dtype))
         a = jnp.einsum("btr,rf->btf", low[..., :r], w_a2.astype(self.dtype))
-        g = -jnp.repeat(jnp.exp(a_log), d) * jax.nn.softplus(
-            a.astype(f32) + dt_bias)
-        beta = jax.nn.sigmoid(jnp.einsum(
-            "bth,hn->btn", x, w_beta.astype(self.dtype)).astype(f32))
+        beta_logits = jnp.einsum("bth,hn->btn", x, w_beta.astype(self.dtype))
+        gate_logits = jnp.einsum(
+            "btr,rf->btf", low[..., r:], w_g2.astype(self.dtype))
 
-        if self.is_initializing():
-            o = jnp.zeros((B, T, D), self.dtype)
-        else:
+        if kernels:
             with jax.named_scope("kda"):
-                o = kda_ops.kda_flat(q, k, v, g, beta)
-
-        o32 = o.reshape(B, T, n, d).astype(f32)
-        o32 = o32 * jax.lax.rsqrt(
-            jnp.mean(jnp.square(o32), axis=-1, keepdims=True)
-            + cfg.rms_norm_eps) * o_norm
-        gate = jax.nn.sigmoid(jnp.einsum(
-            "btr,rf->btf", low[..., r:], w_g2.astype(self.dtype)).astype(f32))
-        y = (o32.reshape(B, T, D) * gate).astype(self.dtype)
+                o, g = kda_ops.gated_kda(
+                    q, k, v, a, beta_logits, dt_bias, a_log)
+            y = kda_ops.mixer_out(
+                o, gate_logits, o_norm, eps=cfg.rms_norm_eps)
+        else:
+            g, beta = gates_plain(a, beta_logits, dt_bias, a_log)
+            if init:
+                o = jnp.zeros((B, T, D), self.dtype)
+            else:
+                with jax.named_scope("kda"):
+                    o = kda_ops.kda_flat(q, k, v, g, beta)
+            y = out_plain(o, gate_logits, o_norm, cfg.rms_norm_eps)
         out = jnp.einsum("btf,fh->bth", y, wo.astype(self.dtype))
         stats = {"kda_decay_min": jax.lax.stop_gradient(
             kda_ops.chunk_decay_min(g))}

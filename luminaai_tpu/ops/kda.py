@@ -72,14 +72,20 @@ block's backward, and a layer builds the inverse once a step: 90 + 2 x 46 +
 
 Layout: q, k, g [B, T, H, dk]; v [B, T, H, dv]; beta [B, T, H]. The
 kernels read [B, T, H*d] blocks of (1, C, d) directly, no head-major
-transpose (`kda_flat` takes that layout as it is). beta is folded into k
-and v outside the kernel (one fused elementwise pass in XLA, which also
-carries its gradient).
+transpose (`kda_flat` takes that layout as it is). They take beta folded
+into k and v: `gated_kda`, the mixer's entry, folds it in the kernel that
+also forms g (`mixer_gates_fwd`) and differentiates the fold and the
+recurrence in ONE backward (`kda_bwd`, then `mixer_gates_bwd`); `kda_flat`,
+from a g and a beta already formed, folds it in plain jax (`fold_beta`:
+one elementwise pass in XLA, which also carries its gradient).
 
-What makes q, k and v is here too (`qkv_prepare`, below the recurrence):
-two more kernels, `qkv_prepare_fwd` and `qkv_prepare_bwd`, named apart
-from the three above because the benchmark's `kda_ms_step` and
-`kda_roofline` read every op called `kda_*` as the recurrence.
+What stands around the recurrence in the mixer is here too, below it:
+`qkv_prepare` (q, k and v from their projection's output), the front
+stage of `gated_kda` and `mixer_out` (the output norm x gate): six more
+kernels, `qkv_prepare_fwd` / `_bwd`, `mixer_gates_fwd` / `_bwd` and
+`mixer_out_fwd` / `_bwd`, named apart from the three above because the
+benchmark's `kda_ms_step` and `kda_roofline` read every op called `kda_*`
+as the recurrence (and `qkv_prepare_ms_step` every `qkv_prepare_*`).
 
 Interpreted off the TPU: it asks `flash_attention._interpret()` through
 the module at call time, the one switch tests and benchmark/rehearse.py
@@ -312,6 +318,14 @@ def _bwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, st_ref, t_ref, do_ref,
     dst_scr[...] = dst
 
 
+def _pad_rows(xs, to):
+    """[B, T, .] arrays with T padded to a whole multiple of `to`."""
+    pad = -xs[0].shape[1] % to
+    if not pad:
+        return xs
+    return [jnp.pad(x, ((0, 0), (0, pad), (0, 0))) for x in xs]
+
+
 def _params(*semantics):
     return pltpu.CompilerParams(dimension_semantics=semantics)
 
@@ -416,6 +430,8 @@ def _kda_flat(q, k, kb, vb, g, H, C, mxu):
 
 
 def _kda_flat_fwd(q, k, kb, vb, g, H, C, mxu):
+    """(o, what `_bwd_call` reads): the forward under differentiation,
+    `_kda_flat`'s and `_gated_kda`'s."""
     o, states, inv = _inverse_then_fwd(q, k, kb, vb, g, H, C, mxu, True)
     # Named too, and kept by no policy of this repo: with them the block's
     # backward would not run `kda_fwd` a second time (537 MB a layer at
@@ -674,11 +690,337 @@ def qkv_prepare(x: jax.Array, conv_w: jax.Array, *, heads: int,
     assert x.shape[2] == 3 * D == conv_w.shape[1], (x.shape, conv_w.shape)
     assert qkv_prepare_eligible(head_dim, conv_w.shape[0]), conv_w.shape
     blocks = _prep_blocks(T, D, head_dim)
-    pad = -T % blocks[0]
-    if pad:
-        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
+    x, = _pad_rows([x], blocks[0])
     q, k, v = _qkv_prepare(x, conv_w.astype(_F32), head_dim, blocks)
     return q[:, :T], k[:, :T], v[:, :T]
+
+
+# What stands between the projections and the recurrence, and behind it:
+# the decay and beta's fold in front (`mixer_gates_fwd` / `_bwd`), the
+# output norm x gate behind (`mixer_out_fwd` / `_bwd`). Elementwise passes
+# over [B, T, H*d] with one number a head in them (beta, the norm's rsqrt),
+# which XLA reduces and broadcasts in a head-a-row tiling [.., H, d] and
+# re-tiles on the way in and out. Here a head is 128 lanes of a row: the
+# blocks and the strip walk are `qkv_prepare`'s, the grid is (B, row
+# blocks, channel blocks) with the channel blocks innermost, so that the
+# [rows, H] block of beta's logits (H is that array's whole last
+# dimension) stands while its heads go by.
+
+
+def _head_column(x, h):
+    """Column h of x [rows, H] as [rows, 1] (h may be traced): one value
+    and zeros summed over the lanes, so exact."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.sum(jnp.where(lane == h, x, 0.0), axis=-1, keepdims=True)
+
+
+def _gates_math(a, k, v, bl, bias, rate, *, h):
+    """One head's strip in float32: a, k, v [rows, d], beta's logits
+    bl [rows, H] of which head h is this one, bias and rate [1, d] (the
+    head's dt_bias and -exp(A_log)). Returns (g, beta * k, beta * v):
+    models/kda.py's `gates_plain` and `fold_beta` below, line for line."""
+    b = jax.nn.sigmoid(_head_column(bl, h))
+    return rate * jax.nn.softplus(a + bias), k * b, v * b
+
+
+def _out_math(o, z, w, *, eps):
+    """One head's strip in float32: o, z [rows, d], w [1, d]. Returns
+    rmsnorm_d(o) * w * sigmoid(z): models/kda.py's `out_plain`."""
+    o = o * jax.lax.rsqrt(
+        jnp.mean(jnp.square(o), axis=-1, keepdims=True) + eps) * w
+    return o * jax.nn.sigmoid(z)
+
+
+def _f32(ref, rows, cols):
+    return ref[0, rows, cols].astype(_F32)
+
+
+def _gates_fwd_kernel(a_ref, k_ref, v_ref, bl_ref, p_ref, g_ref, kb_ref,
+                      vb_ref, *, d: int, strip: int):
+    bt, bd = a_ref.shape[1:]
+    h0 = pl.program_id(2) * (bd // d)
+    for cols, strips in _prep_strips(bt, bd, d, strip):
+        math = functools.partial(_gates_math, h=h0 + cols.start // d)
+        for rows in strips:
+            g, kb, vb = math(
+                _f32(a_ref, rows, cols), _f32(k_ref, rows, cols),
+                _f32(v_ref, rows, cols), bl_ref[0, rows].astype(_F32),
+                p_ref[0:1, cols], p_ref[1:2, cols])
+            g_ref[0, rows, cols] = g
+            kb_ref[0, rows, cols] = kb.astype(kb_ref.dtype)
+            vb_ref[0, rows, cols] = vb.astype(vb_ref.dtype)
+
+
+def _gates_bwd_kernel(a_ref, k_ref, v_ref, bl_ref, p_ref, dg_ref, dkb_ref,
+                      dvb_ref, dkr_ref, da_ref, dk_ref, dv_ref, dbl_ref,
+                      dp_ref, *, d: int, strip: int):
+    """`_gates_math`'s own vjp a head's strip (the two cannot drift
+    apart). dk is the recurrence's dk plus the fold's, rounded once; the
+    logits' cotangent gathers its heads in the block that stands while
+    the channel blocks go by; dt_bias's and the rate's are this grid
+    step's sums over its rows (the caller adds the steps')."""
+    bt, bd = a_ref.shape[1:]
+    h0 = pl.program_id(2) * (bd // d)
+
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        dbl_ref[...] = jnp.zeros_like(dbl_ref)
+
+    for cols, strips in _prep_strips(bt, bd, d, strip):
+        math = functools.partial(_gates_math, h=h0 + cols.start // d)
+        dbias = drate = 0.0
+        for rows in strips:
+            _, vjp = jax.vjp(
+                math, _f32(a_ref, rows, cols), _f32(k_ref, rows, cols),
+                _f32(v_ref, rows, cols), bl_ref[0, rows].astype(_F32),
+                p_ref[0:1, cols], p_ref[1:2, cols])
+            da, dk, dv, dbl, db, dr = vjp((
+                dg_ref[0, rows, cols], _f32(dkb_ref, rows, cols),
+                _f32(dvb_ref, rows, cols)))
+            da_ref[0, rows, cols] = da.astype(da_ref.dtype)
+            dk_ref[0, rows, cols] = (
+                _f32(dkr_ref, rows, cols) + dk).astype(dk_ref.dtype)
+            dv_ref[0, rows, cols] = dv.astype(dv_ref.dtype)
+            # the other heads' columns of dbl are zeros: the sum is exact
+            dbl_ref[0, rows] = (
+                dbl_ref[0, rows].astype(_F32) + dbl).astype(dbl_ref.dtype)
+            dbias, drate = dbias + db, drate + dr
+        dp_ref[0, 0:1, cols] = dbias
+        dp_ref[0, 1:2, cols] = drate
+
+
+def _out_fwd_kernel(o_ref, z_ref, w_ref, y_ref, *, d: int, strip: int,
+                    eps: float):
+    for cols, strips in _prep_strips(*o_ref.shape[1:], d, strip):
+        for rows in strips:
+            y_ref[0, rows, cols] = _out_math(
+                _f32(o_ref, rows, cols), _f32(z_ref, rows, cols), w_ref[...],
+                eps=eps).astype(y_ref.dtype)
+
+
+def _out_bwd_kernel(o_ref, z_ref, w_ref, dy_ref, do_ref, dz_ref, dw_ref, *,
+                    d: int, strip: int, eps: float):
+    """`_out_math`'s own vjp a head's strip; the norm's weight is every
+    head's, so its cotangent is one [1, d] sum a grid step."""
+    dw = 0.0
+    for cols, strips in _prep_strips(*o_ref.shape[1:], d, strip):
+        for rows in strips:
+            _, vjp = jax.vjp(
+                functools.partial(_out_math, eps=eps),
+                _f32(o_ref, rows, cols), _f32(z_ref, rows, cols), w_ref[...])
+            do, dz, dw_strip = vjp(_f32(dy_ref, rows, cols))
+            do_ref[0, rows, cols] = do.astype(do_ref.dtype)
+            dz_ref[0, rows, cols] = dz.astype(dz_ref.dtype)
+            dw = dw + dw_strip
+    dw_ref[0] = dw
+
+
+def _tok_spec(bt, bd):
+    """A [B, T, D] array's block over the grid (B, row blocks, channel
+    blocks)."""
+    return pl.BlockSpec((1, bt, bd), lambda b, t, j: (b, t, j))
+
+
+def _gates_specs(bt, bd, H):
+    """(a [B, T, D] array's block, the whole rows of beta's logits
+    [B, T, H], the two rows of per-channel numbers [2, D])."""
+    return (_tok_spec(bt, bd),
+            pl.BlockSpec((1, bt, H), lambda b, t, j: (b, t, 0)),
+            pl.BlockSpec((2, bd), lambda b, t, j: (0, j)))
+
+
+# jitted with `interpret` static, as `_prep_fwd_call` is and for its reasons.
+@functools.partial(jax.jit, static_argnames=("d", "blocks", "interpret"))
+def _gates_fwd_call(a, k, v, bl, p, *, d, blocks, interpret):
+    """(g float32, beta * k, beta * v) from a, k, v [B, T, D], beta's
+    logits bl [B, T, H] and p [2, D] float32 (dt_bias, -exp(A_log) a
+    channel)."""
+    B, T, D = a.shape
+    bt, bd, strip = blocks
+    tok, per_head, per_channel = _gates_specs(bt, bd, bl.shape[2])
+    return pl.pallas_call(
+        functools.partial(_gates_fwd_kernel, d=d, strip=strip),
+        grid=(B, T // bt, D // bd),
+        in_specs=[tok, tok, tok, per_head, per_channel],
+        out_specs=[tok] * 3,
+        out_shape=[jax.ShapeDtypeStruct(a.shape, _F32),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        compiler_params=_params("parallel", "parallel", "parallel"),
+        interpret=interpret,
+        name="mixer_gates_fwd",
+    )(a, k, v, bl, p)
+
+
+@functools.partial(jax.jit, static_argnames=("d", "blocks", "interpret"))
+def _gates_bwd_call(a, k, v, bl, p, dg, dkb, dvb, dk, *, d, blocks,
+                    interpret):
+    """(da, dk, dv, dbl in their primals' dtypes, dp [2, D] float32) from
+    the cotangents of g, beta * k, beta * v and the dk that reached k
+    past the fold (the recurrence's own)."""
+    B, T, D = a.shape
+    bt, bd, strip = blocks
+    nt = T // bt
+    tok, per_head, per_channel = _gates_specs(bt, bd, bl.shape[2])
+    *grads, dp = pl.pallas_call(
+        functools.partial(_gates_bwd_kernel, d=d, strip=strip),
+        grid=(B, nt, D // bd),
+        in_specs=[tok, tok, tok, per_head, per_channel, tok, tok, tok, tok],
+        out_specs=[tok, tok, tok, per_head,
+                   pl.BlockSpec((1, 2, bd), lambda b, t, j: (b * nt + t, 0, j))],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
+                   for x in (a, k, v, bl)] + [
+            jax.ShapeDtypeStruct((B * nt, 2, D), _F32)],
+        compiler_params=_params("parallel", "parallel", "arbitrary"),
+        interpret=interpret,
+        name="mixer_gates_bwd",
+    )(a, k, v, bl, p, dg, dkb, dvb, dk)
+    return (*grads, dp.sum(axis=0))
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("d", "eps", "blocks", "interpret"))
+def _out_fwd_call(o, z, w, *, d, eps, blocks, interpret):
+    B, T, D = o.shape
+    bt, bd, strip = blocks
+    tok = _tok_spec(bt, bd)
+    return pl.pallas_call(
+        functools.partial(_out_fwd_kernel, d=d, strip=strip, eps=eps),
+        grid=(B, T // bt, D // bd),
+        in_specs=[tok, tok, pl.BlockSpec((1, d), lambda b, t, j: (0, 0))],
+        out_specs=tok,
+        out_shape=jax.ShapeDtypeStruct(o.shape, o.dtype),
+        compiler_params=_params("parallel", "parallel", "parallel"),
+        interpret=interpret,
+        name="mixer_out_fwd",
+    )(o, z, w)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("d", "eps", "blocks", "interpret"))
+def _out_bwd_call(o, z, w, dy, *, d, eps, blocks, interpret):
+    """(do, dz in their primals' dtypes, dw [1, d] float32)."""
+    B, T, D = o.shape
+    bt, bd, strip = blocks
+    nt, J = T // bt, D // bd
+    tok = _tok_spec(bt, bd)
+    do, dz, dw = pl.pallas_call(
+        functools.partial(_out_bwd_kernel, d=d, strip=strip, eps=eps),
+        grid=(B, nt, J),
+        in_specs=[tok, tok, pl.BlockSpec((1, d), lambda b, t, j: (0, 0)),
+                  tok],
+        out_specs=[tok, tok, pl.BlockSpec(
+            (1, 1, d), lambda b, t, j: ((b * nt + t) * J + j, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct(o.shape, o.dtype),
+                   jax.ShapeDtypeStruct(z.shape, z.dtype),
+                   jax.ShapeDtypeStruct((B * nt * J, 1, d), _F32)],
+        compiler_params=_params("parallel", "parallel", "parallel"),
+        interpret=interpret,
+        name="mixer_out_bwd",
+    )(o, z, w, dy)
+    return do, dz, dw.sum(axis=0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _mixer_out(o, z, w, d, eps, blocks):
+    return _out_fwd_call(o, z, w, d=d, eps=eps, blocks=blocks,
+                         interpret=_fa._interpret())
+
+
+def _mixer_out_fwd(o, z, w, d, eps, blocks):
+    return _mixer_out(o, z, w, d, eps, blocks), (o, z, w)
+
+
+def _mixer_out_bwd(d, eps, blocks, res, dy):
+    return _out_bwd_call(*res, dy, d=d, eps=eps, blocks=blocks,
+                         interpret=_fa._interpret())
+
+
+_mixer_out.defvjp(_mixer_out_fwd, _mixer_out_bwd)
+
+
+def mixer_out(o: jax.Array, gate_logits: jax.Array, o_norm: jax.Array, *,
+              eps: float) -> jax.Array:
+    """rmsnorm_d(o; o_norm) * sigmoid(gate_logits) in o's dtype: o and
+    the logits [B, T, H*d], o_norm [d] the norm's weight (every head's).
+    One kernel forward and one backward, float32 in VMEM alone, each
+    result rounded once as models/kda.py's `out_plain` rounds it.
+    Differentiable in all three (the cotangents in their primals'
+    dtypes). Any T, as `qkv_prepare` takes it."""
+    T, D = o.shape[1:]
+    d = o_norm.shape[0]
+    assert kda_eligible(d, d) and D % d == 0, (o.shape, o_norm.shape)
+    blocks = _prep_blocks(T, D, d)
+    o, z = _pad_rows([o, gate_logits], blocks[0])
+    y = _mixer_out(o, z, o_norm.astype(_F32).reshape(1, d), d, float(eps),
+                   blocks)
+    return y[:, :T]
+
+
+def _gates(a, k, v, bl, p, H, blocks):
+    return _gates_fwd_call(a, k, v, bl, p, d=a.shape[-1] // H, blocks=blocks,
+                           interpret=_fa._interpret())
+
+
+def _gates_params(dt_bias, a_log, d):
+    """The two rows of per-channel numbers the gates' kernels read:
+    dt_bias and -exp(A_log) a channel, [2, H*d] float32."""
+    return jnp.stack([dt_bias.astype(_F32),
+                      -jnp.repeat(jnp.exp(a_log.astype(_F32)), d)])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
+def _gated_kda(q, k, v, a, bl, p, H, C, mxu, blocks):
+    g, kb, vb = _gates(a, k, v, bl, p, H, blocks)
+    return _inverse_then_fwd(q, k, kb, vb, g, H, C, mxu, False)[0], g
+
+
+def _gated_kda_fwd(q, k, v, a, bl, p, H, C, mxu, blocks):
+    g, kb, vb = _gates(a, k, v, bl, p, H, blocks)
+    o, res = _kda_flat_fwd(q, k, kb, vb, g, H, C, mxu)
+    return (o, g), (res, v, a, bl, p)
+
+
+def _gated_kda_bwd(H, C, mxu, blocks, res, cts):
+    """ONE backward for the fold and the recurrence, so that k's two
+    cotangents (the recurrence's own and the fold's) are added in float32
+    inside `mixer_gates_bwd` and written once. g leaves `gated_kda`
+    under stop_gradient: its cotangent is not read."""
+    res, v, a, bl, p = res
+    k = res[1]
+    dq, dk, dkb, dvb, dg = _bwd_call(*res, cts[0], H=H, C=C, mxu=mxu)
+    da, dk, dv, dbl, dp = _gates_bwd_call(
+        a, k, v, bl, p, dg, dkb, dvb, dk, d=a.shape[-1] // H,
+        blocks=blocks, interpret=_fa._interpret())
+    return dq, dk, dv, da, dbl, dp
+
+
+_gated_kda.defvjp(_gated_kda_fwd, _gated_kda_bwd)
+
+
+def gated_kda(q: jax.Array, k: jax.Array, v: jax.Array, a: jax.Array,
+              beta_logits: jax.Array, dt_bias: jax.Array, a_log: jax.Array,
+              *, chunk: int = CHUNK, mxu_dtype=None):
+    """`kda_flat` from what the mixer's projections give: the decay
+    g = -exp(a_log) * softplus(a + dt_bias) and beta = sigmoid(beta_logits)
+    are formed, and beta folded into k and v, in one kernel in front of
+    the recurrence's (float32 in VMEM alone; g float32, beta * k and
+    beta * v rounded once, as models/kda.py's `gates_plain` and
+    `fold_beta` round them), and one kernel behind the recurrence's
+    backward writes the cotangents of a, k, v, the logits, dt_bias and
+    a_log, k's once. q, k, v, a [B, T, H*d]; beta_logits [B, T, H];
+    dt_bias [H*d]; a_log [H]. Returns (o [B, T, H*d] in v's dtype, g
+    [B, T, H*d] float32 under stop_gradient: for gauges)."""
+    B, T, H = beta_logits.shape
+    D = a.shape[-1]
+    d = D // H
+    assert kda_eligible(d, d), d
+    assert q.shape == k.shape == v.shape == a.shape, (q.shape, a.shape)
+    blocks = _prep_blocks(T, D, d)
+    args = _pad_rows([q, k, v, a, beta_logits], math.lcm(blocks[0], chunk))
+    o, g = _gated_kda(*args, _gates_params(dt_bias, a_log, d), H, chunk,
+                      jnp.dtype(mxu_dtype or q.dtype), blocks)
+    return o[:, :T], jax.lax.stop_gradient(g[:, :T])
 
 
 def kda_eligible(dk: int, dv: int) -> bool:
@@ -705,6 +1047,16 @@ def kda(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     return o.reshape(B, T, H, v.shape[-1])
 
 
+def fold_beta(k: jax.Array, v: jax.Array, beta: jax.Array):
+    """(beta * k, beta * v) in k's and v's dtypes: k [B, T, H*dk],
+    v [B, T, H*dv], beta [B, T, H]. The plain form of what
+    `mixer_gates_fwd` does with beta."""
+    b = beta.astype(_F32)
+    fold = lambda x: (x.astype(_F32) * jnp.repeat(  # noqa: E731
+        b, x.shape[-1] // b.shape[-1], axis=-1)).astype(x.dtype)
+    return fold(k), fold(v)
+
+
 def kda_flat(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
              beta: jax.Array, *, chunk: int = CHUNK,
              mxu_dtype=None) -> jax.Array:
@@ -719,13 +1071,7 @@ def kda_flat(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
         heads = lambda x: x.reshape(B, T, H, -1)  # noqa: E731
         return kda_recurrent(
             *(heads(x) for x in (q, k, v, g)), beta).reshape(v.shape)
-    b = beta.astype(_F32)
-    kb = (k.astype(_F32) * jnp.repeat(b, dk, axis=-1)).astype(k.dtype)
-    vb = (v.astype(_F32) * jnp.repeat(b, dv, axis=-1)).astype(v.dtype)
-    args = [q, k, kb, vb, g.astype(_F32)]
-    pad = -T % chunk
-    if pad:
-        args = [jnp.pad(x, ((0, 0), (0, pad), (0, 0))) for x in args]
+    args = _pad_rows([q, k, *fold_beta(k, v, beta), g.astype(_F32)], chunk)
     o = _kda_flat(*args, H, chunk, jnp.dtype(mxu_dtype or q.dtype))
     return o[:, :T]
 

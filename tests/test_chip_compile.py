@@ -316,6 +316,65 @@ def test_qkv_prepare_compiles_at_cell_shapes(one_chip, grad):
     assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
 
 
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
+def test_mixer_gates_compile_at_cell_shapes(one_chip, grad):
+    """The decay and beta's fold in front of the recurrence, from the
+    cell's bf16[2, 8192, 4096] (32 heads of 128) and beta's logits
+    bf16[2, 8192, 32]: a head's column picked out of the [rows, 32] block
+    and broadcast over its 128 lanes, the logits' cotangent gathered into
+    that block, must lower in Mosaic. One backward for the fold and the
+    recurrence: k's cotangent leaves `mixer_gates_bwd` alone, and no
+    [.., 32, 128] view of a [2, 8192, 4096] array stands anywhere."""
+    from luminaai_tpu.ops import kda
+
+    def run(q, k, v, a, bl, dt_bias, a_log):
+        return kda.gated_kda(q, k, v, a, bl, dt_bias, a_log)
+
+    def both(do, *args):
+        out, vjp = jax.vjp(lambda *xs: run(*xs)[0], *args)
+        return out, vjp(do)
+
+    wide = ((KB, KS, KH * 128), BF16)
+    shapes = (wide,) * 4 + (((KB, KS, KH), BF16), ((KH * 128,), jnp.float32),
+                            ((KH,), jnp.float32))
+    text = _compile(both if grad else run, one_chip,
+                    *(((wide,) if grad else ()) + shapes))
+    assert text.count('custom_call_target="tpu_custom_call"') == (
+        5 if grad else 3)
+    assert "mixer_gates_fwd" in text and "kda_fwd" in text
+    for name in ("mixer_gates_bwd", "kda_bwd"):
+        assert (name in text) is grad
+    assert "[2,8192,32,128]" not in text and "[2048,8,32,128]" not in text
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
+def test_mixer_out_compiles_at_cell_shapes(one_chip, grad):
+    """The output norm x gate behind the recurrence at the cell's shapes:
+    a head's mean square is a sum over the 128 lanes of a row, and
+    nothing float32 of the arrays' size stands around the kernels."""
+    from luminaai_tpu.ops import kda
+
+    def run(o, z, w):
+        return kda.mixer_out(o, z, w, eps=1e-5)
+
+    def both(dy, o, z, w):
+        out, vjp = jax.vjp(run, o, z, w)
+        return out, vjp(dy)
+
+    wide = ((KB, KS, KH * 128), BF16)
+    shapes = (wide, wide, ((128,), jnp.float32))
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in ((wide,) if grad else ()) + shapes]
+    compiled = jax.jit(both if grad else run).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == (
+        2 if grad else 1)
+    assert "mixer_out_fwd" in text
+    assert ("mixer_out_bwd" in text) is grad
+    assert "f32[2,8192," not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
+
+
 def test_flash_compiles_with_values_narrower_than_scores(one_chip):
     """Latent attention's shapes: scores over 192, values and output of
     128, forward and both backward kernels."""

@@ -240,7 +240,9 @@ def test_remat_keeps_the_inverse_and_reruns_the_forward_alone(policy, tri, fwd):
         jax.make_jaxpr(jax.grad(loss))(params).jaxpr)
         if e.primitive.name == "pallas_call"]
     assert sorted(set(names)) == [
-        "kda_bwd", "kda_fwd", "kda_tri", "qkv_prepare_bwd", "qkv_prepare_fwd"]
+        "kda_bwd", "kda_fwd", "kda_tri", "mixer_gates_bwd", "mixer_gates_fwd",
+        "mixer_out_bwd", "mixer_out_fwd", "qkv_prepare_bwd",
+        "qkv_prepare_fwd"]
     assert names.count("kda_tri") == tri
     assert names.count("kda_fwd") == fwd
     assert names.count("kda_bwd") == 2
