@@ -35,9 +35,14 @@ activations' dtype (bfloat16 in training) and accumulate in float32.
 
 T depends on k, beta and g alone: not on the state, not on q. So it is built
 once and carried, in float32, as it was computed. Three kernels, each a
-grid over (batch, head, chunk); by MXU passes a chunk (a float32 product at
-HIGHEST is six bf16 passes, and on the chip the kernels' time follows the
-count):
+grid over (batch, head, chunks): a grid step takes several chunks of one
+head where that count divides T/C (`_TRI_CHUNKS` = 4, `_FWD_CHUNKS` = 8,
+`_BWD_CHUNKS` = 4 at most; a T/C they do not divide takes their gcd with
+it, one chunk at worst), laid out one behind another, so that one chunk's
+products run in the gaps of its neighbour's; the arithmetic, its order and
+every output's bits are those of one chunk a step. By MXU passes a chunk
+(a float32 product at HIGHEST is six bf16 passes, and on the chip the
+kernels' time follows the count):
 
   `kda_tri`  reads k, beta*k, g; forms G and A's rows and writes
              T = (I + A)^{-1}, [B, H, T/C, C/2, 2C] float32 (a chunk's
@@ -46,16 +51,18 @@ count):
              grid axis is parallel. G 6 + scores 24 + the inverse's ten
              products 60 = 90 passes.
   `kda_fwd`  reads q, k, beta*k, beta*v, g and the chunk's T; chunks
-             innermost and in order, S^T in float32 VMEM scratch. It forms
+             innermost and in order, S^T carried through the step's
+             chunks and left in float32 VMEM scratch between steps. It forms
              G and P's rows, never A: 6 + 24 + W and T (beta v) 12 + the
              four matmuls against the state 4 = 46 passes (106 when it
              built T itself). Under differentiation it also writes each
              chunk's ENTERING state ([B, H, T/C, dv, dk] float32: 64 KB a
              chunk a head), which the backward reads instead of
              recomputing the scan.
-  `kda_bwd`  the same grid walked from the last chunk to the first with dS
-             in scratch, reading the same T and the entering state; each
-             step differentiates the chunk's own forward math (`jax.vjp`
+  `kda_bwd`  the same walk from the last chunk to the first (the blocks
+             of a step's chunks in reverse, and the chunks inside one)
+             with dS carried likewise, reading the same T and the entering
+             state; it differentiates each chunk's own forward math (`jax.vjp`
              of `_chunk_math`, traced into the kernel), so the two cannot
              drift apart. There the inverse is `_carried_inverse(A, T)`:
              its primal is the T that was read, and its cotangent reaches
@@ -108,9 +115,24 @@ from luminaai_tpu.ops import flash_attention as _fa
 CHUNK = 64
 SUB = 16
 # Chunks a grid step of `kda_tri`. On a v5e at 2 x 8192 tokens and 32 heads:
-# 14.71 ms a call at 1, 13.76 at 2, 13.29 at 4, 13.01 at 8 (a grid step's
-# fixed cost, 8192 of them at 1); 8 unrolls twice the code for 0.3 ms.
+# 14.71 ms a call at 1, 13.76 at 2, 13.29 at 4, 13.01 at 8 (read then as a
+# grid step's fixed cost, 8192 of them at 1; `kda_fwd`'s rolled loop, below,
+# puts that at a third of this gain: the rest is chunks laid out straight);
+# 8 unrolls twice the code for 0.3 ms.
 _TRI_CHUNKS = 4
+# Chunks a grid step of `kda_fwd` and of `kda_bwd`, in order, by a loop
+# that is laid out straight (`unroll=True`: the body is traced once and
+# lowered once a chunk). On a v5e at 2 x 8192 tokens and 32 heads, ms a
+# call, forward / backward: 7.04 / 22.94 at 1, 5.81 / 21.76 at 2, 5.24 /
+# 21.15 at 4, 4.95 / 20.90 at 8. The same chunks as a rolled loop: 6.83 /
+# 22.82 at 2, 6.65 / 22.52 at 4, 6.56 / 22.37 at 8, 6.52 / 22.30 at 16: a
+# grid step's fixed cost is 0.07-0.09 us (0.55 and 0.70 ms a call at 8192
+# steps), and what pays is the products of one chunk that wait for no
+# state (G, the scores, W, T (beta v)) scheduled into the gaps of its
+# neighbour's chain through the state. The backward's 8 is twice the code
+# of a whole `jax.vjp` for 0.25 ms.
+_FWD_CHUNKS = 8
+_BWD_CHUNKS = 4
 _HI = jax.lax.Precision.HIGHEST
 _F32 = jnp.float32
 
@@ -278,44 +300,63 @@ def _tri_kernel(k_ref, kb_ref, g_ref, t_ref, *, sub: int):
             k_ref[0, rows], kb_ref[0, rows], g_ref[0, rows], sub=sub))
 
 
+def _chunk_rows(n, C):
+    """Chunk n's rows of a grid step's token block (n may be traced)."""
+    return pl.ds(pl.multiple_of(n * C, C), C)
+
+
 def _fwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, t_ref, o_ref, *rest,
                 sub: int, mxu, keep_states: bool):
     st_scr = rest[-1]
+    C = t_ref.shape[-1] // 2
 
     @pl.when(pl.program_id(2) == 0)
     def _init():
         st_scr[...] = jnp.zeros_like(st_scr)
 
-    st = st_scr[...]
-    if keep_states:
-        rest[0][0, 0, 0] = st
-    o, st_new = _chunk_math(
-        q_ref[0], k_ref[0], kb_ref[0], vb_ref[0], g_ref[0], st,
-        _unpack(t_ref[0, 0, 0]), sub=sub, mxu=mxu)
-    o_ref[0] = o.astype(o_ref.dtype)
-    st_scr[...] = st_new
+    def chunk(n, st):
+        rows = _chunk_rows(n, C)
+        if keep_states:
+            rest[0][0, 0, n] = st
+        o, st = _chunk_math(
+            q_ref[0, rows], k_ref[0, rows], kb_ref[0, rows], vb_ref[0, rows],
+            g_ref[0, rows], st, _unpack(t_ref[0, 0, n]), sub=sub, mxu=mxu)
+        o_ref[0, rows] = o.astype(o_ref.dtype)
+        return st
+
+    st_scr[...] = jax.lax.fori_loop(
+        0, t_ref.shape[2], chunk, st_scr[...], unroll=True)
 
 
 def _bwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, st_ref, t_ref, do_ref,
                 dq_ref, dk_ref, dkb_ref, dvb_ref, dg_ref, dst_scr, *,
                 sub: int, mxu):
+    C = t_ref.shape[-1] // 2
+    chunks = t_ref.shape[2]
+
     @pl.when(pl.program_id(2) == 0)
     def _init():
         dst_scr[...] = jnp.zeros_like(dst_scr)
 
-    _, vjp = jax.vjp(
-        functools.partial(_chunk_math, T=_unpack(t_ref[0, 0, 0]), sub=sub,
-                          mxu=mxu, through_inverse=True),
-        q_ref[0], k_ref[0], kb_ref[0], vb_ref[0], g_ref[0],
-        st_ref[0, 0, 0])
-    dq, dk, dkb, dvb, dg, dst = vjp(
-        (do_ref[0].astype(_F32), dst_scr[...]))
-    dq_ref[0] = dq.astype(dq_ref.dtype)
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dkb_ref[0] = dkb.astype(dkb_ref.dtype)
-    dvb_ref[0] = dvb.astype(dvb_ref.dtype)
-    dg_ref[0] = dg.astype(dg_ref.dtype)
-    dst_scr[...] = dst
+    def chunk(i, dst):
+        n = chunks - 1 - i  # the step's chunks from its last to its first
+        rows = _chunk_rows(n, C)
+        _, vjp = jax.vjp(
+            functools.partial(_chunk_math, T=_unpack(t_ref[0, 0, n]), sub=sub,
+                              mxu=mxu, through_inverse=True),
+            q_ref[0, rows], k_ref[0, rows], kb_ref[0, rows], vb_ref[0, rows],
+            g_ref[0, rows], st_ref[0, 0, n])
+        dq, dk, dkb, dvb, dg, dst = vjp(
+            (do_ref[0, rows].astype(_F32), dst))
+        dq_ref[0, rows] = dq.astype(dq_ref.dtype)
+        dk_ref[0, rows] = dk.astype(dk_ref.dtype)
+        dkb_ref[0, rows] = dkb.astype(dkb_ref.dtype)
+        dvb_ref[0, rows] = dvb.astype(dvb_ref.dtype)
+        dg_ref[0, rows] = dg.astype(dg_ref.dtype)
+        return dst
+
+    dst_scr[...] = jax.lax.fori_loop(
+        0, chunks, chunk, dst_scr[...], unroll=True)
 
 
 def _pad_rows(xs, to):
@@ -330,17 +371,17 @@ def _params(*semantics):
     return pltpu.CompilerParams(dimension_semantics=semantics)
 
 
-def _specs(C, dk, dv, order):
-    """BlockSpecs of q, k, kb, g (dk wide) and vb (dv wide) over
-    [B, T, H*d] arrays, and of a chunk's [.., r, c] float32 block of a
-    [B, H, T/C, r, c] array; `order(t)` maps the grid's chunk axis to a
-    chunk."""
+def _specs(C, dk, dv, n, order):
+    """BlockSpecs of a grid step's `n` chunks: of q, k, kb, g (dk wide)
+    and vb (dv wide) over [B, T, H*d] arrays, and of the chunks' [.., r, c]
+    float32 blocks of a [B, H, T/C, r, c] array; `order(t)` maps the
+    grid's last axis to a block of `n` chunks."""
     def tok(d):
-        return pl.BlockSpec((1, C, d), lambda b, h, t: (b, order(t), h))
+        return pl.BlockSpec((1, n * C, d), lambda b, h, t: (b, order(t), h))
 
     def per_chunk(r, c):
         return pl.BlockSpec(
-            (1, 1, 1, r, c), lambda b, h, t: (b, h, order(t), 0, 0))
+            (1, 1, n, r, c), lambda b, h, t: (b, h, order(t), 0, 0))
 
     return tok(dk), tok(dv), per_chunk
 
@@ -367,11 +408,19 @@ def _tri_call(k, kb, g, *, H, C):
     )(k, kb, g)
 
 
-def _fwd_call(q, k, kb, vb, g, inv, *, H, C, mxu, keep_states):
+# jitted, as `_prep_fwd_call` is and for its reasons: a step lowers each
+# of the forward, the forward that keeps its states and the backward once,
+# not once a layer (a body of `n` chunks laid out straight is `n` times
+# the operations to lower, and lowering is not cached from run to run).
+# `n` and `interpret` are arguments because jit's cache outlives a patched
+# constant or `_interpret()`.
+@functools.partial(jax.jit, static_argnames=(
+    "H", "C", "mxu", "keep_states", "n", "interpret"))
+def _fwd_walk(q, k, kb, vb, g, inv, *, H, C, mxu, keep_states, n, interpret):
     B, T, _ = q.shape
     dk, dv = q.shape[-1] // H, vb.shape[-1] // H
     nt = T // C
-    kspec, vspec, per_chunk = _specs(C, dk, dv, lambda t: t)
+    kspec, vspec, per_chunk = _specs(C, dk, dv, n, lambda t: t)
     out_specs = [vspec]
     out_shape = [jax.ShapeDtypeStruct((B, T, H * dv), vb.dtype)]
     if keep_states:
@@ -380,37 +429,56 @@ def _fwd_call(q, k, kb, vb, g, inv, *, H, C, mxu, keep_states):
     out = pl.pallas_call(
         functools.partial(_fwd_kernel, sub=min(SUB, C), mxu=mxu,
                           keep_states=keep_states),
-        grid=(B, H, nt),
+        grid=(B, H, nt // n),
         in_specs=[kspec, kspec, kspec, vspec, kspec,
                   per_chunk(C // 2, 2 * C)],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((dv, dk), _F32)],
         compiler_params=_params("parallel", "parallel", "arbitrary"),
-        interpret=_fa._interpret(),
+        interpret=interpret,
         name="kda_fwd",
     )(q, k, kb, vb, g, inv)
     return out if keep_states else (out[0], None)
 
 
-def _bwd_call(q, k, kb, vb, g, states, inv, do, *, H, C, mxu):
+@functools.partial(jax.jit, static_argnames=(
+    "H", "C", "mxu", "n", "interpret"))
+def _bwd_walk(q, k, kb, vb, g, states, inv, do, *, H, C, mxu, n, interpret):
     B, T, _ = q.shape
     dk, dv = q.shape[-1] // H, vb.shape[-1] // H
     nt = T // C
-    kspec, vspec, per_chunk = _specs(C, dk, dv, lambda t: nt - 1 - t)
+    kspec, vspec, per_chunk = _specs(
+        C, dk, dv, n, lambda t: nt // n - 1 - t)
     like = lambda x, dt=None: jax.ShapeDtypeStruct(x.shape, dt or x.dtype)  # noqa: E731
     return pl.pallas_call(
         functools.partial(_bwd_kernel, sub=min(SUB, C), mxu=mxu),
-        grid=(B, H, nt),
+        grid=(B, H, nt // n),
         in_specs=[kspec, kspec, kspec, vspec, kspec, per_chunk(dv, dk),
                   per_chunk(C // 2, 2 * C), vspec],
         out_specs=[kspec, kspec, kspec, vspec, kspec],
         out_shape=[like(q), like(k), like(kb), like(vb), like(g)],
         scratch_shapes=[pltpu.VMEM((dv, dk), _F32)],
         compiler_params=_params("parallel", "parallel", "arbitrary"),
-        interpret=_fa._interpret(),
+        interpret=interpret,
         name="kda_bwd",
     )(q, k, kb, vb, g, states, inv, do)
+
+
+def _fwd_call(q, k, kb, vb, g, inv, *, H, C, mxu, keep_states):
+    """(o, the chunks' entering states or None): `kda_fwd`, `_FWD_CHUNKS`
+    chunks a grid step where that divides T/C, else their gcd."""
+    return _fwd_walk(
+        q, k, kb, vb, g, inv, H=H, C=C, mxu=mxu, keep_states=keep_states,
+        n=math.gcd(q.shape[1] // C, _FWD_CHUNKS), interpret=_fa._interpret())
+
+
+def _bwd_call(q, k, kb, vb, g, states, inv, do, *, H, C, mxu):
+    """(dq, dk, dkb, dvb, dg): `kda_bwd`, `_BWD_CHUNKS` chunks a grid step
+    as `_fwd_call` takes its own."""
+    return _bwd_walk(
+        q, k, kb, vb, g, states, inv, do, H=H, C=C, mxu=mxu,
+        n=math.gcd(q.shape[1] // C, _BWD_CHUNKS), interpret=_fa._interpret())
 
 
 def _inverse_then_fwd(q, k, kb, vb, g, H, C, mxu, keep_states):

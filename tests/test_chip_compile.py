@@ -18,6 +18,7 @@ kernel themselves.
 """
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -27,6 +28,7 @@ from jax.sharding import SingleDeviceSharding
 from luminaai_tpu.config import ConfigPresets
 from luminaai_tpu.ops import flash_attention as fa
 from luminaai_tpu.ops import ragged_paged_attention as rpa
+from tests.test_kimi_linear import _eqns
 
 CFG = ConfigPresets.flagship()
 B, S = CFG.batch_size, CFG.seq_length  # 16 x 2048
@@ -280,6 +282,17 @@ def test_kda_kernels_compile_at_cell_shapes(one_chip, grad):
         3 if grad else 2)
     assert "kda_tri" in text and "kda_fwd" in text
     assert ("kda_bwd" in text) is grad
+    # The static counter of "several chunks a grid step": the grids of the
+    # calls as traced. 2 x 32 x 128 chunks were 8,192 steps a call at one.
+    traced = jax.make_jaxpr(fn)(
+        *(jax.ShapeDtypeStruct(*shape) for shape in shapes))
+    steps = {e.params["name"]: math.prod(e.params["grid_mapping"].grid)
+             for e in _eqns(traced.jaxpr) if e.primitive.name == "pallas_call"}
+    chunks = KB * KH * KS // kda.CHUNK
+    assert kda._FWD_CHUNKS > 1 and kda._BWD_CHUNKS > 1
+    assert steps["kda_fwd"] == chunks // kda._FWD_CHUNKS
+    assert steps.get("kda_bwd") == (
+        chunks // kda._BWD_CHUNKS if grad else None)
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])

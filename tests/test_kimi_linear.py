@@ -6,6 +6,7 @@ benchmark's plain reference, the routing rule's defaults against the rule
 as it was, and the fences around what does not compose yet."""
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -42,23 +43,40 @@ def _delta_inputs(B, T, H, dk, dv, gate, seed=0):
     (150, 1.0, 32, 16), (96, 3.0, 32, 16), (128, 1.0, 128, 128)])
 def test_kda_kernels_match_the_recurrence(T, gate, dk, dv):
     args = _delta_inputs(2, T, 2, dk, dv, gate)
+    _hold_to_recurrence(args, *_kda_and_grads(args))
+    if gate == 3.0:
+        assert -88.0 < float(kda_ops.chunk_decay_min(args[3])) < -45.0
+        assert float(args[3][:, :64].sum(axis=1).min()) < -120.0
+
+
+def _weights(args):
+    v = args[2]
+    return jax.random.normal(jax.random.key(9), v.shape)
+
+
+def _kda_and_grads(args):
+    """(o from the forward alone, the gradients in all five arguments)
+    through the kernels: the forward that keeps no states, then the one
+    that does and the backward."""
+    w = _weights(args)
+    loss = lambda *a: (kda_ops.kda(*a).astype(jnp.float32) * w).sum()  # noqa: E731
+    return kda_ops.kda(*args), jax.grad(loss, argnums=(0, 1, 2, 3, 4))(*args)
+
+
+def _hold_to_recurrence(args, got, grads):
+    """float32 arguments: the kernels' output and gradients against the
+    token-by-token recurrence's."""
+    w = _weights(args)
     want = kda_ops.kda_recurrent(*args)
-    got = kda_ops.kda(*args)
     assert np.isfinite(np.asarray(got)).all()
     assert float(jnp.abs(got - want).max()) <= 2e-5 * float(
         jnp.abs(want).max())
-    w = jax.random.normal(jax.random.key(9), want.shape)
-    grads = jax.grad(lambda *a: (kda_ops.kda(*a) * w).sum(),
-                     argnums=(0, 1, 2, 3, 4))(*args)
     wants = jax.grad(lambda *a: (kda_ops.kda_recurrent(*a) * w).sum(),
                      argnums=(0, 1, 2, 3, 4))(*args)
     for name, a, b in zip(("q", "k", "v", "g", "beta"), grads, wants):
         assert np.isfinite(np.asarray(a)).all(), name
         assert float(jnp.abs(a - b).max()) <= 1e-4 * float(
             jnp.abs(b).max()) + 1e-7, name
-    if gate == 3.0:
-        assert -88.0 < float(kda_ops.chunk_decay_min(args[3])) < -45.0
-        assert float(args[3][:, :64].sum(axis=1).min()) < -120.0
 
 
 def _inverse_by_products(A):
@@ -250,6 +268,64 @@ def test_remat_keeps_the_inverse_and_reruns_the_forward_alone(policy, tri, fwd):
     # them again from the projection's output, then differentiates once.
     assert names.count("qkv_prepare_fwd") == 4
     assert names.count("qkv_prepare_bwd") == 2
+
+
+# `kda_fwd` and `kda_bwd` take several chunks a grid step: 8 chunks
+# (T = 512) are one step of the forward's 8 and two of the backward's 4;
+# 6 chunks (T = 384) divide by neither, so both walk gcd = 2 a step. The
+# one-chunk walk is the same calls with both constants at 1. The last
+# case is training's dtype at the cell's head size.
+@pytest.mark.parametrize("T,dk,dv,dtype", [
+    (512, 32, 16, jnp.float32), (384, 32, 16, jnp.float32),
+    (512, 128, 128, jnp.float32), (384, 128, 128, jnp.float32),
+    (512, 128, 128, jnp.bfloat16)])
+def test_several_chunks_a_grid_step_walk_as_one_chunk_does(
+        monkeypatch, T, dk, dv, dtype):
+    """The forward, and the states it keeps for the backward, bit for
+    bit. The gradients are the same bits on the chip, where Mosaic lowers
+    the body operation by operation (PERF.md section 6, PR 58: every
+    output of both kernels at the cell's shapes, at 1, 2, 4, 8 and 16
+    chunks a step). Here XLA:CPU compiles the interpreted kernel and
+    contracts multiplies into adds by what it fuses, which differs between
+    a program of one chunk and one of several: float32 sums may end one
+    rounding apart, so the gradients are held to that and no looser."""
+    nt = T // kda_ops.CHUNK
+    assert math.gcd(nt, kda_ops._FWD_CHUNKS) > 1 < math.gcd(
+        nt, kda_ops._BWD_CHUNKS)
+    B, H, C = 1, 2, kda_ops.CHUNK
+    q, k, v, g, beta = _delta_inputs(B, T, H, dk, dv, 1.0, seed=5)
+    args = (q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta)
+
+    qf, kf, vf, gf = (x.reshape(B, T, -1) for x in args[:4])
+    kb, vb = kda_ops.fold_beta(kf, vf, beta)
+    inv = kda_ops._tri_call(kf, kb, gf, H=H, C=C)
+
+    def walk():
+        return *_kda_and_grads(args), kda_ops._fwd_call(
+            qf, kf, kb, vb, gf, inv, H=H, C=C, mxu=jnp.dtype(dtype),
+            keep_states=True)
+
+    got, grads, states = walk()
+    monkeypatch.setattr(kda_ops, "_FWD_CHUNKS", 1)
+    monkeypatch.setattr(kda_ops, "_BWD_CHUNKS", 1)
+    one, one_grads, one_states = walk()
+
+    assert jnp.array_equal(got, one)
+    for a, b in zip(states, one_states):
+        assert jnp.array_equal(a, b)
+    for name, a, b in zip(("q", "k", "v", "g", "beta"), grads, one_grads):
+        assert np.isfinite(np.asarray(a, np.float32)).all(), name
+        rounding = float(jnp.finfo(jnp.float32 if name == "g" else dtype).eps)
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        assert float(jnp.abs(a - b).max()) <= 2 * rounding * float(
+            jnp.abs(b).max()), name
+
+    if dtype == jnp.float32:
+        _hold_to_recurrence(args, got, grads)
+    else:  # as test_kda_in_bf16_stays_near_the_recurrence
+        want = kda_ops.kda_recurrent(*(a.astype(jnp.float32) for a in args))
+        err = got.astype(jnp.float32) - want
+        assert float(jnp.sqrt(jnp.mean(err ** 2)) / jnp.std(want)) < 0.02
 
 
 def test_kda_recurrence_is_the_references_delta_rule():
