@@ -320,6 +320,14 @@ class ThreadPhaseLedger(GoodputLedger):
         self._seg_t0 = now
         return prev
 
+    def owner_seconds(self) -> Dict[str, float]:
+        """`seconds()` for the owning thread: no lock, since nobody
+        else switches."""
+        out = dict(self._totals)
+        if self._cause is not None:
+            out[self._cause] += max(0.0, self._clock() - self._seg_t0)
+        return out
+
     def publish(self) -> None:
         """Push the seconds accrued since the last call to the
         counters. Owner thread only."""

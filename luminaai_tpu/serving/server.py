@@ -64,7 +64,12 @@ from luminaai_tpu.monitoring.timeseries import (
     get_history,
     set_history,
 )
-from luminaai_tpu.monitoring.watchdog import HangWatchdog, StepTimeSentinel
+from luminaai_tpu.monitoring.watchdog import (
+    HangWatchdog,
+    ProcessPauses,
+    StepTimeSentinel,
+    TickStalls,
+)
 from luminaai_tpu.monitoring.telemetry import (
     DEFAULT_LATENCY_BUCKETS,
     MetricsRegistry,
@@ -422,6 +427,32 @@ class ContinuousScheduler:
         self._init_telemetry(registry, tracer, telemetry, latency_buckets)
         self._worker = threading.Thread(target=self._loop, daemon=True)
         self._worker.start()
+        if self.telemetry:
+            self._watch_stalls()
+
+    def _watch_stalls(self) -> None:
+        """Join the process-wide collector hook and heartbeat (one a
+        process, shared with every other scheduler and trainer) and book
+        this thread's flagged ticks against them (TickStalls).
+
+        AFTER the worker thread is started, not before: the heartbeat is
+        a thread too, and on the TPU host a thread started between the
+        runtime's start and the worker's made the worker's loads of
+        compiled programs three times slower (a warm set-up 8 s longer,
+        whatever that thread then did; PERF.md section 6, PRs 59-61).
+        The worker runs no tick before a request is submitted, and
+        none is before __init__ returns."""
+        self._pauses = ProcessPauses.start(self.registry, self.tracer)
+        self._stalls = TickStalls(self.registry, self._phases, self._pauses)
+
+    def close(self) -> None:
+        """Leave the process-wide pause recorder: its last starter takes
+        the hook off `gc.callbacks` and joins the heartbeat thread
+        (idempotent). The worker is a daemon thread and stays; ticks it
+        still runs book their stalls against pauses that stand still."""
+        pauses, self._pauses = self._pauses, None
+        if pauses is not None:
+            pauses.close(self.registry, self.tracer)
 
     def _init_telemetry(self, registry, tracer, telemetry, buckets) -> None:
         """Registry wiring: per-request latency histograms (recorded on
@@ -531,6 +562,12 @@ class ContinuousScheduler:
             prefix="serve_decode_step_seconds",
             program="serve",
         )
+        # What a flagged tick is booked under (serve_tick_stall_*):
+        # _watch_stalls, once the worker thread exists.
+        self._pauses: Optional[ProcessPauses] = None
+        self._stalls: Optional[TickStalls] = None
+        # Rows of the chunk that rides the step in flight ahead.
+        self._chunk_rows_ahead = 0
         self._m_admissions = r.counter(
             "serve_admissions_total", "Requests admitted into a KV slot"
         )
@@ -1657,6 +1694,8 @@ class ContinuousScheduler:
         # (per-step events would be all the ring buffer ever holds).
         self._tick_steps = self._tick_tokens = 0
         self._tick_t0 = self._t_collect = time.perf_counter()
+        if self._stalls is not None:
+            self._stalls.mark()
         steps = self._steps
         while active or self._prefilling or steps.steps_in_flight:
             with self._tick_span(active):
@@ -1697,6 +1736,7 @@ class ContinuousScheduler:
         had taken it), and the generation is over."""
         steps = self._steps
         chunk = self._next_chunk(active) if self._prefilling else None
+        rows = 0
         try:
             with self.tracer.span("decode_step") as sp:
                 ahead = steps.steps_in_flight > 0
@@ -1711,7 +1751,8 @@ class ContinuousScheduler:
                         st["next"] * st["chunk"]
                     )
                     end = int(min(start + st["chunk"], st["length"]))
-                    sp.set(chunk_slot=slot, chunk_rows=end - start,
+                    rows = end - start
+                    sp.set(chunk_slot=slot, chunk_rows=rows,
                            chunk_request_id=req.request_id,
                            chunk_pick=rule)
                     # The other admissions with a chunk to run wait a turn.
@@ -1724,13 +1765,33 @@ class ContinuousScheduler:
                         # at once: a prompt it ended is a lane before
                         # the step noted earlier steps it.
                         self._first_tokens(active)
+                # The step read below is the one that was in flight, if
+                # one was: its chunk was noted a tick ago.
+                rows_read = self._chunk_rows_ahead if ahead else rows
                 if dispatched:
+                    self._chunk_rows_ahead = rows
                     if not ahead:
                         self._t_collect = time.perf_counter()
+                        if self._stalls is not None:
+                            self._stalls.mark()
                     elif self.telemetry:
                         self._m_steps_ahead.inc()
                 if collect and steps.steps_in_flight:
                     toks, produced, eos = steps.collect_step()
+                    # Collect to collect: with a step always queued
+                    # behind the one being read, that IS the gap between
+                    # a lane's tokens (from its own dispatch for a step
+                    # nothing was queued ahead of).
+                    now = time.perf_counter()
+                    # The stall measurements beside the clock, nothing
+                    # between the two (_observe_tick).
+                    here = (self._stalls.read()
+                            if self._stalls is not None else None)
+                    step_dt, self._t_collect = now - self._t_collect, now
+                    n_produced = sum(1 for slot in active if produced[slot])
+                    if here is not None:
+                        self._observe_tick(step_dt, sp, n_produced,
+                                           rows_read, here)
                 else:
                     collect = False
         except Exception as e:
@@ -1743,20 +1804,11 @@ class ContinuousScheduler:
         if not collect:
             self._count_decoder()
             return True
-        # Collect to collect: with a step always queued behind the one
-        # being read, that IS the gap between a lane's tokens (from its
-        # own dispatch for a step nothing was queued ahead of).
-        now = time.perf_counter()
-        step_dt, self._t_collect = now - self._t_collect, now
         if self.watchdog is not None:
             self.watchdog.beat()
         self.last_tick_ts = time.time()
-        n_produced = sum(1 for slot in active if produced[slot])
         if self.telemetry:
             self._m_step.observe(step_dt)
-            self._sentinel.observe(
-                step_dt, step=int(getattr(self.decoder, "steps", 0))
-            )
             self._m_decode_steps.inc()
             # Per-token decode latency: the step IS the inter-token
             # gap for every lane that emitted this step.
@@ -1781,6 +1833,33 @@ class ContinuousScheduler:
         self._first_tokens(active)
         self._count_decoder()
         return True
+
+    def _observe_tick(self, step_dt: float, sp, lanes: int,
+                      chunk_rows: int, here) -> None:
+        """The step-time sentinel's turn, right after a collect and
+        inside its `decode_step` span. A flagged tick books its excess
+        under one cause (TickStalls), and the span and the
+        `step_anomaly` event name it beside the lanes and the chunk rows
+        of the step read; every tick marks where the next one's interval
+        starts, and calls no metric. ONE reading (`here`), taken right
+        beside the clock's, ends this interval and starts the next: a
+        pause that begins between the two is timed into the next tick
+        and measured into this one (met twice in ~110 stalls on the
+        chip while the reading came a few lines later; PERF.md section
+        6, PRs 59-61). This runs inside `_step`'s `try`, whose handler fails
+        every lane: a booking that raises stays in the sentinel
+        (`observe` logs it and emits the event without its fields)."""
+        def explain(excess_s: float) -> Dict[str, Any]:
+            booked = self._stalls.book(excess_s, here)
+            sp.set(**{k: booked[k]
+                      for k in ("stall_s", "stall_cause", "stall_phase")})
+            return {**booked, "lanes": lanes, "chunk_rows": chunk_rows}
+
+        self._sentinel.observe(
+            step_dt, step=int(getattr(self.decoder, "steps", 0)),
+            explain=explain,
+        )
+        self._stalls.mark(here)
 
     def _count_decoder(self) -> None:
         """The decoder counts the lane-steps it drops (where a lane is
@@ -2180,6 +2259,7 @@ class ChatServer:
         # history sampler stops for the same reason.
         if getattr(self, "watchdog", None) is not None:
             self.watchdog.close()
+        self.batcher.close()
         if self.history is not None:
             self.history.stop()
             if self._installed_history and get_history() is self.history:

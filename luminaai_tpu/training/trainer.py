@@ -50,6 +50,7 @@ from luminaai_tpu.monitoring.timeseries import (
 from luminaai_tpu.monitoring.tracing import SpanTracer
 from luminaai_tpu.monitoring.watchdog import (
     HangWatchdog,
+    ProcessPauses,
     StepTimeSentinel,
     host_step_skew,
 )
@@ -372,6 +373,16 @@ class Trainer:
 
         if config.auto_resume:
             self.maybe_resume()
+        # The process-wide collector hook and heartbeat (process_gc_* /
+        # process_pause_* / process_wall_seconds_total; one a process,
+        # shared with a scheduler's): the goodput ledger's kind of
+        # wall-clock counter, on its switch. Nothing on the step path.
+        # Last: after every thread this constructor starts (the history
+        # sampler, a resume's readers), as the scheduler starts it after
+        # its worker.
+        self._pauses: Optional[ProcessPauses] = None
+        if config.goodput:
+            self._pauses = ProcessPauses.start(self.registry, self.tracer)
 
     # -- checkpoint/resume ------------------------------------------------
     def maybe_resume(self) -> bool:
@@ -1720,6 +1731,9 @@ class Trainer:
         self.stop_profile()  # the run ended inside the profiled window
         if self.watchdog is not None:
             self.watchdog.close()
+        pauses, self._pauses = getattr(self, "_pauses", None), None
+        if pauses is not None:
+            pauses.close(self.registry, self.tracer)
         if self.history is not None:
             self.history.stop()
             if self._installed_history and get_history() is self.history:

@@ -41,3 +41,17 @@ import pytest  # noqa: E402
 def _assert_cpu_mesh():
     devices = jax.devices()
     assert devices[0].platform == "cpu" and len(devices) == 8, devices
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _leave_the_pause_recorder():
+    """Most files make schedulers and never close them (the worker is a
+    daemon thread that never ends). Each keeps its place in the
+    process-wide pause recorder, whose one heartbeat thread would go on
+    feeding every registry this worker process ever made: after each
+    file, leave on their behalf (the last one out takes the collector's
+    hook off and joins the thread)."""
+    yield
+    from luminaai_tpu.monitoring.watchdog import ProcessPauses
+
+    ProcessPauses.shutdown()
