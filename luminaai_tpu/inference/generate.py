@@ -965,11 +965,18 @@ class StepwiseDecoder:
     through the pool's ring table (kv_pool.py), and what a ring cannot
     honour is refused by name (RingKeepsWindowError).
 
-    One tick-program compile per sampling parameter set and page extent
-    (max_new is host state, NOT part of the compile key — mixed-length
-    workloads share one executable, the core of the continuous-batching
-    win — and so is whether a chunk is pending: an empty chunk is
-    padding rows).
+    One tick-program compile per sampling parameter set (max_new is host
+    state, NOT part of the compile key — mixed-length workloads share one
+    executable, the core of the continuous-batching win — and so is
+    whether a chunk is pending: an empty chunk is padding rows). Where
+    the decode kernel attends every layer's lanes (lane_attention, whose
+    grid stops at the longest lane's last key block and skips by the
+    lanes' lengths) that is the ONE program of a sampling set, planned
+    over the slot's whole pages; where XLA attends any layer's lanes (it
+    reads a [lanes, extent] slice), or a prefix cache's page table is
+    chased, a ladder of them, one per power-of-two page extent
+    (_tick_ladder, _active_extent). `tick_programs_built` counts what
+    was built, by extent.
     """
 
     # In-flight dedup safety bound: a parked follower proceeds cold
@@ -1214,6 +1221,24 @@ class StepwiseDecoder:
                 page_size=page_size,
                 tenant_quota=prefix_cache_tenant_quota,
             )
+        # What the tick program is specialised by. Where XLA attends any
+        # layer's lanes it reads a [lanes, extent] slice, so the program
+        # is built per power-of-two page extent and a tick reads no
+        # further than its longest lane. Where the decode kernel attends
+        # every layer's lanes (each paged kind, the latent layers) an
+        # extent would only bound its grid, which the kernel bounds
+        # itself from the lengths (lane_grid_blocks): ONE program a
+        # sampling key, planned over the slot's whole pages. Under a
+        # chased page table (the prefix cache) a block is one page and
+        # no cell has measured a plan of lanes x pages steps: the ladder
+        # stays there. 'dense' knows no extent.
+        kernels = list(self._lane_kernels.values()) + (
+            [self._latent_lane_kernel] if latent_layers else [])
+        self._tick_ladder = self.backend != "dense" and not (
+            all(kernels) and self.prefix_cache is None)
+        # Tick programs built (_get_step missed its cache), by extent:
+        # "full" or the rung's rows.
+        self.tick_programs_built: Dict[str, int] = collections.Counter()
         # Global page table [num_slots, pages]: entry (s, j) is the
         # GLOBAL pool page id (slot * pages + page) logical page j of
         # lane s reads through. Identity (own pages) except where a
@@ -1557,13 +1582,13 @@ class StepwiseDecoder:
     def _active_extent(self, pos=None, live=None) -> int:
         """Resident-extent bound in ROWS for the ragged decode step: a
         power-of-two page count covering every active lane's rows
-        (>= 1 page, <= the slot's pages). The step executable is
-        specialized per extent — O(log pages) executables, the same
-        ladder discipline as prompt buckets — and within one extent the
-        kernel/length mask still skips per-lane. `pos` / `live`: the
-        write rows and lanes of a step about to be dispatched (the
-        host's prediction, an upper bound); default, the collected
-        state."""
+        (>= 1 page, <= the slot's pages). Where XLA attends the lanes
+        (_tick_ladder) the step executable is specialized per extent —
+        O(log pages) executables, the same ladder discipline as prompt
+        buckets — and within one extent the length mask still skips
+        per-lane. `pos` / `live`: the write rows and lanes of a step
+        about to be dispatched (the host's prediction, an upper bound);
+        default, the collected state."""
         if pos is None:
             pos, live = self._pos, self._active
         ps = self.pool.page_size
@@ -1582,10 +1607,15 @@ class StepwiseDecoder:
         once for the step and once for the chunk. A tick with no chunk
         pending runs the same program with the chunk's rows as padding
         (position -1: they write nothing and their output is unread):
-        no second shape exists for a warm-up to miss."""
+        no second shape exists for a warm-up to miss. `extent` None: the
+        one program of a sampling key, over the slot's whole pages (the
+        decode kernel attends every lane, or 'dense'); a number of rows:
+        one rung of the ladder (_tick_ladder), whose XLA attention reads
+        that slice."""
         use_global = self.prefix_cache is not None
         key = ("step", sample_key, self.backend, extent, use_global)
         if key not in self._fns:
+            self.tick_programs_built[str(extent or "full")] += 1
             from luminaai_tpu.models.layers import Embedder
 
             temperature, top_k, top_p, rep_penalty = sample_key
@@ -2417,8 +2447,7 @@ class StepwiseDecoder:
             )
             tick[4 * S + 5:] = chunk["ids"][0, start:start + n]
         extent = (
-            self._active_extent(lanes[0], live)
-            if self.backend != "dense" else None
+            self._active_extent(lanes[0], live) if self._tick_ladder else None
         )
         return self._get_step(sample_key, extent), tick, live, extent
 
@@ -2432,8 +2461,9 @@ class StepwiseDecoder:
         decode kernel does (`_lane_kernel`): for a stepped lane the rows
         of the key blocks in which its query sees a key, nothing for a
         lane not stepped (_lane_blocks_read: the kernel's own plan, on the
-        host), and the kernel's grid steps, all and live. A wrap each time
-        a row is written onto the ring's first row again."""
+        host), and the kernel's grid steps, all and live; `extent` None
+        (the one tick program): the plan is the slot's whole pages. A
+        wrap each time a row is written onto the ring's first row again."""
         ring = self.pool.ring_pages * self.pool.page_size
         lanes_full = extent or self.slot_tokens
         lanes_window = ring or lanes_full
@@ -2485,11 +2515,13 @@ class StepwiseDecoder:
         """(grid steps, steps that fetch and compute, rows of k/v such a
         step fetches) of one layer's lane_attention call in a tick that
         steps the lanes `slots`, holding `held` rows each, over `rows`
-        rows a lane (the tick's extent, or the ring): lane_pages_held and
-        lane_blocks, the kernel's own plan, over the host's lengths.
+        rows a lane (the slot's whole pages or the tick's extent, or the
+        ring): lane_pages_held, lane_blocks and lane_grid_blocks, the
+        kernel's own plan and its grid's bound, over the host's lengths.
         `row`: (k/v heads, key columns) of a pool row."""
         from luminaai_tpu.ops.ragged_paged_attention import (
             lane_blocks,
+            lane_grid_blocks,
             lane_pages_held,
         )
 
@@ -2507,7 +2539,10 @@ class StepwiseDecoder:
             seen.reshape(len(slots), pages // per_block, per_block)
             .any(axis=2).sum()
         )
-        return self.num_slots * (pages // per_block), fetched, per_block * ps
+        blocks = pages // per_block
+        if not ring:
+            blocks = int(lane_grid_blocks(held, per_block * ps, blocks, np))
+        return self.num_slots * blocks, fetched, per_block * ps
 
     def step_fn_and_args(
         self, sample_key: Optional[Tuple] = None

@@ -700,6 +700,19 @@ def lane_pages_held(
     return xp.where(seen, logical, -1)
 
 
+def lane_grid_blocks(lengths, block_rows: int, blocks: int, xp=jnp):
+    """Key blocks a lane that lane_attention's grid visits over whole
+    pages, of the `blocks` the plan has: up to the longest lane's last,
+    one at the least. Whole pages are visited in logical order, so no
+    later block holds a key any lane's query sees. On the device it is a
+    number the grid reads (a dynamic bound), not a shape: one program
+    whatever the lanes hold, and no grid step for rows that no lane has.
+    `xp` as in _ring_resident: the kernel's bound and the host's count of
+    its steps are one rule."""
+    longest = xp.max(lengths, initial=0)
+    return xp.clip(-(-longest // block_rows), 1, blocks)
+
+
 def lane_plan(
     meta: LaneMeta, lanes: int, pool_rows: int, n_kv: int, head_dim: int,
     itemsize: int, ring: bool = False, block_bytes: Optional[int] = None,
@@ -754,8 +767,11 @@ def _lane_kernel(
     blk_ref,  # scalar prefetch [B * blocks]: and its block of that slot
     rowh_ref, colh_ref, colk_ref, q_ref, *rest,
     scale, window, page_size, per_block, per_tile, cols, v_dim, parts, sink,
+    blocks,
 ):
     del slot_ref, blk_ref  # the index maps read them
+    # `blocks`: key blocks a lane in the plan (held_ref's layout); the
+    # grid may stop short of them (lane_grid_blocks).
     # `parts`: the key comes as that many operands, each as wide as the
     # value, and the query's columns side by side in their order.
     # `v_dim`: no v operand, the value is the key's first v_dim columns.
@@ -792,7 +808,7 @@ def _lane_kernel(
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     qpos = len_ref[b] - 1
-    first = (b * nj + j) * per_block  # this block's first page in held_ref
+    first = (b * blocks + j) * per_block  # this block's first page in held_ref
 
     def tile(t):
         page0 = first + t * per_tile
@@ -884,7 +900,11 @@ def lane_attention(
     slot), a ring what its table put there (ring_key_positions' rule, a
     page at a time). Online softmax in float32, probabilities cast to
     v's dtype: banded_attention_xla's rounding. `meta.extent` bounds the
-    grid, not the operand: no slice of the pool is made.
+    grid, not the operand: no slice of the pool is made. Over whole pages
+    the grid's key blocks stop at the longest lane's last
+    (lane_grid_blocks: a bound the program reads, so one program serves
+    every length and a tick of short lanes pays no step for the rows a
+    slot could hold).
 
     Inside a block, all k/v heads go through the MXU together: k flat as
     [keys x kv_heads, D] against every query head, and a constant mask
@@ -926,6 +946,8 @@ def _lane_attention(q, k, v, meta, ring, interpret, block_bytes, scale,
         meta, B, C, Hkv, D, ks[0].dtype.itemsize, ring, block_bytes
     )
     nb = held.shape[1] // per_block
+    # A ring is visited whole, in physical order.
+    steps = nb if ring else lane_grid_blocks(lengths, per_block * ps, nb)
 
     Hp = -(-Hq // 16) * 16
     cols = per_tile * ps * Hkv
@@ -943,7 +965,7 @@ def _lane_attention(q, k, v, meta, ring, interpret, block_bytes, scale,
     own = lambda b, j, *_: (b, 0, 0)  # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(B, nb),
+        grid=(B, steps),
         in_specs=[
             pl.BlockSpec((Hp, LANES), const),
             pl.BlockSpec((8, cols), const),
@@ -964,7 +986,7 @@ def _lane_attention(q, k, v, meta, ring, interpret, block_bytes, scale,
         functools.partial(
             _lane_kernel, scale=scale, window=int(meta.window or 0),
             page_size=ps, per_block=per_block, per_tile=per_tile, cols=cols,
-            v_dim=v_dim, parts=len(ks), sink=sink is not None,
+            v_dim=v_dim, parts=len(ks), sink=sink is not None, blocks=nb,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hp, Dv), q.dtype),

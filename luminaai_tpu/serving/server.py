@@ -706,6 +706,21 @@ class ContinuousScheduler:
 
             export_held_combine(form, r, logger)
             self._event("moe_held_combine", **form)
+        # What the tick program is specialised by shows in how many were
+        # built: one a sampling key (`full`) where the decode kernel
+        # attends every layer's lanes, one per page extent it met (the
+        # rung's rows) where XLA attends them. Grows in warm-up, not
+        # under load. (No `serve_tick_*` family with telemetry off, as
+        # for the phase ledger's.)
+        self._m_tick_programs = r.counter(
+            "serve_tick_programs_built_total",
+            "Tick programs the decoder built (a miss of its cache of "
+            "jitted steps), by the rows of k/v the program is "
+            "specialised by: `full` (the slot's whole pages) or a rung "
+            "of the page-extent ladder",
+            labelnames=("extent",),
+        ) if self.telemetry else None
+        self._tick_programs_seen: Dict[str, int] = {}
         # The decoder counts these where they happen; the registry
         # follows (_count_decoder).
         self._decoder_counters = (
@@ -1863,8 +1878,8 @@ class ContinuousScheduler:
 
     def _count_decoder(self) -> None:
         """The decoder counts the lane-steps it drops (where a lane is
-        released or meets a stop token) and the chunks its steps carry;
-        the registry follows."""
+        released or meets a stop token), the chunks its steps carry and
+        the tick programs it builds; the registry follows."""
         for name, metric in self._decoder_counters:
             n = getattr(self.decoder, name, 0)
             seen = self._decoder_seen[name]
@@ -1872,6 +1887,13 @@ class ContinuousScheduler:
                 if self.telemetry:
                     metric.inc(n - seen)
                 self._decoder_seen[name] = n
+        built = getattr(self.decoder, "tick_programs_built", None)
+        if built and self._m_tick_programs is not None:
+            for extent, n in tuple(built.items()):
+                seen = self._tick_programs_seen.get(extent, 0)
+                if n != seen:
+                    self._m_tick_programs.labels(extent=extent).inc(n - seen)
+                    self._tick_programs_seen[extent] = n
 
 
 class _SlotStream:
