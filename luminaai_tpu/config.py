@@ -152,9 +152,27 @@ class Config:
     #               float32 state; trained by a chunked XLA scan, served
     #               by ops/ssm.py's kernel with a fixed state a lane
     #               beside the pages of k/v (every serving path but the
-    #               prefix cache, page pulls and speculation).
+    #               prefix cache, page pulls and speculation);
+    #   'ssm2'      ScalarDecaySSM (models/ssm.py): Mamba-2's heads, ONE
+    #               decay and ONE step a head (ssm2_num_heads of
+    #               ssm2_head_dim), B and C shared by ssm2_groups groups
+    #               of heads, the convolution over [x, B, C], a gated
+    #               RMSNorm by group behind the scan; the same
+    #               [state x channels] float32 LaneState as 'ssm'.
+    #               Trained and prefilled in the block (matmul) form of
+    #               ops/ssm.py::block_scan, the lanes stepped by
+    #               ops/ssm.py::ssm_scan_heads;
+    #   'none'      no mixer: the layer is its feed-forward alone behind
+    #               ONE norm, and a lane keeps nothing of it (no cache
+    #               entry: init_cache's None).
     # None = every layer 'attention'. scan_layers needs one kind.
     layer_mixers: Optional[tuple] = None
+    # Feed-forward of each layer, beside layer_mixers: 'dense' (SwiGLU),
+    # 'moe' (MoELayer) or 'none' (the layer is its mixer alone behind ONE
+    # norm: x + mixer(norm(x))). None = by moe_pattern (is_moe_layer),
+    # every layer with a feed-forward. Set, it IS the placement:
+    # is_moe_layer reads it and moe_pattern is not consulted.
+    layer_ffns: Optional[tuple] = None
     # False: GQAttention rotates nothing (a stack whose recurrent layers
     # carry the position, as Jamba's).
     use_rope: bool = True
@@ -203,6 +221,20 @@ class Config:
     ssm_dt_rank: Optional[int] = None  # None = ceil(hidden_size / 16)
     ssm_expand: int = 2
     ssm_conv_size: int = 4
+    # An 'ssm2' layer: heads x head width is its inner width (the state is
+    # [ssm_state_size, heads x head width] float32 a lane), B and C are
+    # shared by ssm2_groups groups of heads (the convolution runs over
+    # inner + 2 x groups x ssm_state_size channels), ssm2_chunk rows a
+    # block of the matmul form. The step's bias is drawn as the inverse
+    # softplus of a log-uniform step in [ssm2_dt_min, ssm2_dt_max]
+    # floored at ssm2_dt_floor, A_log as log U(1, 16) a head.
+    ssm2_num_heads: int = 8
+    ssm2_head_dim: int = 64
+    ssm2_groups: int = 1
+    ssm2_chunk: int = 128
+    ssm2_dt_min: float = 1e-3
+    ssm2_dt_max: float = 1e-1
+    ssm2_dt_floor: float = 1e-4
     kda_num_heads: Optional[int] = None  # None = num_heads
     kda_head_dim: int = 128
     kda_conv_size: int = 4
@@ -271,6 +303,20 @@ class Config:
     # mean ('average').
     num_shared_experts: int = 0
     shared_expert_combine: str = "sum"
+    # The three below run under experts_held alone (the other dispatch
+    # paths refuse them by name).
+    # 'swiglu': W_down (silu(W_gate x) * W_up x), wi holds gate | up;
+    # 'relu2': the non-gated W_down relu(W_up x)^2, wi holds W_up alone.
+    moe_expert_act: str = "swiglu"
+    # Experts in a latent: the layer projects its rows H -> moe_latent_size
+    # (`fc1`) in front of dispatch and back (`fc2`) behind the combine, the
+    # experts are moe_latent_size wide in and out; the router and the
+    # shared expert read the un-projected row. None: experts at H.
+    moe_latent_size: Optional[int] = None
+    # A shared expert of its OWN width (one MLP of this width and of the
+    # experts' activation on the un-projected row, added once): set, it
+    # replaces num_shared_experts x the expert width.
+    moe_shared_size: Optional[int] = None
     # (offset, count): this program holds experts [offset, offset+count)
     # of num_experts, as one chip of an expert-parallel group does. The
     # router keeps its num_experts outputs and its top-k; the layer
@@ -598,6 +644,8 @@ class Config:
         self.moe_stat_pmean_axes = tuple(self.moe_stat_pmean_axes)
         if self.layer_mixers is not None:
             self.layer_mixers = tuple(self.layer_mixers)
+        if self.layer_ffns is not None:
+            self.layer_ffns = tuple(self.layer_ffns)
         if self.experts_held is not None:
             self.experts_held = tuple(int(x) for x in self.experts_held)
         if self.layer_windows is not None:
@@ -978,28 +1026,73 @@ class Config:
                     "(an 'expert' mesh axis derives the share itself)"
                 )
                 assert not self.use_mod, "experts_held does not compose with MoD"
+            assert self.moe_expert_act in ("swiglu", "relu2"), (
+                f"invalid moe_expert_act {self.moe_expert_act}"
+            )
+            for name, off in (
+                ("moe_expert_act", "swiglu"), ("moe_latent_size", None),
+                ("moe_shared_size", None),
+            ):
+                assert getattr(self, name) == off or (
+                    self.experts_held is not None
+                ), (
+                    f"{name}={getattr(self, name)!r} runs under "
+                    "experts_held (moe_dispatch='gmm' over a share of the "
+                    "experts): the sort, gather, einsum and a2a paths and "
+                    "the unshared gmm path compute SwiGLU experts at the "
+                    "hidden width"
+                )
+        if self.layer_ffns is not None:
+            assert len(self.layer_ffns) == self.num_layers, (
+                f"layer_ffns names {len(self.layer_ffns)} layers, "
+                f"num_layers is {self.num_layers}"
+            )
+            assert set(self.layer_ffns) <= {"dense", "moe", "none"}, (
+                f"invalid layer_ffns {sorted(set(self.layer_ffns))}"
+            )
+            assert self.use_moe or "moe" not in self.layer_ffns, (
+                "layer_ffns names 'moe' layers and use_moe is off"
+            )
+            assert not self.scan_layers and not self.parallel_block, (
+                "layer_ffns (a feed-forward kind a layer) runs an unrolled "
+                "stack of sequential blocks: no scan_layers, no "
+                "parallel_block"
+            )
+            assert self.pipeline_parallel_size == 1, (
+                "layer_ffns does not compose with pipeline parallelism yet"
+            )
         if self.layer_mixers is not None:
             assert len(self.layer_mixers) == self.num_layers, (
                 f"layer_mixers names {len(self.layer_mixers)} layers, "
                 f"num_layers is {self.num_layers}"
             )
             kinds = set(self.layer_mixers)
-            assert kinds <= {"attention", "latent", "kda", "ssm"}, (
-                f"invalid layer_mixers {sorted(kinds)}"
-            )
+            assert kinds <= {
+                "attention", "latent", "kda", "ssm", "ssm2", "none"
+            }, f"invalid layer_mixers {sorted(kinds)}"
+            if "ssm2" in kinds:
+                inner = self.ssm2_inner()
+                assert self.ssm2_num_heads % self.ssm2_groups == 0, (
+                    f"ssm2_num_heads {self.ssm2_num_heads} is no multiple "
+                    f"of ssm2_groups {self.ssm2_groups}"
+                )
+                assert inner % 128 == 0 or inner < 128, (
+                    f"an 'ssm2' layer's inner width {inner} is neither a "
+                    "multiple of 128 lanes nor under one tile"
+                )
             assert not (self.scan_layers and len(kinds) > 1), (
                 "scan_layers needs a stack of one mixer kind; "
                 f"layer_mixers has {sorted(kinds)}"
             )
-            if kinds - {"attention"}:
+            if kinds - {"attention", "none"}:
                 for name, size in (
                     ("sequence", self.sequence_parallel_size),
                     ("pipeline", self.pipeline_parallel_size),
                     ("tensor", self.tensor_parallel_size),
                 ):
                     assert size == 1, (
-                        f"'latent', 'kda' and 'ssm' mixers do not compose "
-                        f"with {name}_parallel_size={size} yet"
+                        f"'latent', 'kda', 'ssm' and 'ssm2' mixers do not "
+                        f"compose with {name}_parallel_size={size} yet"
                     )
                 assert self.attention_window is None, (
                     "'latent', 'kda' and 'ssm' mixers take no "
@@ -1012,6 +1105,14 @@ class Config:
             ), (
                 "kv_cache_dtype='int8' does not compose with 'latent' "
                 "layers: the latent entry carries no per-row scales"
+            )
+        if self.layer_mixers is not None and self.layer_ffns is not None:
+            assert ("none", "none") not in zip(
+                self.layer_mixers, self.layer_ffns
+            ), (
+                f"layer_mixers {self.layer_mixers} and layer_ffns "
+                f"{self.layer_ffns}: a layer has neither mixer nor "
+                "feed-forward"
             )
         assert self.yarn_factor is None or (
             self.latent_rope and self.yarn_factor >= 1.0
@@ -1186,19 +1287,34 @@ class Config:
     def ssm_rank(self) -> int:
         return self.ssm_dt_rank or -(-self.hidden_size // 16)
 
+    def ssm2_inner(self) -> int:
+        return self.ssm2_num_heads * self.ssm2_head_dim
+
+    def ssm2_conv_width(self) -> int:
+        """Channels an 'ssm2' layer's convolution runs over: x, B, C."""
+        return self.ssm2_inner() + 2 * self.ssm2_groups * self.ssm_state_size
+
     def mixer_kind(self, layer_idx: int) -> str:
-        """'attention' | 'latent' | 'kda' | 'ssm' for a layer
-        (layer_mixers)."""
+        """'attention' | 'latent' | 'kda' | 'ssm' | 'ssm2' | 'none' for a
+        layer (layer_mixers)."""
         if self.layer_mixers is None:
             return "attention"
         return self.layer_mixers[layer_idx]
+
+    def ffn_kind(self, layer_idx: int) -> str:
+        """'dense' | 'moe' | 'none' for a layer: layer_ffns, or
+        moe_pattern's placement where that is None."""
+        if self.layer_ffns is not None:
+            return self.layer_ffns[layer_idx]
+        return "moe" if self.is_moe_layer(layer_idx) else "dense"
 
     def unserved_mixers(self) -> tuple:
         """The mixer kinds of this stack that only training runs: 'kda'
         (no delta-rule state a lane yet). 'attention', 'ssm' and 'latent'
         layers are served."""
         return tuple(sorted(
-            set(self.layer_mixers or ()) - {"attention", "ssm", "latent"}
+            set(self.layer_mixers or ())
+            - {"attention", "ssm", "ssm2", "latent", "none"}
         ))
 
     def recurrent_or_latent(self) -> bool:
@@ -1235,7 +1351,12 @@ class Config:
         """True when some layer keeps a fixed state a lane (models/ssm.py
         LaneState) and not pages of k/v: what the prefix cache, page
         pulls and speculation cannot share, copy or roll back yet."""
-        return "ssm" in (self.layer_mixers or ())
+        return bool({"ssm", "ssm2"} & set(self.layer_mixers or ()))
+
+    def moe_width(self) -> int:
+        """Width of the rows the routed experts read and write: the
+        latent's, or the hidden size."""
+        return self.moe_latent_size or self.hidden_size
 
     def expert_width(self) -> int:
         return self.moe_intermediate_size or self.intermediate_size
@@ -1285,6 +1406,8 @@ class Config:
         """MoE layer placement pattern (ref core/model.py:1545 _should_use_moe)."""
         if not self.use_moe or self.moe_pattern == "none":
             return False
+        if self.layer_ffns is not None:
+            return self.layer_ffns[layer_idx] == "moe"
         if self.moe_pattern == "all":
             return True
         if self.moe_pattern == "every_3rd":

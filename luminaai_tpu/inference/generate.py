@@ -995,7 +995,7 @@ class StepwiseDecoder:
         prefix_cache_pages: Optional[int] = None,
         prefix_cache_tenant_quota: Optional[int] = None,
     ):
-        from luminaai_tpu.inference.kv_pool import PagedKVPool
+        from luminaai_tpu.inference.kv_pool import PagedKVPool, lane_states
 
         self.engine = engine
         self.model = engine.model
@@ -1083,6 +1083,14 @@ class StepwiseDecoder:
             ring_pages=ring_pages,
         )
         self._ring = (page_size, _chunk_eff) if ring_pages else None
+        # A stack with 'ssm2' layers takes EVERY prompt in chunks, as a
+        # pool with rings does: the tick's chunk enters from the slot's
+        # stored state in block form and rides the weights' one read,
+        # where the whole-prompt path is one more block-form program a
+        # bucket (64, 128, 256 rows) and an insert of the fresh states
+        # (4 MB a layer) an admission.
+        self._chunks_every_prompt = _chunk_eff > 0 and "ssm2" in (
+            engine.config.layer_mixers or ())
         self.pool.caches = self._init_pool_caches()
         # The decode budget honors the ENGINE's context contract: the
         # page rounding above may leave slack rows past max_context, and
@@ -1098,6 +1106,13 @@ class StepwiseDecoder:
         self._held = bool(
             engine.config.use_moe and engine.config.experts_held
         )
+        # Experts in a latent: a fourth count, the held experts a tick's
+        # pairs touched, summed over layers (their weights are what the
+        # grouped matmuls read).
+        self._held_keys = (
+            "moe_routed_pairs", "moe_held_pairs", "moe_held_pairs_dropped",
+        ) + (("moe_held_experts_hit",)
+             if self._held and engine.config.moe_latent_size else ())
         # Host-side lane state; device state is the pool + counts + rngs.
         self._reset_lane_state()
         self.steps = 0
@@ -1110,8 +1125,15 @@ class StepwiseDecoder:
         self.chunks_carried = 0
         self.chunk_rows = 0
         # With state-space layers: live rows through the recurrence
-        # (stepped lanes + live chunk rows).
+        # (stepped lanes + live chunk rows), and the bytes of state they
+        # moved (a stepped lane's and a live chunk's slab read and
+        # written, a layer: twice _state_bytes, what one slot's states
+        # hold without their tails, from the pool's own shapes).
         self.ssm_rows = 0
+        self.ssm_state_bytes = 0
+        self._state_bytes = sum(
+            s.state.nbytes // s.state.shape[-3]
+            for s in lane_states(self.pool.caches))
         # Rows of k/v the ticks' attention read, by kind of layer (window
         # of its own / full), and the times a lane's rows came round its
         # ring: counted on the host from the lengths it has
@@ -1146,6 +1168,8 @@ class StepwiseDecoder:
         self.moe_routed_pairs = 0
         self.moe_held_pairs = 0
         self.moe_held_pairs_dropped = 0
+        self.moe_held_experts_hit = 0
+        self.moe_held_experts = 0
         self._fns: Dict[Any, Any] = {}
         # Serving attention backend (config.attention_backend): 'dense'
         # keeps the legacy full-extent per-lane mask; the ragged backends
@@ -1390,7 +1414,8 @@ class StepwiseDecoder:
         self._host_tok = np.ones((S,), bool)
         # (With a share of the experts its three pair counts ride behind
         # the lanes' tokens.)
-        self._nxt_dev = jnp.zeros((S + 3 * self._held,), jnp.int32)
+        self._nxt_dev = jnp.zeros(
+            (S + len(self._held_keys) * self._held,), jnp.int32)
 
     def _identity_gtable(self) -> np.ndarray:
         P = self.pool.pages
@@ -1629,6 +1654,7 @@ class StepwiseDecoder:
             page_size = self.pool.page_size
             ring_table = self._ring_table
             held = self._held
+            held_keys = self._held_keys
             moe_layers = self.engine.config.num_moe_layers()
             lm_head = Embedder(self.model.config, dtype=self.model.dtype)
 
@@ -1765,9 +1791,7 @@ class StepwiseDecoder:
                     # (a layer's mean x the expert layers), behind the
                     # tokens: one fetch brings both.
                     nxt = jnp.concatenate([nxt, jnp.round(jnp.stack([
-                        aux[k] for k in (
-                            "moe_routed_pairs", "moe_held_pairs",
-                            "moe_held_pairs_dropped")
+                        aux[k] for k in held_keys
                     ]) * moe_layers).astype(jnp.int32)])
                 return self._paged(flat), nxt, eos, counts, new_rngs
 
@@ -1960,7 +1984,8 @@ class StepwiseDecoder:
                     )
             if L <= chunk and not peek_keys:
                 return None
-        elif L <= chunk and not self.pool.ring_pages:
+        elif (L <= chunk and not self.pool.ring_pages
+              and not self._chunks_every_prompt):
             # A one-chunk prompt can't stall anyone longer than a chunk
             # anyway, and the bucketed prefill_into_slot path moves only
             # a page-aligned prompt prefix where a chunk call round-trips
@@ -2184,6 +2209,7 @@ class StepwiseDecoder:
         self.chunks_carried += int(carried)
         if self.pool.keeps_state:
             self.ssm_rows += end - start
+            self.ssm_state_bytes += 2 * self._state_bytes
         st["next"] += 1
         last = st["next"] >= st["n_chunks"]
         # Residency telemetry tracks rows as they land.
@@ -2608,6 +2634,7 @@ class StepwiseDecoder:
         self._host_tok[:] = False
         if self.pool.keeps_state:
             self.ssm_rows += int(live.sum())
+            self.ssm_state_bytes += 2 * int(live.sum()) * self._state_bytes
         self._kv_rows_of(extent, tick[: self.num_slots], live, chunk)
         return {
             "nxt": nxt, "eos": eos, "stepped": live,
@@ -2671,10 +2698,12 @@ class StepwiseDecoder:
         is read once: by collect_step, or by advance_prefill)."""
         got = np.asarray(step["nxt"])
         if self._held:
-            routed, here, dropped = (int(x) for x in got[self.num_slots:])
-            self.moe_routed_pairs += routed
-            self.moe_held_pairs += here
-            self.moe_held_pairs_dropped += dropped
+            for key, n in zip(self._held_keys, got[self.num_slots:]):
+                setattr(self, key, getattr(self, key) + int(n))
+            if "moe_held_experts_hit" in self._held_keys:
+                cfg = self.engine.config
+                self.moe_held_experts += (
+                    cfg.experts_held[1] * cfg.num_moe_layers())
         return got[: self.num_slots]
 
     def abandon_steps(self) -> None:

@@ -208,6 +208,32 @@ class SwiGLU(nn.Module):
         return jnp.einsum("...f,fd->...d", act, wo.astype(self.dtype))
 
 
+class Relu2MLP(nn.Module):
+    """The non-gated FFN down(relu(up(x))^2): two matrices, no bias."""
+
+    intermediate_size: int
+    dtype: Dtype = jnp.bfloat16
+    init_std: float = 0.02
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        hidden = x.shape[-1]
+        wi = self.param(
+            "wi",
+            nn.with_logical_partitioning(
+                default_init(self.init_std), ("embed", "mlp")),
+            (hidden, self.intermediate_size), jnp.float32)
+        wo = self.param(
+            "wo",
+            nn.with_logical_partitioning(
+                default_init(self.init_std / jnp.sqrt(2.0)),
+                ("mlp", "embed")),
+            (self.intermediate_size, hidden), jnp.float32)
+        up = jnp.einsum("...d,df->...f", x, wi.astype(self.dtype))
+        return jnp.einsum(
+            "...f,fd->...d", jnp.square(nn.relu(up)), wo.astype(self.dtype))
+
+
 # Above this many bytes of [chunk rows, heads, keys] float32 scores the
 # tick's chunk attends through the blocked kernel (GQAttention.
 # _tick_attention): 64 x 16 x 2,048 and 64 x 20 x 2,048 scores are 8 and

@@ -263,6 +263,10 @@ class MoELayer(nn.Module):
         G, S, H = x.shape
         E, k = cfg.num_experts, cfg.moe_top_k
         F = cfg.expert_width()
+        # What the routed experts read and write: the hidden row, or its
+        # projection to a latent (fc1 / fc2 below, experts_held alone).
+        Hm = cfg.moe_width()
+        gated = cfg.moe_expert_act == "swiglu"
         capacity = max(1, int(cfg.capacity_factor * S * k / E))
         # Round capacity to a multiple of 8 (fp32 sublane) when big enough —
         # keeps the [E, G, C, H] buffers tileable.
@@ -311,7 +315,7 @@ class MoELayer(nn.Module):
             nn.with_logical_partitioning(
                 default_init(cfg.init_std), ("expert", "embed", "mlp_fused")
             ),
-            (E_w, H, 2 * F),
+            (E_w, Hm, 2 * F if gated else F),
             jnp.float32,
         )
         wo = self.param(
@@ -319,9 +323,21 @@ class MoELayer(nn.Module):
             nn.with_logical_partitioning(
                 default_init(cfg.init_std / jnp.sqrt(2.0)), ("expert", "mlp", "embed")
             ),
-            (E_w, F, H),
+            (E_w, F, Hm),
             jnp.float32,
         )
+        if cfg.moe_latent_size:
+            fc1 = self.param(
+                "fc1",
+                nn.with_logical_partitioning(
+                    default_init(cfg.init_std), ("embed", None)),
+                (H, Hm), jnp.float32)
+            fc2 = self.param(
+                "fc2",
+                nn.with_logical_partitioning(
+                    default_init(cfg.init_std / jnp.sqrt(2.0)),
+                    (None, "embed")),
+                (Hm, H), jnp.float32)
 
         # --- Routing (fp32 throughout; ref :1200) ---
         gate_logits = jnp.einsum("gsh,he->gse", x.astype(jnp.float32), wg)
@@ -385,13 +401,26 @@ class MoELayer(nn.Module):
                 # most once, so S a group never binds); the one limit is
                 # the held experts' rows together, capacity_factor x the
                 # expected N * k * count / E.
+                rows = x
+                if cfg.moe_latent_size:
+                    with jax.named_scope("moe.latent_in"):
+                        rows = jnp.einsum(
+                            "gsh,hl->gsl", x.astype(self.dtype),
+                            fc1.astype(self.dtype))
                 with jax.named_scope("moe_held"):
                     out, tokens_per_expert, dropped, ep_stats = _gmm_held(
-                        x, router_probs, wi, wo, top_k=k, num_experts=E,
+                        rows, router_probs, wi, wo, top_k=k, num_experts=E,
                         offset=cfg.experts_held[0], dtype=self.dtype,
                         gmm_fn=_pick_gmm(), rule=rule,
                         row_bound=held_row_bound(cfg, G * S), live=live,
+                        gated=gated,
+                        count_hit=cfg.moe_latent_size is not None,
                     )
+                if cfg.moe_latent_size:
+                    # No bias, so the shares' fc2 of their parts add up.
+                    with jax.named_scope("moe.latent_out"):
+                        out = jnp.einsum(
+                            "gsl,lh->gsh", out, fc2.astype(self.dtype))
             else:
                 out, tokens_per_expert, dropped = self._gmm_path(
                     x, router_probs, wi, wo, capacity, rule
@@ -528,7 +557,18 @@ class MoELayer(nn.Module):
                 out = jnp.einsum("gsec,egch->gsh", combine_w, expert_out)
         if cfg.expert_output_scaling != 1.0:
             out = out * cfg.expert_output_scaling
-        if cfg.num_shared_experts:
+        if cfg.moe_shared_size:
+            from luminaai_tpu.models.layers import Relu2MLP, SwiGLU
+
+            # ONE shared expert of its own width on the un-projected row.
+            with jax.named_scope("moe.shared"):
+                out = out + (SwiGLU if gated else Relu2MLP)(
+                    cfg.moe_shared_size,
+                    dtype=self.dtype,
+                    init_std=cfg.init_std,
+                    name="shared_expert",
+                )(x.astype(self.dtype))
+        elif cfg.num_shared_experts:
             from luminaai_tpu.models.layers import SwiGLU
 
             # num_shared_experts SwiGLUs of width F side by side are one
@@ -1093,9 +1133,9 @@ def held_combine_form(cfg, tokens: int, dtype):
         return None
     rows = held_row_bound(cfg, tokens)
     return {
-        "T": int(tokens), "R": rows, "H": int(cfg.hidden_size),
+        "T": int(tokens), "R": rows, "H": int(cfg.moe_width()),
         "product": held_combine_is_product(
-            tokens, rows, cfg.hidden_size, dtype
+            tokens, rows, cfg.moe_width(), dtype
         ),
     }
 
@@ -1152,7 +1192,8 @@ def _held_combine(yrow, tok, w_row, row_kept, tokens: int, product: bool):
 
 
 def _gmm_held(x, router_probs, wi, wo, *, top_k, num_experts, offset,
-              row_bound, dtype, gmm_fn, rule, live=None):
+              row_bound, dtype, gmm_fn, rule, live=None, gated=True,
+              count_hit=False):
     """The grouped-matmul expert FFN of a share the configuration names
     (Config.experts_held): wi / wo hold experts [offset, offset + E_l) of
     `num_experts`, routing runs over all of them, and the sort is
@@ -1167,7 +1208,11 @@ def _gmm_held(x, router_probs, wi, wo, *, top_k, num_experts, offset,
     the kernel's uninitialised-tail contract are _gmm_local's. `live`
     [G, S] (a serving tick): rows that are no tokens go to the excluded
     tail with the pairs of experts held elsewhere, and the pair counts are
-    over live rows.
+    over live rows. `gated` False: the experts are the non-gated
+    W_down relu(W_up x)^2 (wi is W_up alone). x may be narrower than the
+    router's rows (a latent). `count_hit`: the stats also carry
+    moe_held_experts_hit, the held experts with a computed row (the
+    weights a call reads are the touched experts').
 
     Returns (out [G,S,H], tokens_per_expert [E], dropped [G,S], and the
     pair counts: routed, held (chosen for a held expert), held and not
@@ -1209,8 +1254,11 @@ def _gmm_held(x, router_probs, wi, wo, *, top_k, num_experts, offset,
     lhs = jnp.where(row_kept, x_flat[tok], 0)
     fused = gmm_fn(lhs, wi.astype(dtype), group_sizes,
                    preferred_element_type=dtype, tiling=_held_gmm_tiling)
-    gate_act, up = jnp.split(fused, 2, axis=-1)
-    act = jnp.where(row_kept, nn.silu(gate_act) * up, 0)
+    if gated:
+        gate_act, up = jnp.split(fused, 2, axis=-1)
+        act = jnp.where(row_kept, nn.silu(gate_act) * up, 0)
+    else:
+        act = jnp.where(row_kept, jnp.square(nn.relu(fused)), 0)
     yrow = gmm_fn(act, wo.astype(dtype), group_sizes,
                   preferred_element_type=dtype, tiling=_held_gmm_tiling)
     with jax.named_scope("moe_held_combine"):
@@ -1223,5 +1271,8 @@ def _gmm_held(x, router_probs, wi, wo, *, top_k, num_experts, offset,
         "moe_held_pairs": routed_here.astype(jnp.float32),
         "moe_held_pairs_dropped": (routed_here - total).astype(jnp.float32),
     }
+    if count_hit:
+        stats["moe_held_experts_hit"] = (
+            group_sizes > 0).sum().astype(jnp.float32)
     return (out.reshape(G, S, H).astype(dtype),
             counts_e.astype(jnp.float32), dropped, stats)

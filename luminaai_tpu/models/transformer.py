@@ -29,7 +29,7 @@ from luminaai_tpu.models.layers import (
     SwiGLU,
 )
 from luminaai_tpu.models.mod import MoDRouter, apply_mod
-from luminaai_tpu.models.ssm import SelectiveSSM
+from luminaai_tpu.models.ssm import ScalarDecaySSM, SelectiveSSM
 from luminaai_tpu.models.moe import MoELayer
 
 Dtype = Any
@@ -112,74 +112,92 @@ class TransformerBlock(nn.Module):
         deterministic = self.deterministic
         metrics: Dict[str, jax.Array] = {}
 
-        normed = block_norm(cfg, self.dtype, "attn_norm")(x)
         kind = cfg.mixer_kind(self.layer_idx)
-        if kind == "attention":
-            h, new_cache = GQAttention(
-                cfg, dtype=self.dtype,
-                multi_row_update=self.multi_row_update,
-                layer_idx=self.layer_idx, name="attention",
-            )(
-                normed,
-                positions=positions,
-                kv_cache=kv_cache,
-                cache_index=cache_index,
-                lane_meta=lane_meta,
-            )
-        elif kind == "ssm":
-            h, new_cache = SelectiveSSM(cfg, dtype=self.dtype, name="ssm")(
-                normed,
-                positions=positions,
-                cache=kv_cache,
-                cache_index=cache_index,
-                lane_meta=lane_meta,
-            )
-        elif kind == "latent":
-            h, new_cache = LatentAttention(
-                cfg, dtype=self.dtype, name="latent_attention"
-            )(
-                normed,
-                positions=positions,
-                kv_cache=kv_cache,
-                cache_index=cache_index,
-                lane_meta=lane_meta,
-            )
-        else:
-            if kv_cache is not None:
-                raise NotImplementedError(
-                    f"layer {self.layer_idx}'s {kind!r} mixer has no "
-                    "decode path (no delta-rule state a lane yet)"
-                )
+        ffn_kind = cfg.ffn_kind(self.layer_idx)
+        decoding = kv_cache is not None
+        if kind == "none":
+            # The layer is its feed-forward alone, ONE norm: a lane keeps
+            # nothing of it, so a cached call shows in cache_index alone.
             new_cache = None
-            h, kda_stats = KimiDeltaAttention(
-                cfg, dtype=self.dtype, name="kda"
-            )(normed)
-            metrics.update(kda_stats)
-        h = checkpoint_name(h, "attn_out")
-        if cfg.parallel_block:
-            # The feed-forward reads the same normed rows as the mixer;
-            # the residual takes both below.
-            residual, y = x + h, normed
-        else:
-            x = x + h
-            x = nn.with_logical_constraint(
-                x,
-                ("activation_batch", "activation_length", "activation_embed"),
-            )
+            decoding = cache_index is not None
             residual = x
             y = block_norm(cfg, self.dtype, "ffn_norm")(x)
+        else:
+            normed = block_norm(cfg, self.dtype, "attn_norm")(x)
+            if kind == "attention":
+                h, new_cache = GQAttention(
+                    cfg, dtype=self.dtype,
+                    multi_row_update=self.multi_row_update,
+                    layer_idx=self.layer_idx, name="attention",
+                )(
+                    normed,
+                    positions=positions,
+                    kv_cache=kv_cache,
+                    cache_index=cache_index,
+                    lane_meta=lane_meta,
+                )
+            elif kind in ("ssm", "ssm2"):
+                mixer = ScalarDecaySSM if kind == "ssm2" else SelectiveSSM
+                h, new_cache = mixer(cfg, dtype=self.dtype, name="ssm")(
+                    normed,
+                    positions=positions,
+                    cache=kv_cache,
+                    cache_index=cache_index,
+                    lane_meta=lane_meta,
+                )
+            elif kind == "latent":
+                h, new_cache = LatentAttention(
+                    cfg, dtype=self.dtype, name="latent_attention"
+                )(
+                    normed,
+                    positions=positions,
+                    kv_cache=kv_cache,
+                    cache_index=cache_index,
+                    lane_meta=lane_meta,
+                )
+            else:
+                if kv_cache is not None:
+                    raise NotImplementedError(
+                        f"layer {self.layer_idx}'s {kind!r} mixer has no "
+                        "decode path (no delta-rule state a lane yet)"
+                    )
+                new_cache = None
+                h, kda_stats = KimiDeltaAttention(
+                    cfg, dtype=self.dtype, name="kda"
+                )(normed)
+                metrics.update(kda_stats)
+            h = checkpoint_name(h, "attn_out")
+            if ffn_kind == "none":
+                # The layer is its mixer alone, ONE norm.
+                x = x + h
+                x = nn.with_logical_constraint(
+                    x, ("activation_batch", "activation_length",
+                        "activation_embed"))
+                return x, new_cache, metrics
+            if cfg.parallel_block:
+                # The feed-forward reads the same normed rows as the mixer;
+                # the residual takes both below.
+                residual, y = x + h, normed
+            else:
+                x = x + h
+                x = nn.with_logical_constraint(
+                    x,
+                    ("activation_batch", "activation_length", "activation_embed"),
+                )
+                residual = x
+                y = block_norm(cfg, self.dtype, "ffn_norm")(x)
         if cfg.is_moe_layer(self.layer_idx):
             # A serving tick's rows at position -1 (a lane not stepped, a
             # chunk's padding) are no tokens: a share's grouped matmul and
             # its pair counters leave them out.
             live = None
-            if kv_cache is not None and positions is not None:
+            if decoding and positions is not None:
                 live = positions >= 0
             ffn_out, moe_metrics = MoELayer(
                 cfg, dtype=self.dtype, deterministic=deterministic, name="moe"
             )(y, live=live)
             metrics.update(moe_metrics)
-        elif cfg.use_mod and kv_cache is None:
+        elif cfg.use_mod and not decoding:
             # MoD skip-routing on dense layers (hybrid mode); decode path runs
             # dense — per-token routing at S=1 has nothing to skip.
             ffn = SwiGLU(
@@ -566,9 +584,15 @@ class LuminaTransformer(nn.Module):
 
         def entry(layer, *lead):
             """What a lane keeps of `layer`, by its mixer: pages of k/v,
-            pages of one latent a token, or a fixed state."""
+            pages of one latent a token, a fixed state, or nothing (a
+            layer that is its feed-forward alone)."""
+            if cfg.mixer_kind(layer) == "none":
+                return None
             if cfg.mixer_kind(layer) == "ssm":
                 return SelectiveSSM.init_cache(
+                    cfg, batch_size, self.dtype, lead)
+            if cfg.mixer_kind(layer) == "ssm2":
+                return ScalarDecaySSM.init_cache(
                     cfg, batch_size, self.dtype, lead)
             if cfg.mixer_kind(layer) == "latent":
                 return LatentAttention.init_cache(

@@ -596,8 +596,9 @@ def lane_attention_eligible(
     the layout: heads fill whole lanes (`head_dim`: the width of an array
     the entry keeps its key in, a whole key or one part of it; `v_dim`:
     the value array's, None = the same); a pool row [kv_heads, width]
-    is whole (8, 128) tiles, one head, or 4 heads of one 128-lane tile
-    (the chip tiles such an array (4, 128), rows one after another), so
+    is whole (8, 128) tiles, one head, or 2 or 4 heads of one 128-lane
+    tile (the chip tiles such an array (2, 128) or (4, 128), rows one
+    after another), so
     the pool flattens to [rows x kv_heads, width] for free: 4 k/v heads
     of 256 columns do NOT (a copy of the pool in front of the kernel),
     which is why a key wider than its value lies in value-width parts
@@ -618,7 +619,8 @@ def lane_attention_eligible(
         and (page_size * n_kv) % 128 == 0
         and all(
             width % 128 == 0
-            and (n_kv == 1 or n_kv % 8 == 0 or (n_kv == 4 and width == 128))
+            and (n_kv == 1 or n_kv % 8 == 0
+                 or (n_kv in (2, 4) and width == 128))
             for width in (head_dim, v_dim or head_dim)
         )
     )

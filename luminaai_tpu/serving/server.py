@@ -615,6 +615,13 @@ class ContinuousScheduler:
             "Live rows through the state-space recurrence: stepped lanes "
             "and live rows of prefill chunks",
         )
+        self._m_ssm_state_bytes = r.counter(
+            "ssm_state_bytes_total",
+            "Bytes of recurrent state the ticks moved: each stepped "
+            "lane's and each live chunk's state read and written, over "
+            "the state-space layers (the host's count, from the pool's "
+            "shapes)",
+        )
         # Layers with a window of their own keep a ring of pages a lane
         # beside the full layers' whole pages: rows of k/v the ticks'
         # attention read in each kind, and the times a lane's rows came
@@ -698,6 +705,20 @@ class ContinuousScheduler:
             "Held pairs not computed: beyond the grouped matmul's static "
             "row bound (must stay 0)",
         )
+        # Experts in a latent (Config.moe_latent_size): the held experts
+        # at least one computed pair fell on, a layer a tick, of those
+        # held: the weights the grouped matmuls read. Both stay 0
+        # elsewhere.
+        self._m_held_experts_hit = r.counter(
+            "moe_held_experts_hit_total",
+            "Held experts with at least one computed pair, summed over "
+            "expert layers and ticks",
+        )
+        self._m_held_experts = r.counter(
+            "moe_held_experts_total",
+            "Held experts, summed over expert layers and ticks (the "
+            "denominator of moe_held_experts_hit_total)",
+        )
         # Which form the held layers' combine has in the tick program
         # (no series without a share of the experts).
         form = getattr(self.decoder, "held_combine", None)
@@ -728,6 +749,7 @@ class ContinuousScheduler:
             ("chunks_carried", self._m_chunks_carried),
             ("chunk_rows", self._m_chunk_rows),
             ("ssm_rows", self._m_ssm_rows),
+            ("ssm_state_bytes", self._m_ssm_state_bytes),
             ("kv_window_rows", self._m_kv_window_rows),
             ("kv_global_rows", self._m_kv_global_rows),
             ("kv_window_bytes", self._m_kv_window_bytes),
@@ -740,6 +762,8 @@ class ContinuousScheduler:
             ("moe_routed_pairs", self._m_routed_pairs),
             ("moe_held_pairs", self._m_held_pairs),
             ("moe_held_pairs_dropped", self._m_held_dropped),
+            ("moe_held_experts_hit", self._m_held_experts_hit),
+            ("moe_held_experts", self._m_held_experts),
         )
         self._decoder_seen = {
             name: 0 for name, _ in self._decoder_counters

@@ -24,6 +24,7 @@ from benchmark.architectures.jamba.test_reference import *  # noqa: F401,F403
 from benchmark.architectures.kimi_k2.test_reference import *  # noqa: F401,F403
 from benchmark.architectures.kimi_linear.test_reference import *  # noqa: F401,F403
 from benchmark.architectures.mimo_v2.test_reference import *  # noqa: F401,F403
+from benchmark.architectures.nemotron_h.test_reference import *  # noqa: F401,F403
 from benchmark.architectures.prenorm_decoder.test_reference import *  # noqa: F401,F403
 from benchmark.tests.test_architectures import *  # noqa: F401,F403
 from benchmark.tests.test_control_serve import *  # noqa: F401,F403
@@ -40,6 +41,7 @@ COLLECTED = (
     "benchmark/architectures/kimi_k2/test_reference.py",
     "benchmark/architectures/kimi_linear/test_reference.py",
     "benchmark/architectures/mimo_v2/test_reference.py",
+    "benchmark/architectures/nemotron_h/test_reference.py",
     "benchmark/architectures/prenorm_decoder/test_reference.py",
     "benchmark/tests/test_architectures.py",
     "benchmark/tests/test_control_serve.py",
@@ -54,7 +56,7 @@ COLLECTED = (
 # benchmark/tests/test_architectures.py was written when the benchmark had
 # ONE architecture, and its resolver test asserts that every cell's is
 # `prenorm_decoder`. PR 35 adds a second one (PR 37 a third, PR 42 a fourth, PR 45 a
-# fifth, PR 50 a sixth), and a PR that adds to the
+# fifth, PR 50 a sixth, PR 63 a seventh), and a PR that adds to the
 # benchmark may not edit a file the benchmark has: the same test is taken
 # here with each cell held to the architecture its own configuration file
 # names, under the same name so that it is counted once. A `benchmark` PR
@@ -128,7 +130,7 @@ def test_every_cell_resolves_its_architecture():  # noqa: F811
                 assert hasattr(module, name), (part, name)
         assert set(arch.work.KERNEL_FNS) == manifest.kernel_names(arch.name)
     assert seen == {"prenorm_decoder", "kimi_linear", "jamba", "cohere2_moe",
-                    "kimi_k2", "mimo_v2"}
+                    "kimi_k2", "mimo_v2", "nemotron_h"}
 
 
 def test_the_stage_means_add_up_to_the_programs_ttft(window):  # noqa: F811

@@ -192,8 +192,10 @@ def test_megablox_gmm_compiles_at_expert_shapes(one_chip, transpose):
     (128, 2048, 20, 1, None, 1024, False),
     (28, 2048, 16, 16, None, 512, False),
     (28, 2048, 16, 16, None, 2048, False),
+    (128, 6144, 32, 2, None, 6144, False),
 ], ids=["command_a_ring", "command_a_whole_pages", "command_a_extent_2048",
-        "jamba_one_kv_head", "olmoe_mha_extent_512", "olmoe_mha_extent_2048"])
+        "jamba_one_kv_head", "olmoe_mha_extent_512", "olmoe_mha_extent_2048",
+        "nemotron_two_kv_heads"])
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
 def test_lane_attention_compiles_at_the_ticks_shapes(
         one_chip, lanes, rows, hq, hkv, window, extent, ring, kv_dtype):
@@ -245,7 +247,7 @@ def test_lane_attention_compiles_at_the_ticks_shapes(
     assert f'"size":"{rpa._LANE_VMEM_LIMIT}"' in call
     pages = rows // PAGE if ring else extent // PAGE
     per_block, _ = rpa.lane_blocks(pages, PAGE, hkv, 128, 2)
-    assert per_block == {8: 7 if ring else 8, 1: 8, 16: 2}[hkv]
+    assert per_block == {8: 7 if ring else 8, 1: 8, 16: 2, 2: 24}[hkv]
     in_flight = 2 * 2 * per_block * PAGE * hkv * 128 * 2
     assert in_flight <= rpa._LANE_VMEM_LIMIT // 8
     if kv_dtype == "bf16":
@@ -429,6 +431,31 @@ def test_ssm_scan_compiles_at_the_ticks_shapes(one_chip, slots):
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert "ssm_scan" in text
     assert ssm._block_width(slots, n, d) == 1024
+
+
+# -- the scalar-decay heads' lanes (nemotron-3-super-serve-reason) -----------
+def test_ssm_scan_heads_compiles_at_the_ticks_shapes(one_chip):
+    """One state-space layer's lanes of one tick at Nemotron-3-Super's
+    widths: 128 lanes' states of 128 x 8192 float32 (537 MB, aliased in
+    and out: no copy of the pool, temporaries a ten-thousandth of it), a
+    grid step a group's 1,024 channels, a ring of four [128, 1024] slabs."""
+    from luminaai_tpu.ops import ssm
+
+    slots, n, d, g = 128, 128, 8192, 8
+    f32 = jnp.float32
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    compiled = jax.jit(ssm.ssm_scan_heads, donate_argnums=(0,)).lower(
+        sds((slots, n, d), f32), sds((slots, d), f32), sds((slots, d), f32),
+        sds((slots, g, n), BF16), sds((slots, g, n), BF16),
+        sds((slots,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "ssm_scan_heads" in text
+    assert ssm._heads_block_width(d, g) == 1024
+    mem = compiled.memory_analysis()
+    pool = slots * n * d * 4
+    assert mem.alias_size_in_bytes == pool
+    assert mem.temp_size_in_bytes < pool // 1000
 
 
 # -- the mixed-window serving cell's kernel (command-a-plus-serve-mixed) -----

@@ -90,7 +90,8 @@ def test_whole_pages_match_the_xla_reference(four_page_blocks, lengths,
     (128, 8, 128, 16, jnp.bfloat16, 2e-2),  # 16 query heads a k/v head
     (20, 1, 128, 128, jnp.bfloat16, 2e-2),  # 20 over one
     (6, 2, 16, 4, jnp.float32, 2e-5),       # the tick test's tiny heads
-], ids=["group16_of_8", "group20_of_1", "group3_of_2"])
+    (32, 2, 128, 128, jnp.bfloat16, 2e-2),  # 16 over each of two
+], ids=["group16_of_8", "group20_of_1", "group3_of_2", "group16_of_2"])
 def test_head_groups_match_the_xla_reference(Hq, Hkv, D, ps, dtype, tol):
     C = 8 * ps
     lengths = [C, 0, 3 * ps + 1, 1]
@@ -271,11 +272,13 @@ SERVED = {
     # (PR 50) 4 k/v heads of ONE 128-lane tile: the chip tiles such an
     # array (4, 128), rows one after another, so it flattens for free
     "mimo-v2-flash-serve-reason, full layers": (64, 4, 128, 128),
+    # (PR 63) and 2 heads of one 128-lane tile alike: tiled (2, 128)
+    "nemotron-3-super-serve-reason": (32, 2, 128, 128),
 }
 NOT_BY_LAYOUT = {
     "the flagship preset: half a lane a head": (16, 8, 64, 128),
     "half a tile a row, two tiles wide": (64, 4, 256, 128),
-    "two k/v heads": (16, 2, 128, 128),
+    "two k/v heads, two tiles wide": (16, 2, 256, 128),
     "an unaligned page": (128, 8, 128, 12),
     "a page short of whole lanes": (16, 8, 128, 8),
 }

@@ -431,10 +431,12 @@ def test_the_eligibility_rules_at_the_published_shapes():
     assert rpa.lane_attention_eligible(64, 8, 256, 128, 128)
     assert not rpa.lane_attention_eligible(64, 4, 192, 128, 128)
     assert not rpa.lane_attention_eligible(64, 8, 128, 128, 64)
-    assert not rpa.lane_attention_eligible(64, 2, 128, 128)
+    # 2 heads of one 128-lane tile flatten for free like 4 (PR 63)
+    assert rpa.lane_attention_eligible(64, 2, 128, 128)
+    assert not rpa.lane_attention_eligible(64, 2, 256, 128)
     for shape in OLD_LANE_SHAPES:
         assert rpa.lane_attention_eligible(*shape), shape
-    for n_kv in (2, 3, 5, 6, 7):
+    for n_kv in (3, 5, 6, 7):
         assert not rpa.lane_attention_eligible(16, n_kv, 128, 128)
     assert not rpa.lane_attention_eligible(16, 8, 64, 128)
 

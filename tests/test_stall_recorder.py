@@ -798,7 +798,9 @@ def test_the_fourteen_entries_resolve():
     assert manifest.check(bench) == []
     entries = new_entries()
     assert len(entries) == 14
-    assert bench["per_layer"][-14:] == entries  # appended, in one piece
+    # appended in one piece (later PRs append behind them: PR 63's two)
+    at = bench["per_layer"].index(entries[0])
+    assert bench["per_layer"][at:at + 14] == entries
     chat = next(m for m in bench["per_layer"]
                 if m["name"] == "host_sched_ms_step.chat")["workloads"]
     for m in entries:
